@@ -130,7 +130,9 @@ class BlockBackend:
         if cache_cfg.kv_quant not in (None, "int8"):
             raise ValueError(f"unknown kv_quant {cache_cfg.kv_quant!r}")
         self.ccfg = cache_cfg
-        self.params = layer_params
+        # Host layer stacks (utils/checkpoint.py) are placed here, or by
+        # shard_pytree below straight to their tp shards.
+        self.params = layer_params if tp > 1 else jax.device_put(layer_params)
         self.first_layer, self.last_layer = first_layer, last_layer
         self.num_block_layers = last_layer - first_layer + 1
         self.max_sessions = max_sessions

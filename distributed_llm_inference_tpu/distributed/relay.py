@@ -20,19 +20,19 @@ import random
 import socket
 import struct
 import subprocess
-import threading
 import time
 import zlib
 from typing import Optional, Tuple
 
 import numpy as np
 
+from ..utils.native_build import build_shared
+
 __all__ = ["RelayServer", "RelayClient", "build_native", "native_available"]
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
 _SRC = os.path.join(_NATIVE_DIR, "relay.cc")
 _SO = os.path.join(_NATIVE_DIR, "_relay.so")
-_build_lock = threading.Lock()
 
 OP_PUT, OP_GET, OP_PING, OP_CANCEL = 1, 2, 3, 4
 CANCEL_ACK = (1 << 64) - 1
@@ -49,21 +49,9 @@ def frame_crc(payload: bytes) -> int:
 
 
 def build_native(force: bool = False) -> str:
-    """Compile ``relay.cc`` → ``_relay.so`` (cached by source mtime)."""
-    with _build_lock:
-        if (
-            not force
-            and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        ):
-            return _SO
-        subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", _SO,
-             "-pthread"],
-            check=True,
-            capture_output=True,
-        )
-        return _SO
+    """Compile ``relay.cc`` → ``_relay.so`` (reused while the source's hash
+    is unchanged; see :func:`utils.native_build.build_shared`)."""
+    return build_shared(_SRC, _SO, force)
 
 
 def native_available() -> bool:

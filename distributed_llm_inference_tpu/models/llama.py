@@ -652,6 +652,7 @@ def convert_hf_state_dict(
     state: Mapping[str, np.ndarray],
     layer_ids: Optional[Sequence[int]] = None,
     dtype=jnp.bfloat16,
+    consume: bool = False,
 ) -> Params:
     """Convert an HF Llama/Mistral/Qwen2 state dict into our param pytree.
 
@@ -660,14 +661,33 @@ def convert_hf_state_dict(
     (``/root/reference/distributed_llm_inference/models/llama/model.py:17``).
     When ``layer_ids`` is None, converts the full model including embeddings
     and head.
+
+    The stacked layer leaves stay HOST (numpy) arrays: whoever serves them
+    places them (``InferenceEngine`` / ``BlockBackend``), one leaf at a
+    time through ``quantize_params`` or straight to their mesh shards. A
+    7B bf16 tree placed whole here is 14.5 GB of a 16 GB chip before its
+    int8 copy exists.
+
+    ``consume``: the caller owns ``state`` (a dict) and is done with it —
+    each layer's tensors are dropped from it as soon as they are converted,
+    so the host holds the checkpoint once plus one stacked leaf instead of
+    three times over (state, per-layer copies, stacks: 35 GiB and counting
+    for a 14.5 GB checkpoint on a 40 GiB host — my chip run, PR 21).
     """
     ids: List[int] = list(layer_ids) if layer_ids is not None else list(
         range(cfg.num_layers)
     )
-    per_layer = [convert_hf_layer(cfg, state, i, dtype) for i in ids]
+    per_layer = []
+    for i in ids:
+        per_layer.append(convert_hf_layer(cfg, state, i, dtype))
+        if consume:
+            prefix = f"model.layers.{i}."
+            for key in [k for k in state if k.startswith(prefix)]:
+                del state[key]
+    # pop: each name's per-layer copies are freed as soon as they are stacked
     stacked = {
-        name: jnp.asarray(np.stack([layer[name] for layer in per_layer]))
-        for name in per_layer[0]
+        name: np.stack([layer.pop(name) for layer in per_layer])
+        for name in list(per_layer[0])
     }
     params: Params = {"layers": stacked}
     if layer_ids is None:

@@ -724,9 +724,10 @@ def _qpaged_fused_kernel(
         vq = jnp.clip(jnp.round(vn / vsc[..., None]), -127, 127).astype(
             jnp.int8
         )
-        slot = jax.lax.broadcasted_iota(jnp.int32, (1, kt, 1), 1)
-        hit3 = slot == step
-        hit2 = hit3[..., 0]
+        # Two iotas, not ``hit3[..., 0]``: Mosaic (jax 0.9.0) refuses the
+        # squeeze of a mask's lane dim ("Invalid vector register cast").
+        hit3 = jax.lax.broadcasted_iota(jnp.int32, (1, kt, 1), 1) == step
+        hit2 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1) == step
         tk = jnp.where(hit3, kq, tk_ref[0, 0])    # [Hkv, KT, D]
         tv = jnp.where(hit3, vq, tv_ref[0, 0])
         tks = jnp.where(hit2, ksc, tks_ref[0, 0])  # [Hkv, KT]

@@ -521,9 +521,10 @@ def _qfused_kernel(
             jnp.int8
         )
 
-        slot = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kt, 1), 2)
-        hit4 = slot == step
-        hit3 = hit4[..., 0]
+        # Two iotas, not ``hit4[..., 0]``: Mosaic (jax 0.9.0) refuses the
+        # squeeze of a mask's lane dim ("Invalid vector register cast").
+        hit4 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kt, 1), 2) == step
+        hit3 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kt), 2) == step
         tk = jnp.where(hit4, kq, tk_ref[0])    # [NB, Hkv, KT, D]
         tv = jnp.where(hit4, vq, tv_ref[0])
         tks = jnp.where(hit3, ksc, tks_ref[0])  # [NB, Hkv, KT]
@@ -1018,9 +1019,9 @@ def _qsink_kernel(
         vq = jnp.clip(jnp.round(vn / vsc[..., None]), -127, 127).astype(
             jnp.int8
         )
-        slot4 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kt, 1), 2)
-        hit4 = slot4 == step
-        hit3 = hit4[..., 0]
+        # As in _qfused_kernel: no squeeze of the mask's lane dim.
+        hit4 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kt, 1), 2) == step
+        hit3 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kt), 2) == step
         tk = jnp.where(hit4, kq, tk_ref[0])    # [NB, Hkv, KT, D]
         tv = jnp.where(hit4, vq, tv_ref[0])
         tks = jnp.where(hit3, ksc, tks_ref[0])  # [NB, Hkv, KT]

@@ -101,8 +101,9 @@ def _int4_kernel(
         alo_ref[:] = jnp.zeros_like(alo_ref)
         ahi_ref[:] = jnp.zeros_like(ahi_ref)
 
-    # int32-domain unpack: Mosaic cannot lower int8 left_shift (HTTP 500 on
-    # this platform's compiler); the sign-extending int8→int32 convert makes
+    # int32-domain unpack: Mosaic could not lower int8 left_shift when this
+    # was written (not retried on jax 0.9.0); the sign-extending
+    # int8→int32 convert makes
     # the arithmetic right shift recover hi directly.
     w32 = w_ref[...].astype(jnp.int32)
     x = x_ref[...]

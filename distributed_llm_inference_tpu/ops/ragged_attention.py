@@ -241,10 +241,43 @@ def _qragged_kernel(
         out_ref[0] = jnp.transpose(out, (1, 0, 2, 3)).astype(out_ref.dtype)
 
 
-def _prep(q, page_size, block_q):
+# Mosaic's default scoped-VMEM limit on a v5e core is 16 MiB. The estimate
+# in :func:`_block_q` counts only what this file allocates per grid step;
+# the compiler's own temporaries (the in-kernel q transpose, the sublane
+# padding of the G axis) come on top, so the budget keeps a third of the
+# limit free. Calibrated by compiling for a described v5e: at 32 query
+# heads of 128 both kernels are refused at block_q 128 (18-23 MiB asked, in
+# bf16, f32 and over int8 pages) and accepted at 64, for 8 and for 32 kv
+# heads; tests/test_chip_compile.py holds the line.
+_VMEM_BUDGET = 10 * 2**20
+
+
+def _block_q(s, hq, hkv, d, page_size, q_itemsize, kv_itemsize):
+    """Largest power-of-two q block (<= 128, <= S rounded up) whose VMEM
+    footprint fits :data:`_VMEM_BUDGET`. Every term but the K/V page
+    blocks scales with ``rows = block_q * Hq``, so wide-head or f32 models
+    get a shorter block instead of a compile-time RESOURCE_EXHAUSTED."""
+    lanes = -(-d // 128) * 128
+    kv_blocks = 2 * 2 * hkv * page_size * lanes * kv_itemsize  # K+V, 2 bufs
+    per_row = (
+        (lanes + 2 * 128) * 4            # acc/m/l scratch (f32)
+        + 2 * 2 * lanes * q_itemsize     # q + out blocks, double-buffered
+        + 2 * max(page_size, 128) * 4    # scores and probs (f32)
+    )
+    bq = min(128, _next_pow2(s))
+    while bq > 8 and bq * hq * per_row + kv_blocks > _VMEM_BUDGET:
+        bq //= 2
+    return bq
+
+
+def _prep(q, k_pages, block_q):
     b, s, hq, d = q.shape
+    _, hkv, page_size, _ = k_pages.shape
     if block_q is None:
-        block_q = min(128, _next_pow2(s))
+        block_q = _block_q(
+            s, hq, hkv, d, page_size, q.dtype.itemsize,
+            k_pages.dtype.itemsize,
+        )
     s_pad = -(-s // block_q) * block_q
     return b, s, hq, d, block_q, s_pad
 
@@ -277,7 +310,7 @@ def ragged_paged_attention(
     ``[B, S, Hq, D]`` with pad query rows zeroed.
     """
     _, hkv, page_size, _ = k_pages.shape
-    b, s, hq, d, bq, s_pad = _prep(q, page_size, block_q)
+    b, s, hq, d, bq, s_pad = _prep(q, k_pages, block_q)
     t = page_table.shape[1]
     g = hq // hkv
     if scale is None:
@@ -361,7 +394,7 @@ def quantized_ragged_paged_attention(
     head) scale planes (``ks_pages``/``vs_pages``: ``[P, Hkv, page_size]``
     f32)."""
     _, hkv, page_size, _ = k_pages.shape
-    b, s, hq, d, bq, s_pad = _prep(q, page_size, block_q)
+    b, s, hq, d, bq, s_pad = _prep(q, k_pages, block_q)
     t = page_table.shape[1]
     g = hq // hkv
     if scale is None:

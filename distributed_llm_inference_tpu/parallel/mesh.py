@@ -21,7 +21,6 @@ row-parallel matmuls, ring permutes of KV blocks) ride the fastest ICI hops.
 
 from __future__ import annotations
 
-import warnings
 from typing import Optional, Sequence
 
 import jax
@@ -79,8 +78,10 @@ def build_mesh(
 
     Uses ``mesh_utils.create_device_mesh`` when the requested shape covers all
     devices of the default backend (it picks an ICI-friendly physical layout on
-    real TPU slices); otherwise lays out the first ``num_devices`` devices in
-    order (virtual CPU meshes, subsets).
+    real TPU slices, and a plain reshape on CPU) — a shape it cannot lay over
+    the slice is an error, not a reason to serve from an enumeration-order
+    mesh whose tp/sp collectives cross the slice; a subset of the devices
+    (virtual CPU meshes, tests) is laid out in order.
     """
     n = mesh_cfg.num_devices
     if devices is None:
@@ -90,19 +91,12 @@ def build_mesh(
             f"mesh {mesh_cfg.shape} needs {n} devices, have {len(devices)}"
         )
     if n == len(devices):
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(
-                mesh_cfg.shape, devices=list(devices)
-            )
-            return Mesh(dev_array, mesh_cfg.axis_names)
-        except Exception as e:  # fall through to the order-preserving layout
-            warnings.warn(
-                f"create_device_mesh failed ({e!r}); using enumeration-order "
-                "device layout — ICI locality of tp/sp collectives may be "
-                "degraded on a real slice"
-            )
+        dev_array = mesh_utils.create_device_mesh(
+            mesh_cfg.shape, devices=list(devices)
+        )
+        return Mesh(dev_array, mesh_cfg.axis_names)
     dev_array = np.asarray(list(devices)[:n]).reshape(mesh_cfg.shape)
     return Mesh(dev_array, mesh_cfg.axis_names)
 

@@ -10,10 +10,12 @@ TPU-native rebuild of the reference's weight loader
   only those layers' shard files (``utils/model.py:40-44``);
 * tensors come out as numpy, get cast to ``bfloat16`` (the reference casts
   non-integer tensors to fp16 for CUDA, ``utils/model.py:66-68``; bf16 is the
-  TPU-native choice), converted to this package's stacked-layer layout, and
-  ``device_put`` with their ``NamedSharding`` — placement *is* the sharding
-  story, replacing accelerate's ``set_module_tensor_to_device``
-  (``utils/model.py:70``).
+  TPU-native choice) and converted to this package's stacked-layer layout.
+  The layer stacks are returned as HOST arrays and placed by whoever serves
+  them — quantized leaf by leaf, or ``device_put`` with their
+  ``NamedSharding`` — so the unquantized whole is never resident on one
+  device; placement *is* the sharding story, replacing accelerate's
+  ``set_module_tensor_to_device`` (``utils/model.py:70``).
 
 Paths are local snapshot directories (an HF hub cache dir works as-is); a
 ``resolve`` callable parameterizes filename→path lookup so a hub/remote
@@ -25,6 +27,7 @@ from __future__ import annotations
 
 import json
 import os
+import warnings
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import jax
@@ -89,8 +92,11 @@ def _read_tensors_safetensors(path: str, wanted: Callable[[str], bool]):
         try:
             with streader.NativeSafetensors(path) as f:
                 return f.read_many([k for k in f.keys() if wanted(k)])
-        except Exception:
-            pass  # unreadable via native path: fall through to the wheel
+        except Exception as e:  # host file I/O only — no device in reach
+            warnings.warn(
+                f"native read of {path!r} failed ({e!r}); falling back to "
+                "the safetensors wheel"
+            )
     from safetensors import safe_open
 
     out: Dict[str, np.ndarray] = {}
@@ -183,7 +189,9 @@ def load_block_params(
     """
     def build():
         state = block_state_dict(model_dir, layer_ids, resolve=resolve)
-        return llama.convert_hf_state_dict(cfg, state, layer_ids, dtype)
+        return llama.convert_hf_state_dict(
+            cfg, state, layer_ids, dtype, consume=True
+        )
 
     return _cached_load(
         build, model_dir, cache_dir, layer_ids, dtype, resolve, tag="block"
@@ -203,7 +211,9 @@ def load_model_params(
         state = block_state_dict(
             model_dir, None, include_non_layer=True, resolve=resolve
         )
-        return llama.convert_hf_state_dict(cfg, state, None, dtype)
+        return llama.convert_hf_state_dict(
+            cfg, state, None, dtype, consume=True
+        )
 
     return _cached_load(
         build, model_dir, cache_dir, None, dtype, resolve, tag="model"
@@ -291,9 +301,12 @@ def _cached_load(build, model_dir, cache_dir, layer_ids, dtype, resolve, tag):
         except Exception:
             pass  # corrupt/partial cache entry: rebuild below
         else:
-            return _unflatten_params(
-                {k: jnp.asarray(v) for k, v in flat.items()}
-            )
+            # Layer stacks stay on the host, as from a fresh conversion
+            # (llama.convert_hf_state_dict): the consumer places them.
+            return _unflatten_params({
+                k: v if k.startswith("layers.") else jnp.asarray(v)
+                for k, v in flat.items()
+            })
     params = build()
     os.makedirs(cache_dir, exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}"

@@ -38,6 +38,7 @@ METRICS = {
     "sessions_finished": ("counter", "Sessions retired (any reason)"),
     "sessions_rejected": ("counter", "Sessions refused at admission"),
     "sessions_deadline_expired": ("counter", "Sessions reaped past deadline"),
+    "admission_order_errors": ("counter", "Admission-order hook raised; tick fell back to FIFO"),
     "admit_sync_sessions": ("counter", "Sessions admitted synchronously"),
     "admit_overlap_sessions": ("counter", "Sessions admitted via overlap"),
     "admit_overlap_spill": ("counter", "Overlap admissions spilled to sync"),

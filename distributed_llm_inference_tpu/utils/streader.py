@@ -14,18 +14,18 @@ from __future__ import annotations
 import ctypes
 import json
 import os
-import subprocess
-import threading
+import warnings
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+
+from .native_build import build_shared
 
 __all__ = ["NativeSafetensors", "build_native", "native_available", "DTYPES"]
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
 _SRC = os.path.join(_NATIVE_DIR, "streader.cc")
 _SO = os.path.join(_NATIVE_DIR, "_streader.so")
-_build_lock = threading.Lock()
 _lib = None
 
 
@@ -51,26 +51,9 @@ DTYPES = {
 
 
 def build_native(force: bool = False) -> str:
-    """Compile ``streader.cc`` → ``_streader.so`` (cached by source mtime).
-
-    Compiles to a pid-suffixed temp path then ``os.replace``s it in, so a
-    concurrent process never ``dlopen``s a half-written library."""
-    with _build_lock:
-        if (
-            not force
-            and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)
-        ):
-            return _SO
-        tmp = f"{_SO}.tmp.{os.getpid()}"
-        subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp,
-             "-pthread"],
-            check=True,
-            capture_output=True,
-        )
-        os.replace(tmp, _SO)
-        return _SO
+    """Compile ``streader.cc`` → ``_streader.so`` (reused while the source's
+    hash is unchanged; see :func:`utils.native_build.build_shared`)."""
+    return build_shared(_SRC, _SO, force)
 
 
 def _load_lib():
@@ -81,10 +64,15 @@ def _load_lib():
         return _lib
     try:
         lib = ctypes.CDLL(build_native())
-    except Exception:
+    except Exception as e:
         # Cache the failure: without this, every shard read on the startup
-        # path would re-spawn a doomed g++ subprocess.
+        # path would re-spawn a doomed g++ subprocess. Said once, since
+        # native_available() turns the raise into a quiet False.
         _lib = False
+        warnings.warn(
+            f"native safetensors reader unavailable ({e!r}); checkpoints "
+            "are read through the safetensors wheel"
+        )
         raise
     lib.st_open.restype = ctypes.c_void_p
     lib.st_open.argtypes = [ctypes.c_char_p]

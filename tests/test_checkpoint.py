@@ -302,3 +302,66 @@ def test_load_config_rejects_unsupported_family(tmp_path):
         checkpoint.load_config(str(tmp_path))
     cfg = checkpoint.load_config(str(tmp_path), validate=False)
     assert cfg.family == "gpt2"
+
+
+# -- load → quantize → place: the unquantized whole is never on one device ----
+
+
+def test_loaded_layer_stacks_stay_on_the_host(tmp_path):
+    """A 7B bf16 tree placed whole is 14.5 GB of a 16 GB chip before its
+    int8 copy exists: the loader hands the layer stacks over unplaced
+    (fresh and from the pre-converted cache alike) and the engine places
+    them, quantized leaf by leaf."""
+    import jax
+
+    from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig
+    from distributed_llm_inference_tpu.engine.engine import InferenceEngine
+    from distributed_llm_inference_tpu.ops.quant import (
+        QuantizedTensor, quantize_params,
+    )
+
+    _write_sharded(str(tmp_path), _hf_state(CFG))
+    cache_dir = str(tmp_path / "wcache")
+    fresh = checkpoint.load_model_params(
+        str(tmp_path), CFG, jnp.float32, cache_dir=cache_dir
+    )
+    cached = checkpoint.load_model_params(
+        str(tmp_path), CFG, jnp.float32, cache_dir=cache_dir
+    )
+    for params in (fresh, cached):
+        assert all(
+            isinstance(a, np.ndarray) for a in params["layers"].values()
+        )
+    engine = InferenceEngine(
+        CFG, fresh,
+        EngineConfig(max_batch_size=2, prefill_buckets=(8,), max_seq_len=16,
+                     dtype="float32", quantization="int8"),
+        CacheConfig(kind="dense"),
+    )
+    served = engine.params["layers"]
+    assert isinstance(served["wq"], QuantizedTensor)
+    assert all(
+        isinstance(a, jax.Array) for a in jax.tree.leaves(engine.params)
+    )
+    # Host leaves quantize to the bytes device leaves do.
+    placed = quantize_params(jax.device_put(fresh))["layers"]
+    for name in ("wq", "wd"):
+        np.testing.assert_array_equal(served[name].q, placed[name].q)
+        np.testing.assert_array_equal(served[name].scale, placed[name].scale)
+
+
+def test_convert_consume_frees_the_state_as_it_goes():
+    """``consume=True`` (the loader owns its state dict): same params, and
+    every layer tensor is gone from the dict afterwards — the host holds
+    the checkpoint once, not three times."""
+    state = _hf_state(CFG)
+    ref = llama.convert_hf_state_dict(CFG, dict(state), None, jnp.float32)
+    out = llama.convert_hf_state_dict(
+        CFG, state, None, jnp.float32, consume=True
+    )
+    assert not [k for k in state if k.startswith("model.layers.")]
+    for name in ref["layers"]:
+        np.testing.assert_array_equal(out["layers"][name], ref["layers"][name])
+    np.testing.assert_array_equal(
+        np.asarray(out["embed"]), np.asarray(ref["embed"])
+    )

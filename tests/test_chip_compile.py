@@ -1,0 +1,131 @@
+"""The serving path's kernels compile for a v5e at Mistral-7B widths.
+
+Interpret mode cannot see what the chip's compiler refuses: a scratch that
+overflows a core's 16 MiB of scoped VMEM (the ragged kernels at 32 query / 8
+kv heads of 128 with the old fixed ``block_q`` of 128), or a cast Mosaic no
+longer lowers (the fused int8 decode kernels' mask squeeze under jax 0.9.0).
+The TPU compiler is installed beside the CPU backend and compiles for a chip
+that is described, not attached — about a second or two a kernel, no chip
+time. Nothing here runs, so nothing here says a kernel is right or fast.
+
+Skipped as one where the topology cannot be described.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+# libtpu lets one process load it at a time (a lockfile): no chip is held
+# here, and under pytest-xdist every worker may get some of these cases.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from distributed_llm_inference_tpu.ops import flash_attention as fa
+from distributed_llm_inference_tpu.ops import paged_attention as pa
+from distributed_llm_inference_tpu.ops import ragged_attention as ra
+
+# Mistral-7B-v0.1: 32 query / 8 kv heads of 128; the engine's defaults:
+# 64-token pages, a 512-page pool, 64 table slots, 16-step fused decode.
+HQ, HKV, D, PS, PAGES, SLOTS, LAYERS, KT = 32, 8, 128, 64, 512, 64, 32, 16
+I8, F32, I32 = jnp.int8, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def chip():
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or it cannot describe a v5e here
+        pytest.skip(f"cannot describe a v5e topology: {e!r}")
+    one = SingleDeviceSharding(topo.devices[0])
+    # A described-device executable written to the persistent cache cannot
+    # be read back without a chip (the next compile warns): keep it off,
+    # whatever the session set. And compile at the chip's own matmul
+    # precision, not the "highest" the CPU suite asks for in conftest
+    # (Mosaic has no fp32-precision matmul over bf16 operands).
+    was = (jax.config.jax_enable_compilation_cache,
+           jax.config.jax_default_matmul_precision)
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one
+    )
+    jax.config.update("jax_enable_compilation_cache", was[0])
+    jax.config.update("jax_default_matmul_precision", was[1])
+
+
+def _compiles_with_kernel(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the compiled step"
+
+
+def _pool(s, dtype):
+    pages = s((PAGES, HKV, PS, D), dtype)
+    return (pages, pages) if dtype != I8 else (
+        pages, s((PAGES, HKV, PS), F32), pages, s((PAGES, HKV, PS), F32)
+    )
+
+
+@pytest.mark.parametrize("seq,batch", [(128, 4), (2048, 1)])
+@pytest.mark.parametrize("q_dtype", [jnp.bfloat16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("pages", ["model-dtype", "int8"])
+def test_ragged_prefill_kernels(chip, seq, batch, q_dtype, pages):
+    """Both ragged kernels with the block_q they choose for themselves."""
+    s = chip
+    rows = (s((batch, SLOTS), I32), s((batch,), I32), s((batch,), I32))
+    q = s((batch, seq, HQ, D), q_dtype)
+    kernel = (
+        ra.quantized_ragged_paged_attention if pages == "int8"
+        else ra.ragged_paged_attention
+    )
+    _compiles_with_kernel(
+        lambda q, *a: kernel(q, *a, sliding_window=4096, interpret=False),
+        q, *_pool(s, I8 if pages == "int8" else q_dtype), *rows,
+    )
+
+
+@pytest.mark.parametrize("pages", ["bf16", "int8"])
+def test_paged_decode_kernels(chip, pages):
+    s, b = chip, 8
+    kernel = (
+        pa.quantized_paged_attention if pages == "int8" else pa.paged_attention
+    )
+    _compiles_with_kernel(
+        lambda q, *a: kernel(q, *a, sliding_window=4096, interpret=False),
+        s((b, 1, HQ, D), jnp.bfloat16),
+        *_pool(s, I8 if pages == "int8" else jnp.bfloat16),
+        s((b, SLOTS), I32), s((b,), I32),
+    )
+
+
+def test_fused_int8_paged_decode_kernel(chip):
+    """At the shapes the engine's decode scan gives it: the whole
+    ``[L, P, ...]`` pool, a 16-slot tail, rank-0 layer and step indices."""
+    s, b = chip, 8
+    bf16 = jnp.bfloat16
+    pool = (s((LAYERS, PAGES, HKV, PS, D), I8), s((LAYERS, PAGES, HKV, PS), F32))
+    tail = (s((LAYERS, b, HKV, KT, D), I8), s((LAYERS, b, HKV, KT), F32))
+    _compiles_with_kernel(
+        lambda *a: pa.quantized_paged_fused_attention(
+            *a, sliding_window=4096, interpret=False
+        ),
+        s((b, 1, HQ, D), bf16), s((b, 1, HKV, D), bf16), s((b, 1, HKV, D), bf16),
+        *pool, *pool, *tail, *tail, s((), I32), s((), I32),
+        s((b, SLOTS), I32), s((b,), I32), s((b,), I32), s((b,), I32),
+    )
+
+
+def test_flash_attention_kernel(chip):
+    s, seq, t = chip, 2048, 4096
+    bf16 = jnp.bfloat16
+    _compiles_with_kernel(
+        lambda *a: fa.flash_attention(*a, interpret=False),
+        s((1, seq, HQ, D), bf16), s((1, t, HKV, D), bf16),
+        s((1, t, HKV, D), bf16), s((1, seq, t), jnp.bool_),
+    )

@@ -1,0 +1,76 @@
+"""Where the program keeps JAX's persistent compilation cache
+(``utils/compile_cache.py``): placed from outside through
+``JAX_COMPILATION_CACHE_DIR``, else one fixed directory in the checkout."""
+
+import os
+import re
+
+import jax
+import pytest
+
+from distributed_llm_inference_tpu.utils import compile_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_OPTIONS = (
+    "jax_compilation_cache_dir",
+    "jax_persistent_cache_min_compile_time_secs",
+    "jax_persistent_cache_min_entry_size_bytes",
+)
+
+
+@pytest.fixture
+def restore_config():
+    was = {name: getattr(jax.config, name) for name in _OPTIONS}
+    yield was
+    for name, value in was.items():
+        jax.config.update(name, value)
+
+
+def test_env_placed_cache_sets_no_directory_in_code(monkeypatch, restore_config):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    # JAX read the variable itself at import (or not, if set since): the
+    # helper leaves the option exactly as it found it.
+    assert (
+        jax.config.jax_compilation_cache_dir
+        == restore_config["jax_compilation_cache_dir"]
+    )
+
+
+def test_unset_env_uses_the_checkout(monkeypatch, restore_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable_compile_cache()
+    assert path == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == path
+
+
+@pytest.mark.parametrize("placed", [None, "/somewhere/else"])
+def test_every_executable_is_kept(monkeypatch, restore_config, placed):
+    """JAX's default skips compiles under a second, so a warm second process
+    would still compile the small ones."""
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    compile_cache.enable_compile_cache()
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+
+def test_checkout_path_is_fixed():
+    """No pid, clock or temporary component: the directory is part of the
+    cache key, so a path that moves never hits."""
+    path = compile_cache.CHECKOUT_CACHE_DIR
+    rel = os.path.relpath(path, REPO)
+    assert rel == ".jax_cache"
+    assert str(os.getpid()) not in rel and not re.search(r"\d", rel)
+    assert "tmp" not in rel.lower() and "temp" not in rel.lower()
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_cache_entries_counts_executables(tmp_path):
+    assert compile_cache.cache_entries(str(tmp_path / "missing")) == 0
+    for name in ("a-cache", "a-atime", "b-cache"):
+        (tmp_path / name).write_bytes(b"x")
+    assert compile_cache.cache_entries(str(tmp_path)) == 2

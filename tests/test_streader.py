@@ -102,3 +102,26 @@ def test_checkpoint_loader_uses_native(tmp_path, monkeypatch):
     assert set(native) == set(wheel)
     for k in native:
         np.testing.assert_array_equal(native[k], wheel[k])
+
+
+def test_native_library_is_rebuilt_when_its_source_changes(tmp_path):
+    """Staleness is the source's hash, not file times: the libraries are
+    git-ignored yet travel with a copied tree, and a copy need not keep
+    mtimes (``utils/native_build.py``)."""
+    import shutil
+
+    from distributed_llm_inference_tpu.utils.native_build import build_shared
+
+    src, so = str(tmp_path / "streader.cc"), str(tmp_path / "_streader.so")
+    shutil.copy(streader._SRC, src)
+    build_shared(src, so)
+    built = os.stat(so).st_mtime_ns
+    os.utime(src)  # a newer source with the same bytes: still current
+    build_shared(src, so)
+    assert os.stat(so).st_mtime_ns == built
+    with open(src, "a") as f:
+        f.write("\n// changed\n")
+    os.utime(so, (2e9, 2e9))  # a library "newer" than its changed source
+    build_shared(src, so)
+    assert os.stat(so).st_mtime_ns != int(2e9 * 1e9)
+    assert not [n for n in os.listdir(tmp_path) if ".tmp." in n]

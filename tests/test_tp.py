@@ -150,3 +150,17 @@ def test_validate_tp_rejects_bad_degrees():
         validate_tp(CFG, 3)
     with pytest.raises(ValueError):
         validate_tp(CFG, 2, sp=3)
+
+
+def test_build_mesh_layout_failure_is_an_error(monkeypatch):
+    """A shape ``create_device_mesh`` cannot lay over the slice used to
+    warn and serve from an enumeration-order mesh; on real chips that
+    hides a degraded layout, so it raises."""
+    from jax.experimental import mesh_utils
+
+    def refuse(*a, **k):
+        raise RuntimeError("cannot assign this mesh to the slice")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
+    with pytest.raises(RuntimeError, match="cannot assign"):
+        build_mesh(MeshConfig(dp=2, tp=4))

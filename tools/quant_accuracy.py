@@ -57,8 +57,7 @@ SHAPES = {
     ),
     # Full 7B width at 8 layers: bf16 + a quantized copy coexist on one
     # chip, so every mode runs device-side (the 32-layer host path works
-    # but pays slow host<->device transfers per quantize op on tunneled
-    # platforms). Width drives per-layer quantization error; depth drives
+    # but moves each leaf host<->device as it is quantized). Width drives per-layer quantization error; depth drives
     # accumulation — report the proxy as what it is.
     "llama2-7b-8l": ModelConfig(
         vocab_size=32000, hidden_size=4096, intermediate_size=11008,
