@@ -286,7 +286,7 @@ def cmd_generate(args) -> int:
 def cmd_local(args) -> int:
     import jax.numpy as jnp
 
-    from .config import CacheConfig, EngineConfig
+    from .config import CacheConfig, EngineConfig, TraceConfig
     from .engine.engine import InferenceEngine
     from .engine.sampling import SamplingOptions
     from .utils import checkpoint
@@ -324,6 +324,11 @@ def cmd_local(args) -> int:
         ),
         CacheConfig(kind=args.cache, kv_quant=args.kv_quant),
         draft=draft,
+        # with a profile asked for, the flight recorder is on: the trace's
+        # host plane then carries every tick (``engine_tick``, step_num =
+        # the tick's id) and its phases, and the ticks are written beside it
+        trace_cfg=TraceConfig(ticks_capacity=100_000)
+        if args.profile_dir else None,
     )
     with profile_trace(args.profile_dir):
         out = engine.generate(
@@ -335,11 +340,8 @@ def cmd_local(args) -> int:
             ),
         )[0]
     if args.profile_dir:
-        import os
-
-        engine.spans.dump_chrome_trace(
-            os.path.join(args.profile_dir, "host_spans.json")
-        )
+        with open(os.path.join(args.profile_dir, "ticks.json"), "w") as f:
+            json.dump({"ticks": engine.flight.snapshot()}, f)
     extra["metrics"] = engine.metrics.snapshot()
     if draft is not None:
         st = engine.spec_stats
@@ -770,8 +772,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "decoding (same tokenizer/vocab as --model)")
     l.add_argument("--speculative-k", type=int, default=4)
     l.add_argument("--profile-dir", default=None,
-                   help="dump a jax.profiler device trace + host span "
-                        "timeline (Perfetto-loadable) into this directory")
+                   help="dump a jax.profiler trace (device planes, and the "
+                        "engine's ticks and phases on the host plane) and "
+                        "the flight recorder's tick records (ticks.json; "
+                        "tick id = the trace's step_num) into this directory")
     l.set_defaults(fn=cmd_local)
 
     a = sub.add_parser(

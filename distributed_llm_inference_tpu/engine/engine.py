@@ -23,6 +23,7 @@ Step anatomy (host orchestrates, device computes):
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import math
@@ -47,10 +48,13 @@ _SINK_KINDS = (SinkKVCache, QuantizedSinkKVCache)
 from ..config import CacheConfig, EngineConfig, ModelConfig, PrefixConfig
 from ..models import llama
 from ..utils.metrics import Metrics
-from ..utils.tracing import FlightRecorder, SpanRecorder, span
+from ..utils.tracing import FlightRecorder, Span
 from .plan import AttentionPlan
 from .sampling import SamplingOptions, SamplingParams, sample
 from .session import Session, SessionState
+
+# ``_region`` with the flight recorder off: one shared, re-entrant no-op.
+_NO_REGION = contextlib.nullcontext()
 
 
 class InferenceEngine:
@@ -145,16 +149,20 @@ class InferenceEngine:
         self.pcfg = prefix_cfg or PrefixConfig()
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
         self.metrics = Metrics()
-        self.spans = SpanRecorder()
         # Flight recorder (``trace_cfg`` = a config.TraceConfig): a bounded
-        # ring of per-tick records behind /debug/ticks. None when tracing
-        # is off — step() then pays one attribute load + branch, no
+        # ring of per-tick records behind /debug/ticks, and the host clock
+        # that splits every tick into phases (``_region``). None when
+        # tracing is off — step() then pays one attribute load + branch, no
         # allocation, no host sync (the DC301 decode-tick contract).
         self.flight = (
-            FlightRecorder(trace_cfg.ticks_capacity)
+            FlightRecorder(trace_cfg.ticks_capacity, self.metrics)
             if trace_cfg is not None and trace_cfg.enabled
             else None
         )
+        # The gateway's span recorder (``EngineBackend.attach_tracer``): a
+        # traced session's ``engine.queue`` / ``engine.first_token`` spans
+        # go where the gateway's own do. None = no request spans.
+        self.tracer = None
         # Scheduler lock (SURVEY §5.2): slots/cache/allocator are mutated
         # only by step()/collect_finished() under this lock (single-writer).
         # submit()/cancel() are deliberately LOCK-FREE — step() holds the
@@ -181,6 +189,8 @@ class InferenceEngine:
         # paged multi-token rows through the ragged mixed-phase kernel on
         # TPU, and owns every prefill-family pad width below.
         self.plan = AttentionPlan(self.ecfg, self.ccfg, metrics=self.metrics)
+        if self.flight is not None:
+            self.plan.dispatches = []  # a tick's worth; step() takes it
         if mesh_cfg is not None:
             # Mesh engines keep the legacy path end to end: ring/sp prefill
             # is a different collective-bearing program and the ragged
@@ -1248,48 +1258,28 @@ class InferenceEngine:
         Pipelined engines (``EngineConfig.pipelined_ticks``) dispatch the
         next device tick BEFORE resolving the previous one, so a tick's
         tokens arrive one ``step()`` later than they were dispatched."""
-        produced: List[Tuple[str, int, bool]] = []
-        # Flight recorder: host-clock only (perf_counter — no device_get,
-        # no block_until_ready), and None unless a TraceConfig enabled it,
-        # so the disabled tick pays one attribute load + branch.
-        fr = self.flight
-        t0 = time.perf_counter() if fr is not None else 0.0
-        queued0 = len(self.waiting) if fr is not None else 0
+        # Flight recorder: host-clock only (no device_get, no
+        # block_until_ready of its own), and None unless a TraceConfig
+        # enabled it, so the disabled tick pays one attribute load + branch.
+        # Its clock is read and written under the scheduler lock alone, as
+        # is everything else a tick touches (waiting for the lock is the
+        # tick's ``outside``).
         with self._lock:
-            if self._ext_produced:
-                produced.extend(self._ext_produced)
-                self._ext_produced.clear()
-            if self._pipelined:
-                prev = self._pending
-                self._pending = self._dispatch_tick(produced, prev)
-                self._resolve_pending(produced, prev)
-                # Chunked-prefill co-scheduling rides BEHIND the decode
-                # dispatch (device-ordered after it) and after the resolve,
-                # so a final chunk's deferred first token rides the NEXT
-                # tick's device_get exactly like an overlapped admission.
-                self._chunk_dispatch(produced)
-                self._admit(produced)
-            else:
-                self._admit(produced)
-                self._chunk_dispatch(produced)
-                if any(
-                    gid is not None and not self.sessions[gid].chunking
-                    for gid in self.slots
-                ):
-                    self._decode_tick(produced)
-                elif (
-                    self.draft is not None
-                    and self._spec_pending is not None
-                ):
-                    # Every speculative session left (cancel/finish burst)
-                    # with a tick in flight and nothing was admitted:
-                    # _decode_tick won't run to drain it, so resolve here —
-                    # otherwise has_work() reports the orphaned pending
-                    # tick forever.
-                    self._spec_flush(produced)
-        if fr is not None:
+            fr = self.flight
+            if fr is None:
+                return self._run_tick()
+            tick = fr.begin()
+            queued0 = len(self.waiting)
+            # The profiler's host plane carries the tick (step_num = the
+            # tick record's id) and, nested in it, the ``engine.<phase>``
+            # regions.
+            with jax.profiler.StepTraceAnnotation(
+                "engine_tick", step_num=tick
+            ):
+                produced = self._run_tick()
             queued1 = len(self.waiting)
-            fr.record(
+            dispatches, self.plan.dispatches = self.plan.dispatches, []
+            fr.end(
                 kind="pipelined" if self._pipelined else "plain",
                 occupancy=sum(1 for g in self.slots if g is not None),
                 queued=queued1,
@@ -1302,9 +1292,110 @@ class InferenceEngine:
                 pending=self._pending is not None,
                 events=len(produced),
                 dispatch=self.plan.last_dispatch,
-                host_ms=(time.perf_counter() - t0) * 1e3,
+                dispatches=dispatches,
+                free_pages=(
+                    self.allocator.free_count
+                    if self.allocator is not None else None
+                ),
             )
         return produced
+
+    def _run_tick(self) -> List[Tuple[str, int, bool]]:
+        """One tick's work; the caller holds the scheduler lock."""
+        produced: List[Tuple[str, int, bool]] = []
+        if self._ext_produced:
+            produced.extend(self._ext_produced)
+            self._ext_produced.clear()
+        if self._pipelined:
+            prev = self._pending
+            with self._region("dispatch"):
+                self._pending = self._dispatch_tick(produced, prev)
+            with self._region("deliver"):
+                self._resolve_pending(produced, prev)
+            # Chunked-prefill co-scheduling rides BEHIND the decode
+            # dispatch (device-ordered after it) and after the resolve,
+            # so a final chunk's deferred first token rides the NEXT
+            # tick's device_get exactly like an overlapped admission.
+            with self._region("admit"):
+                self._chunk_dispatch(produced)
+                self._admit(produced)
+        else:
+            with self._region("admit"):
+                self._admit(produced)
+                self._chunk_dispatch(produced)
+            if any(
+                gid is not None and not self.sessions[gid].chunking
+                for gid in self.slots
+            ):
+                with self._region("dispatch"):
+                    self._decode_tick(produced)
+            elif (
+                self.draft is not None
+                and self._spec_pending is not None
+            ):
+                # Every speculative session left (cancel/finish burst)
+                # with a tick in flight and nothing was admitted:
+                # _decode_tick won't run to drain it, so resolve here —
+                # otherwise has_work() reports the orphaned pending
+                # tick forever.
+                with self._region("deliver"):
+                    self._spec_flush(produced)
+        return produced
+
+    # -- the tick's host clock (utils/tracing.py FlightRecorder) -------------
+
+    def _region(self, phase: str):
+        """Where the drive thread is, for the flight recorder: a
+        ``TraceAnnotation("engine.<phase>")`` on the profiler's host plane
+        and the region's host seconds on the tick in progress, each region
+        timed once (a region inside another suspends the outer one). A
+        shared no-op with the recorder off."""
+        fr = self.flight
+        return _NO_REGION if fr is None else fr.region(phase)
+
+    def _fetch(self, x):
+        """``jax.device_get`` for the tick path: the time the drive thread
+        waits here is the tick's ``blocked`` phase, measured at the sync
+        itself and taken out of whichever phase encloses it."""
+        with self._region("blocked"):
+            return jax.device_get(x)
+
+    def _live_positions(self, active, pending=None) -> int:
+        """The census of a decode dispatch (``plan.note_dispatch``): the
+        host-known context lengths of its active rows, summed — with the
+        tokens a tick in flight may still deliver (``pending``) counted
+        in."""
+        live = 0
+        for slot in np.flatnonzero(active):
+            live += self.sessions[self.slots[slot]].total_len
+        if pending is not None:
+            live += int(pending[active].sum())
+        return live
+
+    def _note_admitted(self, s: Session) -> None:
+        """The admission dispatch takes ``s`` (called right before its
+        prefill is dispatched, or as it is parked for chunked prefill):
+        ``engine_queue_wait`` observes submit → now, and a traced session
+        records the same as its ``engine.queue`` span."""
+        now = time.monotonic()
+        s.admit_time = now
+        self.metrics.observe("engine_queue_wait", now - s.submit_time)
+        if s.trace is not None and self.tracer is not None:
+            self._request_span("engine.queue", s, s.submit_time, now)
+
+    def _request_span(self, name: str, s: Session, m0: float, m1: float):
+        """One span of a traced session between two ``time.monotonic()``
+        readings, stamped on the epoch clock like every other span of the
+        request, as a child of the request's context."""
+        c = s.trace.child()
+        fr = self.flight
+        self.tracer.record(Span(
+            name, time.time() - (time.monotonic() - m0), m1 - m0,
+            {"gen_id": s.generation_id,
+             "tick": fr.tick if fr is not None else None},
+            trace_id=c.trace_id, span_id=c.span_id, parent_id=c.parent_id,
+            node="engine",
+        ))
 
     def has_work(self) -> bool:
         with self._lock:
@@ -2386,44 +2477,44 @@ class InferenceEngine:
             opts[i] = s.options
         sp = SamplingParams.stack(opts)
         self.plan.note_dispatch("prefill", (nr, width), int(n_valid.sum()))
-        with self.metrics.timer("prefill"), span(
-            "prefill_batch", self.spans, sessions=k,
-            prompt_tokens=int(n_valid.sum()),
-        ):
-            sub = self._fresh_sub(nr)
-            if sub is not None:
-                # Split pair (see _prefill_rows_standalone): compact
-                # prefill with NO big-cache arrays, then a merge-only
-                # dispatch — the combined program crashed the compiler
-                # of rounds 1–5 past B×T ≈ 22.5k (not retried since).
-                toks, sub = self._prefill_batch_standalone(
-                    self.params, jnp.asarray(tokens), sub,
-                    jnp.asarray(n_valid), self._next_key(), sp,
-                )
-                self.cache = self._merge_rows_only(
-                    self.cache, sub, jnp.asarray(rows)
-                )
-            else:
-                toks, self.cache = self._prefill_batch(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(rows), jnp.asarray(n_valid),
-                    self._next_key(), sp,
-                )
-            if self._overlap_ok():
-                # Everything above was dispatch-only; defer the blocking
-                # token fetch to the next tick boundary (it rides the tick
-                # resolve's device_get) so this tick never stalls on
-                # prefill completion.
-                self.metrics.counter("batched_prefills", k)
-                self._defer_admit(group, toks, rows, [0] * k)
-                return
-            toks = np.asarray(jax.device_get(toks))
+        for s in group:
+            self._note_admitted(s)
+        sub = self._fresh_sub(nr)
+        if sub is not None:
+            # Split pair (see _prefill_rows_standalone): compact
+            # prefill with NO big-cache arrays, then a merge-only
+            # dispatch — the combined program crashed the compiler
+            # of rounds 1–5 past B×T ≈ 22.5k (not retried since).
+            toks, sub = self._prefill_batch_standalone(
+                self.params, jnp.asarray(tokens), sub,
+                jnp.asarray(n_valid), self._next_key(), sp,
+            )
+            self.cache = self._merge_rows_only(
+                self.cache, sub, jnp.asarray(rows)
+            )
+        else:
+            toks, self.cache = self._prefill_batch(
+                self.params, jnp.asarray(tokens), self.cache,
+                jnp.asarray(rows), jnp.asarray(n_valid),
+                self._next_key(), sp,
+            )
+        if self._overlap_ok():
+            # Everything above was dispatch-only; defer the blocking
+            # token fetch to the next tick boundary (it rides the tick
+            # resolve's device_get) so this tick never stalls on
+            # prefill completion.
+            self.metrics.counter("batched_prefills", k)
+            self._defer_admit(group, toks, rows, [0] * k)
+            return
+        toks = np.asarray(self._fetch(toks))
         self.metrics.counter("batched_prefills", k)
         self.metrics.counter("admit_sync_sessions", k)
-        for i, s in enumerate(group):
-            self._finish_prefill(
-                s, int(toks[i]), np.asarray(s.prompt, np.int32), produced, 0
-            )
+        with self._region("deliver"):
+            for i, s in enumerate(group):
+                self._finish_prefill(
+                    s, int(toks[i]), np.asarray(s.prompt, np.int32),
+                    produced, 0,
+                )
 
     def _fresh_sub(self, nr: int):
         """A fresh ``nr``-row cache of the serving kind/shape for the split
@@ -2480,6 +2571,7 @@ class InferenceEngine:
         sequence-sharded over the ring instead (one dispatch for the whole
         prompt; each sp device computes ``bucket/sp`` positions)."""
         self._flush_installs()  # prefill writes through the page table
+        self._note_admitted(s)
         if s.cow_src is not None:
             # Deferred copy-on-write split: enqueue the device copy of the
             # fully-shared final page into this session's private page, then
@@ -2503,43 +2595,35 @@ class InferenceEngine:
             bucket = self._ring_bucket(len(prompt))
             padded = np.zeros((1, bucket), np.int32)
             padded[0, : len(prompt)] = prompt
-            with self.metrics.timer("prefill"), span(
-                "ring_prefill", self.spans,
-                generation_id=s.generation_id, prompt_tokens=len(s.prompt),
-            ):
-                token, self.cache = self._ring_prefill(
-                    self.params, jnp.asarray(padded), self.cache, s.slot,
-                    jnp.int32(len(prompt)), self._next_key(), sp,
-                )
+            token, self.cache = self._ring_prefill(
+                self.params, jnp.asarray(padded), self.cache, s.slot,
+                jnp.int32(len(prompt)), self._next_key(), sp,
+            )
             self.metrics.counter("ring_prefills")
             # Ring/sp prefill stays synchronous by design: it only exists
             # on mesh engines (see _overlap_ok's rationale).
             self.metrics.counter("admit_sync_sessions")
-            self._finish_prefill(s, int(token), prompt, produced, skip)
+            self._finish_sync_prefill(s, token, prompt, produced, skip)
             return
         offset = skip
         stride = self.plan.prefill_stride(chunk_cap)
-        with self.metrics.timer("prefill"), span(
-            "prefill", self.spans,
-            generation_id=s.generation_id, prompt_tokens=len(s.prompt),
-        ):
-            while len(prompt) - offset > stride:
-                chunk = prompt[offset : offset + stride]
-                padded = jnp.asarray(chunk)[None, :]
-                self.plan.note_dispatch("chunk", (1, stride), len(chunk))
-                self.cache = self._prefill_ns(
-                    self.params, padded, self.cache, s.slot, jnp.int32(len(chunk))
-                )
-                offset += stride
-            rest = prompt[offset:]
-            width = self.plan.final_shape(len(rest), chunk_cap)
-            padded = np.zeros((1, width), np.int32)
-            padded[0, : len(rest)] = rest
-            self.plan.note_dispatch("prefill", (1, width), len(rest))
-            token, self.cache = self._prefill(
-                self.params, jnp.asarray(padded), self.cache, s.slot,
-                jnp.int32(len(rest)), self._next_key(), sp,
+        while len(prompt) - offset > stride:
+            chunk = prompt[offset : offset + stride]
+            padded = jnp.asarray(chunk)[None, :]
+            self.plan.note_dispatch("chunk", (1, stride), len(chunk))
+            self.cache = self._prefill_ns(
+                self.params, padded, self.cache, s.slot, jnp.int32(len(chunk))
             )
+            offset += stride
+        rest = prompt[offset:]
+        width = self.plan.final_shape(len(rest), chunk_cap)
+        padded = np.zeros((1, width), np.int32)
+        padded[0, : len(rest)] = rest
+        self.plan.note_dispatch("prefill", (1, width), len(rest))
+        token, self.cache = self._prefill(
+            self.params, jnp.asarray(padded), self.cache, s.slot,
+            jnp.int32(len(rest)), self._next_key(), sp,
+        )
         if self._overlap_ok():
             # Single-row admissions defer the token fetch exactly like the
             # batched path — the chunked prefill above was dispatch-only.
@@ -2547,7 +2631,14 @@ class InferenceEngine:
                               [skip])
             return
         self.metrics.counter("admit_sync_sessions")
-        self._finish_prefill(s, int(token), prompt, produced, skip)
+        self._finish_sync_prefill(s, token, prompt, produced, skip)
+
+    def _finish_sync_prefill(self, s, token, prompt, produced, skip):
+        """A synchronous admission's tail: wait for the sampled first token
+        (the tick's ``blocked`` phase) and deliver it."""
+        tok = int(np.asarray(self._fetch(token)))
+        with self._region("deliver"):
+            self._finish_prefill(s, tok, prompt, produced, skip)
 
     def _chunk_admit(self, s: Session, skip: int) -> bool:
         """Park an admitted long GREEDY prompt for chunk/decode
@@ -2598,6 +2689,7 @@ class InferenceEngine:
             self.cache = self.cache.copy_page(s.pages[skip // ps], s.cow_src)
             self.allocator.free([s.cow_src])
             s.cow_src = None
+        self._note_admitted(s)  # parked: it left the queue for a slot
         s.chunking = True
         s.chunk_off = skip
         s.chunk_skip = skip
@@ -2641,11 +2733,10 @@ class InferenceEngine:
             if rest > stride:
                 chunk = prompt[s.chunk_off : s.chunk_off + stride]
                 self.plan.note_dispatch("chunk", (1, stride), len(chunk))
-                with self.metrics.timer("prefill"):
-                    self.cache = self._prefill_ns(
-                        self.params, jnp.asarray(chunk)[None, :],
-                        self.cache, s.slot, jnp.int32(len(chunk)),
-                    )
+                self.cache = self._prefill_ns(
+                    self.params, jnp.asarray(chunk)[None, :],
+                    self.cache, s.slot, jnp.int32(len(chunk)),
+                )
                 s.chunk_off += stride
                 self.plan.note_chunk_rows()
                 continue
@@ -2656,11 +2747,10 @@ class InferenceEngine:
                 1, s.options.temperature, s.options.top_k, s.options.top_p
             )
             self.plan.note_dispatch("prefill", (1, width), rest)
-            with self.metrics.timer("prefill"):
-                token, self.cache = self._prefill(
-                    self.params, jnp.asarray(padded), self.cache, s.slot,
-                    jnp.int32(rest), s.parked_key, sp,
-                )
+            token, self.cache = self._prefill(
+                self.params, jnp.asarray(padded), self.cache, s.slot,
+                jnp.int32(rest), s.parked_key, sp,
+            )
             self.plan.note_chunk_rows()
             s.chunking = False
             s.parked_key = None
@@ -2673,8 +2763,9 @@ class InferenceEngine:
                 continue
             self.metrics.counter("admit_sync_sessions")
             # distcheck: host-sync-ok(final-chunk first-token fetch — the same one-per-admission sync the legacy _run_prefill path pays)
-            tok = int(np.asarray(jax.device_get(token)))
-            self._finish_prefill(s, tok, prompt, produced, s.chunk_skip)
+            self._finish_sync_prefill(
+                s, token, prompt, produced, s.chunk_skip
+            )
 
     def _finish_prefill(self, s, token, prompt, produced, skip):
         self._deliver(s, int(token), produced)
@@ -2971,15 +3062,12 @@ class InferenceEngine:
             self.batch, K,
             self.cache.page_table.shape[1] if paged
             else int(getattr(self.cache, "max_len", 0)),
-        ))
-        with self.metrics.timer("decode_step"), span(
-            "decode_step", self.spans, batch=int(active.sum()),
-        ):
-            emitted, self.cache = self._decode_k(
-                self.params, tokens_dev, self.cache, act_dev,
-                self._next_key(), sp, jnp.asarray(eos_ids),
-                jnp.asarray(budget),
-            )
+        ), self._live_positions(active, pend_b))
+        emitted, self.cache = self._decode_k(
+            self.params, tokens_dev, self.cache, act_dev,
+            self._next_key(), sp, jnp.asarray(eos_ids),
+            jnp.asarray(budget),
+        )
         old = (
             self._carry if self._carry is not None
             else jnp.zeros((self.batch, 1), jnp.int32)
@@ -3006,8 +3094,7 @@ class InferenceEngine:
         fetch = [toks for _, toks, _ in admits]
         if prev is not None:
             fetch.append(prev[0])
-        with self.metrics.timer("decode_resolve"):
-            got = jax.device_get(fetch)
+        got = self._fetch(fetch)
         if admits:
             self._admit_pend[:] = 0
             self.metrics.gauge("admit_overlap_inflight", 0.0)
@@ -3149,39 +3236,37 @@ class InferenceEngine:
             self.cache.page_table.shape[1]
             if isinstance(self.cache, PagedKVCache)
             else int(getattr(self.cache, "max_len", 0)),
-        ))
-        with self.metrics.timer("decode_step"), span(
-            "decode_step", self.spans, batch=int(active.sum()),
-        ):
-            if K == 1:
-                next_tokens, self.cache = self._decode(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(active), self._next_key(), sp,
-                )
-                # distcheck: host-sync-ok(the one per-tick fetch for K=1)
-                emitted = np.asarray(jax.device_get(next_tokens))[None, :]
-            else:
-                eos_ids = np.asarray(
-                    [o.eos_token_id for o in opts], np.int32
-                )
-                emitted, self.cache = self._decode_k(
-                    self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(active), self._next_key(), sp,
-                    jnp.asarray(eos_ids), jnp.asarray(budget),
-                )
-                # distcheck: host-sync-ok(the one per-tick fetch for K>1)
-                emitted = np.asarray(jax.device_get(emitted))
+        ), self._live_positions(active))
+        if K == 1:
+            next_tokens, self.cache = self._decode(
+                self.params, jnp.asarray(tokens), self.cache,
+                jnp.asarray(active), self._next_key(), sp,
+            )
+            # distcheck: host-sync-ok(the one per-tick fetch for K=1)
+            emitted = np.asarray(self._fetch(next_tokens))[None, :]
+        else:
+            eos_ids = np.asarray(
+                [o.eos_token_id for o in opts], np.int32
+            )
+            emitted, self.cache = self._decode_k(
+                self.params, jnp.asarray(tokens), self.cache,
+                jnp.asarray(active), self._next_key(), sp,
+                jnp.asarray(eos_ids), jnp.asarray(budget),
+            )
+            # distcheck: host-sync-ok(the one per-tick fetch for K>1)
+            emitted = np.asarray(self._fetch(emitted))
 
         delivered = 0
-        for slot, gid in enumerate(list(self.slots)):
-            if gid is None or not active[slot]:
-                continue
-            s = self.sessions[gid]
-            for i in range(int(budget[slot])):
-                if s.state != SessionState.ACTIVE:
-                    break
-                self._deliver(s, int(emitted[i, slot]), produced)
-                delivered += 1
+        with self._region("deliver"):
+            for slot, gid in enumerate(list(self.slots)):
+                if gid is None or not active[slot]:
+                    continue
+                s = self.sessions[gid]
+                for i in range(int(budget[slot])):
+                    if s.state != SessionState.ACTIVE:
+                        break
+                    self._deliver(s, int(emitted[i, slot]), produced)
+                    delivered += 1
         self.metrics.counter("decode_tokens", delivered)
 
     def _grow_pages(self, s: Session, want: int) -> int:
@@ -3332,18 +3417,15 @@ class InferenceEngine:
                 cmask_dev, jnp.asarray(use_carry)
             )
         self._flush_installs()
-        with self.metrics.timer("decode_step"), span(
-            "speculative_rounds", self.spans, batch=int(active.sum()),
-        ):
-            pack_d, tok_d, ctok_d, cmask_d, self.cache, self.draft_cache = (
-                self._spec_rounds_fn(
-                    self.params, self.draft[1], tokens_dev,
-                    self.cache, self.draft_cache, jnp.asarray(spec),
-                    jnp.asarray(active), jnp.asarray(eos_ids),
-                    jnp.asarray(budget), self._next_key(), sp,
-                    ctok_dev, cmask_dev,
-                )
+        pack_d, tok_d, ctok_d, cmask_d, self.cache, self.draft_cache = (
+            self._spec_rounds_fn(
+                self.params, self.draft[1], tokens_dev,
+                self.cache, self.draft_cache, jnp.asarray(spec),
+                jnp.asarray(active), jnp.asarray(eos_ids),
+                jnp.asarray(budget), self._next_key(), sp,
+                ctok_dev, cmask_dev,
             )
+        )
         self._spec_carry = tok_d
         self._spec_catch = (ctok_d, cmask_d)
         self._spec_carry_ok = self._spec_carry_ok | active
@@ -3359,42 +3441,42 @@ class InferenceEngine:
             return
         pack_d, active, spec, _pend, gids = prev
         k = self.ecfg.speculative_k
-        with self.metrics.timer("decode_resolve"):
-            # distcheck: host-sync-ok(deferred-fetch: overlaps next dispatch)
-            pack = np.asarray(jax.device_get(pack_d))  # [R, B, k+3]
-        emits = pack[:, :, : k + 1]
-        accs = pack[:, :, k + 1]
-        palive = pack[:, :, k + 2]
-        delivered_total = 0
-        for slot, gid in enumerate(gids):
-            if gid is None or not active[slot]:
-                continue
-            s = self.sessions.get(gid)
-            if s is None or self.slots[slot] != gid:
-                continue  # cancelled/reaped since dispatch
-            emitted_in_graph = int((emits[:, slot] != -1).sum())
-            delivered = 0
-            for r in range(emits.shape[0]):
-                for j in range(k + 1):
-                    if s.state != SessionState.ACTIVE:
-                        break
-                    tok = int(emits[r, slot, j])
-                    if tok == -1:
-                        break
-                    self._deliver(s, tok, produced)
-                    delivered += 1
-            delivered_total += delivered
-            if delivered < emitted_in_graph:
-                # Host-side stop mid-pack: the device carry token sits
-                # beyond the session's true last token.
-                self._spec_carry_ok[slot] = False
-            if spec[slot]:
-                rounds_run = int(palive[:, slot].sum())
-                self.spec_stats["proposed"] += k * rounds_run
-                self.spec_stats["accepted"] += int(
-                    (accs[:, slot] * palive[:, slot]).sum()
-                )
-                self.spec_stats["steps"] += rounds_run
+        # distcheck: host-sync-ok(deferred-fetch: overlaps next dispatch)
+        pack = np.asarray(self._fetch(pack_d))  # [R, B, k+3]
+        with self._region("deliver"):
+            emits = pack[:, :, : k + 1]
+            accs = pack[:, :, k + 1]
+            palive = pack[:, :, k + 2]
+            delivered_total = 0
+            for slot, gid in enumerate(gids):
+                if gid is None or not active[slot]:
+                    continue
+                s = self.sessions.get(gid)
+                if s is None or self.slots[slot] != gid:
+                    continue  # cancelled/reaped since dispatch
+                emitted_in_graph = int((emits[:, slot] != -1).sum())
+                delivered = 0
+                for r in range(emits.shape[0]):
+                    for j in range(k + 1):
+                        if s.state != SessionState.ACTIVE:
+                            break
+                        tok = int(emits[r, slot, j])
+                        if tok == -1:
+                            break
+                        self._deliver(s, tok, produced)
+                        delivered += 1
+                delivered_total += delivered
+                if delivered < emitted_in_graph:
+                    # Host-side stop mid-pack: the device carry token sits
+                    # beyond the session's true last token.
+                    self._spec_carry_ok[slot] = False
+                if spec[slot]:
+                    rounds_run = int(palive[:, slot].sum())
+                    self.spec_stats["proposed"] += k * rounds_run
+                    self.spec_stats["accepted"] += int(
+                        (accs[:, slot] * palive[:, slot]).sum()
+                    )
+                    self.spec_stats["steps"] += rounds_run
         self.metrics.counter("decode_tokens", delivered_total)
 
     def _speculative_tick(self, produced) -> None:
@@ -3471,67 +3553,63 @@ class InferenceEngine:
         )
         sp = SamplingParams.stack(opts)
         self._flush_installs()
-        with self.metrics.timer("decode_step"), span(
-            "speculative_step", self.spans, batch=int(active.sum()),
-        ):
-            preds_d, sampled_d, self.cache = self._verify(
-                self.params, jnp.asarray(tokens), prop_d, jnp.asarray(spec),
-                self.cache, jnp.asarray(num_new), self._next_key(), sp,
-            )
+        preds_d, sampled_d, self.cache = self._verify(
+            self.params, jnp.asarray(tokens), prop_d, jnp.asarray(spec),
+            self.cache, jnp.asarray(num_new), self._next_key(), sp,
+        )
         # Fetch the proposals AFTER dispatching verify: the copy overlaps
         # the target's k+1-position forward instead of serializing before it.
         # distcheck: host-sync-ok(post-verify fetch overlaps the forward)
-        prop = np.asarray(jax.device_get(prop_d)).T  # [B, k]
-        # distcheck: host-sync-ok(post-verify fetch overlaps the forward)
-        preds = np.asarray(jax.device_get(preds_d))
-        # distcheck: host-sync-ok(post-verify fetch overlaps the forward)
-        sampled = np.asarray(jax.device_get(sampled_d))
+        prop, preds, sampled = self._fetch((prop_d, preds_d, sampled_d))
+        prop = np.asarray(prop).T  # [B, k]
+        preds, sampled = np.asarray(preds), np.asarray(sampled)
 
         rollback = np.zeros((b,), np.int32)
         d_rollback = np.zeros((b,), np.int32)
         catch_mask = np.zeros((b,), np.int32)
         catch_tok = np.zeros((b, 1), np.int32)
         delivered = 0
-        for slot, gid in enumerate(list(self.slots)):
-            if gid is None or not active[slot]:
-                continue
-            s = self.sessions[gid]
-            if spec[slot]:
-                a = 0
-                while a < k and prop[slot, a] == preds[slot, a]:
-                    a += 1
-                emitted = [int(t) for t in prop[slot, :a]]
-                emitted.append(int(preds[slot, a]) if a < k
-                               else int(preds[slot, k]))
-                rollback[slot] = k - a
-                if a == k:
-                    # Full acceptance: the draft never consumed its own
-                    # final proposal — catch it up below.
-                    catch_mask[slot] = 1
-                    catch_tok[slot, 0] = prop[slot, -1]
+        with self._region("deliver"):
+            for slot, gid in enumerate(list(self.slots)):
+                if gid is None or not active[slot]:
+                    continue
+                s = self.sessions[gid]
+                if spec[slot]:
+                    a = 0
+                    while a < k and prop[slot, a] == preds[slot, a]:
+                        a += 1
+                    emitted = [int(t) for t in prop[slot, :a]]
+                    emitted.append(int(preds[slot, a]) if a < k
+                                   else int(preds[slot, k]))
+                    rollback[slot] = k - a
+                    if a == k:
+                        # Full acceptance: the draft never consumed its own
+                        # final proposal — catch it up below.
+                        catch_mask[slot] = 1
+                        catch_tok[slot, 0] = prop[slot, -1]
+                    else:
+                        d_rollback[slot] = k - a - 1
+                    self.spec_stats["proposed"] += k
+                    self.spec_stats["accepted"] += a
+                    self.spec_stats["steps"] += 1
                 else:
-                    d_rollback[slot] = k - a - 1
-                self.spec_stats["proposed"] += k
-                self.spec_stats["accepted"] += a
-                self.spec_stats["steps"] += 1
-            else:
-                emitted = [int(sampled[slot])]
-            for t in emitted:
-                if s.state != SessionState.ACTIVE:
-                    break
-                self._deliver(s, t, produced)
-                delivered += 1
-            if (
-                not spec[slot]
-                and self._session_speculative(s)
-                and s.state == SessionState.ACTIVE
-            ):
-                # A speculative session that decoded plainly this tick
-                # (capacity pressure): its draft cache did not see the
-                # consumed token — catch it up, or every later proposal is
-                # positionally garbage (speculation cost with ~0 acceptance).
-                catch_mask[slot] = 1
-                catch_tok[slot, 0] = tokens[slot, 0]
+                    emitted = [int(sampled[slot])]
+                for t in emitted:
+                    if s.state != SessionState.ACTIVE:
+                        break
+                    self._deliver(s, t, produced)
+                    delivered += 1
+                if (
+                    not spec[slot]
+                    and self._session_speculative(s)
+                    and s.state == SessionState.ACTIVE
+                ):
+                    # A speculative session that decoded plainly this tick
+                    # (capacity pressure): its draft cache did not see the
+                    # consumed token — catch it up, or every later proposal is
+                    # positionally garbage (speculation cost with ~0 acceptance).
+                    catch_mask[slot] = 1
+                    catch_tok[slot, 0] = tokens[slot, 0]
         self.metrics.counter("decode_tokens", delivered)
 
         # Roll lengths back to the true sequence (rejected positions become
@@ -3553,7 +3631,19 @@ class InferenceEngine:
     def _deliver(self, s: Session, token: int, produced) -> None:
         if s.cancel_requested or s.state == SessionState.CANCELLED:
             return  # cancelled mid-step; the scheduler reaps the slot next tick
+        first = s.first_token_time is None
         s.record_token(token)
+        if first and s.admit_time is not None:
+            # the host holds the session's first token: the other end of
+            # its admission dispatch (``_note_admitted``)
+            self.metrics.observe(
+                "engine_first_token_wait", s.first_token_time - s.admit_time
+            )
+            if s.trace is not None and self.tracer is not None:
+                self._request_span(
+                    "engine.first_token", s, s.admit_time,
+                    s.first_token_time,
+                )
         done_eos = token == s.options.eos_token_id
         done_len = len(s.generated) >= s.options.max_new_tokens
         if done_eos or done_len:

@@ -35,8 +35,10 @@ The plan centralizes that policy:
   :meth:`note_dispatch`, which maintains the seen-shape set behind the
   ``attn_recompiles`` counter (a first-seen (kind, shape) is exactly one
   fresh XLA executable), counts ``attn_ragged_dispatches`` /
-  ``attn_chunked_rows``, and publishes ``attn_grid_occupancy`` (valid /
-  padded token fraction of the latest prefill-family dispatch).
+  ``attn_chunked_rows``, and keeps the census of what the shapes cost:
+  cumulative ``prefill_valid_tokens`` / ``prefill_padded_tokens`` over
+  every prefill-family dispatch and ``decode_live_positions`` /
+  ``decode_grid_positions`` over every decode dispatch.
 
 This is also the fusion point ROADMAP item 4 (batched spec verification)
 needs: a verify row is just one more ``num_new == k`` row class.
@@ -114,6 +116,10 @@ class AttentionPlan:
         # read by the engine's flight recorder so each tick record carries
         # the dispatch shape without a second telemetry funnel.
         self.last_dispatch: Optional[Tuple] = None
+        # Every dispatch since the engine last took the list (one tick's
+        # worth). None unless the engine's flight recorder is on: nobody
+        # would empty it.
+        self.dispatches: Optional[list] = None
         # Set by the engine when the cache stores the latent (MLA) fused
         # form: every dispatch then reads latents and decompresses in
         # place via the page walk, which note_dispatch surfaces as the
@@ -231,12 +237,21 @@ class AttentionPlan:
                       valid_tokens: Optional[int] = None) -> None:
         """Record one attention dispatch: first-seen (kind, shape) is one
         fresh executable (``attn_recompiles``); prefill-family dispatches
-        under ragged mode count ``attn_ragged_dispatches`` and publish the
-        valid/padded occupancy gauge."""
-        key = (kind,) + tuple(int(x) for x in shape)
-        self.last_dispatch = (
-            kind, tuple(int(x) for x in shape), valid_tokens
-        )
+        under ragged mode count ``attn_ragged_dispatches``.
+
+        The census. A prefill-family ``shape`` is (rows, pad width) and
+        ``valid_tokens`` its prompt tokens: they add to
+        ``prefill_valid_tokens`` / ``prefill_padded_tokens``. A decode
+        ``shape`` is (rows, steps, table width in pages — or ``max_len``
+        for a dense cache, with ``page_size`` 1 then) and ``valid_tokens``
+        the host-known context lengths of the active rows, summed: they add
+        to ``decode_live_positions`` / ``decode_grid_positions`` (rows x
+        table width x page size: what one step of the dispatch walks)."""
+        shape = tuple(int(x) for x in shape)
+        self.last_dispatch = (kind, shape, valid_tokens)
+        if self.dispatches is not None:
+            self.dispatches.append(self.last_dispatch)
+        key = (kind,) + shape
         if key not in self._shapes:
             self._shapes.add(key)
             if self.metrics is not None:
@@ -247,14 +262,18 @@ class AttentionPlan:
             self.metrics.counter("latent_decompress_dispatches")
         if self.enabled and kind != DECODE:
             self.metrics.counter("attn_ragged_dispatches")
-        if valid_tokens is not None:
-            padded = 1
-            for x in shape:
-                padded *= int(x)
-            if padded > 0:
-                self.metrics.gauge(
-                    "attn_grid_occupancy", valid_tokens / padded
-                )
+        if valid_tokens is None:
+            return
+        if kind == DECODE:
+            paged = self.ccfg.kind == "paged"
+            self.metrics.counter("decode_live_positions", valid_tokens)
+            self.metrics.counter(
+                "decode_grid_positions",
+                shape[0] * shape[2] * (self.ccfg.page_size if paged else 1),
+            )
+        else:
+            self.metrics.counter("prefill_valid_tokens", valid_tokens)
+            self.metrics.counter("prefill_padded_tokens", shape[0] * shape[1])
 
     def note_chunk_rows(self, n: int = 1) -> None:
         if self.metrics is not None:

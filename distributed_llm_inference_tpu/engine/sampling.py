@@ -110,13 +110,14 @@ def sample(
     sort costs milliseconds at [112, 32k] and is the dominant stochastic-tick
     cost); mixed batches compile the full program once.
     """
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if params.all_greedy:
-        return greedy
+    with jax.named_scope("sampler"):  # the device trace's name for it
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if params.all_greedy:
+            return greedy
 
-    temp = jnp.maximum(params.temperature, 1e-6)[:, None]
-    scaled = logits.astype(jnp.float32) / temp
-    scaled = _filter_top_k_top_p(scaled, params.top_k, params.top_p)
-    drawn = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+        temp = jnp.maximum(params.temperature, 1e-6)[:, None]
+        scaled = logits.astype(jnp.float32) / temp
+        scaled = _filter_top_k_top_p(scaled, params.top_k, params.top_p)
+        drawn = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
 
-    return jnp.where(params.temperature > 0.0, drawn, greedy)
+        return jnp.where(params.temperature > 0.0, drawn, greedy)

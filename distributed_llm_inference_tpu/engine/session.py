@@ -99,6 +99,11 @@ class Session:
     trace: Optional[Any] = None
     # timing (metrics: TTFT, tokens/sec — SURVEY §5.5)
     submit_time: float = dataclasses.field(default_factory=time.monotonic)
+    # time.monotonic() of the admission dispatch that took the session
+    # (engine._note_admitted): submit → here is its queue wait, here →
+    # first_token_time its first-token wait. None for sessions that enter
+    # with their KV already made (disaggregated admits, resumes).
+    admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
 

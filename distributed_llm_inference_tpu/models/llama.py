@@ -147,36 +147,39 @@ def _decoder_layer(
     b, s, _ = x.shape
     hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
-    h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-    if cfg.use_latent:
-        attn_flat, new_state = _latent_attention(
-            cfg, p, h, layer_state, cache, rope, q_pos, num_new, attention_fn
-        )
+    # The scopes (``attention`` here, ``mlp`` / ``moe_*`` below, ``head``,
+    # ``sampler``) are the stable part of every operation's name in a device
+    # trace: fusion numbers move with each recompile, these do not.
+    with jax.named_scope("attention"):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        if cfg.use_latent:
+            attn_flat, new_state = _latent_attention(
+                cfg, p, h, layer_state, cache, rope, q_pos, num_new,
+                attention_fn,
+            )
+        else:
+            q = qmatmul(h, p["wq"])
+            k = qmatmul(h, p["wk"])
+            v = qmatmul(h, p["wv"])
+            # Biases applied iff the checkpoint carries them (HF
+            # `attention_bias`).
+            if "bq" in p:
+                q = q + p["bq"]
+                k = k + p["bk"]
+                v = v + p["bv"]
+            q = q.reshape(b, s, hq, d)
+            k = k.reshape(b, s, hkv, d)
+            v = v.reshape(b, s, hkv, d)
+
+            attn, new_state = cache.attend(
+                layer_state, q, k, v, rope, q_pos, num_new,
+                cfg.sliding_window, attention_fn, d**-0.5,
+            )
+            attn_flat = attn.reshape(b, s, hq * d)
         o = qmatmul(attn_flat, p["wo"])
         if "bo" in p:
             o = o + p["bo"]
         x = x + o
-        return _mlp_residual(cfg, p, x, s, num_new), new_state
-    q = qmatmul(h, p["wq"])
-    k = qmatmul(h, p["wk"])
-    v = qmatmul(h, p["wv"])
-    # Biases applied iff the checkpoint carries them (HF `attention_bias`).
-    if "bq" in p:
-        q = q + p["bq"]
-        k = k + p["bk"]
-        v = v + p["bv"]
-    q = q.reshape(b, s, hq, d)
-    k = k.reshape(b, s, hkv, d)
-    v = v.reshape(b, s, hkv, d)
-
-    attn, new_state = cache.attend(
-        layer_state, q, k, v, rope, q_pos, num_new,
-        cfg.sliding_window, attention_fn, d**-0.5,
-    )
-    o = qmatmul(attn.reshape(b, s, hq * d), p["wo"])
-    if "bo" in p:
-        o = o + p["bo"]
-    x = x + o
     return _mlp_residual(cfg, p, x, s, num_new), new_state
 
 
@@ -184,20 +187,24 @@ def _mlp_residual(cfg, p, x, s, num_new):
     """Pre-norm MLP + residual (shared by the dense and latent attention
     branches of :func:`_decoder_layer`)."""
     b = x.shape[0]
-    h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-    if cfg.num_experts > 0:
-        # Bucket-padding positions (>= num_new) must not consume expert
-        # capacity in the dispatched prefill path.
-        valid = None
-        if s > 1:
-            valid = (
-                jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
-                < num_new[:, None]
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        if cfg.num_experts > 0:
+            # Bucket-padding positions (>= num_new) must not consume expert
+            # capacity in the dispatched prefill path.
+            valid = None
+            if s > 1:
+                valid = (
+                    jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
+                    < num_new[:, None]
+                )
+            mlp = moe_mlp(cfg, p, h2, valid=valid)
+        else:
+            mlp = qmatmul(
+                jax.nn.silu(qmatmul(h2, p["wg"])) * qmatmul(h2, p["wu"]),
+                p["wd"],
             )
-        mlp = moe_mlp(cfg, p, h2, valid=valid)
-    else:
-        mlp = qmatmul(jax.nn.silu(qmatmul(h2, p["wg"])) * qmatmul(h2, p["wu"]), p["wd"])
-    return x + mlp
+        return x + mlp
 
 
 def _latent_attention(
@@ -563,11 +570,12 @@ def multi_decode_apply(
 def apply_head(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
     """Final norm + lm_head (tied to the embedding when absent): ``[..., H]``
     hidden states → fp32 logits ``[..., V]``."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    return qmatmul(x, head).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed"].T
+        return qmatmul(x, head).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -137,6 +137,7 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         out_shape=jax.ShapeDtypeStruct((b, hkv, s, g, d), q.dtype),
         grid=grid,
         in_specs=[

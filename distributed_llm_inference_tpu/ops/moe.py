@@ -42,12 +42,13 @@ def router_weights(
     ``x``: ``[B, S, H]``; ``router``: ``[H, E]``. Returns the dense combine
     matrix ``[B, S, E]`` (sums to 1 over the selected experts, 0 elsewhere).
     """
-    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    one_hot = jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
-    return jnp.einsum("bsk,bske->bse", top_p, one_hot)
+    with jax.named_scope("moe_router"):
+        logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        one_hot = jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
+        return jnp.einsum("bsk,bske->bse", top_p, one_hot)
 
 
 def moe_mlp(
@@ -74,10 +75,12 @@ def moe_mlp(
     if cfg.moe_capacity_factor is not None and x.shape[1] >= 16:
         return moe_mlp_dispatch(cfg, p, x, cfg.moe_capacity_factor, valid)
     combine = router_weights(cfg, x, p["router"]).astype(x.dtype)
-    t = quant.einsum("bsh,ehf->bsef", x, p["we_g"])
-    u = quant.einsum("bsh,ehf->bsef", x, p["we_u"])
-    y = quant.einsum("bsef,efh->bseh", jax.nn.silu(t) * u, p["we_d"])
-    return jnp.einsum("bse,bseh->bsh", combine, y)
+    with jax.named_scope("moe_experts"):
+        t = quant.einsum("bsh,ehf->bsef", x, p["we_g"])
+        u = quant.einsum("bsh,ehf->bsef", x, p["we_u"])
+        y = quant.einsum("bsef,efh->bseh", jax.nn.silu(t) * u, p["we_d"])
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum("bse,bseh->bsh", combine, y)
 
 
 def _expert_matmul(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
@@ -126,10 +129,11 @@ def moe_mlp_dispatch(
     n = b * s
     xf = x.reshape(n, h)
 
-    logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("moe_router"):
+        logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_i = jax.lax.top_k(probs, k)
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     pair_e = top_i.reshape(-1)                                  # [N*k]
     pair_t = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)      # [N*k]
@@ -157,20 +161,22 @@ def moe_mlp_dispatch(
     slot_tok = sorted_t[jnp.clip(slot_pos, 0, n * k - 1)]       # [E, C]
 
     gathered = xf[slot_tok] * slot_valid[..., None].astype(x.dtype)
-    t = _expert_matmul("ech,ehf->ecf", gathered, p["we_g"])
-    u = _expert_matmul("ech,ehf->ecf", gathered, p["we_u"])
-    y = _expert_matmul("ecf,efh->ech", jax.nn.silu(t) * u, p["we_d"])
+    with jax.named_scope("moe_experts"):
+        t = _expert_matmul("ech,ehf->ecf", gathered, p["we_g"])
+        u = _expert_matmul("ech,ehf->ecf", gathered, p["we_u"])
+        y = _expert_matmul("ecf,efh->ech", jax.nn.silu(t) * u, p["we_d"])
 
     # Back to pair order (pure gathers: undo the sort), then a dense [N, k]
     # weighted combine.
-    kept = pos_in_group < c
-    pair_out_sorted = y[
-        sorted_e, jnp.clip(pos_in_group, 0, c - 1)
-    ] * kept[:, None].astype(x.dtype)                           # [N*k, H]
-    inv = jnp.argsort(order)
-    pair_out = pair_out_sorted[inv].reshape(n, k, h)
-    out = jnp.einsum(
-        "nk,nkh->nh", top_p.astype(jnp.float32),
-        pair_out.astype(jnp.float32),
-    )
-    return out.reshape(b, s, h).astype(x.dtype)
+    with jax.named_scope("moe_combine"):
+        kept = pos_in_group < c
+        pair_out_sorted = y[
+            sorted_e, jnp.clip(pos_in_group, 0, c - 1)
+        ] * kept[:, None].astype(x.dtype)                       # [N*k, H]
+        inv = jnp.argsort(order)
+        pair_out = pair_out_sorted[inv].reshape(n, k, h)
+        out = jnp.einsum(
+            "nk,nkh->nh", top_p.astype(jnp.float32),
+            pair_out.astype(jnp.float32),
+        )
+        return out.reshape(b, s, h).astype(x.dtype)

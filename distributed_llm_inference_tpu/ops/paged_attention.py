@@ -236,6 +236,7 @@ def paged_attention(
     )
     out, m, l = pl.pallas_call(
         kernel,
+        name="paged_attention",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv * g, 128), jnp.float32),
@@ -427,6 +428,7 @@ def quantized_paged_attention(
     )
     out, m, l = pl.pallas_call(
         kernel,
+        name="quantized_paged_attention",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv * g, 128), jnp.float32),
@@ -601,6 +603,7 @@ def quantized_paged_fused_attention(
     )
     out, tk, tks, tv, tvs = pl.pallas_call(
         kernel,
+        name="quantized_paged_fused_attention",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct(tail_k.shape, tail_k.dtype),
@@ -865,6 +868,7 @@ def paged_tail_flush(
     )
     return pl.pallas_call(
         kernel,
+        name="paged_tail_flush",
         out_shape=(
             jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
             jax.ShapeDtypeStruct(pool_ks.shape, pool_ks.dtype),

@@ -220,6 +220,7 @@ def quantized_decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="quantized_decode_attention",
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -384,6 +385,7 @@ def quantized_fused_decode_attention(
     )
     out, tk, tks, tv, tvs = pl.pallas_call(
         kernel,
+        name="quantized_fused_decode_attention",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct(tail_k.shape, tail_k.dtype),
@@ -689,6 +691,7 @@ def fused_tail_flush(
     )
     return pl.pallas_call(
         kernel,
+        name="fused_tail_flush",
         out_shape=(
             jax.ShapeDtypeStruct(big_k.shape, big_k.dtype),
             jax.ShapeDtypeStruct(big_ks.shape, big_ks.dtype),
@@ -866,6 +869,7 @@ def sink_fused_decode_attention(
     )
     out, tk, tks, tv, tvs = pl.pallas_call(
         kernel,
+        name="sink_fused_decode_attention",
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct(tail_k.shape, tail_k.dtype),
@@ -1173,6 +1177,7 @@ def sink_tail_flush(
     )
     return pl.pallas_call(
         kernel,
+        name="sink_tail_flush",
         out_shape=(
             jax.ShapeDtypeStruct(big_k.shape, big_k.dtype),
             jax.ShapeDtypeStruct(big_ks.shape, big_ks.dtype),

@@ -160,6 +160,7 @@ def int4_matmul(
 
     out_lo, out_hi = pl.pallas_call(
         functools.partial(_int4_kernel, n_in=n_in),
+        name="int4_matmul",
         grid=(n_out, n_in),
         in_specs=[
             pl.BlockSpec((bp, bin_), lambda oi, ii: (0, ii)),
@@ -277,6 +278,7 @@ def int4_matmul_stacked(
     )
     out_lo, out_hi = pl.pallas_call(
         functools.partial(_int4_stacked_kernel, n_in=n_in),
+        name="int4_matmul_stacked",
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((bp, outp), x.dtype),

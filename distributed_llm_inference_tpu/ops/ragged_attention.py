@@ -366,6 +366,7 @@ def ragged_paged_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="ragged_paged_attention",
         out_shape=jax.ShapeDtypeStruct((b, s_pad, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -456,6 +457,7 @@ def quantized_ragged_paged_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="quantized_ragged_paged_attention",
         out_shape=jax.ShapeDtypeStruct((b, s_pad, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,

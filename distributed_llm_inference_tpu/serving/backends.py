@@ -331,6 +331,12 @@ class EngineBackend(Backend):
             self._handles[gid] = h
         return h
 
+    def attach_tracer(self, recorder, cfg) -> None:
+        # The engine records a traced session's ``engine.queue`` and
+        # ``engine.first_token`` spans into the gateway's own recorder.
+        super().attach_tracer(recorder, cfg)
+        self.engine.tracer = recorder
+
     def flight_snapshot(self, last: Optional[int] = None) -> List[dict]:
         fr = getattr(self.engine, "flight", None)
         return fr.snapshot(last) if fr is not None else []

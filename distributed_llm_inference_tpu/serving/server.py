@@ -470,8 +470,13 @@ class ApiServer:
         # short-circuits on it.
         tctx = None
         if self.tracer is not None and self.tcfg is not None:
-            tctx = TraceContext.mint(self.tcfg.trace_sample_rate)
-            if tctx is not None:
+            root = TraceContext.mint(self.tcfg.trace_sample_rate)
+            if root is not None:
+                # The context every segment of the request records under is
+                # the ``gateway.request`` span's own (minted now, recorded
+                # when the request ends): queue wait, route, kv transfer,
+                # decode wait and the engine's spans are its children.
+                tctx = root.child()
                 self.backend.metrics.counter("traces_sampled")
                 if ticket is not None:
                     ticket.trace = tctx
@@ -503,16 +508,15 @@ class ApiServer:
             self._handles.discard(handle)
             self._inflight -= 1
             if tctx is not None and self.tracer is not None:
-                # The whole-request envelope span: every other gateway
-                # segment (queue wait, route, kv transfer, decode wait)
-                # nests inside it on the stitched timeline.
-                c = tctx.child()
+                # The whole-request envelope span: every other segment
+                # (queue wait, route, kv transfer, decode wait, the
+                # engine's queue and first-token spans) is its child.
                 self.tracer.record(Span(
                     "gateway.request", req_t0, time.time() - req_t0,
                     {"id": req_id, "reason": reason,
                      "prompt_tokens": len(req.prompt)},
-                    trace_id=c.trace_id, span_id=c.span_id,
-                    parent_id=c.parent_id, node="gateway",
+                    trace_id=tctx.trace_id, span_id=tctx.span_id,
+                    parent_id=tctx.parent_id, node="gateway",
                 ))
             if self.sched is not None and ticket is not None:
                 # Retire the ticket even when the stream died before its

@@ -10,13 +10,11 @@ logging hooks.
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
 import logging
 import re
 import statistics
 import threading
-import time
 from typing import Dict, List, Optional
 
 _PROM_NAME = re.compile(r"[^a-zA-Z0-9_:]")
@@ -29,7 +27,7 @@ logger = logging.getLogger("distributed_llm_inference_tpu")
 # a dead declaration fails tier-1. ``*`` entries match dynamically
 # suffixed families (f-string names). Kinds: ``counter`` (monotonic,
 # ``_total`` on /metrics), ``gauge`` (last-write-wins), ``summary``
-# (observe()/timer() histories; ``_seconds`` on /metrics unless the name
+# (observe() histories; ``_seconds`` on /metrics unless the name
 # carries its own unit suffix). Names here are pre-exposition — the
 # prometheus() renderer appends the suffixes, so declarations must not.
 METRICS = {
@@ -45,7 +43,6 @@ METRICS = {
     "admit_overlap_inflight": ("gauge", "Prefills in flight behind decode"),
     "admit_to_merge": ("summary", "Overlap admission to KV-merge latency"),
     # engine: prefill / decode hot path
-    "prefill": ("summary", "Prefill dispatch latency"),
     "prefill_tokens": ("counter", "Prompt tokens prefilled"),
     "batched_prefills": ("counter", "Prefills served by batched dispatch"),
     "ring_prefills": ("counter", "Prefills served by the ring pipeline"),
@@ -64,9 +61,12 @@ METRICS = {
     "attn_recompiles": ("counter", "First-seen attention dispatch shapes"),
     "attn_ragged_dispatches": ("counter", "Prefill-family ragged dispatches"),
     "attn_chunked_rows": ("counter", "Chunk rows co-scheduled with decode"),
-    "attn_grid_occupancy": ("gauge", "Valid/padded tokens, last dispatch"),
-    "decode_step": ("summary", "One decode tick (dispatch+resolve)"),
-    "decode_resolve": ("summary", "Deferred decode fetch latency"),
+    # census of what the dispatches walk, cumulative (valid / padded and
+    # live / grid give the occupancy of any interval between two scrapes)
+    "prefill_valid_tokens": ("counter", "Prompt tokens in prefill-family dispatches"),
+    "prefill_padded_tokens": ("counter", "Rows x pad width of the same dispatches"),
+    "decode_live_positions": ("counter", "Context positions of active decode rows"),
+    "decode_grid_positions": ("counter", "Rows x table width x page size walked"),
     "decode_tokens": ("counter", "Tokens emitted by decode"),
     "cache_growths": ("counter", "KV cache reallocations"),
     # latent (MLA) KV compression (cache/latent.py)
@@ -111,6 +111,13 @@ METRICS = {
     "engine_ttft": ("summary", "Engine-side TTFT (sync admission)"),
     "engine_ttft_decode": ("summary", "Engine-side TTFT (overlap admission)"),
     "engine_ttft_prefill": ("summary", "Engine-side TTFT (disagg prefill)"),
+    # observed at the event, so _sum/_count give a mean over any interval
+    "engine_queue_wait": ("summary", "submit() to the admission dispatch"),
+    "engine_first_token_wait": ("summary", "Admission dispatch to first token on the host"),
+    # engine tick host clock (utils/tracing.py FlightRecorder; TraceConfig on)
+    "engine_ticks": ("counter", "step() calls the flight recorder timed"),
+    "engine_tick_seconds": ("counter", "Wall seconds of those ticks, outside included"),
+    "engine_tick_*_seconds": ("counter", "The same by host phase (tracing.PHASES)"),
     # multi-tenant admission scheduler (sched/)
     "sched_admitted": ("counter", "Tickets admitted by the scheduler"),
     "sched_tenant_admit_*": ("counter", "Admitted tickets by tenant"),
@@ -159,8 +166,9 @@ METRICS = {
 
 
 class Metrics:
-    """Thread-safe counters and timers (the serving loop runs host threads
-    around the jitted steps — SURVEY §5.2's concurrency caution)."""
+    """Thread-safe counters, gauges and summaries (the serving loop runs
+    host threads around the jitted steps — SURVEY §5.2's concurrency
+    caution)."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -182,15 +190,6 @@ class Metrics:
     def get_gauge(self, name: str, default: float = 0.0) -> float:
         with self._lock:
             return self._gauges.get(name, default)
-
-    @contextlib.contextmanager
-    def timer(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            with self._lock:
-                self._timings[name].append(time.perf_counter() - t0)
 
     def get_counter(self, name: str) -> float:
         """One counter's current value (snapshot() is unsuitable for

@@ -6,13 +6,11 @@ model.py:61,82``). This module supplies the two tiers the TPU rebuild needs:
 
 * **Device profiling** — :func:`profile_trace` / :func:`start_profile` wrap
   ``jax.profiler`` so a serving window dumps an XLA trace (TensorBoard /
-  Perfetto-viewable) with the engine's step names attached via
-  ``jax.profiler.TraceAnnotation``.
-* **Host spans** — :class:`SpanRecorder` records named wall-clock spans
-  (per-request prefill/decode/queue segments) and exports standard Chrome
-  trace-event JSON (``chrome://tracing`` / Perfetto load it directly), so
-  request-level timelines exist even off-TPU and without the profiler
-  running.
+  Perfetto-viewable). The engine's ticks and their host phases are on that
+  trace's host plane, on the profiler's own clock: one
+  ``StepTraceAnnotation("engine_tick", step_num=<tick id>)`` a ``step()``
+  and one ``TraceAnnotation("engine.<phase>")`` a region (see
+  :class:`FlightRecorder`).
 * **Distributed request tracing** — :class:`TraceContext` carries a
   (trace_id, span_id, parent) triple from the gateway across relay frame
   headers (the flat ``"trace"``/``"span"`` keys, so the distcheck DC500/
@@ -23,19 +21,22 @@ model.py:61,82``). This module supplies the two tiers the TPU rebuild needs:
   one ``pid`` lane per node, all on the shared epoch clock.
 * **Flight recorder** — :class:`FlightRecorder` keeps a bounded ring of
   per-engine-tick records (tick kind, occupancy, admitted/chunked/parked
-  rows, dispatch shape, host ms) for the ``/debug/ticks`` endpoint. It is
-  ``None`` on engines without a :class:`~..config.TraceConfig`, so the
-  decode tick pays exactly one attribute load + branch when disabled.
+  rows, every dispatch's shape, free pages, and the tick's wall time split
+  into the host phases ``admit`` / ``dispatch`` / ``blocked`` / ``deliver``
+  / ``outside``) for the ``/debug/ticks`` endpoint, and adds the same split
+  to the ``engine_tick_*`` counters on ``/metrics``. It is ``None`` on
+  engines without a :class:`~..config.TraceConfig`, so the decode tick pays
+  exactly one attribute load + branch when disabled.
 
-Both tiers are cheap no-ops when idle: ``span`` costs two ``perf_counter``
-calls when no profiler is active, and the recorder is bounded.
+Clocks: every stamp that leaves the process (``Span.start_s``, a tick's
+``t`` and ``t0_ns``) is epoch time; ``perf_counter`` measures durations only
+and is never stored.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
-import json
 import random
 import threading
 import time
@@ -50,7 +51,7 @@ __all__ = [
     "SpanRecorder",
     "TraceContext",
     "FlightRecorder",
-    "span",
+    "PHASES",
     "trace_span",
     "stitch_chrome_trace",
     "profile_trace",
@@ -62,12 +63,10 @@ __all__ = [
 @dataclass
 class Span:
     name: str
-    start_s: float  # perf_counter timestamp (epoch for trace spans)
+    start_s: float  # epoch (time.time()): spans of every process share it
     duration_s: float
     args: Optional[Dict[str, Any]] = None
-    # Distributed-trace attribution (None for plain local spans). Trace
-    # spans use time.time() epoch start_s so spans from different
-    # processes stitch onto one timeline.
+    # Distributed-trace attribution (None for a span outside any trace).
     trace_id: Optional[str] = None
     span_id: Optional[str] = None
     parent_id: Optional[str] = None
@@ -136,7 +135,8 @@ class TraceContext:
 
 
 class SpanRecorder:
-    """Bounded, thread-safe span log with Chrome trace-event export.
+    """Bounded, thread-safe span log (per-trace export:
+    :func:`stitch_chrome_trace`).
 
     The engine's host threads (SURVEY §5.2's concurrency caution) may record
     concurrently; the newest ``capacity`` spans are kept. Eviction is NOT
@@ -179,27 +179,6 @@ class SpanRecorder:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
-
-    def chrome_trace(self) -> Dict:
-        """Chrome trace-event JSON object (load in Perfetto / about:tracing)."""
-        events = []
-        for s in self.spans():
-            ev = {
-                "name": s.name,
-                "ph": "X",  # complete event
-                "ts": s.start_s * 1e6,  # microseconds
-                "dur": s.duration_s * 1e6,
-                "pid": 0,
-                "tid": 0,
-            }
-            if s.args:
-                ev["args"] = s.args
-            events.append(ev)
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def dump_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
 
 
 @contextlib.contextmanager
@@ -269,24 +248,80 @@ def stitch_chrome_trace(
     }
 
 
+#: Where the drive thread is, for every instant of a tick's wall time (the
+#: engine's region helper names them; ``engine/engine.py``): ``admit``
+#: (planning, page allocation, prefill-family dispatches, and what of
+#: ``step()`` lies outside every other region), ``dispatch`` (building the
+#: decode inputs and calling the decode program), ``blocked`` (inside
+#: ``jax.device_get`` on the tick path), ``deliver`` (what follows a fetch)
+#: and ``outside`` (from the end of the previous ``step()`` to the start of
+#: this one: fan-out, ``collect_finished``, idle sleep).
+PHASES = ("admit", "dispatch", "blocked", "deliver", "outside")
+_ADMIT, _OUTSIDE = PHASES.index("admit"), PHASES.index("outside")
+_ANNOTATION = tuple(f"engine.{p}" for p in PHASES)
+
+
+class _Region:
+    """One host phase of the tick in progress: a ``TraceAnnotation`` on the
+    profiler's host plane and exclusive host seconds on the tick record (a
+    region entered inside another suspends the outer one, so the phases of
+    a tick sum to its wall time)."""
+
+    __slots__ = ("_fr", "_phase", "_ann")
+
+    def __init__(self, fr: "FlightRecorder", phase: int):
+        self._fr, self._phase = fr, phase
+
+    def __enter__(self) -> None:
+        fr = self._fr
+        fr._charge()
+        fr._stack.append(self._phase)
+        self._ann = jax.profiler.TraceAnnotation(_ANNOTATION[self._phase])
+        self._ann.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        fr = self._fr
+        fr._charge()
+        fr._stack.pop()
+
+
 class FlightRecorder:
     """Bounded ring of per-engine-tick records — the "what was the engine
-    doing at 14:32:07" tool. The engine appends one dict per ``step()``
-    (tick kind, batch occupancy, admitted/chunked/parked rows, dispatch
-    shape, host ms); ``/debug/ticks`` snapshots the ring. Thread-safe:
-    ``step()`` appends from the drive thread while HTTP handlers read."""
+    doing at 14:32:07" tool — and the tick's host clock.
 
-    def __init__(self, capacity: int = 512):
+    The engine brackets every ``step()`` with :meth:`begin` / :meth:`end`
+    and its host phases with :meth:`region`; ``end`` appends one dict (tick
+    kind, batch occupancy, admitted/chunked/parked rows, every dispatch of
+    the tick, free pages, the five :data:`PHASES` in ms) and adds the same
+    seconds to the ``engine_tick_*`` counters of ``metrics``, so two
+    ``/metrics`` scrapes give the split of any interval. ``/debug/ticks``
+    snapshots the ring. The ring is thread-safe (``step()`` appends from
+    the drive thread while HTTP handlers read); the clock is not, and the
+    engine touches it under its scheduler lock alone.
+
+    ``tick`` is the id of the tick in progress: the ``step_num`` of its
+    ``engine_tick`` step in a profiler trace and the ``tick`` of its
+    record."""
+
+    def __init__(self, capacity: int = 512, metrics=None):
         self.capacity = capacity
+        self.metrics = metrics
+        self.tick = 0
         self._lock = threading.Lock()
         self._ring: collections.deque = collections.deque(maxlen=capacity)
-        self._tick = 0
+        # the tick clock: perf_counter, durations only
+        self._acc = [0.0] * len(PHASES)
+        self._stack = [_ADMIT]      # the phase that what is in no region has
+        self._mark = self._t0 = time.perf_counter()
+        self._t0_ns = 0
+        self._end: Optional[float] = None
 
     def record(self, **fields: Any) -> None:
         with self._lock:
-            fields["tick"] = self._tick
+            fields["tick"] = self.tick
             fields["t"] = time.time()
-            self._tick += 1
+            self.tick += 1
             self._ring.append(fields)
 
     def snapshot(self, last: Optional[int] = None) -> List[Dict[str, Any]]:
@@ -296,30 +331,44 @@ class FlightRecorder:
             items = items[-last:]
         return items
 
+    def _charge(self) -> float:
+        """The time since the last charge goes to the phase on top."""
+        now = time.perf_counter()
+        self._acc[self._stack[-1]] += now - self._mark
+        self._mark = now
+        return now
 
-@contextlib.contextmanager
-def span(
-    name: str,
-    recorder: Optional[SpanRecorder] = None,
-    **args: Any,
-) -> Iterator[None]:
-    """Time a host-side region; annotate any device work launched inside it.
+    def region(self, phase: str) -> _Region:
+        return _Region(self, PHASES.index(phase))
 
-    ``TraceAnnotation`` threads ``name`` into the XLA profiler timeline when a
-    device trace is running (so engine steps show up named in the Perfetto
-    dump); the wall-clock span goes to ``recorder`` if given.
-    """
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
-            yield
-    finally:
-        # Record even when the region raises — the failing/slow step is
-        # exactly the one worth having on the timeline.
-        if recorder is not None:
-            recorder.record(
-                Span(name, t0, time.perf_counter() - t0, args or None)
-            )
+    def begin(self) -> int:
+        """A ``step()`` starts: what passed since the last one ended is this
+        tick's ``outside``. Returns the tick's id."""
+        now = time.perf_counter()
+        self._acc = [0.0] * len(PHASES)
+        if self._end is not None:
+            self._acc[_OUTSIDE] = now - self._end
+        self._t0 = self._mark = now
+        self._t0_ns = time.time_ns()
+        del self._stack[1:]
+        return self.tick
+
+    def end(self, **fields: Any) -> None:
+        """The ``step()`` ends: close the clock, add the tick to the
+        counters and append its record."""
+        self._end = self._charge()
+        acc = self._acc
+        m = self.metrics
+        if m is not None:
+            m.counter("engine_ticks")
+            m.counter("engine_tick_seconds", sum(acc))
+            for name, seconds in zip(PHASES, acc):
+                m.counter(f"engine_tick_{name}_seconds", seconds)
+        fields["t0_ns"] = self._t0_ns
+        fields["host_ms"] = (self._end - self._t0) * 1e3
+        for name, seconds in zip(PHASES, acc):
+            fields[f"{name}_ms"] = seconds * 1e3
+        self.record(**fields)
 
 
 _profile_lock = threading.Lock()

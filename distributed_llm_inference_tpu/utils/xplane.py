@@ -1,16 +1,32 @@
-"""Minimal xplane.pb parser: aggregate TPU device-op durations from a
-``jax.profiler`` trace.
+"""Reduce a ``jax.profiler`` trace (``*.xplane.pb``) to where the device's
+time went and what the host was doing while the device waited.
 
 The reference has no profiling story at all (SURVEY §5.1 — its only
 observability is two ``print`` calls in the weight loader,
 ``/root/reference/distributed_llm_inference/utils/model.py:61,82``); here the
-profiler is a first-class tool: ``tools/xplane_profile.py`` drives this module
-interactively, and ``bench.py`` uses :func:`device_time_ps` to report the
-device-only component of TTFT (a synchronous wall-clock measurement also
-counts the host's dispatch and fetch; how much that is on a directly
-attached chip has not been measured).
+profiler is a first-class tool: ``tools/xplane_profile.py`` and
+``tools/profile_decode.py`` print :func:`aggregate`'s result, and ``bench.py``
+uses :func:`device_time_ps` for the device-only component of TTFT.
 
-Durations in the xplane protobuf are picoseconds.
+The trace is read with ``jax.profiler.ProfileData`` into plain data, so the
+arithmetic below is checked on made-up planes as well as on a trace. What it
+knows of a trace:
+
+* a chip is a plane ``/device:TPU:<n>``; its line ``XLA Ops`` has one event
+  for every operation the chip ran (named by its HLO text, kept here as
+  ``<opcode>:<result name>``), ``XLA Modules`` one for every execution of a
+  compiled program. Busy time is the UNION of the operations' intervals,
+  never the sum of their durations;
+* the host is the plane ``/host:CPU``, one line a thread. The engine's drive
+  thread carries one ``engine_tick`` event a ``step()`` (stat ``step_num`` =
+  the flight recorder's tick id) and, nested in it, the ``engine.<phase>``
+  regions (``utils/tracing.py``). Both planes are on the profiler's clock,
+  so an idle gap of device 0 belongs to the phase the drive thread was in:
+  the innermost region that holds the instant, ``admit`` inside a tick but
+  outside every region (as the tick's own clock has it), ``outside`` between
+  two ticks.
+
+Times are nanoseconds; :func:`device_time_ps` alone speaks picoseconds.
 """
 
 from __future__ import annotations
@@ -18,103 +34,191 @@ from __future__ import annotations
 import collections
 import glob
 import os
-from typing import Counter, Tuple
+import re
+from typing import Dict, Iterable, List, Sequence, Tuple
+
+from .tracing import PHASES
+
+DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:(\d+)$")
+HOST_PLANE = "/host:CPU"
+OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+TICK, REGION = "engine_tick", "engine."
+#: operations that only hold others (their events enclose their bodies')
+CONTAINERS = ("while", "conditional", "call")
+_OPCODE = re.compile(r"([a-z][a-z\-]*)\(")
+
+Event = Tuple[str, int, int, dict]  # name, start_ns, duration_ns, stats
+Interval = Tuple[int, int]
 
 
-def read_varint(buf: bytes, i: int):
-    r = 0
-    s = 0
-    while True:
-        b = buf[i]
-        i += 1
-        r |= (b & 0x7F) << s
-        if not b & 0x80:
-            return r, i
-        s += 7
+def short_op_name(text: str) -> str:
+    """``%x.1 = f32[2]{0} custom-call(...)`` → ``custom-call:x.1``; a name
+    that is not HLO text is kept."""
+    lhs, sep, rhs = text.partition(" = ")
+    if not sep:
+        return text
+    found = _OPCODE.search(rhs)
+    return f"{found.group(1) if found else '?'}:{lhs.lstrip('%')}"
 
 
-def fields(buf: bytes):
-    """Iterate (field_number, value) over a serialized protobuf message."""
-    i = 0
-    n = len(buf)
-    while i < n:
-        tag, i = read_varint(buf, i)
-        fnum, wt = tag >> 3, tag & 7
-        if wt == 0:
-            v, i = read_varint(buf, i)
-            yield fnum, v
-        elif wt == 2:
-            ln, i = read_varint(buf, i)
-            yield fnum, buf[i : i + ln]
-            i += ln
-        elif wt == 5:
-            yield fnum, buf[i : i + 4]
-            i += 4
-        elif wt == 1:
-            yield fnum, buf[i : i + 8]
-            i += 8
-        else:
-            raise ValueError(f"wire type {wt}")
+def read_planes(path: str) -> List[dict]:
+    """Device planes (their ``XLA Ops`` / ``XLA Modules`` lines) and the host
+    plane's lines that carry the engine's annotations, as plain data."""
+    import jax
 
-
-def aggregate(path: str, device: str = "/device:TPU:0") -> Tuple[
-    int, Counter, Counter
-]:
-    """Parse one ``*.xplane.pb`` and sum per-op durations on ``device``.
-
-    Returns ``(total_ps, dur_ps_by_op, count_by_op)``. Umbrella lines
-    ("Steps", "XLA Modules") are excluded so the total counts each op once.
-    """
-    space = open(path, "rb").read()
-    for fnum, plane_buf in fields(space):
-        if fnum != 1:
+    planes = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        device = DEVICE_PLANE.match(plane.name)
+        if not device and plane.name != HOST_PLANE:
             continue
-        name = None
-        meta = {}
         lines = []
-        for pf, pv in fields(plane_buf):
-            if pf == 2 and isinstance(pv, bytes):
-                name = pv.decode(errors="replace")
-            elif pf == 4:  # event_metadata map entry
-                mid, mname = None, ""
-                for mf, mv in fields(pv):
-                    if mf == 1:
-                        mid = mv
-                    elif mf == 2:
-                        for ef, ev in fields(mv):
-                            if ef == 2 and isinstance(ev, bytes):
-                                mname = ev.decode(errors="replace")
-                meta[mid] = mname
-            elif pf == 3:
-                lines.append(pv)
-        if name != device:
+        for line in plane.lines:
+            if device and line.name not in (OPS_LINE, MODULES_LINE):
+                continue
+            events = [
+                (e.name, int(e.start_ns), int(e.duration_ns), dict(e.stats))
+                for e in line.events
+                if device or e.name == TICK or e.name.startswith(REGION)
+            ]
+            if events:
+                lines.append({"name": line.name, "events": events})
+        planes.append({"name": plane.name, "lines": lines})
+    return planes
+
+
+def merged(intervals: Iterable[Interval]) -> List[Interval]:
+    """``(start, end)`` intervals, sorted, overlaps joined."""
+    out: List[List[int]] = []
+    for lo, hi in sorted(intervals):
+        if out and lo <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], hi)
+        else:
+            out.append([lo, hi])
+    return [(a, b) for a, b in out]
+
+
+def host_segments(planes: Sequence[dict]) -> List[Tuple[int, int, str]]:
+    """The drive thread's time as ``(start, end, phase)``, no two
+    overlapping: the innermost ``engine.<phase>`` region at every instant of
+    an ``engine_tick``, ``admit`` where a tick is in no region. What lies
+    between two ticks is in no segment (it is ``outside``)."""
+    events = [
+        e for p in planes if p["name"] == HOST_PLANE
+        for ln in p["lines"] for e in ln["events"]
+    ]
+    out: List[Tuple[int, int, str]] = []
+    # properly nested on one thread: a sweep with a stack of open events
+    stack: List[Tuple[int, str]] = []     # (end, phase)
+    cursor = 0
+
+    def close_until(t: int) -> None:
+        nonlocal cursor
+        while stack and stack[-1][0] <= t:
+            end, phase = stack.pop()
+            if end > cursor:
+                out.append((cursor, end, phase))
+                cursor = end
+
+    for name, start, dur, _ in sorted(events, key=lambda e: (e[1], -e[2])):
+        close_until(start)
+        if stack and start > cursor:
+            out.append((cursor, start, stack[-1][1]))
+        cursor = max(cursor, start)
+        phase = "admit" if name == TICK else name[len(REGION):]
+        stack.append((start + dur, phase))
+    close_until(max((s + d for _, s, d, _ in events), default=0))
+    return out
+
+
+def split_by_phase(
+    gaps: Sequence[Interval], segments: Sequence[Tuple[int, int, str]]
+) -> Dict[str, int]:
+    """Nanoseconds of ``gaps`` in each host phase; what no segment covers is
+    ``outside``."""
+    out = {p: 0 for p in PHASES}
+    i = 0
+    for lo, hi in gaps:
+        covered = 0
+        while i < len(segments) and segments[i][1] <= lo:
+            i += 1
+        j = i
+        while j < len(segments) and segments[j][0] < hi:
+            a, b, phase = segments[j]
+            part = min(b, hi) - max(a, lo)
+            if part > 0:
+                out[phase] = out.get(phase, 0) + part
+                covered += part
+            j += 1
+        out["outside"] += (hi - lo) - covered
+    return out
+
+
+def _line(plane: dict, name: str) -> Sequence[Event]:
+    for line in plane["lines"]:
+        if line["name"] == name:
+            return line["events"]
+    return ()
+
+
+def reduce_planes(planes: Sequence[dict]) -> dict:
+    """Per device plane: the traced span, busy time as a union, idle time.
+    Of device 0: every operation's summed duration and count (containers
+    left out: their bodies' operations are listed), the programs run, and
+    its idle time by the host phase that holds it. Of the host: the ticks
+    seen and the drive thread's time by phase."""
+    devices = []
+    ops: collections.Counter = collections.Counter()
+    counts: collections.Counter = collections.Counter()
+    modules: collections.Counter = collections.Counter()
+    gaps0: List[Interval] = []
+    found = sorted(
+        (p for p in planes if DEVICE_PLANE.match(p["name"])),
+        key=lambda p: int(DEVICE_PLANE.match(p["name"]).group(1)),
+    )
+    for plane in found:
+        events = _line(plane, OPS_LINE)
+        if not events:
             continue
-        agg: Counter = collections.Counter()
-        cnt: Counter = collections.Counter()
-        for line_buf in lines:
-            lname = ""
-            evs = []
-            for lf, lv in fields(line_buf):
-                if lf == 2 and isinstance(lv, bytes):
-                    try:
-                        lname = lv.decode()
-                    except Exception:
-                        lname = repr(lv)
-                elif lf == 4:
-                    evs.append(lv)
-            if "Step" in lname or "Modules" in lname:
-                continue  # whole-program umbrella lines
-            for ev in evs:
-                mid, dur = None, 0
-                for ef, v in fields(ev):
-                    if ef == 1:
-                        mid = v
-                    elif ef == 3:
-                        dur = v
-                agg[meta.get(mid, f"id{mid}")] += dur
-                cnt[meta.get(mid, f"id{mid}")] += 1
-        return sum(agg.values()), agg, cnt
-    return 0, collections.Counter(), collections.Counter()
+        busy = merged((s, s + d) for _, s, d, _ in events)
+        first, last = busy[0][0], busy[-1][1]
+        busy_ns = sum(b - a for a, b in busy)
+        if not devices:
+            for name, _, d, _ in events:
+                name = short_op_name(name)
+                if name.partition(":")[0] in CONTAINERS:
+                    continue
+                ops[name] += d
+                counts[name] += 1
+            for name, _, d, _ in _line(plane, MODULES_LINE):
+                modules[re.sub(r"\(\d+\)$", "", name)] += d
+            gaps0 = [(a[1], b[0]) for a, b in zip(busy, busy[1:])]
+        devices.append({
+            "plane": plane["name"], "first_ns": first, "last_ns": last,
+            "busy_ns": busy_ns, "idle_ns": (last - first) - busy_ns,
+        })
+    segments = host_segments(planes)
+    host = {p: 0 for p in PHASES}
+    for a, b, phase in segments:
+        host[phase] = host.get(phase, 0) + (b - a)
+    if segments:  # between the first tick's start and the last one's end
+        host["outside"] = (
+            segments[-1][1] - segments[0][0] - sum(host.values())
+        )
+    ticks = sorted(
+        e[3].get("step_num") for p in planes if p["name"] == HOST_PLANE
+        for ln in p["lines"] for e in ln["events"] if e[0] == TICK
+    )
+    return {
+        "devices": devices, "ops_ns": ops, "op_counts": counts,
+        "modules_ns": modules,
+        "idle_by_phase_ns": split_by_phase(gaps0, segments) if devices else {},
+        "host_by_phase_ns": host, "ticks": ticks,
+    }
+
+
+def aggregate(path: str) -> dict:
+    """:func:`reduce_planes` of one ``*.xplane.pb``."""
+    return reduce_planes(read_planes(path))
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -127,7 +231,38 @@ def find_xplane(trace_dir: str) -> str:
     return max(hits, key=os.path.getmtime)
 
 
-def device_time_ps(trace_dir: str, device: str = "/device:TPU:0") -> int:
-    """Total device-op time (picoseconds) recorded in a trace directory."""
-    total, _, _ = aggregate(find_xplane(trace_dir), device)
-    return total
+def device_time_ps(trace_dir: str) -> int:
+    """Busy time of device 0 (the union of its operations' intervals, in
+    picoseconds) recorded in a trace directory; 0 where the trace has no
+    device plane (a CPU run)."""
+    devices = aggregate(find_xplane(trace_dir))["devices"]
+    return devices[0]["busy_ns"] * 1000 if devices else 0
+
+
+def describe(path: str, per_line: int = 3, like: Sequence[str] = ()) -> List[str]:
+    """What a trace calls things: every plane and line with its event count
+    and a few events (name, duration, stats), and every distinct event whose
+    name or stats hold one of the words in ``like`` — for finding where a
+    kernel's ``name=``, a ``jax.named_scope`` or an annotation landed."""
+    import jax
+
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        out.append(f"plane {plane.name!r}")
+        for line in plane.lines:
+            events = list(line.events)
+            out.append(f"  line {line.name!r}: {len(events)} events")
+            seen = set()
+            for e in events:
+                key = e.name.partition("(")[0][:60]
+                if key in seen:
+                    continue
+                stats = {k: str(v)[:200] for k, v in dict(e.stats).items()}
+                text = e.name + " ".join(stats.values())
+                if len(seen) >= per_line and not any(w in text for w in like):
+                    continue
+                seen.add(key)
+                out.append(
+                    f"    {e.name[:200]!r} {int(e.duration_ns)} ns {stats}"
+                )
+    return out

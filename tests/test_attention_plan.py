@@ -201,7 +201,43 @@ def test_plan_recompile_counter_first_seen_only():
     p.note_dispatch("decode", (4, 16, 64))
     assert m.get_counter("attn_recompiles") == 2.0
     assert m.get_counter("attn_ragged_dispatches") == 2.0
-    assert m.get_gauge("attn_grid_occupancy") == pytest.approx(3 / 8)
+    # the census is cumulative (every dispatch, not the last): 5 + 3 valid
+    # of 8 + 8 padded; a decode dispatch without its live count adds nothing
+    assert m.get_counter("prefill_valid_tokens") == 8.0
+    assert m.get_counter("prefill_padded_tokens") == 16.0
+    assert m.get_counter("decode_grid_positions") == 0.0
+    p.note_dispatch("decode", (4, 16, 64), 100)
+    assert m.get_counter("decode_live_positions") == 100.0
+    # rows x table width x page size (CacheConfig's default page)
+    assert m.get_counter("decode_grid_positions") == (
+        4 * 64 * CacheConfig(kind="paged").page_size
+    )
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense"])
+def test_census_counters_on_a_two_row_engine(kind):
+    """``prefill_*_tokens`` and ``decode_*_positions`` against values
+    computed by hand: two prompts of 3 and 10 tokens admitted alone (buckets
+    8 and 16), then decode ticks of one token over both rows."""
+    eng = make_engine(ragged=False, kind=kind, batch=2, decode_steps=1)
+    a, b = [1, 2, 3], list(range(1, 11))
+    opts = SamplingOptions(max_new_tokens=3)
+    eng.submit(a, opts), eng.submit(b, opts)
+    eng.step()  # admits both (one prefill each), then one decode dispatch
+    m = eng.metrics
+    assert m.get_counter("prefill_valid_tokens") == 3 + 10
+    assert m.get_counter("prefill_padded_tokens") == 8 + 16
+    # each row holds its prompt and the token its prefill sampled
+    assert m.get_counter("decode_live_positions") == (3 + 1) + (10 + 1)
+    if kind == "paged":
+        width = eng.cache.page_table.shape[1] * eng.ccfg.page_size
+    else:
+        width = eng.cache.max_len
+    assert m.get_counter("decode_grid_positions") == 2 * width
+    eng.step()  # both rows one token longer, the same grid again
+    assert m.get_counter("decode_live_positions") == (4 + 11) + (5 + 12)
+    assert m.get_counter("decode_grid_positions") == 2 * (2 * width)
+    assert m.get_counter("prefill_padded_tokens") == 8 + 16  # no new prefill
 
 
 # ---------------------------------------------------------------------------

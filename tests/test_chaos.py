@@ -308,11 +308,27 @@ def test_concurrent_close_is_connection_error_not_attribute_error():
 # -- end-to-end generation under faults ---------------------------------------
 
 
+def _warm_nodes(relay_port, params, prompts, steps=STEPS):
+    """The same generation over the clean path first, so the nodes' programs
+    are compiled before a fault is judged: the hops below have 2 s each, and
+    on a busy machine a first hop that still has to compile outlasts that —
+    the replay then outruns a duplicated frame and nothing is left for the
+    worker to dedupe."""
+    with DistributedClient(
+        relay_port, CFG, params, prefill_buckets=(16,), dtype=jnp.float32,
+    ) as client:
+        if len(prompts) == 1:
+            client.generate(prompts[0], max_new_tokens=steps, timeout=60.0)
+        else:
+            client.generate_many(prompts, max_new_tokens=steps, timeout=60.0)
+
+
 def _generate_through_chaos(relay_port, params, plan, max_retries=3,
                             steps=STEPS):
     """One full generation with ALL client traffic (data + directory)
     routed through a chaos proxy; returns (tokens, streamed, client)."""
     streamed = []
+    _warm_nodes(relay_port, params, [PROMPT], steps)
     with ChaosProxy("127.0.0.1", relay_port, plan=plan) as proxy:
         with DistributedClient(
             proxy.port, CFG, params, prefill_buckets=(16,),
@@ -626,6 +642,7 @@ def test_generate_many_byte_exact_under_fault(cluster, params, spec):
     relay, _service, n1, n2 = cluster
     prompts = [[5, 11, 42], [7, 3], [9, 1, 30]]
     plan = FaultPlan.from_specs([spec], seed=42)
+    _warm_nodes(relay.port, params, prompts)
     with ChaosProxy("127.0.0.1", relay.port, plan=plan) as proxy:
         with DistributedClient(
             proxy.port, CFG, params, prefill_buckets=(16,),

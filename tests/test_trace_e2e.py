@@ -484,6 +484,50 @@ def test_http_trace_id_debug_trace_ticks_and_healthz():
 
 
 @pytest.mark.http
+def test_http_debug_trace_holds_the_engine_spans_under_the_request():
+    """``engine.queue`` and ``engine.first_token`` of one request: recorded
+    by the engine into the gateway's recorder, descendants of
+    ``gateway.request``, each naming the tick of its event, and inside
+    ``gateway.decode_wait`` on the shared epoch clock."""
+    with serving(trace_cfg=TraceConfig(trace_sample_rate=1.0,
+                                       ticks_capacity=64)) as (server, b):
+        conn, resp = _post(server.port, {"prompt": [1, 2, 3],
+                                         "max_tokens": 4})
+        tid = resp.getheader("X-Trace-Id")
+        resp.read()
+        conn.close()
+        c2, r2 = _get(server.port, f"/debug/trace/{tid}")
+        events = json.loads(r2.read())["traceEvents"]
+        c2.close()
+        by_name = {e["name"]: e for e in events}
+        assert {"gateway.request", "gateway.decode_wait", "engine.queue",
+                "engine.first_token"} <= set(by_name), sorted(by_name)
+        parent = {e["args"]["span_id"]: e["args"].get("parent_id")
+                  for e in events}
+        request = by_name["gateway.request"]["args"]["span_id"]
+        ticks = {t["tick"] for t in b.flight_snapshot()}
+        wait = by_name["gateway.decode_wait"]
+        for name in ("engine.queue", "engine.first_token"):
+            e = by_name[name]
+            node = e["args"]["span_id"]
+            while parent.get(node) is not None and node != request:
+                node = parent[node]
+            assert node == request, name
+            assert e["args"]["tick"] in ticks, e["args"]
+            assert e["args"]["gen_id"] == wait["args"]["gen_id"]
+            assert e["dur"] >= 0
+            # inside the gateway's decode wait, to the clocks' resolution
+            assert e["ts"] >= wait["ts"] - 5e3
+            assert e["ts"] + e["dur"] <= wait["ts"] + wait["dur"] + 5e3
+        q, f = by_name["engine.queue"], by_name["engine.first_token"]
+        assert q["args"]["tick"] <= f["args"]["tick"]
+        assert abs(q["ts"] + q["dur"] - f["ts"]) < 5e3  # one instant, in us
+        snap = b.metrics.snapshot()
+        assert snap["engine_queue_wait_count"] == 1
+        assert snap["engine_first_token_wait_count"] == 1
+
+
+@pytest.mark.http
 def test_http_tracing_disabled_no_header_404_and_parity():
     with serving(trace_cfg=TraceConfig(trace_sample_rate=1.0)) as (s_on, _b):
         conn, resp = _post(s_on.port, {"prompt": [1, 2, 3], "max_tokens": 4})

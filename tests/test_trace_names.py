@@ -1,0 +1,96 @@
+"""What a device trace calls things: a stable ``name=`` on every Pallas
+kernel under ``ops/`` and ``jax.named_scope`` around the model's parts.
+Fusion and custom-call numbers (``closed_call.41``, ``fusion.153``) move with
+every recompile; these names are what a reduction of the trace reads."""
+
+import ast
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+
+from distributed_llm_inference_tpu.cache.dense import DenseKVCache
+from distributed_llm_inference_tpu.config import ModelConfig
+from distributed_llm_inference_tpu.engine.sampling import SamplingParams, sample
+from distributed_llm_inference_tpu.models import llama
+
+OPS = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "distributed_llm_inference_tpu", "ops",
+)
+
+
+def pallas_calls():
+    """(file, line, enclosing function, the call) of every ``pallas_call``."""
+    for path in sorted(glob.glob(os.path.join(OPS, "*.py"))):
+        tree = ast.parse(open(path).read())
+        for fn in ast.walk(tree):
+            if not isinstance(fn, ast.FunctionDef):
+                continue
+            for node in ast.walk(fn):
+                if (isinstance(node, ast.Call)
+                        and isinstance(node.func, ast.Attribute)
+                        and node.func.attr == "pallas_call"):
+                    yield os.path.basename(path), node.lineno, fn.name, node
+
+
+def test_every_pallas_call_has_a_name_of_its_own():
+    names = {}
+    for path, line, fn, call in pallas_calls():
+        kw = {k.arg: k.value for k in call.keywords}
+        assert "name" in kw, f"{path}:{line} pallas_call without name="
+        # a constant: the same whatever the block sizes and shapes
+        assert isinstance(kw["name"], ast.Constant), f"{path}:{line}"
+        assert isinstance(kw["name"].value, str) and kw["name"].value
+        names.setdefault(kw["name"].value, []).append(f"{path}:{line} {fn}")
+    assert len(names) >= 14
+    shared = {n: at for n, at in names.items() if len(at) > 1}
+    assert not shared, shared
+
+
+def lowered_text(cfg):
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    cache = DenseKVCache.create(
+        cfg.num_layers, 1, 16, cfg.num_kv_heads, cfg.head_dim, jnp.float32
+    )
+
+    def forward(params, tokens, cache, key):
+        logits, cache = llama.model_apply(
+            cfg, params, tokens, cache, jnp.full((1,), 4, jnp.int32),
+            head="last",
+        )
+        sp = SamplingParams.create(1, 0.0, 0, 1.0)
+        return sample(logits[:, 0], key, sp), cache
+
+    return jax.jit(forward).lower(
+        params, jnp.zeros((1, 4), jnp.int32), cache, jax.random.PRNGKey(1)
+    ).as_text(debug_info=True)
+
+
+def scoped(text, scope):
+    """An operation's location is ``<scope path>/<primitive>``: inside the
+    layer scan without the ``jit(...)`` prefix, outside it with."""
+    return any(f"{a}{scope}{b}" in text for a in '"/' for b in '"/')
+
+
+def test_a_lowered_forward_carries_the_model_scopes():
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16,
+    )
+    text = lowered_text(cfg)
+    for scope in ("attention", "mlp", "head", "sampler"):
+        assert scoped(text, scope), scope
+    assert "moe_experts" not in text
+
+
+def test_a_lowered_moe_forward_carries_the_expert_scopes():
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16, num_experts=4,
+        num_experts_per_tok=2,
+    )
+    text = lowered_text(cfg)
+    for scope in ("mlp/moe_router", "mlp/moe_experts", "mlp/moe_combine"):
+        assert scoped(text, scope), scope

@@ -1,12 +1,14 @@
 """Tracing/profiling subsystem (SURVEY §5.1).
 
-Host spans must capture engine step timing and export valid Chrome
-trace-event JSON; the jax.profiler wrapper must produce a trace dump and be
-idempotent/no-op-safe.
+The flight recorder splits every engine tick's wall time into host phases
+and counts them (counts and structure are checked here, never times); the
+jax.profiler wrapper must produce a trace dump and be idempotent/no-op-safe.
 """
 
-import json
 import threading
+import time
+
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -19,23 +21,54 @@ from distributed_llm_inference_tpu.models import llama
 from distributed_llm_inference_tpu.utils import tracing
 
 
-def test_span_records_and_exports(tmp_path):
-    rec = tracing.SpanRecorder()
-    with tracing.span("work", rec, items=3):
-        pass
-    with tracing.span("unrecorded"):
-        pass
-    spans = rec.spans()
-    assert [s.name for s in spans] == ["work"]
-    assert spans[0].duration_s >= 0
-    assert spans[0].args == {"items": 3}
+PHASE_MS = [f"{p}_ms" for p in tracing.PHASES]
 
-    path = tmp_path / "trace.json"
-    rec.dump_chrome_trace(str(path))
-    doc = json.loads(path.read_text())
-    assert doc["traceEvents"][0]["name"] == "work"
-    assert doc["traceEvents"][0]["ph"] == "X"
-    assert doc["traceEvents"][0]["dur"] >= 0
+
+def small_engine(trace_cfg=None, batch=2, **ekw):
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16,
+    )
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    ecfg = EngineConfig(max_batch_size=batch, prefill_buckets=(8, 16),
+                        max_seq_len=32, dtype="float32", **ekw)
+    return InferenceEngine(cfg, params, ecfg, CacheConfig(kind="dense"),
+                           trace_cfg=trace_cfg)
+
+
+def test_regions_charge_exclusive_time_to_the_tick():
+    """A region inside another suspends the outer one: the five phases of a
+    tick sum to its wall time, and the counters get the same seconds."""
+    from distributed_llm_inference_tpu.utils.metrics import Metrics
+
+    m = Metrics()
+    fr = tracing.FlightRecorder(capacity=4, metrics=m)
+    assert fr.begin() == 0
+    with fr.region("dispatch"):
+        with fr.region("blocked"):
+            time.sleep(0.002)
+        with fr.region("deliver"):
+            pass
+    fr.end(kind="plain")
+    assert fr.begin() == 1  # what passed since end() is this tick's outside
+    fr.end(kind="plain")
+    first, second = fr.snapshot()
+    assert [first["tick"], second["tick"]] == [0, 1]
+    assert first["outside_ms"] == 0.0 and second["outside_ms"] > 0.0
+    assert first["blocked_ms"] >= 2.0 > first["dispatch_ms"]  # suspended
+    for t in (first, second):
+        assert all(t[k] >= 0.0 for k in PHASE_MS)
+        wall = t["host_ms"] + t["outside_ms"]
+        assert sum(t[k] for k in PHASE_MS) == pytest.approx(wall, abs=1e-6)
+        assert t["t0_ns"] <= t["t"] * 1e9
+    assert m.get_counter("engine_ticks") == 2.0
+    for p in tracing.PHASES:
+        assert m.get_counter(f"engine_tick_{p}_seconds") * 1e3 == (
+            pytest.approx(first[f"{p}_ms"] + second[f"{p}_ms"])
+        )
+    assert m.get_counter("engine_tick_seconds") == pytest.approx(sum(
+        m.get_counter(f"engine_tick_{p}_seconds") for p in tracing.PHASES
+    ))
 
 
 def test_span_recorder_bounded_and_thread_safe():
@@ -66,33 +99,100 @@ def test_profile_trace_writes_device_trace(tmp_path):
     assert tracing.stop_profile() is None
 
 
-def test_engine_records_prefill_and_decode_spans():
-    cfg = ModelConfig(
-        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
-        num_heads=2, num_kv_heads=2, head_dim=16,
-    )
-    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    eng = InferenceEngine(
-        cfg, params,
-        EngineConfig(max_batch_size=2, prefill_buckets=(8,), max_seq_len=32,
-                     dtype="float32"),
-        CacheConfig(kind="dense"),
-    )
-    eng.generate([[1, 2, 3]], SamplingOptions(max_new_tokens=4))
-    names = {s.name for s in eng.spans.spans()}
-    assert "prefill" in names and "decode_step" in names
-    pre = next(s for s in eng.spans.spans() if s.name == "prefill")
-    assert pre.args["prompt_tokens"] == 3
+def test_engine_tick_records_split_the_host_time():
+    """The tick record in place of the old prefill / decode_step spans: one
+    tick that admitted two rows and decoded lists all three dispatches, its
+    five phases are non-negative and sum to its wall time, and it was
+    blocked in its fetches."""
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    eng = small_engine(TraceConfig(ticks_capacity=64), decode_steps=1)
+    opts = SamplingOptions(max_new_tokens=4)
+    eng.submit([1, 2, 3], opts), eng.submit(list(range(1, 11)), opts)
+    eng.step()
+    (tick,) = eng.flight.snapshot()
+    assert tick["admitted"] == 2 and tick["occupancy"] == 2
+    assert tick["dispatches"] == [
+        ("prefill", (1, 8), 3), ("prefill", (1, 16), 10),
+        ("decode", (2, 1, eng.cache.max_len), 4 + 11),
+    ]
+    assert tick["dispatch"] == tick["dispatches"][-1]
+    assert tick["free_pages"] is None  # a dense cache has no page pool
+    assert all(tick[k] >= 0.0 for k in PHASE_MS)
+    wall = tick["host_ms"] + tick["outside_ms"]
+    assert abs(sum(tick[k] for k in PHASE_MS) - wall) < 1.0  # ms
+    # two synchronous admissions and the decode fetch each waited
+    assert tick["blocked_ms"] > 0.0 and tick["deliver_ms"] > 0.0
+    assert tick["admit_ms"] > 0.0 and tick["dispatch_ms"] > 0.0
+    while eng.has_work():
+        eng.step()
+    ticks = eng.flight.snapshot()
+    assert [t["tick"] for t in ticks] == list(range(len(ticks)))
+    assert all(t["dispatches"] == [t["dispatch"]] for t in ticks[1:])
+    assert all(t["outside_ms"] > 0.0 for t in ticks[1:])
 
 
-def test_span_recorded_on_exception():
-    rec = tracing.SpanRecorder()
-    try:
-        with tracing.span("boom", rec):
-            raise RuntimeError("x")
-    except RuntimeError:
+def test_tick_counters_equal_the_sum_of_the_tick_fields():
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    eng = small_engine(TraceConfig(ticks_capacity=256))
+    eng.generate([[1, 2, 3], [4, 5, 6, 7]], SamplingOptions(max_new_tokens=9))
+    ticks = eng.flight.snapshot()
+    m = eng.metrics
+    assert m.get_counter("engine_ticks") == len(ticks) > 0
+    for p in tracing.PHASES:
+        assert m.get_counter(f"engine_tick_{p}_seconds") * 1e3 == (
+            pytest.approx(sum(t[f"{p}_ms"] for t in ticks))
+        )
+    assert m.get_counter("engine_tick_seconds") * 1e3 == pytest.approx(
+        sum(t["host_ms"] + t["outside_ms"] for t in ticks)
+    )
+    # the request-side pair: observed once a session, at the event
+    snap = m.snapshot()
+    assert snap["engine_queue_wait_count"] == 2
+    assert snap["engine_first_token_wait_count"] == 2
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_without_a_trace_config_step_touches_no_recorder(
+    monkeypatch, decode_steps
+):
+    """``trace_cfg=None``: the same tokens, and not one call into the flight
+    recorder, the profiler's annotations or a span recorder."""
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    prompts = [[1, 2, 3], list(range(1, 11)), [5, 6]]
+    opts = SamplingOptions(max_new_tokens=7)
+    on = small_engine(TraceConfig(), decode_steps=decode_steps)
+    traced = on.generate(prompts, opts)
+    assert on.flight.snapshot()
+
+    def boom(*a, **k):
+        raise AssertionError("tracing work on the disabled path")
+
+    for name in ("begin", "end", "region", "record"):
+        monkeypatch.setattr(tracing.FlightRecorder, name, boom)
+    monkeypatch.setattr(tracing.SpanRecorder, "record", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", boom)
+    off = small_engine(None, decode_steps=decode_steps)
+    assert off.flight is None and off.tracer is None
+    assert off.plan.dispatches is None
+    assert off.generate(prompts, opts) == traced
+
+
+def test_a_raising_region_leaves_the_tick_clock_whole():
+    fr = tracing.FlightRecorder()
+    fr.begin()
+    with pytest.raises(RuntimeError):
+        with fr.region("dispatch"):
+            with fr.region("blocked"):
+                raise RuntimeError("x")
+    with fr.region("deliver"):  # the stack unwound: regions still nest
         pass
-    assert [s.name for s in rec.spans()] == ["boom"]
+    fr.end(kind="plain")
+    (t,) = fr.snapshot()
+    assert sum(t[k] for k in PHASE_MS) == pytest.approx(t["host_ms"], abs=1e-6)
 
 
 def test_nested_profile_trace_keeps_outer(tmp_path):

@@ -13,8 +13,10 @@ outside the loop (every iteration draws the same stream). ``split`` and
 
 **DC301 — host sync in the tick hot path.** Within engine tick-path
 functions (``step`` and the ``_*tick`` / ``_*dispatch`` / ``_*resolve``
-/ ``_*flush`` family under ``engine/``), ``jax.device_get`` and
-``.block_until_ready()`` force a device round-trip per call. The tick
+/ ``_*flush`` family under ``engine/``), ``jax.device_get`` (also
+through the engine's ``self._fetch``, which times it as the tick's
+``blocked`` phase) and ``.block_until_ready()`` force a device round-trip
+per call. The tick
 budget allows exactly the amortized fetches the overlap design
 documents — each of those carries ``# distcheck: host-sync-ok(reason)``;
 anything new gets flagged so the ragged-kernel work can't quietly grow
@@ -127,6 +129,9 @@ def _host_sync_reason(node: ast.Call) -> Optional[str]:
         return ".block_until_ready()"
     if name == "jax.block_until_ready":
         return "jax.block_until_ready"
+    if name == "self._fetch":
+        # the engine's one road to jax.device_get on the tick path
+        return "self._fetch"
     return None
 
 

@@ -14,8 +14,9 @@ mysteriously flat graph.
   includes the registry itself and at least one call site.
 * **DC402** — registry entry violating prometheus naming rules: names
   must be ``snake_case``; counters must not end in ``_total`` /
-  ``_seconds`` / ``_count`` and summaries must not end in ``_total`` /
-  ``_seconds`` (the exposition layer appends those suffixes itself).
+  ``_count`` and summaries must not end in ``_total`` / ``_seconds`` (the
+  exposition layer appends those suffixes itself; a counter OF seconds
+  names its unit, ``engine_tick_seconds`` → ``..._seconds_total``).
 
 Dynamic names: f-strings become ``*`` wildcard patterns and must match a
 wildcard registry entry (``pool_batches_size_*``). A name computed some
@@ -45,7 +46,7 @@ _EMITTERS = {
 _KINDS = ("counter", "gauge", "summary")
 _NAME_OK = re.compile(r"^[a-z][a-z0-9_*]*$")
 _BAD_SUFFIX = {
-    "counter": ("_total", "_seconds", "_count"),
+    "counter": ("_total", "_count"),
     "summary": ("_total", "_seconds"),
     "gauge": ("_total",),
 }

@@ -22,7 +22,8 @@ from distributed_llm_inference_tpu.cache.dense import (
     QuantizedDenseKVCache,
 )
 from distributed_llm_inference_tpu.models import llama
-from distributed_llm_inference_tpu.utils.xplane import aggregate
+from distributed_llm_inference_tpu.utils.xplane import aggregate, find_xplane
+from xplane_profile import report  # the script's own directory
 
 
 def main():
@@ -72,16 +73,11 @@ def main():
                 tokens, cache = decode(params, tokens, cache)
             jax.block_until_ready(tokens)
         dt = time.perf_counter() - t0
-        import glob
-        pb = glob.glob(os.path.join(td, "**", "*.xplane.pb"), recursive=True)
-        total, agg, cnt = aggregate(pb[0])
+        agg = aggregate(find_xplane(td))
     per_step = dt / reps * 1e3
     print(f"wall {per_step:.2f} ms/call ({scan_k} tokens) -> "
           f"{batch*scan_k*reps/dt:.0f} tok/s")
-    print(f"device line-total {total/1e9:.2f} ms over {sum(cnt.values())} events"
-          f" ({total/1e9/reps:.2f} ms/call)")
-    for nm, d in agg.most_common(40):
-        print(f"{d/1e9:9.3f} ms  x{cnt[nm]:<5} {nm[:110]}")
+    print("\n".join(report(agg)))
 
 
 if __name__ == "__main__":

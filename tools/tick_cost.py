@@ -1,0 +1,96 @@
+"""What the engine's tracing costs a tick, on this machine's host.
+
+    python tools/tick_cost.py [ticks]
+
+Two readings, each the median of five rounds of ``ticks`` calls:
+
+* ``step()`` of an engine whose slots are all idle (nothing queued, nothing
+  decoding: the tick is its bookkeeping alone), with ``TraceConfig()`` and
+  with ``trace_cfg=None`` — the difference is one tick's ``begin`` / ``end``,
+  its ``engine_tick`` step annotation, one ``admit`` region, the tick record
+  and the seven counters;
+* the recorder's calls of a FULL tick alone (``begin``, the four regions a
+  decoding tick opens, ``end`` with every field), since a tick that decodes
+  is bound by its device and hides a few microseconds.
+
+The model is tiny: this times the host, and says nothing of a device.
+"""
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from distributed_llm_inference_tpu.config import (  # noqa: E402
+    CacheConfig, EngineConfig, ModelConfig, TraceConfig,
+)
+from distributed_llm_inference_tpu.engine.engine import InferenceEngine  # noqa: E402
+from distributed_llm_inference_tpu.models import llama  # noqa: E402
+from distributed_llm_inference_tpu.utils.metrics import Metrics  # noqa: E402
+from distributed_llm_inference_tpu.utils.tracing import FlightRecorder  # noqa: E402
+
+
+def engine(trace_cfg):
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16,
+    )
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    return InferenceEngine(
+        cfg, params,
+        EngineConfig(max_batch_size=32, prefill_buckets=(8,), max_seq_len=64,
+                     dtype="float32"),
+        CacheConfig(kind="paged", page_size=8, num_pages=64,
+                    max_pages_per_session=8),
+        trace_cfg=trace_cfg,
+    )
+
+
+def median_us(fn, ticks, rounds=5):
+    fn()
+    out = []
+    for _ in range(rounds):
+        t0 = time.perf_counter()
+        for _ in range(ticks):
+            fn()
+        out.append((time.perf_counter() - t0) / ticks * 1e6)
+    return statistics.median(out)
+
+
+def full_tick(fr):
+    fr.begin()
+    with fr.region("admit"):
+        pass
+    with fr.region("dispatch"):
+        with fr.region("blocked"):
+            pass
+        with fr.region("deliver"):
+            pass
+    fr.end(kind="plain", occupancy=32, queued=0, admitted=0, chunking=0,
+           parked=0, overlap_inflight=0, pending=False, events=32,
+           dispatch=("decode", (32, 1, 38), 12345),
+           dispatches=[("decode", (32, 1, 38), 12345)], free_pages=100)
+
+
+def main(argv):
+    ticks = int(argv[0]) if argv else 5000
+    on, off = engine(TraceConfig()), engine(None)
+    enabled = median_us(on.step, ticks)
+    disabled = median_us(off.step, ticks)
+    fr = FlightRecorder(512, Metrics())
+    recorder = median_us(lambda: full_tick(fr), ticks)
+    print({
+        "platform": jax.devices()[0].platform, "ticks": ticks,
+        "idle_step_us_enabled": round(enabled, 2),
+        "idle_step_us_disabled": round(disabled, 2),
+        "idle_step_us_tracing": round(enabled - disabled, 2),
+        "full_tick_recorder_calls_us": round(recorder, 2),
+    })
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])
