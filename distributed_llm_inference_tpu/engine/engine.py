@@ -56,6 +56,17 @@ from .session import Session, SessionState
 # ``_region`` with the flight recorder off: one shared, re-entrant no-op.
 _NO_REGION = contextlib.nullcontext()
 
+# Seconds without a resident session before ``_shrink_if_idle`` gives the
+# cache's high-water shape back. Every (old, new) shape on the way back up
+# is an executable of its own: 0.6 s each to load from the compile cache
+# and 8-10 s to compile (chip runs, PERF.md §6, PR 24), where keeping a
+# paged table at its widest costs the requests that come next a tenth of a
+# decode step at most (the scale rows' gather, 1.3 of 12.4 ms at 47 slots)
+# and an idle engine nothing. So a pause between two requests must not
+# shrink, and 30 s outlasts 95% of the gaps of arrivals as sparse as one
+# in ten seconds. Not swept; a constant, not an option.
+IDLE_SHRINK_S = 30.0
+
 
 class InferenceEngine:
     """Single-host continuous-batching engine over one model replica.
@@ -633,6 +644,7 @@ class InferenceEngine:
         self._pending = None
         self._carry = None
         self._carry_ok = np.zeros(self.batch, np.bool_)
+        self._idle_since = time.monotonic()  # last time every slot was free
         # -- overlapped (stall-free) admission ---------------------------------
         # With a pipelined tick in flight, admission prefills DISPATCH as
         # usual (the program queues right behind the running tick — JAX
@@ -2146,8 +2158,11 @@ class InferenceEngine:
         """With no resident sessions, re-create the dense buffer at the
         smallest bucket (nothing to copy) — one long-context session must not
         pin its high-water-mark buffer (and its decode bandwidth cost) for
-        the rest of the process. Shapes revisited later hit the jit cache."""
+        the rest of the process — once it has had none for
+        :data:`IDLE_SHRINK_S`. Shapes revisited later hit the jit cache."""
         if not self._windows or any(g is not None for g in self.slots):
+            return
+        if time.monotonic() - self._idle_since < IDLE_SHRINK_S:
             return
         if isinstance(self.cache, PagedKVCache):
             if self.cache.page_table.shape[1] > self._first_slots:
@@ -3675,6 +3690,8 @@ class InferenceEngine:
             self._chunking.remove(s)
         if s.slot is not None:
             self.slots[s.slot] = None
+            if not any(g is not None for g in self.slots):
+                self._idle_since = time.monotonic()
             # The device carry holds THIS session's last token; the slot's
             # next tenant must be fed its own fresh token.
             self._carry_ok[s.slot] = False

@@ -14,10 +14,23 @@ dict of growing tensors,
 
 Bandwidth properties:
 * no materialized contiguous copy — pages stream through VMEM once;
-* page blocks past a row's live length are clamped to the null page 0 in the
-  index map, so short rows in a long-table batch fetch (cheap, cached) zeros
-  instead of the whole table span — the dense cache by contrast always reads
-  its full padded buffer;
+* a page block past a row's live length is clamped to the null page 0 in
+  the index map, which saves its DMA (the block index does not change) —
+  and, in ``paged_attention``, ``quantized_paged_attention`` and the two
+  latent wrappers over them, NOTHING ELSE: their grid is ``(slots, table
+  width)`` and every step of it runs the whole tile (two matmuls, the
+  ``exp``, the accumulator's rescale) and pays the pipeline's fixed cost,
+  live or not. The ledger priced such a step at 0.57-0.65 µs where a page's
+  bytes take 0.17 (PR 23's breakdown, PERF.md §6), so their cost follows
+  slots x table width, not the live tokens. No benchmark cell runs those
+  four; the debt is open (PERF.md §7);
+* ``quantized_paged_fused_attention`` — the kernel every int8 paged engine
+  decodes through past ``INPLACE_CTX`` — sweeps a row's LIVE pages: its
+  grid is over rows only, K and V stay in HBM, and a loop inside the kernel
+  fetches (double-buffered async copies, the physical ids from the page
+  table in SMEM) and attends to the pages that hold something the query
+  sees. A page past the row's length, or wholly before its sliding window,
+  costs nothing; an empty slot runs the tail tile alone;
 * MHA (``G == 1``) uses a VPU multiply-reduce for QK^T and PV — a 1-row MXU
   matmul per head wastes the systolic array; GQA (``G > 1``) uses
   ``Hkv``-batched ``dot_general``.
@@ -489,6 +502,36 @@ def quantized_latent_paged_attention(
     )
 
 
+# VMEM the in-place kernel's page buffers may take: K and V of a block of
+# pages, twice (double buffering). The row's scale rows, its tail blocks, q
+# and the softmax scratch come on top (under a MiB at 8 kv heads of 128 and
+# a table 64 wide), and Mosaic's own temporaries for one page tile; a core
+# has 16 MiB scoped by default, and tests/test_chip_compile.py compiles the
+# cells' shapes.
+_SWEEP_VMEM_BUDGET = 4 * 2**20
+
+
+def _pages_per_block(t, hkv, page_size, d, kt):
+    """Pages of K and V one block of the sweep fetches: the most (a power
+    of two, no more than the table is wide, and no more than 8: a block is
+    then a megabyte in flight, and the chip timed 2, 4 and 8 alike, PERF.md
+    §6) whose double buffers fit :data:`_SWEEP_VMEM_BUDGET` beside the row's
+    scale rows and tail."""
+    lanes = -(-d // 128) * 128
+    heads = -(-hkv // 8) * 8
+    page = 2 * hkv * -(-page_size // 32) * 32 * lanes          # K + V, int8
+    row = 2 * 2 * t * heads * max(page_size, 128) * 4          # scale rows
+    row += 2 * 2 * (2 * hkv * -(-kt // 32) * 32 * lanes        # tail in +
+                    + 2 * heads * max(kt, 128) * 4)            # out, 2 bufs
+    n = 1
+    while (
+        2 * n <= min(t, 8)
+        and 2 * (2 * n) * page + row <= _SWEEP_VMEM_BUDGET
+    ):
+        n *= 2
+    return n
+
+
 def quantized_paged_fused_attention(
     q: jnp.ndarray,
     k_new: jnp.ndarray,
@@ -512,14 +555,35 @@ def quantized_paged_fused_attention(
     sliding_window: Optional[int] = None,
 ):
     """ONE kernel for a fused-decode step over the int8 page pool IN PLACE:
-    the WHOLE ``[L, P, Hkv, PS, D]`` pool passes through unsliced (the block
-    index map resolves ``(layer, physical page)``, so the operand is
-    zero-copy — the r2 per-layer pool slices materialized a full pool copy
-    per (layer, step), and the r3 gather-per-window fix held a second
-    contiguous copy of the live KV alive, halving the admissible batch at
-    long contexts); the step's fresh K/V quantizes in-kernel into the
+    the WHOLE ``[L, P, Hkv, PS, D]`` K and V planes stay in HBM
+    (``pl.ANY``: zero-copy — the r2 per-layer pool slices materialized a
+    full pool copy per (layer, step), and the r3 gather-per-window fix held
+    a second contiguous copy of the live KV alive, halving the admissible
+    batch at long contexts) and the kernel fetches ``(layer, physical
+    page)`` itself; the step's fresh K/V quantizes in-kernel into the
     io-aliased write-behind tail, which joins the page sweep as the final
     online-softmax tile.
+
+    The grid is over rows. A row's sweep is a loop INSIDE the kernel over
+    its own live pages — page ``lo`` (0, or under a sliding window the first
+    page that holds a position the query still sees) to
+    ``cdiv(base_len, page_size)`` — fetched a block of
+    :func:`_pages_per_block` pages at a time with double-buffered async
+    copies, the physical ids read from the page table in SMEM. A page past
+    the row's length, or wholly before its window, is neither fetched nor
+    computed, and a slot that is not decoding (``tail_valid_len`` 0: the
+    engine leaves a released row's length stale until its next admission)
+    runs the tail tile alone, whatever its length says: a call costs what
+    the live tokens cost, not slots x table width. Skipping a dead page
+    changes no sum (it was an exact no-op of the online softmax: alpha 1,
+    p 0), so a decoding row's results are the whole-grid walk's, bit for bit.
+
+    The scale rows do not come that way: Mosaic (jax 0.9.0) refuses an
+    async copy out of an HBM plane whose minor dimension is one page
+    (``[.., Hkv, 64]`` f32: "Slice shape along dimension 3 must be aligned
+    to tiling (128), but is 64"). So the wrapper gathers the layer's scale
+    rows of every table slot (``[B, T, Hkv, PS]`` f32, 1/64 of the bytes
+    K and V hold there) and a row's come as one pipelined block.
 
     Shapes: ``q`` ``[B, 1, Hq, D]`` (rotated); ``k_new``/``v_new``
     ``[B, 1, Hkv, D]`` (k rotated); pool planes ``[L, P, Hkv, PS, D]`` int8
@@ -538,45 +602,46 @@ def quantized_paged_fused_attention(
         scale = d**-0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    n = _pages_per_block(t, hkv, page_size, d, kt)
 
     qr = q.reshape(b, hkv, g, d)
     knr = jnp.moveaxis(k_new, 1, 2)  # [B, Hkv, 1, D]
     vnr = jnp.moveaxis(v_new, 1, 2)
     lref = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     sref = jnp.asarray(step_idx, jnp.int32).reshape(1)
+    table = page_table.astype(jnp.int32)
 
-    def _pool_index(bi, ji, lidx, step, table, lens, vlen, qpos):
-        live = ji * page_size < lens[bi]
-        return (lidx[0], jnp.where(live, table[bi, ji], 0), 0, 0, 0)
+    def _scale_rows(plane):  # [L, P, Hkv, PS] -> this layer's [B, T, Hkv, PS]
+        layer = jax.lax.dynamic_index_in_dim(plane, lref[0], keepdims=False)
+        # A table holds page ids; "clip" spares the default mode's bounds
+        # test and fill (a quarter of this gather's time on the chip).
+        return jnp.take(layer, table, axis=0, mode="clip")
 
-    def _pool_index4(bi, ji, lidx, step, table, lens, vlen, qpos):
-        live = ji * page_size < lens[bi]
-        return (lidx[0], jnp.where(live, table[bi, ji], 0), 0, 0)
-
-    def _tail_index(bi, ji, lidx, step, table, lens, vlen, qpos):
+    def _tail_index(bi, lidx, step, table, lens, vlen, qpos):
         return (lidx[0], bi, 0, 0, 0)
 
-    def _tail_index3(bi, ji, lidx, step, table, lens, vlen, qpos):
+    def _tail_index3(bi, lidx, step, table, lens, vlen, qpos):
         return (lidx[0], bi, 0, 0)
 
-    def _row_index(bi, ji, lidx, step, table, lens, vlen, qpos):
+    def _row_index(bi, lidx, step, table, lens, vlen, qpos):
         return (bi, 0, 0, 0)
 
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(b, t),
+        grid=(b,),
         in_specs=[
             pl.BlockSpec((1, hkv, g, d), _row_index),
             pl.BlockSpec((1, hkv, 1, d), _row_index),
             pl.BlockSpec((1, hkv, 1, d), _row_index),
-            pl.BlockSpec((1, 1, hkv, page_size, d), _pool_index),
-            pl.BlockSpec((1, 1, hkv, page_size), _pool_index4),
-            pl.BlockSpec((1, 1, hkv, page_size, d), _pool_index),
-            pl.BlockSpec((1, 1, hkv, page_size), _pool_index4),
             pl.BlockSpec((1, 1, hkv, kt, d), _tail_index),
             pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
             pl.BlockSpec((1, 1, hkv, kt, d), _tail_index),
             pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
+            in_hbm,
+            pl.BlockSpec((1, t, hkv, page_size), _row_index),
+            in_hbm,
+            pl.BlockSpec((1, t, hkv, page_size), _row_index),
         ],
         out_specs=(
             pl.BlockSpec((1, hkv, g, d), _row_index),
@@ -586,6 +651,9 @@ def quantized_paged_fused_attention(
             pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
         ),
         scratch_shapes=[
+            pltpu.VMEM((2, n, hkv, page_size, d), pool_k.dtype),
+            pltpu.VMEM((2, n, hkv, page_size, d), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, n)),
             pltpu.VMEM((hkv * g, d), jnp.float32),
             pltpu.VMEM((hkv * g, 128), jnp.float32),
             pltpu.VMEM((hkv * g, 128), jnp.float32),
@@ -595,7 +663,7 @@ def quantized_paged_fused_attention(
         _qpaged_fused_kernel,
         scale=scale,
         page_size=page_size,
-        num_page_blocks=t,
+        pages_per_block=n,
         sliding_window=sliding_window,
         hkv=hkv,
         g=g,
@@ -615,64 +683,117 @@ def quantized_paged_fused_attention(
         interpret=interpret,
         # Tail planes update in place; indices count every flattened input
         # including the 6 scalar-prefetch operands.
-        input_output_aliases={13: 1, 14: 2, 15: 3, 16: 4},
+        input_output_aliases={9: 1, 10: 2, 11: 3, 12: 4},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
-    )(lref, sref, page_table.astype(jnp.int32), base_len.astype(jnp.int32),
+    )(lref, sref, table, base_len.astype(jnp.int32),
       tail_valid_len.astype(jnp.int32), q_positions.astype(jnp.int32),
       qr, knr, vnr,
-      pool_k, pool_ks, pool_v, pool_vs,
-      tail_k, tail_ks, tail_v, tail_vs)
+      tail_k, tail_ks, tail_v, tail_vs,
+      pool_k, _scale_rows(pool_ks), pool_v, _scale_rows(pool_vs))
     return out.reshape(b, 1, hq, d), tk, tks, tv, tvs
 
 
 def _qpaged_fused_kernel(
-    lidx_ref,   # SMEM [1] int32 (layer; consumed by index maps)
+    lidx_ref,   # SMEM [1] int32 (layer)
     step_ref,   # SMEM [1] int32 (tail write slot)
-    table_ref,  # SMEM [B, T] int32 (consumed by index maps)
+    table_ref,  # SMEM [B, T] int32 (physical page ids)
     len_ref,    # SMEM [B] int32 (live pool tokens)
     vlen_ref,   # SMEM [B] int32 (valid tail slots incl. this write)
     qpos_ref,   # SMEM [B] int32 (query positions)
     q_ref,      # [1, Hkv, G, D]
     kn_ref,     # [1, Hkv, 1, D]
     vn_ref,     # [1, Hkv, 1, D]
-    k_ref,      # [1, 1, Hkv, PS, D] int8 (one physical page)
-    ks_ref,     # [1, 1, Hkv, PS] f32
-    v_ref,      # [1, 1, Hkv, PS, D] int8
-    vs_ref,     # [1, 1, Hkv, PS] f32
     tk_ref,     # [1, 1, Hkv, KT, D] int8 (in)
     tks_ref,    # [1, 1, Hkv, KT] f32 (in)
     tv_ref,     # [1, 1, Hkv, KT, D] int8 (in)
     tvs_ref,    # [1, 1, Hkv, KT] f32 (in)
+    k_hbm,      # HBM [L, P, Hkv, PS, D] int8 (the whole pool)
+    ks_ref,     # [1, T, Hkv, PS] f32 (the row's scale rows, by table slot)
+    v_hbm,      # HBM [L, P, Hkv, PS, D] int8
+    vs_ref,     # [1, T, Hkv, PS] f32
     out_ref,    # [1, Hkv, G, D]
     tk_out,     # aliased tail outputs
     tks_out,
     tv_out,
     tvs_out,
+    k_buf,      # VMEM [2, N, Hkv, PS, D] int8 (two blocks of N pages)
+    v_buf,      # VMEM [2, N, Hkv, PS, D] int8
+    sems,       # DMA [2, N]: one a page buffer, its K and V together
     acc_ref,    # VMEM [Hkv*G, D] f32
     m_ref,      # VMEM [Hkv*G, 128] f32
     l_ref,      # VMEM [Hkv*G, 128] f32
     *,
     scale: float,
     page_size: int,
-    num_page_blocks: int,
+    pages_per_block: int,
     sliding_window: Optional[int],
     hkv: int,
     g: int,
     kt: int,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    n = pages_per_block
+    layer = lidx_ref[0]
+    # A row with no valid tail slot is not decoding (an empty slot, a
+    # released one, one parked mid-prefill: a decoding row's tail holds at
+    # least the token this step writes). Its length is whatever its last
+    # tenant left there, so it is not believed: the row sweeps nothing.
+    kv_len = jnp.where(vlen_ref[b] > 0, len_ref[b], 0)
+    qpos = qpos_ref[b]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    # The row's live pages [lo, hi): what lies past its length, or wholly
+    # before its window, is never fetched and never computed. Both ends are
+    # held inside the table: a length is the caller's word, and a page id
+    # read past the table's end would be the source of a DMA.
+    hi = jnp.clip(
+        (kv_len + page_size - 1) // page_size, 0, table_ref.shape[1]
+    )
+    if sliding_window is None:
+        lo = 0
+    else:
+        lo = jnp.minimum(
+            jnp.maximum(qpos - sliding_window + 1, 0) // page_size, hi
+        )
+    num_blocks = (hi - lo + n - 1) // n
 
-    q = q_ref[0]                               # [Hkv, G, D]
+    def _page_copies(slot, i, page):
+        phys = table_ref[b, page]
+        return [
+            pltpu.make_async_copy(
+                hbm.at[layer, phys], buf.at[slot, i], sems.at[slot, i]
+            )
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf))
+        ]
+
+    def _block_pages(blk, body):
+        """``body(i, page)`` for the live pages of block ``blk``: a loop,
+        not ``n`` copies of the body (the engine traces this kernel once an
+        executable, and one executable a table width)."""
+        first = lo + blk * n
+
+        def step(i, carry):
+            body(i, first + i)
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(n, hi - first), step, 0)
+
+    def _start_block(blk, slot):
+        def start(i, page):
+            for copy in _page_copies(slot, i, page):
+                copy.start()
+
+        _block_pages(blk, start)
+
+    @pl.when(num_blocks > 0)
+    def _first_block():
+        _start_block(0, 0)
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
 
     def _accumulate(s, valid):
         s = jnp.where(valid, s, _NEG_INF)
@@ -689,8 +810,10 @@ def _qpaged_fused_kernel(
         return p, alpha
 
     def _tile(kk, kks, vv, vvs, valid, width):
+        # q from its block every tile: held across the page loop it cost
+        # 3% of the kernel's time on the chip (PERF.md §6, PR 24).
         s = jax.lax.dot_general(
-            q.astype(jnp.bfloat16).reshape(hkv, g, -1),
+            q_ref[0].astype(jnp.bfloat16).reshape(hkv, g, -1),
             kk.astype(jnp.bfloat16).reshape(hkv, width, -1),
             (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -705,51 +828,60 @@ def _qpaged_fused_kernel(
         )
         acc_ref[:] = acc_ref[:] * alpha + pv.reshape(hkv * g, -1)
 
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1
-    )
-    valid = pos < len_ref[b]
+    def _block(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < num_blocks)
+        def _prefetch():
+            _start_block(blk + 1, 1 - slot)
+
+        def attend(i, page):
+            for copy in _page_copies(slot, i, page):
+                copy.wait()
+            pos = page * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (1, page_size), 1
+            )
+            valid = pos < kv_len
+            if sliding_window is not None:
+                valid &= pos > qpos - sliding_window
+            _tile(k_buf[slot, i], ks_ref[0, page], v_buf[slot, i],
+                  vs_ref[0, page], valid, page_size)
+
+        _block_pages(blk, attend)
+        return carry
+
+    jax.lax.fori_loop(0, num_blocks, _block, 0)
+
+    # Once a row, after its pages: the step's K/V into the tail, the tail
+    # as the last tile, the division.
+    step = step_ref[0]
+    kn = kn_ref[0].astype(jnp.float32)     # [Hkv, 1, D]
+    vn = vn_ref[0].astype(jnp.float32)
+    ksc = jnp.maximum(jnp.max(jnp.abs(kn), axis=-1), 1e-8) / 127.0
+    vsc = jnp.maximum(jnp.max(jnp.abs(vn), axis=-1), 1e-8) / 127.0
+    kq = jnp.clip(jnp.round(kn / ksc[..., None]), -127, 127).astype(jnp.int8)
+    vq = jnp.clip(jnp.round(vn / vsc[..., None]), -127, 127).astype(jnp.int8)
+    # Two iotas, not ``hit3[..., 0]``: Mosaic (jax 0.9.0) refuses the
+    # squeeze of a mask's lane dim ("Invalid vector register cast").
+    hit3 = jax.lax.broadcasted_iota(jnp.int32, (1, kt, 1), 1) == step
+    hit2 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1) == step
+    tk = jnp.where(hit3, kq, tk_ref[0, 0])    # [Hkv, KT, D]
+    tv = jnp.where(hit3, vq, tv_ref[0, 0])
+    tks = jnp.where(hit2, ksc, tks_ref[0, 0])  # [Hkv, KT]
+    tvs = jnp.where(hit2, vsc, tvs_ref[0, 0])
+    tk_out[0, 0] = tk
+    tv_out[0, 0] = tv
+    tks_out[0, 0] = tks
+    tvs_out[0, 0] = tvs
+
+    pos1 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1)
+    tail_valid = pos1 < vlen_ref[b]
     if sliding_window is not None:
-        valid &= pos > qpos_ref[b] - sliding_window
-    _tile(k_ref[0, 0], ks_ref[0, 0], v_ref[0, 0], vs_ref[0, 0], valid,
-          page_size)
+        tail_valid &= kv_len + pos1 > qpos - sliding_window
+    _tile(tk, tks, tv, tvs, tail_valid, kt)
 
-    @pl.when(j == num_page_blocks - 1)
-    def _tail_tile():
-        step = step_ref[0]
-        kn = kn_ref[0].astype(jnp.float32)     # [Hkv, 1, D]
-        vn = vn_ref[0].astype(jnp.float32)
-        ksc = jnp.maximum(jnp.max(jnp.abs(kn), axis=-1), 1e-8) / 127.0
-        vsc = jnp.maximum(jnp.max(jnp.abs(vn), axis=-1), 1e-8) / 127.0
-        kq = jnp.clip(jnp.round(kn / ksc[..., None]), -127, 127).astype(
-            jnp.int8
-        )
-        vq = jnp.clip(jnp.round(vn / vsc[..., None]), -127, 127).astype(
-            jnp.int8
-        )
-        # Two iotas, not ``hit3[..., 0]``: Mosaic (jax 0.9.0) refuses the
-        # squeeze of a mask's lane dim ("Invalid vector register cast").
-        hit3 = jax.lax.broadcasted_iota(jnp.int32, (1, kt, 1), 1) == step
-        hit2 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1) == step
-        tk = jnp.where(hit3, kq, tk_ref[0, 0])    # [Hkv, KT, D]
-        tv = jnp.where(hit3, vq, tv_ref[0, 0])
-        tks = jnp.where(hit2, ksc, tks_ref[0, 0])  # [Hkv, KT]
-        tvs = jnp.where(hit2, vsc, tvs_ref[0, 0])
-        tk_out[0, 0] = tk
-        tv_out[0, 0] = tv
-        tks_out[0, 0] = tks
-        tvs_out[0, 0] = tvs
-
-        pos1 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1)
-        tail_valid = pos1 < vlen_ref[b]
-        if sliding_window is not None:
-            tail_pos = len_ref[b] + pos1
-            tail_valid &= tail_pos > qpos_ref[b] - sliding_window
-        _tile(tk, tks, tv, tvs, tail_valid, kt)
-
-        l = l_ref[:, :1]
-        out = acc_ref[:] / jnp.maximum(l, 1e-20)
-        out_ref[0] = out.reshape(hkv, g, -1).astype(out_ref.dtype)
+    out = acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-20)
+    out_ref[0] = out.reshape(hkv, g, -1).astype(out_ref.dtype)
 
 
 def paged_tail_flush(

@@ -104,20 +104,32 @@ def test_paged_decode_kernels(chip, pages):
     )
 
 
-def test_fused_int8_paged_decode_kernel(chip):
-    """At the shapes the engine's decode scan gives it: the whole
-    ``[L, P, ...]`` pool, a 16-slot tail, rank-0 layer and step indices."""
-    s, b = chip, 8
+@pytest.mark.parametrize(
+    "rows,width,pages,window",
+    [
+        (8, SLOTS, PAGES, 4096),     # the engine's defaults
+        (32, 38, 1280, 4096),        # mistral-7b.reason's pinned table
+        (32, 47, 1280, 4096),        # mistral-7b.chat's
+        (16, 64, 1024, None),        # mixtral-8x7b-8l.rag: no window
+        (32, 64, 1280, None),
+    ],
+)
+def test_fused_int8_paged_decode_kernel(chip, rows, width, pages, window):
+    """At the shapes the engine's decode scan gives it in the benchmark's
+    cells: the whole ``[L, P, ...]`` pool, a 16-slot tail, rank-0 layer and
+    step indices, and the pages a block the kernel picks for itself — so a
+    block over the scoped VMEM, or an operand Mosaic refuses, fails here."""
+    s, b = chip, rows
     bf16 = jnp.bfloat16
-    pool = (s((LAYERS, PAGES, HKV, PS, D), I8), s((LAYERS, PAGES, HKV, PS), F32))
+    pool = (s((LAYERS, pages, HKV, PS, D), I8), s((LAYERS, pages, HKV, PS), F32))
     tail = (s((LAYERS, b, HKV, KT, D), I8), s((LAYERS, b, HKV, KT), F32))
     _compiles_with_kernel(
         lambda *a: pa.quantized_paged_fused_attention(
-            *a, sliding_window=4096, interpret=False
+            *a, sliding_window=window, interpret=False
         ),
         s((b, 1, HQ, D), bf16), s((b, 1, HKV, D), bf16), s((b, 1, HKV, D), bf16),
         *pool, *pool, *tail, *tail, s((), I32), s((), I32),
-        s((b, SLOTS), I32), s((b,), I32), s((b,), I32), s((b,), I32),
+        s((b, width), I32), s((b,), I32), s((b,), I32), s((b,), I32),
     )
 
 

@@ -12,7 +12,10 @@ import numpy as np
 import pytest
 
 from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig, ModelConfig
-from distributed_llm_inference_tpu.engine.engine import InferenceEngine
+from distributed_llm_inference_tpu.engine.engine import (
+    IDLE_SHRINK_S,
+    InferenceEngine,
+)
 from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
 from distributed_llm_inference_tpu.models import llama
 
@@ -451,8 +454,10 @@ def test_cache_growth_and_idle_shrink():
     assert eng.metrics.snapshot().get("cache_growths", 0) >= 1
     grown = eng.cache.max_len
     assert grown >= 41
-    # Next admission with everything idle shrinks back to the first bucket
-    # (then regrows as needed for the new prompt).
+    # Next admission with everything idle (for IDLE_SHRINK_S: aged here)
+    # shrinks back to the first bucket (then regrows as needed for the new
+    # prompt).
+    eng._idle_since -= IDLE_SHRINK_S
     eng.generate([prompts(1, lo=3, hi=4, seed=51)[0]],
                  SamplingOptions(max_new_tokens=2))
     assert eng.cache.max_len < grown
@@ -475,7 +480,8 @@ def test_decode_windows_validation():
         )
 
 
-def test_paged_table_growth_and_shrink():
+@pytest.mark.parametrize("keep_alive", [False, True], ids=["idle-long", "idle-a-moment"])
+def test_paged_table_growth_and_shrink(keep_alive):
     eng = make_engine(kind="paged", batch=2)
     first_slots = eng.cache.page_table.shape[1]
     assert first_slots < eng.ccfg.max_pages_per_session
@@ -493,9 +499,18 @@ def test_paged_table_growth_and_shrink():
     assert eng.metrics.snapshot().get("cache_growths", 0) >= 1
     grown = eng.cache.page_table.shape[1]
     assert grown > first_slots
-    # Idle admission shrinks the table back.
+    # Idle admission shrinks the table back — once the engine has been
+    # idle for IDLE_SHRINK_S; a moment between two requests keeps the
+    # high-water table (and the executables already compiled for it).
+    if not keep_alive:
+        eng._idle_since -= IDLE_SHRINK_S  # half a minute later
     eng.generate([prompts(1, lo=3, hi=4, seed=61)[0]],
                  SamplingOptions(max_new_tokens=2))
+    if keep_alive:
+        assert eng.cache.page_table.shape[1] == grown
+        eng._idle_since -= IDLE_SHRINK_S  # ... and half a minute later
+        eng.generate([prompts(1, lo=3, hi=4, seed=62)[0]],
+                     SamplingOptions(max_new_tokens=2))
     assert eng.cache.page_table.shape[1] < grown
 
 

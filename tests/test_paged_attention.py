@@ -244,3 +244,168 @@ def test_paged_kernel_stats_merge_oracle():
     np.testing.assert_allclose(
         np.asarray(merged), np.asarray(ref), rtol=2e-5, atol=2e-5
     )
+
+
+# -- the in-place fused decode kernel (int8 pool, write-behind tail) ----------
+
+_FUSED = dict(ps=8, t=20, kt=4, d=16, layers=2, layer=1)
+
+
+def _fused_rows():
+    """Row lengths the sweep's bounds must get right: an empty slot, one
+    token, a page less one / exactly / plus one, a count of live pages the
+    block does not divide (with a partial last page), the full table, an
+    INACTIVE row (nothing in the pool, nothing valid in the tail), an
+    inactive row whose length is STALE (a released slot: the engine resets
+    a row at its next admission; every page of it is poisoned, and the
+    kernel must not believe the length), and a row whose length is longer
+    than the table: the sweep stops at the table's end and reads no page
+    id past it."""
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        _pages_per_block,
+    )
+
+    f = _FUSED
+    n = _pages_per_block(f["t"], 2, f["ps"], f["d"], f["kt"])
+    assert 1 < n < f["t"] and f["t"] % n, "want partial blocks in this test"
+    ps = f["ps"]
+    lengths = [0, 1, ps - 1, ps, ps + 1, (n + 3) * ps - 5, f["t"] * ps, 0,
+               3 * ps + 1, (f["t"] + 2) * ps + 3]
+    active = [True] * 7 + [False, False, True]
+    return np.asarray(lengths, np.int32), np.asarray(active), n
+
+
+def _fused_inputs(seed, g, step):
+    f = _FUSED
+    ps, t, kt, d, layers = f["ps"], f["t"], f["kt"], f["d"], f["layers"]
+    hkv = 2
+    lengths, active, _ = _fused_rows()
+    b = len(lengths)
+    rng = np.random.default_rng(seed)
+    pages = b * t + 1
+    pool = [rng.integers(-127, 128, (layers, pages, hkv, ps, d)).astype(np.int8)
+            for _ in range(2)]
+    scales = [rng.uniform(0.01, 0.03, (layers, pages, hkv, ps)).astype(np.float32)
+              for _ in range(2)]
+    # A shuffled, non-contiguous table: slot order is not pool order.
+    table = (rng.permutation(pages - 1)[: b * t] + 1).reshape(b, t).astype(np.int32)
+    # Poison what no live token owns: the null page and every page past a
+    # row's length. A read of one shows as NaN (or as a gross error).
+    live_pages = np.where(active, np.minimum(-(-lengths // ps), t), 0)
+    dead = [0] + [int(table[r, s]) for r in range(b)
+                  for s in range(int(live_pages[r]), t)]
+    for plane in pool:
+        plane[:, dead] = np.where(rng.random(plane[:, dead].shape) < 0.5, 127, -127)
+    for plane in scales:
+        plane[:, dead] = np.nan
+    tail = [rng.integers(-127, 128, (layers, b, hkv, kt, d)).astype(np.int8)
+            for _ in range(2)]
+    tscale = [rng.uniform(0.01, 0.03, (layers, b, hkv, kt)).astype(np.float32)
+              for _ in range(2)]
+    bf16 = lambda x: jnp.asarray(x, jnp.float32).astype(jnp.bfloat16)
+    q = bf16(rng.normal(size=(b, 1, hkv * g, d)))
+    k_new = bf16(rng.normal(size=(b, 1, hkv, d)))
+    v_new = bf16(rng.normal(size=(b, 1, hkv, d)))
+    tail_valid_len = np.where(active, step + 1, 0).astype(np.int32)
+    q_positions = (lengths + step).astype(np.int32)
+    return dict(
+        q=q, k_new=k_new, v_new=v_new,
+        pool_k=jnp.asarray(pool[0]), pool_ks=jnp.asarray(scales[0]),
+        pool_v=jnp.asarray(pool[1]), pool_vs=jnp.asarray(scales[1]),
+        tail_k=jnp.asarray(tail[0]), tail_ks=jnp.asarray(tscale[0]),
+        tail_v=jnp.asarray(tail[1]), tail_vs=jnp.asarray(tscale[1]),
+        layer_idx=jnp.asarray(f["layer"], jnp.int32),
+        step_idx=jnp.asarray(step, jnp.int32),
+        page_table=jnp.asarray(table), base_len=jnp.asarray(lengths),
+        tail_valid_len=jnp.asarray(tail_valid_len),
+        q_positions=jnp.asarray(q_positions),
+    )
+
+
+def _fused_oracle(a, window):
+    """float32 ``jax.numpy`` over the dequantised pool and tail: the new
+    token quantised (symmetric absmax, as ``_scatter_q``) into tail slot
+    ``step``, then one softmax over pool positions ``< base_len`` (of a row
+    that is decoding: ``tail_valid_len > 0``) and tail slots
+    ``< tail_valid_len``, the window measured from ``q_positions``."""
+    f32 = jnp.float32
+    layer, step = int(a["layer_idx"]), int(a["step_idx"])
+    table, base_len = a["page_table"], a["base_len"]
+    b, t = table.shape
+    hkv, ps, d = a["pool_k"].shape[2:]
+    kt = a["tail_k"].shape[3]
+
+    @jax.jit  # compiled like the kernel: XLA turns ``/ 127`` into a multiply
+    def quant(x):  # [B, 1, Hkv, D] -> int8 [B, Hkv, D], f32 [B, Hkv]
+        x = x[:, 0].astype(f32)
+        sc = jnp.maximum(jnp.max(jnp.abs(x), axis=-1), 1e-8) / 127.0
+        return jnp.clip(jnp.round(x / sc[..., None]), -127, 127).astype(jnp.int8), sc
+
+    kq, ksc = quant(a["k_new"])
+    vq, vsc = quant(a["v_new"])
+    tails = (
+        a["tail_k"].at[layer, :, :, step].set(kq),
+        a["tail_ks"].at[layer, :, :, step].set(ksc),
+        a["tail_v"].at[layer, :, :, step].set(vq),
+        a["tail_vs"].at[layer, :, :, step].set(vsc),
+    )
+
+    pos = jnp.arange(t * ps)[None, :]
+    decoding = a["tail_valid_len"][:, None] > 0
+    pool_valid = (pos < base_len[:, None]) & decoding
+    tpos = jnp.arange(kt)[None, :]
+    tail_valid = tpos < a["tail_valid_len"][:, None]
+    if window is not None:
+        qp = a["q_positions"][:, None]
+        pool_valid &= pos > qp - window
+        tail_valid &= base_len[:, None] + tpos > qp - window
+    valid = jnp.concatenate([pool_valid, tail_valid], axis=1)  # [B, T*PS+KT]
+
+    def deq(pages, scales, tail, tscale):  # -> [B, Hkv, T*PS + KT, D] f32
+        x = pages[layer][table].astype(f32) * scales[layer][table][..., None]
+        x = x.transpose(0, 2, 1, 3, 4).reshape(b, hkv, t * ps, d)
+        y = tail[layer].astype(f32) * tscale[layer][..., None]
+        x = jnp.concatenate([x, y], axis=2)
+        return jnp.where(valid[:, None, :, None], x, 0.0)
+
+    k = deq(a["pool_k"], a["pool_ks"], tails[0], tails[1])
+    v = deq(a["pool_v"], a["pool_vs"], tails[2], tails[3])
+    g = a["q"].shape[2] // hkv
+    q = a["q"][:, 0].astype(f32).reshape(b, hkv, g, d)
+    s = jnp.einsum("bhgd,bhtd->bhgt", q, k, precision="highest") * d**-0.5
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(valid[:, None, None, :], jnp.exp(s - jnp.where(m > -jnp.inf, m, 0.0)), 0.0)
+    out = jnp.einsum("bhgt,bhtd->bhgd", p, v, precision="highest")
+    out = out / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-20)
+    return out.reshape(b, 1, hkv * g, d), tails
+
+
+@pytest.mark.parametrize("step", [0, _FUSED["kt"] - 1], ids=["step0", "stepKT-1"])
+@pytest.mark.parametrize("g", [4, 1], ids=["G4", "G1"])
+@pytest.mark.parametrize(
+    "window", [None, 2 * _FUSED["ps"] + 3], ids=["nowindow", "window19"]
+)
+def test_fused_inplace_kernel_matches_oracle(window, g, step):
+    """``quantized_paged_fused_attention`` — the kernel every int8 paged
+    engine decodes through past ``INPLACE_CTX`` — against the oracle, with
+    every page no live token owns poisoned. The window of 19 cuts whole
+    leading pages off the long rows (and none off the short ones)."""
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        quantized_paged_fused_attention,
+    )
+
+    a = _fused_inputs(seed=7 + step, g=g, step=step)
+    out, tk, tks, tv, tvs = quantized_paged_fused_attention(
+        **a, sliding_window=window
+    )
+    ref, tails = _fused_oracle(a, window)
+    out = np.asarray(out.astype(jnp.float32))
+    assert np.isfinite(out).all(), "a dead page was read"
+    lengths, active, _ = _fused_rows()
+    assert (out[~active] == 0).all(), "an inactive row attends to nothing"
+    # bf16 operands into f32 sums: the probabilities round to 8 bits before
+    # they meet V (|V| <= 127 * 0.03).
+    np.testing.assert_allclose(out, np.asarray(ref), atol=0.03, rtol=0.02)
+    for got, want in zip((tk, tks, tv, tvs), tails):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -182,12 +182,20 @@ def test_quantized_paged_engine_matches_exact():
         assert agree >= len(ref) - 1, (name, ref, out)
 
 
-def test_paged_fused_kernel_tail_matches_xla_path():
+@pytest.mark.parametrize(
+    "PS,SLOTS,prompt_lens",
+    [(8, 4, [9, 14, 5]), (64, 12, [70, 130, 5])],
+    ids=["gathered-cap32", "inplace-cap768"],
+)
+def test_paged_fused_kernel_tail_matches_xla_path(PS, SLOTS, prompt_lens):
     """kernel-mode fused decode (in-kernel quantize + io-aliased int8 tail +
-    big gathered segment in one Pallas call) emits the same tokens as the
-    XLA two-segment path and leaves the pool within 1 int8 LSB (the XLA
-    path's bf16 tail rounds once more before its flush-quantize; the kernel
-    quantizes the full-precision values directly)."""
+    the big segment in one Pallas call) emits the same tokens as the XLA
+    two-segment path and leaves the pool within 1 int8 LSB (the XLA path's
+    bf16 tail rounds once more before its flush-quantize; the kernel
+    quantizes the full-precision values directly). Under a table capacity of
+    ``INPLACE_CTX`` the big segment is the gathered stack
+    (``quantized_fused_decode_attention``); at 768 it is the pool itself,
+    read in place by ``quantized_paged_fused_attention``."""
     import numpy as np
 
     from distributed_llm_inference_tpu.cache.paged import (
@@ -199,7 +207,8 @@ def test_paged_fused_kernel_tail_matches_xla_path():
     cfg = ModelConfig(vocab_size=128, hidden_size=64, intermediate_size=160,
                       num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16)
     params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
-    B, PS, SLOTS, K = 3, 8, 4, 4
+    B, K = 3, 4
+    inplace = PS * SLOTS >= QuantizedPagedKVCache.INPLACE_CTX
 
     def run(use_kernel):
         cache = QuantizedPagedKVCache.create(
@@ -209,9 +218,11 @@ def test_paged_fused_kernel_tail_matches_xla_path():
         alloc = PageAllocator(B * SLOTS + 1)
         for r in range(B):
             cache = cache.assign_pages(r, alloc.alloc(SLOTS))
-        lens = jnp.asarray([9, 14, 5], jnp.int32)
+        assert cache._fused_inplace == (use_kernel and inplace)
+        lens = jnp.asarray(prompt_lens, jnp.int32)
         toks = jax.random.randint(
-            jax.random.PRNGKey(1), (B, 16), 0, cfg.vocab_size
+            jax.random.PRNGKey(1), (B, -(-max(prompt_lens) // 16) * 16), 0,
+            cfg.vocab_size,
         )
         logits, cache = llama.model_apply(cfg, params, toks, cache, lens)
         active = jnp.ones((B,), bool)

@@ -1,0 +1,183 @@
+"""Time the in-place paged decode kernel alone, on the chip, by occupancy.
+
+    python tools/profile_paged_sweep.py [--rows 32] [--width 47] [--pages 1600]
+        [--window 4096] [--occupancy full reason chat]
+        [--form inplace|gathered]
+
+``quantized_paged_fused_attention`` at Mistral-7B widths (32 q / 8 kv heads
+of 128, 64-token pages, 32 layers, a 16-slot tail) over a seeded int8 pool,
+one jitted "decode step" = the 32 layers' calls in a row, the tail planes
+carried through as the engine's scan carries them. Device time is the sum of
+the kernel's events in a profiler trace (``utils/xplane.py``), never a host
+clock. ``--occupancy``: ``full`` every page of every row live; ``reason`` 32
+live rows a quarter full (lengths spread about a mean of 657); ``chat`` 5% of
+the table's positions live, in a few rows, the other slots empty; a number is
+that share of every row's table, in per cent. An empty slot has nothing
+valid in its tail, which is what has the kernel skip it (the engine leaves a
+released slot's length stale; the kernel does not read it).
+
+``--form gathered`` times what ``QuantizedPagedKVCache`` runs UNDER
+``INPLACE_CTX`` instead: ``quantized_fused_decode_attention`` over every
+row's table span gathered to contiguous stacks, and the gather itself (once
+a fused window of ``KT`` steps, so a ``KT``-th of it belongs to a step).
+
+Import the package from another checkout with ``PYTHONPATH=<root>`` to time
+that checkout's kernel on the same chip: the tool itself uses nothing else
+of the repository but ``utils/xplane.py``. ``busy_ms_a_step`` is the whole
+step's device time: the kernel and what its wrapper runs beside it.
+"""
+import argparse
+import json
+import os
+import sys
+import tempfile
+import time
+
+sys.path.append(os.path.join(os.path.dirname(__file__), ".."))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from distributed_llm_inference_tpu.ops import paged_attention as pa
+from distributed_llm_inference_tpu.ops import quant_attention as qa
+from distributed_llm_inference_tpu.utils.xplane import aggregate, find_xplane
+
+HQ, HKV, D, PS, LAYERS, KT = 32, 8, 128, 64, 32, 16
+KERNEL = "quantized_paged_fused_attention"
+
+
+def row_lengths(kind, rows, width, rng):
+    cap = width * PS
+    if kind == "full":
+        return np.full(rows, cap, np.int32)
+    if kind == "reason":
+        return np.minimum(rng.integers(214, 1100, rows), cap).astype(np.int32)
+    if kind == "chat":
+        lens = np.zeros(rows, np.int32)
+        live = max(1, rows // 4)
+        lens[rng.permutation(rows)[:live]] = int(0.05 * rows * cap / live)
+        return np.minimum(lens, cap)
+    return np.full(rows, int(float(kind) / 100 * cap), np.int32)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rows", type=int, default=32)
+    ap.add_argument("--width", type=int, default=47)
+    ap.add_argument("--pages", type=int, default=1600)
+    ap.add_argument("--window", type=int, default=4096)
+    ap.add_argument("--occupancy", nargs="+", default=["full", "reason", "chat"])
+    ap.add_argument("--reps", type=int, default=3)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--form", choices=("inplace", "gathered"), default="inplace")
+    args = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        sys.exit("a device time comes from a chip: no TPU here")
+    b, t = args.rows, args.width
+    key = jax.random.PRNGKey(args.seed)
+    kk, kv, ks, kq = jax.random.split(key, 4)
+
+    @jax.jit
+    def make(kk, kv, ks):
+        shape = (LAYERS, args.pages, HKV, PS, D)
+        k = jax.random.randint(kk, shape, -127, 128, jnp.int8)
+        v = jax.random.randint(kv, shape, -127, 128, jnp.int8)
+        s = jax.random.uniform(ks, shape[:-1], jnp.float32, 0.01, 0.03)
+        return k, s, v, s + 0.001
+
+    pool = make(kk, kv, ks)
+    q = jax.random.normal(kq, (b, 1, HQ, D), jnp.bfloat16)
+    new = jax.random.normal(kq, (b, 1, HKV, D), jnp.bfloat16)
+    window = args.window or None
+    kernel = KERNEL if args.form == "inplace" else "quantized_fused_decode_attention"
+
+    @jax.jit
+    def gather(pool, table):  # cache/paged.py tail_big_stacks, kernel order
+        def g(pages):
+            v = jnp.moveaxis(jnp.take(pages, table, axis=1), 3, 2)
+            return v.reshape(v.shape[:3] + (t * PS,) + v.shape[5:])
+
+        return tuple(g(p) for p in pool)
+
+    @jax.jit
+    def step(pool, tails, table, lens, vlen):
+        def layer(i, carry):
+            tails, acc = carry
+            if args.form == "gathered":
+                out, *tails = qa.quantized_fused_decode_attention(
+                    q, new, new, *pool, *tails, i, jnp.int32(3),
+                    lens, vlen, lens + 3, sliding_window=window,
+                )
+            else:
+                out, *tails = pa.quantized_paged_fused_attention(
+                    q, new, new, *pool, *tails, i, jnp.int32(3), table,
+                    lens, vlen, lens + 3, sliding_window=window,
+                )
+            return tuple(tails), acc + out.astype(jnp.float32)
+
+        return jax.lax.fori_loop(
+            0, LAYERS, layer, (tails, jnp.zeros(q.shape, jnp.float32))
+        )
+
+    for kind in args.occupancy:
+        rng = np.random.default_rng(args.seed)
+        lens = row_lengths(kind, b, t, rng)
+        live = -(-lens // PS)
+        if live.sum() >= args.pages:
+            sys.exit(f"{kind}: {live.sum()} live pages, the pool has {args.pages}")
+        ids = rng.permutation(args.pages - 1)[: live.sum()] + 1
+        table = np.zeros((b, t), np.int32)
+        at = 0
+        for r in range(b):
+            table[r, : live[r]] = ids[at : at + live[r]]
+            at += live[r]
+        vlen = np.where(lens > 0, 4, 0).astype(np.int32)
+        tails = (
+            jnp.zeros((LAYERS, b, HKV, KT, D), jnp.int8),
+            jnp.zeros((LAYERS, b, HKV, KT), jnp.float32),
+            jnp.zeros((LAYERS, b, HKV, KT, D), jnp.int8),
+            jnp.zeros((LAYERS, b, HKV, KT), jnp.float32),
+        )
+        big = pool
+        if args.form == "gathered":
+            big = jax.block_until_ready(gather(pool, jnp.asarray(table)))
+        argv = (big, tails, jnp.asarray(table), jnp.asarray(lens),
+                jnp.asarray(vlen))
+        t0 = time.perf_counter()
+        tails, acc = step(*argv)
+        jax.block_until_ready(acc)
+        first_call_s = time.perf_counter() - t0  # trace, compile, run
+        gather_ms = 0.0
+        if args.form == "gathered":
+            with tempfile.TemporaryDirectory() as td:
+                with jax.profiler.trace(td):
+                    jax.block_until_ready(gather(pool, argv[2]))
+                found = aggregate(find_xplane(td))["devices"]
+                gather_ms = found[0]["busy_ns"] / 1e6 if found else 0.0
+        with tempfile.TemporaryDirectory() as td:
+            with jax.profiler.trace(td):
+                for _ in range(args.reps):
+                    tails, acc = step(big, tails, *argv[2:])
+                jax.block_until_ready(acc)
+            agg = aggregate(find_xplane(td))
+        ns = sum(v for k, v in agg["ops_ns"].items() if kernel in k)
+        calls = sum(v for k, v in agg["op_counts"].items() if kernel in k)
+        print(json.dumps({
+            "form": args.form, "gather_ms_a_window": round(gather_ms, 3),
+            "occupancy": kind, "rows": b, "width": t,
+            "live_positions_pct": round(100 * lens.sum() / (b * t * PS), 1),
+            "live_pages": int(live.sum()), "kernel_calls": calls,
+            "kernel_us_a_call": round(ns / max(calls, 1) / 1e3, 2),
+            "kernel_ms_a_step": round(ns / args.reps / 1e6, 3),
+            "busy_ms_a_step": round(
+                agg["devices"][0]["busy_ns"] / args.reps / 1e6, 3
+            ) if agg["devices"] else 0.0,
+            "first_call_s": round(first_call_s, 2),
+            "checksum": float(jnp.sum(acc)),
+            "device": jax.devices()[0].device_kind,
+        }), flush=True)
+
+
+if __name__ == "__main__":
+    main()
