@@ -137,6 +137,15 @@ def is_all_reduce(name: str) -> bool:
     return opcode(name).startswith("all-reduce")
 
 
+def kernel_name(name: str) -> str:
+    """``custom-call:quantized_paged_fused_attention.5`` → the kernel's own
+    name, ``quantized_paged_fused_attention``: a ``pallas_call``'s ``name=``
+    is its operation's result name, and the suffix is XLA's instance
+    number, which moves with every recompile. XLA's own custom calls
+    (``custom-call.14``, nanoseconds each) fold into ``custom-call``."""
+    return re.sub(r"\.\d+$", "", name.partition(":")[2].lstrip("%"))
+
+
 def reduce_trace(planes: List[dict]) -> Optional[dict]:
     """Everything the per-layer metrics read from a trace, or ``None`` where
     no operation ran on any device plane."""
@@ -152,6 +161,12 @@ def reduce_trace(planes: List[dict]) -> Optional[dict]:
             if opcode(name) in CONTAINERS:
                 continue            # its body's operations are listed
             by_op[name] = by_op.get(name, 0) + d
+        kernels: Dict[str, List[int]] = {}
+        for name, _, d in ops:
+            if is_custom_call(name):
+                seen = kernels.setdefault(kernel_name(name), [0, 0])
+                seen[0] += 1
+                seen[1] += d
         modules: Dict[str, List[int]] = {}
         for name, _, d in _line(plane, MODULES_LINE):
             modules.setdefault(module_name(name), []).append(d)
@@ -167,6 +182,7 @@ def reduce_trace(planes: List[dict]) -> Optional[dict]:
                 (s, s + d) for n, s, d in ops if is_all_reduce(n)
             ),
             "ops_by_time": sorted(by_op.items(), key=lambda kv: -kv[1]),
+            "kernels": kernels,
             "modules": modules,
             "busy_intervals": merged(spans),
             "module_events": sorted(
@@ -214,6 +230,12 @@ def reduce_trace(planes: List[dict]) -> Optional[dict]:
         "device_ops": [
             [k, v / 1e9] for k, v in dev0["ops_by_time"][:10]
         ],
+        # every custom call by its kernel's name, however small: what a
+        # ``<kernel>_roofline`` reader divides its bytes and operations by
+        "kernels_device0": {
+            k: {"count": n, "sum_s": ns / 1e9}
+            for k, (n, ns) in sorted(dev0["kernels"].items())
+        },
         "idle_gaps": [
             [k, v / 1e9]
             for k, v in sorted(by_gap.items(), key=lambda kv: -kv[1])[:10]

@@ -33,18 +33,18 @@ import importlib
 import json
 import os
 import shutil
+import statistics
 import sys
 import threading
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NO_CHIP = 2
-HF_KEYS = (
-    "model_type", "vocab_size", "hidden_size", "intermediate_size",
-    "num_hidden_layers", "num_attention_heads", "num_key_value_heads",
-    "head_dim", "num_local_experts", "num_experts_per_tok", "rms_norm_eps",
-    "rope_theta", "max_position_embeddings", "sliding_window",
-    "tie_word_embeddings",
+#: what a configuration file carries for the harness; every other top-level
+#: key is the published ``config.json``'s
+HARNESS_KEYS = (
+    "name", "source", "reduced", "assumed", "deployment", "serve", "correct",
+    "rehearse",
 )
 
 
@@ -66,7 +66,10 @@ def load_config(path: str, rehearse: bool) -> dict:
 
 
 def hf_block(conf: dict) -> dict:
-    return {k: conf[k] for k in HF_KEYS if k in conf}
+    """The published block, whole: what ``ModelConfig.from_hf_config`` and
+    the plain reference both receive. A family's own keys (latent ranks,
+    windows by layer, shared experts) need no list here."""
+    return {k: v for k, v in conf.items() if k not in HARNESS_KEYS}
 
 
 class CompileLog:
@@ -96,11 +99,25 @@ class CompileLog:
         return [(n, round(s, 3)) for t, n, s in self.events if lo <= t <= hi]
 
 
+def probe_cache(like, pages: int, slots: int, dtype):
+    """A one-row cache as ``like``, the engine's own, says of itself: its
+    class, its pool's layers, head count and stored width (``k_pages`` is
+    ``[layers, pages, heads, page size, width]`` in every paged class: 8 x
+    128 for Mistral's K and V, 1 x ``lat_dim`` for a latent pool), its page
+    size and kernel flags, over a ``slots``-wide table with ``pages`` pages
+    assigned (page 0 stays the null page)."""
+    layers, _, heads, page_size, width = like.k_pages.shape
+    return type(like).create(
+        layers, 1, pages + 1, page_size, slots, heads, width, dtype,
+        use_kernel=like.use_kernel, use_ragged=like.use_ragged,
+    ).assign_pages(0, list(range(1, pages + 1)))
+
+
 def probe(engine, cfg, params, prompt, forced, slots: int, dtype):
     """Logits of ``prompt``'s last position and of ``len(forced) - 1``
     teacher-forced decode steps (step i consumes ``forced[i]``), through the
-    engine's own attention path: a one-row cache of its cache's class, page
-    size and kernel flags over a ``slots``-wide table, its prefill pad
+    engine's own attention path: a one-row cache as its cache describes
+    itself (``probe_cache``) over a ``slots``-wide table, its prefill pad
     width, its decode program (the fused write-behind scan, or one token a
     dispatch) and its mesh. As ``chip_smoke.probe`` (PR 21), copied so that
     the yardstick does not move with that file."""
@@ -110,13 +127,8 @@ def probe(engine, cfg, params, prompt, forced, slots: int, dtype):
     from distributed_llm_inference_tpu.models import llama
     from distributed_llm_inference_tpu.parallel import cache_pspecs, shard_pytree
 
-    like = engine.cache
-    pages = -(-(len(prompt) + len(forced)) // like.page_size)
-    cache = type(like).create(
-        cfg.num_layers, 1, pages + 1, like.page_size, slots,
-        cfg.num_kv_heads, cfg.head_dim, dtype,
-        use_kernel=like.use_kernel, use_ragged=like.use_ragged,
-    ).assign_pages(0, list(range(1, pages + 1)))
+    pages = -(-(len(prompt) + len(forced)) // engine.cache.page_size)
+    cache = probe_cache(engine.cache, pages, slots, dtype)
     pad_to = engine.plan.final_shape(len(prompt), engine.plan.buckets[-1])
     tokens = jnp.zeros((1, pad_to), jnp.int32).at[0, : len(prompt)].set(
         jnp.asarray(prompt, jnp.int32)
@@ -149,6 +161,26 @@ def probe(engine, cfg, params, prompt, forced, slots: int, dtype):
     cache = shard_pytree(cache, engine.mesh, cache_pspecs(cache))
     with engine.mesh:
         return jax.device_get(jax.jit(run)(params, tokens, forced, cache))
+
+
+def judged_numbers(dist: list, judge: str) -> list:
+    """What of the positions' distances (the prefill's, then each decode
+    step's) is held to the tolerance. ``"each"``: the prefill position and
+    the median of the decode steps, each. ``"third_least"``, for routed
+    models: the third smallest of all positions. A token that rounding sends
+    to another expert than float32 does moves that token's logits as far as
+    unrelated ones and says nothing of the path; how many of the 17 do so
+    goes from 2 to 9 with the seed, and the median with it (0.23 to 0.75,
+    my chip runs, PR 25: no limit holds it apart from a path in int4, which
+    reads 1.03). The positions least disturbed read the path's precision: a
+    lower precision, a wrong mask or a dropped term moves every position
+    (the decode steps read the K and V the prefill wrote), so it moves these
+    too. The third and not the least, so that no single position decides."""
+    if judge == "third_least":
+        return [sorted(dist)[2]]
+    if judge == "each":
+        return [dist[0], statistics.median(dist[1:])]
+    raise ValueError(f"correct.judge {judge!r}: each or third_least")
 
 
 def check_numerics(conf: dict, cfg, engine, seed: int) -> dict:
@@ -198,18 +230,8 @@ def check_numerics(conf: dict, cfg, engine, seed: int) -> dict:
     # what unrelated logits would read: the reference against itself, one
     # position off
     unrelated = rel(gold[1], gold[0])
-    # ``"judge": "each"``: the prefill position and the median of the decode
-    # steps each stay under the tolerance. ``"median"``: the median of all
-    # positions does — for routed models, where a token that bf16 rounding
-    # sends to another expert than float32 does moves that one token's
-    # logits far and says nothing of the path, while a wrong mask or a
-    # dropped term moves every position (the decode steps read the K and V
-    # the prefill wrote). The largest step is reported beside it.
     decode_median = float(np.median(dist[1:]))
-    judged = (
-        [float(np.median(dist))] if want.get("judge") == "median"
-        else [dist[0], decode_median]
-    )
+    judged = judged_numbers(dist, want.get("judge", "each"))
     ok = bool(np.all(np.isfinite(ours))) and max(judged) <= want["tolerance"]
     return {
         "ok": ok, "judged": judged, "prefill": dist[0],
@@ -389,6 +411,7 @@ def main(argv=None) -> int:
     })
 
     sampler, opened, trace_dir, engine_ttft0 = None, None, None, 0
+    traced = []                 # epoch seconds: the trace's start and stop
 
     def ttft_readings():
         # the summary's own list: /metrics gives its quantiles only over
@@ -410,8 +433,10 @@ def main(argv=None) -> int:
             options.python_tracer_level = 0
             options.host_tracer_level = 1
             jax.profiler.start_trace(trace_dir, profiler_options=options)
+            traced[:] = [time.time()]
             emit({"reply": "trace_start"})
         elif cmd == "trace_stop":
+            traced.append(time.time())
             jax.profiler.stop_trace()
             emit({"reply": "trace_stop"})
         elif cmd == "close":
@@ -435,6 +460,9 @@ def main(argv=None) -> int:
                 ))
                 planes = xplane.read_xplane(files[0]) if files else []
                 reply["trace"] = xplane.reduce_trace(planes)
+                # on the flight recorder's clock, for a reader that takes
+                # tick records and kernel events over the same span
+                reply["trace_epoch_s"] = traced
                 if planes and reply["trace"]:
                     first = min(
                         e[1] for p in planes for ln in p["lines"]

@@ -25,6 +25,11 @@ def traffic(name):
         return json.load(f)
 
 
+def config(name):
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
 def record(i, due=None, sent=None, arrivals=(), asked=None, status=200,
            phase="traffic", prompt=10, error=None):
     asked = len(arrivals) if asked is None else asked
@@ -58,7 +63,13 @@ def test_a_miss_sorts_after_every_reading():
     assert stats.percentile([0.1, 0.2, stats.MISSED], 50) == 0.2
 
 
-@pytest.mark.parametrize("mix", ["chat-open", "reason-closed", "rag-replay", "chat-closed32"])
+def mixes_the_cells_name():
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        cells = json.load(f)["workloads"]
+    return list(dict.fromkeys(w["traffic"] for w in cells))
+
+
+@pytest.mark.parametrize("mix", mixes_the_cells_name())
 def test_same_seed_same_plan_other_seed_other_plan(mix):
     params = traffic(mix)
     gen = importlib.import_module(f"benchmark.generators.{params['generator']}")
@@ -213,10 +224,7 @@ def test_peaks_are_keyed_by_device_kind_and_an_unknown_kind_raises():
 def test_flops_count_top_k_experts_and_valid_tokens_only():
     from benchmark import flops
 
-    with open(os.path.join(BENCH, "configs", "mistral-7b.json")) as f:
-        dense = json.load(f)
-    with open(os.path.join(BENCH, "configs", "mixtral-8x7b-8l.json")) as f:
-        moe = json.load(f)
+    dense, moe = config("mistral-7b"), config("mixtral-8x7b-8l")
     # Mistral-7B: 7.24e9 parameters, 0.13e9 of them the embedding lookup
     assert flops.matmul_params_per_token(dense) == pytest.approx(7.11e9, rel=0.01)
     layer = 4096 * (4096 + 1024 + 1024 + 4096) + 2 * 3 * 4096 * 14336 + 4096 * 8
@@ -226,6 +234,72 @@ def test_flops_count_top_k_experts_and_valid_tokens_only():
     assert flops.prompt_flops(dense, 2048) == pytest.approx(2 * 7.11e9 * 2048, rel=0.08)
     assert flops.decode_token_flops(dense, 8000) == flops.decode_token_flops(dense, 4096)
     assert flops.kv_bytes_per_token(dense, 1.0) == 2 * 32 * 8 * 128
+
+
+def test_the_paged_decode_kernels_bytes_and_operations_come_from_shapes():
+    from benchmark import flops
+    from benchmark.kernels import quantized_paged_fused_attention as kernel
+
+    dense = config("mistral-7b")
+    # one position of one layer: int8 K and V of 8 heads of 128, and a
+    # float32 scale a head for each
+    assert kernel.bytes_read(dense, 1) == 2 * 8 * 128 + 2 * 8 * 4 == 2112
+    # a call a layer: 32 of them read what ``flops.py`` counts for a token
+    assert 32 * kernel.bytes_read(dense, 64) == 64 * flops.kv_bytes_per_token(dense, 1 + 4 / 128)
+    assert kernel.operations(dense, 1) == 4 * 32 * 128
+    # a live page: 0.165 us of bytes at 819 GB/s, 30 times its operations'
+    v5e = peaks.peaks_for("TPU v5 lite")
+    page_s = kernel.bytes_read(dense, 64) / v5e["hbm_bytes_per_s"]
+    assert page_s == pytest.approx(0.165e-6, rel=0.01)
+    assert 25 < page_s / (kernel.operations(dense, 64) / v5e["bf16_flops"]) < 35
+
+
+def test_the_decode_kernels_roofline_share_is_taken_over_the_traced_span_alone():
+    reader = importlib.import_module("benchmark.layer_metrics.paged_decode_attn_roofline_pct")
+    dense = config("mistral-7b")
+    decode = lambda live, steps=16: ["decode", [32, steps, 38], live]   # noqa: E731
+    ticks = {
+        1: {"t0_ns": 999.5e9, "dispatches": [decode(90000)]},     # before it
+        2: {"t0_ns": 1000.5e9, "dispatches": [["prefill", [1, 2048], 100], decode(20000)]},
+        3: {"t0_ns": 1001.5e9, "dispatches": [decode(22000)]},
+        4: {"t0_ns": 1002.5e9, "dispatches": [decode(90000)]},    # after it
+        5: {"t0_ns": 1001.7e9, "dispatches": []},                 # an idle tick
+    }
+
+    def run(ticks=ticks, **closed):
+        return types.SimpleNamespace(
+            closed=closed, ticks=ticks, conf=dense, device={"kind": "TPU v5 lite"},
+        )
+
+    def traced(calls, positions, share=0.3):
+        least_s = calls * positions * 2112 / 819e9
+        return {"trace_epoch_s": [1000.0, 1002.0], "trace": {"kernels_device0": {
+            "quantized_paged_fused_attention": {"count": calls, "sum_s": least_s / share},
+            "quantized_ragged_paged_attention": {"count": 64, "sum_s": 0.3},
+        }}}
+
+    # two dispatches of 16 steps over 32 layers; a mean of 21000 live
+    # positions a call; the kernel took 3.3 times what their bytes take
+    whole = traced(2 * 16 * 32, 21000)
+    assert reader.read(run(**whole)) == pytest.approx(30.0)
+    assert reader.LAYER == "kernels" and reader.DEVICE_METRIC
+    # the device lags the host by a tick: a dispatch more or less in the
+    # trace than in the records moves the count, not the mean a call
+    assert reader.read(run(**traced(3 * 16 * 32, 21000))) == pytest.approx(30.0)
+    # a dispatch of 4 steps is 4 calls a layer, one of 16 is 16: the mean a
+    # call is (16 * 20000 + 4 * 30000) / 20, not 25000
+    mixed = {2: ticks[2], 3: {"t0_ns": 1001.5e9, "dispatches": [decode(30000, 4)]}}
+    assert reader.read(run(mixed, **traced(20 * 32, 22000))) == pytest.approx(30.0)
+    # records of seven dispatches against the events of two: these decode
+    # dispatches did not run this kernel, and their mean is no call's
+    other = {i: {"t0_ns": (1000.1 + i / 10) * 1e9, "dispatches": [decode(20000)]}
+             for i in range(7)}
+    assert reader.read(run(other, **whole)) is None
+    # nothing to read: no trace, no such kernel in it, no span, no tick in it
+    assert reader.read(run()) is None
+    assert reader.read(run(trace={"kernels_device0": {}}, trace_epoch_s=[1000.0, 1002.0])) is None
+    assert reader.read(run(trace=whole["trace"])) is None
+    assert reader.read(run(trace=whole["trace"], trace_epoch_s=[10.0, 12.0])) is None
 
 
 def test_the_traffic_files_schedule_seed_makes_the_plan_and_the_run_seed_the_tokens():
@@ -249,7 +323,7 @@ def test_the_traffic_files_schedule_seed_makes_the_plan_and_the_run_seed_the_tok
     asyncio.run(run_mod.one_window(Ctx(), Generator, {"schedule_seed": 77}, 5))
     asyncio.run(run_mod.one_window(Ctx(), Generator, {}, 5))
     assert seen == [77, 5]
-    for mix in ("chat-open", "reason-closed", "rag-replay", "chat-closed32"):
+    for mix in mixes_the_cells_name():
         assert isinstance(traffic(mix)["schedule_seed"], int)
 
 

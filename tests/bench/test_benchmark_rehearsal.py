@@ -43,9 +43,34 @@ def detail(proc):
     return json.loads(proc.stdout.strip().splitlines()[-2])
 
 
-@pytest.mark.parametrize("workload,loop", [
-    ("mistral-7b.chat", "open"), ("mixtral-8x7b-8l.rag", "closed"),
-])
+def one_chip_configurations():
+    """``(configuration, its cells)`` for every configuration of
+    ``BENCHMARK.json`` that serves on one chip. The tests take their cases
+    from here, so a configuration a later PR adds is tested with no edit."""
+    b = bench()
+    out = []
+    for config in b["configs"]:
+        cells = [w for w in b["workloads"] if w["config"] == config["name"]]
+        if cells and all(w["chips"] == 1 for w in cells):
+            out.append((config["name"], cells))
+    return out
+
+
+def first_cells_on_one_chip():
+    """``(cell, loop)`` of each such configuration's first cell; the loop is
+    its generator's."""
+    sys.path.insert(0, REPO)
+    out = []
+    for _, cells in one_chip_configurations():
+        with open(os.path.join(REPO, "benchmark", "traffic",
+                               cells[0]["traffic"] + ".json")) as f:
+            generator = json.load(f)["generator"]
+        loop = importlib.import_module(f"benchmark.generators.{generator}").LOOP
+        out.append((cells[0]["name"], loop))
+    return out
+
+
+@pytest.mark.parametrize("workload,loop", first_cells_on_one_chip())
 def test_rehearsal_end_to_end(tmp_path, workload, loop):
     proc = run_cell(tmp_path, workload, trace=0)
     line = last_line(proc)
