@@ -11,7 +11,7 @@ checkpoints, and the host spill arena.
 
 The trick that makes one stored latent serve every query head with NO
 per-token decompression is the absorbed-MLA formulation
-(``models/llama.py:_latent_decoder_layer``): the key up-projection is
+(``models/llama.py:_latent_attention``): the key up-projection is
 folded into the query (``q_lat[h] = q_nope[h] @ w_uk[h]``) and the value
 up-projection is applied AFTER attention, so the attention itself runs
 over the stored form — ``K = V = [c ; k_rope]`` with a single KV head.

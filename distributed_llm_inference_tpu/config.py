@@ -64,6 +64,9 @@ class LatentConfig:
     rope_head_dim: int = 16
     # No-rope query/key head dim (``qk_nope_head_dim``); None = head_dim.
     nope_head_dim: Optional[int] = None
+    # Per-head value dim (``v_head_dim``): the width ``wv_b`` up-projects
+    # the latent to and ``wo`` consumes; None = head_dim.
+    v_head_dim: Optional[int] = None
 
     @property
     def lat_dim(self) -> int:
@@ -72,13 +75,28 @@ class LatentConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerSegment:
+    """A run of consecutive decoder layers that are all alike: one
+    ``lax.scan`` over one stacked parameter dict (``params[key]``). The
+    cache's layer axis runs through every segment: this one owns cache
+    layers ``start .. start + count``."""
+
+    kind: str   # a key of ``models.llama.SEGMENT_SCOPES``: "dense" | "moe"
+    key: str    # where the segment's stacked parameters live in the tree
+    start: int
+    count: int
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters for a decoder-only transformer.
 
     Covers the Llama family (the reference's only model family —
     ``/root/reference/distributed_llm_inference/models/llama/model.py``) plus
-    Mistral (``sliding_window``), Qwen2 (``qkv_bias``) and Mixtral-style MoE
-    (``num_experts``/``num_experts_per_tok``).
+    Mistral (``sliding_window``), Qwen2 (``qkv_bias``), Mixtral's experts
+    (``num_experts``/``num_experts_per_tok``) and the DeepSeek-V2/V3 block
+    (``latent`` attention; routed experts beside shared ones behind leading
+    dense layers; the routing rule's switches).
     """
 
     vocab_size: int = 32000
@@ -106,6 +124,25 @@ class ModelConfig:
     # keeps the exact dense-combine path everywhere — drops would also make
     # chunked prefill depend on chunk boundaries.
     moe_capacity_factor: Optional[float] = None
+    # Width of one routed expert (``moe_intermediate_size``); None =
+    # ``intermediate_size`` (Mixtral: every MLP of the model is an expert).
+    moe_intermediate_size: Optional[int] = None
+    # Shared experts (``n_shared_experts``): applied to every token beside
+    # the routed ones, as ONE SwiGLU MLP of this many expert widths.
+    num_shared_experts: int = 0
+    # Leading layers whose MLP is dense, ``intermediate_size`` wide
+    # (``first_k_dense_replace``); the rest route.
+    first_dense_layers: int = 0
+    # The routing rule (``ops/moe.py:route``). ``moe_scoring``: "softmax"
+    # (Mixtral, DeepSeek-V2) or "sigmoid" (DeepSeek-V3, Moonlight) over all
+    # experts. ``moe_select_bias``: selection is by score PLUS a per-expert
+    # parameter (``topk_method`` "noaux_tc"); the weights never carry it.
+    # ``moe_norm_topk``: the selected weights are divided by their sum.
+    # ``moe_routed_scale``: then multiplied by this.
+    moe_scoring: str = "softmax"
+    moe_select_bias: bool = False
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
     # Latent (MLA-style) KV compression; requires the "mla" family and the
     # paged cache kind. None = conventional per-head K/V.
     latent: Optional[LatentConfig] = None
@@ -115,6 +152,31 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def expert_intermediate_size(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def segments(self) -> Tuple[LayerSegment, ...]:
+        """THE description of the stack: homogeneous segments in layer
+        order. Initialisation, the forward programs, the checkpoint
+        converter, the quantiser and the census all walk this. A stack
+        whose layers are all alike is one segment under ``"layers"``, the
+        parameter tree every such model has always had; a stack with
+        leading dense layers is a dense segment then an expert one."""
+        kind = "moe" if self.num_experts > 0 else "dense"
+        k = self.first_dense_layers if kind == "moe" else 0
+        if k == 0:
+            return (LayerSegment(kind, "layers", 0, self.num_layers),)
+        return (
+            LayerSegment("dense", "layers_0_dense", 0, k),
+            LayerSegment("moe", "layers_1_moe", k, self.num_layers - k),
+        )
+
+    @property
+    def num_expert_layers(self) -> int:
+        return sum(s.count for s in self.segments if s.kind == "moe")
 
     @property
     def use_latent(self) -> bool:
@@ -133,15 +195,33 @@ class ModelConfig:
         num_heads = get("num_attention_heads", 32)
         hidden = get("hidden_size", 4096)
         latent = None
+        moe = {}
         if get("kv_lora_rank", None):
-            # DeepSeek-V2/V3-style MLA checkpoint: map the latent dims and
-            # normalize the family tag to the registry's "mla".
+            # DeepSeek-V2/V3-style checkpoint: map the latent dims and
+            # normalize the family tag to the registry's "mla". What the
+            # block has and this program does not implement is refused by
+            # the key's name: a model served under its name is that model.
+            _refuse_unimplemented(get)
             latent = LatentConfig(
                 rank=int(get("kv_lora_rank")),
                 rope_head_dim=int(get("qk_rope_head_dim", 64)),
                 nope_head_dim=get("qk_nope_head_dim", None),
+                v_head_dim=get("v_head_dim", None),
             )
             model_type = "mla"
+            if get("n_routed_experts", None):
+                method = get("topk_method", "greedy")
+                moe = dict(
+                    moe_intermediate_size=get("moe_intermediate_size", None),
+                    num_shared_experts=get("n_shared_experts", 0) or 0,
+                    first_dense_layers=get("first_k_dense_replace", 0) or 0,
+                    moe_scoring=get("scoring_func", "softmax"),
+                    moe_select_bias=method == "noaux_tc",
+                    moe_norm_topk=bool(get("norm_topk_prob", False)),
+                    moe_routed_scale=float(
+                        get("routed_scaling_factor", 1.0) or 1.0
+                    ),
+                )
         return ModelConfig(
             vocab_size=get("vocab_size", 32000),
             hidden_size=hidden,
@@ -157,11 +237,41 @@ class ModelConfig:
             tie_word_embeddings=bool(get("tie_word_embeddings", False)),
             sliding_window=get("sliding_window", None),
             qkv_bias=bool(get("attention_bias", False)) or model_type in ("qwen2",),
-            num_experts=get("num_local_experts", 0) or 0,
+            num_experts=(
+                get("num_local_experts", 0) or get("n_routed_experts", 0) or 0
+            ),
             num_experts_per_tok=get("num_experts_per_tok", 2) or 2,
             latent=latent,
             family=model_type,
+            **moe,
         )
+
+
+def _refuse_unimplemented(get) -> None:
+    """Keys of a DeepSeek-V2/V3 ``config.json`` whose published meaning
+    this program does not compute. Each raises under its own name."""
+    scaling = get("rope_scaling", None) or {}
+    refused = {
+        "q_lora_rank": get("q_lora_rank", None) is not None,
+        "n_group": (get("n_group", 1) or 1) > 1,
+        "rope_scaling": any("mscale" in k for k in scaling),
+        "num_nextn_predict_layers": (
+            get("num_nextn_predict_layers", 0) or 0
+        ) > 0,
+        "moe_layer_freq": (get("moe_layer_freq", 1) or 1) != 1,
+        "topk_method": get("topk_method", "greedy")
+        not in ("greedy", "noaux_tc"),
+        "scoring_func": get("scoring_func", "softmax")
+        not in ("softmax", "sigmoid"),
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"config key {key!r} = {get(key)!r} is not implemented: "
+                f"compressed queries, routing by groups, YaRN's mscale, "
+                f"next-token-prediction layers and interleaved dense layers "
+                f"are outside what models/llama.py computes"
+            )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -249,6 +249,13 @@ class InferenceEngine:
                     "sharding of the latent pool is not implemented)"
                 )
         self.plan.latent = self._latent
+        if cfg.num_experts > 0:
+            from ..ops.moe import expert_rows_per_token
+
+            self.plan.expert_rows = lambda seq_len: tuple(
+                n * cfg.num_expert_layers
+                for n in expert_rows_per_token(cfg, seq_len)
+            )
         if cc.kind == "dense":
             cache_cls = (
                 QuantizedDenseKVCache if cc.kv_quant == "int8" else DenseKVCache
@@ -3077,7 +3084,7 @@ class InferenceEngine:
             self.batch, K,
             self.cache.page_table.shape[1] if paged
             else int(getattr(self.cache, "max_len", 0)),
-        ), self._live_positions(active, pend_b))
+        ), self._live_positions(active, pend_b), int(active.sum()))
         emitted, self.cache = self._decode_k(
             self.params, tokens_dev, self.cache, act_dev,
             self._next_key(), sp, jnp.asarray(eos_ids),
@@ -3251,7 +3258,7 @@ class InferenceEngine:
             self.cache.page_table.shape[1]
             if isinstance(self.cache, PagedKVCache)
             else int(getattr(self.cache, "max_len", 0)),
-        ), self._live_positions(active))
+        ), self._live_positions(active), int(active.sum()))
         if K == 1:
             next_tokens, self.cache = self._decode(
                 self.params, jnp.asarray(tokens), self.cache,

@@ -38,7 +38,8 @@ The plan centralizes that policy:
   ``attn_chunked_rows``, and keeps the census of what the shapes cost:
   cumulative ``prefill_valid_tokens`` / ``prefill_padded_tokens`` over
   every prefill-family dispatch and ``decode_live_positions`` /
-  ``decode_grid_positions`` over every decode dispatch.
+  ``decode_grid_positions`` over every decode dispatch, and for a routed
+  model ``moe_expert_rows_needed`` / ``moe_expert_rows_computed``.
 
 This is also the fusion point ROADMAP item 4 (batched spec verification)
 needs: a verify row is just one more ``num_new == k`` row class.
@@ -125,6 +126,10 @@ class AttentionPlan:
         # place via the page walk, which note_dispatch surfaces as the
         # ``latent_decompress_dispatches`` counter.
         self.latent = False
+        # Set by the engine for a model with routed experts:
+        # ``seq_len -> (needed, computed)`` expert MLP rows a token, over
+        # all its expert layers; note_dispatch keeps their census.
+        self.expert_rows = None
 
     # ------------------------------------------------------------------
     # Row classification / shape policy
@@ -234,7 +239,8 @@ class AttentionPlan:
     # Dispatch telemetry
     # ------------------------------------------------------------------
     def note_dispatch(self, kind: str, shape: Tuple[int, ...],
-                      valid_tokens: Optional[int] = None) -> None:
+                      valid_tokens: Optional[int] = None,
+                      active_rows: Optional[int] = None) -> None:
         """Record one attention dispatch: first-seen (kind, shape) is one
         fresh executable (``attn_recompiles``); prefill-family dispatches
         under ragged mode count ``attn_ragged_dispatches``.
@@ -246,7 +252,14 @@ class AttentionPlan:
         for a dense cache, with ``page_size`` 1 then) and ``valid_tokens``
         the host-known context lengths of the active rows, summed: they add
         to ``decode_live_positions`` / ``decode_grid_positions`` (rows x
-        table width x page size: what one step of the dispatch walks)."""
+        table width x page size: what one step of the dispatch walks).
+
+        A routed model (``expert_rows``) also counts the expert MLP rows
+        its tokens need (valid tokens x the experts a token's result takes)
+        and those the program runs (padded tokens x the experts it computes
+        a token): ``moe_expert_rows_needed`` / ``moe_expert_rows_computed``.
+        A decode dispatch's tokens are its ``active_rows`` (of ``shape[0]``
+        rows) times its steps."""
         shape = tuple(int(x) for x in shape)
         self.last_dispatch = (kind, shape, valid_tokens)
         if self.dispatches is not None:
@@ -264,6 +277,15 @@ class AttentionPlan:
             self.metrics.counter("attn_ragged_dispatches")
         if valid_tokens is None:
             return
+        if self.expert_rows is not None:
+            if kind == DECODE:
+                needed, computed = self.expert_rows(1)
+                valid, padded = (active_rows or 0) * shape[1], shape[0] * shape[1]
+            else:
+                needed, computed = self.expert_rows(shape[1])
+                valid, padded = valid_tokens, shape[0] * shape[1]
+            self.metrics.counter("moe_expert_rows_needed", valid * needed)
+            self.metrics.counter("moe_expert_rows_computed", padded * computed)
         if kind == DECODE:
             paged = self.ccfg.kind == "paged"
             self.metrics.counter("decode_live_positions", valid_tokens)

@@ -25,7 +25,8 @@ Weight layout: all projections are stored ``[in_features, out_features]``
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
+import functools
+from typing import Any, Dict, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,13 @@ from ..ops.rotary import RopeAngles, apply_rope, rope_cos_sin, rope_inv_freq
 
 Params = Dict[str, Any]
 
+# A stack is ``ModelConfig.segments``: runs of layers that are all alike,
+# each one ``lax.scan`` over ``params[segment.key]``. A segment's kind names
+# its MLP (the attention is the model's, the same in every segment) and its
+# scope in a device trace. A further kind is a row here, its leaves in
+# :func:`_mlp_params`, and its branch in :func:`_mlp_residual`.
+SEGMENT_SCOPES = {"dense": "dense_stack", "moe": "moe_stack"}
+
 
 # ---------------------------------------------------------------------------
 # Initialization
@@ -47,11 +55,15 @@ Params = Dict[str, Any]
 
 
 def init_layer_params(
-    cfg: ModelConfig, key: jax.Array, num_layers: int, dtype=jnp.bfloat16
+    cfg: ModelConfig, key: jax.Array, num_layers: int, dtype=jnp.bfloat16,
+    kind: Optional[str] = None,
 ) -> Params:
-    """Random (normal 0.02) stacked parameters for ``num_layers`` decoder layers."""
+    """Random (normal 0.02) stacked parameters for ``num_layers`` decoder
+    layers of one segment ``kind`` (default: the kind of the stack's last
+    segment — a Mixtral's or a dense model's only one)."""
     h, d = cfg.hidden_size, cfg.head_dim
-    hq, hkv, inter = cfg.num_heads, cfg.num_kv_heads, cfg.intermediate_size
+    hq, hkv = cfg.num_heads, cfg.num_kv_heads
+    kind = kind or cfg.segments[-1].kind
     keys = jax.random.split(key, 8)
 
     def w(k, *shape):
@@ -65,6 +77,7 @@ def init_layer_params(
         lat = cfg.latent
         dn = lat.nope_head_dim or d
         dr = lat.rope_head_dim
+        dv = lat.v_head_dim or d
         p = {
             "attn_norm": jnp.ones((num_layers, h), dtype),
             "wq": w(keys[0], h, hq * (dn + dr)),
@@ -74,8 +87,8 @@ def init_layer_params(
             # Key up-projection (absorbed into the query at apply time).
             "wk_b": w(keys[2], lat.rank, hq, dn),
             # Value up-projection (applied after the softmax).
-            "wv_b": w(keys[7], lat.rank, hq, d),
-            "wo": w(keys[3], hq * d, h),
+            "wv_b": w(keys[7], lat.rank, hq, dv),
+            "wo": w(keys[3], hq * dv, h),
             "mlp_norm": jnp.ones((num_layers, h), dtype),
         }
     else:
@@ -87,16 +100,7 @@ def init_layer_params(
             "wo": w(keys[3], hq * d, h),
             "mlp_norm": jnp.ones((num_layers, h), dtype),
         }
-    if cfg.num_experts > 0:
-        e = cfg.num_experts
-        p["router"] = w(keys[7], h, e)
-        p["we_g"] = w(keys[4], e, h, inter)
-        p["we_u"] = w(keys[5], e, h, inter)
-        p["we_d"] = w(keys[6], e, inter, h)
-    else:
-        p["wg"] = w(keys[4], h, inter)
-        p["wu"] = w(keys[5], h, inter)
-        p["wd"] = w(keys[6], inter, h)
+    p.update(_mlp_params(cfg, kind, w, keys))
     if cfg.qkv_bias:
         p["bq"] = jnp.zeros((num_layers, hq * d), dtype)
         p["bk"] = jnp.zeros((num_layers, hkv * d), dtype)
@@ -104,15 +108,56 @@ def init_layer_params(
     return p
 
 
+def _mlp_params(cfg: ModelConfig, kind: str, w, keys) -> Params:
+    """The MLP leaves of one segment kind (``w(key, *shape)`` draws a
+    stacked matrix)."""
+    h = cfg.hidden_size
+    if kind == "dense":
+        inter = cfg.intermediate_size
+        return {
+            "wg": w(keys[4], h, inter),
+            "wu": w(keys[5], h, inter),
+            "wd": w(keys[6], inter, h),
+        }
+    if kind != "moe":
+        raise ValueError(f"unknown segment kind {kind!r}")
+    e, f = cfg.num_experts, cfg.expert_intermediate_size
+    p = {
+        "router": w(keys[7], h, e),
+        "we_g": w(keys[4], e, h, f),
+        "we_u": w(keys[5], e, h, f),
+        "we_d": w(keys[6], e, f, h),
+    }
+    more = jax.random.split(keys[7], 4)
+    if cfg.moe_select_bias:
+        # A float32 parameter of the router (selection only, ops/moe.py).
+        p["router_bias"] = w(more[0], e).astype(jnp.float32)
+    if cfg.num_shared_experts:
+        fs = cfg.num_shared_experts * f
+        p["ws_g"] = w(more[1], h, fs)
+        p["ws_u"] = w(more[2], h, fs)
+        p["ws_d"] = w(more[3], fs, h)
+    return p
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
-    """Full-model parameters (embedding + stacked layers + head)."""
+    """Full-model parameters (embedding + one stacked dict a segment +
+    head)."""
     k_embed, k_layers, k_head = jax.random.split(key, 3)
+    segments = cfg.segments
+    seg_keys = (
+        [k_layers] if len(segments) == 1
+        else list(jax.random.split(k_layers, len(segments)))
+    )
     params: Params = {
         "embed": (
             jax.random.normal(k_embed, (cfg.vocab_size, cfg.hidden_size), jnp.float32)
             * 0.02
         ).astype(dtype),
-        "layers": init_layer_params(cfg, k_layers, cfg.num_layers, dtype),
+        **{
+            seg.key: init_layer_params(cfg, k, seg.count, dtype, seg.kind)
+            for seg, k in zip(segments, seg_keys)
+        },
         "final_norm": jnp.ones((cfg.hidden_size,), dtype),
     }
     if not cfg.tie_word_embeddings:
@@ -189,7 +234,9 @@ def _mlp_residual(cfg, p, x, s, num_new):
     b = x.shape[0]
     with jax.named_scope("mlp"):
         h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-        if cfg.num_experts > 0:
+        # The segment's kind, read off its leaves: a routed layer carries
+        # a router.
+        if "router" in p:
             # Bucket-padding positions (>= num_new) must not consume expert
             # capacity in the dispatched prefill path.
             valid = None
@@ -245,6 +292,7 @@ def _latent_attention(
     hq, d = cfg.num_heads, cfg.head_dim
     dn = lat.nope_head_dim or d
     dr = lat.rope_head_dim
+    dv = lat.v_head_dim or d
     rank = lat.rank
 
     q = qmatmul(h, p["wq"]).reshape(b, s, hq, dn + dr)
@@ -267,7 +315,7 @@ def _latent_attention(
     )
     # Deferred value up-projection from the latent-space attention result.
     o = jnp.einsum("bshr,rhd->bshd", attn[..., :rank], p["wv_b"])
-    return o.reshape(b, s, hq * d), new_state
+    return o.reshape(b, s, hq * dv), new_state
 
 
 def _rope_dim(cfg: ModelConfig) -> int:
@@ -314,6 +362,7 @@ def block_apply(
     cache,
     num_new: jnp.ndarray,
     attention_fn=gqa_attention,
+    first_layer: int = 0,
 ):
     """Run a block (contiguous or not) of decoder layers over hidden states.
 
@@ -321,7 +370,10 @@ def block_apply(
     (``/root/reference/distributed_llm_inference/models/llama/model.py:25-76``):
     hidden states in, hidden states out, cache threaded explicitly. ``cache``
     holds stacked per-layer k/v with leading dim equal to this block's layer
-    count; ``lax.scan`` slices one layer's params+cache per step.
+    count; ``lax.scan`` slices one layer's params+cache per step. A
+    segment of a longer stack (``ModelConfig.segments``) passes its
+    ``first_layer``: its ``n`` stacked layers then read and write the cache's
+    layers ``first_layer .. first_layer + n``.
 
     Returns ``(x, cache)`` with the cache's k/v updated (lengths NOT advanced —
     call ``cache.advance(num_new)`` after the last block of the model so that
@@ -334,7 +386,7 @@ def block_apply(
     rope = RopeAngles(inv_freq, cos, sin)
 
     stacks = cache.layer_stacks  # tuple of [L, ...] arrays (k/v [+ scales])
-    num_stack = stacks[0].shape[0]
+    num_stack = layer_params["attn_norm"].shape[0]
 
     # Cache buffers ride the scan CARRY and are updated in place at the layer
     # index — carries are aliased by XLA, so a decode step writes one token
@@ -346,7 +398,8 @@ def block_apply(
     def step(carry, xs):
         x, bufs = carry
         p, idx = xs
-        p = {**p, **_int4_views(whole_w, idx)}
+        # int4 stacks are this block's own: indexed from its first layer
+        p = {**p, **_int4_views(whole_w, idx - first_layer if first_layer else idx)}
         layer_state = tuple(
             jax.lax.dynamic_index_in_dim(b, idx, 0, keepdims=False)
             for b in bufs
@@ -361,7 +414,8 @@ def block_apply(
         return (out, bufs), None
 
     (x, new_stacks), _ = jax.lax.scan(
-        step, (x, stacks), (scanned_w, jnp.arange(num_stack))
+        step, (x, stacks),
+        (scanned_w, jnp.arange(first_layer, first_layer + num_stack)),
     )
     return x, cache.with_layer_stacks(*new_stacks)
 
@@ -393,11 +447,16 @@ def model_apply(
     """
     x = jnp.take(params["embed"], tokens, axis=0)
     if block_fn is None:
-        x, cache = block_apply(
-            cfg, params["layers"], x, cache, num_new, attention_fn
-        )
+        for seg in cfg.segments:
+            with jax.named_scope(SEGMENT_SCOPES[seg.kind]):
+                x, cache = block_apply(
+                    cfg, params[seg.key], x, cache, num_new, attention_fn,
+                    first_layer=seg.start,
+                )
     else:
-        x, cache = block_fn(cfg, params["layers"], x, cache, num_new)
+        # A staged pipeline divides ONE stacked dict among its stages.
+        (seg,) = cfg.segments
+        x, cache = block_fn(cfg, params[seg.key], x, cache, num_new)
     if head == "none":
         return None, cache.advance(num_new)
     if head == "last":
@@ -505,7 +564,8 @@ def multi_decode_apply(
     # per-layer tail state.
     whole_tail = getattr(cache, "tail_in_kernel", False)
     view_num_big = num_big + 1 if whole_big else num_big
-    whole_w, scanned_w = _split_int4_stacks(params["layers"])
+    segments = cfg.segments
+    split_w = [_split_int4_stacks(params[seg.key]) for seg in segments]
 
     def token_step(carry, i):
         tokens, tail, tail_len, num_new, state = carry
@@ -515,11 +575,14 @@ def multi_decode_apply(
         cos, sin = rope_cos_sin(q_pos, inv_freq)
         rope = RopeAngles(inv_freq, cos, sin)
 
-        def layer_step(carry2, xs):
+        def layer_step(whole_w, first_layer, carry2, xs):
             x, tail_bufs = carry2
             p = xs[0]
             idx = xs[-1]
-            p = {**p, **_int4_views(whole_w, idx)}
+            # int4 stacks are a segment's own: indexed from its first layer
+            p = {**p, **_int4_views(
+                whole_w, idx - first_layer if first_layer else idx
+            )}
             if whole_big:
                 big_state = (*big_stacks, idx)
             else:
@@ -544,12 +607,19 @@ def multi_decode_apply(
                 )
             return (out, tail_bufs), None
 
-        (x, tail), _ = jax.lax.scan(
-            layer_step, (x, tail),
-            (scanned_w,
-             *(() if whole_big else big_stacks),
-             jnp.arange(num_stack)),
-        )
+        for seg, (whole_w, scanned_w) in zip(segments, split_w):
+            # The read-only big planes ride a segment's scan as ITS layers'
+            # slice (the whole of them for a one-segment stack).
+            lo, hi = seg.start, seg.start + seg.count
+            seg_big = () if whole_big else (
+                big_stacks if seg.count == num_stack
+                else tuple(b[lo:hi] for b in big_stacks)
+            )
+            with jax.named_scope(SEGMENT_SCOPES[seg.kind]):
+                (x, tail), _ = jax.lax.scan(
+                    functools.partial(layer_step, whole_w, lo), (x, tail),
+                    (scanned_w, *seg_big, jnp.arange(lo, hi)),
+                )
         logits = apply_head(cfg, params, x)
         next_tokens, next_num_new, state, emit = step_fn(i, logits[:, 0], state)
         tail_len = tail_len + num_new
@@ -622,36 +692,79 @@ def convert_hf_layer(
         if transpose:
             arr = arr.T
         out[name] = arr.astype(jnp.dtype(dtype))
-    # MLA (DeepSeek-V2-style) latent attention: the joint kv_b_proj
+    # MLA (DeepSeek-V2/V3) latent attention: the joint kv_b_proj
     # [Hq*(dn+dv), rank] splits into the key up-projection (absorbed into
     # the query) and the value up-projection (applied post-softmax).
     kvb_key = prefix + "self_attn.kv_b_proj.weight"
     if cfg.use_latent and kvb_key in state:
         lat = cfg.latent
         dn = lat.nope_head_dim or cfg.head_dim
+        dr = lat.rope_head_dim
+        dv = lat.v_head_dim or cfg.head_dim
         kvb = np.asarray(state[kvb_key]).T.reshape(
-            lat.rank, cfg.num_heads, dn + cfg.head_dim
+            lat.rank, cfg.num_heads, dn + dv
         )
         out["wk_b"] = kvb[..., :dn].astype(jnp.dtype(dtype))
         out["wv_b"] = kvb[..., dn:].astype(jnp.dtype(dtype))
+        # The checkpoint's rotary slices hold (even, odd) pairs side by
+        # side (``apply_rotary_pos_emb`` de-interleaves them before
+        # ``rotate_half``); ``ops/rotary.py`` rotates halves, so the rope
+        # columns are stored de-interleaved once, here.
+        halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
+        if "wq" in out:
+            wq = out["wq"].reshape(-1, cfg.num_heads, dn + dr)
+            out["wq"] = np.concatenate(
+                [wq[..., :dn], wq[..., dn:][..., halves]], -1
+            ).reshape(out["wq"].shape)
         akey = prefix + "self_attn.kv_a_proj_with_mqa.weight"
         if akey in state:
-            out["wkv_a"] = np.asarray(state[akey]).T.astype(jnp.dtype(dtype))
+            wkv_a = np.asarray(state[akey]).T
+            out["wkv_a"] = np.concatenate(
+                [wkv_a[:, : lat.rank], wkv_a[:, lat.rank :][:, halves]], -1
+            ).astype(jnp.dtype(dtype))
         nkey = prefix + "self_attn.kv_a_layernorm.weight"
         if nkey in state:
             out["kv_norm"] = np.asarray(state[nkey]).astype(jnp.dtype(dtype))
-    # Mixtral MoE: gate (router) + per-expert w1/w3/w2 → stacked [E, …].
-    gate_key = prefix + "block_sparse_moe.gate.weight"
-    if gate_key in state and cfg.num_experts > 0:
-        out["router"] = np.asarray(state[gate_key]).T.astype(jnp.dtype(dtype))
-        ep = prefix + "block_sparse_moe.experts.{e}.{w}.weight"
-        stack = lambda w: np.stack([
-            np.asarray(state[ep.format(e=e, w=w)]).T
-            for e in range(cfg.num_experts)
-        ]).astype(jnp.dtype(dtype))
-        out["we_g"] = stack("w1")  # gate_proj
-        out["we_d"] = stack("w2")  # down_proj
-        out["we_u"] = stack("w3")  # up_proj
+    if cfg.num_experts > 0:
+        out.update(_convert_hf_experts(cfg, state, prefix, jnp.dtype(dtype)))
+    return out
+
+
+def _convert_hf_experts(cfg, state, prefix, dtype) -> Dict[str, np.ndarray]:
+    """A routed layer's gate and expert tensors, stacked ``[E, …]``:
+    Mixtral's ``block_sparse_moe.*`` (w1/w3/w2) or DeepSeek-V2/V3's
+    ``mlp.gate`` / ``mlp.experts.{e}`` / ``mlp.shared_experts``. Empty for
+    a layer that has neither (a leading dense layer: its ``mlp.*_proj`` are
+    in ``_LAYER_KEY_MAP``)."""
+    def t(key):
+        return np.asarray(state[key]).T.astype(dtype)
+
+    def stack(pattern):
+        return np.stack([
+            t(pattern.format(e=e)) for e in range(cfg.num_experts)
+        ])
+
+    out: Dict[str, np.ndarray] = {}
+    if prefix + "block_sparse_moe.gate.weight" in state:
+        ep = prefix + "block_sparse_moe.experts.{e}."
+        out["router"] = t(prefix + "block_sparse_moe.gate.weight")
+        out["we_g"] = stack(ep + "w1.weight")  # gate_proj
+        out["we_d"] = stack(ep + "w2.weight")  # down_proj
+        out["we_u"] = stack(ep + "w3.weight")  # up_proj
+    elif prefix + "mlp.gate.weight" in state:
+        ep = prefix + "mlp.experts.{e}."
+        out["router"] = t(prefix + "mlp.gate.weight")
+        bias = prefix + "mlp.gate.e_score_correction_bias"
+        if bias in state:
+            out["router_bias"] = np.asarray(state[bias]).astype(np.float32)
+        out["we_g"] = stack(ep + "gate_proj.weight")
+        out["we_u"] = stack(ep + "up_proj.weight")
+        out["we_d"] = stack(ep + "down_proj.weight")
+        sp = prefix + "mlp.shared_experts."
+        if sp + "gate_proj.weight" in state:
+            out["ws_g"] = t(sp + "gate_proj.weight")
+            out["ws_u"] = t(sp + "up_proj.weight")
+            out["ws_d"] = t(sp + "down_proj.weight")
     return out
 
 
@@ -662,13 +775,16 @@ def convert_hf_state_dict(
     dtype=jnp.bfloat16,
     consume: bool = False,
 ) -> Params:
-    """Convert an HF Llama/Mistral/Qwen2 state dict into our param pytree.
+    """Convert an HF state dict (every family of ``models/registry.py``)
+    into our param pytree.
 
     ``layer_ids`` selects an arbitrary list of layers (the block a node
     serves), mirroring ``LlamaBlock(config, layer_ids)``
-    (``/root/reference/distributed_llm_inference/models/llama/model.py:17``).
-    When ``layer_ids`` is None, converts the full model including embeddings
-    and head.
+    (``/root/reference/distributed_llm_inference/models/llama/model.py:17``):
+    one stacked dict under ``"layers"``, so the block lies inside one
+    segment of the stack. When ``layer_ids`` is None, converts the full
+    model including embeddings and head, one stacked dict a segment
+    (``ModelConfig.segments``).
 
     The stacked layer leaves stay HOST (numpy) arrays: whoever serves them
     places them (``InferenceEngine`` / ``BlockBackend``), one leaf at a
@@ -682,24 +798,33 @@ def convert_hf_state_dict(
     three times over (state, per-layer copies, stacks: 35 GiB and counting
     for a 14.5 GB checkpoint on a 40 GiB host — my chip run, PR 21).
     """
-    ids: List[int] = list(layer_ids) if layer_ids is not None else list(
-        range(cfg.num_layers)
-    )
-    per_layer = []
-    for i in ids:
-        per_layer.append(convert_hf_layer(cfg, state, i, dtype))
-        if consume:
-            prefix = f"model.layers.{i}."
-            for key in [k for k in state if k.startswith(prefix)]:
-                del state[key]
-    # pop: each name's per-layer copies are freed as soon as they are stacked
-    stacked = {
-        name: np.stack([layer.pop(name) for layer in per_layer])
-        for name in list(per_layer[0])
+    def stacked(ids):
+        per_layer = []
+        for i in ids:
+            per_layer.append(convert_hf_layer(cfg, state, i, dtype))
+            if consume:
+                prefix = f"model.layers.{i}."
+                for key in [k for k in state if k.startswith(prefix)]:
+                    del state[key]
+        names = set(per_layer[0])
+        if any(set(layer) != names for layer in per_layer):
+            raise ValueError(
+                f"layers {list(ids)} are not all alike (a block lies inside "
+                f"one segment of the stack: {cfg.segments})"
+            )
+        # pop: each name's per-layer copies are freed as soon as stacked
+        return {
+            name: np.stack([layer.pop(name) for layer in per_layer])
+            for name in list(per_layer[0])
+        }
+
+    if layer_ids is not None:
+        return {"layers": stacked(list(layer_ids))}
+    params: Params = {
+        seg.key: stacked(range(seg.start, seg.start + seg.count))
+        for seg in cfg.segments
     }
-    params: Params = {"layers": stacked}
-    if layer_ids is None:
-        params.update(convert_hf_non_layer(cfg, state, dtype))
+    params.update(convert_hf_non_layer(cfg, state, dtype))
     return params
 
 

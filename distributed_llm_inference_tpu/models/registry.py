@@ -16,9 +16,18 @@ Families:
   configs) tied embeddings.
 * ``mixtral`` — + MoE MLP (``num_experts``/``num_experts_per_tok``), expert
   parallelism over the ``ep`` mesh axis (``ops/moe.py``).
-* ``mla``     — latent (low-rank) KV attention (``ModelConfig.latent``):
-  DeepSeek-V2-style MLA with a shared per-token KV latent and a decoupled
-  rotary key, served through the latent paged cache (``cache/latent.py``).
+* ``mla``     — the DeepSeek-V2/V3 block (``deepseek_v2``, ``deepseek_v3``:
+  DeepSeek-V2-Lite, Moonlight-16B-A3B): latent (low-rank) KV attention
+  (``ModelConfig.latent``: a shared per-token KV latent and a decoupled
+  rotary key, served through the latent paged cache, ``cache/latent.py``)
+  and, where the config has them, routed experts beside shared ones behind
+  leading dense layers (``ModelConfig.segments``), with softmax or sigmoid
+  routing (``ops/moe.py:route``). A latent model without experts is the
+  same entry.
+
+The switches are independent: a family may permit any of them together
+(``mla`` permits experts AND requires the latent); what a family does not
+permit is refused by :func:`validate_config`.
 """
 
 from __future__ import annotations
@@ -42,8 +51,9 @@ class ModelFamily:
     qkv_bias: bool = False
     moe: bool = False
     # Latent (MLA) KV attention: the family both permits AND requires
-    # ``ModelConfig.latent`` — the latent decoder path has its own
-    # projection set, so a family is one or the other, never both.
+    # ``ModelConfig.latent`` — the latent attention has its own projection
+    # set, so a family's attention is one or the other; its MLP switch
+    # (``moe``) is independent of it.
     latent: bool = False
     # The compute/conversion program (shared stack for all current families).
     apply: Callable = llama.model_apply
@@ -60,7 +70,8 @@ FAMILIES: Dict[str, ModelFamily] = {
         ModelFamily("qwen2", ("qwen2",), sliding_window=True, qkv_bias=True),
         ModelFamily("mixtral", ("mixtral",), sliding_window=True, moe=True),
         ModelFamily(
-            "mla", ("mla", "deepseek_v2", "deepseek_v3"), latent=True
+            "mla", ("mla", "deepseek_v2", "deepseek_v3"), latent=True,
+            moe=True,
         ),
     )
 }
@@ -100,6 +111,22 @@ def validate_config(cfg: ModelConfig) -> ModelFamily:
             f"family {fam.name!r} is dense but config has "
             f"num_experts={cfg.num_experts}"
         )
+    if cfg.num_experts == 0 and (
+        cfg.num_shared_experts or cfg.first_dense_layers
+    ):
+        raise ValueError(
+            "shared experts and leading dense layers belong to a stack "
+            "with routed experts (num_experts is 0)"
+        )
+    if cfg.num_experts > 0 and not (
+        0 <= cfg.first_dense_layers < cfg.num_layers
+    ):
+        raise ValueError(
+            f"first_dense_layers={cfg.first_dense_layers} leaves no expert "
+            f"layer among num_layers={cfg.num_layers}"
+        )
+    if cfg.moe_scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
     if cfg.qkv_bias and not fam.qkv_bias:
         raise ValueError(f"family {fam.name!r} does not use qkv_bias")
     if cfg.latent is not None and not fam.latent:

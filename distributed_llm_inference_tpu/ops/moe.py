@@ -1,14 +1,16 @@
-"""Mixture-of-experts MLP (Mixtral-style) with expert-parallel sharding.
+"""Mixture-of-experts MLP with expert-parallel sharding: routed experts,
+optional shared experts, and the routing rule the config names.
 
 The reference has no MoE layers — it only reuses hivemind's *moe.server*
 machinery for serving scaffolding (SURVEY §2.3;
 ``/root/reference/distributed_llm_inference/server/backend.py:5``). MoE here is
-a capability extension required for the Mixtral model family.
+a capability extension required for the Mixtral and DeepSeek-V2/V3 families.
 
-Routing follows Mixtral: softmax over ALL expert logits in fp32, top-k
-selection, renormalize the selected probabilities.
-
-Two compute strategies, both all-static shapes:
+Routing is ONE function (:func:`route`) whose rule the config chooses:
+Mixtral's (softmax over ALL expert logits in fp32, top-k, renormalise) and
+DeepSeek-V3's (sigmoid scores, selection by score plus a per-expert bias,
+weights from the scores alone, normalised and scaled). Both compute
+strategies sit behind it, all-static shapes:
 
 * **dense-combine** (decode, S == 1) — every expert processes every token and
   a ``[B, S, E]`` combine matrix (zero off the top-k) weights the outputs.
@@ -19,6 +21,10 @@ Two compute strategies, both all-static shapes:
   experts; each expert computes only its capacity-bounded slice
   (``moe_mlp_dispatch``), cutting MLP FLOPs by E/(k·capacity_factor). The
   whole path is gathers (a scatter would serialize on TPU).
+
+Shared experts (``p["ws_g"]``/``ws_u``/``ws_d``, present where the config
+has them) are one SwiGLU MLP every token passes through, added to the routed
+sum under the scope ``moe_shared``.
 """
 
 from __future__ import annotations
@@ -31,24 +37,74 @@ import jax.numpy as jnp
 from ..config import ModelConfig
 from . import quant
 
-__all__ = ["moe_mlp", "router_weights"]
+__all__ = ["moe_mlp", "route", "router_weights", "expert_rows_per_token"]
+
+
+def route(cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray, bias=None):
+    """The routing rule, as the config states it. ``x``: ``[..., H]``;
+    ``router``: ``[H, E]``; ``bias``: ``[E]`` or None. Returns ``(weights
+    [..., k] fp32, experts [..., k] int32)``.
+
+    Scores over ALL experts in fp32: softmax (Mixtral, DeepSeek-V2) or
+    sigmoid (DeepSeek-V3 ``MoEGate``). Selection: the ``k`` largest of the
+    scores, or of scores PLUS ``bias`` where the checkpoint carries one
+    (``e_score_correction_bias``, ``topk_method`` "noaux_tc"; groups of one
+    make the published group step the identity). The bias chooses and is
+    never weighed: the weights are the selected SCORES, divided by their
+    sum (``moe_norm_topk``), times ``moe_routed_scale``.
+    """
+    k = cfg.num_experts_per_tok
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    if cfg.moe_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top_w, top_i = jax.lax.top_k(scores, k)
+    else:
+        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    if cfg.moe_norm_topk:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if cfg.moe_routed_scale != 1.0:
+        top_w = top_w * cfg.moe_routed_scale
+    return top_w, top_i
 
 
 def router_weights(
-    cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray
+    cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray, bias=None
 ) -> jnp.ndarray:
-    """Mixtral routing: fp32 softmax over all experts → top-k → renormalize.
+    """:func:`route` as the dense combine matrix.
 
-    ``x``: ``[B, S, H]``; ``router``: ``[H, E]``. Returns the dense combine
-    matrix ``[B, S, E]`` (sums to 1 over the selected experts, 0 elsewhere).
+    ``x``: ``[B, S, H]``; ``router``: ``[H, E]``. Returns ``[B, S, E]``: a
+    token's routing weights at its selected experts, 0 elsewhere.
     """
     with jax.named_scope("moe_router"):
-        logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p, top_i = route(cfg, x, router, bias)
         one_hot = jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
         return jnp.einsum("bsk,bske->bse", top_p, one_hot)
+
+
+def expert_rows_per_token(cfg: ModelConfig, seq_len: int):
+    """``(needed, computed)``: expert MLPs one token's result needs in one
+    expert layer (its selected experts and the shared ones) and how many
+    the program runs for it in a dispatch ``seq_len`` wide (every routed
+    expert under dense-combine; its capacity's share under sorted
+    dispatch). The census behind ``moe_expert_rows_*``."""
+    shared = cfg.num_shared_experts
+    k = cfg.num_experts_per_tok
+    if cfg.moe_capacity_factor is not None and seq_len >= 16:
+        return k + shared, k * cfg.moe_capacity_factor + shared
+    return k + shared, cfg.num_experts + shared
+
+
+def _shared_experts(p, x: jnp.ndarray):
+    """The shared experts: one SwiGLU MLP over every token."""
+    with jax.named_scope("moe_shared"):
+        return quant.matmul(
+            jax.nn.silu(quant.matmul(x, p["ws_g"])) * quant.matmul(x, p["ws_u"]),
+            p["ws_d"],
+        )
 
 
 def moe_mlp(
@@ -60,7 +116,9 @@ def moe_mlp(
     """SwiGLU expert MLPs + weighted combine.
 
     ``p["router"]``: ``[H, E]``; ``p["we_g"]``/``p["we_u"]``: ``[E, H, F]``;
-    ``p["we_d"]``: ``[E, F, H]`` (E shardable over ``ep``, F over ``tp``).
+    ``p["we_d"]``: ``[E, F, H]`` (E shardable over ``ep``, F over ``tp``);
+    ``p["router_bias"]`` ``[E]`` and the shared experts' ``p["ws_*"]`` where
+    the model has them (:func:`route`, :func:`_shared_experts`).
 
     Dense-combine is the default everywhere: exact, shape-static, and every
     token's output independent of co-batched rows (decode and verify steps
@@ -73,14 +131,20 @@ def moe_mlp(
     must not consume expert capacity in the dispatched path.
     """
     if cfg.moe_capacity_factor is not None and x.shape[1] >= 16:
-        return moe_mlp_dispatch(cfg, p, x, cfg.moe_capacity_factor, valid)
-    combine = router_weights(cfg, x, p["router"]).astype(x.dtype)
-    with jax.named_scope("moe_experts"):
-        t = quant.einsum("bsh,ehf->bsef", x, p["we_g"])
-        u = quant.einsum("bsh,ehf->bsef", x, p["we_u"])
-        y = quant.einsum("bsef,efh->bseh", jax.nn.silu(t) * u, p["we_d"])
-    with jax.named_scope("moe_combine"):
-        return jnp.einsum("bse,bseh->bsh", combine, y)
+        out = moe_mlp_dispatch(cfg, p, x, cfg.moe_capacity_factor, valid)
+    else:
+        combine = router_weights(
+            cfg, x, p["router"], p.get("router_bias")
+        ).astype(x.dtype)
+        with jax.named_scope("moe_experts"):
+            t = quant.einsum("bsh,ehf->bsef", x, p["we_g"])
+            u = quant.einsum("bsh,ehf->bsef", x, p["we_u"])
+            y = quant.einsum("bsef,efh->bseh", jax.nn.silu(t) * u, p["we_d"])
+        with jax.named_scope("moe_combine"):
+            out = jnp.einsum("bse,bseh->bsh", combine, y)
+    if "ws_g" in p:
+        out = out + _shared_experts(p, x)
+    return out
 
 
 def _expert_matmul(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
@@ -130,10 +194,7 @@ def moe_mlp_dispatch(
     xf = x.reshape(n, h)
 
     with jax.named_scope("moe_router"):
-        logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_i = jax.lax.top_k(probs, k)
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p, top_i = route(cfg, xf, p["router"], p.get("router_bias"))
 
     pair_e = top_i.reshape(-1)                                  # [N*k]
     pair_t = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)      # [N*k]

@@ -175,6 +175,7 @@ def paged_attention(
     interpret: Optional[bool] = None,
     q_positions: Optional[jnp.ndarray] = None,
     return_stats: bool = False,
+    name: str = "paged_attention",
 ):
     """Decode attention straight over the page pool.
 
@@ -249,7 +250,7 @@ def paged_attention(
     )
     out, m, l = pl.pallas_call(
         kernel,
-        name="paged_attention",
+        name=name,
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv * g, 128), jnp.float32),
@@ -373,6 +374,7 @@ def quantized_paged_attention(
     interpret: Optional[bool] = None,
     q_positions: Optional[jnp.ndarray] = None,
     return_stats: bool = False,
+    name: str = "quantized_paged_attention",
 ):
     """As :func:`paged_attention` over int8 pages with per-(slot, head)
     scale planes (``ks_pages``/``vs_pages``: ``[P, Hkv, page_size]`` f32)."""
@@ -441,7 +443,7 @@ def quantized_paged_attention(
     )
     out, m, l = pl.pallas_call(
         kernel,
-        name="quantized_paged_attention",
+        name=name,
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv * g, 128), jnp.float32),
@@ -478,6 +480,7 @@ def latent_paged_attention(
         q, c_pages, c_pages, page_table, kv_lengths, scale=scale,
         sliding_window=sliding_window, interpret=interpret,
         q_positions=q_positions, return_stats=return_stats,
+        name="latent_paged_attention",
     )
 
 
@@ -499,6 +502,7 @@ def quantized_latent_paged_attention(
         q, c_pages, cs_pages, c_pages, cs_pages, page_table, kv_lengths,
         scale=scale, sliding_window=sliding_window, interpret=interpret,
         q_positions=q_positions, return_stats=return_stats,
+        name="quantized_latent_paged_attention",
     )
 
 

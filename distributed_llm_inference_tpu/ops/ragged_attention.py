@@ -294,6 +294,7 @@ def ragged_paged_attention(
     sliding_window: Optional[int] = None,
     block_q: Optional[int] = None,
     interpret: Optional[bool] = None,
+    name: str = "ragged_paged_attention",
 ):
     """Ragged mixed-phase attention straight over the page pool.
 
@@ -366,7 +367,7 @@ def ragged_paged_attention(
     )
     out = pl.pallas_call(
         kernel,
-        name="ragged_paged_attention",
+        name=name,
         out_shape=jax.ShapeDtypeStruct((b, s_pad, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -390,6 +391,7 @@ def quantized_ragged_paged_attention(
     sliding_window: Optional[int] = None,
     block_q: Optional[int] = None,
     interpret: Optional[bool] = None,
+    name: str = "quantized_ragged_paged_attention",
 ):
     """As :func:`ragged_paged_attention` over int8 pages with per-(slot,
     head) scale planes (``ks_pages``/``vs_pages``: ``[P, Hkv, page_size]``
@@ -457,7 +459,7 @@ def quantized_ragged_paged_attention(
     )
     out = pl.pallas_call(
         kernel,
-        name="quantized_ragged_paged_attention",
+        name=name,
         out_shape=jax.ShapeDtypeStruct((b, s_pad, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -486,7 +488,7 @@ def latent_ragged_paged_attention(
     slice by the model); ``q``: the absorbed query ``[B, S, Hq,
     lat_dim]``. Because the key up-projection is folded into ``q`` and
     the value up-projection is deferred past the softmax
-    (``models/llama.py:_latent_decoder_layer``), attention runs with
+    (``models/llama.py:_latent_attention``), attention runs with
     ``K = V =`` the STORED latent: the kernel's existing page-table walk
     IS the latent→K/V decompression fusion — no per-token K/V ever
     materializes, on-chip or off. Output: ``[B, S, Hq, lat_dim]`` whose
@@ -496,6 +498,7 @@ def latent_ragged_paged_attention(
         q, c_pages, c_pages, page_table, kv_lengths, num_new,
         q_start=q_start, scale=scale, sliding_window=sliding_window,
         block_q=block_q, interpret=interpret,
+        name="latent_ragged_paged_attention",
     )
 
 
@@ -519,6 +522,7 @@ def quantized_latent_ragged_paged_attention(
         q, c_pages, cs_pages, c_pages, cs_pages, page_table, kv_lengths,
         num_new, q_start=q_start, scale=scale,
         sliding_window=sliding_window, block_q=block_q, interpret=interpret,
+        name="quantized_latent_ragged_paged_attention",
     )
 
 

@@ -304,7 +304,7 @@ def _cached_load(build, model_dir, cache_dir, layer_ids, dtype, resolve, tag):
             # Layer stacks stay on the host, as from a fresh conversion
             # (llama.convert_hf_state_dict): the consumer places them.
             return _unflatten_params({
-                k: v if k.startswith("layers.") else jnp.asarray(v)
+                k: v if k.startswith("layers") else jnp.asarray(v)
                 for k, v in flat.items()
             })
     params = build()

@@ -67,6 +67,14 @@ METRICS = {
     "prefill_padded_tokens": ("counter", "Rows x pad width of the same dispatches"),
     "decode_live_positions": ("counter", "Context positions of active decode rows"),
     "decode_grid_positions": ("counter", "Rows x table width x page size walked"),
+    # routed experts (ops/moe.py:expert_rows_per_token): needed / computed
+    # over an interval is pad waste times the compute strategy's waste
+    "moe_expert_rows_needed": (
+        "counter", "Expert MLP rows the dispatches' valid tokens need"
+    ),
+    "moe_expert_rows_computed": (
+        "counter", "Expert MLP rows the dispatches' padded tokens run"
+    ),
     "decode_tokens": ("counter", "Tokens emitted by decode"),
     "cache_growths": ("counter", "KV cache reallocations"),
     # latent (MLA) KV compression (cache/latent.py)

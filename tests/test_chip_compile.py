@@ -133,6 +133,52 @@ def test_fused_int8_paged_decode_kernel(chip, rows, width, pages, window):
     )
 
 
+# moonlight-16b-a3b.reason1k: 16 query heads on ONE latent head, the stored
+# row 512 + 64 = 576 wide (not a multiple of the 128 lanes), int8 with a
+# float32 scale a token, 1792 pages of 64, 32 slots.
+LAT_HQ, LAT_W, LAT_PAGES = 16, 576, 1792
+
+
+def _latent_pool(s, pool):
+    pages = s((LAT_PAGES, 1, PS, LAT_W), I8 if pool == "int8" else F32)
+    return (pages, s((LAT_PAGES, 1, PS), F32)) if pool == "int8" else (pages,)
+
+
+@pytest.mark.parametrize("width", [59, SLOTS])   # the cell's pinned table; the cap
+@pytest.mark.parametrize("pool", ["int8", "f32"])
+def test_latent_decode_kernels_at_moonlights_shapes(chip, pool, width):
+    """Both latent decode wrappers take the 576-wide row as it is stored:
+    Mosaic pads the lanes itself, nothing had to change in the layout."""
+    s, b = chip, 32
+    kernel = (
+        pa.quantized_latent_paged_attention if pool == "int8"
+        else pa.latent_paged_attention
+    )
+    _compiles_with_kernel(
+        lambda q, *a: kernel(q, *a, scale=192 ** -0.5, interpret=False),
+        s((b, 1, LAT_HQ, LAT_W), jnp.bfloat16), *_latent_pool(s, pool),
+        s((b, width), I32), s((b,), I32),
+    )
+
+
+@pytest.mark.parametrize("rows", [1, 2, 4])      # one admission; groups of 2, 4
+@pytest.mark.parametrize("pool", ["int8", "f32"])
+def test_latent_ragged_prefill_kernels_at_moonlights_shapes(chip, pool, rows):
+    """A 2048-wide prefill dispatch of 1, 2 and 4 rows, with the block_q the
+    kernel picks for 16 heads of 576 (its VMEM estimate rounds the row up to
+    640 lanes)."""
+    s = chip
+    kernel = (
+        ra.quantized_latent_ragged_paged_attention if pool == "int8"
+        else ra.latent_ragged_paged_attention
+    )
+    _compiles_with_kernel(
+        lambda q, *a: kernel(q, *a, scale=192 ** -0.5, interpret=False),
+        s((rows, 2048, LAT_HQ, LAT_W), jnp.bfloat16), *_latent_pool(s, pool),
+        s((rows, 59), I32), s((rows,), I32), s((rows,), I32),
+    )
+
+
 def test_flash_attention_kernel(chip):
     s, seq, t = chip, 2048, 4096
     bf16 = jnp.bfloat16

@@ -35,16 +35,46 @@ def pallas_calls():
                     yield os.path.basename(path), node.lineno, fn.name, node
 
 
+def names_through_a_parameter(fn_name):
+    """The names a kernel takes through its wrapper's ``name`` parameter:
+    that parameter's default and every constant a caller under ``ops/``
+    passes for it (the latent wrappers call the per-head wrappers under
+    their own names)."""
+    out = []
+    for path in sorted(glob.glob(os.path.join(OPS, "*.py"))):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if isinstance(node, ast.FunctionDef) and node.name == fn_name:
+                a = node.args
+                named = dict(zip(
+                    [x.arg for x in a.args[len(a.args) - len(a.defaults):]],
+                    a.defaults,
+                ))
+                out.append(named["name"])
+            if (isinstance(node, ast.Call)
+                    and getattr(node.func, "id", None) == fn_name):
+                out += [k.value for k in node.keywords if k.arg == "name"]
+    return out
+
+
 def test_every_pallas_call_has_a_name_of_its_own():
     names = {}
     for path, line, fn, call in pallas_calls():
         kw = {k.arg: k.value for k in call.keywords}
         assert "name" in kw, f"{path}:{line} pallas_call without name="
-        # a constant: the same whatever the block sizes and shapes
-        assert isinstance(kw["name"], ast.Constant), f"{path}:{line}"
-        assert isinstance(kw["name"].value, str) and kw["name"].value
-        names.setdefault(kw["name"].value, []).append(f"{path}:{line} {fn}")
-    assert len(names) >= 14
+        given = [kw["name"]]
+        if isinstance(kw["name"], ast.Name):
+            assert kw["name"].id == "name", f"{path}:{line}"
+            given = names_through_a_parameter(fn)
+        for value in given:
+            # a constant: the same whatever the block sizes and shapes
+            assert isinstance(value, ast.Constant), f"{path}:{line}"
+            assert isinstance(value.value, str) and value.value
+            names.setdefault(value.value, []).append(f"{path}:{line} {fn}")
+    assert len(names) >= 18
+    for latent in ("latent_paged_attention", "quantized_latent_paged_attention",
+                   "latent_ragged_paged_attention",
+                   "quantized_latent_ragged_paged_attention"):
+        assert latent in names, latent
     shared = {n: at for n, at in names.items() if len(at) > 1}
     assert not shared, shared
 
@@ -94,3 +124,28 @@ def test_a_lowered_moe_forward_carries_the_expert_scopes():
     text = lowered_text(cfg)
     for scope in ("mlp/moe_router", "mlp/moe_experts", "mlp/moe_combine"):
         assert scoped(text, scope), scope
+
+
+def test_a_lowered_two_segment_forward_carries_a_scope_a_segment():
+    """A leading dense layer before routed ones with a shared expert: one
+    scope a segment, and ``moe_shared`` beside the three of a routed MLP."""
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=3,
+        num_heads=2, num_kv_heads=2, head_dim=16, num_experts=4,
+        num_experts_per_tok=2, moe_intermediate_size=16, num_shared_experts=1,
+        first_dense_layers=1, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_routed_scale=2.0, family="mixtral",
+    )
+    text = lowered_text(cfg)
+    for scope in ("dense_stack", "moe_stack", "mlp/moe_router",
+                  "mlp/moe_experts", "mlp/moe_combine", "mlp/moe_shared"):
+        assert scoped(text, scope), scope
+
+
+def test_a_one_segment_forward_is_in_its_segments_scope():
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16,
+    )
+    text = lowered_text(cfg)
+    assert scoped(text, "dense_stack") and not scoped(text, "moe_stack")
