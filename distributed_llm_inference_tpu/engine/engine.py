@@ -47,6 +47,7 @@ from ..cache.sink import QuantizedSinkKVCache, SinkKVCache
 _SINK_KINDS = (SinkKVCache, QuantizedSinkKVCache)
 from ..config import CacheConfig, EngineConfig, ModelConfig, PrefixConfig
 from ..models import llama
+from ..ops.ragged_attention import _block_q
 from ..utils.metrics import Metrics
 from ..utils.tracing import FlightRecorder, Span
 from .plan import AttentionPlan
@@ -249,6 +250,7 @@ class InferenceEngine:
                     "sharding of the latent pool is not implemented)"
                 )
         self.plan.latent = self._latent
+        self.plan.sliding_window = cfg.sliding_window
         if cfg.num_experts > 0:
             from ..ops.moe import expert_rows_per_token
 
@@ -328,6 +330,15 @@ class InferenceEngine:
                     use_ragged=_sel.use_ragged,
                 )
             self.allocator = PageAllocator(cc.num_pages)
+            # The q block the ragged kernel picks at a pad width over THIS
+            # pool (heads, stored width, element size): the plan's census
+            # of live tiles (note_dispatch) walks that kernel's grid.
+            _, _, pool_heads, _, pool_width = self.cache.k_pages.shape
+            pool_itemsize = self.cache.k_pages.dtype.itemsize
+            self.plan.ragged_block_q = lambda width: _block_q(
+                width, cfg.num_heads, pool_heads, pool_width, cc.page_size,
+                dtype.itemsize, pool_itemsize,
+            )
             # Stored KV footprint per token across all layers — the number
             # the latent cache exists to shrink (bench.py --phase kvbytes
             # reads it back for the latent-vs-baseline comparison).
@@ -1390,6 +1401,17 @@ class InferenceEngine:
         if pending is not None:
             live += int(pending[active].sum())
         return live
+
+    def _note_prefill(self, kind: str, shape, row_spans) -> None:
+        """The census of a prefill-family dispatch (``plan.note_dispatch``):
+        ``row_spans`` holds a ``(first position, tokens)`` pair for every
+        real row, which is what the ragged kernel sees as ``q_start`` and
+        ``num_new``; over a paged cache the table's width goes with it."""
+        paged = self.ccfg.kind == "paged"
+        self.plan.note_dispatch(
+            kind, shape, sum(n for _, n in row_spans), row_spans=row_spans,
+            table_width=self.cache.page_table.shape[1] if paged else None,
+        )
 
     def _note_admitted(self, s: Session) -> None:
         """The admission dispatch takes ``s`` (called right before its
@@ -2498,7 +2520,9 @@ class InferenceEngine:
             tokens[i, : len(s.prompt)] = s.prompt
             opts[i] = s.options
         sp = SamplingParams.stack(opts)
-        self.plan.note_dispatch("prefill", (nr, width), int(n_valid.sum()))
+        self._note_prefill(
+            "prefill", (nr, width), [(0, int(n)) for n in n_valid[:k]]
+        )
         for s in group:
             self._note_admitted(s)
         sub = self._fresh_sub(nr)
@@ -2632,7 +2656,7 @@ class InferenceEngine:
         while len(prompt) - offset > stride:
             chunk = prompt[offset : offset + stride]
             padded = jnp.asarray(chunk)[None, :]
-            self.plan.note_dispatch("chunk", (1, stride), len(chunk))
+            self._note_prefill("chunk", (1, stride), [(offset, len(chunk))])
             self.cache = self._prefill_ns(
                 self.params, padded, self.cache, s.slot, jnp.int32(len(chunk))
             )
@@ -2641,7 +2665,7 @@ class InferenceEngine:
         width = self.plan.final_shape(len(rest), chunk_cap)
         padded = np.zeros((1, width), np.int32)
         padded[0, : len(rest)] = rest
-        self.plan.note_dispatch("prefill", (1, width), len(rest))
+        self._note_prefill("prefill", (1, width), [(offset, len(rest))])
         token, self.cache = self._prefill(
             self.params, jnp.asarray(padded), self.cache, s.slot,
             jnp.int32(len(rest)), self._next_key(), sp,
@@ -2754,7 +2778,9 @@ class InferenceEngine:
             rest = len(prompt) - s.chunk_off
             if rest > stride:
                 chunk = prompt[s.chunk_off : s.chunk_off + stride]
-                self.plan.note_dispatch("chunk", (1, stride), len(chunk))
+                self._note_prefill(
+                    "chunk", (1, stride), [(s.chunk_off, len(chunk))]
+                )
                 self.cache = self._prefill_ns(
                     self.params, jnp.asarray(chunk)[None, :],
                     self.cache, s.slot, jnp.int32(len(chunk)),
@@ -2768,7 +2794,7 @@ class InferenceEngine:
             sp = SamplingParams.create(
                 1, s.options.temperature, s.options.top_k, s.options.top_p
             )
-            self.plan.note_dispatch("prefill", (1, width), rest)
+            self._note_prefill("prefill", (1, width), [(s.chunk_off, rest)])
             token, self.cache = self._prefill(
                 self.params, jnp.asarray(padded), self.cache, s.slot,
                 jnp.int32(rest), s.parked_key, sp,

@@ -38,8 +38,12 @@ The plan centralizes that policy:
   ``attn_chunked_rows``, and keeps the census of what the shapes cost:
   cumulative ``prefill_valid_tokens`` / ``prefill_padded_tokens`` over
   every prefill-family dispatch and ``decode_live_positions`` /
-  ``decode_grid_positions`` over every decode dispatch, and for a routed
-  model ``moe_expert_rows_needed`` / ``moe_expert_rows_computed``.
+  ``decode_grid_positions`` over every decode dispatch, for a routed
+  model ``moe_expert_rows_needed`` / ``moe_expert_rows_computed``, and
+  under the ragged plan ``ragged_attn_tiles_live`` /
+  ``ragged_attn_tiles_grid``: the tiles of the ragged kernel's grid that
+  hold a live (query, key) pair, which it computes, over all it steps
+  through.
 
 This is also the fusion point ROADMAP item 4 (batched spec verification)
 needs: a verify row is just one more ``num_new == k`` row class.
@@ -51,6 +55,9 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
+import numpy as np
+
+from ..ops.ragged_attention import _tile_live
 
 __all__ = ["AttentionPlan", "KernelSelection", "PREFILL", "CHUNKED", "DECODE"]
 
@@ -130,6 +137,12 @@ class AttentionPlan:
         # ``seq_len -> (needed, computed)`` expert MLP rows a token, over
         # all its expert layers; note_dispatch keeps their census.
         self.expert_rows = None
+        # Set by the engine over a paged cache: ``pad width -> block_q``,
+        # the q block the ragged kernel picks for this model at that width
+        # (``ops/ragged_attention.py:_prep``), and the model's window;
+        # note_dispatch keeps the census of the kernel's live tiles.
+        self.ragged_block_q = None
+        self.sliding_window: Optional[int] = None
 
     # ------------------------------------------------------------------
     # Row classification / shape policy
@@ -163,7 +176,9 @@ class AttentionPlan:
     def final_shape(self, rest: int, legacy_cap: int) -> int:
         """Pad width for the final (sampled) chunk of a single-row prefill.
         Ragged mode pads every final to the stride — ONE warm shape per row
-        count — instead of the rest's bucket."""
+        count — instead of the rest's bucket. Cheap in attention since the
+        ragged kernel computes only its live tiles: a short prompt's pad
+        costs it a skipped grid step a tile, not a tile's matmuls."""
         if not self.enabled:
             return self.bucket_for(rest)
         return self.prefill_stride(legacy_cap)
@@ -240,7 +255,9 @@ class AttentionPlan:
     # ------------------------------------------------------------------
     def note_dispatch(self, kind: str, shape: Tuple[int, ...],
                       valid_tokens: Optional[int] = None,
-                      active_rows: Optional[int] = None) -> None:
+                      active_rows: Optional[int] = None,
+                      row_spans=None,
+                      table_width: Optional[int] = None) -> None:
         """Record one attention dispatch: first-seen (kind, shape) is one
         fresh executable (``attn_recompiles``); prefill-family dispatches
         under ragged mode count ``attn_ragged_dispatches``.
@@ -259,7 +276,16 @@ class AttentionPlan:
         and those the program runs (padded tokens x the experts it computes
         a token): ``moe_expert_rows_needed`` / ``moe_expert_rows_computed``.
         A decode dispatch's tokens are its ``active_rows`` (of ``shape[0]``
-        rows) times its steps."""
+        rows) times its steps.
+
+        A prefill-family dispatch under the ragged plan over a paged cache
+        also gives ``row_spans``, a ``(q_start, num_new)`` pair a real row
+        (a pad row has no query and so no live tile), and the page table's
+        width: of the ragged kernel's (rows, q-blocks, table width) grid,
+        ``ragged_attn_tiles_grid``, the tiles :func:`_tile_live` keeps add
+        to ``ragged_attn_tiles_live`` — the kernel's own predicate over
+        its own ``block_q``, so the count is what it computes, chip or
+        not."""
         shape = tuple(int(x) for x in shape)
         self.last_dispatch = (kind, shape, valid_tokens)
         if self.dispatches is not None:
@@ -296,6 +322,28 @@ class AttentionPlan:
         else:
             self.metrics.counter("prefill_valid_tokens", valid_tokens)
             self.metrics.counter("prefill_padded_tokens", shape[0] * shape[1])
+            if self.enabled and table_width is not None:
+                live, grid = self._ragged_tiles(shape, row_spans, table_width)
+                self.metrics.counter("ragged_attn_tiles_live", live)
+                self.metrics.counter("ragged_attn_tiles_grid", grid)
+
+    def _ragged_tiles(self, shape, row_spans, table_width) -> Tuple[int, int]:
+        """(live, all) tiles of one layer's ragged-kernel grid for a
+        dispatch of ``shape`` (rows, pad width) whose real rows span
+        ``row_spans``; a row's live kv is ``q_start + num_new``, as the
+        cache passes it."""
+        block_q = self.ragged_block_q(shape[1])
+        q_blocks = -(-shape[1] // block_q)
+        spans = np.asarray(row_spans, np.int64).reshape(-1, 2)
+        start, new = spans[:, 0, None, None], spans[:, 1, None, None]
+        live = _tile_live(
+            np.arange(q_blocks)[None, :, None],
+            np.arange(table_width)[None, None, :],
+            start, new, start + new, block_q=block_q,
+            page_size=self.ccfg.page_size,
+            sliding_window=self.sliding_window,
+        )
+        return int(np.count_nonzero(live)), shape[0] * q_blocks * table_width
 
     def note_chunk_rows(self, n: int = 1) -> None:
         if self.metrics is not None:

@@ -15,12 +15,20 @@ This kernel serves rows with PER-ROW true lengths in ONE grid call:
   (``q_start > 0``, ``num_new == C``), and a decode row (``num_new == 1``)
   are the SAME cell of the same grid — phase is data, not shape, so mixed
   prefill/decode batches never recompile.
-* K/V stream IN PLACE from the page pool exactly as the decode kernel: the
-  grid walks ``(batch, q-block, page)`` with the page table scalar-prefetched,
-  and the index map clamps dead blocks — a page past the row's live span,
-  past the causal frontier of this q-block, or under a q-block past the
-  row's query count — to the null page 0, so short rows in a ragged batch
-  fetch one hot cached page instead of the table span.
+* K/V stream IN PLACE from the page pool: the grid walks ``(batch, q-block,
+  page)`` with the page table scalar-prefetched. A tile is LIVE if some
+  valid query of its block may see some live key of its page
+  (:func:`_tile_live`: under the row's query count, under its live span, at
+  or before the block's causal frontier, inside the window). A dead tile is
+  neither fetched nor computed: the index maps clamp it to the null page 0
+  (an unchanged block index is not fetched again) and the kernel bodies run
+  their matmuls, mask and softmax update under ``pl.when(live)``, both by
+  that one predicate. What a dead step still costs is the grid step itself:
+  the index maps of its operands and the pipeline's bookkeeping (0.08-0.14
+  us over int8 K/V, 0.15-0.21 us over the latent pool, against 3.7 us for a
+  live tile at Mistral-7B widths: PERF.md, PR 27); ``_init`` and
+  ``_finalize`` stay unconditional, so a q-block past the row's query count
+  still writes its zeros.
 * The query tile ``[BQ, Hkv, G, D]`` rides the MXU as an ``Hkv``-batched
   ``[BQ*G, D] x [D, PS]`` ``dot_general`` (prefill has real row counts; the
   1-row VPU special case in ``_paged_kernel`` only pays off at ``BQ*G == 1``).
@@ -59,6 +67,31 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+def _tile_live(qi, j, q_start, num_new, kv_len, *, block_q, page_size,
+               sliding_window):
+    """Whether tile (q-block ``qi``, table slot ``j``) of a row holds a
+    (query, key) pair that the kernels' element mask keeps: exactly
+    ``valid.any()``, from scalars. The block's valid queries are
+    ``[qi*BQ, min(qi*BQ + BQ, num_new))`` past ``q_start`` and the page's
+    live keys ``[j*PS, min(j*PS + PS, kv_len))``; ``key - query`` takes
+    every value between the corners of that rectangle, so a causal (and
+    windowed) pair exists iff the corners straddle the band. THE one
+    predicate: the index maps fetch by it, the kernel bodies compute by it
+    and ``engine/plan.py`` counts by it. Comparisons and ``&`` only, so
+    ints, numpy arrays and traced scalars all pass through."""
+    q_lo = q_start + qi * block_q       # first query of the block
+    q_end = q_start + num_new           # one past the row's last query
+    k_lo = j * page_size
+    live = (
+        (q_lo < q_end) & (k_lo < kv_len)
+        & (k_lo < q_lo + block_q) & (k_lo < q_end)
+    )
+    if sliding_window is not None:
+        floor = q_lo - sliding_window   # keys at or under it are too old
+        live = live & (k_lo + page_size - 1 > floor) & (kv_len - 1 > floor)
+    return live
+
+
 def _ragged_kernel(
     table_ref,   # SMEM [B, T] int32 (scalar prefetch)
     len_ref,     # SMEM [B] int32: live kv per row (incl. this call's tokens)
@@ -91,55 +124,64 @@ def _ragged_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
 
     kv_len = len_ref[b]
-    rows = hkv * block_q * g
-
-    # Flat scratch row r covers (head = r // (BQ*G), query = (r % (BQ*G))
-    # // G); its query's position inside the dispatch and in the sequence:
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    q_rel = qi * block_q + (ridx % (block_q * g)) // g
-    q_pos = qstart_ref[b] + q_rel
-
-    # Per-(query, slot) mask: slot live, causal vs the query's absolute
-    # position, and the query itself valid (pad rows past num_new mask to
-    # all-dead → l == 0 → zeros at finalize).
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1
+    live = _tile_live(
+        qi, j, qstart_ref[b], nnew_ref[b], kv_len, block_q=block_q,
+        page_size=page_size, sliding_window=sliding_window,
     )
-    valid = (pos < kv_len) & (pos <= q_pos) & (q_rel < nnew_ref[b])
-    if sliding_window is not None:
-        valid &= pos > q_pos - sliding_window
 
-    # [BQ, Hkv, G, D] -> kv-head-major [Hkv, BQ*G, D] so QK^T/PV batch over
-    # kv heads with real MXU row counts.
-    q = jnp.transpose(q_ref[0], (1, 0, 2, 3)).reshape(hkv, block_q * g, -1)
-    k = k_ref[0]  # [Hkv, PS, D]
-    v = v_ref[0]
+    # A tile with no valid pair would leave m, l and acc as they are
+    # (m_new = m_prev, alpha = 1, p = 0): skipping it is bit-exact.
+    @pl.when(live)
+    def _update():
+        rows = hkv * block_q * g
 
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ).reshape(rows, page_size)
-    s = s * scale
-    s = jnp.where(valid, s, _NEG_INF)
+        # Flat scratch row r covers (head = r // (BQ*G), query = (r % (BQ*G))
+        # // G); its query's position inside the dispatch and in the sequence:
+        ridx = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        q_rel = qi * block_q + (ridx % (block_q * g)) // g
+        q_pos = qstart_ref[b] + q_rel
 
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        # Per-(query, slot) mask: slot live, causal vs the query's absolute
+        # position, and the query itself valid (pad rows past num_new mask to
+        # all-dead → l == 0 → zeros at finalize).
+        pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1
+        )
+        valid = (pos < kv_len) & (pos <= q_pos) & (q_rel < nnew_ref[b])
+        if sliding_window is not None:
+            valid &= pos > q_pos - sliding_window
 
-    l_ref[:] = jnp.broadcast_to(
-        alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
-    )
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        # [BQ, Hkv, G, D] -> kv-head-major [Hkv, BQ*G, D] so QK^T/PV batch over
+        # kv heads with real MXU row counts.
+        q = jnp.transpose(q_ref[0], (1, 0, 2, 3)).reshape(hkv, block_q * g, -1)
+        k = k_ref[0]  # [Hkv, PS, D]
+        v = v_ref[0]
 
-    pg = p.reshape(hkv, block_q * g, page_size).astype(v.dtype)
-    pv = jax.lax.dot_general(
-        pg, v, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[:] = acc_ref[:] * alpha + pv.reshape(rows, -1)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ).reshape(rows, page_size)
+        s = s * scale
+        s = jnp.where(valid, s, _NEG_INF)
+
+        m_prev = m_ref[:, :1]
+        l_prev = l_ref[:, :1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+
+        l_ref[:] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
+        )
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+        pg = p.reshape(hkv, block_q * g, page_size).astype(v.dtype)
+        pv = jax.lax.dot_general(
+            pg, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(rows, -1)
 
     @pl.when(j == num_page_blocks - 1)
     def _finalize():
@@ -187,51 +229,58 @@ def _qragged_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
 
     kv_len = len_ref[b]
-    rows = hkv * block_q * g
-
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    q_rel = qi * block_q + (ridx % (block_q * g)) // g
-    q_pos = qstart_ref[b] + q_rel
-
-    pos = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1
+    live = _tile_live(
+        qi, j, qstart_ref[b], nnew_ref[b], kv_len, block_q=block_q,
+        page_size=page_size, sliding_window=sliding_window,
     )
-    valid = (pos < kv_len) & (pos <= q_pos) & (q_rel < nnew_ref[b])
-    if sliding_window is not None:
-        valid &= pos > q_pos - sliding_window
 
-    q = jnp.transpose(q_ref[0], (1, 0, 2, 3)).reshape(hkv, block_q * g, -1)
-    k = k_ref[0]   # [Hkv, PS, D] int8
-    ks = ks_ref[0]  # [Hkv, PS] f32
+    @pl.when(live)  # as _ragged_kernel: a dead tile changes nothing
+    def _update():
+        rows = hkv * block_q * g
 
-    s = jax.lax.dot_general(
-        q.astype(jnp.float32), k.astype(jnp.float32),
-        (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ) * ks[:, None, :]
-    s = s.reshape(rows, page_size) * scale
-    s = jnp.where(valid, s, _NEG_INF)
+        ridx = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        q_rel = qi * block_q + (ridx % (block_q * g)) // g
+        q_pos = qstart_ref[b] + q_rel
 
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1
+        )
+        valid = (pos < kv_len) & (pos <= q_pos) & (q_rel < nnew_ref[b])
+        if sliding_window is not None:
+            valid &= pos > q_pos - sliding_window
 
-    l_ref[:] = jnp.broadcast_to(
-        alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
-    )
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        q = jnp.transpose(q_ref[0], (1, 0, 2, 3)).reshape(hkv, block_q * g, -1)
+        k = k_ref[0]   # [Hkv, PS, D] int8
+        ks = ks_ref[0]  # [Hkv, PS] f32
 
-    v = v_ref[0]    # [Hkv, PS, D] int8
-    vs = vs_ref[0]  # [Hkv, PS] f32
-    pw = p.reshape(hkv, block_q * g, page_size) * vs[:, None, :]
-    pv = jax.lax.dot_general(
-        pw, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    acc_ref[:] = acc_ref[:] * alpha + pv.reshape(rows, -1)
+        s = jax.lax.dot_general(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * ks[:, None, :]
+        s = s.reshape(rows, page_size) * scale
+        s = jnp.where(valid, s, _NEG_INF)
+
+        m_prev = m_ref[:, :1]
+        l_prev = l_ref[:, :1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+
+        l_ref[:] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
+        )
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+
+        v = v_ref[0]    # [Hkv, PS, D] int8
+        vs = vs_ref[0]  # [Hkv, PS] f32
+        pw = p.reshape(hkv, block_q * g, page_size) * vs[:, None, :]
+        pv = jax.lax.dot_general(
+            pw, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(rows, -1)
 
     @pl.when(j == num_page_blocks - 1)
     def _finalize():
@@ -282,6 +331,31 @@ def _prep(q, k_pages, block_q):
     return b, s, hq, d, block_q, s_pad
 
 
+def _index_maps(block_q, page_size, sliding_window):
+    """The grid's index maps: a dead tile (:func:`_tile_live`) is clamped to
+    the null page 0, so consecutive dead steps name one block and nothing
+    is fetched for them (BlockSpec semantics skip an unchanged block); the
+    kernel bodies skip the same tiles by the same predicate."""
+
+    def _page(bi, qi, ji, table, lens, qstart, nnew):
+        live = _tile_live(
+            qi, ji, qstart[bi], nnew[bi], lens[bi], block_q=block_q,
+            page_size=page_size, sliding_window=sliding_window,
+        )
+        return jnp.where(live, table[bi, ji], 0)
+
+    def _page_index(bi, qi, ji, table, lens, qstart, nnew):
+        return (_page(bi, qi, ji, table, lens, qstart, nnew), 0, 0, 0)
+
+    def _page_index3(bi, qi, ji, table, lens, qstart, nnew):
+        return (_page(bi, qi, ji, table, lens, qstart, nnew), 0, 0)
+
+    def _q_index(bi, qi, ji, table, lens, qstart, nnew):
+        return (bi, qi, 0, 0, 0)
+
+    return _page_index, _page_index3, _q_index
+
+
 def ragged_paged_attention(
     q: jnp.ndarray,
     k_pages: jnp.ndarray,
@@ -325,20 +399,7 @@ def ragged_paged_attention(
     if s_pad != s:
         qr = jnp.pad(qr, ((0, 0), (0, s_pad - s), (0, 0), (0, 0), (0, 0)))
 
-    def _page_index(bi, qi, ji, table, lens, qstart, nnew):
-        # Clamp dead blocks to the null page: past the row's live span, past
-        # this q-block's causal frontier, or under a q-block past the row's
-        # query count. The fetch still happens (BlockSpec semantics) but
-        # hits one hot page.
-        live = (
-            (ji * page_size < lens[bi])
-            & (qi * bq < nnew[bi])
-            & (ji * page_size <= qstart[bi] + qi * bq + bq - 1)
-        )
-        return (jnp.where(live, table[bi, ji], 0), 0, 0, 0)
-
-    def _q_index(bi, qi, ji, table, lens, qstart, nnew):
-        return (bi, qi, 0, 0, 0)
+    _page_index, _, _q_index = _index_maps(bq, page_size, sliding_window)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -411,24 +472,9 @@ def quantized_ragged_paged_attention(
     if s_pad != s:
         qr = jnp.pad(qr, ((0, 0), (0, s_pad - s), (0, 0), (0, 0), (0, 0)))
 
-    def _page_index(bi, qi, ji, table, lens, qstart, nnew):
-        live = (
-            (ji * page_size < lens[bi])
-            & (qi * bq < nnew[bi])
-            & (ji * page_size <= qstart[bi] + qi * bq + bq - 1)
-        )
-        return (jnp.where(live, table[bi, ji], 0), 0, 0, 0)
-
-    def _page_index3(bi, qi, ji, table, lens, qstart, nnew):
-        live = (
-            (ji * page_size < lens[bi])
-            & (qi * bq < nnew[bi])
-            & (ji * page_size <= qstart[bi] + qi * bq + bq - 1)
-        )
-        return (jnp.where(live, table[bi, ji], 0), 0, 0)
-
-    def _q_index(bi, qi, ji, table, lens, qstart, nnew):
-        return (bi, qi, 0, 0, 0)
+    _page_index, _page_index3, _q_index = _index_maps(
+        bq, page_size, sliding_window
+    )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,

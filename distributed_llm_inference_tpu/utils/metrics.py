@@ -75,6 +75,14 @@ METRICS = {
     "moe_expert_rows_computed": (
         "counter", "Expert MLP rows the dispatches' padded tokens run"
     ),
+    # the ragged prefill kernel's grid, a layer's a dispatch: live / grid
+    # is the share of its steps that compute (ops/ragged_attention.py)
+    "ragged_attn_tiles_live": (
+        "counter", "Ragged-kernel tiles holding a live (query, key) pair"
+    ),
+    "ragged_attn_tiles_grid": (
+        "counter", "Rows x q-blocks x table width of the same dispatches"
+    ),
     "decode_tokens": ("counter", "Tokens emitted by decode"),
     "cache_growths": ("counter", "KV cache reallocations"),
     # latent (MLA) KV compression (cache/latent.py)

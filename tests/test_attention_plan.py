@@ -34,6 +34,10 @@ from distributed_llm_inference_tpu.engine.plan import (
 from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
 from distributed_llm_inference_tpu.models import llama
 from distributed_llm_inference_tpu.ops.ragged_attention import (
+    _prep,
+    _tile_live,
+    latent_ragged_paged_attention,
+    quantized_latent_ragged_paged_attention,
     quantized_ragged_paged_attention,
     ragged_attention_reference,
     ragged_paged_attention,
@@ -151,6 +155,139 @@ def test_ragged_kernel_multi_query_block():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+# --- the live-tile guard: one predicate for fetch, compute and census -----
+
+@pytest.mark.parametrize("windowed", [False, True])
+@pytest.mark.parametrize("seed", range(4))
+def test_tile_live_is_exactly_the_element_masks_any(seed, windowed):
+    """Over random rows, block sizes and table widths a tile is live if and
+    only if the kernels' element mask ``valid`` has a true element: the
+    guard never skips a tile with a valid pair and never runs one without.
+    ``kv_len`` is drawn on its own, not as ``q_start + num_new``, so keys
+    newer than every query and a length that cuts a page are both met."""
+    rng = np.random.default_rng(100 * seed + windowed)
+    seen = {True: 0, False: 0}
+    for _ in range(150):
+        bq = int(rng.choice([1, 4, 8, 16]))
+        ps = int(rng.choice([4, 8, 16]))
+        nq, t = int(rng.integers(1, 6)), int(rng.integers(1, 9))
+        q_start = int(rng.integers(0, t * ps))
+        num_new = int(rng.integers(0, nq * bq + 1))
+        kv_len = int(rng.integers(0, t * ps + 1))
+        window = int(rng.integers(1, 2 * t * ps)) if windowed else None
+        q_rel = np.arange(nq * bq)[:, None]
+        pos = np.arange(t * ps)[None, :]
+        valid = (
+            (pos < kv_len) & (pos <= q_start + q_rel) & (q_rel < num_new)
+        )
+        if windowed:
+            valid &= pos > q_start + q_rel - window
+        brute = valid.reshape(nq, bq, t, ps).any(axis=(1, 3))
+        live = _tile_live(
+            np.arange(nq)[:, None], np.arange(t)[None, :], q_start, num_new,
+            kv_len, block_q=bq, page_size=ps, sliding_window=window,
+        )
+        np.testing.assert_array_equal(
+            np.broadcast_to(live, brute.shape), brute,
+            err_msg=f"{bq=} {ps=} {q_start=} {num_new=} {kv_len=} {window=}",
+        )
+        seen[True] += int(brute.sum())
+        seen[False] += int((~brute).sum())
+    assert seen[True] > 100 and seen[False] > 100  # both sides exercised
+
+
+_GUARD_S, _GUARD_BQ, _GUARD_PS, _GUARD_T = 32, 8, 8, 8
+# num_new of one, a part of a block, several blocks, the whole width
+_GUARD_NEW = {"one": 1, "part": 5, "several": 19, "whole": 32}
+# (q_start of row 0, sliding window)
+_GUARD_VARIANTS = {"fresh": (0, None), "chunk": (21, None),
+                   "chunk_window": (21, 12)}
+
+
+def _guard_call(kernel, q, pool, scales, table, kv_len, num_new, q_start,
+                window):
+    kw = dict(q_start=q_start, sliding_window=window, block_q=_GUARD_BQ,
+              interpret=True)
+    if kernel == "bf16":
+        return ragged_paged_attention(
+            q, pool[0], pool[1], table, kv_len, num_new, **kw)
+    if kernel == "int8":
+        return quantized_ragged_paged_attention(
+            q, pool[0], scales[0], pool[1], scales[1], table, kv_len,
+            num_new, **kw)
+    if kernel == "latent":
+        return latent_ragged_paged_attention(
+            q, pool[0], table, kv_len, num_new, **kw)
+    return quantized_latent_ragged_paged_attention(
+        q, pool[0], scales[0], table, kv_len, num_new, **kw)
+
+
+@pytest.mark.parametrize("variant", list(_GUARD_VARIANTS))
+@pytest.mark.parametrize("new", list(_GUARD_NEW))
+@pytest.mark.parametrize("rows", [1, 2])
+@pytest.mark.parametrize("kernel", ["bf16", "int8", "latent", "int8_latent"])
+def test_guarded_kernel_equals_reference_and_the_narrowest_call(
+    kernel, rows, new, variant
+):
+    """Both kernel bodies and both latent wrappers, interpret mode: a wide
+    dispatch (most q-blocks and pages dead) equals the XLA oracle, and is
+    BIT-EQUAL to the same call made at the narrowest width that holds
+    ``num_new`` (no dead q-block), whose pad rows are zeros. A second row
+    carries another length, so the rows' live spans differ."""
+    rng = np.random.default_rng(7)
+    S, PS, T = _GUARD_S, _GUARD_PS, _GUARD_T
+    latent = "latent" in kernel
+    hq, hkv, d, pages = 4, (1 if latent else 2), 16, rows * T + 1
+    start0, window = _GUARD_VARIANTS[variant]
+    news = [_GUARD_NEW[new], 11][:rows]
+    starts = [start0, 3][:rows]
+    q = jnp.asarray(rng.standard_normal((rows, S, hq, d)), jnp.float32)
+    pool = [
+        jnp.asarray(rng.standard_normal((pages, hkv, PS, d)), jnp.float32)
+        for _ in range(1 if latent else 2)
+    ]
+    scales = None
+    if "int8" in kernel:
+        scales = [
+            jnp.asarray(
+                0.02 + 0.01 * rng.random((pages, hkv, PS)), jnp.float32
+            )
+            for _ in pool
+        ]
+        pool = [
+            jnp.clip(jnp.round(40 * p), -127, 127).astype(jnp.int8)
+            for p in pool
+        ]
+    table = jnp.asarray(
+        1 + rng.permutation(pages - 1)[: rows * T].reshape(rows, T), jnp.int32
+    )
+    num_new = jnp.asarray(news, jnp.int32)
+    q_start = jnp.asarray(starts, jnp.int32)
+    kv_len = q_start + num_new
+    assert int(kv_len.max()) <= T * PS
+
+    wide = _guard_call(
+        kernel, q, pool, scales, table, kv_len, num_new, q_start, window
+    )
+    ref = ragged_attention_reference(
+        q, pool[0], pool[-1], table, kv_len, num_new,
+        ks_pages=scales[0] if scales else None,
+        vs_pages=scales[-1] if scales else None,
+        q_start=q_start, sliding_window=window,
+    )
+    np.testing.assert_allclose(np.asarray(wide), np.asarray(ref), atol=2e-5)
+
+    narrow_s = -(-max(news) // _GUARD_BQ) * _GUARD_BQ
+    narrow = _guard_call(
+        kernel, q[:, :narrow_s], pool, scales, table, kv_len, num_new,
+        q_start, window,
+    )
+    np.testing.assert_array_equal(
+        np.asarray(wide[:, :narrow_s]), np.asarray(narrow)
+    )
+    assert not np.asarray(wide[:, narrow_s:]).any()
+
+
 # ---------------------------------------------------------------------------
 # Plan unit contracts
 # ---------------------------------------------------------------------------
@@ -238,6 +375,51 @@ def test_census_counters_on_a_two_row_engine(kind):
     assert m.get_counter("decode_live_positions") == (4 + 11) + (5 + 12)
     assert m.get_counter("decode_grid_positions") == 2 * (2 * width)
     assert m.get_counter("prefill_padded_tokens") == 8 + 16  # no new prefill
+
+
+@pytest.mark.parametrize("block_q", [8, 16])
+def test_ragged_tile_census_on_a_two_row_engine(block_q):
+    """``ragged_attn_tiles_live`` / ``_grid`` against a count made by hand.
+    Pad width 16, pages of 8; a 3-token prompt is one prefill, a 20-token
+    prompt a 16-token chunk and a 4-token final at position 16. With
+    q-blocks of 8 (two a dispatch): the short prompt's one valid block sees
+    page 0 (1 tile); the chunk's blocks see pages {0} and {0, 1} (3); the
+    final's block, queries 16-19, sees pages {0, 1, 2} (3). With one block
+    of 16 a dispatch: 1, 2 and 3. The grid is q-blocks x the table's width
+    a dispatch, live or not."""
+    eng = make_engine(ragged=True, batch=2, chunk=16, decode_steps=1)
+    # the engine hands the plan the kernel's OWN choice of q block
+    pool = eng.cache.k_pages
+    q = jax.ShapeDtypeStruct((1, 16, CFG.num_heads, CFG.head_dim), jnp.float32)
+    assert eng.plan.ragged_block_q(16) == _prep(
+        q, jax.ShapeDtypeStruct(pool.shape[1:], pool.dtype), None
+    )[4] == 16
+    eng.plan.ragged_block_q = lambda width: block_q
+    width = eng.cache.page_table.shape[1]
+    opts = SamplingOptions(max_new_tokens=2)
+    eng.submit([1, 2, 3], opts), eng.submit(list(range(1, 21)), opts)
+    for _ in range(12):
+        eng.step()
+    assert not eng.has_work()
+    assert eng.cache.page_table.shape[1] == width  # no growth on the way
+    m = eng.metrics
+    assert m.get_counter("prefill_valid_tokens") == 3 + 16 + 4
+    live = {8: 1 + 3 + 3, 16: 1 + 2 + 3}[block_q]
+    assert m.get_counter("ragged_attn_tiles_live") == live
+    grid = 3 * (16 // block_q) * width
+    assert m.get_counter("ragged_attn_tiles_grid") == grid
+
+
+def test_ragged_tile_census_counts_nothing_off_the_ragged_plan():
+    """The legacy plan runs no ragged kernel, and a dense cache has no page
+    table: neither keeps the census."""
+    for kw in ({"ragged": False}, {"ragged": True, "kind": "dense"}):
+        eng = make_engine(**kw)
+        eng.submit([1, 2, 3], SamplingOptions(max_new_tokens=2))
+        for _ in range(4):
+            eng.step()
+        assert eng.metrics.get_counter("prefill_valid_tokens") == 3
+        assert eng.metrics.get_counter("ragged_attn_tiles_grid") == 0
 
 
 # ---------------------------------------------------------------------------
