@@ -18,11 +18,13 @@ over the stored form — ``K = V = [c ; k_rope]`` with a single KV head.
 Every existing paged kernel is generic over ``(Hkv, head_dim)``, so the
 "fused decompression" is literally the kernels' existing page-table walk
 reading the latent pool in place (``ops/ragged_attention.py:
-latent_ragged_paged_attention`` and ``ops/paged_attention.py:
-latent_paged_attention`` are the named entry points the AttentionPlan
-selects).
+latent_ragged_paged_attention`` for prefill; for decode
+``ops/paged_attention.py: quantized_latent_paged_fused_attention`` over the
+int8 pool, the fused 16-step window with a write-behind tail, and
+``latent_paged_attention`` / ``quantized_latent_paged_attention``, the
+one-token step of the float32 pool and of an int8 pool without its kernel).
 
-Two consequences shape this module:
+Three consequences shape this module:
 
 * Rope is applied by the MODEL (to the ``k_rope`` slice only, before the
   latent is handed to the cache) — the latent itself is position-free.
@@ -32,6 +34,11 @@ Two consequences shape this module:
   ``[lat_dim]`` per token) or ``c``+``cs`` (int8 + per-token f32 scale),
   flowing unchanged through export/ingest/spill/page-ship — the same
   page/refcount/CoW machinery as the parent, via ``PLANE_FIELDS``.
+
+* The write-behind tail is the int8 pool's alone
+  (``QuantizedLatentPagedKVCache.tail_*``): ONE stored plane where the
+  per-head pool has two, rope-free, in the kernel. The float32 pool keeps
+  the one-token path; it runs in no benchmark cell.
 
 ``v_pages`` survives as a 1-element placeholder (flax dataclass fields
 cannot be removed in a subclass); no code path reads it — every pool
@@ -202,11 +209,16 @@ class LatentPagedKVCache(PagedKVCache):
             first_slot,
         )
 
-    # -- write-behind tail: never used (the engine's tail gate excludes
-    # latent caches — the parent's tail re-applies rope, which would
-    # corrupt the pre-rotated stored form). Fail loudly if reached.
+    # -- write-behind tail: the float32 pool has none (it runs in no
+    # benchmark cell; the engine's tail gate passes only a cache whose
+    # ``has_tail`` says so, and the parent's tail would re-apply rope to the
+    # pre-rotated stored form). Fail loudly if reached.
+    has_tail = False
+
     def tail_init(self, k_steps: int):
-        raise NotImplementedError("latent cache has no write-behind tail")
+        raise NotImplementedError(
+            "float32 latent cache has no write-behind tail"
+        )
 
 
 class QuantizedLatentPagedKVCache(LatentPagedKVCache):
@@ -292,6 +304,16 @@ class QuantizedLatentPagedKVCache(LatentPagedKVCache):
                 )
 
             return jax.lax.fori_loop(0, b, body, (layer_c, layer_cs))
+        return self._scatter_planes(
+            layer_c, layer_cs, c_q, c_s, phys_page, offset_bs
+        )
+
+    @staticmethod
+    def _scatter_planes(layer_c, layer_cs, c_q, c_s, phys_page, offset_bs):
+        """PRE-QUANTIZED latents ``[B, S, 1, D]`` int8 and scales
+        ``[B, S, 1]`` into one layer's pool at ``(page, offset)`` ``[B, S]``
+        (:meth:`_slot_pages`' diverted to the null page where not valid)."""
+        b, s, _, d = c_q.shape
         flat_page = phys_page.reshape(-1)
         flat_off = offset_bs.reshape(-1)
         return (
@@ -337,3 +359,93 @@ class QuantizedLatentPagedKVCache(LatentPagedKVCache):
         c_all = self._contiguous_view(new_state, q.shape[0], q.dtype)
         mask = self._latent_mask(q.shape[0], q_pos, num_new, sliding_window)
         return attention_fn(q, c_all, c_all, mask, scale=scale), new_state
+
+    # -- write-behind tail ----------------------------------------------------
+    #
+    # The protocol of ``QuantizedPagedKVCache`` past ``INPLACE_CTX``, with
+    # ONE stored plane: through a fused K-step window the pool is a
+    # read-only operand, whole (``[L, P, 1, PS, lat_dim]`` in HBM, the layer
+    # resolved inside the kernel: no slice, no copy), each row's live pages
+    # are swept in place and fetched once (the page is K and V), the step's
+    # latent is quantized in the kernel into an io-aliased tail, and the
+    # tail is merged into the pool once a window. There is no gathered form
+    # under a context threshold and no XLA form: without the kernel the
+    # cache has no tail and the engine decodes a token a dispatch.
+
+    @property
+    def has_tail(self) -> bool:
+        return self.use_kernel
+
+    tail_reads_whole_big = has_tail
+    tail_in_kernel = has_tail
+
+    def tail_big_stacks(self):
+        return (self.k_pages, self.cs_pages)
+
+    def tail_init(self, k_steps: int):
+        if not self.has_tail:
+            raise NotImplementedError(
+                "the int8 latent cache's tail is in-kernel (use_kernel)"
+            )
+        l, _, _, _, d = self.k_pages.shape
+        b = self.page_table.shape[0]
+        return (
+            jnp.zeros((l, b, 1, k_steps, d), jnp.int8),
+            jnp.zeros((l, b, 1, k_steps), jnp.float32),
+        )
+
+    def tail_attend(self, big_state, tail_state, q, k_new, v_new, rope,
+                    base_len, tail_len, step_idx, num_new, sliding_window,
+                    scale=None):
+        """NO rope here either (see :meth:`attend`): ``q`` and ``k_new``
+        arrive rotated, ``k_new`` in stored form."""
+        from ..ops.paged_attention import (
+            quantized_latent_paged_fused_attention,
+        )
+
+        if sliding_window is not None:
+            raise ValueError("latent attention has no sliding window")
+        pool_c, pool_cs, lidx = big_state  # whole [L, ...] + layer index
+        tail_c, tail_cs = tail_state
+        out, tail_c, tail_cs = quantized_latent_paged_fused_attention(
+            q, k_new, pool_c, pool_cs, tail_c, tail_cs,
+            layer_idx=lidx, step_idx=step_idx,
+            page_table=self.page_table, base_len=base_len,
+            tail_valid_len=tail_len + num_new,
+            q_positions=base_len + tail_len, scale=scale,
+        )
+        return out, (tail_c, tail_cs)
+
+    def tail_flush(self, tail, tail_len):
+        """The window's tail into the pool, pre-quantized: what the pool
+        holds afterwards is bit for bit what :meth:`_scatter_latent` writes
+        a token at a time."""
+        tail_c, tail_cs = tail  # [L, B, 1, K, D] int8 / [L, B, 1, K] f32
+        kk = tail_c.shape[3]
+        if kk <= self.page_size:
+            # The blocked page RMW of the per-head pool (its reasons:
+            # ops/paged_attention.py:paged_tail_flush), one plane.
+            from ..ops.paged_attention import paged_tail_flush
+
+            new_c, new_cs = paged_tail_flush(
+                self.k_pages, self.cs_pages, None, None,
+                tail_c, tail_cs, None, None,
+                self.page_table, self.lengths, tail_len,
+            )
+        else:
+            q_pos = (
+                self.lengths[:, None]
+                + jnp.arange(kk, dtype=jnp.int32)[None, :]
+            )
+            page, off = self._slot_pages(q_pos, tail_len)
+            # a layer's tail [B, 1, K, D] / [B, 1, K] -> [B, K, 1, D] / [B, K, 1]
+            new_c, new_cs = jax.vmap(
+                lambda lc, lcs, tc, tcs: self._scatter_planes(
+                    lc, lcs, jnp.swapaxes(tc, 1, 2), jnp.swapaxes(tcs, 1, 2),
+                    page, off,
+                )
+            )(self.k_pages, self.cs_pages, tail_c, tail_cs)
+        return self.replace(
+            k_pages=new_c, cs_pages=new_cs,
+            lengths=self.lengths + tail_len,
+        )

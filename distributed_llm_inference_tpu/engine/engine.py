@@ -554,15 +554,22 @@ class InferenceEngine:
         # slot writes and flush gather partition) but not with the staged
         # pipeline program, which pp engines use per step instead. The int8
         # paged cache's tail gathers its pool once per fused window (pure
-        # XLA); the bf16 paged tail still reads pages in place and requires
-        # the Pallas kernel.
+        # XLA) or, past ``INPLACE_CTX`` with the kernel, reads it in place;
+        # the bf16 paged tail still reads pages in place and requires the
+        # Pallas kernel.
         tail_capable = (
             attention is None
             and not self._use_pp
-            # Latent caches have no tail protocol: the tail segment would
-            # re-apply RoPE to an already-decoupled stored form (tail_init
-            # raises by design). They scan model_apply per step instead.
-            and not isinstance(self.cache, LatentPagedKVCache)
+            # A latent cache says itself whether it has the protocol: the
+            # int8 pool with the Pallas kernel does (its own rope-free
+            # tail_attend over the fused in-place sweep); the float32 pool,
+            # and the int8 pool without the kernel, decode a token a
+            # dispatch (the per-head tail would re-apply RoPE to the
+            # pre-rotated stored form; their tail_init raises).
+            and (
+                not isinstance(self.cache, LatentPagedKVCache)
+                or self.cache.has_tail
+            )
             and (
                 isinstance(
                     self.cache,
@@ -3286,6 +3293,7 @@ class InferenceEngine:
             else int(getattr(self.cache, "max_len", 0)),
         ), self._live_positions(active), int(active.sum()))
         if K == 1:
+            self.metrics.counter("decode_one_token_ticks")
             next_tokens, self.cache = self._decode(
                 self.params, jnp.asarray(tokens), self.cache,
                 jnp.asarray(active), self._next_key(), sp,

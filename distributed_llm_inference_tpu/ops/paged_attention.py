@@ -25,7 +25,9 @@ Bandwidth properties:
   slots x table width, not the live tokens. No benchmark cell runs those
   four; the debt is open (PERF.md §7);
 * ``quantized_paged_fused_attention`` — the kernel every int8 paged engine
-  decodes through past ``INPLACE_CTX`` — sweeps a row's LIVE pages: its
+  decodes through past ``INPLACE_CTX``, and (its one-stored-plane form,
+  ``quantized_latent_paged_fused_attention``) every int8 latent engine —
+  sweeps a row's LIVE pages: its
   grid is over rows only, K and V stay in HBM, and a loop inside the kernel
   fetches (double-buffered async copies, the physical ids from the page
   table in SMEM) and attends to the pages that hold something the query
@@ -68,6 +70,7 @@ __all__ = [
     "quantized_paged_attention",
     "latent_paged_attention",
     "quantized_latent_paged_attention",
+    "quantized_latent_paged_fused_attention",
     "quantized_paged_fused_attention",
 ]
 
@@ -470,12 +473,13 @@ def latent_paged_attention(
     q_positions: Optional[jnp.ndarray] = None,
     return_stats: bool = False,
 ):
-    """Absorbed-MLA decode attention over the latent pool, in place — the
-    non-ragged fallback of ``ops/ragged_attention.py:
-    latent_ragged_paged_attention`` (same contract: ``c_pages``
-    ``[P, 1, page_size, lat_dim]`` fused ``[c ; k_rope]`` latents, ``q``
-    the absorbed ``[B, 1, Hq, lat_dim]`` query, ``K = V =`` stored
-    latents, so the page walk is the decompression fusion)."""
+    """Absorbed-MLA decode attention over ONE LAYER's float32 latent pool,
+    already written, in place: the one-token step of ``cache/latent.py:
+    LatentPagedKVCache.attend`` (``c_pages`` ``[P, 1, page_size, lat_dim]``
+    fused ``[c ; k_rope]`` latents, ``q`` the absorbed
+    ``[B, 1, Hq, lat_dim]`` query, ``K = V =`` stored latents, so the page
+    walk is the decompression fusion). The ``(slots, table width)`` grid of
+    :func:`paged_attention`; no benchmark cell runs it."""
     return paged_attention(
         q, c_pages, c_pages, page_table, kv_lengths, scale=scale,
         sliding_window=sliding_window, interpret=interpret,
@@ -497,11 +501,52 @@ def quantized_latent_paged_attention(
     return_stats: bool = False,
 ):
     """As :func:`latent_paged_attention` over the int8 latent pool with
-    per-token f32 scales (``cs_pages``: ``[P, 1, page_size]``)."""
+    per-token f32 scales (``cs_pages``: ``[P, 1, page_size]``): the
+    one-token step of an int8 latent engine that was given ``decode_steps``
+    1. The grid form: every tile of ``(slots, table width)``, the pool
+    fetched as K and again as V. An engine left to itself decodes through
+    :func:`quantized_latent_paged_fused_attention`, which took this
+    wrapper's name in a device trace; this one is traced as
+    ``quantized_latent_paged_grid_attention``."""
     return quantized_paged_attention(
         q, c_pages, cs_pages, c_pages, cs_pages, page_table, kv_lengths,
         scale=scale, sliding_window=sliding_window, interpret=interpret,
         q_positions=q_positions, return_stats=return_stats,
+        name="quantized_latent_paged_grid_attention",
+    )
+
+
+def quantized_latent_paged_fused_attention(
+    q: jnp.ndarray,
+    c_new: jnp.ndarray,
+    pool_c: jnp.ndarray,
+    pool_cs: jnp.ndarray,
+    tail_c: jnp.ndarray,
+    tail_cs: jnp.ndarray,
+    layer_idx: jnp.ndarray,
+    step_idx: jnp.ndarray,
+    page_table: jnp.ndarray,
+    base_len: jnp.ndarray,
+    tail_valid_len: jnp.ndarray,
+    q_positions: jnp.ndarray,
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
+):
+    """A fused-decode step of an int8 latent engine: the one-stored-plane
+    form of :func:`quantized_paged_fused_attention` (its body, its sweep of
+    a row's live pages, its in-kernel tail) over the WHOLE
+    ``[L, P, 1, PS, lat_dim]`` latent pool read in place, each live page
+    fetched once and used as K and as V. ``q`` is the absorbed query and
+    ``c_new`` ``[B, 1, 1, lat_dim]`` the step's latent in stored form (both
+    rotated by the model). Returns ``(out, tail_c', tail_cs')``. Traced as
+    ``quantized_latent_paged_attention``: one event is one layer of one
+    decode step of a latent engine, as it was under the grid form."""
+    return quantized_paged_fused_attention(
+        q, c_new, None, pool_c, pool_cs, None, None,
+        tail_c, tail_cs, None, None,
+        layer_idx=layer_idx, step_idx=step_idx, page_table=page_table,
+        base_len=base_len, tail_valid_len=tail_valid_len,
+        q_positions=q_positions, scale=scale, interpret=interpret,
         name="quantized_latent_paged_attention",
     )
 
@@ -515,18 +560,31 @@ def quantized_latent_paged_attention(
 _SWEEP_VMEM_BUDGET = 4 * 2**20
 
 
-def _pages_per_block(t, hkv, page_size, d, kt):
-    """Pages of K and V one block of the sweep fetches: the most (a power
-    of two, no more than the table is wide, and no more than 8: a block is
-    then a megabyte in flight, and the chip timed 2, 4 and 8 alike, PERF.md
-    §6) whose double buffers fit :data:`_SWEEP_VMEM_BUDGET` beside the row's
-    scale rows and tail."""
+def _pages_by_grid(d):
+    """Whether a fused-decode call takes its pool pages as pipelined blocks
+    (the grid walks a row's table, a block of pages a step) and not by async
+    copies of its own. Mosaic (jax 0.9.0) refuses EVERY slice of an HBM
+    plane whose minor dimension is not whole 128-lane tiles, the whole row
+    too ("Slice shape along dimension 4 must be aligned to tiling (128), but
+    is 576": the latent pool's stored row), so such a pool cannot be swept
+    by copies; a pipelined block of whole rows it takes as stored. A row
+    narrower than a tile (no chip runs one) keeps the copies: the CPU suite's
+    small pools walk the path the cells' per-head pools walk."""
+    return d > 128 and d % 128 != 0
+
+
+def _pages_per_block(t, hkv, page_size, d, kt, planes=2):
+    """Pages of K and V (``planes`` 2; 1 where one stored plane is both) one
+    block of the sweep fetches: the most (a power of two, no more than the
+    table is wide, and no more than 8: a block is then a megabyte in flight,
+    and the chip timed 2, 4 and 8 alike, PERF.md §6) whose double buffers
+    fit :data:`_SWEEP_VMEM_BUDGET` beside the row's scale rows and tail."""
     lanes = -(-d // 128) * 128
     heads = -(-hkv // 8) * 8
-    page = 2 * hkv * -(-page_size // 32) * 32 * lanes          # K + V, int8
-    row = 2 * 2 * t * heads * max(page_size, 128) * 4          # scale rows
-    row += 2 * 2 * (2 * hkv * -(-kt // 32) * 32 * lanes        # tail in +
-                    + 2 * heads * max(kt, 128) * 4)            # out, 2 bufs
+    page = planes * hkv * -(-page_size // 32) * 32 * lanes     # K + V, int8
+    row = 2 * planes * t * heads * max(page_size, 128) * 4     # scale rows
+    row += 2 * 2 * (planes * hkv * -(-kt // 32) * 32 * lanes   # tail in +
+                    + planes * heads * max(kt, 128) * 4)       # out, 2 bufs
     n = 1
     while (
         2 * n <= min(t, 8)
@@ -536,18 +594,31 @@ def _pages_per_block(t, hkv, page_size, d, kt):
     return n
 
 
+def _live_pages(kv_len, qpos, page_size, width, sliding_window):
+    """A row's live pages ``[lo, hi)``: what lies past its length, or wholly
+    before its window, is never fetched and never computed. Both ends are
+    held inside the table: a length is the caller's word, and a page id read
+    past the table's end would be the source of a DMA."""
+    hi = jnp.clip((kv_len + page_size - 1) // page_size, 0, width)
+    if sliding_window is None:
+        return 0, hi
+    return jnp.minimum(
+        jnp.maximum(qpos - sliding_window + 1, 0) // page_size, hi
+    ), hi
+
+
 def quantized_paged_fused_attention(
     q: jnp.ndarray,
     k_new: jnp.ndarray,
-    v_new: jnp.ndarray,
+    v_new: Optional[jnp.ndarray],
     pool_k: jnp.ndarray,
     pool_ks: jnp.ndarray,
-    pool_v: jnp.ndarray,
-    pool_vs: jnp.ndarray,
+    pool_v: Optional[jnp.ndarray],
+    pool_vs: Optional[jnp.ndarray],
     tail_k: jnp.ndarray,
     tail_ks: jnp.ndarray,
-    tail_v: jnp.ndarray,
-    tail_vs: jnp.ndarray,
+    tail_v: Optional[jnp.ndarray],
+    tail_vs: Optional[jnp.ndarray],
     layer_idx: jnp.ndarray,
     step_idx: jnp.ndarray,
     page_table: jnp.ndarray,
@@ -557,6 +628,7 @@ def quantized_paged_fused_attention(
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
     sliding_window: Optional[int] = None,
+    name: str = "quantized_paged_fused_attention",
 ):
     """ONE kernel for a fused-decode step over the int8 page pool IN PLACE:
     the WHOLE ``[L, P, Hkv, PS, D]`` K and V planes stay in HBM
@@ -567,6 +639,15 @@ def quantized_paged_fused_attention(
     page)`` itself; the step's fresh K/V quantizes in-kernel into the
     io-aliased write-behind tail, which joins the page sweep as the final
     online-softmax tile.
+
+    **One stored plane or two.** A pool whose stored plane is both K and V
+    (the latent cache: ``K = V = [c ; k_rope]``) passes ``None`` for
+    ``v_new``, ``pool_v``, ``pool_vs``, ``tail_v`` and ``tail_vs``: the
+    same body then has ONE pool operand, one page buffer, one fetch a live
+    page and one tail plane with its scale row, and every tile uses the
+    fetched page as K and as V. That is a static branch on what the caller
+    stores, not a second kernel; the two-plane call traces to the program
+    it always did.
 
     The grid is over rows. A row's sweep is a loop INSIDE the kernel over
     its own live pages — page ``lo`` (0, or under a sliding window the first
@@ -582,6 +663,22 @@ def quantized_paged_fused_attention(
     changes no sum (it was an exact no-op of the online softmax: alpha 1,
     p 0), so a decoding row's results are the whole-grid walk's, bit for bit.
 
+    **Where Mosaic cannot copy a page** (:func:`_pages_by_grid`: a stored
+    row that is not whole 128-lane tiles, the latent pool's 576) the same
+    blocks of a row's table are the steps of a second grid axis and the
+    block's pages come as pipelined operands, the whole pool behind each,
+    its index map the page table's entry. A dead page names the null page
+    (one fetch, then none while the index stands); a block with a live page
+    is ONE tile of the online softmax, its pages side by side under the
+    positions' mask (a page cost 0.54 us as a tile of its own and costs
+    0.20-0.25 in a block of eight, PERF.md §6, PR 31), and a block with
+    none does nothing.
+    The accumulator lives across a row's steps; the tail, the division and
+    the results' write are the last step's. Still no slice and no copy of
+    the pool, each live page read once; what it pays over the copies is the
+    pipeline's fixed cost a step of ``(rows, cdiv(table width, pages a
+    block))`` and the dead pages beside a row's last live one.
+
     The scale rows do not come that way: Mosaic (jax 0.9.0) refuses an
     async copy out of an HBM plane whose minor dimension is one page
     (``[.., Hkv, 64]`` f32: "Slice shape along dimension 3 must be aligned
@@ -593,11 +690,17 @@ def quantized_paged_fused_attention(
     ``[B, 1, Hkv, D]`` (k rotated); pool planes ``[L, P, Hkv, PS, D]`` int8
     (+ ``[L, P, Hkv, PS]`` f32 scales); tail planes ``[L, B, Hkv, KT, D]``
     (+ scales, io-aliased). Returns ``(out, tail_k', tail_ks', tail_v',
-    tail_vs')``.
+    tail_vs')``, or ``(out, tail_k', tail_ks')`` of one stored plane.
+    ``name`` is what a device trace calls the kernel.
     """
     b, s, hq, d = q.shape
     if s != 1:
         raise ValueError(f"decode-only kernel (S=1), got S={s}")
+    shared = pool_v is None
+    if shared and any(
+        x is not None for x in (v_new, pool_vs, tail_v, tail_vs)
+    ):
+        raise ValueError("one stored plane takes no V operand at all")
     num_l, _, hkv, page_size, _ = pool_k.shape
     kt = tail_k.shape[3]
     t = page_table.shape[1]
@@ -606,11 +709,21 @@ def quantized_paged_fused_attention(
         scale = d**-0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    n = _pages_per_block(t, hkv, page_size, d, kt)
+    planes = 1 if shared else 2
+    n = _pages_per_block(t, hkv, page_size, d, kt, planes)
+    by_grid = _pages_by_grid(d)
 
     qr = q.reshape(b, hkv, g, d)
-    knr = jnp.moveaxis(k_new, 1, 2)  # [B, Hkv, 1, D]
-    vnr = jnp.moveaxis(v_new, 1, 2)
+    # Per stored plane, in the kernel's operand order: the step's fresh
+    # values, the tail (values + scale row, io-aliased), the pool (whole)
+    # with its scale plane (gathered to the row's table slots below).
+    fresh = [jnp.moveaxis(k_new, 1, 2)]                  # [B, Hkv, 1, D]
+    tails = [tail_k, tail_ks]
+    pools = [(pool_k, pool_ks)]
+    if not shared:
+        fresh += [jnp.moveaxis(v_new, 1, 2)]
+        tails += [tail_v, tail_vs]
+        pools += [(pool_v, pool_vs)]
     lref = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     sref = jnp.asarray(step_idx, jnp.int32).reshape(1)
     table = page_table.astype(jnp.int32)
@@ -621,43 +734,66 @@ def quantized_paged_fused_attention(
         # test and fill (a quarter of this gather's time on the chip).
         return jnp.take(layer, table, axis=0, mode="clip")
 
-    def _tail_index(bi, lidx, step, table, lens, vlen, qpos):
-        return (lidx[0], bi, 0, 0, 0)
+    # An index map takes the grid's ids, then the six scalar operands
+    # (layer, step, table, lengths, valid tail slots, query positions).
+    def _tail_index(bi, *a):
+        return (a[-6][0], bi, 0, 0, 0)
 
-    def _tail_index3(bi, lidx, step, table, lens, vlen, qpos):
-        return (lidx[0], bi, 0, 0)
+    def _tail_index3(bi, *a):
+        return (a[-6][0], bi, 0, 0)
 
-    def _row_index(bi, lidx, step, table, lens, vlen, qpos):
+    def _row_index(bi, *a):
         return (bi, 0, 0, 0)
 
-    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    def _page_index(i):
+        def index(bi, ji, lidx, step, table, lens, vlen, qpos):
+            page = ji * n + i
+            lo, hi = _live_pages(
+                jnp.where(vlen[bi] > 0, lens[bi], 0), qpos[bi], page_size,
+                t, sliding_window,
+            )
+            live = (page >= lo) & (page < hi)
+            slot = jnp.minimum(page, t - 1)
+            return (lidx[0], jnp.where(live, table[bi, slot], 0), 0, 0, 0)
+
+        return index
+
+    def _tail_specs():
+        return [
+            pl.BlockSpec((1, 1, hkv, kt, d), _tail_index),
+            pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
+        ] * planes
+
+    if by_grid:
+        pool_specs = [
+            pl.BlockSpec((1, 1, hkv, page_size, d), _page_index(i))
+            for i in range(n)
+        ]
+        page_bufs = []
+    else:
+        pool_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+        page_bufs = [
+            *[pltpu.VMEM((2, n, hkv, page_size, d), pool_k.dtype)] * planes,
+            pltpu.SemaphoreType.DMA((2, n)),
+        ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(b,),
+        grid=(b, -(-t // n)) if by_grid else (b,),
         in_specs=[
             pl.BlockSpec((1, hkv, g, d), _row_index),
-            pl.BlockSpec((1, hkv, 1, d), _row_index),
-            pl.BlockSpec((1, hkv, 1, d), _row_index),
-            pl.BlockSpec((1, 1, hkv, kt, d), _tail_index),
-            pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
-            pl.BlockSpec((1, 1, hkv, kt, d), _tail_index),
-            pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
-            in_hbm,
-            pl.BlockSpec((1, t, hkv, page_size), _row_index),
-            in_hbm,
-            pl.BlockSpec((1, t, hkv, page_size), _row_index),
+            *[pl.BlockSpec((1, hkv, 1, d), _row_index)] * planes,
+            *_tail_specs(),
+            *[
+                *pool_specs,
+                pl.BlockSpec((1, t, hkv, page_size), _row_index),
+            ] * planes,
         ],
         out_specs=(
             pl.BlockSpec((1, hkv, g, d), _row_index),
-            pl.BlockSpec((1, 1, hkv, kt, d), _tail_index),
-            pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
-            pl.BlockSpec((1, 1, hkv, kt, d), _tail_index),
-            pl.BlockSpec((1, 1, hkv, kt), _tail_index3),
+            *_tail_specs(),
         ),
         scratch_shapes=[
-            pltpu.VMEM((2, n, hkv, page_size, d), pool_k.dtype),
-            pltpu.VMEM((2, n, hkv, page_size, d), pool_v.dtype),
-            pltpu.SemaphoreType.DMA((2, n)),
+            *page_bufs,
             pltpu.VMEM((hkv * g, d), jnp.float32),
             pltpu.VMEM((hkv * g, 128), jnp.float32),
             pltpu.VMEM((hkv * g, 128), jnp.float32),
@@ -665,6 +801,8 @@ def quantized_paged_fused_attention(
     )
     kernel = functools.partial(
         _qpaged_fused_kernel,
+        planes=planes,
+        by_grid=by_grid,
         scale=scale,
         page_size=page_size,
         pages_per_block=n,
@@ -673,31 +811,35 @@ def quantized_paged_fused_attention(
         g=g,
         kt=kt,
     )
-    out, tk, tks, tv, tvs = pl.pallas_call(
+    # Tail planes update in place; an alias's index counts every flattened
+    # input, the 6 scalar-prefetch operands and q and the fresh values too.
+    first_tail = 6 + 1 + planes
+    out, *new_tails = pl.pallas_call(
         kernel,
-        name="quantized_paged_fused_attention",
+        name=name,
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-            jax.ShapeDtypeStruct(tail_k.shape, tail_k.dtype),
-            jax.ShapeDtypeStruct(tail_ks.shape, tail_ks.dtype),
-            jax.ShapeDtypeStruct(tail_v.shape, tail_v.dtype),
-            jax.ShapeDtypeStruct(tail_vs.shape, tail_vs.dtype),
+            *[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in tails],
         ),
         grid_spec=grid_spec,
         interpret=interpret,
-        # Tail planes update in place; indices count every flattened input
-        # including the 6 scalar-prefetch operands.
-        input_output_aliases={9: 1, 10: 2, 11: 3, 12: 4},
+        input_output_aliases={
+            first_tail + i: 1 + i for i in range(len(tails))
+        },
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=(
+                ("parallel", "arbitrary") if by_grid else ("parallel",)
+            ),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
     )(lref, sref, table, base_len.astype(jnp.int32),
       tail_valid_len.astype(jnp.int32), q_positions.astype(jnp.int32),
-      qr, knr, vnr,
-      tail_k, tail_ks, tail_v, tail_vs,
-      pool_k, _scale_rows(pool_ks), pool_v, _scale_rows(pool_vs))
-    return out.reshape(b, 1, hq, d), tk, tks, tv, tvs
+      qr, *fresh, *tails,
+      *[
+          x for plane, sc in pools
+          for x in (*[plane] * len(pool_specs), _scale_rows(sc))
+      ])
+    return (out.reshape(b, 1, hq, d), *new_tails)
 
 
 def _qpaged_fused_kernel(
@@ -708,28 +850,9 @@ def _qpaged_fused_kernel(
     vlen_ref,   # SMEM [B] int32 (valid tail slots incl. this write)
     qpos_ref,   # SMEM [B] int32 (query positions)
     q_ref,      # [1, Hkv, G, D]
-    kn_ref,     # [1, Hkv, 1, D]
-    vn_ref,     # [1, Hkv, 1, D]
-    tk_ref,     # [1, 1, Hkv, KT, D] int8 (in)
-    tks_ref,    # [1, 1, Hkv, KT] f32 (in)
-    tv_ref,     # [1, 1, Hkv, KT, D] int8 (in)
-    tvs_ref,    # [1, 1, Hkv, KT] f32 (in)
-    k_hbm,      # HBM [L, P, Hkv, PS, D] int8 (the whole pool)
-    ks_ref,     # [1, T, Hkv, PS] f32 (the row's scale rows, by table slot)
-    v_hbm,      # HBM [L, P, Hkv, PS, D] int8
-    vs_ref,     # [1, T, Hkv, PS] f32
-    out_ref,    # [1, Hkv, G, D]
-    tk_out,     # aliased tail outputs
-    tks_out,
-    tv_out,
-    tvs_out,
-    k_buf,      # VMEM [2, N, Hkv, PS, D] int8 (two blocks of N pages)
-    v_buf,      # VMEM [2, N, Hkv, PS, D] int8
-    sems,       # DMA [2, N]: one a page buffer, its K and V together
-    acc_ref,    # VMEM [Hkv*G, D] f32
-    m_ref,      # VMEM [Hkv*G, 128] f32
-    l_ref,      # VMEM [Hkv*G, 128] f32
-    *,
+    *refs,
+    planes: int,
+    by_grid: bool,
     scale: float,
     page_size: int,
     pages_per_block: int,
@@ -738,8 +861,41 @@ def _qpaged_fused_kernel(
     g: int,
     kt: int,
 ):
-    b = pl.program_id(0)
+    """``refs``, for the stored planes K and V (``planes`` 2) or the one
+    plane that is both (``planes`` 1), a plane after the other in each group:
+
+    * fresh values ``[1, Hkv, 1, D]``;
+    * tail in: values ``[1, 1, Hkv, KT, D]`` int8, scale row
+      ``[1, 1, Hkv, KT]`` f32;
+    * pool: HBM ``[L, P, Hkv, PS, D]`` int8 (the whole pool) or, ``by_grid``,
+      the N pages of this step's block ``[1, 1, Hkv, PS, D]`` each; then the
+      row's scale rows by table slot ``[1, T, Hkv, PS]`` f32;
+    * ``out_ref`` ``[1, Hkv, G, D]``, then the aliased tail outputs;
+    * scratch: unless ``by_grid``, a plane's VMEM ``[2, N, Hkv, PS, D]`` int8
+      (two blocks of N pages) and DMA semaphores ``[2, N]`` (one a page
+      buffer, its planes together); ``acc`` ``[Hkv*G, D]``, ``m`` and ``l``
+      ``[Hkv*G, 128]`` f32.
+    """
+    refs = list(refs)
     n = pages_per_block
+
+    def _take(count):
+        taken = refs[:count]
+        del refs[:count]
+        return taken
+
+    new_refs = _take(planes)
+    tail_in = _take(2 * planes)
+    per = n + 1 if by_grid else 2
+    pool = _take(per * planes)
+    (out_ref,) = _take(1)
+    tail_out = _take(2 * planes)
+    bufs = [] if by_grid else _take(planes)
+    sems = None if by_grid else _take(1)[0]
+    acc_ref, m_ref, l_ref = refs
+    scale_rows = pool[per - 1 :: per]
+
+    b = pl.program_id(0)
     layer = lidx_ref[0]
     # A row with no valid tail slot is not decoding (an empty slot, a
     # released one, one parked mid-prefill: a decoding row's tail holds at
@@ -747,20 +903,135 @@ def _qpaged_fused_kernel(
     # tenant left there, so it is not believed: the row sweeps nothing.
     kv_len = jnp.where(vlen_ref[b] > 0, len_ref[b], 0)
     qpos = qpos_ref[b]
-
-    # The row's live pages [lo, hi): what lies past its length, or wholly
-    # before its window, is never fetched and never computed. Both ends are
-    # held inside the table: a length is the caller's word, and a page id
-    # read past the table's end would be the source of a DMA.
-    hi = jnp.clip(
-        (kv_len + page_size - 1) // page_size, 0, table_ref.shape[1]
+    lo, hi = _live_pages(
+        kv_len, qpos, page_size, table_ref.shape[1], sliding_window
     )
-    if sliding_window is None:
-        lo = 0
-    else:
-        lo = jnp.minimum(
-            jnp.maximum(qpos - sliding_window + 1, 0) // page_size, hi
+
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def _accumulate(s, valid):
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        l_prev = l_ref[:, :1]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[:] = jnp.broadcast_to(
+            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
         )
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        return p, alpha
+
+    def _tile(stored, valid, width):
+        """One tile of the online softmax over ``stored``, a plane's
+        ``(values [Hkv, width, D], scale row)`` after the other: K then V,
+        or the one plane that is both (its bf16 form is then made once)."""
+        (kk, kks), (vv, vvs) = stored[0], stored[-1]
+        # q from its block every tile: held across the page loop it cost
+        # 3% of the kernel's time on the chip (PERF.md §6, PR 24).
+        qb = q_ref[0].astype(jnp.bfloat16).reshape(hkv, g, -1)
+        kb = kk.astype(jnp.bfloat16)
+        s = jax.lax.dot_general(
+            qb, kb.reshape(hkv, width, -1),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                        # [Hkv, G, W]
+        s = (s * kks[:, None, :] * scale).reshape(hkv * g, width)
+        p, alpha = _accumulate(s, valid)
+        pw = p.reshape(hkv, g, width) * vvs[:, None, :]
+        pv = jax.lax.dot_general(
+            pw.astype(jnp.bfloat16),
+            kb if vv is kk else vv.astype(jnp.bfloat16),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(hkv * g, -1)
+
+    def _valid(first_page, width):
+        """Which of ``width`` positions from ``first_page`` on the query
+        sees: those the row holds, inside its window."""
+        pos = first_page * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, width), 1
+        )
+        valid = pos < kv_len
+        if sliding_window is not None:
+            valid &= pos > qpos - sliding_window
+        return valid
+
+    def _finish():
+        """Once a row, after its pages: the step's fresh values into the
+        tail (absmax / 127 a token a head, ``cache/dense.py:_quantize_kv``'s
+        rule), the tail as the last tile, the division."""
+        step = step_ref[0]
+        new = [ref[0].astype(jnp.float32) for ref in new_refs]  # [Hkv, 1, D]
+        new_sc = [
+            jnp.maximum(jnp.max(jnp.abs(x), axis=-1), 1e-8) / 127.0
+            for x in new
+        ]
+        new_q = [
+            jnp.clip(jnp.round(x / sc[..., None]), -127, 127).astype(jnp.int8)
+            for x, sc in zip(new, new_sc)
+        ]
+        # Two iotas, not ``hit3[..., 0]``: Mosaic (jax 0.9.0) refuses the
+        # squeeze of a mask's lane dim ("Invalid vector register cast").
+        hit3 = jax.lax.broadcasted_iota(jnp.int32, (1, kt, 1), 1) == step
+        hit2 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1) == step
+        tail_vals = [
+            jnp.where(hit3, xq, ref[0, 0])                    # [Hkv, KT, D]
+            for xq, ref in zip(new_q, tail_in[0::2])
+        ]
+        tail_scs = [
+            jnp.where(hit2, sc, ref[0, 0])                    # [Hkv, KT]
+            for sc, ref in zip(new_sc, tail_in[1::2])
+        ]
+        for ref, x in zip(tail_out[0::2], tail_vals):
+            ref[0, 0] = x
+        for ref, x in zip(tail_out[1::2], tail_scs):
+            ref[0, 0] = x
+
+        pos1 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1)
+        tail_valid = pos1 < vlen_ref[b]
+        if sliding_window is not None:
+            tail_valid &= kv_len + pos1 > qpos - sliding_window
+        _tile(list(zip(tail_vals, tail_scs)), tail_valid, kt)
+
+        out = acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-20)
+        out_ref[0] = out.reshape(hkv, g, -1).astype(out_ref.dtype)
+
+    if by_grid:
+        # The block of the row's table this grid step holds, its pages the
+        # step's pipelined operands: put side by side they are ONE tile, if
+        # any of them is live (a page a tile is a chain of matmul, maximum,
+        # exponent, sum, matmul that the next page's waits for: 0.54 us a
+        # live page against 0.20-0.25, PERF.md §6, PR 31). A dead page
+        # beside a live one is the null page under a mask (its scale row any
+        # row the table has).
+        blk = pl.program_id(1)
+        pl.when(blk == 0)(_init)
+
+        @pl.when((blk * n < hi) & (blk * n + n > lo))
+        def _block_tile():
+            last = table_ref.shape[1] - 1
+            _tile(
+                [
+                    (jnp.concatenate(
+                        [pool[plane * per + i][0, 0] for i in range(n)], 1),
+                     jnp.concatenate(
+                        [scale_rows[plane][0, jnp.minimum(blk * n + i, last)]
+                         for i in range(n)], -1))
+                    for plane in range(planes)
+                ],
+                _valid(blk * n, n * page_size), n * page_size,
+            )
+
+        pl.when(blk == pl.num_programs(1) - 1)(_finish)
+        return
+
+    hbms = pool[0::per]
     num_blocks = (hi - lo + n - 1) // n
 
     def _page_copies(slot, i, page):
@@ -769,7 +1040,7 @@ def _qpaged_fused_kernel(
             pltpu.make_async_copy(
                 hbm.at[layer, phys], buf.at[slot, i], sems.at[slot, i]
             )
-            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf))
+            for hbm, buf in zip(hbms, bufs)
         ]
 
     def _block_pages(blk, body):
@@ -795,42 +1066,7 @@ def _qpaged_fused_kernel(
     def _first_block():
         _start_block(0, 0)
 
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
-
-    def _accumulate(s, valid):
-        s = jnp.where(valid, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_ref[:] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
-        )
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        return p, alpha
-
-    def _tile(kk, kks, vv, vvs, valid, width):
-        # q from its block every tile: held across the page loop it cost
-        # 3% of the kernel's time on the chip (PERF.md §6, PR 24).
-        s = jax.lax.dot_general(
-            q_ref[0].astype(jnp.bfloat16).reshape(hkv, g, -1),
-            kk.astype(jnp.bfloat16).reshape(hkv, width, -1),
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                        # [Hkv, G, W]
-        s = (s * kks[:, None, :] * scale).reshape(hkv * g, width)
-        p, alpha = _accumulate(s, valid)
-        pw = p.reshape(hkv, g, width) * vvs[:, None, :]
-        pv = jax.lax.dot_general(
-            pw.astype(jnp.bfloat16), vv.astype(jnp.bfloat16),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[:] = acc_ref[:] * alpha + pv.reshape(hkv * g, -1)
+    _init()
 
     def _block(blk, carry):
         slot = blk % 2
@@ -842,61 +1078,29 @@ def _qpaged_fused_kernel(
         def attend(i, page):
             for copy in _page_copies(slot, i, page):
                 copy.wait()
-            pos = page * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (1, page_size), 1
+            valid = _valid(page, page_size)
+            _tile(
+                [(buf[slot, i], rows[0, page])
+                 for buf, rows in zip(bufs, scale_rows)],
+                valid, page_size,
             )
-            valid = pos < kv_len
-            if sliding_window is not None:
-                valid &= pos > qpos - sliding_window
-            _tile(k_buf[slot, i], ks_ref[0, page], v_buf[slot, i],
-                  vs_ref[0, page], valid, page_size)
 
         _block_pages(blk, attend)
         return carry
 
     jax.lax.fori_loop(0, num_blocks, _block, 0)
-
-    # Once a row, after its pages: the step's K/V into the tail, the tail
-    # as the last tile, the division.
-    step = step_ref[0]
-    kn = kn_ref[0].astype(jnp.float32)     # [Hkv, 1, D]
-    vn = vn_ref[0].astype(jnp.float32)
-    ksc = jnp.maximum(jnp.max(jnp.abs(kn), axis=-1), 1e-8) / 127.0
-    vsc = jnp.maximum(jnp.max(jnp.abs(vn), axis=-1), 1e-8) / 127.0
-    kq = jnp.clip(jnp.round(kn / ksc[..., None]), -127, 127).astype(jnp.int8)
-    vq = jnp.clip(jnp.round(vn / vsc[..., None]), -127, 127).astype(jnp.int8)
-    # Two iotas, not ``hit3[..., 0]``: Mosaic (jax 0.9.0) refuses the
-    # squeeze of a mask's lane dim ("Invalid vector register cast").
-    hit3 = jax.lax.broadcasted_iota(jnp.int32, (1, kt, 1), 1) == step
-    hit2 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1) == step
-    tk = jnp.where(hit3, kq, tk_ref[0, 0])    # [Hkv, KT, D]
-    tv = jnp.where(hit3, vq, tv_ref[0, 0])
-    tks = jnp.where(hit2, ksc, tks_ref[0, 0])  # [Hkv, KT]
-    tvs = jnp.where(hit2, vsc, tvs_ref[0, 0])
-    tk_out[0, 0] = tk
-    tv_out[0, 0] = tv
-    tks_out[0, 0] = tks
-    tvs_out[0, 0] = tvs
-
-    pos1 = jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1)
-    tail_valid = pos1 < vlen_ref[b]
-    if sliding_window is not None:
-        tail_valid &= kv_len + pos1 > qpos - sliding_window
-    _tile(tk, tks, tv, tvs, tail_valid, kt)
-
-    out = acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-20)
-    out_ref[0] = out.reshape(hkv, g, -1).astype(out_ref.dtype)
+    _finish()
 
 
 def paged_tail_flush(
     pool_k: jnp.ndarray,
     pool_ks: jnp.ndarray,
-    pool_v: jnp.ndarray,
-    pool_vs: jnp.ndarray,
+    pool_v: Optional[jnp.ndarray],
+    pool_vs: Optional[jnp.ndarray],
     tail_k: jnp.ndarray,
     tail_ks: jnp.ndarray,
-    tail_v: jnp.ndarray,
-    tail_vs: jnp.ndarray,
+    tail_v: Optional[jnp.ndarray],
+    tail_vs: Optional[jnp.ndarray],
     page_table: jnp.ndarray,
     base_len: jnp.ndarray,
     tail_len: jnp.ndarray,
@@ -918,7 +1122,9 @@ def paged_tail_flush(
     scales), KT <= page_size. Rows must have table slots mapped through
     ``base_len + tail_len`` (engine growth contract); clamped visits hit
     the null page 0 and compose no changes. Returns the four updated pool
-    planes (inputs consumed — aliased).
+    planes (inputs consumed — aliased). A pool of ONE stored plane (the
+    latent cache's: the same array cannot be aliased twice) passes ``None``
+    for the four V arguments and gets its two planes back.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -929,6 +1135,9 @@ def paged_tail_flush(
     if kt > ps:
         raise ValueError(f"tail ({kt}) must fit one page ({ps})")
     nj = -(-kt // ps) + 1  # straddle: at most 2 pages per row's window
+    planes = 1 if pool_v is None else 2
+    tails = [tail_k, tail_ks, tail_v, tail_vs][: 2 * planes]
+    pools = [pool_k, pool_ks, pool_v, pool_vs][: 2 * planes]
 
     def _pidx(li, bi, ji, table, lens, tl):
         slot = jnp.minimum(lens[bi] // ps + ji, t - 1)
@@ -944,10 +1153,11 @@ def paged_tail_flush(
     def _tidx3(li, bi, ji, table, lens, tl):
         return (li, bi, 0, 0)
 
-    def kernel(table_ref, lens_ref, tl_ref,
-               tk, tks, tv, tvs,
-               pk_in, pks_in, pv_in, pvs_in,
-               pk_out, pks_out, pv_out, pvs_out):
+    def kernel(table_ref, lens_ref, tl_ref, *refs):
+        # A plane's (values, scale row) after the other in each group.
+        tail_refs = refs[: 2 * planes]
+        pool_in = refs[2 * planes : 4 * planes]
+        pool_out = refs[4 * planes :]
         bi = pl.program_id(1)
         ji = pl.program_id(2)
         start = lens_ref[bi]
@@ -976,51 +1186,45 @@ def paged_tail_flush(
                 cur = jnp.where(hit, tail[:, i : i + 1], cur)
             out_ref[0, 0] = cur
 
-        compose_values(pk_in, tk, pk_out)
-        compose_values(pv_in, tv, pv_out)
-        compose_scales(pks_in, tks, pks_out)
-        compose_scales(pvs_in, tvs, pvs_out)
+        for compose, first in ((compose_values, 0), (compose_scales, 1)):
+            for i in range(first, 2 * planes, 2):
+                compose(pool_in[i], tail_refs[i], pool_out[i])
+
+    def _pool_specs():
+        return [
+            pl.BlockSpec((1, 1, hkv, ps, d), _pidx),
+            pl.BlockSpec((1, 1, hkv, ps), _pidx4),
+        ] * planes
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(num_l, b, nj),
         in_specs=[
-            pl.BlockSpec((1, 1, hkv, kt, d), _tidx),
-            pl.BlockSpec((1, 1, hkv, kt), _tidx3),
-            pl.BlockSpec((1, 1, hkv, kt, d), _tidx),
-            pl.BlockSpec((1, 1, hkv, kt), _tidx3),
-            pl.BlockSpec((1, 1, hkv, ps, d), _pidx),
-            pl.BlockSpec((1, 1, hkv, ps), _pidx4),
-            pl.BlockSpec((1, 1, hkv, ps, d), _pidx),
-            pl.BlockSpec((1, 1, hkv, ps), _pidx4),
+            *[
+                pl.BlockSpec((1, 1, hkv, kt, d), _tidx),
+                pl.BlockSpec((1, 1, hkv, kt), _tidx3),
+            ] * planes,
+            *_pool_specs(),
         ],
-        out_specs=(
-            pl.BlockSpec((1, 1, hkv, ps, d), _pidx),
-            pl.BlockSpec((1, 1, hkv, ps), _pidx4),
-            pl.BlockSpec((1, 1, hkv, ps, d), _pidx),
-            pl.BlockSpec((1, 1, hkv, ps), _pidx4),
-        ),
+        out_specs=tuple(_pool_specs()),
         scratch_shapes=[],
     )
     return pl.pallas_call(
         kernel,
         name="paged_tail_flush",
-        out_shape=(
-            jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype),
-            jax.ShapeDtypeStruct(pool_ks.shape, pool_ks.dtype),
-            jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype),
-            jax.ShapeDtypeStruct(pool_vs.shape, pool_vs.dtype),
+        out_shape=tuple(
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools
         ),
         grid_spec=grid_spec,
         interpret=interpret,
-        # Inputs counting scalars: table 0, lens 1, tl 2, tails 3-6,
-        # pools 7-10.
-        input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3},
+        # Inputs counting scalars: table 0, lens 1, tl 2, then the tails,
+        # then the pools, each aliased to its output.
+        input_output_aliases={
+            3 + len(tails) + i: i for i in range(len(pools))
+        },
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
     )(page_table.astype(jnp.int32), base_len.astype(jnp.int32),
-      tail_len.astype(jnp.int32),
-      tail_k, tail_ks, tail_v, tail_vs,
-      pool_k, pool_ks, pool_v, pool_vs)
+      tail_len.astype(jnp.int32), *tails, *pools)

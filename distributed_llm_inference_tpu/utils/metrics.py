@@ -84,6 +84,11 @@ METRICS = {
         "counter", "Rows x q-blocks x table width of the same dispatches"
     ),
     "decode_tokens": ("counter", "Tokens emitted by decode"),
+    # decode ticks of an engine whose decode_steps is 1 (a cache without a
+    # write-behind tail, or the operator's choice): a token a dispatch
+    "decode_one_token_ticks": (
+        "counter", "Decode ticks dispatched on the one-token path"
+    ),
     "cache_growths": ("counter", "KV cache reallocations"),
     # latent (MLA) KV compression (cache/latent.py)
     "kv_bytes_per_token": ("gauge", "Stored KV bytes per token, all layers"),

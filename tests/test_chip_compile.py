@@ -136,7 +136,7 @@ def test_fused_int8_paged_decode_kernel(chip, rows, width, pages, window):
 # moonlight-16b-a3b.reason1k: 16 query heads on ONE latent head, the stored
 # row 512 + 64 = 576 wide (not a multiple of the 128 lanes), int8 with a
 # float32 scale a token, 1792 pages of 64, 32 slots.
-LAT_HQ, LAT_W, LAT_PAGES = 16, 576, 1792
+LAT_HQ, LAT_W, LAT_PAGES, LAT_LAYERS = 16, 576, 1792, 16
 
 
 def _latent_pool(s, pool):
@@ -158,6 +158,44 @@ def test_latent_decode_kernels_at_moonlights_shapes(chip, pool, width):
         lambda q, *a: kernel(q, *a, scale=192 ** -0.5, interpret=False),
         s((b, 1, LAT_HQ, LAT_W), jnp.bfloat16), *_latent_pool(s, pool),
         s((b, width), I32), s((b,), I32),
+    )
+
+
+@pytest.mark.parametrize("width", [59, SLOTS])   # the cell's pinned table; the cap
+def test_fused_latent_decode_kernel_at_reason1ks_shapes(chip, width):
+    """The one-stored-plane form of the fused int8 decode kernel as
+    ``moonlight-16b-a3b.reason1k``'s decode scan gives it: the WHOLE
+    16-layer latent pool in HBM (an async copy of a ``[1, 64, 576]`` int8
+    page out of it), 32 rows, a 16-slot tail, bf16 matmuls over the 576-wide
+    row as stored."""
+    s, b = chip, 32
+    _compiles_with_kernel(
+        lambda *a: pa.quantized_latent_paged_fused_attention(
+            *a, scale=192 ** -0.5, interpret=False
+        ),
+        s((b, 1, LAT_HQ, LAT_W), jnp.bfloat16),
+        s((b, 1, 1, LAT_W), jnp.bfloat16),
+        s((LAT_LAYERS, LAT_PAGES, 1, PS, LAT_W), I8),
+        s((LAT_LAYERS, LAT_PAGES, 1, PS), F32),
+        s((LAT_LAYERS, b, 1, KT, LAT_W), I8), s((LAT_LAYERS, b, 1, KT), F32),
+        s((), I32), s((), I32),
+        s((b, width), I32), s((b,), I32), s((b,), I32), s((b,), I32),
+    )
+
+
+@pytest.mark.parametrize("width", [59, SLOTS])
+def test_one_plane_tail_flush_at_reason1ks_shapes(chip, width):
+    """``paged_tail_flush`` over the latent pool's one stored plane: two
+    aliased pool operands where the per-head pool has four."""
+    s, b = chip, 32
+    _compiles_with_kernel(
+        lambda c, cs, tc, tcs, *rows: pa.paged_tail_flush(
+            c, cs, None, None, tc, tcs, None, None, *rows, interpret=False
+        ),
+        s((LAT_LAYERS, LAT_PAGES, 1, PS, LAT_W), I8),
+        s((LAT_LAYERS, LAT_PAGES, 1, PS), F32),
+        s((LAT_LAYERS, b, 1, KT, LAT_W), I8), s((LAT_LAYERS, b, 1, KT), F32),
+        s((b, width), I32), s((b,), I32), s((b,), I32),
     )
 
 

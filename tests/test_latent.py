@@ -408,3 +408,278 @@ def test_spec_adapt_normalizes_per_spec_row():
     eng.sessions = {}
     eng._spec_adapt([])
     assert eng._spec_ctl["win_t0"] is None
+
+
+# -- the write-behind tail of the int8 latent pool ------------------------------
+#
+# ``QuantizedLatentPagedKVCache`` decodes through the one-stored-plane form of
+# ``quantized_paged_fused_attention`` (ops/paged_attention.py): the fused
+# sweep's body with ONE pool operand, one page buffer, one tail plane. A
+# stored row of whole 128-lane tiles, or one narrower than a tile (every pool
+# of this suite but the 160-wide one), is swept by the kernel's own async
+# copies; a row like the latent pool's 576, which Mosaic cannot slice, gets
+# its pages as pipelined blocks (``_pages_by_grid``): 160 = 128 + 32 is that
+# row at a size the interpreter walks in seconds.
+
+import numpy as np
+
+from distributed_llm_inference_tpu.cache.dense import _quantize_kv
+from distributed_llm_inference_tpu.cache.latent import (
+    QuantizedLatentPagedKVCache,
+)
+from distributed_llm_inference_tpu.ops import paged_attention as pa
+
+_TAIL = dict(ps=16, t=5, kt=16, g=4, layers=2, layer=1, scale=0.2)
+
+
+def _tail_rows(rows):
+    """(pool length, decoding) a row: a released slot whose length is stale
+    (it sweeps nothing), one token, a part of a page, several pages (a block
+    and a partial one), and all the table holds beside the window's tail."""
+    ps, t, kt = _TAIL["ps"], _TAIL["t"], _TAIL["kt"]
+    kinds = [(3 * ps + 2, False), (1, True), (ps - 3, True),
+             (3 * ps + 2, True), (t * ps - kt, True)]
+    return {1: kinds[3:4], 2: [kinds[0], kinds[4]], 5: kinds}[rows]
+
+
+def _tail_inputs(rows, d, step, seed=0):
+    f = _TAIL
+    ps, t, kt, g, layers = f["ps"], f["t"], f["kt"], f["g"], f["layers"]
+    rng = np.random.default_rng([seed, rows, d, step])
+    lens, decoding = map(np.asarray, zip(*_tail_rows(rows)))
+    pages = rows * t + 1
+    # every page no live token owns, and every slot past a row's length, is
+    # poison: a dead page read, or a dead slot unmasked, shows
+    pool = np.full((layers, pages, 1, ps, d), 127, np.int8)
+    scales = np.full((layers, pages, 1, ps), 1e30, np.float32)
+    table = np.zeros((rows, t), np.int32)
+    ids = rng.permutation(np.arange(1, pages))
+    for r in range(rows):
+        table[r] = ids[r * t:(r + 1) * t]
+        for pos in range(lens[r]):
+            pool[:, table[r, pos // ps], 0, pos % ps] = rng.integers(
+                -127, 128, (layers, d))
+            scales[:, table[r, pos // ps], 0, pos % ps] = rng.uniform(
+                0.01, 0.03, layers)
+    tail = rng.integers(-127, 128, (layers, rows, 1, kt, d)).astype(np.int8)
+    tail_s = rng.uniform(0.01, 0.03, (layers, rows, 1, kt)).astype(np.float32)
+    return dict(
+        q=jnp.asarray(rng.normal(size=(rows, 1, g, d)), jnp.bfloat16),
+        c_new=jnp.asarray(rng.normal(size=(rows, 1, 1, d)), jnp.bfloat16),
+        pool_c=jnp.asarray(pool), pool_cs=jnp.asarray(scales),
+        tail_c=jnp.asarray(tail), tail_cs=jnp.asarray(tail_s),
+        layer_idx=f["layer"], step_idx=step, page_table=jnp.asarray(table),
+        base_len=jnp.asarray(lens, jnp.int32),
+        tail_valid_len=jnp.asarray(np.where(decoding, step + 1, 0), jnp.int32),
+        q_positions=jnp.asarray(lens + step, jnp.int32), scale=f["scale"],
+    )
+
+
+def _tail_oracle(a, tail_c, tail_cs):
+    """float32 softmax over a decoding row's live pool positions and valid
+    tail slots, dequantised; zeros for a row that is not decoding."""
+    ps, layer = _TAIL["ps"], _TAIL["layer"]
+    pool, scales = np.asarray(a["pool_c"]), np.asarray(a["pool_cs"])
+    table, lens = np.asarray(a["page_table"]), np.asarray(a["base_len"])
+    vlen = np.asarray(a["tail_valid_len"])
+    q = np.asarray(a["q"].astype(jnp.float32))
+    out = np.zeros(q.shape, np.float32)
+    for r in range(q.shape[0]):
+        if not vlen[r]:
+            continue
+        kv = [pool[layer, table[r, p // ps], 0, p % ps].astype(np.float32)
+              * scales[layer, table[r, p // ps], 0, p % ps]
+              for p in range(lens[r])]
+        kv += [np.asarray(tail_c[layer, r, 0, i], np.float32)
+               * float(tail_cs[layer, r, 0, i]) for i in range(vlen[r])]
+        kv = np.stack(kv)
+        s = q[r, 0] @ kv.T * a["scale"]
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[r, 0] = (p / p.sum(-1, keepdims=True)) @ kv
+    return out
+
+
+@pytest.mark.parametrize("step", [0, 7, 15])
+@pytest.mark.parametrize("d", [24, 160], ids=["copied-pages", "pipelined-pages"])
+@pytest.mark.parametrize("rows", [1, 2, 5])
+def test_one_plane_fused_decode_matches_reference_and_grid_form(rows, d, step):
+    a = _tail_inputs(rows, d, step)
+    assert pa._pages_by_grid(d) == (d == 160)
+    out, tail_c, tail_cs = pa.quantized_latent_paged_fused_attention(**a)
+    layer = _TAIL["layer"]
+    # the step's latent lands at its slot, quantised by the pool's own rule;
+    # the other slots and the other layer are as they were
+    # (jitted, as the one-token path's scatter runs it: compiled, XLA turns
+    # ``/ 127`` into a multiply)
+    want_c, want_cs = jax.jit(_quantize_kv)(a["c_new"])
+    np.testing.assert_array_equal(tail_c[layer, :, :, step], want_c[:, 0])
+    np.testing.assert_array_equal(tail_cs[layer, :, :, step], want_cs[:, 0])
+    keep = np.arange(_TAIL["kt"]) != step
+    np.testing.assert_array_equal(
+        np.asarray(tail_c)[layer][:, :, keep], np.asarray(a["tail_c"])[layer][:, :, keep])
+    np.testing.assert_array_equal(tail_c[1 - layer], a["tail_c"][1 - layer])
+    np.testing.assert_array_equal(tail_cs[1 - layer], a["tail_cs"][1 - layer])
+
+    got = np.asarray(out.astype(jnp.float32))
+    assert np.isfinite(got).all(), "a dead page or a dead slot was read"
+    decoding = np.asarray(a["tail_valid_len"]) > 0
+    assert (got[~decoding] == 0).all(), "a released row attends to nothing"
+    # bf16 operands into f32 sums, the probabilities rounded to 8 bits before
+    # they meet the values (|value| <= 127 * 0.03)
+    np.testing.assert_allclose(
+        got, _tail_oracle(a, tail_c, tail_cs), atol=0.03, rtol=0.02)
+
+    # the grid form (one layer's pool, already written, every tile of slots x
+    # table width, float32 sums): flush the tail as the engine would and ask it
+    tail_len = a["tail_valid_len"]
+    new_c, new_cs = pa.paged_tail_flush(
+        a["pool_c"], a["pool_cs"], None, None, tail_c, tail_cs, None, None,
+        a["page_table"], a["base_len"], tail_len,
+    )
+    grid = pa.quantized_latent_paged_attention(
+        a["q"], new_c[layer], new_cs[layer], a["page_table"],
+        jnp.where(tail_len > 0, a["base_len"] + tail_len, 0), scale=a["scale"],
+    )
+    np.testing.assert_allclose(
+        got, np.asarray(grid.astype(jnp.float32)), atol=0.03, rtol=0.02)
+
+
+@pytest.mark.parametrize("page_size", [8, 16], ids=["scattered", "page-rmw"])
+def test_one_plane_tail_flush_writes_what_the_one_token_path_writes(page_size):
+    """The window's tail lands in the pool at each row's next positions, the
+    slots past a row's tail length and the rows that wrote nothing left as
+    they were: a 16-slot tail over pages of 16 (the blocked page
+    read-modify-write, one plane) and over pages of 8 (the scatter)."""
+    layers, rows, t, kt, d = 2, 3, 6, 16, 24
+    rng = np.random.default_rng(page_size)
+    cache = QuantizedLatentPagedKVCache.create(
+        layers, rows, rows * t + 1, page_size, t, 1, d, use_kernel=True)
+    ids = rng.permutation(np.arange(1, rows * t + 1)).reshape(rows, t)
+    lens = np.asarray([page_size - 3, 0, 2 * page_size + 1], np.int32)
+    wrote = np.asarray([kt, 0, 5], np.int32)
+    cache = cache.replace(
+        k_pages=jnp.asarray(rng.integers(-127, 128, cache.k_pages.shape), jnp.int8),
+        cs_pages=jnp.asarray(rng.uniform(0.01, 0.03, cache.cs_pages.shape), jnp.float32),
+        page_table=jnp.asarray(ids, jnp.int32), lengths=jnp.asarray(lens),
+    )
+    tail_c = rng.integers(-127, 128, (layers, rows, 1, kt, d)).astype(np.int8)
+    tail_cs = rng.uniform(0.01, 0.03, (layers, rows, 1, kt)).astype(np.float32)
+    want_c, want_cs = np.asarray(cache.k_pages).copy(), np.asarray(cache.cs_pages).copy()
+    for r in range(rows):
+        for i in range(wrote[r]):
+            pos = lens[r] + i
+            want_c[:, ids[r, pos // page_size], 0, pos % page_size] = tail_c[:, r, 0, i]
+            want_cs[:, ids[r, pos // page_size], 0, pos % page_size] = tail_cs[:, r, 0, i]
+    new = cache.tail_flush((jnp.asarray(tail_c), jnp.asarray(tail_cs)), jnp.asarray(wrote))
+    # page 0 is the null page: diverted writes may land there
+    np.testing.assert_array_equal(np.asarray(new.k_pages)[:, 1:], want_c[:, 1:])
+    np.testing.assert_array_equal(np.asarray(new.cs_pages)[:, 1:], want_cs[:, 1:])
+    np.testing.assert_array_equal(new.lengths, lens + wrote)
+
+
+def _kernel_cache(cfg, page_size, rows=2, slots=8):
+    cache = QuantizedLatentPagedKVCache.create(
+        cfg.num_layers, rows, rows * slots + 1, page_size, slots, 1,
+        cfg.latent.lat_dim, use_kernel=True)
+    for r in range(rows):
+        cache = cache.assign_pages(r, list(range(1 + r * slots, 1 + (r + 1) * slots)))
+    return cache
+
+
+@pytest.mark.parametrize("page_size", [8, 16], ids=["scattered", "page-rmw"])
+@pytest.mark.parametrize("layers", [1, 2])
+def test_sixteen_fused_steps_are_sixteen_one_token_steps(layers, page_size):
+    """``multi_decode_apply`` over the int8 latent pool (the kernel,
+    interpreted) against 16 ``model_apply`` steps over the same pool: the same
+    tokens, and after ``tail_flush`` the same pool. Bit for bit where what is
+    stored does not pass through attention first (a layer's latent is a
+    function of its input, so the first layer's, and every layer's scales'
+    and values' where no value sat on a rounding edge); the fused body rounds
+    its probabilities to bf16 where the grid form keeps float32, so a deeper
+    layer's latents may differ by one step of the int8 grid."""
+    import dataclasses
+
+    cfg = dataclasses.replace(MLA_CFG, num_layers=layers)
+    params = llama.init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    cache = _kernel_cache(cfg, page_size)
+    assert cache.has_tail and cache.tail_in_kernel and cache.tail_reads_whole_big
+    prompts = jnp.asarray([[3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37],
+                           [2, 4, 6, 8, 10, 0, 0, 0, 0, 0, 0]], jnp.int32)
+    n_valid = jnp.asarray([11, 5], jnp.int32)
+    logits, cache = llama.model_apply(cfg, params, prompts, cache, n_valid, head="last")
+    first = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+    one = jnp.ones((2,), jnp.int32)
+
+    def token(carry, _):
+        tok, cache = carry
+        logits, cache = llama.model_apply(cfg, params, tok[:, None], cache, one)
+        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        return (nxt, cache), (nxt, logits[:, 0])
+
+    (_, stepped), (want, want_logits) = jax.jit(
+        lambda c: jax.lax.scan(token, (first, c), None, length=16))(cache)
+
+    def step_fn(i, logits, state):
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return nxt, one, state, (nxt, logits)
+
+    (got, got_logits), fused = jax.jit(lambda c: llama.multi_decode_apply(
+        cfg, params, first[:, None], c, 16, step_fn, jnp.zeros(()), one))(cache)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got_logits, want_logits, atol=2e-2, rtol=2e-2)
+    np.testing.assert_array_equal(fused.lengths, stepped.lengths)
+    a_c, b_c = np.asarray(fused.k_pages)[:, 1:], np.asarray(stepped.k_pages)[:, 1:]
+    a_s, b_s = np.asarray(fused.cs_pages)[:, 1:], np.asarray(stepped.cs_pages)[:, 1:]
+    np.testing.assert_array_equal(a_c[0], b_c[0])
+    np.testing.assert_array_equal(a_s[0], b_s[0])
+    assert np.abs(a_c.astype(np.int32) - b_c).max() <= 1
+    np.testing.assert_allclose(a_s, b_s, rtol=2e-2)
+    assert (a_c != b_c).mean() < 0.02
+
+
+def _pallas_call(fn, *args):
+    (eqn,) = [e for e in jax.make_jaxpr(fn)(*args).eqns
+              if e.primitive.name == "pallas_call"]
+    return eqn
+
+
+@pytest.mark.parametrize("planes", [2, 1])
+def test_the_fused_kernels_operands_by_stored_planes(planes):
+    """The two-plane call is the call it was: two pool operands left in HBM,
+    two page buffers beside the semaphores and the three softmax scratches,
+    four tail planes aliased to their outputs. One stored plane halves each."""
+    s = jax.ShapeDtypeStruct
+    b, t, hkv, g, d, ps, kt, layers, pages = 3, 6, 2, 2, 128, 16, 4, 2, 8
+    pool = (s((layers, pages, hkv, ps, d), jnp.int8), s((layers, pages, hkv, ps), jnp.float32))
+    tail = (s((layers, b, hkv, kt, d), jnp.int8), s((layers, b, hkv, kt), jnp.float32))
+    new = s((b, 1, hkv, d), jnp.bfloat16)
+    rest = (s((), jnp.int32), s((), jnp.int32), s((b, t), jnp.int32),
+            s((b,), jnp.int32), s((b,), jnp.int32), s((b,), jnp.int32))
+    if planes == 2:
+        def call(q, kn, vn, pk, pks, pv, pvs, tk, tks, tv, tvs, *r):
+            return pa.quantized_paged_fused_attention(
+                q, kn, vn, pk, pks, pv, pvs, tk, tks, tv, tvs, *r, interpret=True)
+
+        operands = (new, new, *pool, *pool, *tail, *tail)
+    else:
+        def call(q, kn, pk, pks, tk, tks, *r):
+            return pa.quantized_paged_fused_attention(
+                q, kn, None, pk, pks, None, None, tk, tks, None, None, *r,
+                interpret=True)
+
+        operands = (new, *pool, *tail)
+    eqn = _pallas_call(call, s((b, 1, hkv * g, d), jnp.bfloat16), *operands, *rest)
+    gm = eqn.params["grid_mapping"]
+    # an operand left in HBM is the whole array, in no memory space of the
+    # kernel's own
+    in_hbm = [m for m in gm.block_mappings[:gm.num_inputs]
+              if m.block_aval.shape == (layers, pages, hkv, ps, d)
+              and "any" in str(m.block_aval)]
+    first_tail = 6 + 1 + planes
+    assert gm.grid == (b,)
+    assert len(eqn.invars) == 6 + 1 + 5 * planes
+    assert len(eqn.outvars) == 1 + 2 * planes
+    assert gm.num_scratch_operands == planes + 4
+    assert tuple(eqn.params["input_output_aliases"]) == tuple(
+        (first_tail + i, 1 + i) for i in range(2 * planes))
+    assert len(in_hbm) == planes, [str(m.block_aval) for m in gm.block_mappings]

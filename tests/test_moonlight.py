@@ -502,3 +502,95 @@ def test_the_census_counts_latent_and_expert_work_exactly():
     # 4 decode tokens a request after the prefill's first: the active rows
     assert decode_valid == 2 * 4
     assert 0 < decode_valid <= decode_padded
+
+
+# -- the int8 latent pool's write-behind tail, through the engine -------------
+
+
+def kernel_conf():
+    """The rehearsal's configuration with the cache's Pallas kernels on (the
+    chip's default; here interpreted)."""
+    conf = tiny()
+    serve = conf["serve"]
+    conf["serve"] = {**serve, "engine": {**serve["engine"], "use_pallas_attention": True}}
+    return conf
+
+
+def decode_dispatches(engine):
+    return [tuple(d[1]) for t in engine.flight.snapshot()
+            for d in t.get("dispatches", ()) if d[0] == "decode"]
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "no-kernel"])
+def test_an_int8_latent_engine_decodes_sixteen_steps_a_dispatch(kernel):
+    """With its kernel the int8 latent cache has the tail protocol, so the
+    engine resolves ``decode_steps`` 16, pipelines its ticks and every decode
+    dispatch is ``_decode_scan``'s ``(rows, 16, width)``; without it (and in
+    float32) the cache says it has none and the engine keeps the one-token
+    path, counted by ``decode_one_token_ticks``."""
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    cfg, engine = engine_for(
+        kernel_conf() if kernel else tiny(), kv_quant="int8",
+        trace_cfg=TraceConfig(),
+    )
+    assert engine.cache.has_tail is kernel
+    assert engine.decode_steps == (16 if kernel else 1)
+    assert engine._pipelined is kernel
+    out = engine.generate(
+        [list(range(1, 12)), list(range(3, 30))],
+        SamplingOptions(max_new_tokens=20),
+    )
+    assert [len(o) for o in out] == [20, 20]
+    steps = {d[1] for d in decode_dispatches(engine)}
+    assert steps == ({16} if kernel else {1})
+    one_token = engine.metrics.get_counter("decode_one_token_ticks")
+    assert (one_token == 0) if kernel else (one_token >= 19)
+    _, f32 = engine_for(kernel_conf())
+    assert not f32.cache.has_tail and f32.decode_steps == 1
+
+
+def test_a_row_of_the_fused_latent_engine_is_exported_and_resumed():
+    """A session checkpointed between two fused windows, shipped through the
+    codec and resumed on a fresh engine continues the uninterrupted stream:
+    the snapshot is the pool's planes (``c`` and ``cs``: ``PLANE_FIELDS`` as
+    it was), which after a window's flush hold what the one-token path
+    writes."""
+    from distributed_llm_inference_tpu.disagg.kv_codec import (
+        decode_session, encode_session,
+    )
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    conf = kernel_conf()
+    prompt = [3, 5, 7, 11, 13, 17, 19]
+    opts = SamplingOptions(max_new_tokens=40)
+
+    def drain(engine, gid, until=None):
+        got = []
+        for _ in range(200):
+            for g, tok, fin in engine.step():
+                if g == gid and tok >= 0:
+                    got.append(tok)
+                if g == gid and fin:
+                    return got
+            if until is not None and len(got) >= until:
+                return got
+        raise AssertionError("the generation did not end")
+
+    _, ref = engine_for(conf, kv_quant="int8")
+    base = drain(ref, ref.submit(list(prompt), opts))
+    assert len(base) == 40
+
+    _, victim = engine_for(conf, kv_quant="int8")
+    gid = victim.submit(list(prompt), opts)
+    drain(victim, gid, until=6)
+    snap = victim.export_session(gid)
+    assert set(snap["planes"]) == {"c", "cs"}
+    assert snap["planes"]["c"].dtype == np.int8
+    assert snap["planes"]["c"].shape[-1] == victim.cfg.latent.lat_dim
+    assert 6 <= len(snap["generated"]) < 40
+    snap2, meta = decode_session(
+        encode_session("mig", snap, page_size=victim.ccfg.page_size))
+    assert meta["layout"] == "latent"
+    _, fresh = engine_for(conf, kv_quant="int8")
+    assert snap["generated"] + drain(fresh, fresh.resume_session(snap2)) == base

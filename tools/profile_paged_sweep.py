@@ -2,7 +2,7 @@
 
     python tools/profile_paged_sweep.py [--rows 32] [--width 47] [--pages 1600]
         [--window 4096] [--occupancy full reason chat]
-        [--form inplace|gathered]
+        [--form inplace|gathered|latent|latent-grid]
 
 ``quantized_paged_fused_attention`` at Mistral-7B widths (32 q / 8 kv heads
 of 128, 64-token pages, 32 layers, a 16-slot tail) over a seeded int8 pool,
@@ -20,6 +20,14 @@ released slot's length stale; the kernel does not read it).
 ``INPLACE_CTX`` instead: ``quantized_fused_decode_attention`` over every
 row's table span gathered to contiguous stacks, and the gather itself (once
 a fused window of ``KT`` steps, so a ``KT``-th of it belongs to a step).
+
+``--form latent`` times the one-stored-plane form of the same kernel at
+Moonlight's widths (16 query heads on one latent head of 576, 16 layers: an
+int8 latent engine's decode step, ``quantized_latent_paged_fused_attention``)
+and ``--form latent-grid`` what such an engine ran before it had a tail: the
+``(slots, table width)`` grid of ``quantized_latent_paged_attention`` over a
+layer's slice of the pool (the slice's copy is in ``busy_ms_a_step``).
+``--occupancy reason1k``: 32 live rows of 1.5-3k tokens, that cell's mix.
 
 Import the package from another checkout with ``PYTHONPATH=<root>`` to time
 that checkout's kernel on the same chip: the tool itself uses nothing else
@@ -43,8 +51,15 @@ from distributed_llm_inference_tpu.ops import paged_attention as pa
 from distributed_llm_inference_tpu.ops import quant_attention as qa
 from distributed_llm_inference_tpu.utils.xplane import aggregate, find_xplane
 
-HQ, HKV, D, PS, LAYERS, KT = 32, 8, 128, 64, 32, 16
-KERNEL = "quantized_paged_fused_attention"
+PS, KT = 64, 16
+# form -> query heads, kv heads, stored width, layers, the kernel's trace name
+FORMS = {
+    "inplace": (32, 8, 128, 32, "quantized_paged_fused_attention"),
+    "gathered": (32, 8, 128, 32, "quantized_fused_decode_attention"),
+    "latent": (16, 1, 576, 16, "quantized_latent_paged_attention"),
+    # "..._grid_attention" since the fused form took the name
+    "latent-grid": (16, 1, 576, 16, "quantized_latent_paged_"),
+}
 
 
 def row_lengths(kind, rows, width, rng):
@@ -53,6 +68,8 @@ def row_lengths(kind, rows, width, rng):
         return np.full(rows, cap, np.int32)
     if kind == "reason":
         return np.minimum(rng.integers(214, 1100, rows), cap).astype(np.int32)
+    if kind == "reason1k":
+        return np.minimum(rng.integers(1536, 3072, rows), cap).astype(np.int32)
     if kind == "chat":
         lens = np.zeros(rows, np.int32)
         live = max(1, rows // 4)
@@ -70,8 +87,10 @@ def main():
     ap.add_argument("--occupancy", nargs="+", default=["full", "reason", "chat"])
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--form", choices=("inplace", "gathered"), default="inplace")
+    ap.add_argument("--form", choices=tuple(FORMS), default="inplace")
     args = ap.parse_args()
+    HQ, HKV, D, LAYERS, kernel = FORMS[args.form]
+    latent = args.form.startswith("latent")
     if jax.default_backend() != "tpu":
         sys.exit("a device time comes from a chip: no TPU here")
     b, t = args.rows, args.width
@@ -84,13 +103,12 @@ def main():
         k = jax.random.randint(kk, shape, -127, 128, jnp.int8)
         v = jax.random.randint(kv, shape, -127, 128, jnp.int8)
         s = jax.random.uniform(ks, shape[:-1], jnp.float32, 0.01, 0.03)
-        return k, s, v, s + 0.001
+        return (k, s) if latent else (k, s, v, s + 0.001)
 
     pool = make(kk, kv, ks)
     q = jax.random.normal(kq, (b, 1, HQ, D), jnp.bfloat16)
     new = jax.random.normal(kq, (b, 1, HKV, D), jnp.bfloat16)
-    window = args.window or None
-    kernel = KERNEL if args.form == "inplace" else "quantized_fused_decode_attention"
+    window = None if latent else args.window or None
 
     @jax.jit
     def gather(pool, table):  # cache/paged.py tail_big_stacks, kernel order
@@ -108,6 +126,17 @@ def main():
                 out, *tails = qa.quantized_fused_decode_attention(
                     q, new, new, *pool, *tails, i, jnp.int32(3),
                     lens, vlen, lens + 3, sliding_window=window,
+                )
+            elif args.form == "latent":
+                out, *tails = pa.quantized_latent_paged_fused_attention(
+                    q, new, *pool, *tails, i, jnp.int32(3), table,
+                    lens, vlen, lens + 3, scale=192 ** -0.5,
+                )
+            elif args.form == "latent-grid":
+                out = pa.quantized_latent_paged_attention(
+                    q, *(jax.lax.dynamic_index_in_dim(p, i, keepdims=False)
+                         for p in pool),
+                    table, jnp.where(vlen > 0, lens, 0), scale=192 ** -0.5,
                 )
             else:
                 out, *tails = pa.quantized_paged_fused_attention(
@@ -136,9 +165,7 @@ def main():
         tails = (
             jnp.zeros((LAYERS, b, HKV, KT, D), jnp.int8),
             jnp.zeros((LAYERS, b, HKV, KT), jnp.float32),
-            jnp.zeros((LAYERS, b, HKV, KT, D), jnp.int8),
-            jnp.zeros((LAYERS, b, HKV, KT), jnp.float32),
-        )
+        ) * (1 if latent else 2)
         big = pool
         if args.form == "gathered":
             big = jax.block_until_ready(gather(pool, jnp.asarray(table)))
@@ -170,6 +197,9 @@ def main():
             "live_pages": int(live.sum()), "kernel_calls": calls,
             "kernel_us_a_call": round(ns / max(calls, 1) / 1e3, 2),
             "kernel_ms_a_step": round(ns / args.reps / 1e6, 3),
+            "kernel_us_a_live_page": round(
+                ns / args.reps / LAYERS / max(int(live.sum()), 1) / 1e3, 4
+            ),
             "busy_ms_a_step": round(
                 agg["devices"][0]["busy_ns"] / args.reps / 1e6, 3
             ) if agg["devices"] else 0.0,
