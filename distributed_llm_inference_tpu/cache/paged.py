@@ -26,6 +26,8 @@ corrupt another session's pages.
 from __future__ import annotations
 
 import collections
+import dataclasses
+import functools
 import hashlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -36,6 +38,9 @@ from flax import struct
 
 from ..ops.attention import causal_mask
 from ..ops.rotary import RopeAngles, apply_rope
+from ..ops.sparse_attention import (
+    KERNEL_DECODE, KERNEL_INDEX_FLUSH, KERNEL_PREFILL, selection_mask,
+)
 from .base import GatherAttendMixin, flash_prefill_fn
 
 
@@ -424,8 +429,7 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
 
     def merge_row(self, sub: "PagedKVCache", row) -> "PagedKVCache":
         return self.replace(
-            k_pages=sub.k_pages,
-            v_pages=sub.v_pages,
+            **{name: getattr(sub, name) for name in self.SHARED_FIELDS},
             page_table=jax.lax.dynamic_update_slice_in_dim(
                 self.page_table, sub.page_table, row, axis=0
             ),
@@ -824,20 +828,6 @@ class QuantizedPagedKVCache(PagedKVCache):
     def with_layer_stacks(self, k, v, ks, vs) -> "QuantizedPagedKVCache":
         return self.replace(k_pages=k, v_pages=v, ks_pages=ks, vs_pages=vs)
 
-    def merge_row(self, sub, row) -> "QuantizedPagedKVCache":
-        return self.replace(
-            k_pages=sub.k_pages,
-            v_pages=sub.v_pages,
-            ks_pages=sub.ks_pages,
-            vs_pages=sub.vs_pages,
-            page_table=jax.lax.dynamic_update_slice_in_dim(
-                self.page_table, sub.page_table, row, axis=0
-            ),
-            lengths=jax.lax.dynamic_update_slice_in_dim(
-                self.lengths, sub.lengths, row, axis=0
-            ),
-        )
-
     def ingest_row(self, ks, vs, n_valid, first_slot=0):
         """Ring-prefill ingest, quantized pool form: per-(token, head)
         int8 + scale planes (cf. ``QuantizedDenseKVCache.ingest_row``)."""
@@ -1104,7 +1094,10 @@ class QuantizedPagedKVCache(PagedKVCache):
 
     def tail_attend(self, big_state, tail_state, q, k_new, v_new, rope,
                     base_len, tail_len, step_idx, num_new, sliding_window,
-                    scale=None):
+                    scale=None, select=None):
+        """``select``: a learned selection's masks of the big and the tail
+        segment (:class:`IndexedQuantizedPagedKVCache`), in the form the
+        path taken wants them; None attends to every live key."""
         from ..ops.attention import gqa_attention_quantized_segments
         from .dense import segment_valids
 
@@ -1127,8 +1120,13 @@ class QuantizedPagedKVCache(PagedKVCache):
                     tail_valid_len=tail_len + num_new,
                     q_positions=base_len + tail_len,
                     scale=scale, sliding_window=sliding_window,
+                    **({} if select is None else {
+                        "select": select, "name": KERNEL_DECODE,
+                    }),
                 )
                 return out, (ntk, ntv, ntks, ntvs)
+            if select is not None:
+                raise ValueError("a selection decodes over the pool in place")
             from ..ops.quant_attention import (
                 quantized_fused_decode_attention,
             )
@@ -1155,6 +1153,9 @@ class QuantizedPagedKVCache(PagedKVCache):
             base_len, tail_len, num_new, gk.shape[2], tk.shape[2],
             sliding_window,
         )
+        if select is not None:
+            big_valid &= select[0]
+            tail_valid &= select[1]
         ones = jnp.ones(tk.shape[:3], jnp.float32)
         out = gqa_attention_quantized_segments(
             q_rot,
@@ -1212,3 +1213,315 @@ class QuantizedPagedKVCache(PagedKVCache):
             k_pages=new_k, v_pages=new_v, ks_pages=new_ks, vs_pages=new_vs,
             lengths=self.lengths + tail_len,
         )
+
+
+# -- learned sparse attention: an index plane beside K and V -------------------
+#
+# A model that selects its keys (``ModelConfig.sparse``,
+# ``ops/sparse_attention.py``) caches ONE index key a token a layer beside K
+# and V: a third kind of per-token state in the same pool, under the same page
+# table. The two classes below are their parents' own code with that plane
+# more: it is listed in ``PLANE_FIELDS``, so it travels wherever planes travel
+# (copy-on-write, the prefix store's spill and reload, export and ingest for
+# the disagg codec and for preemption with resume), it is written by the same
+# write paths (prefill scatter, decode step, tail flush), and the allocator's
+# bytes a page grow by it. ``attend`` receives the model's ``index`` inputs,
+# writes the index key, scores the row's live index keys, selects, and runs
+# the parent's attention under the selection's mask.
+#
+# The index key's width is a property of the CLASS (``INDEX_DIM``), not an
+# argument of ``create``: whoever builds "a cache like this one" from its
+# pool's shape alone (``type(cache).create(layers, batch, pages, page size,
+# slots, heads, width, dtype)``: the benchmark's probe, a resumed engine)
+# gets the plane too. :func:`indexed_cache_class` makes the class once a
+# width.
+
+
+def _row_index_keys(table, plane):
+    """A row's index keys in position order, by its page table: one layer's
+    ``plane [P, 1, PS, D]`` to ``[B, T*PS, D]``. An unmapped slot reads the
+    null page; validity comes from the lengths."""
+    b, t = table.shape
+    keys = jnp.take(plane, table, axis=0, mode="clip")
+    return keys.reshape(b, t * keys.shape[3], keys.shape[4])
+
+
+class _IndexPlane:
+    """What the two indexed classes share: the plane's place in the class's
+    field lists, its write path and its ingest. ``ik_pages [L, P, 1, PS,
+    INDEX_DIM]`` holds the index keys in the MODEL's dtype beside an int8 K
+    and V too: a selection is a hard choice, and an int8 index key moves
+    which keys a query attends to (at 46 positions and topk 8 it moved the
+    logits by 0.3 where int8 K and V move them by 0.005; tests/bench holds
+    an int8 pool to 0.05), for 60 B a token a layer more than an int8 key
+    and its scale would take, of 1184."""
+
+    INDEX_DIM = None
+    #: plane name -> pool field of what is stored beside K and V
+    INDEX_PLANES = {"ik": "ik_pages"}
+
+    @classmethod
+    def create(cls, num_layers, batch, num_pages, page_size,
+               max_pages_per_session, num_kv_heads, head_dim,
+               dtype=jnp.bfloat16, use_kernel=False, use_ragged=False):
+        """The parent pool's cache (``POOL.create``, its arguments) with a
+        zeroed index plane over the same pages: the plane's width is the
+        class's. A class without the kernels' paths (``KERNELS``) drops
+        the flags."""
+        base = cls.POOL.create(
+            num_layers, batch, num_pages, page_size, max_pages_per_session,
+            num_kv_heads, head_dim, dtype,
+            use_kernel=use_kernel and cls.KERNELS,
+            use_ragged=use_ragged and cls.KERNELS,
+        )
+        return cls(
+            **{f.name: getattr(base, f.name) for f in dataclasses.fields(base)},
+            ik_pages=jnp.zeros(
+                (num_layers, num_pages, 1, page_size, cls.INDEX_DIM), dtype
+            ),
+        )
+
+    def _scatter_index(self, lik, index_k, q_pos, num_new):
+        """Index keys ``[B, S, D]`` into one layer's plane, at the positions
+        K and V go to (``_slot_pages``: pads and strangers' pages divert to
+        the null page)."""
+        page, offset = self._slot_pages(q_pos, num_new)
+        return lik.at[page.reshape(-1), 0, offset.reshape(-1)].set(
+            index_k.reshape(-1, index_k.shape[-1]).astype(lik.dtype),
+            mode="drop",
+        )
+
+    def _select(self, index, nik, q_pos, num_new):
+        """The selection ``[B, S, T*PS]`` of a dispatch's queries over the
+        row's index keys, this dispatch's included."""
+        return selection_mask(
+            index, _row_index_keys(self.page_table, nik), q_pos,
+            self.lengths + num_new,
+        )
+
+    def ingest_index_row(self, planes, n_valid, first_slot=0):
+        """Install shipped index planes (``INDEX_PLANES`` names; ``[L, 1, S,
+        1, D]``) as ``ingest_row`` installs K and V."""
+        return self._ingest_planes(
+            {self.INDEX_PLANES[name]: a for name, a in planes.items()},
+            n_valid, first_slot,
+        )
+
+
+class IndexedPagedKVCache(_IndexPlane, PagedKVCache):
+    """:class:`PagedKVCache` with the index plane. The exact-arithmetic form
+    (float32 tests, the int8 class's oracle): attention is the gather path
+    under the selection's mask, a token a dispatch; the kernels and the
+    write-behind tail are the int8 class's."""
+
+    ik_pages: jax.Array = None
+
+    POOL, KERNELS = PagedKVCache, False
+    LAYER_FIELDS = ("k_pages", "v_pages", "ik_pages")
+    SHARED_FIELDS = LAYER_FIELDS
+    PLANE_FIELDS = {"k": "k_pages", "v": "v_pages", "ik": "ik_pages"}
+
+    @property
+    def layer_stacks(self):
+        return (self.k_pages, self.v_pages, self.ik_pages)
+
+    def with_layer_stacks(self, k, v, ik) -> "IndexedPagedKVCache":
+        return self.replace(k_pages=k, v_pages=v, ik_pages=ik)
+
+    def attend(self, layer_state, q, k_new, v_new, rope, q_pos, num_new,
+               sliding_window, attention_fn, scale=None, index=None):
+        *kv_state, lik = layer_state
+        nik = self._scatter_index(lik, index.k, q_pos, num_new)
+        sel = self._select(index, nik, q_pos, num_new)
+        q_rot, k_all, v_all, mask, new = self.update_and_gather(
+            tuple(kv_state), q, k_new, v_new, rope, q_pos, num_new,
+            sliding_window,
+        )
+        with jax.named_scope("sparse_attention"):
+            out = attention_fn(q_rot, k_all, v_all, mask & sel, scale=scale)
+        return out, (*new, nik)
+
+    def tail_init(self, k_steps: int):
+        raise NotImplementedError(
+            "the value-dtype indexed cache decodes a token a dispatch"
+        )
+
+
+class IndexedQuantizedPagedKVCache(_IndexPlane, QuantizedPagedKVCache):
+    """:class:`QuantizedPagedKVCache` with the index plane. It inherits the
+    tail protocol: a decode step writes its index key into an index tail
+    ``[L, B, 1, K, INDEX_DIM]`` beside the K/V tail, scores the pool's and
+    the tail's index keys together, selects, and runs the parent's fused
+    in-place sweep under the selection; ``tail_flush`` scatters the index
+    tail where the K/V tail goes. The fused window reads the pool in place
+    at every table width (``INPLACE_CTX`` 0): the gathered form has no
+    mask."""
+
+    ik_pages: jax.Array = None
+
+    POOL, KERNELS = QuantizedPagedKVCache, True
+    INPLACE_CTX = 0
+    LAYER_FIELDS = (
+        "k_pages", "v_pages", "ks_pages", "vs_pages", "ik_pages",
+    )
+    SHARED_FIELDS = LAYER_FIELDS
+    PLANE_FIELDS = {
+        "k": "k_pages", "v": "v_pages", "ks": "ks_pages", "vs": "vs_pages",
+        "ik": "ik_pages",
+    }
+
+    @property
+    def layer_stacks(self):
+        return (*super().layer_stacks, self.ik_pages)
+
+    def with_layer_stacks(self, k, v, ks, vs, ik):
+        return self.replace(
+            k_pages=k, v_pages=v, ks_pages=ks, vs_pages=vs, ik_pages=ik
+        )
+
+    def attend(self, layer_state, q, k_new, v_new, rope, q_pos, num_new,
+               sliding_window, attention_fn, scale=None, index=None):
+        *kv_state, lik = layer_state
+        nik = self._scatter_index(lik, index.k, q_pos, num_new)
+        sel = self._select(index, nik, q_pos, num_new)
+        if self.use_ragged and q.shape[1] > 1:
+            from ..ops.ragged_attention import (
+                quantized_ragged_paged_attention,
+            )
+
+            new = self._scatter_q(
+                *kv_state, apply_rope(k_new, rope.cos, rope.sin), v_new,
+                q_pos, num_new,
+            )
+            b, s, _ = sel.shape
+            with jax.named_scope("sparse_attention"):
+                out = quantized_ragged_paged_attention(
+                    apply_rope(q, rope.cos, rope.sin),
+                    new[0], new[2], new[1], new[3], self.page_table,
+                    self.lengths + num_new, num_new,
+                    scale=scale, sliding_window=sliding_window,
+                    # [B, S, T*PS] -> a (page, q-block) tile a block
+                    select=sel.reshape(b, s, -1, self.page_size).transpose(
+                        0, 2, 1, 3
+                    ).astype(jnp.int8),
+                    name=KERNEL_PREFILL,
+                )
+            return out, (*new, nik)
+        # Without the ragged kernel (the CPU's default plan; a decode step
+        # of an engine without the tail): the gather path under the mask.
+        q_rot, k_all, v_all, mask, new = self.update_and_gather(
+            tuple(kv_state), q, k_new, v_new, rope, q_pos, num_new,
+            sliding_window,
+        )
+        with jax.named_scope("sparse_attention"):
+            out = attention_fn(q_rot, k_all, v_all, mask & sel, scale=scale)
+        return out, (*new, nik)
+
+    # -- write-behind tail ----------------------------------------------------
+
+    def tail_big_stacks(self):
+        big = super().tail_big_stacks()
+        if self._fused_inplace:
+            return (*big, self.ik_pages)
+        l, b = self.ik_pages.shape[0], self.page_table.shape[0]
+        ik = jnp.take(self.ik_pages, self.page_table, axis=1)
+        return (*big, ik.reshape(l, b, -1, ik.shape[-1]))
+
+    def tail_init(self, k_steps: int):
+        l, b = self.ik_pages.shape[0], self.page_table.shape[0]
+        return (
+            *super().tail_init(k_steps),
+            jnp.zeros((l, b, 1, k_steps, self.INDEX_DIM), self.ik_pages.dtype),
+        )
+
+    def tail_attend(self, big_state, tail_state, q, k_new, v_new, rope,
+                    base_len, tail_len, step_idx, num_new, sliding_window,
+                    scale=None, index=None):
+        whole = self._kernel_tail_ok and q.shape[1] == 1
+        *kv_tail, tik = tail_state
+        ik_new = index.k[:, None].astype(tik.dtype)        # [B, 1, 1, D]
+        if whole:
+            # whole [L, ...] planes and tails, the layer's index last
+            *kv_big, gik, lidx = big_state
+            kv_big = (*kv_big, lidx)
+            tik = jax.lax.dynamic_update_slice(
+                tik, ik_new[None], (lidx, 0, 0, step_idx, 0)
+            )
+            pool_keys = _row_index_keys(
+                self.page_table,
+                jax.lax.dynamic_index_in_dim(gik, lidx, keepdims=False),
+            )
+            tail_keys = jax.lax.dynamic_index_in_dim(tik, lidx, keepdims=False)
+        else:
+            # this layer's gathered [B, N, D] keys and [B, 1, K, D] tail
+            *kv_big, pool_keys = big_state
+            tik = jax.lax.dynamic_update_slice_in_dim(tik, ik_new, step_idx, 2)
+            tail_keys = tik
+        b, n = pool_keys.shape[:2]
+        kk = tail_keys.shape[2]
+        steps = jnp.arange(kk, dtype=jnp.int32)[None, :]
+        slots = jnp.arange(n, dtype=jnp.int32)[None, :]
+        sel = selection_mask(
+            index, jnp.concatenate([pool_keys, tail_keys[:, 0]], 1),
+            (base_len + tail_len)[:, None], None,
+            key_pos=jnp.concatenate(
+                [jnp.broadcast_to(slots, (b, n)), base_len[:, None] + steps], 1
+            ),
+            key_valid=jnp.concatenate([
+                slots < base_len[:, None],
+                steps < (tail_len + num_new)[:, None],
+            ], 1),
+        )[:, 0]
+        select = (sel[:, :n], sel[:, n:])
+        if whole:
+            select = (
+                select[0].reshape(b, -1, 1, self.page_size),
+                select[1][:, None, :],
+            )
+        with jax.named_scope("sparse_attention"):
+            out, new_kv = super().tail_attend(
+                tuple(kv_big), tuple(kv_tail), q, k_new, v_new, rope,
+                base_len, tail_len, step_idx, num_new, sliding_window, scale,
+                select=select,
+            )
+        return out, (*new_kv, tik)
+
+    def tail_flush(self, tail, tail_len):
+        *kv_tail, tik = tail                      # tik [L, B, 1, K, D]
+        kk = tik.shape[3]
+        if self._kernel_tail_ok and kk <= self.page_size:
+            # The K/V tail's own page read-modify-write, for the reason it
+            # has one: the XLA scatter below wants the plane transposed,
+            # two whole-plane relayout copies in the decode executable
+            # (1.7 GB of temporaries at the cell's pool: a described-v5e
+            # compile, PR 32).
+            from ..ops.paged_attention import paged_tail_flush
+
+            (nik,) = paged_tail_flush(
+                self.ik_pages, None, None, None, tik, None, None, None,
+                self.page_table, self.lengths, tail_len,
+                name=KERNEL_INDEX_FLUSH,
+            )
+        else:
+            q_pos = self.lengths[:, None] + jnp.arange(
+                kk, dtype=jnp.int32
+            )[None, :]
+            nik = jax.vmap(
+                lambda lik, t: self._scatter_index(
+                    lik, t[:, 0], q_pos, tail_len
+                )
+            )(self.ik_pages, tik)
+        return super().tail_flush(tuple(kv_tail), tail_len).replace(
+            ik_pages=nik
+        )
+
+
+@functools.lru_cache(maxsize=None)
+def indexed_cache_class(quantized: bool, index_dim: int):
+    """THE indexed cache class of a stored form and an index key's width,
+    made once (a class is a pytree node type: two engines of one width must
+    hold the same one)."""
+    base = IndexedQuantizedPagedKVCache if quantized else IndexedPagedKVCache
+    return type(
+        f"{base.__name__}{index_dim}", (base,), {"INDEX_DIM": int(index_dim)}
+    )

@@ -75,6 +75,26 @@ class LatentConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseAttentionConfig:
+    """Learned sparse attention (DeepSeek-V3.2's lightning indexer and top-k
+    selection, ``sa_config`` of a ``KeyeVL2`` block) over per-head K/V.
+
+    Beside ``q``, ``k``, ``v`` a layer projects ``index_heads`` index queries
+    and ONE index key of ``index_dim`` a token, and a weight a head; the
+    index score of a (query, key) pair is the weighted sum over heads of
+    ``relu(qI . kI)``, and a query attends only to the ``topk`` positions
+    ``s <= t`` of largest score (all of them while ``t < topk``), the same
+    positions for every attention head. The index key is cached beside K
+    and V in the page pool (``cache/paged.py``: the indexed cache classes);
+    scores, selection and the masked attention are ``ops/sparse_attention.py``.
+    """
+
+    index_heads: int = 16
+    index_dim: int = 64
+    topk: int = 2048
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSegment:
     """A run of consecutive decoder layers that are all alike: one
     ``lax.scan`` over one stacked parameter dict (``params[key]``). The
@@ -94,9 +114,12 @@ class ModelConfig:
     Covers the Llama family (the reference's only model family —
     ``/root/reference/distributed_llm_inference/models/llama/model.py``) plus
     Mistral (``sliding_window``), Qwen2 (``qkv_bias``), Mixtral's experts
-    (``num_experts``/``num_experts_per_tok``) and the DeepSeek-V2/V3 block
+    (``num_experts``/``num_experts_per_tok``), the DeepSeek-V2/V3 block
     (``latent`` attention; routed experts beside shared ones behind leading
-    dense layers; the routing rule's switches).
+    dense layers; the routing rule's switches) and the ``KeyeVL2`` block
+    (GQA with a per-head RMSNorm of queries and keys, ``qk_norm``; a learned
+    top-k key selection, ``sparse``; softmax-routed experts of
+    ``moe_intermediate_size`` with no latent).
     """
 
     vocab_size: int = 32000
@@ -146,7 +169,14 @@ class ModelConfig:
     # Latent (MLA-style) KV compression; requires the "mla" family and the
     # paged cache kind. None = conventional per-head K/V.
     latent: Optional[LatentConfig] = None
-    # Model family tag ("llama", "mistral", "qwen2", "mixtral", "mla").
+    # RMSNorm over each query and key head's ``head_dim`` (its own gain,
+    # ``q_norm`` / ``k_norm``) before RoPE: the Qwen3-style block.
+    qk_norm: bool = False
+    # Learned top-k key selection (an indexer beside GQA); requires the
+    # "keye_vl2" family and the paged cache kind. None = every key.
+    sparse: Optional[SparseAttentionConfig] = None
+    # Model family tag ("llama", "mistral", "qwen2", "mixtral", "mla",
+    # "keye_vl2").
     family: str = "llama"
 
     @property
@@ -185,6 +215,11 @@ class ModelConfig:
         uniformly the baseline per-head path, never a half-latent mix."""
         return self.latent is not None and self.latent.enabled
 
+    @property
+    def use_sparse(self) -> bool:
+        """THE selection predicate (as :attr:`use_latent` is the latent's)."""
+        return self.sparse is not None
+
     @staticmethod
     def from_hf_config(hf: Any) -> "ModelConfig":
         """Build from a ``transformers`` PretrainedConfig (or plain dict)."""
@@ -222,6 +257,31 @@ class ModelConfig:
                         get("routed_scaling_factor", 1.0) or 1.0
                     ),
                 )
+        extra = {}
+        if model_type == "KeyeVL2":
+            _refuse_unimplemented_keye(get)
+            sa = get("sa_config", None) or {}
+            if (sa.get("indexer_num_kv_heads", 1) or 1) != 1:
+                raise ValueError(
+                    "config key 'sa_config.indexer_num_kv_heads' = "
+                    f"{sa.get('indexer_num_kv_heads')!r} is not implemented: "
+                    "the index plane holds ONE index key a token"
+                )
+            model_type = "keye_vl2"
+            extra = dict(
+                qk_norm=True,
+                sparse=SparseAttentionConfig(
+                    index_heads=int(sa.get("indexer_num_heads", 16)),
+                    index_dim=int(sa.get("indexer_head_dim", 64)),
+                    topk=int(sa.get("topk", 2048)),
+                ) if sa else None,
+            )
+            if get("num_experts", 0) or get("num_local_experts", 0):
+                moe = dict(
+                    moe_intermediate_size=get("moe_intermediate_size", None),
+                    moe_scoring="softmax",
+                    moe_norm_topk=bool(get("norm_topk_prob", False)),
+                )
         return ModelConfig(
             vocab_size=get("vocab_size", 32000),
             hidden_size=hidden,
@@ -235,15 +295,23 @@ class ModelConfig:
             rope_scaling=RopeScaling.from_hf(get("rope_scaling", None)),
             max_position_embeddings=get("max_position_embeddings", 4096),
             tie_word_embeddings=bool(get("tie_word_embeddings", False)),
-            sliding_window=get("sliding_window", None),
+            # a ``KeyeVL2`` block's ``sliding_window`` counts only under
+            # ``use_sliding_window`` (refused above): the key alone states
+            # no window
+            sliding_window=(
+                None if model_type == "keye_vl2"
+                else get("sliding_window", None)
+            ),
             qkv_bias=bool(get("attention_bias", False)) or model_type in ("qwen2",),
             num_experts=(
-                get("num_local_experts", 0) or get("n_routed_experts", 0) or 0
+                get("num_local_experts", 0) or get("n_routed_experts", 0)
+                or get("num_experts", 0) or 0
             ),
             num_experts_per_tok=get("num_experts_per_tok", 2) or 2,
             latent=latent,
             family=model_type,
             **moe,
+            **extra,
         )
 
 
@@ -271,6 +339,24 @@ def _refuse_unimplemented(get) -> None:
                 f"compressed queries, routing by groups, YaRN's mscale, "
                 f"next-token-prediction layers and interleaved dense layers "
                 f"are outside what models/llama.py computes"
+            )
+
+
+def _refuse_unimplemented_keye(get) -> None:
+    """Keys of a ``KeyeVL2`` ``config.json`` whose published meaning this
+    program does not compute. Each raises under its own name."""
+    refused = {
+        "decoder_sparse_step": (get("decoder_sparse_step", 1) or 1) != 1,
+        "mlp_only_layers": bool(get("mlp_only_layers", None)),
+        "use_sliding_window": bool(get("use_sliding_window", False)),
+    }
+    for key, bad in refused.items():
+        if bad:
+            raise ValueError(
+                f"config key {key!r} = {get(key)!r} is not implemented: "
+                f"dense layers between the expert layers and windowed "
+                f"layers are outside what models/llama.py computes for "
+                f"this family (every layer routes, every layer selects)"
             )
 
 

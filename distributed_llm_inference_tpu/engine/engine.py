@@ -38,7 +38,9 @@ import numpy as np
 from ..cache.base import window_ladder
 from ..cache.dense import DenseKVCache, QuantizedDenseKVCache
 from ..cache.latent import LatentPagedKVCache, QuantizedLatentPagedKVCache
-from ..cache.paged import PageAllocator, PagedKVCache, QuantizedPagedKVCache
+from ..cache.paged import (
+    PageAllocator, PagedKVCache, QuantizedPagedKVCache, indexed_cache_class,
+)
 from ..cache.sink import QuantizedSinkKVCache, SinkKVCache
 
 # Cache kinds implementing the StreamingLLM sink-window policy (unbounded
@@ -249,6 +251,21 @@ class InferenceEngine:
                     "latent KV attention is single-device only (mesh "
                     "sharding of the latent pool is not implemented)"
                 )
+        if cfg.use_sparse:
+            # A learned key selection caches an index key a token beside K
+            # and V: the page pool's indexed classes hold that plane, on
+            # one device.
+            if cc.kind != "paged":
+                raise ValueError(
+                    "ModelConfig.sparse requires the paged cache "
+                    f"(got kind={cc.kind!r})"
+                )
+            if mesh_cfg is not None:
+                raise ValueError(
+                    "a learned key selection is single-device only (mesh "
+                    "sharding of the index plane is not implemented)"
+                )
+            self.plan.sparse_topk = cfg.sparse.topk
         self.plan.latent = self._latent
         self.plan.sliding_window = cfg.sliding_window
         if cfg.num_experts > 0:
@@ -323,6 +340,10 @@ class InferenceEngine:
                     QuantizedPagedKVCache
                     if cc.kv_quant == "int8" else PagedKVCache
                 )
+                if cfg.use_sparse:
+                    paged_cls = indexed_cache_class(
+                        cc.kv_quant == "int8", cfg.sparse.index_dim
+                    )
                 self.cache = paged_cls.create(
                     cfg.num_layers, b, cc.num_pages, cc.page_size,
                     self._first_slots, cfg.num_kv_heads, cfg.head_dim, dtype,
@@ -1409,6 +1430,21 @@ class InferenceEngine:
             live += int(pending[active].sum())
         return live
 
+    def _decode_spans(self, active, steps: int, pending=None):
+        """A decode dispatch's queries as ``(first position, queries)``
+        pairs, a pair an active row, for the selection's census
+        (``plan.note_dispatch``); None where the model selects no keys."""
+        if self.plan.sparse_topk is None:
+            return None
+        return [
+            (
+                self.sessions[self.slots[slot]].total_len - 1
+                + (0 if pending is None else int(pending[slot])),
+                steps,
+            )
+            for slot in np.flatnonzero(active)
+        ]
+
     def _note_prefill(self, kind: str, shape, row_spans) -> None:
         """The census of a prefill-family dispatch (``plan.note_dispatch``):
         ``row_spans`` holds a ``(first position, tokens)`` pair for every
@@ -1418,6 +1454,9 @@ class InferenceEngine:
         self.plan.note_dispatch(
             kind, shape, sum(n for _, n in row_spans), row_spans=row_spans,
             table_width=self.cache.page_table.shape[1] if paged else None,
+            sparse_spans=(
+                row_spans if self.plan.sparse_topk is not None else None
+            ),
         )
 
     def _note_admitted(self, s: Session) -> None:
@@ -1769,6 +1808,9 @@ class InferenceEngine:
 
                 out["ks"] = scales(cache.ks_pages)
                 out["vs"] = scales(cache.vs_pages)
+            # what a learned selection stores beside K and V: [L,S,1,D]
+            for name, f in getattr(cache, "INDEX_PLANES", {}).items():
+                out[name] = vals(getattr(cache, f))
             return out
         if isinstance(cache, QuantizedDenseKVCache):
             return {  # head-major [L,B,H,T,D] -> time-major [L,S,H,D]
@@ -1804,6 +1846,8 @@ class InferenceEngine:
             want = {"k", "v", "ks", "vs"}
         else:
             want = {"k", "v"}
+        index_planes = getattr(cache, "INDEX_PLANES", {})
+        want |= set(index_planes)
         if set(planes) != want:
             raise ValueError(
                 f"KV planes {sorted(planes)} do not match this cache "
@@ -1819,6 +1863,9 @@ class InferenceEngine:
             )
         for name in sorted(want):
             expect = shape if name in ("c", "k", "v") else shape[:3]
+            if name in index_planes:  # one index key a token
+                pool = getattr(cache, index_planes[name])
+                expect = (shape[0], n, 1, pool.shape[4])
             got = tuple(np.asarray(planes[name]).shape)
             if got != expect:
                 raise ValueError(
@@ -1830,6 +1877,12 @@ class InferenceEngine:
         """Scatter validated planes (from :meth:`_check_planes`) into a
         batch-1 cache view, dispatching on the stored form."""
         cache = self.cache
+        index_planes = getattr(cache, "INDEX_PLANES", None)
+        if index_planes:
+            sub = sub.ingest_index_row(
+                {name: dev[name] for name in index_planes}, n,
+                first_slot=first_slot,
+            )
         if isinstance(cache, LatentPagedKVCache):
             return sub.ingest_latent_row(dev, n, first_slot=first_slot)
         if isinstance(cache, QuantizedPagedKVCache):
@@ -3117,7 +3170,8 @@ class InferenceEngine:
             self.batch, K,
             self.cache.page_table.shape[1] if paged
             else int(getattr(self.cache, "max_len", 0)),
-        ), self._live_positions(active, pend_b), int(active.sum()))
+        ), self._live_positions(active, pend_b), int(active.sum()),
+            sparse_spans=self._decode_spans(active, K, pend_b))
         emitted, self.cache = self._decode_k(
             self.params, tokens_dev, self.cache, act_dev,
             self._next_key(), sp, jnp.asarray(eos_ids),
@@ -3291,7 +3345,8 @@ class InferenceEngine:
             self.cache.page_table.shape[1]
             if isinstance(self.cache, PagedKVCache)
             else int(getattr(self.cache, "max_len", 0)),
-        ), self._live_positions(active), int(active.sum()))
+        ), self._live_positions(active), int(active.sum()),
+            sparse_spans=self._decode_spans(active, K))
         if K == 1:
             self.metrics.counter("decode_one_token_ticks")
             next_tokens, self.cache = self._decode(

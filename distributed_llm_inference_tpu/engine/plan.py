@@ -143,6 +143,10 @@ class AttentionPlan:
         # note_dispatch keeps the census of the kernel's live tiles.
         self.ragged_block_q = None
         self.sliding_window: Optional[int] = None
+        # Set by the engine for a model that selects its keys
+        # (``ModelConfig.sparse``): note_dispatch keeps the census of the
+        # selected and the live keys.
+        self.sparse_topk: Optional[int] = None
 
     # ------------------------------------------------------------------
     # Row classification / shape policy
@@ -257,7 +261,8 @@ class AttentionPlan:
                       valid_tokens: Optional[int] = None,
                       active_rows: Optional[int] = None,
                       row_spans=None,
-                      table_width: Optional[int] = None) -> None:
+                      table_width: Optional[int] = None,
+                      sparse_spans=None) -> None:
         """Record one attention dispatch: first-seen (kind, shape) is one
         fresh executable (``attn_recompiles``); prefill-family dispatches
         under ragged mode count ``attn_ragged_dispatches``.
@@ -285,9 +290,22 @@ class AttentionPlan:
         ``ragged_attn_tiles_grid``, the tiles :func:`_tile_live` keeps add
         to ``ragged_attn_tiles_live`` — the kernel's own predicate over
         its own ``block_q``, so the count is what it computes, chip or
-        not."""
+        not.
+
+        A model that selects its keys (``sparse_topk``) gives
+        ``sparse_spans``, a ``(first position, queries)`` pair a real row (a
+        decode row's queries are the dispatch's steps): a query at position
+        ``t`` has ``t + 1`` live keys and attends to ``min(topk, t + 1)`` of
+        them, in every layer alike. Their sums add to
+        ``sparse_keys_live`` / ``sparse_keys_selected`` (``_total`` on
+        ``/metrics``) and ride
+        the dispatch's record as a fourth entry ``(selected, live)``."""
         shape = tuple(int(x) for x in shape)
         self.last_dispatch = (kind, shape, valid_tokens)
+        sparse_keys = None
+        if self.sparse_topk is not None and sparse_spans is not None:
+            sparse_keys = self._sparse_keys(sparse_spans)
+            self.last_dispatch += (sparse_keys,)
         if self.dispatches is not None:
             self.dispatches.append(self.last_dispatch)
         key = (kind,) + shape
@@ -299,6 +317,9 @@ class AttentionPlan:
             return
         if self.latent:
             self.metrics.counter("latent_decompress_dispatches")
+        if sparse_keys is not None:
+            self.metrics.counter("sparse_keys_selected", sparse_keys[0])
+            self.metrics.counter("sparse_keys_live", sparse_keys[1])
         if self.enabled and kind != DECODE:
             self.metrics.counter("attn_ragged_dispatches")
         if valid_tokens is None:
@@ -326,6 +347,22 @@ class AttentionPlan:
                 live, grid = self._ragged_tiles(shape, row_spans, table_width)
                 self.metrics.counter("ragged_attn_tiles_live", live)
                 self.metrics.counter("ragged_attn_tiles_grid", grid)
+
+    def _sparse_keys(self, spans) -> Tuple[int, int]:
+        """(selected, live) keys of queries at positions ``start .. start +
+        n - 1``, summed over ``spans``: closed sums of ``min(topk, t + 1)``
+        and ``t + 1``."""
+        k = self.sparse_topk
+        selected = live = 0
+        for start, n in spans:
+            start, n = int(start), int(n)
+            if n <= 0:
+                continue
+            lo, hi = start + 1, start + n          # live keys: lo .. hi
+            live += (lo + hi) * n // 2
+            under = max(0, min(hi, k) - lo + 1)    # queries with <= k keys
+            selected += (lo + lo + under - 1) * under // 2 + (n - under) * k
+        return selected, live
 
     def _ragged_tiles(self, shape, row_spans, table_width) -> Tuple[int, int]:
         """(live, all) tiles of one layer's ragged-kernel grid for a

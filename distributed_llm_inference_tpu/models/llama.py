@@ -38,6 +38,7 @@ from ..ops.moe import moe_mlp
 from ..ops.norms import rms_norm
 from ..ops.quant import matmul as qmatmul
 from ..ops.rotary import RopeAngles, apply_rope, rope_cos_sin, rope_inv_freq
+from ..ops.sparse_attention import IndexInputs
 
 Params = Dict[str, Any]
 
@@ -100,6 +101,19 @@ def init_layer_params(
             "wo": w(keys[3], hq * d, h),
             "mlp_norm": jnp.ones((num_layers, h), dtype),
         }
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((num_layers, d), dtype)
+        p["k_norm"] = jnp.ones((num_layers, d), dtype)
+    if cfg.use_sparse:
+        # The indexer (see :func:`_index_inputs`): index queries, ONE index
+        # key a token under a LayerNorm, and a weight a head.
+        sa = cfg.sparse
+        ik = jax.random.split(keys[3], 4)
+        p["wq_i"] = w(ik[1], h, sa.index_heads * sa.index_dim)
+        p["wk_i"] = w(ik[2], h, sa.index_dim)
+        p["w_i"] = w(ik[3], h, sa.index_heads)
+        p["k_i_norm"] = jnp.ones((num_layers, sa.index_dim), dtype)
+        p["k_i_norm_bias"] = jnp.zeros((num_layers, sa.index_dim), dtype)
     p.update(_mlp_params(cfg, kind, w, keys))
     if cfg.qkv_bias:
         p["bq"] = jnp.zeros((num_layers, hq * d), dtype)
@@ -183,11 +197,14 @@ def _decoder_layer(
     q_pos: jnp.ndarray,
     num_new: jnp.ndarray,
     attention_fn=gqa_attention,
+    index_rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
     """One decoder layer: pre-norm attention + pre-norm SwiGLU MLP.
 
     Mirrors the reference layer structure (``modules.py:146-184``) minus its
-    double-residual deviation (SURVEY §2.9.3).
+    double-residual deviation (SURVEY §2.9.3). ``index_rope``: the rotary
+    tables of a learned selection's index queries and keys (their own
+    width; :func:`_index_rope`).
     """
     b, s, _ = x.shape
     hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -215,10 +232,18 @@ def _decoder_layer(
             q = q.reshape(b, s, hq, d)
             k = k.reshape(b, s, hkv, d)
             v = v.reshape(b, s, hkv, d)
-
+            if "q_norm" in p:
+                q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+                k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+            # A learned selection hands the cache its index inputs beside
+            # q, k, v; every other model's call is the one it always was.
+            more = (
+                {"index": _index_inputs(cfg, p, h, index_rope)}
+                if cfg.use_sparse else {}
+            )
             attn, new_state = cache.attend(
                 layer_state, q, k, v, rope, q_pos, num_new,
-                cfg.sliding_window, attention_fn, d**-0.5,
+                cfg.sliding_window, attention_fn, d**-0.5, **more,
             )
             attn_flat = attn.reshape(b, s, hq * d)
         o = qmatmul(attn_flat, p["wo"])
@@ -226,6 +251,47 @@ def _decoder_layer(
             o = o + p["bo"]
         x = x + o
     return _mlp_residual(cfg, p, x, s, num_new), new_state
+
+
+def _index_inputs(cfg, p, h, index_rope) -> IndexInputs:
+    """The lightning indexer's projections of the normed hidden state ``h``
+    (DeepSeek-V3.2's, the query taken from ``h``: this block has no
+    compressed query): ``index_heads`` queries and ONE key of ``index_dim``
+    a token, both rotated over their whole width, the key under a LayerNorm
+    first; a weight a head, scaled by ``index_heads ** -0.5`` and the
+    scores' ``index_dim ** -0.5``. The three matrices stay in the model's
+    dtype (``ops/quant.py``)."""
+    sa = cfg.sparse
+    b, s, _ = h.shape
+    cos, sin = index_rope
+    qi = qmatmul(h, p["wq_i"]).reshape(b, s, sa.index_heads, sa.index_dim)
+    ki = qmatmul(h, p["wk_i"]).astype(jnp.float32)
+    mean = jnp.mean(ki, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+    ki = (ki - mean) * jax.lax.rsqrt(var + cfg.rms_norm_eps)
+    ki = (
+        ki * p["k_i_norm"].astype(jnp.float32)
+        + p["k_i_norm_bias"].astype(jnp.float32)
+    ).astype(h.dtype)
+    w = qmatmul(h, p["w_i"]) * (sa.index_heads ** -0.5 * sa.index_dim ** -0.5)
+    return IndexInputs(
+        q=apply_rope(qi, cos, sin),
+        k=apply_rope(ki[:, :, None, :], cos, sin)[:, :, 0],
+        w=w,
+        topk=sa.topk,
+    )
+
+
+def _index_rope(cfg: ModelConfig, positions):
+    """Rotary tables ``[B, S, index_dim]`` of the index queries and keys:
+    the model's ``rope_theta`` over the indexer's own width. None for a
+    model that selects no keys."""
+    if not cfg.use_sparse:
+        return None
+    return rope_cos_sin(
+        positions,
+        rope_inv_freq(cfg.sparse.index_dim, cfg.rope_theta, cfg.rope_scaling),
+    )
 
 
 def _mlp_residual(cfg, p, x, s, num_new):
@@ -384,6 +450,7 @@ def block_apply(
     rot_pos = cache.rope_positions(x.shape[1], num_new)
     cos, sin = rope_cos_sin(rot_pos, inv_freq)
     rope = RopeAngles(inv_freq, cos, sin)
+    index_rope = _index_rope(cfg, rot_pos)
 
     stacks = cache.layer_stacks  # tuple of [L, ...] arrays (k/v [+ scales])
     num_stack = layer_params["attn_norm"].shape[0]
@@ -405,7 +472,8 @@ def block_apply(
             for b in bufs
         )
         out, new_state = _decoder_layer(
-            cfg, p, x, layer_state, cache, rope, q_pos, num_new, attention_fn
+            cfg, p, x, layer_state, cache, rope, q_pos, num_new, attention_fn,
+            index_rope,
         )
         bufs = tuple(
             jax.lax.dynamic_update_index_in_dim(b, n, idx, 0)
@@ -491,12 +559,12 @@ class _TailView:
         return self.q_positions(seq_len)
 
     def attend(self, layer_state, q, k_new, v_new, rope, q_pos, num_new,
-               sliding_window, attention_fn, scale=None):
+               sliding_window, attention_fn, scale=None, **more):
         big = layer_state[: self.num_big]
         tail = layer_state[self.num_big:]
         out, new_tail = self.cache.tail_attend(
             big, tail, q, k_new, v_new, rope, self.base_len, self.tail_len,
-            self.step_idx, num_new, sliding_window, scale,
+            self.step_idx, num_new, sliding_window, scale, **more,
         )
         return out, (*big, *new_tail)
 
@@ -574,6 +642,7 @@ def multi_decode_apply(
         q_pos = view.q_positions(1)
         cos, sin = rope_cos_sin(q_pos, inv_freq)
         rope = RopeAngles(inv_freq, cos, sin)
+        index_rope = _index_rope(cfg, q_pos)
 
         def layer_step(whole_w, first_layer, carry2, xs):
             x, tail_bufs = carry2
@@ -596,7 +665,7 @@ def multi_decode_apply(
                 )
             out, new_state = _decoder_layer(
                 cfg, p, x, (*big_state, *tail_state), view, rope, q_pos,
-                num_new,
+                num_new, index_rope=index_rope,
             )
             if whole_tail:
                 tail_bufs = tuple(new_state[view_num_big:])
@@ -798,6 +867,14 @@ def convert_hf_state_dict(
     three times over (state, per-layer copies, stacks: 35 GiB and counting
     for a 14.5 GB checkpoint on a 40 GiB host — my chip run, PR 21).
     """
+    if cfg.family == "keye_vl2":
+        raise ValueError(
+            "family 'keye_vl2' (KeyeVL2) has no checkpoint converter: the "
+            "key names of its checkpoint (the indexer's, the per-head "
+            "norms') are not known to this program, and a guessed "
+            "converter is worse than none"
+        )
+
     def stacked(ids):
         per_layer = []
         for i in ids:

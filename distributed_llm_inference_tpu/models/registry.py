@@ -24,6 +24,14 @@ Families:
   leading dense layers (``ModelConfig.segments``), with softmax or sigmoid
   routing (``ops/moe.py:route``). A latent model without experts is the
   same entry.
+* ``keye_vl2`` — the ``KeyeVL2`` language model (Keye-VL-2.0-30B-A3B): GQA
+  with three switches beside it: ``qk_norm`` (an RMSNorm over each query
+  and key head before RoPE), ``sparse`` (a learned top-k key selection:
+  an indexer whose keys are cached beside K and V,
+  ``ModelConfig.sparse``, ``ops/sparse_attention.py``) and ``moe``
+  (softmax-routed experts of ``moe_intermediate_size``, no shared expert,
+  no dense layer). Its checkpoint's key names are not known to this
+  program: :func:`llama.convert_hf_state_dict` refuses the family.
 
 The switches are independent: a family may permit any of them together
 (``mla`` permits experts AND requires the latent); what a family does not
@@ -55,6 +63,9 @@ class ModelFamily:
     # set, so a family's attention is one or the other; its MLP switch
     # (``moe``) is independent of it.
     latent: bool = False
+    # A per-head RMSNorm of q and k, and the learned top-k key selection.
+    qk_norm: bool = False
+    sparse: bool = False
     # The compute/conversion program (shared stack for all current families).
     apply: Callable = llama.model_apply
     block_apply: Callable = llama.block_apply
@@ -72,6 +83,10 @@ FAMILIES: Dict[str, ModelFamily] = {
         ModelFamily(
             "mla", ("mla", "deepseek_v2", "deepseek_v3"), latent=True,
             moe=True,
+        ),
+        ModelFamily(
+            "keye_vl2", ("keye_vl2", "KeyeVL2"), moe=True, qk_norm=True,
+            sparse=True,
         ),
     )
 }
@@ -133,6 +148,13 @@ def validate_config(cfg: ModelConfig) -> ModelFamily:
         raise ValueError(
             f"family {fam.name!r} does not use latent KV attention "
             f"(use the 'mla' family)"
+        )
+    if cfg.qk_norm and not fam.qk_norm:
+        raise ValueError(f"family {fam.name!r} does not use qk_norm")
+    if cfg.sparse is not None and not fam.sparse:
+        raise ValueError(
+            f"family {fam.name!r} does not use a learned key selection "
+            f"(ModelConfig.sparse; use the 'keye_vl2' family)"
         )
     if fam.latent and (cfg.latent is None or not cfg.latent.enabled):
         raise ValueError(

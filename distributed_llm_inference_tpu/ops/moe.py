@@ -98,6 +98,13 @@ def expert_rows_per_token(cfg: ModelConfig, seq_len: int):
     return k + shared, cfg.num_experts + shared
 
 
+# Dense-combine runs a dispatch's tokens whole up to this many a row (every
+# cell before PR 32 pads to 2048 or fewer: their programs are as they were),
+# and in blocks of ``DENSE_COMBINE_BLOCK`` past it.
+DENSE_COMBINE_TOKENS = 2048
+DENSE_COMBINE_BLOCK = 1024
+
+
 def _shared_experts(p, x: jnp.ndarray):
     """The shared experts: one SwiGLU MLP over every token."""
     with jax.named_scope("moe_shared"):
@@ -132,6 +139,18 @@ def moe_mlp(
     """
     if cfg.moe_capacity_factor is not None and x.shape[1] >= 16:
         out = moe_mlp_dispatch(cfg, p, x, cfg.moe_capacity_factor, valid)
+    elif x.shape[1] > DENSE_COMBINE_TOKENS and x.shape[1] % DENSE_COMBINE_BLOCK == 0:
+        # A dispatch wider than any before PR 32 (a 4096-wide chunk over 128
+        # experts: ``[b, s, E, H]`` alone is 2.1 GB in bf16) walks its
+        # tokens a block at a time. A token's result does not depend on its
+        # neighbours, so the numbers are the whole dispatch's.
+        b, s, h = x.shape
+        blocks = jnp.moveaxis(
+            x.reshape(b, s // DENSE_COMBINE_BLOCK, DENSE_COMBINE_BLOCK, h), 1, 0
+        )
+        routed = {k: v for k, v in p.items() if not k.startswith("ws_")}
+        out = jax.lax.map(lambda xb: moe_mlp(cfg, routed, xb), blocks)
+        out = jnp.moveaxis(out, 0, 1).reshape(b, s, h)
     else:
         combine = router_weights(
             cfg, x, p["router"], p.get("router_bias")

@@ -629,6 +629,7 @@ def quantized_paged_fused_attention(
     interpret: Optional[bool] = None,
     sliding_window: Optional[int] = None,
     name: str = "quantized_paged_fused_attention",
+    select=None,
 ):
     """ONE kernel for a fused-decode step over the int8 page pool IN PLACE:
     the WHOLE ``[L, P, Hkv, PS, D]`` K and V planes stay in HBM
@@ -692,6 +693,14 @@ def quantized_paged_fused_attention(
     (+ scales, io-aliased). Returns ``(out, tail_k', tail_ks', tail_v',
     tail_vs')``, or ``(out, tail_k', tail_ks')`` of one stored plane.
     ``name`` is what a device trace calls the kernel.
+
+    **Under a selection** (``select``: learned sparse attention,
+    ``ops/sparse_attention.py``) a row attends only to the positions its
+    indexer chose: ``select`` is ``(pool [B, T, 1, PS], tail [B, 1, KT])``
+    float32, positive where a pool position (by table slot) or a tail slot
+    is selected. They are two more pipelined blocks a row, and one more
+    term of each tile's mask: every live page is still fetched, and a call
+    without a selection traces to the program it always did.
     """
     b, s, hq, d = q.shape
     if s != 1:
@@ -712,6 +721,8 @@ def quantized_paged_fused_attention(
     planes = 1 if shared else 2
     n = _pages_per_block(t, hkv, page_size, d, kt, planes)
     by_grid = _pages_by_grid(d)
+    if select is not None and by_grid:
+        raise ValueError("a selection is swept by copies (whole-tile rows)")
 
     qr = q.reshape(b, hkv, g, d)
     # Per stored plane, in the kernel's operand order: the step's fresh
@@ -787,6 +798,10 @@ def quantized_paged_fused_attention(
                 *pool_specs,
                 pl.BlockSpec((1, t, hkv, page_size), _row_index),
             ] * planes,
+            *([] if select is None else [
+                pl.BlockSpec((1, t, 1, page_size), _row_index),
+                pl.BlockSpec((1, 1, kt), lambda bi, *a: (bi, 0, 0)),
+            ]),
         ],
         out_specs=(
             pl.BlockSpec((1, hkv, g, d), _row_index),
@@ -810,6 +825,7 @@ def quantized_paged_fused_attention(
         hkv=hkv,
         g=g,
         kt=kt,
+        selected=select is not None,
     )
     # Tail planes update in place; an alias's index counts every flattened
     # input, the 6 scalar-prefetch operands and q and the fresh values too.
@@ -838,7 +854,10 @@ def quantized_paged_fused_attention(
       *[
           x for plane, sc in pools
           for x in (*[plane] * len(pool_specs), _scale_rows(sc))
-      ])
+      ],
+      *(() if select is None else (
+          select[0].astype(jnp.float32), select[1].astype(jnp.float32),
+      )))
     return (out.reshape(b, 1, hq, d), *new_tails)
 
 
@@ -860,6 +879,7 @@ def _qpaged_fused_kernel(
     hkv: int,
     g: int,
     kt: int,
+    selected: bool = False,
 ):
     """``refs``, for the stored planes K and V (``planes`` 2) or the one
     plane that is both (``planes`` 1), a plane after the other in each group:
@@ -870,6 +890,8 @@ def _qpaged_fused_kernel(
     * pool: HBM ``[L, P, Hkv, PS, D]`` int8 (the whole pool) or, ``by_grid``,
       the N pages of this step's block ``[1, 1, Hkv, PS, D]`` each; then the
       row's scale rows by table slot ``[1, T, Hkv, PS]`` f32;
+    * ``selected``: the row's selection, by table slot ``[1, T, 1, PS]`` and
+      by tail slot ``[1, 1, KT]`` f32 (positive = attend);
     * ``out_ref`` ``[1, Hkv, G, D]``, then the aliased tail outputs;
     * scratch: unless ``by_grid``, a plane's VMEM ``[2, N, Hkv, PS, D]`` int8
       (two blocks of N pages) and DMA semaphores ``[2, N]`` (one a page
@@ -888,6 +910,7 @@ def _qpaged_fused_kernel(
     tail_in = _take(2 * planes)
     per = n + 1 if by_grid else 2
     pool = _take(per * planes)
+    sel_pool, sel_tail = _take(2) if selected else (None, None)
     (out_ref,) = _take(1)
     tail_out = _take(2 * planes)
     bufs = [] if by_grid else _take(planes)
@@ -997,6 +1020,8 @@ def _qpaged_fused_kernel(
         tail_valid = pos1 < vlen_ref[b]
         if sliding_window is not None:
             tail_valid &= kv_len + pos1 > qpos - sliding_window
+        if selected:
+            tail_valid &= sel_tail[0] > 0
         _tile(list(zip(tail_vals, tail_scs)), tail_valid, kt)
 
         out = acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-20)
@@ -1079,6 +1104,8 @@ def _qpaged_fused_kernel(
             for copy in _page_copies(slot, i, page):
                 copy.wait()
             valid = _valid(page, page_size)
+            if selected:
+                valid &= sel_pool[0, page] > 0
             _tile(
                 [(buf[slot, i], rows[0, page])
                  for buf, rows in zip(bufs, scale_rows)],
@@ -1094,17 +1121,18 @@ def _qpaged_fused_kernel(
 
 def paged_tail_flush(
     pool_k: jnp.ndarray,
-    pool_ks: jnp.ndarray,
+    pool_ks: Optional[jnp.ndarray],
     pool_v: Optional[jnp.ndarray],
     pool_vs: Optional[jnp.ndarray],
     tail_k: jnp.ndarray,
-    tail_ks: jnp.ndarray,
+    tail_ks: Optional[jnp.ndarray],
     tail_v: Optional[jnp.ndarray],
     tail_vs: Optional[jnp.ndarray],
     page_table: jnp.ndarray,
     base_len: jnp.ndarray,
     tail_len: jnp.ndarray,
     interpret: Optional[bool] = None,
+    name: str = "paged_tail_flush",
 ):
     """Merge the fused window's int8 tail into the page pool by
     read-modify-writing ONLY the pages each row's window touches.
@@ -1121,31 +1149,41 @@ def paged_tail_flush(
     ``tail_*``: ``[L, B, Hkv, KT, D]`` int8 (+ ``[L, B, Hkv, KT]`` f32
     scales), KT <= page_size. Rows must have table slots mapped through
     ``base_len + tail_len`` (engine growth contract); clamped visits hit
-    the null page 0 and compose no changes. Returns the four updated pool
-    planes (inputs consumed — aliased). A pool of ONE stored plane (the
-    latent cache's: the same array cannot be aliased twice) passes ``None``
-    for the four V arguments and gets its two planes back.
+    the null page 0 and compose no changes. Returns the updated pool
+    planes, one a plane given (inputs consumed — aliased). A pool of ONE
+    stored plane (the latent cache's: the same array cannot be aliased
+    twice) passes ``None`` for the four V arguments and gets its two planes
+    back; a value plane with no scales (an index plane in the model's
+    dtype, ``cache/paged.py``; ``name`` is then its own) passes ``None``
+    for those too. Each plane is blocked by its own head count and width.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    num_l, _, hkv, ps, d = pool_k.shape
+    ps = pool_k.shape[3]
+    num_l = pool_k.shape[0]
     b = page_table.shape[0]
     t = page_table.shape[1]
     kt = tail_k.shape[3]
     if kt > ps:
         raise ValueError(f"tail ({kt}) must fit one page ({ps})")
     nj = -(-kt // ps) + 1  # straddle: at most 2 pages per row's window
-    planes = 1 if pool_v is None else 2
-    tails = [tail_k, tail_ks, tail_v, tail_vs][: 2 * planes]
-    pools = [pool_k, pool_ks, pool_v, pool_vs][: 2 * planes]
+    given = [
+        (tail, pool) for tail, pool in zip(
+            (tail_k, tail_ks, tail_v, tail_vs),
+            (pool_k, pool_ks, pool_v, pool_vs),
+        ) if pool is not None
+    ]
+    tails = [tail for tail, _ in given]
+    pools = [pool for _, pool in given]
+
+    def _slot(bi, ji, table, lens):
+        return table[bi, jnp.minimum(lens[bi] // ps + ji, t - 1)]
 
     def _pidx(li, bi, ji, table, lens, tl):
-        slot = jnp.minimum(lens[bi] // ps + ji, t - 1)
-        return (li, table[bi, slot], 0, 0, 0)
+        return (li, _slot(bi, ji, table, lens), 0, 0, 0)
 
     def _pidx4(li, bi, ji, table, lens, tl):
-        slot = jnp.minimum(lens[bi] // ps + ji, t - 1)
-        return (li, table[bi, slot], 0, 0)
+        return (li, _slot(bi, ji, table, lens), 0, 0)
 
     def _tidx(li, bi, ji, table, lens, tl):
         return (li, bi, 0, 0, 0)
@@ -1155,63 +1193,51 @@ def paged_tail_flush(
 
     def kernel(table_ref, lens_ref, tl_ref, *refs):
         # A plane's (values, scale row) after the other in each group.
-        tail_refs = refs[: 2 * planes]
-        pool_in = refs[2 * planes : 4 * planes]
-        pool_out = refs[4 * planes :]
+        tail_refs = refs[: len(tails)]
+        pool_in = refs[len(tails) : 2 * len(tails)]
+        pool_out = refs[2 * len(tails) :]
         bi = pl.program_id(1)
         ji = pl.program_id(2)
         start = lens_ref[bi]
         tl = tl_ref[bi]
         slot = jnp.minimum(start // ps + ji, t - 1)
 
-        def compose_values(pool_ref, tail_ref, out_ref):
+        def compose(pool_ref, tail_ref, out_ref):
+            """Values ``[Hkv, PS, D]`` or a scale row ``[Hkv, PS]``."""
+            values = len(pool_ref.shape) == 5
             pos = slot * ps + jax.lax.broadcasted_iota(
-                jnp.int32, (1, ps, 1), 1
+                jnp.int32, (1, ps, 1) if values else (1, ps), 1
             )
-            cur = pool_ref[0, 0]                       # [Hkv, PS, D]
-            tail = tail_ref[0, 0]                      # [Hkv, KT, D]
+            cur = pool_ref[0, 0]
+            tail = tail_ref[0, 0]
             for i in range(kt):
                 hit = (pos == start + i) & (i < tl)
                 cur = jnp.where(hit, tail[:, i : i + 1], cur)
             out_ref[0, 0] = cur
 
-        def compose_scales(pool_ref, tail_ref, out_ref):
-            pos = slot * ps + jax.lax.broadcasted_iota(
-                jnp.int32, (1, ps), 1
-            )
-            cur = pool_ref[0, 0]                       # [Hkv, PS]
-            tail = tail_ref[0, 0]                      # [Hkv, KT]
-            for i in range(kt):
-                hit = (pos == start + i) & (i < tl)
-                cur = jnp.where(hit, tail[:, i : i + 1], cur)
-            out_ref[0, 0] = cur
+        for rank in (5, 4):  # the value planes, then the scale rows
+            for i, pool in enumerate(pools):
+                if pool.ndim == rank:
+                    compose(pool_in[i], tail_refs[i], pool_out[i])
 
-        for compose, first in ((compose_values, 0), (compose_scales, 1)):
-            for i in range(first, 2 * planes, 2):
-                compose(pool_in[i], tail_refs[i], pool_out[i])
-
-    def _pool_specs():
+    def _specs(arrays, idx5, idx4):
         return [
-            pl.BlockSpec((1, 1, hkv, ps, d), _pidx),
-            pl.BlockSpec((1, 1, hkv, ps), _pidx4),
-        ] * planes
+            pl.BlockSpec((1, 1, *a.shape[2:]), idx5 if a.ndim == 5 else idx4)
+            for a in arrays
+        ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(num_l, b, nj),
         in_specs=[
-            *[
-                pl.BlockSpec((1, 1, hkv, kt, d), _tidx),
-                pl.BlockSpec((1, 1, hkv, kt), _tidx3),
-            ] * planes,
-            *_pool_specs(),
+            *_specs(tails, _tidx, _tidx3), *_specs(pools, _pidx, _pidx4),
         ],
-        out_specs=tuple(_pool_specs()),
+        out_specs=tuple(_specs(pools, _pidx, _pidx4)),
         scratch_shapes=[],
     )
     return pl.pallas_call(
         kernel,
-        name="paged_tail_flush",
+        name=name,
         out_shape=tuple(
             jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools
         ),

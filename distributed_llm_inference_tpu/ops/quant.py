@@ -58,6 +58,9 @@ QUANTIZED_WEIGHTS = (
     # latent attention's own: ``wkv_a`` (its output IS the stored latent)
     # and the einsum operands ``wk_b`` / ``wv_b`` ``[rank, Hq, d]`` stay in
     # the model's dtype: 4.2 M of a Moonlight layer's 585 M parameters
+    # a learned selection's indexer (``wq_i``, ``wk_i``, ``w_i``) stays in the
+    # model's dtype too: its scores rank keys, a hard choice that rounding
+    # moves, for 2.3 M of a Keye layer's 625 M parameters
     "lm_head",
 )
 

@@ -202,11 +202,7 @@ def _qragged_kernel(
     ks_ref,      # [1, Hkv, PS] f32
     v_ref,       # [1, Hkv, PS, D] int8
     vs_ref,      # [1, Hkv, PS] f32
-    out_ref,     # [1, BQ, Hkv, G, D]
-    acc_ref,
-    m_ref,
-    l_ref,
-    *,
+    *refs,       # (sel_ref [1, 1, BQ, PS] int8 if selected,) out, acc, m, l
     scale: float,
     page_size: int,
     num_page_blocks: int,
@@ -214,10 +210,15 @@ def _qragged_kernel(
     sliding_window: Optional[int],
     hkv: int,
     g: int,
+    selected: bool = False,
 ):
     """int8 page variant of :func:`_ragged_kernel`: per-(slot, head) scales
     apply to the SCORES/probs (``q·(k·s) = s·(q·k)``), so the int8 pages
-    stream through VMEM without a dequantized copy."""
+    stream through VMEM without a dequantized copy. ``selected``: one more
+    operand, the (query, key) pairs a learned selection keeps
+    (``ops/sparse_attention.py``), a term of the tile's mask."""
+    sel_ref = refs[0] if selected else None
+    out_ref, acc_ref, m_ref, l_ref = refs[-4:]
     b = pl.program_id(0)
     qi = pl.program_id(1)
     j = pl.program_id(2)
@@ -248,6 +249,24 @@ def _qragged_kernel(
         valid = (pos < kv_len) & (pos <= q_pos) & (q_rel < nnew_ref[b])
         if sliding_window is not None:
             valid &= pos > q_pos - sliding_window
+        if selected:
+            # The block's [BQ, PS] selection, a row a query, to the scratch's
+            # rows (head, query, group member): a 0/1 matmul repeats each
+            # query's row G times (Mosaic has no sublane repeat), and the
+            # heads share it.
+            bg = block_q * g
+            spread = (
+                jax.lax.broadcasted_iota(jnp.int32, (bg, block_q), 0) // g
+                == jax.lax.broadcasted_iota(jnp.int32, (bg, block_q), 1)
+            ).astype(jnp.float32)
+            sel = jax.lax.dot_general(
+                spread, sel_ref[0, 0].astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            valid &= jnp.broadcast_to(
+                sel[None], (hkv, bg, page_size)
+            ).reshape(rows, page_size) > 0.5
 
         q = jnp.transpose(q_ref[0], (1, 0, 2, 3)).reshape(hkv, block_q * g, -1)
         k = k_ref[0]   # [Hkv, PS, D] int8
@@ -356,6 +375,20 @@ def _index_maps(block_q, page_size, sliding_window):
     return _page_index, _page_index3, _q_index
 
 
+def _select_index(block_q, page_size, sliding_window):
+    """Index map of a selection ``[B, T, S, PS]``: the (page, q-block) tile
+    of a live step, and one unchanged tile through a run of dead ones."""
+
+    def _sel_index(bi, qi, ji, table, lens, qstart, nnew):
+        live = _tile_live(
+            qi, ji, qstart[bi], nnew[bi], lens[bi], block_q=block_q,
+            page_size=page_size, sliding_window=sliding_window,
+        )
+        return (bi, jnp.where(live, ji, 0), jnp.where(live, qi, 0), 0)
+
+    return _sel_index
+
+
 def ragged_paged_attention(
     q: jnp.ndarray,
     k_pages: jnp.ndarray,
@@ -453,10 +486,15 @@ def quantized_ragged_paged_attention(
     block_q: Optional[int] = None,
     interpret: Optional[bool] = None,
     name: str = "quantized_ragged_paged_attention",
+    select: Optional[jnp.ndarray] = None,
 ):
     """As :func:`ragged_paged_attention` over int8 pages with per-(slot,
     head) scale planes (``ks_pages``/``vs_pages``: ``[P, Hkv, page_size]``
-    f32)."""
+    f32). ``select`` ``[B, T, S, page_size]`` int8 (a learned selection,
+    ``ops/sparse_attention.py``): nonzero where query ``s`` of the dispatch
+    attends to the position at table slot ``t``, offset ``p``; one more
+    pipelined block a tile and one more term of its mask. Without it the
+    call traces to the program it always did."""
     _, hkv, page_size, _ = k_pages.shape
     b, s, hq, d, bq, s_pad = _prep(q, k_pages, block_q)
     t = page_table.shape[1]
@@ -485,6 +523,10 @@ def quantized_ragged_paged_attention(
             pl.BlockSpec((1, hkv, page_size), _page_index3),
             pl.BlockSpec((1, hkv, page_size, d), _page_index),
             pl.BlockSpec((1, hkv, page_size), _page_index3),
+            *([] if select is None else [pl.BlockSpec(
+                (1, 1, bq, page_size),
+                _select_index(bq, page_size, sliding_window),
+            )]),
         ],
         out_specs=pl.BlockSpec((1, bq, hkv, g, d), _q_index),
         scratch_shapes=[
@@ -502,7 +544,15 @@ def quantized_ragged_paged_attention(
         sliding_window=sliding_window,
         hkv=hkv,
         g=g,
+        selected=select is not None,
     )
+    extra = ()
+    if select is not None:
+        if s_pad != s:
+            select = jnp.pad(
+                select, ((0, 0), (0, 0), (0, s_pad - s), (0, 0))
+            )
+        extra = (select.astype(jnp.int8),)
     out = pl.pallas_call(
         kernel,
         name=name,
@@ -511,7 +561,7 @@ def quantized_ragged_paged_attention(
         interpret=interpret,
     )(page_table.astype(jnp.int32), kv_lengths.astype(jnp.int32),
       q_start.astype(jnp.int32), num_new.astype(jnp.int32),
-      qr, k_pages, ks_pages, v_pages, vs_pages)
+      qr, k_pages, ks_pages, v_pages, vs_pages, *extra)
     return out[:, :s].reshape(b, s, hq, d)
 
 

@@ -83,6 +83,14 @@ METRICS = {
     "ragged_attn_tiles_grid": (
         "counter", "Rows x q-blocks x table width of the same dispatches"
     ),
+    # a learned key selection (ops/sparse_attention.py): selected / live
+    # over an interval says whether the contexts passed topk
+    "sparse_keys_selected": (
+        "counter", "Keys the dispatches' queries attend to under a selection"
+    ),
+    "sparse_keys_live": (
+        "counter", "Keys the same queries could see (their positions + 1)"
+    ),
     "decode_tokens": ("counter", "Tokens emitted by decode"),
     # decode ticks of an engine whose decode_steps is 1 (a cache without a
     # write-behind tail, or the operator's choice): a token a dispatch

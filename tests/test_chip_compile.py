@@ -225,3 +225,94 @@ def test_flash_attention_kernel(chip):
         s((1, seq, HQ, D), bf16), s((1, t, HKV, D), bf16),
         s((1, t, HKV, D), bf16), s((1, seq, t), jnp.bool_),
     )
+
+
+# keye-vl2-30b-a3b.longdoc: 32 query / 4 kv heads of 128 under a learned
+# top-2048 selection, an indexer of 16 heads of 64 over ONE bf16 index key a
+# token beside the int8 K and V, 12 layers, 3072 pages of 64, 16 slots, a
+# 4096-wide chunk; the table pinned at 182 pages, its cap 256.
+KEYE_HKV, KEYE_HI, KEYE_DI, KEYE_TOPK = 4, 16, 64, 2048
+KEYE_PAGES, KEYE_LAYERS, KEYE_ROWS = 3072, 12, 16
+
+
+def _keye_cache(s, rows, width):
+    from distributed_llm_inference_tpu.cache.paged import indexed_cache_class
+
+    kv = s((KEYE_LAYERS, KEYE_PAGES, KEYE_HKV, PS, D), I8)
+    sc = s((KEYE_LAYERS, KEYE_PAGES, KEYE_HKV, PS), F32)
+    return indexed_cache_class(True, KEYE_DI)(
+        k_pages=kv, v_pages=kv, ks_pages=sc, vs_pages=sc,
+        ik_pages=s((KEYE_LAYERS, KEYE_PAGES, 1, PS, KEYE_DI), jnp.bfloat16),
+        page_table=s((rows, width), I32), lengths=s((rows,), I32),
+        page_size=PS, use_kernel=True, use_ragged=True,
+    )
+
+
+def _keye_index(s, rows, seq):
+    from distributed_llm_inference_tpu.ops.sparse_attention import IndexInputs
+
+    bf16 = jnp.bfloat16
+    return lambda q, k, w: IndexInputs(q, k, w, KEYE_TOPK), (
+        s((rows, seq, KEYE_HI, KEYE_DI), bf16), s((rows, seq, KEYE_DI), bf16),
+        s((rows, seq, KEYE_HI), bf16),
+    )
+
+
+@pytest.mark.parametrize("width", [182, 256])   # the cell's pinned table; the cap
+def test_sparse_decode_step_at_keyes_shapes(chip, width):
+    """One layer of one step of ``keye-vl2-30b-a3b.longdoc``'s decode scan,
+    as the indexed int8 cache runs it: the index key into its tail, the
+    scores of the pool's and the tail's index keys, the exact selection, and
+    the fused in-place sweep under its mask (``sparse_paged_fused_attention``)
+    over the WHOLE 12-layer pool; then the window's flush."""
+    from distributed_llm_inference_tpu.ops.rotary import RopeAngles
+
+    s, b, bf16 = chip, KEYE_ROWS, jnp.bfloat16
+    make, index = _keye_index(s, b, 1)
+
+    def step(cache, tail, q, k, v, cos, sin, iq, ik, iw, lens, lidx, step):
+        out, tail = cache.tail_attend(
+            (*cache.tail_big_stacks(), lidx), tail, q, k, v,
+            RopeAngles(None, cos, sin), lens, lens * 0, step, lens * 0 + 1,
+            None, D ** -0.5, index=make(iq, ik, iw),
+        )
+        return out, cache.tail_flush(tail, lens * 0 + 1)
+
+    cache = _keye_cache(s, b, width)
+    tail = jax.eval_shape(lambda c: c.tail_init(KT), cache)
+    tail = jax.tree.map(lambda x: s(x.shape, x.dtype), tail)
+    text = jax.jit(step).lower(
+        cache, tail, s((b, 1, HQ, D), bf16), s((b, 1, KEYE_HKV, D), bf16),
+        s((b, 1, KEYE_HKV, D), bf16), s((b, 1, D), F32), s((b, 1, D), F32),
+        *index, s((b,), I32), s((), I32), s((), I32),
+    ).compile().as_text()
+    assert "sparse_paged_fused_attention" in text
+    assert "paged_tail_flush" in text and "index_tail_flush" in text
+
+
+@pytest.mark.parametrize("width", [182, 256])
+def test_sparse_prefill_chunk_at_keyes_shapes(chip, width):
+    """One layer of a 4096-wide chunk: the chunk's K, V and index keys into
+    the pool, every query's selection over the row's whole table (scored a
+    block of queries at a time), and the ragged kernel under the (query,
+    key) mask (``sparse_ragged_paged_attention``)."""
+    from distributed_llm_inference_tpu.ops.attention import gqa_attention
+    from distributed_llm_inference_tpu.ops.rotary import RopeAngles
+
+    s, seq, bf16 = chip, 4096, jnp.bfloat16
+    make, index = _keye_index(s, 1, seq)
+
+    def chunk(cache, q, k, v, cos, sin, iq, ik, iw, q_pos, num_new):
+        state = tuple(x[3] for x in cache.layer_stacks)
+        return cache.attend(
+            state, q, k, v, RopeAngles(None, cos, sin), q_pos, num_new, None,
+            gqa_attention, D ** -0.5, index=make(iq, ik, iw),
+        )
+
+    text = jax.jit(chunk).lower(
+        _keye_cache(s, 1, width), s((1, seq, HQ, D), bf16),
+        s((1, seq, KEYE_HKV, D), bf16), s((1, seq, KEYE_HKV, D), bf16),
+        s((1, seq, D), F32), s((1, seq, D), F32), *index,
+        s((1, seq), I32), s((1,), I32),
+    ).compile().as_text()
+    assert "sparse_ragged_paged_attention" in text

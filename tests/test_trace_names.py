@@ -149,3 +149,45 @@ def test_a_one_segment_forward_is_in_its_segments_scope():
     )
     text = lowered_text(cfg)
     assert scoped(text, "dense_stack") and not scoped(text, "moe_stack")
+
+
+def test_a_lowered_sparse_forward_carries_the_selections_scopes_and_kernels():
+    """A model that selects its keys, through the indexed int8 cache with
+    its kernels (interpreted here): the three scopes of
+    ``ops/sparse_attention.py`` inside ``attention`` and the kernels' own
+    names, in a prefill chunk and in the fused decode scan."""
+    from distributed_llm_inference_tpu.cache.paged import indexed_cache_class
+    from distributed_llm_inference_tpu.config import SparseAttentionConfig
+
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16, qk_norm=True,
+        sparse=SparseAttentionConfig(2, 8, 4), family="keye_vl2",
+    )
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    cache = indexed_cache_class(True, 8).create(
+        2, 1, 5, 8, 4, 2, 16, jnp.float32, use_kernel=True, use_ragged=True
+    )
+    one = jnp.ones((1,), jnp.int32)
+    prefill = jax.jit(
+        lambda p, t, c: llama.model_apply(cfg, p, t, c, 8 * one, head="last")
+    ).lower(params, jnp.zeros((1, 8), jnp.int32), cache).as_text(debug_info=True)
+    decode = jax.jit(lambda p, t, c: llama.multi_decode_apply(
+        cfg, p, t, c, 4, lambda i, logits, st: (t[:, 0], one, st, logits),
+        jnp.zeros(()), one,
+    )).lower(params, jnp.zeros((1, 1), jnp.int32), cache).as_text(debug_info=True)
+    for text, kernels in (
+        (prefill, ["sparse_ragged_paged_attention"]),
+        (decode, ["sparse_paged_fused_attention", "index_tail_flush",
+                  "paged_tail_flush"]),
+    ):
+        for scope in ("attention/index_scores", "attention/index_select",
+                      "attention/sparse_attention"):
+            assert scoped(text, scope), scope
+        for kernel in kernels:
+            assert kernel in text, kernel
+    dense = lowered_text(ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16,
+    ))
+    assert "index_scores" not in dense and "sparse_attention" not in dense
