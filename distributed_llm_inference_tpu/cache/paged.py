@@ -130,6 +130,29 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
     # Stored-form plane name -> pool field (export/spill/reload share this
     # map so the host-facing naming cannot drift between them).
     PLANE_FIELDS = {"k": "k_pages", "v": "v_pages"}
+    #: per-row fields ``[B, slots]`` that widen and narrow with the table
+    TABLE_FIELDS = ("page_table",)
+    #: role ("decode" | "prefill" | "flush") -> the name a class gives its
+    #: kernel calls in a device trace; a role left out keeps the kernel's
+    #: own (the window pool of a two-pool cache names all three)
+    KERNEL_NAMES = {}
+
+    def _kernel_name(self, role: str) -> Dict[str, str]:
+        name = self.KERNEL_NAMES.get(role)
+        return {} if name is None else {"name": name}
+
+    def resize_table(self, slots: int) -> "PagedKVCache":
+        """The same cache over a table ``slots`` wide: zero (null page)
+        columns padded on, or the last columns dropped. The pool never
+        moves."""
+        pad = slots - self.page_table.shape[1]
+        return self.replace(**{
+            f: (
+                jnp.pad(getattr(self, f), ((0, 0), (0, pad))) if pad > 0
+                else getattr(self, f)[:, :slots]
+            )
+            for f in self.TABLE_FIELDS
+        })
 
     @staticmethod
     def create(
@@ -276,6 +299,7 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
                 q_rot, new_k, new_v, self.page_table,
                 self.lengths + num_new, num_new,
                 scale=scale, sliding_window=sliding_window,
+                **self._kernel_name("prefill"),
             )
             return out, (new_k, new_v)
         if not self.use_kernel or q.shape[1] != 1:
@@ -928,6 +952,7 @@ class QuantizedPagedKVCache(PagedKVCache):
                 q_rot, new[0], new[2], new[1], new[3], self.page_table,
                 self.lengths + num_new, num_new,
                 scale=scale, sliding_window=sliding_window,
+                **self._kernel_name("prefill"),
             )
             return out, new
         if not self.use_kernel or q.shape[1] != 1:
@@ -1120,6 +1145,7 @@ class QuantizedPagedKVCache(PagedKVCache):
                     tail_valid_len=tail_len + num_new,
                     q_positions=base_len + tail_len,
                     scale=scale, sliding_window=sliding_window,
+                    **self._kernel_name("decode"),
                     **({} if select is None else {
                         "select": select, "name": KERNEL_DECODE,
                     }),
@@ -1184,6 +1210,7 @@ class QuantizedPagedKVCache(PagedKVCache):
                     self.k_pages, self.ks_pages, self.v_pages, self.vs_pages,
                     wk, wks, wv, wvs,
                     self.page_table, self.lengths, tail_len,
+                    **self._kernel_name("flush"),
                 )
                 return self.replace(
                     k_pages=new_k, v_pages=new_v,
@@ -1525,3 +1552,282 @@ def indexed_cache_class(quantized: bool, index_dim: int):
     return type(
         f"{base.__name__}{index_dim}", (base,), {"INDEX_DIM": int(index_dim)}
     )
+
+
+# -- window and full layers in one stack: a pool a kind --------------------------
+#
+# A model whose layers are not all of one attention kind
+# (``ModelConfig.mixed_attention``) keeps two kinds of per-token state. A full
+# layer needs every position of a row for as long as the row lives; a window
+# layer needs the last ``window`` positions and nothing before them. Under ONE
+# page table a window layer would keep pages it can never read, so the two
+# classes below hold the kinds apart: the full layers keep the parent's pool
+# ``k_pages [full layers, P, heads, PS, D]`` and table, and the window layers
+# a pool ``wk_pages [window layers, Pw, ...]`` with a table ``w_page_table``
+# of their own, of the SAME logical width (slot ``i`` is positions ``i * PS
+# .. (i + 1) * PS`` in both). The kernels then run on (window pool, window
+# table, the window) unchanged: ``ops/paged_attention.py:_live_pages`` and
+# ``ops/ragged_attention.py:_tile_live`` never fetch a slot that lies wholly
+# before the window, so the engine releases such a slot's page and gives it
+# to another row, and a row's window pages are bounded by the window and what
+# one dispatch writes, whatever its context.
+#
+# A released slot keeps its STALE page id in the table. That is safe, and
+# relied on, in two ways. The id stays a valid page of the window pool (a
+# page is only ever handed to another row, never removed), so the paths that
+# gather a row's whole table in XLA (``update_and_gather``, the gathered
+# ``tail_big_stacks`` under ``INPLACE_CTX``) read finite values of another
+# row there; and every position of a released slot is at or before ``query -
+# window`` for every query the row can still make (the engine's release
+# rule), so those values lie under the window's mask (``causal_mask`` /
+# ``segment_valids`` with ``sliding_window``) and weigh nothing. Writes never
+# reach a stale slot: they go to positions at or past the row's length.
+#
+# The model reaches a pool through :meth:`pool_view`: a plain instance of the
+# parent class over that pool's planes and table (the window pool's under
+# kernel names of its own), so attention, the scatter paths and the whole
+# tail protocol are the parent's code for both pools, and
+# ``models/llama.py`` puts the planes back with :meth:`with_pool_view`.
+#
+# Which layers are of which kind is the CLASS's (``LAYER_KINDS``), as the
+# index key's width is the indexed classes': whoever builds "a cache like
+# this one" from ``k_pages``' shape alone (the benchmark's probe) gets both
+# pools. :func:`two_pool_cache_class` makes the class once a (stored form,
+# layer kinds, window).
+
+_WINDOW_KERNELS = {
+    "decode": "window_paged_fused_attention",
+    "prefill": "window_ragged_paged_attention",
+    "flush": "window_tail_flush",
+}
+
+
+class _WindowPagedKVCache(PagedKVCache):
+    """The window layers' pool as the model sees it: the parent's code
+    under the window pool's kernel names."""
+
+    KERNEL_NAMES = _WINDOW_KERNELS
+
+
+class _WindowQuantizedPagedKVCache(QuantizedPagedKVCache):
+    KERNEL_NAMES = _WINDOW_KERNELS
+
+
+class _TwoPools:
+    """What the two two-pool classes share. ``WINDOW_PLANES`` maps a parent
+    plane's field to the window pool's."""
+
+    LAYER_KINDS = None      # "window" | "full", a layer of the stack
+    WINDOW = None           # the window layers' window, in positions
+
+    @classmethod
+    def num_layers_of(cls, kind: str) -> int:
+        return sum(1 for k in cls.LAYER_KINDS if k == kind)
+
+    @classmethod
+    def create(cls, num_layers, batch, num_pages, page_size,
+               max_pages_per_session, num_kv_heads, head_dim,
+               dtype=jnp.bfloat16, use_kernel=False, use_ragged=False,
+               window_pages=None):
+        """``num_layers`` counts ``k_pages``' layers, the FULL ones (what a
+        caller reads off ``k_pages.shape``); the window pool's layers are
+        the class's. ``window_pages``: the window pool's pages (the engine
+        sizes it by the window; default as many as the full pool, which is
+        what a one-row probe wants: no page is ever reused there)."""
+        if num_layers != cls.num_layers_of("full"):
+            raise ValueError(
+                f"{cls.__name__} holds {cls.num_layers_of('full')} full "
+                f"layers in k_pages (and {cls.num_layers_of('window')} "
+                f"window layers beside them), not {num_layers}"
+            )
+        full = cls.POOL.create(
+            num_layers, batch, num_pages, page_size, max_pages_per_session,
+            num_kv_heads, head_dim, dtype,
+            use_kernel=use_kernel, use_ragged=use_ragged,
+        )
+        window = cls.POOL.create(
+            cls.num_layers_of("window"), batch, window_pages or num_pages,
+            page_size, max_pages_per_session, num_kv_heads, head_dim, dtype,
+        )
+        return cls(
+            **{f.name: getattr(full, f.name) for f in dataclasses.fields(full)},
+            **{w: getattr(window, f) for f, w in cls.WINDOW_PLANES.items()},
+            w_page_table=window.page_table,
+        )
+
+    # -- the pools, as the model sees them ------------------------------------
+
+    def pool_view(self, kind: str):
+        """The cache of one attention kind's layers: an instance of the
+        parent class over that pool's planes and table, sharing this
+        cache's lengths."""
+        if kind == "full":
+            return self.POOL(**{
+                f.name: getattr(self, f.name)
+                for f in dataclasses.fields(self.POOL)
+            })
+        return self.WINDOW_POOL(
+            **{f: getattr(self, w) for f, w in self.WINDOW_PLANES.items()},
+            page_table=self.w_page_table, lengths=self.lengths,
+            page_size=self.page_size, use_kernel=self.use_kernel,
+            use_ragged=self.use_ragged,
+        )
+
+    def with_pool_view(self, kind: str, view, lengths: bool = False):
+        """This cache with ``view``'s planes put back (and, after a tail
+        flush, its advanced lengths: the same from either pool)."""
+        planes = (
+            {f: getattr(view, f) for f in self.WINDOW_PLANES}
+            if kind == "full"
+            else {w: getattr(view, f) for f, w in self.WINDOW_PLANES.items()}
+        )
+        if lengths:
+            planes["lengths"] = view.lengths
+        return self.replace(**planes)
+
+    # -- rows ----------------------------------------------------------------
+
+    def reset_rows(self, row_mask):
+        return super().reset_rows(row_mask).replace(
+            w_page_table=jnp.where(row_mask[:, None], 0, self.w_page_table)
+        )
+
+    def select_row(self, row):
+        return super().select_row(row).replace(
+            w_page_table=jax.lax.dynamic_slice_in_dim(
+                self.w_page_table, row, 1, axis=0
+            )
+        )
+
+    def merge_row(self, sub, row):
+        return super().merge_row(sub, row).replace(
+            w_page_table=jax.lax.dynamic_update_slice_in_dim(
+                self.w_page_table, sub.w_page_table, row, axis=0
+            )
+        )
+
+    def select_rows(self, rows):
+        return super().select_rows(rows).replace(
+            w_page_table=jnp.take(self.w_page_table, rows, axis=0, mode="clip")
+        )
+
+    def merge_rows(self, sub, rows):
+        return super().merge_rows(sub, rows).replace(
+            w_page_table=self.w_page_table.at[rows].set(
+                sub.w_page_table, mode="drop"
+            )
+        )
+
+    # -- tables --------------------------------------------------------------
+
+    def assign_pages(self, row, pages, start_slot=0):
+        """The SAME page ids into both tables: what a one-row cache wants
+        (the benchmark's probe; the engine's warm-up of the table write),
+        where both pools hold as many pages and none is reused. The engine
+        installs a serving row's pages a pool at a time
+        (``assign_pages_batch``, ``assign_window_pages_batch``)."""
+        pages = jnp.asarray(pages, jnp.int32)
+        at = (pages[None, :], jnp.int32(row), jnp.int32(start_slot))
+        return self.replace(
+            page_table=_table_write(self.page_table, *at),
+            w_page_table=_table_write(self.w_page_table, *at),
+        )
+
+    def assign_window_pages_batch(self, rows, slots, pages, pad_to=0):
+        """:meth:`assign_pages_batch` into the window table."""
+        view = self.pool_view("window").assign_pages_batch(
+            rows, slots, pages, pad_to
+        )
+        return self.replace(w_page_table=view.page_table)
+
+    # -- what a two-pool cache does not do --------------------------------------
+
+    def _one_table_only(self, what: str):
+        raise NotImplementedError(
+            f"{what} is not implemented for a cache of window and full "
+            f"layers: a window layer keeps only a row's last pages, so a "
+            f"row's KV cannot be shipped, shared or reloaded as one run of "
+            f"pages"
+        )
+
+    def _ingest_planes(self, planes, n_valid, first_slot=0):
+        self._one_table_only("ingesting a row's KV")
+
+    def copy_page(self, dst, src):
+        self._one_table_only("a copy-on-write page split")
+
+    def read_page(self, page):
+        self._one_table_only("reading a page for the spill store")
+
+    def write_page(self, page, tiles):
+        self._one_table_only("reloading a spilled page")
+
+
+class TwoPoolPagedKVCache(_TwoPools, PagedKVCache):
+    """:class:`PagedKVCache` for a stack of window and full layers: the
+    exact-arithmetic form (float32 tests, the int8 class's oracle)."""
+
+    wk_pages: jax.Array = None
+    wv_pages: jax.Array = None
+    w_page_table: jax.Array = None
+
+    POOL, WINDOW_POOL = PagedKVCache, _WindowPagedKVCache
+    WINDOW_PLANES = {"k_pages": "wk_pages", "v_pages": "wv_pages"}
+    BATCH_AXES = {"page_table": 0, "lengths": 0, "w_page_table": 0}
+    TABLE_FIELDS = ("page_table", "w_page_table")
+    LAYER_FIELDS = ("k_pages", "v_pages", "wk_pages", "wv_pages")
+    SHARED_FIELDS = LAYER_FIELDS
+    PLANE_FIELDS = {
+        "k": "k_pages", "v": "v_pages", "wk": "wk_pages", "wv": "wv_pages",
+    }
+
+
+class TwoPoolQuantizedPagedKVCache(_TwoPools, QuantizedPagedKVCache):
+    """:class:`QuantizedPagedKVCache` for a stack of window and full layers.
+    Both pools have the parent's tail protocol, each through its view: the
+    fused 16-step scan writes a K/V tail a pool and flushes each where its
+    table says."""
+
+    wk_pages: jax.Array = None
+    wv_pages: jax.Array = None
+    wks_pages: jax.Array = None
+    wvs_pages: jax.Array = None
+    w_page_table: jax.Array = None
+
+    POOL, WINDOW_POOL = QuantizedPagedKVCache, _WindowQuantizedPagedKVCache
+    WINDOW_PLANES = {
+        "k_pages": "wk_pages", "v_pages": "wv_pages",
+        "ks_pages": "wks_pages", "vs_pages": "wvs_pages",
+    }
+    BATCH_AXES = {"page_table": 0, "lengths": 0, "w_page_table": 0}
+    TABLE_FIELDS = ("page_table", "w_page_table")
+    LAYER_FIELDS = (
+        "k_pages", "v_pages", "ks_pages", "vs_pages",
+        "wk_pages", "wv_pages", "wks_pages", "wvs_pages",
+    )
+    SHARED_FIELDS = LAYER_FIELDS
+    PLANE_FIELDS = {
+        "k": "k_pages", "v": "v_pages", "ks": "ks_pages", "vs": "vs_pages",
+        "wk": "wk_pages", "wv": "wv_pages",
+        "wks": "wks_pages", "wvs": "wvs_pages",
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def two_pool_cache_class(quantized: bool, layer_kinds: tuple, window: int):
+    """THE two-pool cache class of a stored form, a stack's attention kinds
+    ("window" | "full", a layer) and its window, made once (a class is a
+    pytree node type: two engines of one stack must hold the same one)."""
+    base = TwoPoolQuantizedPagedKVCache if quantized else TwoPoolPagedKVCache
+    full = sum(1 for k in layer_kinds if k == "full")
+    return type(
+        f"{base.__name__}{full}of{len(layer_kinds)}w{window}", (base,),
+        {"LAYER_KINDS": tuple(layer_kinds), "WINDOW": int(window)},
+    )
+
+
+def window_pages_bound(window: int, page_size: int, tokens: int) -> int:
+    """The most window pages a row holds around one dispatch that writes
+    ``tokens`` positions: the pages the window before the first of them
+    reaches into, those the dispatch writes, and one for the straddle."""
+    return -(-(window - 1 + tokens) // page_size) + 1

@@ -105,6 +105,19 @@ class LayerSegment:
     key: str    # where the segment's stacked parameters live in the tree
     start: int
     count: int
+    # The attention of its layers (``ModelConfig.attention_kinds``): "window"
+    # layers see the last ``window`` keys, "full" layers every key (``window``
+    # None); ``rope`` says whether its queries and keys are rotated.
+    attention: str = "full"
+    window: Optional[int] = None
+    rope: bool = True
+    # Its part of the cache. A stack of ONE attention kind has one pool and
+    # ``pool`` is None: the segment owns cache layers ``cache_start ..
+    # cache_start + count`` with ``cache_start == start``. A stack of two
+    # kinds keeps each kind's layers in a pool of its own (``pool`` = the
+    # kind), and ``cache_start`` counts the earlier layers of that kind.
+    pool: Optional[str] = None
+    cache_start: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,6 +152,9 @@ class ModelConfig:
     # Qwen2-style bias on q/k/v projections.
     qkv_bias: bool = False
     # MoE (Mixtral): 0 experts = dense MLP.
+    # ``num_experts`` is the ROUTER's width: the experts a token's scores
+    # run over. How many of them this program holds is
+    # :attr:`num_held_experts` (all, unless ``expert_shares`` > 1).
     num_experts: int = 0
     num_experts_per_tok: int = 2
     # Opt-in sorted expert dispatch for MoE prefill (ops/moe.py): tokens
@@ -175,8 +191,22 @@ class ModelConfig:
     # Learned top-k key selection (an indexer beside GQA); requires the
     # "keye_vl2" family and the paged cache kind. None = every key.
     sparse: Optional[SparseAttentionConfig] = None
+    # The layers' attention, one of "window" | "full" a layer, where they are
+    # not all alike (``layer_types``); None = every layer the same: a window
+    # layer where ``sliding_window`` is set, else a full one. A window layer
+    # sees keys ``j`` with ``t - sliding_window < j <= t``.
+    layer_attention: Optional[Tuple[str, ...]] = None
+    # Whether a FULL layer of a stack of two kinds rotates its queries and
+    # keys (EXAONE 4.0's hybrid rule: RoPE in the window layers only).
+    full_attention_rope: bool = True
+    # The share of each expert layer's experts held here: share
+    # ``expert_share_index`` of ``expert_shares`` contiguous, equal shares
+    # of the ``num_experts`` the router scores (``ops/moe.py``). 1 share =
+    # every expert is here.
+    expert_shares: int = 1
+    expert_share_index: int = 0
     # Model family tag ("llama", "mistral", "qwen2", "mixtral", "mla",
-    # "keye_vl2").
+    # "keye_vl2", "exaone_moe").
     family: str = "llama"
 
     @property
@@ -188,21 +218,67 @@ class ModelConfig:
         return self.moe_intermediate_size or self.intermediate_size
 
     @property
+    def num_held_experts(self) -> int:
+        """Expert matrices a routed layer holds: the router's width over the
+        shares."""
+        return self.num_experts // self.expert_shares
+
+    @property
+    def first_held_expert(self) -> int:
+        return self.expert_share_index * self.num_held_experts
+
+    @property
+    def attention_kinds(self) -> Tuple[str, ...]:
+        """"window" | "full", a layer. One window for the whole model
+        (Mistral) is every layer a window layer."""
+        if self.layer_attention is not None:
+            return self.layer_attention
+        kind = "window" if self.sliding_window is not None else "full"
+        return (kind,) * self.num_layers
+
+    @property
+    def mixed_attention(self) -> bool:
+        """THE two-kinds predicate: window and full layers in one stack, so
+        the cache keeps a pool a kind (``cache/paged.py``)."""
+        return len(set(self.attention_kinds)) > 1
+
+    @property
     def segments(self) -> Tuple[LayerSegment, ...]:
         """THE description of the stack: homogeneous segments in layer
-        order. Initialisation, the forward programs, the checkpoint
-        converter, the quantiser and the census all walk this. A stack
-        whose layers are all alike is one segment under ``"layers"``, the
-        parameter tree every such model has always had; a stack with
-        leading dense layers is a dense segment then an expert one."""
-        kind = "moe" if self.num_experts > 0 else "dense"
-        k = self.first_dense_layers if kind == "moe" else 0
-        if k == 0:
-            return (LayerSegment(kind, "layers", 0, self.num_layers),)
-        return (
-            LayerSegment("dense", "layers_0_dense", 0, k),
-            LayerSegment("moe", "layers_1_moe", k, self.num_layers - k),
-        )
+        order, runs of layers alike in MLP ("dense" | "moe") and attention
+        ("window" | "full"). Initialisation, the forward programs, the
+        checkpoint converter, the quantiser and the census all walk this. A
+        stack whose layers are all alike is one segment under ``"layers"``,
+        the parameter tree every such model has always had; a stack with
+        leading dense layers is a dense segment then an expert one; window
+        and full layers alternate segments, each with its window, its RoPE
+        switch and its part of the cache."""
+        moe = self.num_experts > 0
+        k = self.first_dense_layers if moe else 0
+        kinds = self.attention_kinds
+        mixed = self.mixed_attention
+        runs = []                       # [mlp, attention, start, count]
+        for i in range(self.num_layers):
+            mlp = "moe" if moe and i >= k else "dense"
+            if runs and runs[-1][:2] == [mlp, kinds[i]]:
+                runs[-1][3] += 1
+            else:
+                runs.append([mlp, kinds[i], i, 1])
+        seen = {"window": 0, "full": 0}
+        out = []
+        for n, (mlp, att, start, count) in enumerate(runs):
+            out.append(LayerSegment(
+                mlp,
+                "layers" if len(runs) == 1 else f"layers_{n}_{mlp}",
+                start, count,
+                attention=att,
+                window=self.sliding_window if att == "window" else None,
+                rope=att == "window" or not mixed or self.full_attention_rope,
+                pool=att if mixed else None,
+                cache_start=seen[att] if mixed else start,
+            ))
+            seen[att] += count
+        return tuple(out)
 
     @property
     def num_expert_layers(self) -> int:
@@ -282,6 +358,27 @@ class ModelConfig:
                     moe_scoring="softmax",
                     moe_norm_topk=bool(get("norm_topk_prob", False)),
                 )
+        # a ``KeyeVL2`` block's ``sliding_window`` counts only under
+        # ``use_sliding_window`` (refused above): the key alone states no
+        # window
+        window = (
+            None if model_type == "keye_vl2" else get("sliding_window", None)
+        )
+        experts = (
+            get("num_local_experts", 0) or get("n_routed_experts", 0)
+            or get("num_experts", 0) or 0
+        )
+        if model_type == "exaone_moe":
+            moe, extra = _exaone_moe_keys(get)
+            window, experts = extra.pop("sliding_window"), extra.pop("num_experts")
+        # A block may nest its RoPE keys (``rope_parameters``: theta and
+        # type together) where older ones spread them at the top level.
+        nested = get("rope_parameters", None) or {}
+        rope_scaling = get("rope_scaling", None)
+        if rope_scaling is None and nested.get(
+            "rope_type", "default"
+        ) != "default":
+            rope_scaling = nested
         return ModelConfig(
             vocab_size=get("vocab_size", 32000),
             hidden_size=hidden,
@@ -291,22 +388,15 @@ class ModelConfig:
             num_kv_heads=get("num_key_value_heads", num_heads) or num_heads,
             head_dim=get("head_dim", None) or hidden // num_heads,
             rms_norm_eps=get("rms_norm_eps", 1e-5),
-            rope_theta=get("rope_theta", 10000.0),
-            rope_scaling=RopeScaling.from_hf(get("rope_scaling", None)),
+            rope_theta=(
+                get("rope_theta", None) or nested.get("rope_theta", 10000.0)
+            ),
+            rope_scaling=RopeScaling.from_hf(rope_scaling),
             max_position_embeddings=get("max_position_embeddings", 4096),
             tie_word_embeddings=bool(get("tie_word_embeddings", False)),
-            # a ``KeyeVL2`` block's ``sliding_window`` counts only under
-            # ``use_sliding_window`` (refused above): the key alone states
-            # no window
-            sliding_window=(
-                None if model_type == "keye_vl2"
-                else get("sliding_window", None)
-            ),
+            sliding_window=window,
             qkv_bias=bool(get("attention_bias", False)) or model_type in ("qwen2",),
-            num_experts=(
-                get("num_local_experts", 0) or get("n_routed_experts", 0)
-                or get("num_experts", 0) or 0
-            ),
+            num_experts=experts,
             num_experts_per_tok=get("num_experts_per_tok", 2) or 2,
             latent=latent,
             family=model_type,
@@ -340,6 +430,108 @@ def _refuse_unimplemented(get) -> None:
                 f"next-token-prediction layers and interleaved dense layers "
                 f"are outside what models/llama.py computes"
             )
+
+
+_ATTENTION_KINDS = {"sliding_attention": "window", "full_attention": "full"}
+
+
+def _exaone_moe_keys(get):
+    """The keys of an ``exaone_moe`` ``config.json`` (K-EXAONE): window and
+    full layers by ``layer_types``, a leading dense layer by
+    ``mlp_layer_types`` / ``first_k_dense_replace``, DeepSeek-V3's routing
+    keys without a latent, and (a benchmark configuration's own key)
+    ``expert_share``: ``{"router_experts", "shares", "index"}``, the share
+    of each layer's experts that ``num_experts`` counts. Returns ``(moe,
+    extra)`` keyword groups for :class:`ModelConfig`. What the block has
+    and this program does not compute raises under its key's name."""
+    def refuse(key, why):
+        raise ValueError(
+            f"config key {key!r} = {get(key)!r} is not implemented: {why}"
+        )
+
+    layers = get("num_hidden_layers", 32)
+    if (get("num_nextn_predict_layers", 0) or 0) > 0:
+        refuse(
+            "num_nextn_predict_layers",
+            "a step of this engine yields one token a row (the fused decode "
+            "scan, the write-behind tail); multi-token prediction layers "
+            "are not served",
+        )
+    if (get("n_group", 1) or 1) > 1 or (get("topk_group", 1) or 1) > 1:
+        refuse("n_group", "routing by groups of experts")
+    if get("scoring_func", "sigmoid") not in ("softmax", "sigmoid"):
+        refuse("scoring_func", "softmax or sigmoid scores")
+    window = get("sliding_window", None)
+    types = get("layer_types", None)
+    kinds = None
+    if types is not None:
+        if len(types) != layers or any(t not in _ATTENTION_KINDS for t in types):
+            refuse(
+                "layer_types",
+                f"one of {sorted(_ATTENTION_KINDS)} for each of the "
+                f"{layers} layers",
+            )
+        kinds = tuple(_ATTENTION_KINDS[t] for t in types)
+        if "window" in kinds and not window:
+            refuse("sliding_window", "a window layer needs its window")
+        # which layers have a window; its size is ``sliding_window``'s
+        windows = get("sliding_windows", None)
+        if windows is not None and [bool(w) for w in windows] != [
+            k == "window" for k in kinds
+        ]:
+            refuse(
+                "sliding_windows",
+                "a window for every sliding_attention layer, 0 for the "
+                "full ones",
+            )
+        if "window" not in kinds:
+            window = None
+        if len(set(kinds)) == 1:
+            kinds = None
+    first_dense = get("first_k_dense_replace", 0) or 0
+    mlps = get("mlp_layer_types", None)
+    if mlps is not None:
+        dense = sum(1 for m in mlps if m == "dense")
+        if len(mlps) != layers or list(mlps) != (
+            ["dense"] * dense + ["sparse"] * (layers - dense)
+        ):
+            refuse(
+                "mlp_layer_types",
+                "leading dense layers and then expert layers only",
+            )
+        if dense != first_dense:
+            refuse("first_k_dense_replace", "agreement with mlp_layer_types")
+    held = get("num_experts", 0) or 0
+    share = get("expert_share", None) or {}
+    shares = int(share.get("shares", 1))
+    router = int(share.get("router_experts", held))
+    if router != held * shares or not 0 <= int(share.get("index", 0)) < shares:
+        refuse(
+            "expert_share",
+            f"router_experts = num_experts ({held}) x shares, and an index "
+            f"under shares",
+        )
+    moe = dict(
+        moe_intermediate_size=get("moe_intermediate_size", None),
+        num_shared_experts=get("num_shared_experts", 0) or 0,
+        first_dense_layers=first_dense,
+        moe_scoring=get("scoring_func", "sigmoid"),
+        # the selection bias of DeepSeek-V3's gate: assumed, the config has
+        # no key for it (benchmark/configs/k-exaone-236b-a23b.json)
+        moe_select_bias=True,
+        moe_norm_topk=bool(get("norm_topk_prob", False)),
+        moe_routed_scale=float(get("routed_scaling_factor", 1.0) or 1.0),
+    ) if held else {}
+    extra = dict(
+        qk_norm=True,
+        layer_attention=kinds,
+        full_attention_rope=False,
+        sliding_window=window,
+        num_experts=router,
+        expert_shares=shares,
+        expert_share_index=int(share.get("index", 0)),
+    )
+    return moe, extra
 
 
 def _refuse_unimplemented_keye(get) -> None:

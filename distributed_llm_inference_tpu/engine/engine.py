@@ -40,6 +40,7 @@ from ..cache.dense import DenseKVCache, QuantizedDenseKVCache
 from ..cache.latent import LatentPagedKVCache, QuantizedLatentPagedKVCache
 from ..cache.paged import (
     PageAllocator, PagedKVCache, QuantizedPagedKVCache, indexed_cache_class,
+    two_pool_cache_class, window_pages_bound,
 )
 from ..cache.sink import QuantizedSinkKVCache, SinkKVCache
 
@@ -69,6 +70,25 @@ _NO_REGION = contextlib.nullcontext()
 # shrink, and 30 s outlasts 95% of the gaps of arrivals as sparse as one
 # in ten seconds. Not swept; a constant, not an option.
 IDLE_SHRINK_S = 30.0
+
+# Rows of one batched admission dispatch (``_prefill_group``).
+GROUP_ROWS = 8
+
+
+def window_pool_pages(window: int, page_size: int, batch: int,
+                      chunk_tokens: int, decode_tokens: int) -> int:
+    """Pages of the WINDOW pool of a stack of window and full layers: the
+    null page, what every row holds while it decodes (its window and the
+    ``decode_tokens`` a dispatch and the one in flight may write), and what
+    the rows of ONE prefill dispatch hold while it is assembled (a chunk of
+    ``chunk_tokens`` a row, ``GROUP_ROWS`` rows at most), in whole 128s. It
+    does not grow with the context: that is the pool's point. Not an
+    option: the window, the batch and the chunk size it."""
+    rows = batch * window_pages_bound(window, page_size, decode_tokens)
+    burst = min(batch, GROUP_ROWS) * window_pages_bound(
+        window, page_size, chunk_tokens
+    )
+    return -(-(1 + rows + burst) // 128) * 128
 
 
 class InferenceEngine:
@@ -266,8 +286,46 @@ class InferenceEngine:
                     "sharding of the index plane is not implemented)"
                 )
             self.plan.sparse_topk = cfg.sparse.topk
+        # Window and full layers in one stack: the cache keeps a pool a kind
+        # (cache/paged.py, the two-pool classes), and the window pool's
+        # pages leave a row as its window passes them. What is written for
+        # ONE run of pages a row is refused here by name.
+        two_pools = cfg.mixed_attention
+        self.window_allocator = None
+        if two_pools:
+            if cc.kind != "paged":
+                raise ValueError(
+                    "a stack of window and full layers "
+                    "(ModelConfig.layer_attention) requires the paged cache "
+                    f"(got kind={cc.kind!r})"
+                )
+            for bad, what in (
+                (mesh_cfg is not None,
+                 "a mesh (sharding of the window pool is not implemented)"),
+                (cc.prefix_caching,
+                 "prefix_caching (a shared page chain would need its "
+                 "window pages too)"),
+                (draft is not None,
+                 "a draft model (speculative decoding over a separate "
+                 "dense cache)"),
+            ):
+                if bad:
+                    raise ValueError(
+                        "a stack of window and full layers does not "
+                        f"compose with {what}"
+                    )
         self.plan.latent = self._latent
-        self.plan.sliding_window = cfg.sliding_window
+        # The census walks the stack's attention kinds: (window, layers) a
+        # kind. A stack of ONE kind counts one layer, as it always did
+        # (every layer is alike); a stack of two weighs each by its layers.
+        kinds = cfg.attention_kinds
+        self.plan.attention_layers = tuple(
+            (
+                cfg.sliding_window if kind == "window" else None,
+                kinds.count(kind) if two_pools else 1,
+            )
+            for kind in dict.fromkeys(kinds)
+        )
         if cfg.num_experts > 0:
             from ..ops.moe import expert_rows_per_token
 
@@ -344,11 +402,39 @@ class InferenceEngine:
                     paged_cls = indexed_cache_class(
                         cc.kv_quant == "int8", cfg.sparse.index_dim
                     )
+                pool_layers, more = cfg.num_layers, {}
+                if two_pools:
+                    # ``num_pages`` sizes the FULL layers' pool (the one
+                    # that grows with the context); the window pool is
+                    # sized by the window: by what a prefill dispatch
+                    # writes a row (a chunk) and what a decode dispatch
+                    # and the one in flight may.
+                    paged_cls = two_pool_cache_class(
+                        cc.kv_quant == "int8", kinds, cfg.sliding_window
+                    )
+                    pool_layers = paged_cls.num_layers_of("full")
+                    sizes = (cfg.sliding_window, cc.page_size)
+                    chunk = self.ecfg.prefill_buckets[-1]
+                    ahead = 2 * (self.ecfg.decode_steps or 16)
+                    more["window_pages"] = window_pool_pages(
+                        *sizes, b, chunk, ahead
+                    )
+                    self.window_allocator = PageAllocator(
+                        more["window_pages"]
+                    )
+                    # what admission leaves free: one chunk's pages and
+                    # every row's while it decodes (``_admit``)
+                    self._window_reserve = window_pages_bound(
+                        *sizes, chunk
+                    ) + b * window_pages_bound(*sizes, ahead)
+                    self._pending_window_installs: List[
+                        Tuple[int, int, int]
+                    ] = []
                 self.cache = paged_cls.create(
-                    cfg.num_layers, b, cc.num_pages, cc.page_size,
+                    pool_layers, b, cc.num_pages, cc.page_size,
                     self._first_slots, cfg.num_kv_heads, cfg.head_dim, dtype,
                     use_kernel=self._use_pallas,
-                    use_ragged=_sel.use_ragged,
+                    use_ragged=_sel.use_ragged, **more,
                 )
             self.allocator = PageAllocator(cc.num_pages)
             # The q block the ragged kernel picks at a pad width over THIS
@@ -1104,11 +1190,8 @@ class InferenceEngine:
                  if -(-w // ps) >= slots_needed),
                 self.ccfg.max_pages_per_session,
             )
-            pad = new_slots - self.cache.page_table.shape[1]
-            if pad > 0:
-                self.cache = self.cache.replace(page_table=jnp.pad(
-                    self.cache.page_table, ((0, 0), (0, pad))
-                ))
+            if new_slots > self.cache.page_table.shape[1]:
+                self.cache = self.cache.resize_table(new_slots)
                 self._reshard_cache()
                 self._warm_table_write()  # new table shape → new executable
                 self.metrics.counter("cache_growths")
@@ -1165,6 +1248,12 @@ class InferenceEngine:
         self._pending_installs.append((row, slot_idx, page))
 
     def _flush_installs(self) -> None:
+        if self.window_allocator is not None and self._pending_window_installs:
+            # the window table's installs, in the same two warmed pad
+            # buckets (its shape is the full table's)
+            pending = self._pending_window_installs
+            self._pending_window_installs = []
+            self._install_batches(pending, "assign_window_pages_batch")
         if not self._pending_installs:
             return
         pending = self._pending_installs
@@ -1197,9 +1286,11 @@ class InferenceEngine:
                     start += n
                     pages = pages[n:]
             return
-        rows = [r for r, _, _ in pending]
-        slots_ = [si for _, si, _ in pending]
-        pages = [p for _, _, p in pending]
+        self._install_batches(pending, "assign_pages_batch")
+
+    def _install_batches(self, pending, assign: str) -> None:
+        """``pending`` (row, slot, page) installs through the cache's
+        batched table write ``assign``."""
         # Exactly TWO pad buckets (both pre-compiled by _warm_table_write):
         # small flushes (one admission's prompt pages) and everything else.
         # Arbitrary pow2 pads would each compile mid-serving the first time
@@ -1208,12 +1299,13 @@ class InferenceEngine:
         # one tick) splits into bucket-sized chunks — each a warmed
         # executable — instead of silently compiling an unwarmed length.
         small, big = self._install_pads()
-        while rows:
-            n = small if len(rows) <= small else big
-            self.cache = self.cache.assign_pages_batch(
-                rows[:n], slots_[:n], pages[:n], pad_to=n
+        while pending:
+            n = small if len(pending) <= small else big
+            rows, slots_, pages = zip(*pending[:n])
+            self.cache = getattr(self.cache, assign)(
+                rows, slots_, pages, pad_to=n
             )
-            rows, slots_, pages = rows[n:], slots_[n:], pages[n:]
+            pending = pending[n:]
 
     def _reshard_cache(self) -> None:
         """Re-apply the mesh shardings after a growth/shrink re-created the
@@ -1355,8 +1447,47 @@ class InferenceEngine:
                     self.allocator.free_count
                     if self.allocator is not None else None
                 ),
+                **self._window_pool_fields(),
             )
         return produced
+
+    def _refuse_two_pools(self, what: str) -> None:
+        """What moves a row's KV as ONE run of pages (disaggregated export
+        and admission, preemption with resume) is refused by name for a
+        stack of window and full layers: a window layer holds only the
+        row's last pages. Such a row is neither exported nor resumed."""
+        if self.window_allocator is not None:
+            raise ValueError(
+                f"{what} is not implemented for a stack of window and full "
+                "layers (ModelConfig.layer_attention): the window layers' "
+                "pool keeps only a row's last pages"
+            )
+
+    def _window_pool_fields(self) -> Dict[str, int]:
+        """A tick record's fields of the window pool (a stack of window and
+        full layers; nothing otherwise): its free pages, beside
+        ``free_pages`` for the full layers' pool, the most window pages a
+        row holds now, which the window bounds whatever the context, and
+        the pages both pools hold for live rows. The gauges say the same on
+        ``/metrics``."""
+        if self.window_allocator is None:
+            return {}
+        free = self.window_allocator.free_count
+        most = max(
+            (len(s.window_pages) for s in self.sessions.values()
+             if s.slot is not None),
+            default=0,
+        )
+        self.metrics.gauge("window_pool_free_pages", float(free))
+        self.metrics.gauge("kv_pool_free_pages", float(self.allocator.free_count))
+        return {
+            "free_window_pages": free, "row_window_pages_max": most,
+            # pages live rows hold, (full layers' pool, window pool)
+            "kv_pages_held": [
+                self.allocator.num_pages - 1 - self.allocator.free_count,
+                self.window_allocator.num_pages - 1 - free,
+            ],
+        }
 
     def _run_tick(self) -> List[Tuple[str, int, bool]]:
         """One tick's work; the caller holds the scheduler lock."""
@@ -1432,9 +1563,10 @@ class InferenceEngine:
 
     def _decode_spans(self, active, steps: int, pending=None):
         """A decode dispatch's queries as ``(first position, queries)``
-        pairs, a pair an active row, for the selection's census
-        (``plan.note_dispatch``); None where the model selects no keys."""
-        if self.plan.sparse_topk is None:
+        pairs, a pair an active row, for the census of what its queries see
+        (``plan.note_dispatch``: a selection's keys, a window's); None where
+        every layer sees every key."""
+        if self.plan.sparse_topk is None and not self.plan.windowed:
             return None
         return [
             (
@@ -1454,9 +1586,7 @@ class InferenceEngine:
         self.plan.note_dispatch(
             kind, shape, sum(n for _, n in row_spans), row_spans=row_spans,
             table_width=self.cache.page_table.shape[1] if paged else None,
-            sparse_spans=(
-                row_spans if self.plan.sparse_topk is not None else None
-            ),
+            query_spans=row_spans,
         )
 
     def _note_admitted(self, s: Session) -> None:
@@ -1724,6 +1854,7 @@ class InferenceEngine:
         Raises ``RuntimeError`` when admission fails (capacity rejection
         or page-pool pressure) — callers answer with an error frame and
         the gateway falls back to local prefill."""
+        self._refuse_two_pools("disaggregated prefill export")
         if isinstance(self.cache, _SINK_KINDS):
             raise ValueError(
                 "disaggregated prefill unsupported for sink caches"
@@ -1778,6 +1909,7 @@ class InferenceEngine:
         ``total_len - 1`` to take the decoded tail too. Keys are
         post-RoPE, as cached. Caller holds the scheduler lock (or owns
         the engine)."""
+        self._refuse_two_pools("exporting a row's KV")
         n = len(s.prompt) if n is None else int(n)
         cache = self.cache
         if isinstance(cache, LatentPagedKVCache):
@@ -1922,6 +2054,7 @@ class InferenceEngine:
         falling back to a local :meth:`submit`. Raises ``ValueError`` when
         the planes are structurally incompatible with this engine (wrong
         quantization, shape, or cache family)."""
+        self._refuse_two_pools("admitting a remotely prefilled session")
         if isinstance(self.cache, _SINK_KINDS):
             raise ValueError(
                 "disaggregated admission unsupported for sink caches"
@@ -2066,6 +2199,7 @@ class InferenceEngine:
         Returns ``None`` when the session is unknown, not resident, or
         finished during the drain (the terminal event is already on its
         way to the consumer — nothing to migrate)."""
+        self._refuse_two_pools("preemption (export_session)")
         with self._lock:
             s = self.sessions.get(generation_id)
             if s is None or s.state != SessionState.ACTIVE:
@@ -2114,6 +2248,7 @@ class InferenceEngine:
         (caller retries elsewhere), and raises ``ValueError`` on
         structural mismatch (quantization/shape/cache family) or a
         snapshot that is already complete."""
+        self._refuse_two_pools("resume (resume_session)")
         if isinstance(self.cache, _SINK_KINDS):
             raise ValueError("session resume unsupported for sink caches")
         if self.mesh is not None:
@@ -2259,9 +2394,7 @@ class InferenceEngine:
                 # reset or will be reset at its next admission (stale ids
                 # are masked until then) — truncating columns is free and
                 # restores the narrow gather.
-                self.cache = self.cache.replace(
-                    page_table=self.cache.page_table[:, :self._first_slots]
-                )
+                self.cache = self.cache.resize_table(self._first_slots)
                 self._reshard_cache()
             return
         if not isinstance(self.cache, (DenseKVCache, QuantizedDenseKVCache)):
@@ -2410,8 +2543,21 @@ class InferenceEngine:
                     if shared:
                         self.allocator.free(shared)  # return the refs
                     break  # pool pressure: hold the queue, retry next tick
+                if self.window_allocator is not None and (
+                    len(self._window_slots(0, self._window_first_tokens(n)))
+                    + self._window_reserve
+                    > self.window_allocator.free_count
+                ):
+                    # window-pool pressure: the first dispatch's pages, with
+                    # a chunk and every row's decode growth held back so
+                    # that no row already admitted ever waits for a page
+                    break
                 fresh = self.allocator.alloc(need - len(shared) + cow)
                 s.pages = shared + fresh  # owned: _release frees via s
+                if self.window_allocator is not None:
+                    self._window_cover(
+                        s, slot, 0, self._window_first_tokens(n)
+                    )
                 if cow:
                     # Copy-on-write split: the write offset (skip = n-1)
                     # lands INSIDE the last shared page, so the first fresh
@@ -2489,8 +2635,8 @@ class InferenceEngine:
                 singles.extend((s, 0) for s in group)
                 continue
             while group:
-                self._prefill_group(group[:8], bucket, produced)
-                group = group[8:]
+                self._prefill_group(group[:GROUP_ROWS], bucket, produced)
+                group = group[GROUP_ROWS:]
         for s, skip in singles:
             # Long greedy prompts may park for chunk/decode co-scheduling
             # instead of a monolithic synchronous prefill; _chunk_admit
@@ -2604,6 +2750,9 @@ class InferenceEngine:
                 jnp.asarray(rows), jnp.asarray(n_valid),
                 self._next_key(), sp,
             )
+        if self.window_allocator is not None:
+            for s in group:  # enqueued: the next query is the first decode
+                self._window_release(s, len(s.prompt))
         if self._overlap_ok():
             # Everything above was dispatch-only; defer the blocking
             # token fetch to the next tick boundary (it rides the tick
@@ -2716,6 +2865,7 @@ class InferenceEngine:
         while len(prompt) - offset > stride:
             chunk = prompt[offset : offset + stride]
             padded = jnp.asarray(chunk)[None, :]
+            self._window_step(s, offset, offset + stride)
             self._note_prefill("chunk", (1, stride), [(offset, len(chunk))])
             self.cache = self._prefill_ns(
                 self.params, padded, self.cache, s.slot, jnp.int32(len(chunk))
@@ -2725,11 +2875,14 @@ class InferenceEngine:
         width = self.plan.final_shape(len(rest), chunk_cap)
         padded = np.zeros((1, width), np.int32)
         padded[0, : len(rest)] = rest
+        self._window_step(s, offset, len(prompt))
         self._note_prefill("prefill", (1, width), [(offset, len(rest))])
         token, self.cache = self._prefill(
             self.params, jnp.asarray(padded), self.cache, s.slot,
             jnp.int32(len(rest)), self._next_key(), sp,
         )
+        if self.window_allocator is not None:
+            self._window_release(s, len(prompt))
         if self._overlap_ok():
             # Single-row admissions defer the token fetch exactly like the
             # batched path — the chunked prefill above was dispatch-only.
@@ -2833,9 +2986,15 @@ class InferenceEngine:
                 if s in self._chunking:
                     self._chunking.remove(s)
                 continue
-            self._flush_installs()  # chunk writes go through the table
             prompt = np.asarray(s.prompt, np.int32)
             rest = len(prompt) - s.chunk_off
+            if self.window_allocator is not None:
+                self._window_release(s, s.chunk_off)
+                if not self._window_cover(
+                    s, s.slot, s.chunk_off, s.chunk_off + min(rest, stride)
+                ):
+                    continue  # window-pool pressure: this chunk waits a tick
+            self._flush_installs()  # chunk writes go through the table
             if rest > stride:
                 chunk = prompt[s.chunk_off : s.chunk_off + stride]
                 self._note_prefill(
@@ -2863,6 +3022,8 @@ class InferenceEngine:
             s.chunking = False
             s.parked_key = None
             self._chunking.remove(s)
+            if self.window_allocator is not None:
+                self._window_release(s, len(prompt))
             if self._overlap_ok():
                 self._defer_admit(
                     [s], token, np.asarray([s.slot], np.int32),
@@ -3171,7 +3332,7 @@ class InferenceEngine:
             self.cache.page_table.shape[1] if paged
             else int(getattr(self.cache, "max_len", 0)),
         ), self._live_positions(active, pend_b), int(active.sum()),
-            sparse_spans=self._decode_spans(active, K, pend_b))
+            query_spans=self._decode_spans(active, K, pend_b))
         emitted, self.cache = self._decode_k(
             self.params, tokens_dev, self.cache, act_dev,
             self._next_key(), sp, jnp.asarray(eos_ids),
@@ -3346,7 +3507,7 @@ class InferenceEngine:
             if isinstance(self.cache, PagedKVCache)
             else int(getattr(self.cache, "max_len", 0)),
         ), self._live_positions(active), int(active.sum()),
-            sparse_spans=self._decode_spans(active, K))
+            query_spans=self._decode_spans(active, K))
         if K == 1:
             self.metrics.counter("decode_one_token_ticks")
             next_tokens, self.cache = self._decode(
@@ -3384,7 +3545,9 @@ class InferenceEngine:
         """Grow ``s``'s page run to cover ``want`` more tokens (best
         effort); returns the mapped capacity. Shared by the plain,
         speculative, and pipelined ticks so the table-widen-before-assign
-        invariant lives once."""
+        invariant lives once. Over two pools the window pool's pages move
+        with the row too: those its window has passed leave, those the
+        tokens need are taken, and the capacity is what both pools map."""
         ps = self.ccfg.page_size
         while len(s.pages) * ps < s.total_len + want:
             if (
@@ -3398,7 +3561,104 @@ class InferenceEngine:
             new = self.allocator.alloc(1)
             self._queue_install(s.slot, len(s.pages), new[0])
             s.pages.extend(new)
-        return len(s.pages) * ps
+        cap = len(s.pages) * ps
+        if self.window_allocator is not None:
+            # every query still to come is at or past the host's count
+            self._window_release(s, s.total_len - 1)
+            cap = min(cap, self._window_cover(
+                s, s.slot, s.total_len - 1, min(cap, s.total_len + want),
+                partial=True,
+            ))
+        return cap
+
+    # -- the window pool's pages (a stack of window and full layers) ---------
+    #
+    # A window layer's query at position ``t`` reads keys ``t - window < j
+    # <= t`` and nothing before them, and the kernels never fetch a table
+    # slot that lies wholly before that (``_live_pages``, ``_tile_live``).
+    # So a row holds window pages only for the slots its next dispatch
+    # reads or writes; the rest are RELEASED, here, on the host, and given
+    # to whichever row asks next.
+    #
+    # Why that is safe under pipelined ticks and overlapped admission,
+    # where the device runs behind the host. (1) Release looks FORWARD: a
+    # slot is released when every query the row can still make (at or past
+    # ``t_min``, a lower bound the host knows: its count of the row's
+    # tokens, which a tick in flight can only have raised) sees none of
+    # its positions, so no dispatch enqueued from now on reads it. (2)
+    # Dispatches already enqueued may still read it, and the new owner's
+    # writes are in a dispatch enqueued AFTER them: one device runs its
+    # queue in order, so the read is done before the page changes. The
+    # page is never handed on "in time", only in queue order. (3) The
+    # released slot's id stays in the row's table, stale: the kernels skip
+    # it, and an XLA gather of the whole table reads it under the window's
+    # mask (cache/paged.py, the two-pool classes' note).
+
+    def _window_slots(self, lo_pos: int, hi_pos: int) -> range:
+        """Table slots a dispatch touches that writes positions ``lo_pos ..
+        hi_pos`` of a row: from the slot its first query's window reaches
+        back into, to the slot of its last token."""
+        ps = self.ccfg.page_size
+        first = max(0, lo_pos - self.cfg.sliding_window + 1) // ps
+        return range(first, (max(hi_pos, lo_pos + 1) - 1) // ps + 1)
+
+    def _window_cover(self, s: Session, row: int, lo_pos: int, hi_pos: int,
+                      partial: bool = False) -> int:
+        """Give ``s`` (in batch row ``row``) the window pages a dispatch
+        that writes ``lo_pos .. hi_pos`` needs and does not hold yet, and
+        queue their installs. Returns the positions mapped: ``hi_pos``
+        rounded up to its page, or, where the pool runs dry, 0 with nothing
+        taken (``partial``: as far as the pages went instead: a decode row
+        then stops at that capacity, as it does when the full pool runs
+        dry)."""
+        ps = self.ccfg.page_size
+        need = [
+            j for j in self._window_slots(lo_pos, hi_pos)
+            if j not in s.window_pages
+        ]
+        have = self.window_allocator.free_count
+        if len(need) > have:
+            if not partial:
+                return 0
+            need = need[:have]
+        for j, page in zip(need, self.window_allocator.alloc(len(need))):
+            s.window_pages[j] = page
+            self._pending_window_installs.append((row, j, page))
+        mapped = lo_pos // ps
+        while mapped in s.window_pages:
+            mapped += 1
+        return mapped * ps
+
+    def _window_release(self, s: Session, t_min: int) -> None:
+        """Release the window pages of ``s`` that no query at or past
+        position ``t_min`` can see (the note above)."""
+        keep_from = max(0, t_min - self.cfg.sliding_window + 1) // (
+            self.ccfg.page_size
+        )
+        gone = [j for j in s.window_pages if j < keep_from]
+        if gone:
+            self.window_allocator.free([s.window_pages.pop(j) for j in gone])
+            self.metrics.counter("window_pages_released", len(gone))
+
+    def _window_step(self, s: Session, lo_pos: int, hi_pos: int) -> None:
+        """A synchronous prefill's next dispatch writes ``lo_pos ..
+        hi_pos``: release what the row's window has passed, take what the
+        dispatch needs, install. The admission gate holds a chunk's pages
+        back for this (``_window_reserve``), so the pool cannot be dry."""
+        if self.window_allocator is None:
+            return
+        self._window_release(s, lo_pos)
+        if not self._window_cover(s, s.slot, lo_pos, hi_pos):
+            raise MemoryError(
+                "window page pool exhausted inside a prefill: the admission "
+                "gate's reserve was not kept"
+            )
+        self._flush_installs()
+
+    def _window_first_tokens(self, n: int) -> int:
+        """Tokens of an ``n``-token prompt that its FIRST prefill dispatch
+        writes: all of it, or a chunk."""
+        return min(n, self.plan.prefill_stride(self._max_chunk()))
 
     def _grow_pages_for(self, s: Session, want: int, produced) -> Optional[int]:
         """:meth:`_grow_pages` plus the synchronous ticks' rule: a session
@@ -3813,3 +4073,6 @@ class InferenceEngine:
                         self.allocator.register(s.pages[i], key)
             self.allocator.free(s.pages)
             s.pages = []
+        if s.window_pages:
+            self.window_allocator.free(list(s.window_pages.values()))
+            s.window_pages = {}

@@ -139,14 +139,29 @@ class AttentionPlan:
         self.expert_rows = None
         # Set by the engine over a paged cache: ``pad width -> block_q``,
         # the q block the ragged kernel picks for this model at that width
-        # (``ops/ragged_attention.py:_prep``), and the model's window;
-        # note_dispatch keeps the census of the kernel's live tiles.
+        # (``ops/ragged_attention.py:_prep``); note_dispatch keeps the
+        # census of the kernel's live tiles.
         self.ragged_block_q = None
-        self.sliding_window: Optional[int] = None
+        # Set by the engine: the stack's attention kinds as ``(window,
+        # layers)`` pairs (``window`` None = every key). The censuses of
+        # live tiles and of decode positions walk them: a window layer
+        # skips what lies before its window, a full layer nothing. A stack
+        # of one kind is one pair of one layer, as the census always
+        # counted; a stack of window and full layers weighs each kind by
+        # its layers.
+        self.attention_layers: Tuple[Tuple[Optional[int], int], ...] = (
+            (None, 1),
+        )
         # Set by the engine for a model that selects its keys
         # (``ModelConfig.sparse``): note_dispatch keeps the census of the
         # selected and the live keys.
         self.sparse_topk: Optional[int] = None
+
+    @property
+    def windowed(self) -> bool:
+        """Window and full layers in one stack: the census by kind is
+        kept (``window_keys_*``)."""
+        return len(self.attention_layers) > 1
 
     # ------------------------------------------------------------------
     # Row classification / shape policy
@@ -262,7 +277,7 @@ class AttentionPlan:
                       active_rows: Optional[int] = None,
                       row_spans=None,
                       table_width: Optional[int] = None,
-                      sparse_spans=None) -> None:
+                      query_spans=None) -> None:
         """Record one attention dispatch: first-seen (kind, shape) is one
         fresh executable (``attn_recompiles``); prefill-family dispatches
         under ragged mode count ``attn_ragged_dispatches``.
@@ -292,20 +307,28 @@ class AttentionPlan:
         its own ``block_q``, so the count is what it computes, chip or
         not.
 
-        A model that selects its keys (``sparse_topk``) gives
-        ``sparse_spans``, a ``(first position, queries)`` pair a real row (a
-        decode row's queries are the dispatch's steps): a query at position
-        ``t`` has ``t + 1`` live keys and attends to ``min(topk, t + 1)`` of
-        them, in every layer alike. Their sums add to
-        ``sparse_keys_live`` / ``sparse_keys_selected`` (``_total`` on
-        ``/metrics``) and ride
-        the dispatch's record as a fourth entry ``(selected, live)``."""
+        ``query_spans`` is a ``(first position, queries)`` pair a real row
+        (a decode row's queries are the dispatch's steps). A model that
+        selects its keys (``sparse_topk``): a query at position ``t`` has
+        ``t + 1`` live keys and attends to ``min(topk, t + 1)`` of them, in
+        every layer alike. Their sums add to ``sparse_keys_live`` /
+        ``sparse_keys_selected`` (``_total`` on ``/metrics``) and ride the
+        dispatch's record as a fourth entry ``(selected, live)``. A stack
+        of window and full layers (``windowed``): a window layer's query
+        sees ``min(window, t + 1)`` of the ``t + 1`` keys in its context;
+        the sums, of ONE window layer, add to ``window_keys_seen`` /
+        ``window_keys_in_context`` and ride the record as a fifth entry
+        ``(seen, in context)`` behind a fourth that is None."""
         shape = tuple(int(x) for x in shape)
         self.last_dispatch = (kind, shape, valid_tokens)
-        sparse_keys = None
-        if self.sparse_topk is not None and sparse_spans is not None:
-            sparse_keys = self._sparse_keys(sparse_spans)
+        sparse_keys = window_keys = None
+        if self.sparse_topk is not None and query_spans is not None:
+            sparse_keys = self._sparse_keys(query_spans)
             self.last_dispatch += (sparse_keys,)
+        if self.windowed and query_spans is not None:
+            window = next(w for w, _ in self.attention_layers if w)
+            window_keys = self._keys_under(window, query_spans)
+            self.last_dispatch += (None, window_keys)
         if self.dispatches is not None:
             self.dispatches.append(self.last_dispatch)
         key = (kind,) + shape
@@ -320,6 +343,9 @@ class AttentionPlan:
         if sparse_keys is not None:
             self.metrics.counter("sparse_keys_selected", sparse_keys[0])
             self.metrics.counter("sparse_keys_live", sparse_keys[1])
+        if window_keys is not None:
+            self.metrics.counter("window_keys_seen", window_keys[0])
+            self.metrics.counter("window_keys_in_context", window_keys[1])
         if self.enabled and kind != DECODE:
             self.metrics.counter("attn_ragged_dispatches")
         if valid_tokens is None:
@@ -335,11 +361,20 @@ class AttentionPlan:
             self.metrics.counter("moe_expert_rows_computed", padded * computed)
         if kind == DECODE:
             paged = self.ccfg.kind == "paged"
-            self.metrics.counter("decode_live_positions", valid_tokens)
-            self.metrics.counter(
-                "decode_grid_positions",
-                shape[0] * shape[2] * (self.ccfg.page_size if paged else 1),
-            )
+            grid = shape[0] * shape[2] * (self.ccfg.page_size if paged else 1)
+            live = valid_tokens
+            if window_keys is not None:
+                # by kind: a full layer's live positions are the contexts,
+                # a window layer's what its window leaves of them (a step's
+                # share of the dispatch's census)
+                in_window = window_keys[0] // max(shape[1], 1)
+                live = sum(
+                    n * (valid_tokens if w is None else in_window)
+                    for w, n in self.attention_layers
+                )
+                grid *= sum(n for _, n in self.attention_layers)
+            self.metrics.counter("decode_live_positions", live)
+            self.metrics.counter("decode_grid_positions", grid)
         else:
             self.metrics.counter("prefill_valid_tokens", valid_tokens)
             self.metrics.counter("prefill_padded_tokens", shape[0] * shape[1])
@@ -349,10 +384,15 @@ class AttentionPlan:
                 self.metrics.counter("ragged_attn_tiles_grid", grid)
 
     def _sparse_keys(self, spans) -> Tuple[int, int]:
-        """(selected, live) keys of queries at positions ``start .. start +
-        n - 1``, summed over ``spans``: closed sums of ``min(topk, t + 1)``
-        and ``t + 1``."""
-        k = self.sparse_topk
+        """(selected, live) keys of a selection's queries over ``spans``."""
+        return self._keys_under(self.sparse_topk, spans)
+
+    @staticmethod
+    def _keys_under(k: int, spans) -> Tuple[int, int]:
+        """(kept, live) keys of queries at positions ``start .. start + n -
+        1``, summed over ``spans``, where a query keeps at most ``k`` keys
+        (a selection's ``topk``, a layer's window): closed sums of ``min(k,
+        t + 1)`` and ``t + 1``."""
         selected = live = 0
         for start, n in spans:
             start, n = int(start), int(n)
@@ -365,22 +405,27 @@ class AttentionPlan:
         return selected, live
 
     def _ragged_tiles(self, shape, row_spans, table_width) -> Tuple[int, int]:
-        """(live, all) tiles of one layer's ragged-kernel grid for a
-        dispatch of ``shape`` (rows, pad width) whose real rows span
-        ``row_spans``; a row's live kv is ``q_start + num_new``, as the
-        cache passes it."""
+        """(live, all) tiles of the ragged kernel's grid for a dispatch of
+        ``shape`` (rows, pad width) whose real rows span ``row_spans``: one
+        layer's grid where the layers are all alike, each attention kind's
+        by its layers where they are not (``attention_layers``); a row's
+        live kv is ``q_start + num_new``, as the cache passes it."""
         block_q = self.ragged_block_q(shape[1])
         q_blocks = -(-shape[1] // block_q)
         spans = np.asarray(row_spans, np.int64).reshape(-1, 2)
         start, new = spans[:, 0, None, None], spans[:, 1, None, None]
-        live = _tile_live(
-            np.arange(q_blocks)[None, :, None],
-            np.arange(table_width)[None, None, :],
-            start, new, start + new, block_q=block_q,
-            page_size=self.ccfg.page_size,
-            sliding_window=self.sliding_window,
+        live = sum(
+            layers * int(np.count_nonzero(_tile_live(
+                np.arange(q_blocks)[None, :, None],
+                np.arange(table_width)[None, None, :],
+                start, new, start + new, block_q=block_q,
+                page_size=self.ccfg.page_size, sliding_window=window,
+            )))
+            for window, layers in self.attention_layers
         )
-        return int(np.count_nonzero(live)), shape[0] * q_blocks * table_width
+        return live, shape[0] * q_blocks * table_width * sum(
+            layers for _, layers in self.attention_layers
+        )
 
     def note_chunk_rows(self, n: int = 1) -> None:
         if self.metrics is not None:

@@ -13,7 +13,7 @@ import dataclasses
 import enum
 import itertools
 import time
-from typing import Any, List, Optional
+from typing import Any, Dict, List, Optional
 
 from .sampling import SamplingOptions
 
@@ -47,6 +47,10 @@ class Session:
     # burning decode slots). None = no deadline.
     deadline: Optional[float] = None
     pages: List[int] = dataclasses.field(default_factory=list)
+    # A stack of window and full layers: the row's pages of the WINDOW pool,
+    # table slot -> page (``pages`` are then the full layers' pool's). Only
+    # the slots the window still reaches are held (engine/engine.py).
+    window_pages: Dict[int, int] = dataclasses.field(default_factory=dict)
     generated: List[int] = dataclasses.field(default_factory=list)
     finish_reason: Optional[str] = None  # "eos" | "length" | "capacity" | "cancelled" | "deadline"
     # Memoized prompt-prefix chain keys (prefix caching; computed once even

@@ -25,6 +25,7 @@ Weight layout: all projections are stored ``[in_features, out_features]``
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, Mapping, Optional, Sequence, Tuple
 
@@ -32,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..config import ModelConfig
+from ..config import LayerSegment, ModelConfig
 from ..ops.attention import gqa_attention
 from ..ops.moe import moe_mlp
 from ..ops.norms import rms_norm
@@ -44,10 +45,27 @@ Params = Dict[str, Any]
 
 # A stack is ``ModelConfig.segments``: runs of layers that are all alike,
 # each one ``lax.scan`` over ``params[segment.key]``. A segment's kind names
-# its MLP (the attention is the model's, the same in every segment) and its
-# scope in a device trace. A further kind is a row here, its leaves in
-# :func:`_mlp_params`, and its branch in :func:`_mlp_residual`.
+# its MLP and its scope in a device trace; its attention (window or full)
+# comes with it as its window, its RoPE switch and its part of the cache.
+# A further kind is a row here, its leaves in :func:`_mlp_params`, and its
+# branch in :func:`_mlp_residual`.
 SEGMENT_SCOPES = {"dense": "dense_stack", "moe": "moe_stack"}
+
+
+def _segment_cache(cache, seg: LayerSegment):
+    """The cache a segment's layers read and write: the cache itself, or the
+    pool of the segment's attention kind where the cache holds two
+    (``cache/paged.py``: the two-pool classes)."""
+    return cache if seg.pool is None else cache.pool_view(seg.pool)
+
+
+def _segment_scope(seg: LayerSegment):
+    """A segment's scope in a device trace: its MLP kind's, and under it the
+    attention kind's where the stack has two."""
+    name = SEGMENT_SCOPES[seg.kind]
+    if seg.pool is not None:
+        name = f"{name}/{seg.attention}_layers"
+    return jax.named_scope(name)
 
 
 # ---------------------------------------------------------------------------
@@ -135,12 +153,14 @@ def _mlp_params(cfg: ModelConfig, kind: str, w, keys) -> Params:
         }
     if kind != "moe":
         raise ValueError(f"unknown segment kind {kind!r}")
-    e, f = cfg.num_experts, cfg.expert_intermediate_size
+    # the router's width, and the expert matrices held here (ops/moe.py)
+    e, held = cfg.num_experts, cfg.num_held_experts
+    f = cfg.expert_intermediate_size
     p = {
         "router": w(keys[7], h, e),
-        "we_g": w(keys[4], e, h, f),
-        "we_u": w(keys[5], e, h, f),
-        "we_d": w(keys[6], e, f, h),
+        "we_g": w(keys[4], held, h, f),
+        "we_u": w(keys[5], held, h, f),
+        "we_d": w(keys[6], held, f, h),
     }
     more = jax.random.split(keys[7], 4)
     if cfg.moe_select_bias:
@@ -198,16 +218,28 @@ def _decoder_layer(
     num_new: jnp.ndarray,
     attention_fn=gqa_attention,
     index_rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+    segment: Optional[LayerSegment] = None,
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
     """One decoder layer: pre-norm attention + pre-norm SwiGLU MLP.
 
     Mirrors the reference layer structure (``modules.py:146-184``) minus its
     double-residual deviation (SURVEY §2.9.3). ``index_rope``: the rotary
     tables of a learned selection's index queries and keys (their own
-    width; :func:`_index_rope`).
+    width; :func:`_index_rope`). ``segment``: the layer's segment, for its
+    sliding window (None: the model's one window, a stack of like layers).
+    A layer without RoPE is handed the identity rotation as ``rope``
+    (:func:`_rope_angles`).
     """
     b, s, _ = x.shape
     hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    window = cfg.sliding_window if segment is None else segment.window
+    # where the stack has two attention kinds, each has its scope inside
+    # ``attention``
+    kind_scope = (
+        jax.named_scope(f"{segment.attention}_attention")
+        if segment is not None and segment.pool is not None
+        else contextlib.nullcontext()
+    )
 
     # The scopes (``attention`` here, ``mlp`` / ``moe_*`` below, ``head``,
     # ``sampler``) are the stable part of every operation's name in a device
@@ -241,10 +273,11 @@ def _decoder_layer(
                 {"index": _index_inputs(cfg, p, h, index_rope)}
                 if cfg.use_sparse else {}
             )
-            attn, new_state = cache.attend(
-                layer_state, q, k, v, rope, q_pos, num_new,
-                cfg.sliding_window, attention_fn, d**-0.5, **more,
-            )
+            with kind_scope:
+                attn, new_state = cache.attend(
+                    layer_state, q, k, v, rope, q_pos, num_new,
+                    window, attention_fn, d**-0.5, **more,
+                )
             attn_flat = attn.reshape(b, s, hq * d)
         o = qmatmul(attn_flat, p["wo"])
         if "bo" in p:
@@ -392,6 +425,16 @@ def _rope_dim(cfg: ModelConfig) -> int:
     )
 
 
+def _rope_angles(inv_freq, positions, rotate: bool = True) -> RopeAngles:
+    """The rotary tables of ``positions``; for a segment whose layers do not
+    rotate (``LayerSegment.rope``), the identity rotation (cos 1, sin 0), so
+    that the caches, which rotate what they are handed, need no switch."""
+    cos, sin = rope_cos_sin(positions, inv_freq)
+    if not rotate:
+        cos, sin = jnp.ones_like(cos), jnp.zeros_like(sin)
+    return RopeAngles(inv_freq, cos, sin)
+
+
 def _split_int4_stacks(layer_params: Params):
     """Partition the layer dict: half-split int4 leaves are captured WHOLE
     (their Pallas matmul indexes the layer in its block index map);
@@ -429,6 +472,7 @@ def block_apply(
     num_new: jnp.ndarray,
     attention_fn=gqa_attention,
     first_layer: int = 0,
+    segment: Optional[LayerSegment] = None,
 ):
     """Run a block (contiguous or not) of decoder layers over hidden states.
 
@@ -439,7 +483,9 @@ def block_apply(
     count; ``lax.scan`` slices one layer's params+cache per step. A
     segment of a longer stack (``ModelConfig.segments``) passes its
     ``first_layer``: its ``n`` stacked layers then read and write the cache's
-    layers ``first_layer .. first_layer + n``.
+    layers ``first_layer .. first_layer + n``; and itself as ``segment``,
+    for its window and its RoPE switch (None: the model's one window, RoPE
+    on: a block of a stack whose layers are all alike).
 
     Returns ``(x, cache)`` with the cache's k/v updated (lengths NOT advanced —
     call ``cache.advance(num_new)`` after the last block of the model so that
@@ -448,8 +494,7 @@ def block_apply(
     inv_freq = rope_inv_freq(_rope_dim(cfg), cfg.rope_theta, cfg.rope_scaling)
     q_pos = cache.q_positions(x.shape[1])
     rot_pos = cache.rope_positions(x.shape[1], num_new)
-    cos, sin = rope_cos_sin(rot_pos, inv_freq)
-    rope = RopeAngles(inv_freq, cos, sin)
+    rope = _rope_angles(inv_freq, rot_pos, segment is None or segment.rope)
     index_rope = _index_rope(cfg, rot_pos)
 
     stacks = cache.layer_stacks  # tuple of [L, ...] arrays (k/v [+ scales])
@@ -473,7 +518,7 @@ def block_apply(
         )
         out, new_state = _decoder_layer(
             cfg, p, x, layer_state, cache, rope, q_pos, num_new, attention_fn,
-            index_rope,
+            index_rope, segment,
         )
         bufs = tuple(
             jax.lax.dynamic_update_index_in_dim(b, n, idx, 0)
@@ -516,11 +561,16 @@ def model_apply(
     x = jnp.take(params["embed"], tokens, axis=0)
     if block_fn is None:
         for seg in cfg.segments:
-            with jax.named_scope(SEGMENT_SCOPES[seg.kind]):
-                x, cache = block_apply(
-                    cfg, params[seg.key], x, cache, num_new, attention_fn,
-                    first_layer=seg.start,
+            with _segment_scope(seg):
+                x, part = block_apply(
+                    cfg, params[seg.key], x, _segment_cache(cache, seg),
+                    num_new, attention_fn, first_layer=seg.cache_start,
+                    segment=seg,
                 )
+            cache = (
+                part if seg.pool is None
+                else cache.with_pool_view(seg.pool, part)
+            )
     else:
         # A staged pipeline divides ONE stacked dict among its stages.
         (seg,) = cfg.segments
@@ -607,56 +657,46 @@ def multi_decode_apply(
     to per-step ``model_apply`` for other caches.
     """
     inv_freq = rope_inv_freq(_rope_dim(cfg), cfg.rope_theta, cfg.rope_scaling)
-    # ``tail_big_stacks`` lets a cache hand the scan a DIFFERENT read-only
-    # view of its big planes than its storage layout — the quantized paged
-    # cache gathers its page pool to contiguous per-row buffers ONCE here
-    # (per-layer pool slices feeding a kernel materialize a full pool copy
-    # per layer per step; the gather amortizes to ~2% of a step over K).
-    big_stacks = (
-        cache.tail_big_stacks()
-        if hasattr(cache, "tail_big_stacks")
-        else cache.layer_stacks
-    )
-    num_big = len(big_stacks)
-    num_stack = big_stacks[0].shape[0]
-    base_len = cache.lengths
-    # Whole-stack mode (Pallas big-segment kernels): the big buffers are NOT
-    # sliced per layer — a dynamic-slice feeding a custom call materializes a
-    # full HBM copy of that layer's K/V every (layer, step). Instead the
-    # stacks pass through whole with the layer index appended; the kernel's
-    # block index map resolves the layer, so the operand is zero-copy.
-    whole_big = getattr(cache, "tail_reads_whole_big", False)
-    # Whole-tail mode (in-kernel tail): like the big stacks, the tail
-    # buffers pass through UNSLICED — the kernel aliases them in place and
-    # indexes the layer itself, so the scan neither slices nor re-inserts
-    # per-layer tail state.
-    whole_tail = getattr(cache, "tail_in_kernel", False)
-    view_num_big = num_big + 1 if whole_big else num_big
     segments = cfg.segments
+    # The cache's pools: itself, or one a kind of attention where it holds
+    # two (``pool_view``). Each has its own read-only big planes, its own
+    # tail and its own flush; a segment's scan takes its pool's. With one
+    # pool the carry's leaves and the program are what they always were.
+    names = list(dict.fromkeys(seg.pool for seg in segments))
+    pools = [
+        _ScanPool(_segment_cache(
+            cache, next(seg for seg in segments if seg.pool == name)
+        ))
+        for name in names
+    ]
+    base_len = cache.lengths
     split_w = [_split_int4_stacks(params[seg.key]) for seg in segments]
 
     def token_step(carry, i):
-        tokens, tail, tail_len, num_new, state = carry
+        tokens, tails, tail_len, num_new, state = carry
+        tails = list(tails)
         x = jnp.take(params["embed"], tokens, axis=0)
-        view = _TailView(cache, base_len, tail_len, i, view_num_big)
-        q_pos = view.q_positions(1)
-        cos, sin = rope_cos_sin(q_pos, inv_freq)
-        rope = RopeAngles(inv_freq, cos, sin)
+        q_pos = (base_len + tail_len)[:, None]
+        # one table a RoPE switch: the rotation, and the identity where a
+        # segment's layers do not rotate
+        ropes = {}
+        for seg in segments:
+            if seg.rope not in ropes:
+                ropes[seg.rope] = _rope_angles(inv_freq, q_pos, seg.rope)
         index_rope = _index_rope(cfg, q_pos)
 
-        def layer_step(whole_w, first_layer, carry2, xs):
+        def layer_step(pool, view, rope, seg, whole_w, carry2, xs):
             x, tail_bufs = carry2
             p = xs[0]
             idx = xs[-1]
             # int4 stacks are a segment's own: indexed from its first layer
-            p = {**p, **_int4_views(
-                whole_w, idx - first_layer if first_layer else idx
-            )}
-            if whole_big:
-                big_state = (*big_stacks, idx)
+            first = seg.cache_start
+            p = {**p, **_int4_views(whole_w, idx - first if first else idx)}
+            if pool.whole_big:
+                big_state = (*pool.big_stacks, idx)
             else:
-                big_state = tuple(xs[1 : 1 + num_big])
-            if whole_tail:
+                big_state = tuple(xs[1 : 1 + pool.num_big])
+            if pool.whole_tail:
                 tail_state = tail_bufs
             else:
                 tail_state = tuple(
@@ -665,45 +705,96 @@ def multi_decode_apply(
                 )
             out, new_state = _decoder_layer(
                 cfg, p, x, (*big_state, *tail_state), view, rope, q_pos,
-                num_new, index_rope=index_rope,
+                num_new, index_rope=index_rope, segment=seg,
             )
-            if whole_tail:
-                tail_bufs = tuple(new_state[view_num_big:])
+            if pool.whole_tail:
+                tail_bufs = tuple(new_state[pool.view_num_big:])
             else:
                 tail_bufs = tuple(
                     jax.lax.dynamic_update_index_in_dim(b, n, idx, 0)
-                    for b, n in zip(tail_bufs, new_state[view_num_big:])
+                    for b, n in zip(tail_bufs, new_state[pool.view_num_big:])
                 )
             return (out, tail_bufs), None
 
         for seg, (whole_w, scanned_w) in zip(segments, split_w):
+            at = names.index(seg.pool)
+            pool = pools[at]
+            view = _TailView(
+                pool.cache, base_len, tail_len, i, pool.view_num_big
+            )
+            rope = ropes[seg.rope]
             # The read-only big planes ride a segment's scan as ITS layers'
             # slice (the whole of them for a one-segment stack).
-            lo, hi = seg.start, seg.start + seg.count
-            seg_big = () if whole_big else (
-                big_stacks if seg.count == num_stack
-                else tuple(b[lo:hi] for b in big_stacks)
+            lo, hi = seg.cache_start, seg.cache_start + seg.count
+            seg_big = () if pool.whole_big else (
+                pool.big_stacks if seg.count == pool.num_stack
+                else tuple(b[lo:hi] for b in pool.big_stacks)
             )
-            with jax.named_scope(SEGMENT_SCOPES[seg.kind]):
-                (x, tail), _ = jax.lax.scan(
-                    functools.partial(layer_step, whole_w, lo), (x, tail),
+            with _segment_scope(seg):
+                (x, tails[at]), _ = jax.lax.scan(
+                    functools.partial(
+                        layer_step, pool, view, rope, seg, whole_w
+                    ),
+                    (x, tails[at]),
                     (scanned_w, *seg_big, jnp.arange(lo, hi)),
                 )
         logits = apply_head(cfg, params, x)
         next_tokens, next_num_new, state, emit = step_fn(i, logits[:, 0], state)
         tail_len = tail_len + num_new
         return (
-            (next_tokens[:, None], tail, tail_len, next_num_new, state), emit
+            (next_tokens[:, None], tuple(tails), tail_len, next_num_new, state),
+            emit,
         )
 
     zero_len = jnp.zeros_like(base_len)
-    (_, tail, tail_len, _, _), emits = jax.lax.scan(
+    (_, tails, tail_len, _, _), emits = jax.lax.scan(
         token_step,
-        (tokens, cache.tail_init(num_steps), zero_len, init_num_new,
-         init_state),
+        (tokens, tuple(pool.cache.tail_init(num_steps) for pool in pools),
+         zero_len, init_num_new, init_state),
         jnp.arange(num_steps),
     )
-    return emits, cache.tail_flush(tail, tail_len)
+    if names == [None]:
+        return emits, cache.tail_flush(tails[0], tail_len)
+    for name, pool, tail in zip(names, pools, tails):
+        cache = cache.with_pool_view(
+            name, pool.cache.tail_flush(tail, tail_len), lengths=True
+        )
+    return emits, cache
+
+
+class _ScanPool:
+    """What :func:`multi_decode_apply` holds of one pool of the cache for
+    its scans: the read-only big planes, and how they and the tail are
+    handed to a layer."""
+
+    def __init__(self, cache):
+        self.cache = cache
+        # ``tail_big_stacks`` lets a cache hand the scan a DIFFERENT
+        # read-only view of its big planes than its storage layout — the
+        # quantized paged cache gathers its page pool to contiguous per-row
+        # buffers ONCE here (per-layer pool slices feeding a kernel
+        # materialize a full pool copy per layer per step; the gather
+        # amortizes to ~2% of a step over K).
+        self.big_stacks = (
+            cache.tail_big_stacks()
+            if hasattr(cache, "tail_big_stacks")
+            else cache.layer_stacks
+        )
+        self.num_big = len(self.big_stacks)
+        self.num_stack = self.big_stacks[0].shape[0]
+        # Whole-stack mode (Pallas big-segment kernels): the big buffers
+        # are NOT sliced per layer — a dynamic-slice feeding a custom call
+        # materializes a full HBM copy of that layer's K/V every (layer,
+        # step). Instead the stacks pass through whole with the layer
+        # index appended; the kernel's block index map resolves the layer,
+        # so the operand is zero-copy.
+        self.whole_big = getattr(cache, "tail_reads_whole_big", False)
+        # Whole-tail mode (in-kernel tail): like the big stacks, the tail
+        # buffers pass through UNSLICED — the kernel aliases them in place
+        # and indexes the layer itself, so the scan neither slices nor
+        # re-inserts per-layer tail state.
+        self.whole_tail = getattr(cache, "tail_in_kernel", False)
+        self.view_num_big = self.num_big + 1 if self.whole_big else self.num_big
 
 
 def apply_head(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
@@ -867,11 +958,11 @@ def convert_hf_state_dict(
     three times over (state, per-layer copies, stacks: 35 GiB and counting
     for a 14.5 GB checkpoint on a 40 GiB host — my chip run, PR 21).
     """
-    if cfg.family == "keye_vl2":
+    if cfg.qk_norm or cfg.use_sparse:
         raise ValueError(
-            "family 'keye_vl2' (KeyeVL2) has no checkpoint converter: the "
-            "key names of its checkpoint (the indexer's, the per-head "
-            "norms') are not known to this program, and a guessed "
+            f"family {cfg.family!r} has no checkpoint converter: the key "
+            "names of its checkpoint (the per-head q/k norms', an "
+            "indexer's) are not known to this program, and a guessed "
             "converter is worse than none"
         )
 

@@ -12,6 +12,11 @@ Families:
 
 * ``llama``   — the baseline (GQA, RoPE incl. llama3 scaling, SwiGLU).
 * ``mistral`` — + sliding-window attention (``ModelConfig.sliding_window``).
+* ``exaone_moe`` — K-EXAONE's block: window and full layers in one stack
+  (``ModelConfig.layer_attention``; RoPE in the window layers only), per-head
+  q/k norms, sigmoid-routed experts beside a shared one behind a leading
+  dense layer, of which this program may hold a share
+  (``ModelConfig.expert_shares``).
 * ``qwen2``   — + q/k/v projection biases (``qkv_bias``) and (2.5-era
   configs) tied embeddings.
 * ``mixtral`` — + MoE MLP (``num_experts``/``num_experts_per_tok``), expert
@@ -66,6 +71,8 @@ class ModelFamily:
     # A per-head RMSNorm of q and k, and the learned top-k key selection.
     qk_norm: bool = False
     sparse: bool = False
+    # Window and full layers in one stack (``ModelConfig.layer_attention``).
+    layer_attention: bool = False
     # The compute/conversion program (shared stack for all current families).
     apply: Callable = llama.model_apply
     block_apply: Callable = llama.block_apply
@@ -87,6 +94,10 @@ FAMILIES: Dict[str, ModelFamily] = {
         ModelFamily(
             "keye_vl2", ("keye_vl2", "KeyeVL2"), moe=True, qk_norm=True,
             sparse=True,
+        ),
+        ModelFamily(
+            "exaone_moe", ("exaone_moe",), sliding_window=True, moe=True,
+            qk_norm=True, layer_attention=True,
         ),
     )
 }
@@ -155,6 +166,31 @@ def validate_config(cfg: ModelConfig) -> ModelFamily:
         raise ValueError(
             f"family {fam.name!r} does not use a learned key selection "
             f"(ModelConfig.sparse; use the 'keye_vl2' family)"
+        )
+    if cfg.layer_attention is not None:
+        if not fam.layer_attention:
+            raise ValueError(
+                f"family {fam.name!r} does not mix window and full layers "
+                f"(ModelConfig.layer_attention)"
+            )
+        if len(cfg.layer_attention) != cfg.num_layers or (
+            set(cfg.layer_attention) - {"window", "full"}
+        ):
+            raise ValueError(
+                f"layer_attention names 'window' or 'full' for each of the "
+                f"{cfg.num_layers} layers (got {cfg.layer_attention})"
+            )
+        if "window" in cfg.layer_attention and not cfg.sliding_window:
+            raise ValueError("a window layer needs ModelConfig.sliding_window")
+    if cfg.expert_shares != 1 and (
+        cfg.expert_shares < 1
+        or cfg.num_experts % cfg.expert_shares
+        or not 0 <= cfg.expert_share_index < cfg.expert_shares
+    ):
+        raise ValueError(
+            f"expert_shares={cfg.expert_shares} must divide num_experts="
+            f"{cfg.num_experts}, with expert_share_index="
+            f"{cfg.expert_share_index} under it"
         )
     if fam.latent and (cfg.latent is None or not cfg.latent.enabled):
         raise ValueError(

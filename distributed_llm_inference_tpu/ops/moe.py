@@ -25,6 +25,17 @@ strategies sit behind it, all-static shapes:
 Shared experts (``p["ws_g"]``/``ws_u``/``ws_d``, present where the config
 has them) are one SwiGLU MLP every token passes through, added to the routed
 sum under the scope ``moe_shared``.
+
+Two counts, not one. ``ModelConfig.num_experts`` is the ROUTER's width: every
+token is scored over all of them and picks its ``k`` among all of them.
+``ModelConfig.num_held_experts`` is how many expert matrices this program
+holds (``we_*`` are ``[held, ...]``): all of them, or one of
+``expert_shares`` contiguous shares (experts ``first_held_expert ..
+first_held_expert + held``), as one chip of an expert-parallel deployment
+holds. A layer with a share computes ITS experts' part of the routed sum
+(the combine matrix keeps the held columns; a pick that lives elsewhere adds
+nothing here) plus the shared expert, and that partial result goes on.
+Nothing here stands in for the absent experts or for an exchange.
 """
 
 from __future__ import annotations
@@ -76,12 +87,18 @@ def router_weights(
 ) -> jnp.ndarray:
     """:func:`route` as the dense combine matrix.
 
-    ``x``: ``[B, S, H]``; ``router``: ``[H, E]``. Returns ``[B, S, E]``: a
-    token's routing weights at its selected experts, 0 elsewhere.
+    ``x``: ``[B, S, H]``; ``router``: ``[H, E]``, E the router's width.
+    Returns ``[B, S, held]``: a token's routing weights at those of its
+    selected experts that are held here, 0 elsewhere (``held`` = E unless
+    the layer holds a share; a pick outside the share matches no column).
     """
     with jax.named_scope("moe_router"):
         top_p, top_i = route(cfg, x, router, bias)
-        one_hot = jax.nn.one_hot(top_i, cfg.num_experts, dtype=jnp.float32)
+        if cfg.expert_shares > 1:
+            top_i = top_i - cfg.first_held_expert
+        one_hot = jax.nn.one_hot(
+            top_i, cfg.num_held_experts, dtype=jnp.float32
+        )
         return jnp.einsum("bsk,bske->bse", top_p, one_hot)
 
 
@@ -89,13 +106,19 @@ def expert_rows_per_token(cfg: ModelConfig, seq_len: int):
     """``(needed, computed)``: expert MLPs one token's result needs in one
     expert layer (its selected experts and the shared ones) and how many
     the program runs for it in a dispatch ``seq_len`` wide (every routed
-    expert under dense-combine; its capacity's share under sorted
-    dispatch). The census behind ``moe_expert_rows_*``."""
+    HELD expert under dense-combine; its capacity's share under sorted
+    dispatch). The census behind ``moe_expert_rows_*``. Where the layer
+    holds a share, ``needed`` is an EXPECTATION: of a token's ``k`` picks
+    over the router's ``E``, ``k * held / E`` fall here on average (uniform
+    routing); which do is data the host does not see."""
     shared = cfg.num_shared_experts
     k = cfg.num_experts_per_tok
+    held = cfg.num_held_experts
+    if cfg.expert_shares > 1:
+        k = k * held / cfg.num_experts
     if cfg.moe_capacity_factor is not None and seq_len >= 16:
         return k + shared, k * cfg.moe_capacity_factor + shared
-    return k + shared, cfg.num_experts + shared
+    return k + shared, held + shared
 
 
 # Dense-combine runs a dispatch's tokens whole up to this many a row (every
@@ -122,8 +145,10 @@ def moe_mlp(
 ) -> jnp.ndarray:
     """SwiGLU expert MLPs + weighted combine.
 
-    ``p["router"]``: ``[H, E]``; ``p["we_g"]``/``p["we_u"]``: ``[E, H, F]``;
-    ``p["we_d"]``: ``[E, F, H]`` (E shardable over ``ep``, F over ``tp``);
+    ``p["router"]``: ``[H, E]``, E the router's width; ``p["we_g"]`` /
+    ``p["we_u"]``: ``[held, H, F]``; ``p["we_d"]``: ``[held, F, H]`` (held =
+    E, or this program's share of E: the module docstring; the expert axis
+    shardable over ``ep``, F over ``tp``);
     ``p["router_bias"]`` ``[E]`` and the shared experts' ``p["ws_*"]`` where
     the model has them (:func:`route`, :func:`_shared_experts`).
 
@@ -193,7 +218,8 @@ def moe_mlp_dispatch(
     argsorted by expert, each expert's slots gather their tokens, the
     per-expert MLP runs on ``[E, C, H]``, and undoing the sort turns the
     combine into a dense ``[N, k]`` weighted sum. ``C = N·k/E ·
-    capacity_factor`` rounds to a static shape; pairs past an expert's
+    capacity_factor`` (E the router's width: the pairs an expert expects)
+    rounds to a static shape; pairs past an expert's
     capacity are dropped (their routing weight contributes nothing) — rare
     at factor 2 under Mixtral's near-uniform routing, and bounded: a dropped
     pair loses at most its renormalized probability share of one token.
@@ -208,7 +234,9 @@ def moe_mlp_dispatch(
     (``ModelConfig.moe_capacity_factor``) partly for this reason.
     """
     b, s, h = x.shape
-    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    # ``e``: the experts HELD here; the router scores all of its width and
+    # a pick that lives in another share goes to the sentinel below.
+    e, k = cfg.num_held_experts, cfg.num_experts_per_tok
     n = b * s
     xf = x.reshape(n, h)
 
@@ -216,6 +244,14 @@ def moe_mlp_dispatch(
         top_p, top_i = route(cfg, xf, p["router"], p.get("router_bias"))
 
     pair_e = top_i.reshape(-1)                                  # [N*k]
+    if cfg.expert_shares > 1:
+        here = (top_i >= cfg.first_held_expert) & (
+            top_i < cfg.first_held_expert + e
+        )
+        pair_e = jnp.where(
+            here.reshape(-1), pair_e - cfg.first_held_expert, e
+        )
+        top_p = top_p * here.astype(top_p.dtype)
     pair_t = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)      # [N*k]
     if valid is not None:
         vf = valid.reshape(-1)
@@ -233,7 +269,7 @@ def moe_mlp_dispatch(
     ]
 
     c = capacity if capacity is not None else max(
-        1, min(n, math.ceil((n * k) / e * capacity_factor))
+        1, min(n, math.ceil((n * k) / cfg.num_experts * capacity_factor))
     )
     # Slot (expert, c) holds the token at sorted position start_e + c.
     slot_pos = group_start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]

@@ -91,6 +91,22 @@ METRICS = {
     "sparse_keys_live": (
         "counter", "Keys the same queries could see (their positions + 1)"
     ),
+    # a stack of window and full layers (cache/paged.py, the two-pool
+    # classes): what a window layer's queries see of their contexts, and
+    # the window pool's pages as they leave rows and as they stand
+    "window_keys_seen": (
+        "counter", "Keys the dispatches' queries see in one window layer"
+    ),
+    "window_keys_in_context": (
+        "counter", "Keys the same queries have in context (positions + 1)"
+    ),
+    "window_pages_released": (
+        "counter", "Window-pool pages released as rows' windows passed them"
+    ),
+    "window_pool_free_pages": ("gauge", "Free pages of the window pool"),
+    "kv_pool_free_pages": (
+        "gauge", "Free pages of the full layers' pool beside a window pool"
+    ),
     "decode_tokens": ("counter", "Tokens emitted by decode"),
     # decode ticks of an engine whose decode_steps is 1 (a cache without a
     # write-behind tail, or the operator's choice): a token a dispatch

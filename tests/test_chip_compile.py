@@ -316,3 +316,107 @@ def test_sparse_prefill_chunk_at_keyes_shapes(chip, width):
         s((1, seq), I32), s((1,), I32),
     ).compile().as_text()
     assert "sparse_ragged_paged_attention" in text
+
+
+# k-exaone-236b-a23b.mixedlen: 64 query / 8 kv heads of 128, 9 window layers
+# (window 128) over a pool of their own of 512 pages beside 3 full layers over
+# 4608, int8, 32 rows, a 2048-wide chunk; the table pinned at 227 pages, its
+# cap 256. The kernels' bodies are Mistral's at twice its query heads; the
+# window pool's calls run under names of their own.
+EXA_HQ, EXA_ROWS, EXA_WINDOW = 64, 32, 128
+EXA_KINDS = ("window", "window", "window", "full") * 3
+EXA_PAGES = {"full": 4608, "window": 512}
+
+
+def _exaone_cache(s, rows, width):
+    from distributed_llm_inference_tpu.cache.paged import two_pool_cache_class
+
+    def planes(layers, pages):
+        kv = s((layers, pages, HKV, PS, D), I8)
+        sc = s((layers, pages, HKV, PS), F32)
+        return kv, kv, sc, sc
+
+    k, v, ks, vs = planes(3, EXA_PAGES["full"])
+    wk, wv, wks, wvs = planes(9, EXA_PAGES["window"])
+    table = s((rows, width), I32)
+    return two_pool_cache_class(True, EXA_KINDS, EXA_WINDOW)(
+        k_pages=k, v_pages=v, ks_pages=ks, vs_pages=vs,
+        wk_pages=wk, wv_pages=wv, wks_pages=wks, wvs_pages=wvs,
+        page_table=table, w_page_table=table, lengths=s((rows,), I32),
+        page_size=PS, use_kernel=True, use_ragged=True,
+    )
+
+
+@pytest.mark.parametrize("width", [227, 256])   # the cell's pinned table; the cap
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_two_pool_decode_step_at_exaones_shapes(chip, kind, width):
+    """One layer of one step of ``k-exaone-236b-a23b.mixedlen``'s decode
+    scan in each pool, as the int8 two-pool cache's view runs it: the fused
+    in-place sweep over the WHOLE pool of that kind (under the static window,
+    or none) and the window's flush, the window pool's under its own
+    names."""
+    from distributed_llm_inference_tpu.ops.rotary import RopeAngles
+
+    s, b, bf16 = chip, EXA_ROWS, jnp.bfloat16
+    window = EXA_WINDOW if kind == "window" else None
+
+    def step(cache, tail, q, k, v, cos, sin, lens, lidx, step):
+        view = cache.pool_view(kind)
+        out, tail = view.tail_attend(
+            (*view.tail_big_stacks(), lidx), tail, q, k, v,
+            RopeAngles(None, cos, sin), lens, lens * 0, step, lens * 0 + 1,
+            window, D ** -0.5,
+        )
+        return out, cache.with_pool_view(
+            kind, view.tail_flush(tail, lens * 0 + 1), lengths=True
+        )
+
+    cache = _exaone_cache(s, b, width)
+    tail = jax.eval_shape(lambda c: c.pool_view(kind).tail_init(KT), cache)
+    tail = jax.tree.map(lambda x: s(x.shape, x.dtype), tail)
+    text = jax.jit(step).lower(
+        cache, tail, s((b, 1, EXA_HQ, D), bf16), s((b, 1, HKV, D), bf16),
+        s((b, 1, HKV, D), bf16), s((b, 1, D), F32), s((b, 1, D), F32),
+        s((b,), I32), s((), I32), s((), I32),
+    ).compile().as_text()
+    names = (
+        ("window_paged_fused_attention", "window_tail_flush") if window
+        else ("quantized_paged_fused_attention", "paged_tail_flush")
+    )
+    for name in names:
+        assert name in text, name
+    assert ("window_paged_fused_attention" in text) == bool(window)
+
+
+@pytest.mark.parametrize("rows", [1, 4])        # a chunk or one admission; a group
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_two_pool_prefill_chunk_at_exaones_shapes(chip, kind, rows):
+    """One layer of a 2048-wide prefill dispatch in each pool: the chunk's K
+    and V into that pool by its own table, and the ragged kernel under the
+    static window (or none), the window pool's under its own name."""
+    from distributed_llm_inference_tpu.ops.attention import gqa_attention
+    from distributed_llm_inference_tpu.ops.rotary import RopeAngles
+
+    s, seq, bf16 = chip, 2048, jnp.bfloat16
+    window = EXA_WINDOW if kind == "window" else None
+
+    def chunk(cache, q, k, v, cos, sin, q_pos, num_new):
+        view = cache.select_rows(jnp.arange(rows)).pool_view(kind)
+        state = tuple(x[1] for x in view.layer_stacks)
+        return view.attend(
+            state, q, k, v, RopeAngles(None, cos, sin), q_pos, num_new,
+            window, gqa_attention, D ** -0.5,
+        )
+
+    text = jax.jit(chunk).lower(
+        _exaone_cache(s, EXA_ROWS, 227), s((rows, seq, EXA_HQ, D), bf16),
+        s((rows, seq, HKV, D), bf16), s((rows, seq, HKV, D), bf16),
+        s((rows, seq, D), F32), s((rows, seq, D), F32),
+        s((rows, seq), I32), s((rows,), I32),
+    ).compile().as_text()
+    name = (
+        "window_ragged_paged_attention" if window
+        else "quantized_ragged_paged_attention"
+    )
+    assert name in text
+    assert ("window_ragged_paged_attention" in text) == bool(window)

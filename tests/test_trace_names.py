@@ -191,3 +191,55 @@ def test_a_lowered_sparse_forward_carries_the_selections_scopes_and_kernels():
         num_heads=2, num_kv_heads=2, head_dim=16,
     ))
     assert "index_scores" not in dense and "sparse_attention" not in dense
+
+
+def test_a_lowered_stack_of_two_attention_kinds_carries_a_scope_and_kernels_a_kind():
+    """Window and full layers in one stack, through the int8 two-pool cache
+    with its kernels (interpreted here): a segment's scope names its MLP
+    kind and, under it, its attention kind; inside ``attention`` the kind has
+    a scope of its own; and the window pool's calls of the kernels' bodies
+    run under the window pool's names, beside the full pool's under the
+    bodies' own."""
+    from distributed_llm_inference_tpu.cache.paged import two_pool_cache_class
+
+    kinds = ("window", "window", "full", "window")
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=4,
+        num_heads=2, num_kv_heads=2, head_dim=16, qk_norm=True,
+        sliding_window=8, layer_attention=kinds, full_attention_rope=False,
+        num_experts=4, num_experts_per_tok=2, moe_intermediate_size=16,
+        num_shared_experts=1, first_dense_layers=1, moe_scoring="sigmoid",
+        moe_select_bias=True, expert_shares=2, family="exaone_moe",
+    )
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    assert params["layers_1_moe"]["we_g"].shape[:2] == (1, 2)     # held
+    assert params["layers_1_moe"]["router"].shape[-1] == 4        # scored
+    cache = two_pool_cache_class(True, kinds, 8).create(
+        1, 1, 5, 8, 96, 2, 16, jnp.float32, use_kernel=True, use_ragged=True
+    )
+    one = jnp.ones((1,), jnp.int32)
+    prefill = jax.jit(
+        lambda p, t, c: llama.model_apply(cfg, p, t, c, 8 * one, head="last")
+    ).lower(params, jnp.zeros((1, 8), jnp.int32), cache).as_text(debug_info=True)
+    decode = jax.jit(lambda p, t, c: llama.multi_decode_apply(
+        cfg, p, t, c, 4, lambda i, logits, st: (t[:, 0], one, st, logits),
+        jnp.zeros(()), one,
+    )).lower(params, jnp.zeros((1, 1), jnp.int32), cache).as_text(debug_info=True)
+    for text, kernels in (
+        (prefill, ["window_ragged_paged_attention",
+                   "quantized_ragged_paged_attention"]),
+        (decode, ["window_paged_fused_attention", "window_tail_flush",
+                  "quantized_paged_fused_attention", "paged_tail_flush"]),
+    ):
+        for scope in ("dense_stack/window_layers", "moe_stack/window_layers",
+                      "moe_stack/full_layers", "attention/window_attention",
+                      "attention/full_attention", "mlp/moe_shared"):
+            assert scoped(text, scope), scope
+        for kernel in kernels:
+            assert kernel in text, kernel
+    alike = lowered_text(ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16, sliding_window=8,
+        family="mistral",
+    ))
+    assert "window_layers" not in alike and "window_attention" not in alike
