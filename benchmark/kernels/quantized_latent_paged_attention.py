@@ -7,8 +7,8 @@ of the batch; ``positions`` is the rows' live context lengths, summed.
 latent a position, ``kv_lora_rank + qk_rope_head_dim`` int8 values and one
 float32 scale, and it is both K and V (the absorbed form: the query carries
 the key up-projection, the value up-projection comes after). Counted ONCE:
-the least a kernel could read. Today's wrapper hands the pool to the kernel
-as K and again as V, so the kernel fetches twice this; the share says so.
+the least a kernel could read, and since PR 31 what the fused one-plane
+form does read (the grid form before it took the pool as K and again as V).
 Operations: QK^T and PV of every query head against each live position,
 both over the whole stored width, as the kernel computes them (PV's last
 ``qk_rope_head_dim`` columns are computed and dropped by the model: 11% of

@@ -5,13 +5,17 @@ over the bf16 peak) over the time the trace shows for them. The pattern of
 ``paged_decode_attn_roofline_pct``: time and count from ``kernels_device0``
 (one event is one layer of one decode step), the live positions of a call
 from the decode dispatches of the tick records of the same span (K steps a
-dispatch are K calls a layer; a latent engine has K = 1), and the two counts
+dispatch are K calls a layer: since PR 31 an int8 latent engine runs the
+fused 16-step scan, K = 16, as every one-chip cell does), and the two counts
 checked against each other: a dispatch at either end of the span and a tenth
 of the events may differ, beyond that nothing is returned. The bytes are the
 live positions' latents ONCE (``benchmark/kernels/
-quantized_latent_paged_attention.py``); the wrapper passes the pool as K and
-as V over a ``(slots, table width)`` grid, and the share shows both. A
-program without this kernel's name (the parent of PR 26) gives nothing.
+quantized_latent_paged_attention.py``), which is how the fused one-plane
+form now reads them: a row's live pages, fetched once as pipelined blocks of
+8 pages (the name is the fused form's since PR 31; the ``(slots, table
+width)`` grid form that took the pool as K and again as V is traced as
+``quantized_latent_paged_grid_attention`` and no cell runs it). A program
+without this kernel's name (the parent of PR 26) gives nothing.
 """
 
 from benchmark import peaks

@@ -20,7 +20,9 @@ kept here as ``<opcode>:<result name>`` (``fusion:fusion.3``,
 from __future__ import annotations
 
 import bisect
+import glob
 import json
+import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -60,6 +62,38 @@ def read_xplane(path: str) -> List[dict]:
         } for line in plane.lines if line.name in (OPS_LINE, MODULES_LINE)]
         planes.append({"name": plane.name, "lines": lines})
     return planes
+
+
+def trace_files(trace_dir: str) -> List[str]:
+    """The ``.xplane.pb`` files the profiler wrote under ``trace_dir``."""
+    return glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"
+    ))
+
+
+def trace_missing(trace_dir: str, open_profile=None) -> Optional[str]:
+    """Which part of a trace just stopped is missing, or ``None`` where the
+    file is there and a device plane has at least one operation: what
+    ``reduce_trace`` needs to return anything. Cheap, because it runs inside
+    the window: the file is opened, no line is walked past its first event.
+    ``open_profile`` stands in for ``ProfileData.from_file`` in the tests."""
+    files = trace_files(trace_dir)
+    if not files:
+        return "no *.xplane.pb file was written"
+    if open_profile is None:
+        import jax
+
+        open_profile = jax.profiler.ProfileData.from_file
+    devices = [
+        p for p in open_profile(files[0]).planes if DEVICE_PLANE.match(p.name)
+    ]
+    if not devices:
+        return "the file holds no device plane"
+    for plane in devices:
+        for line in plane.lines:
+            if line.name == OPS_LINE and next(iter(line.events), None) is not None:
+                return None
+    return "no device plane has an operation on its XLA Ops line"
 
 
 def read_sample(path: str) -> List[dict]:

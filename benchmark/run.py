@@ -16,13 +16,17 @@ flight recorder, and prints the cell's per-layer metrics.
 
 The LAST line of standard output is one JSON object with the keys
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and
-``breakdown`` with ``--trace 1``). Everything else — the schedule's summary,
-lateness, the per-request table, the reduced trace — goes to earlier lines
-and to ``<out>/``.
+``breakdown`` with ``--trace 1``) and, last, ``compared``: every number that
+``correct`` compared beside its limit, which are also the last lines of
+standard error. A ``--trace 1`` run whose trace holds no device operation is
+traced once more inside the same window, and if that is empty too the run
+prints no result line and exits non-zero. Everything else — the schedule's
+summary, lateness, the per-request table, the reduced trace — goes to
+earlier lines and to ``<out>/``.
 
 Everything that belongs to one configuration, traffic mix or metric is a
 file found by the name ``BENCHMARK.json`` gives: ``configs/<config>.json``
-(with ``weights/`` and ``reference/`` modules it names),
+(with the ``weights/``, ``reference/`` and ``kernels/`` modules it needs),
 ``traffic/<mix>.json`` (with the ``generators/`` module it names),
 ``end_to_end/<metric>.py`` and ``layer_metrics/<metric>.py``.
 
@@ -54,6 +58,9 @@ from benchmark import prom, warmup  # noqa: E402
 from benchmark.loadgen import Client  # noqa: E402
 
 TRACE_SECONDS = 6.0
+#: the least span worth a second trace, and what a second trace leaves of the
+#: window behind it
+TRACE_MARGIN_S = 0.5
 
 
 class Child:
@@ -132,6 +139,7 @@ class Run:
         self.metrics_open = self.metrics_close = self.metrics_end = None
         self.closed = None          # the child's reply to "close"
         self.ticks = {}             # tick id -> record, polled in traced runs
+        self.trace_missing = None   # what the child's last trace lacked
         self.cancelled = 0
         self.warm = None
         self._index = 0
@@ -174,9 +182,20 @@ class Run:
         if self.shapes["decode_steps"] == 1:
             span /= 2
         await asyncio.sleep(max(0.0, self.t0 + (self.seconds - span) / 2 - time.monotonic()))
-        await self._call("trace_start")
-        await asyncio.sleep(span)
-        await self._call("trace_stop")
+        for attempt in (1, 2):
+            await self._call("trace_start")
+            await asyncio.sleep(span)
+            self.trace_missing = (await self._call("trace_stop")).get("missing")
+            # a CPU trace has no device plane and needs none
+            if not self.trace_missing or self.rehearse or attempt == 2:
+                return
+            # an empty trace (PR 36's refusal: a line without busy_s): once
+            # more in what is left of the window, which the first span ends
+            # 19 s before the end of; a second empty one ends the run
+            span = min(span, self.t0 + self.seconds - time.monotonic() - TRACE_MARGIN_S)
+            if span < TRACE_MARGIN_S:
+                self.trace_missing += "; the window had no room for a second trace"
+                return
 
     async def _poll_ticks(self) -> None:
         every = 0.25 if self.shapes["decode_steps"] == 1 else 1.0
@@ -193,6 +212,11 @@ class Run:
             for t in json.loads(await self.client.get("/debug/ticks"))["ticks"]:
                 self.ticks[t["tick"]] = t
             await asyncio.wait(self._background[1:], timeout=120.0)
+            tracer = self._background[1]
+            if not tracer.done():
+                self.trace_missing = "the trace had not ended 120 s after the window"
+            elif tracer.exception() is not None:
+                self.trace_missing = f"the trace's calls failed: {tracer.exception()!r}"
         self._closing = asyncio.get_running_loop().create_task(self._call("close"))
 
     async def finish(self) -> None:
@@ -242,9 +266,11 @@ def served_path_check(run: Run) -> dict:
             and theirs["gateway_tokens"] >= mine["gateway_tokens"]
             and theirs["decode_tokens"] >= mine["decode_tokens"]
         )
-    replies_ok = all(r.ok(vocab) for r in ended)
+    bad_replies = sum(1 for r in ended if not r.ok(vocab))
+    replies_ok = bad_replies == 0
     return {
         "ok": bool(counters_ok and replies_ok), "replies_ok": replies_ok,
+        "bad_replies": bad_replies,
         "counters_ok": bool(counters_ok), "client": mine, "gateway": theirs,
         "hung_up_on": run.cancelled,
     }
@@ -266,6 +292,33 @@ def attempted_failed(run: Run):
             and lo <= r.ended < hi and r.error != "cancelled by the client"
         ]
     return len(mine), sum(1 for r in mine if not r.ok(vocab))
+
+
+def reduced_trace(run: Run):
+    """The child's reduction of the trace. A ``--trace 1`` run on the chip
+    that has none raises, and ``main`` then prints no result line: never a
+    traced run's line without ``busy_s`` and ``window_s``."""
+    trace = run.closed.get("trace")
+    if run.trace and not run.rehearse and not trace:
+        raise RuntimeError(
+            "the traced run has no trace to reduce: "
+            + (run.trace_missing or "the trace was never taken")
+        )
+    return trace
+
+
+def compared_numbers(run: Run, served: dict) -> dict:
+    """Every number ``correct`` compared, as ``[number, limit]`` under a
+    short name: the logit distances the configuration judges (``server.
+    judged_numbers``) beside its tolerance, the replies that were not what
+    was asked and the counter check, beside 0."""
+    out = {
+        f"logit_distance_{i}": [value, run.numerics["tolerance"]]
+        for i, value in enumerate(run.numerics["judged"])
+    }
+    out["bad_replies"] = [served["bad_replies"], 0]
+    out["counters_off"] = [int(not served["counters_ok"]), 0]
+    return out
 
 
 def read_metrics(run: Run, entries, package: str) -> dict:
@@ -303,17 +356,20 @@ def report(run: Run, bench: dict, out_dir: str, tag: str = "") -> dict:
     device = dict(run.device)
     if not run.rehearse:
         device["memory_peak_bytes"] = run.closed["memory"]["peak_bytes"]
-    trace = run.closed.get("trace")
+    trace = reduced_trace(run)
     line = {
         "correct": bool(served["ok"] and run.numerics["ok"]),
         "attempted": attempted, "failed": failed, "metrics": metrics,
         "device": device,
     }
-    if run.trace and trace and not run.rehearse:
+    if trace and not run.rehearse:
         device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
         line["breakdown"] = {
             "device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"],
         }
+    # last in the line, and in ``main`` last on standard error: what a run
+    # that is not correct leaves in the driver's record
+    line["compared"] = compared_numbers(run, served)
     lo = run.t0
     table = [{
         "i": r.index, "phase": r.phase, "client": r.client,
@@ -435,6 +491,8 @@ def main(argv=None) -> int:
         print(f"benchmark: the server exited with {rc}", file=sys.stderr)
         return 1
     assert "jax" not in sys.modules, "the load generator must stay off JAX"
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name} {value} limit {limit}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -28,7 +28,6 @@ chips the cell asks for.
 from __future__ import annotations
 
 import argparse
-import glob
 import importlib
 import json
 import os
@@ -429,6 +428,8 @@ def main(argv=None) -> int:
             emit({"reply": "open"})
         elif cmd == "trace_start":
             trace_dir = os.path.join(args.out, "trace")
+            # a second trace of one window replaces an empty first one
+            shutil.rmtree(trace_dir, ignore_errors=True)
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             options.host_tracer_level = 1
@@ -438,7 +439,15 @@ def main(argv=None) -> int:
         elif cmd == "trace_stop":
             traced.append(time.time())
             jax.profiler.stop_trace()
-            emit({"reply": "trace_stop"})
+            from benchmark.reduce import xplane
+
+            # which part of the trace is missing, if any: the parent takes
+            # the trace once more while the window still runs, and prints
+            # no result line for a traced run that has no trace
+            emit({
+                "reply": "trace_stop",
+                "missing": xplane.trace_missing(trace_dir),
+            })
         elif cmd == "close":
             closed = time.monotonic()
             sampler.stop()
@@ -455,9 +464,7 @@ def main(argv=None) -> int:
             if trace_dir is not None:
                 from benchmark.reduce import xplane
 
-                files = glob.glob(os.path.join(
-                    trace_dir, "plugins", "profile", "*", "*.xplane.pb"
-                ))
+                files = xplane.trace_files(trace_dir)
                 planes = xplane.read_xplane(files[0]) if files else []
                 reply["trace"] = xplane.reduce_trace(planes)
                 # on the flight recorder's clock, for a reader that takes

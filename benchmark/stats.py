@@ -14,18 +14,36 @@ from typing import Iterable, Optional, Sequence
 MISSED = math.inf
 
 
+def rank_of(n: int, q: float) -> int:
+    """The nearest rank of the ``q``-th percentile among ``n`` readings,
+    counted from 1."""
+    return max(1, math.ceil(q / 100.0 * n))
+
+
 def percentile(values: Sequence[float], q: float) -> Optional[float]:
     """Nearest-rank percentile: the smallest value with at least ``q`` percent
     of the sample at or below it. ``None`` for an empty sample."""
     if not values:
         return None
     ordered = sorted(values)
-    rank = max(1, math.ceil(q / 100.0 * len(ordered)))
-    return ordered[rank - 1]
+    return ordered[rank_of(len(ordered), q) - 1]
 
 
 def median(values: Sequence[float]) -> Optional[float]:
     return percentile(values, 50.0)
+
+
+def beyond_rank(n: int, q: float) -> int:
+    """How many of ``n`` readings lie beyond the ``q``-th percentile's rank.
+    A percentile is judged only where this is at least ``MIN_BEYOND``: with
+    four beyond it (the 41st of 45) ONE request that changes sides moves the
+    reading to its neighbour, 55 ms off (PERF.md section 6, PR 37)."""
+    return n - rank_of(n, q)
+
+
+#: the metrics guide's rule: "the highest percentile that has at least ten
+#: samples beyond it"
+MIN_BEYOND = 10
 
 
 def tokens_in_window(arrivals: Iterable[float], start: float, end: float) -> int:

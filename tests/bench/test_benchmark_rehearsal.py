@@ -13,7 +13,7 @@ import sys
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def bench():
@@ -80,6 +80,14 @@ def test_rehearsal_end_to_end(tmp_path, workload, loop):
     # a CPU run reports counts only: no time, rate or utilisation under a
     # device metric's name
     assert line["metrics"] == {}
+    # every number compared beside its limit: last in the line, and the last
+    # lines of standard error
+    assert list(line)[-1] == "compared"
+    assert all(value <= limit for value, limit in line["compared"].values())
+    assert "logit_distance_0" in line["compared"] and "bad_replies" in line["compared"]
+    said = proc.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert [l.split()[1] for l in said] == list(line["compared"])
+    assert all(l.startswith("compared ") and " limit " in l for l in said)
     more = detail(proc)
     assert more["loop"] == loop and more["numerics"]["ok"]
     served = more["served_path"]
