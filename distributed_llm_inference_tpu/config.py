@@ -157,11 +157,11 @@ class ModelConfig:
     # :attr:`num_held_experts` (all, unless ``expert_shares`` > 1).
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # Opt-in sorted expert dispatch for MoE prefill (ops/moe.py): tokens
-    # past an expert's capacity (N·k/E · this factor) are dropped, trading
-    # exactness for E/(k·factor)× less prefill compute. None (default)
-    # keeps the exact dense-combine path everywhere — drops would also make
-    # chunked prefill depend on chunk boundaries.
+    # Opt-in capacity-bounded expert dispatch for MoE prefill (ops/moe.py):
+    # tokens past an expert's capacity (N·k/E · this factor) are dropped,
+    # which also makes chunked prefill depend on chunk boundaries. None
+    # (default) keeps the exact paths: the dropless grouped dispatch where
+    # a dispatch fills the experts' row tiles, dense-combine elsewhere.
     moe_capacity_factor: Optional[float] = None
     # Width of one routed expert (``moe_intermediate_size``); None =
     # ``intermediate_size`` (Mixtral: every MLP of the model is an expert).

@@ -327,12 +327,22 @@ class InferenceEngine:
             for kind in dict.fromkeys(kinds)
         )
         if cfg.num_experts > 0:
-            from ..ops.moe import expert_rows_per_token
+            from ..ops.moe import dispatch_path, expert_rows_per_token
 
-            self.plan.expert_rows = lambda seq_len: tuple(
-                n * cfg.num_expert_layers
-                for n in expert_rows_per_token(cfg, seq_len)
-            )
+            def expert_rows(rows, seq_len, valid_share):
+                # What the traced step sees (``ops/moe.py:under_mesh``):
+                # every step of a sharded engine runs in ``with self.mesh``.
+                sharded = self.mesh is not None and self.mesh.size > 1
+                needed, computed = expert_rows_per_token(
+                    cfg, seq_len, rows, valid_share, sharded
+                )
+                return (
+                    needed * cfg.num_expert_layers,
+                    computed * cfg.num_expert_layers,
+                    dispatch_path(cfg, rows, seq_len, sharded),
+                )
+
+            self.plan.expert_rows = expert_rows
         if cc.kind == "dense":
             cache_cls = (
                 QuantizedDenseKVCache if cc.kv_quant == "int8" else DenseKVCache

@@ -39,7 +39,9 @@ The plan centralizes that policy:
   cumulative ``prefill_valid_tokens`` / ``prefill_padded_tokens`` over
   every prefill-family dispatch and ``decode_live_positions`` /
   ``decode_grid_positions`` over every decode dispatch, for a routed
-  model ``moe_expert_rows_needed`` / ``moe_expert_rows_computed``, and
+  model ``moe_expert_rows_needed`` / ``moe_expert_rows_computed`` and the
+  dispatches by the experts' compute path (``moe_dispatch_grouped`` /
+  ``moe_dispatch_dense``), and
   under the ragged plan ``ragged_attn_tiles_live`` /
   ``ragged_attn_tiles_grid``: the tiles of the ragged kernel's grid that
   hold a live (query, key) pair, which it computes, over all it steps
@@ -133,9 +135,11 @@ class AttentionPlan:
         # place via the page walk, which note_dispatch surfaces as the
         # ``latent_decompress_dispatches`` counter.
         self.latent = False
-        # Set by the engine for a model with routed experts:
-        # ``seq_len -> (needed, computed)`` expert MLP rows a token, over
-        # all its expert layers; note_dispatch keeps their census.
+        # Set by the engine for a model with routed experts: ``(rows,
+        # seq_len, valid_share) -> (needed, computed, path)``, the expert
+        # MLP rows a token over all its expert layers and the experts'
+        # compute path in a dispatch of that shape (``ops/moe.py``);
+        # note_dispatch keeps their census.
         self.expert_rows = None
         # Set by the engine over a paged cache: ``pad width -> block_q``,
         # the q block the ragged kernel picks for this model at that width
@@ -296,7 +300,10 @@ class AttentionPlan:
         and those the program runs (padded tokens x the experts it computes
         a token): ``moe_expert_rows_needed`` / ``moe_expert_rows_computed``.
         A decode dispatch's tokens are its ``active_rows`` (of ``shape[0]``
-        rows) times its steps.
+        rows) times its steps, each step a dispatch of ``shape[0]`` x 1
+        tokens to the experts. The path ``ops/moe.py:dispatch_path`` takes
+        at the dispatch's shape counts it under ``moe_dispatch_grouped``,
+        ``moe_dispatch_dense`` or ``moe_dispatch_capacity``.
 
         A prefill-family dispatch under the ragged plan over a paged cache
         also gives ``row_spans``, a ``(q_start, num_new)`` pair a real row
@@ -352,13 +359,17 @@ class AttentionPlan:
             return
         if self.expert_rows is not None:
             if kind == DECODE:
-                needed, computed = self.expert_rows(1)
                 valid, padded = (active_rows or 0) * shape[1], shape[0] * shape[1]
+                seq_len = 1
             else:
-                needed, computed = self.expert_rows(shape[1])
                 valid, padded = valid_tokens, shape[0] * shape[1]
+                seq_len = shape[1]
+            needed, computed, path = self.expert_rows(
+                shape[0], seq_len, valid / max(padded, 1)
+            )
             self.metrics.counter("moe_expert_rows_needed", valid * needed)
             self.metrics.counter("moe_expert_rows_computed", padded * computed)
+            self.metrics.counter(f"moe_dispatch_{path}")
         if kind == DECODE:
             paged = self.ccfg.kind == "paged"
             grid = shape[0] * shape[2] * (self.ccfg.page_size if paged else 1)

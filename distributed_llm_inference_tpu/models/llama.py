@@ -435,33 +435,49 @@ def _rope_angles(inv_freq, positions, rotate: bool = True) -> RopeAngles:
     return RopeAngles(inv_freq, cos, sin)
 
 
-def _split_int4_stacks(layer_params: Params):
+def _split_whole_stacks(layer_params: Params, also=()):
     """Partition the layer dict: half-split int4 leaves are captured WHOLE
-    (their Pallas matmul indexes the layer in its block index map);
-    everything else rides the scan's xs and gets sliced for free. Slicing
-    an int4 stack per scan step would copy the layer's packed weight
-    through HBM before every kernel call — the copy traffic is why int4
-    decode measured slower than int8 before this split."""
+    (their Pallas matmul indexes the layer in its block index map), and so
+    are the leaves named in ``also`` (the expert stacks of a dispatch that
+    takes the grouped kernel: :func:`_grouped_stacks`); everything else
+    rides the scan's xs and gets sliced for free. Slicing such a stack per
+    scan step would copy the layer's weights through HBM before every
+    kernel call — the copy traffic is why int4 decode measured slower than
+    int8 before this split, and a third to a half of a grouped expert
+    layer's time."""
     from ..ops.quant import QuantizedTensor4Split
 
     whole = {
         k: v
         for k, v in layer_params.items()
-        if isinstance(v, QuantizedTensor4Split)
+        if isinstance(v, QuantizedTensor4Split) or k in also
     }
     scanned = {k: v for k, v in layer_params.items() if k not in whole}
     return whole, scanned
 
 
-def _int4_views(whole: Params, idx) -> Params:
-    from ..ops.quant import QuantizedTensor4SplitView
+def _layer_views(whole: Params, idx) -> Params:
+    """A layer's views of the stacks captured whole."""
+    from ..ops.moe import LayerOf
+    from ..ops.quant import QuantizedTensor4Split, QuantizedTensor4SplitView
 
     return {
         k: QuantizedTensor4SplitView(
             v.q, v.scale_lo, v.scale_hi, idx, v.in_dim, v.out_dim
-        )
+        ) if isinstance(v, QuantizedTensor4Split) else LayerOf(v, idx)
         for k, v in whole.items()
     }
+
+
+def _grouped_stacks(cfg: ModelConfig, layer_params: Params, x) -> tuple:
+    """The expert stacks of a routed segment whose dispatch of ``x``'s shape
+    takes the grouped kernel (``ops/moe.py:traced_path``, what ``moe_mlp``
+    asks inside the layer), else none."""
+    from ..ops import moe
+
+    if "router" in layer_params and moe.traced_path(cfg, x) == "grouped":
+        return moe.GROUPED_STACKS
+    return ()
 
 
 def block_apply(
@@ -505,13 +521,15 @@ def block_apply(
     # per layer. Returning per-layer state as stacked scan outputs instead
     # would materialize a full copy of the whole cache every step, doubling
     # HBM traffic on the bandwidth-bound decode path.
-    whole_w, scanned_w = _split_int4_stacks(layer_params)
+    whole_w, scanned_w = _split_whole_stacks(
+        layer_params, _grouped_stacks(cfg, layer_params, x)
+    )
 
     def step(carry, xs):
         x, bufs = carry
         p, idx = xs
-        # int4 stacks are this block's own: indexed from its first layer
-        p = {**p, **_int4_views(whole_w, idx - first_layer if first_layer else idx)}
+        # whole stacks are this block's own: indexed from its first layer
+        p = {**p, **_layer_views(whole_w, idx - first_layer if first_layer else idx)}
         layer_state = tuple(
             jax.lax.dynamic_index_in_dim(b, idx, 0, keepdims=False)
             for b in bufs
@@ -670,7 +688,7 @@ def multi_decode_apply(
         for name in names
     ]
     base_len = cache.lengths
-    split_w = [_split_int4_stacks(params[seg.key]) for seg in segments]
+    split_w = [_split_whole_stacks(params[seg.key]) for seg in segments]
 
     def token_step(carry, i):
         tokens, tails, tail_len, num_new, state = carry
@@ -689,9 +707,9 @@ def multi_decode_apply(
             x, tail_bufs = carry2
             p = xs[0]
             idx = xs[-1]
-            # int4 stacks are a segment's own: indexed from its first layer
+            # whole stacks are a segment's own: indexed from its first layer
             first = seg.cache_start
-            p = {**p, **_int4_views(whole_w, idx - first if first else idx)}
+            p = {**p, **_layer_views(whole_w, idx - first if first else idx)}
             if pool.whole_big:
                 big_state = (*pool.big_stacks, idx)
             else:

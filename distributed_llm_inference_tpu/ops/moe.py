@@ -9,18 +9,34 @@ a capability extension required for the Mixtral and DeepSeek-V2/V3 families.
 Routing is ONE function (:func:`route`) whose rule the config chooses:
 Mixtral's (softmax over ALL expert logits in fp32, top-k, renormalise) and
 DeepSeek-V3's (sigmoid scores, selection by score plus a per-expert bias,
-weights from the scores alone, normalised and scaled). Both compute
-strategies sit behind it, all-static shapes:
+weights from the scores alone, normalised and scaled). Three
+compute strategies sit behind it, all-static shapes; :func:`dispatch_path`
+picks one from the dispatch's shape, and ``moe_mlp`` has no option for it:
 
-* **dense-combine** (decode, S == 1) — every expert processes every token and
-  a ``[B, S, E]`` combine matrix (zero off the top-k) weights the outputs.
-  Decode is bound by READING every expert's weights regardless, so the
-  overcompute is free, and with experts sharded over ``ep`` the combine
-  contraction becomes a ``psum`` XLA inserts automatically.
-* **sorted dispatch** (prefill) — (token, expert) pairs argsort to their
-  experts; each expert computes only its capacity-bounded slice
-  (``moe_mlp_dispatch``), cutting MLP FLOPs by E/(k·capacity_factor). The
-  whole path is gathers (a scatter would serialize on TPU).
+* **dropless grouped dispatch** (the default wherever a dispatch's (token,
+  pick) pairs fill the held experts' row tiles: a prefill, a chunk) — every
+  token's own experts and no others. Pairs are stable-sorted by expert, each
+  expert's group padded to ``ROW_TILE`` rows, and one Pallas kernel
+  (:func:`grouped_matmul`, traced as ``moe_grouped_matmul``) runs gate, up
+  and down over the row tiles, each against its expert's weight blocks
+  (``moe_mlp_grouped``). No capacity and no dropped pair, so a token's
+  result depends neither on its co-batched rows nor on where a chunk
+  boundary falls. The whole path around the kernel is gathers (a scatter
+  would serialize on TPU), and under a layer scan the kernel reads a
+  layer's matrices out of the stack (:class:`LayerOf`: a slice a scan
+  step would copy every held expert's weights before each call).
+* **dense-combine** (every decode and verify step, narrow buckets, and any
+  dispatch under a mesh) — every held expert processes every token and a
+  ``[B, S, held]`` combine matrix (zero off the top-k) weights the outputs.
+  A decode step of 4–32 rows is bound by READING every expert's weights
+  whatever it computes, so the overcompute is free there and the sort,
+  gathers and tile padding of the grouped path would only add to it; and
+  with experts sharded over ``ep`` the combine contraction becomes a
+  ``psum`` XLA inserts automatically, where the grouped path's
+  expert-indexed gathers trip GSPMD.
+* **capacity dispatch** (opt-in, ``ModelConfig.moe_capacity_factor``) —
+  the sorted dispatch with a capacity-bounded ``[E, C, H]`` einsum
+  (``moe_mlp_dispatch``): it drops the pairs past an expert's capacity.
 
 Shared experts (``p["ws_g"]``/``ws_u``/``ws_d``, present where the config
 has them) are one SwiGLU MLP every token passes through, added to the routed
@@ -40,15 +56,51 @@ Nothing here stands in for the absent experts or for an exchange.
 
 from __future__ import annotations
 
+import functools
 import math
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax._src import mesh as _mesh_lib
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..config import ModelConfig
 from . import quant
 
-__all__ = ["moe_mlp", "route", "router_weights", "expert_rows_per_token"]
+__all__ = [
+    "moe_mlp", "route", "router_weights", "expert_rows_per_token",
+    "dispatch_path", "traced_path", "grouped_matmul", "LayerOf",
+    "GROUPED_STACKS",
+]
+
+class LayerOf(NamedTuple):
+    """One layer's expert matrices, VIEWED out of the layer-stacked tensor
+    (``stack``: ``[L, E, K, N]`` or its int8 :class:`QuantizedTensor`;
+    ``index``: the layer, an int32 scalar of the layer scan). Slicing the
+    stack a scan step would copy every held expert's weights through HBM
+    before each kernel call, which is most of what the grouped dispatch
+    saves; :func:`grouped_matmul` names the layer in its block index maps
+    instead (``models/llama.py:_split_whole_stacks``, as for the int4
+    stacks)."""
+
+    stack: Any
+    index: Any
+
+
+# The grouped dispatch pads each expert's group of rows to this many (one
+# MXU pass a tile) and reads its weights in blocks of at most
+# ``WEIGHT_BLOCK`` ``(in, out)`` channels. Measured on a v5e at the four
+# routed configurations' widths (``tools/profile_grouped_moe.py``): a
+# 256-row tile pads a 16- or 64-expert layer's groups past what the dense
+# combine costs, and ``(2048, 2048)`` blocks read 2–7% better than
+# ``(2048, 1024)``.
+ROW_TILE = 128
+WEIGHT_BLOCK = (2048, 2048)
+# The leaves of a routed layer that :func:`grouped_matmul` reads: the ones
+# a layer scan hands over whole, as :class:`LayerOf` views.
+GROUPED_STACKS = ("we_g", "we_u", "we_d")
 
 
 def route(cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray, bias=None):
@@ -102,28 +154,86 @@ def router_weights(
         return jnp.einsum("bsk,bske->bse", top_p, one_hot)
 
 
-def expert_rows_per_token(cfg: ModelConfig, seq_len: int):
+def under_mesh() -> bool:
+    """Whether the program being traced runs sharded: inside a mesh context
+    of more than one device (the engine's ``with mesh:`` around every step
+    of a sharded deployment). Read at trace time, as the compiler reads
+    it; ``with mesh:`` has no public reader in jax 0.9 (``jax.sharding.
+    get_abstract_mesh`` sees ``use_mesh`` only)."""
+    return _mesh_lib.thread_resources.env.physical_mesh.size > 1
+
+
+def dispatch_path(
+    cfg: ModelConfig, rows: int, seq_len: int, sharded: bool = False
+) -> str:
+    """The compute strategy of a dispatch of ``rows x seq_len`` tokens:
+    ``"grouped"``, ``"dense"`` or ``"capacity"`` (module docstring). A
+    static function of the shape and the config.
+
+    Grouped where the pairs each expert EXPECTS fill a row tile, ``rows x
+    seq_len x k / E >= ROW_TILE``: below that most of a tile is padding and
+    the weight reads, which dense-combine pays as well, lead. That leaves
+    every decode and verify step (``seq_len`` of 1 to a few, 4–32 rows)
+    and narrow buckets dense. A ``sharded`` program stays dense whatever
+    its shape: the grouped path's expert-indexed gathers trip GSPMD under
+    ``ep``/``tp``. ``ModelConfig.moe_capacity_factor`` still opts a
+    prefill-scale dispatch in to the capacity form.
+    """
+    if cfg.moe_capacity_factor is not None and seq_len >= 16:
+        return "capacity"
+    pairs = rows * seq_len * cfg.num_experts_per_tok
+    if not sharded and pairs >= cfg.num_experts * ROW_TILE:
+        return "grouped"
+    return "dense"
+
+
+def traced_path(cfg: ModelConfig, x: jnp.ndarray) -> str:
+    """:func:`dispatch_path` of a dispatch of ``x`` (``[B, S, H]``) as the
+    program being traced sees it: what ``moe_mlp`` will take, and what a
+    layer scan asks before it hands the expert stacks over."""
+    return dispatch_path(cfg, x.shape[0], x.shape[1], under_mesh())
+
+
+def expert_rows_per_token(
+    cfg: ModelConfig,
+    seq_len: int,
+    rows: int = 1,
+    valid_share: float = 1.0,
+    sharded: bool = False,
+):
     """``(needed, computed)``: expert MLPs one token's result needs in one
     expert layer (its selected experts and the shared ones) and how many
-    the program runs for it in a dispatch ``seq_len`` wide (every routed
-    HELD expert under dense-combine; its capacity's share under sorted
-    dispatch). The census behind ``moe_expert_rows_*``. Where the layer
-    holds a share, ``needed`` is an EXPECTATION: of a token's ``k`` picks
-    over the router's ``E``, ``k * held / E`` fall here on average (uniform
-    routing); which do is data the host does not see."""
+    the program runs a PADDED token of a dispatch of ``rows x seq_len``
+    tokens of which ``valid_share`` are real, by the path
+    :func:`dispatch_path` takes there: every routed HELD expert under
+    dense-combine; its capacity's share under the capacity form; under the
+    grouped dispatch the valid tokens' own picks plus half a row tile an
+    expert, what padding each group to whole tiles costs on average. The
+    census behind ``moe_expert_rows_*``. Where the layer holds a share,
+    ``needed`` is an EXPECTATION: of a token's ``k`` picks over the
+    router's ``E``, ``k * held / E`` fall here on average (uniform
+    routing); which do is data the host does not see, and so is how full
+    a group's last tile is."""
     shared = cfg.num_shared_experts
     k = cfg.num_experts_per_tok
     held = cfg.num_held_experts
     if cfg.expert_shares > 1:
         k = k * held / cfg.num_experts
-    if cfg.moe_capacity_factor is not None and seq_len >= 16:
+    path = dispatch_path(cfg, rows, seq_len, sharded)
+    if path == "capacity":
         return k + shared, k * cfg.moe_capacity_factor + shared
+    if path == "grouped":
+        tile_pad = held * ROW_TILE / 2 / (rows * seq_len)
+        return k + shared, k * valid_share + tile_pad + shared
     return k + shared, held + shared
 
 
 # Dense-combine runs a dispatch's tokens whole up to this many a row (every
 # cell before PR 32 pads to 2048 or fewer: their programs are as they were),
-# and in blocks of ``DENSE_COMBINE_BLOCK`` past it.
+# and in blocks of ``DENSE_COMBINE_BLOCK`` past it. Since the grouped
+# dispatch takes the wide prefills, what still walks is a dispatch wider
+# than 2048 under a mesh, or one whose pairs do not fill its router's
+# tiles (:func:`dispatch_path`).
 DENSE_COMBINE_TOKENS = 2048
 DENSE_COMBINE_BLOCK = 1024
 
@@ -152,43 +262,308 @@ def moe_mlp(
     ``p["router_bias"]`` ``[E]`` and the shared experts' ``p["ws_*"]`` where
     the model has them (:func:`route`, :func:`_shared_experts`).
 
-    Dense-combine is the default everywhere: exact, shape-static, and every
-    token's output independent of co-batched rows (decode and verify steps
-    are bound by reading every expert's weights regardless, so the
-    overcompute is free there). Setting ``ModelConfig.moe_capacity_factor``
-    OPTS IN to sorted dispatch for prefill-scale steps (S >= 16):
-    E/(k·factor)× less MLP compute at the cost of capacity drops — which
-    also make results depend on prefill chunk boundaries, hence opt-in.
+    The path is :func:`dispatch_path`'s, from ``x``'s shape and whether the
+    trace runs under a mesh; there is no option. A prefill-scale dispatch
+    takes the dropless grouped dispatch (:func:`moe_mlp_grouped`): each
+    token's own experts, exact, and independent of co-batched rows and of
+    chunk boundaries, which is what lets it be the default. Decode and
+    verify steps, narrow buckets and sharded programs keep dense-combine,
+    bit for bit as before: equally exact and row-independent, and a step
+    of a few rows is bound by reading every expert's weights whatever it
+    computes, so the overcompute is free there.
+    ``ModelConfig.moe_capacity_factor`` still OPTS IN to the capacity form
+    (S >= 16), whose drops make results depend on chunk boundaries.
     ``valid`` (``[B, S]`` bool) marks real tokens; bucket-padding positions
-    must not consume expert capacity in the dispatched path.
+    take no row of an expert in either sorted path.
     """
-    if cfg.moe_capacity_factor is not None and x.shape[1] >= 16:
+    path = traced_path(cfg, x)
+    if path == "capacity":
         out = moe_mlp_dispatch(cfg, p, x, cfg.moe_capacity_factor, valid)
+    elif path == "grouped":
+        out = moe_mlp_grouped(cfg, p, x, valid)
     elif x.shape[1] > DENSE_COMBINE_TOKENS and x.shape[1] % DENSE_COMBINE_BLOCK == 0:
-        # A dispatch wider than any before PR 32 (a 4096-wide chunk over 128
-        # experts: ``[b, s, E, H]`` alone is 2.1 GB in bf16) walks its
-        # tokens a block at a time. A token's result does not depend on its
-        # neighbours, so the numbers are the whole dispatch's.
+        # A wide dispatch (a 4096-wide chunk over 128 experts: ``[b, s, E,
+        # H]`` alone is 2.1 GB in bf16) walks its tokens a block at a
+        # time. A token's result does not depend on its neighbours, so the
+        # numbers are the whole dispatch's.
         b, s, h = x.shape
         blocks = jnp.moveaxis(
             x.reshape(b, s // DENSE_COMBINE_BLOCK, DENSE_COMBINE_BLOCK, h), 1, 0
         )
         routed = {k: v for k, v in p.items() if not k.startswith("ws_")}
-        out = jax.lax.map(lambda xb: moe_mlp(cfg, routed, xb), blocks)
+        out = jax.lax.map(lambda xb: _dense_combine(cfg, routed, xb), blocks)
         out = jnp.moveaxis(out, 0, 1).reshape(b, s, h)
     else:
-        combine = router_weights(
-            cfg, x, p["router"], p.get("router_bias")
-        ).astype(x.dtype)
-        with jax.named_scope("moe_experts"):
-            t = quant.einsum("bsh,ehf->bsef", x, p["we_g"])
-            u = quant.einsum("bsh,ehf->bsef", x, p["we_u"])
-            y = quant.einsum("bsef,efh->bseh", jax.nn.silu(t) * u, p["we_d"])
-        with jax.named_scope("moe_combine"):
-            out = jnp.einsum("bse,bseh->bsh", combine, y)
+        out = _dense_combine(cfg, p, x)
     if "ws_g" in p:
         out = out + _shared_experts(p, x)
     return out
+
+
+def _dense_combine(cfg: ModelConfig, p, x: jnp.ndarray) -> jnp.ndarray:
+    """Every held expert over every token, weighted by the combine matrix."""
+    combine = router_weights(
+        cfg, x, p["router"], p.get("router_bias")
+    ).astype(x.dtype)
+    with jax.named_scope("moe_experts"):
+        t = quant.einsum("bsh,ehf->bsef", x, p["we_g"])
+        u = quant.einsum("bsh,ehf->bsef", x, p["we_u"])
+        y = quant.einsum("bsef,efh->bseh", jax.nn.silu(t) * u, p["we_d"])
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum("bse,bseh->bsh", combine, y)
+
+
+def _routed_pairs(cfg: ModelConfig, p, xf: jnp.ndarray, valid):
+    """Route ``xf`` (``[N, H]``) and flatten to its ``N * k`` (token, pick)
+    pairs, pair ``j`` of token ``j // k``. Returns ``(weights [N, k] fp32,
+    pair_e [N * k])``: a pair's expert as an index into the HELD stack, or
+    the sentinel ``held`` where the pair is PARKED, its weight zeroed: the
+    token is bucket padding (``valid`` false), or the pick lives in another
+    share. A stable sort by ``pair_e`` leaves the parked pairs behind every
+    real expert's group."""
+    e, k = cfg.num_held_experts, cfg.num_experts_per_tok
+    with jax.named_scope("moe_router"):
+        top_p, top_i = route(cfg, xf, p["router"], p.get("router_bias"))
+    here = None
+    if cfg.expert_shares > 1:
+        top_i = top_i - cfg.first_held_expert
+        here = (top_i >= 0) & (top_i < e)
+    if valid is not None:
+        vf = jnp.broadcast_to(valid.reshape(-1, 1), top_i.shape)
+        here = vf if here is None else here & vf
+    if here is not None:
+        top_i = jnp.where(here, top_i, e)
+        top_p = top_p * here.astype(top_p.dtype)
+    return top_p, top_i.reshape(-1)
+
+
+def _block(dim: int, cap: int) -> int:
+    """The widest block of ``dim`` channels within ``cap``: all of them, or
+    their largest divisor in whole 128-lane tiles."""
+    if dim <= cap:
+        return dim
+    for b in range(cap - cap % 128, 0, -128):
+        if dim % b == 0:
+            return b
+    return dim
+
+
+def _grouped_kernel(te_ref, at_ref, x_ref, w_ref, *rest, n_k, quantized):
+    """One (out block, row tile, in block) step of :func:`grouped_matmul`.
+
+    ``te_ref`` (a tile's expert) and ``at_ref`` (``[2]``: the tiles that
+    hold a group's rows, and the layer) are in SMEM and already resolved
+    the blocks: ``x_ref`` ``[tile, bk]`` of the tile's rows, ``w_ref``
+    ``[1, 1, bk, bn]`` of its expert's weights in its layer, ``s_ref``
+    ``[1, 1, bn]`` their f32 scales (int8 weights only), ``o_ref``
+    ``[tile, bn]``, ``acc_ref`` f32. A step of a tile past the live ones
+    names the blocks of the step before it, so nothing is fetched, and
+    computes nothing."""
+    if quantized:
+        s_ref, o_ref, acc_ref = rest
+    else:
+        o_ref, acc_ref = rest
+    kk = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) < at_ref[0])
+    def _live():
+        @pl.when(kk == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        x = x_ref[...]
+        acc_ref[...] += jnp.dot(
+            x, w_ref[0, 0].astype(x.dtype), preferred_element_type=jnp.float32
+        )
+
+        @pl.when(kk == n_k - 1)
+        def _store():
+            acc = acc_ref[...]
+            if quantized:
+                acc = acc * s_ref[0]
+            o_ref[...] = acc.astype(o_ref.dtype)
+
+
+def grouped_matmul(
+    x: jnp.ndarray,
+    w,
+    tile_expert: jnp.ndarray,
+    live_tiles: jnp.ndarray,
+    row_tile: int,
+    blocks=None,
+    interpret=None,
+) -> jnp.ndarray:
+    """Rows grouped by expert times their expert's matrix, ragged.
+
+    ``x``: ``[M, K]``, ``M`` whole ``row_tile``s, every tile's rows ONE
+    expert's; ``w``: ``[E, K, N]`` or its int8 :class:`QuantizedTensor`,
+    or a :class:`LayerOf` the layer-stacked form of either;
+    ``tile_expert``: ``[M / row_tile]`` int32, a tile's expert;
+    ``live_tiles``: int32 scalar, the leading tiles that hold rows.
+    Returns ``[M, N]`` in ``x``'s dtype; rows of a tile past the live ones
+    are NOT written (the caller reads none of them).
+
+    The grid is (out blocks, row tiles, in blocks), the weight block of the
+    tile's expert (and of the view's layer) chosen from the prefetched
+    scalars. int8 weights are read as int8 and converted in VMEM,
+    activations stay in ``x``'s dtype, the accumulator is f32 and takes
+    the per-(expert, out channel) scale once, at the end:
+    ``quant.einsum``'s precision, with the scale applied before the
+    rounding to ``x``'s dtype and not after it.
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    blocks = blocks or WEIGHT_BLOCK
+    layer = 0
+    if isinstance(w, LayerOf):
+        w, layer = w
+    quantized = isinstance(w, quant.QuantizedTensor)
+    wq, scale = (w.q, w.scale) if quantized else (w, None)
+    if wq.ndim == 3:
+        wq = wq[None]       # one layer's own matrices: a stack of one
+    elif quantized:
+        # the scales are small: a layer's ride as a slice
+        scale = jax.lax.dynamic_index_in_dim(scale, layer, 0, keepdims=False)
+    m, k_dim = x.shape
+    _, e, _, n_dim = wq.shape
+    bk, bn = _block(k_dim, blocks[0]), _block(n_dim, blocks[1])
+    n_k, n_n, tiles = k_dim // bk, n_dim // bn, m // row_tile
+
+    def tile(t, at):
+        # the tile whose blocks a step names: itself, or the last live one
+        return jnp.maximum(jnp.minimum(t, at[0] - 1), 0)
+
+    def k_block(t, kk, at):
+        return jnp.where(t < at[0], kk, n_k - 1)
+
+    in_specs = [
+        pl.BlockSpec(
+            (row_tile, bk),
+            lambda nn, t, kk, te, at: (tile(t, at), k_block(t, kk, at)),
+        ),
+        pl.BlockSpec(
+            (1, 1, bk, bn),
+            lambda nn, t, kk, te, at: (
+                at[1], te[tile(t, at)], k_block(t, kk, at), nn
+            ),
+        ),
+    ]
+    operands = [x, wq]
+    if quantized:
+        in_specs.append(pl.BlockSpec(
+            (1, 1, bn), lambda nn, t, kk, te, at: (te[tile(t, at)], 0, nn)
+        ))
+        operands.append(scale.astype(jnp.float32).reshape(e, 1, n_dim))
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, n_k=n_k, quantized=quantized),
+        name="moe_grouped_matmul",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_n, tiles, n_k),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (row_tile, bn), lambda nn, t, kk, te, at: (tile(t, at), nn)
+            ),
+            scratch_shapes=[pltpu.VMEM((row_tile, bn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n_dim), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=100 * 1024 * 1024,
+        ),
+        interpret=interpret,
+    )(
+        tile_expert.astype(jnp.int32),
+        jnp.stack([
+            jnp.asarray(live_tiles, jnp.int32), jnp.asarray(layer, jnp.int32)
+        ]),
+        *operands,
+    )
+
+
+def moe_mlp_grouped(
+    cfg: ModelConfig,
+    p,
+    x: jnp.ndarray,
+    valid=None,
+    row_tile=None,
+    blocks=None,
+) -> jnp.ndarray:
+    """Dropless grouped dispatch: the routed sum of :func:`moe_mlp`, each
+    token through its own held experts and no others.
+
+    The ``N * k`` pairs (:func:`_routed_pairs`) are stable-sorted by
+    expert; each expert's group takes whole ``row_tile``-row tiles of a
+    ``[M, H]`` buffer that its tokens' rows are gathered into once, ``M`` the
+    static worst case ``N * k + held * (row_tile - 1)`` in whole tiles;
+    gate, up and down are three calls of :func:`grouped_matmul` over it,
+    which skip the tiles behind the last group (the parked pairs sit
+    there and cost nothing); undoing the sort is a gather, and the combine a
+    dense ``[N, k]`` weighted sum in f32. No capacity: every pair that is
+    not parked is computed. Gather-only (a scatter lowers to a serial row
+    loop on TPU and trips GSPMD — see cache/dense.py).
+    """
+    b, s, h = x.shape
+    e, k = cfg.num_held_experts, cfg.num_experts_per_tok
+    n = b * s
+    row_tile = row_tile or ROW_TILE
+    xf = x.reshape(n, h)
+    top_p, pair_e = _routed_pairs(cfg, p, xf, valid)
+
+    with jax.named_scope("moe_sort"):
+        order = jnp.argsort(pair_e, stable=True)
+        # e + 1 bounds: the parked pairs sit past EVERY group's end.
+        bounds = jnp.searchsorted(
+            pair_e[order], jnp.arange(e + 1, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
+        group_start, count = bounds[:e], bounds[1:] - bounds[:e]
+        group_tiles = (count + row_tile - 1) // row_tile
+        tile_end = jnp.cumsum(group_tiles)
+        first_row = (tile_end - group_tiles) * row_tile   # [e], in the buffer
+        live_tiles = tile_end[e - 1]
+
+        tiles = -(-(n * k + e * (row_tile - 1)) // row_tile)
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(
+                tile_end, jnp.arange(tiles, dtype=jnp.int32), side="right"
+            ),
+            e - 1,
+        ).astype(jnp.int32)
+        # Row r of the buffer holds the pair at sorted position group_start
+        # + (r - first_row) of its tile's expert; past the group's count it
+        # is padding (any token's row: its result is read by nobody).
+        row_e = jnp.repeat(tile_expert, row_tile)
+        row = jnp.arange(tiles * row_tile, dtype=jnp.int32)
+        src = group_start[row_e] + row - first_row[row_e]
+        row_tok = order[jnp.clip(src, 0, n * k - 1)] // k
+        gathered = xf[row_tok]
+
+    mm = functools.partial(
+        grouped_matmul, tile_expert=tile_expert, live_tiles=live_tiles,
+        row_tile=row_tile, blocks=blocks,
+    )
+    with jax.named_scope("moe_experts"):
+        t = mm(gathered, p["we_g"])
+        u = mm(gathered, p["we_u"])
+        y = mm(jax.nn.silu(t) * u, p["we_d"])
+
+    # Back to pair order (a gather: the inverse permutation says where in
+    # the sorted order, hence in the buffer, a pair's row is), then a dense
+    # [N, k] weighted combine. A parked pair's row was never written:
+    # select, do not multiply.
+    with jax.named_scope("moe_combine"):
+        held = pair_e < e
+        pe = jnp.minimum(pair_e, e - 1)
+        rank = jnp.argsort(order).astype(jnp.int32)     # place in the sort
+        pair_row = first_row[pe] + rank - group_start[pe]
+        pair_out = jnp.where(
+            held[:, None], y[jnp.where(held, pair_row, 0)], 0
+        ).reshape(n, k, h)
+        out = jnp.einsum(
+            "nk,nkh->nh", top_p.astype(jnp.float32),
+            pair_out.astype(jnp.float32),
+        )
+        return out.reshape(b, s, h).astype(x.dtype)
 
 
 def _expert_matmul(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
@@ -211,7 +586,9 @@ def moe_mlp_dispatch(
     valid=None,
     capacity=None,
 ) -> jnp.ndarray:
-    """Sorted (capacity-based) expert dispatch — the prefill MoE path.
+    """Sorted, capacity-bounded expert dispatch — opt-in
+    (``ModelConfig.moe_capacity_factor``); the default prefill path is the
+    dropless :func:`moe_mlp_grouped`.
 
     Gather-only by construction (a scatter lowers to a serial row loop on
     TPU and trips GSPMD — see cache/dense.py): (token, expert) pairs are
@@ -224,39 +601,21 @@ def moe_mlp_dispatch(
     at factor 2 under Mixtral's near-uniform routing, and bounded: a dropped
     pair loses at most its renormalized probability share of one token.
 
-    ``valid`` (``[B, S]`` bool): invalid (bucket-padding) tokens route to a
-    sentinel expert id ``E`` — the stable sort parks them AFTER every real
-    expert's group, so padding can never evict a real token from capacity.
+    Bucket-padding tokens (``valid`` false) and picks of another share are
+    parked on a sentinel expert id past every real expert's group
+    (:func:`_routed_pairs`), so padding can never evict a real token from
+    capacity.
 
     NOTE: under an ``ep``-sharded mesh the expert-indexed gathers here have
     not been perf-verified (GSPMD may all-gather the expert stacks); the
-    dense-combine path is the ep-proven one. Dispatch is opt-in
-    (``ModelConfig.moe_capacity_factor``) partly for this reason.
+    dense-combine path is the ep-proven one.
     """
     b, s, h = x.shape
-    # ``e``: the experts HELD here; the router scores all of its width and
-    # a pick that lives in another share goes to the sentinel below.
     e, k = cfg.num_held_experts, cfg.num_experts_per_tok
     n = b * s
     xf = x.reshape(n, h)
-
-    with jax.named_scope("moe_router"):
-        top_p, top_i = route(cfg, xf, p["router"], p.get("router_bias"))
-
-    pair_e = top_i.reshape(-1)                                  # [N*k]
-    if cfg.expert_shares > 1:
-        here = (top_i >= cfg.first_held_expert) & (
-            top_i < cfg.first_held_expert + e
-        )
-        pair_e = jnp.where(
-            here.reshape(-1), pair_e - cfg.first_held_expert, e
-        )
-        top_p = top_p * here.astype(top_p.dtype)
+    top_p, pair_e = _routed_pairs(cfg, p, xf, valid)
     pair_t = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)      # [N*k]
-    if valid is not None:
-        vf = valid.reshape(-1)
-        pair_e = jnp.where(jnp.repeat(vf, k), pair_e, e)
-        top_p = top_p * vf[:, None].astype(top_p.dtype)
 
     order = jnp.argsort(pair_e, stable=True)
     sorted_e = pair_e[order]
@@ -267,7 +626,6 @@ def moe_mlp_dispatch(
     pos_in_group = jnp.arange(n * k, dtype=jnp.int32) - group_start[
         jnp.clip(sorted_e, 0, e - 1)
     ]
-
     c = capacity if capacity is not None else max(
         1, min(n, math.ceil((n * k) / cfg.num_experts * capacity_factor))
     )

@@ -75,6 +75,12 @@ METRICS = {
     "moe_expert_rows_computed": (
         "counter", "Expert MLP rows the dispatches' padded tokens run"
     ),
+    # dispatches of a routed model by the path its experts took
+    # (ops/moe.py:dispatch_path, from the dispatch's shape): grouped,
+    # dense or capacity
+    "moe_dispatch_*": (
+        "counter", "Dispatches by the experts' compute path at their shape"
+    ),
     # the ragged prefill kernel's grid, a layer's a dispatch: live / grid
     # is the share of its steps that compute (ops/ragged_attention.py)
     "ragged_attn_tiles_live": (

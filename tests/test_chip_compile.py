@@ -420,3 +420,41 @@ def test_two_pool_prefill_chunk_at_exaones_shapes(chip, kind, rows):
     )
     assert name in text
     assert ("window_ragged_paged_attention" in text) == bool(window)
+
+
+@pytest.mark.parametrize(
+    "hidden,ffn,held,pairs",
+    [
+        (4096, 14336, 8, 2 * 2048),      # 8 experts, 2 a token, 2048 wide
+        (2048, 1408, 64, 6 * 2048),      # 64 of 1408, 6 a token
+        (2048, 768, 128, 8 * 4096),      # 128 of 768, 8 a token, 4096 wide
+        (6144, 2048, 16, 8 * 2048),      # a share of 16 of 128, 8 a token
+    ],
+    ids=["8x14336", "64x1408", "128x768", "16x2048-share"],
+)
+def test_grouped_moe_kernel(chip, hidden, ffn, held, pairs):
+    """``moe_grouped_matmul`` over int8 expert stacks at the routed cells'
+    widths, with the row tile and weight blocks the module picks: gate (or
+    up) and down of one layer read out of a two-layer stack (the served
+    form), over the worst-case row buffer of a prefill dispatch."""
+    from distributed_llm_inference_tpu.ops import moe
+    from distributed_llm_inference_tpu.ops.quant import QuantizedTensor
+
+    s = chip
+    tiles = -(-(pairs + held * (moe.ROW_TILE - 1)) // moe.ROW_TILE)
+    rows = tiles * moe.ROW_TILE
+    stack = lambda k, n: QuantizedTensor(
+        q=s((2, held, k, n), I8), scale=s((2, held, n), jnp.bfloat16)
+    )
+
+    def gate_and_down(x, wg, wd, tile_expert, live, layer):
+        mm = lambda x, w: moe.grouped_matmul(
+            x, moe.LayerOf(w, layer), tile_expert, live,
+            row_tile=moe.ROW_TILE, interpret=False,
+        )
+        return mm(jax.nn.silu(mm(x, wg)), wd)
+
+    _compiles_with_kernel(
+        gate_and_down, s((rows, hidden), jnp.bfloat16), stack(hidden, ffn),
+        stack(ffn, hidden), s((tiles,), I32), s((), I32), s((), I32),
+    )

@@ -1,0 +1,438 @@
+"""The dropless grouped dispatch of ``ops/moe.py``: prefill-scale dispatches
+compute each token's own experts (``moe_mlp_grouped`` over the Pallas
+kernel ``moe_grouped_matmul``, interpreted here), decode keeps
+dense-combine, and ``dispatch_path`` picks between them from the shape.
+
+Toy widths throughout (H, F <= 128, 4-8 experts, a row tile of 8): what the
+chip runs at the cells' widths is ``tests/test_chip_compile.py``'s and the
+benchmark's to say."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_llm_inference_tpu.config import (
+    CacheConfig,
+    EngineConfig,
+    MeshConfig,
+    ModelConfig,
+)
+from distributed_llm_inference_tpu.ops import moe
+from distributed_llm_inference_tpu.ops.quant import quantize_params
+from distributed_llm_inference_tpu.parallel import build_mesh
+
+H, F, E, K, TILE = 64, 32, 8, 2, 8
+
+
+def config(**over) -> ModelConfig:
+    kw = dict(
+        vocab_size=128, hidden_size=H, intermediate_size=F,
+        moe_intermediate_size=F, num_layers=2, num_heads=4, num_kv_heads=2,
+        head_dim=8, max_position_embeddings=256, num_experts=E,
+        num_experts_per_tok=K, family="mixtral",
+    )
+    kw.update(over)
+    return ModelConfig(**kw)
+
+
+SOFTMAX = config()
+SIGMOID = config(
+    moe_scoring="sigmoid", moe_select_bias=True, moe_routed_scale=2.5
+)
+
+
+def layer(cfg: ModelConfig, seed=0, dtype=jnp.float32, shared=0):
+    """One routed layer's leaves: the held stack, a bias where the rule
+    selects by one, shared experts where asked."""
+    held = cfg.num_held_experts
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
+    normal = lambda *shape: (
+        jax.random.normal(next(keys), shape, jnp.float32) * shape[-2] ** -0.5
+    ).astype(dtype)
+    p = {
+        "router": normal(H, cfg.num_experts),
+        "we_g": normal(held, H, F), "we_u": normal(held, H, F),
+        "we_d": normal(held, F, H),
+    }
+    if cfg.moe_select_bias:
+        p["router_bias"] = jax.random.normal(
+            next(keys), (cfg.num_experts,), jnp.float32
+        ) * 0.1
+    if shared:
+        p.update(ws_g=normal(H, F * shared), ws_u=normal(H, F * shared),
+                 ws_d=normal(F * shared, H))
+    return p
+
+
+def tokens(rows, width, seed=1, dtype=jnp.float32):
+    return jax.random.normal(
+        jax.random.PRNGKey(seed), (rows, width, H), jnp.float32
+    ).astype(dtype)
+
+
+@pytest.fixture
+def tile8(monkeypatch):
+    """The rule and the kernel at a row tile of 8: a dispatch of 32 tokens
+    fills 8 experts' tiles, as 2048 fill them at 128."""
+    monkeypatch.setattr(moe, "ROW_TILE", TILE)
+
+
+# -- grouped against dense-combine -------------------------------------------
+
+
+@pytest.mark.parametrize("stack", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("shared", [0, 1], ids=["routed_only", "shared_expert"])
+@pytest.mark.parametrize("cfg", [SOFTMAX, SIGMOID], ids=["softmax", "sigmoid_bias"])
+def test_grouped_equals_dense_combine(tile8, monkeypatch, cfg, shared, stack):
+    """``moe_mlp`` at a prefill shape (grouped by the rule) against the same
+    call held to dense-combine: float32 to rounding, bf16 activations and
+    int8 stacks (the served form) within four of bf16's steps at the
+    results' scale (the kernel scales its f32 sum before it rounds, the
+    einsum after)."""
+    act = jnp.float32 if stack == "float32" else jnp.bfloat16
+    p = layer(cfg, dtype=act, shared=shared)
+    if stack == "int8":
+        p = quantize_params(p, scale_dtype=jnp.float32)
+        assert type(p["we_g"]).__name__ == "QuantizedTensor"
+    x = tokens(2, 24, dtype=act)
+    valid = jnp.arange(24)[None, :] < jnp.array([[13], [24]])
+    assert moe.dispatch_path(cfg, 2, 24) == "grouped"
+    got = moe.moe_mlp(cfg, p, x, valid)
+    monkeypatch.setattr(moe, "dispatch_path", lambda *a, **k: "dense")
+    want = moe.moe_mlp(cfg, p, x, valid)
+    keep = np.asarray(valid)[..., None]
+    a = np.where(keep, np.asarray(got, np.float32), 0)
+    b = np.where(keep, np.asarray(want, np.float32), 0)
+    assert got.dtype == want.dtype == act
+    tol = 1e-5 if stack == "float32" else 2 ** -5 * np.abs(b).max()
+    np.testing.assert_allclose(a, b, atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (2048, 2048)])
+def test_grouped_matmul_walks_blocks_of_the_weights(blocks):
+    """The kernel alone against a per-row einsum, at weight blocks smaller
+    than the matrix (2 x 2 of them), in between and wider: the live tiles'
+    rows do not depend on the blocking, each is its own expert's."""
+    w = jax.random.normal(jax.random.PRNGKey(0), (4, 256, 256), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (6 * TILE, 256), jnp.float32)
+    tile_expert = jnp.array([0, 0, 2, 3, 3, 3], jnp.int32)
+    got = moe.grouped_matmul(
+        x, w, tile_expert, jnp.int32(4), row_tile=TILE, blocks=blocks
+    )
+    want = jnp.einsum(
+        "rk,rkn->rn", x, w[jnp.repeat(tile_expert, TILE)],
+        precision="highest",
+    )
+    live = 4 * TILE
+    np.testing.assert_allclose(
+        np.asarray(got[:live]), np.asarray(want[:live]), atol=1e-3, rtol=1e-5
+    )
+
+
+# -- dropless ----------------------------------------------------------------
+
+
+def test_one_expert_takes_every_token_and_nothing_is_dropped(tile8):
+    """A routing that sends every token's first pick to expert 5 and its
+    second to expert 2: 48 rows each where a capacity form at factor 1
+    holds 12. Every pair is computed."""
+    cfg = SOFTMAX
+    p = layer(cfg)
+    router = np.zeros((H, E), np.float32)
+    router[0, 5], router[0, 2] = 4.0, 2.0
+    p["router"] = jnp.asarray(router)
+    x = tokens(1, 48).at[..., 0].set(3.0)
+    _, picks = moe.route(cfg, x.reshape(-1, H), p["router"])
+    assert np.asarray(picks).tolist() == [[5, 2]] * 48
+    got = moe.moe_mlp_grouped(cfg, p, x)
+    want = moe._dense_combine(cfg, p, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    dropped = moe.moe_mlp_dispatch(cfg, p, x, capacity_factor=1.0)
+    assert np.abs(np.asarray(dropped) - np.asarray(want)).max() > 1e-2
+
+
+def test_the_default_path_has_no_capacity(tile8, monkeypatch):
+    """Without ``moe_capacity_factor`` no dispatch reaches the capacity
+    form, whatever its shape; with it a prefill-scale one still does."""
+    def refuse(*a, **k):
+        raise AssertionError("capacity form on the default path")
+
+    assert SOFTMAX.moe_capacity_factor is None
+    monkeypatch.setattr(moe, "moe_mlp_dispatch", refuse)
+    p = layer(SOFTMAX)
+    for rows, width in ((1, 48), (4, 1), (2, 4), (1, 16)):
+        assert moe.dispatch_path(SOFTMAX, rows, width) != "capacity"
+        moe.moe_mlp(SOFTMAX, p, tokens(rows, width))
+    opted = dataclasses.replace(SOFTMAX, moe_capacity_factor=2.0)
+    assert moe.dispatch_path(opted, 1, 48) == "capacity"
+    assert moe.dispatch_path(opted, 4, 1) == "dense"
+
+
+@pytest.mark.parametrize("cfg", [SOFTMAX, SIGMOID], ids=["softmax", "sigmoid_bias"])
+def test_a_tokens_rows_do_not_depend_on_chunks_or_neighbours(tile8, cfg):
+    """The same 64 tokens as one dispatch, as two chunks of 32, and beside
+    another prompt's row: the same result rows."""
+    p = layer(cfg)
+    x = tokens(1, 64)
+    other = tokens(1, 64, seed=7) * 3.0
+    for shape in ((1, 64), (1, 32), (2, 64)):
+        assert moe.dispatch_path(cfg, *shape) == "grouped"
+    whole = np.asarray(moe.moe_mlp(cfg, p, x))
+    chunks = np.concatenate(
+        [np.asarray(moe.moe_mlp(cfg, p, x[:, i:i + 32])) for i in (0, 32)], 1
+    )
+    beside = np.asarray(moe.moe_mlp(cfg, p, jnp.concatenate([other, x])))[1:]
+    np.testing.assert_allclose(chunks, whole, atol=1e-6, rtol=0)
+    np.testing.assert_allclose(beside, whole, atol=1e-6, rtol=0)
+
+
+# -- what is parked ----------------------------------------------------------
+
+
+def test_padding_is_parked_and_contributes_zero(tile8):
+    """Bucket padding takes no row of an expert: its routed result is zero,
+    and the real tokens' rows are what they are without the junk."""
+    cfg = SIGMOID
+    p = layer(cfg)
+    x = tokens(1, 40)
+    valid = (jnp.arange(40) < 23)[None, :]
+    junk = jnp.where(valid[..., None], x, x[:, :1] * 50.0)
+    got = np.asarray(moe.moe_mlp_grouped(cfg, p, junk, valid))
+    clean = np.asarray(moe.moe_mlp_grouped(cfg, p, x[:, :23]))
+    assert not got[:, 23:].any()
+    np.testing.assert_allclose(got[:, :23], clean, atol=1e-6, rtol=0)
+    # the parked pairs sit behind every group: the kernel's live tiles hold
+    # the valid tokens' pairs and no others
+    _, pair_e = moe._routed_pairs(cfg, p, junk.reshape(-1, H), valid)
+    assert int((np.asarray(pair_e) < E).sum()) == 23 * K
+    assert (np.asarray(pair_e).reshape(40, K)[23:] == E).all()
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_picks_of_another_share_are_parked(tile8, shares):
+    """A program that holds a share computes the picks that fall in it and
+    parks the rest: the shares' results sum to the whole model's, and each
+    equals dense-combine over its own held columns."""
+    whole_cfg = SIGMOID
+    whole = layer(whole_cfg)
+    x = tokens(1, 64)
+    want = np.asarray(moe._dense_combine(whole_cfg, whole, x))
+    total = np.zeros_like(want)
+    held = E // shares
+    for index in range(shares):
+        cfg = dataclasses.replace(
+            whole_cfg, expert_shares=shares, expert_share_index=index
+        )
+        assert cfg.num_held_experts == held
+        p = dict(whole)
+        for name in ("we_g", "we_u", "we_d"):
+            p[name] = whole[name][index * held:(index + 1) * held]
+        assert moe.dispatch_path(cfg, 1, 64) == "grouped"
+        got = np.asarray(moe.moe_mlp(cfg, p, x))
+        np.testing.assert_allclose(
+            got, np.asarray(moe._dense_combine(cfg, p, x)), atol=1e-5
+        )
+        _, pair_e = moe._routed_pairs(cfg, p, x.reshape(-1, H), None)
+        _, picks = moe.route(cfg, x.reshape(-1, H), p["router"], p["router_bias"])
+        here = (np.asarray(picks) // held == index).reshape(-1)
+        assert ((np.asarray(pair_e) < held) == here).all()
+        total += got
+    np.testing.assert_allclose(total, want, atol=1e-5)
+
+
+def test_a_dispatch_whose_every_pair_is_parked_is_zero(tile8):
+    cfg = SOFTMAX
+    got = moe.moe_mlp_grouped(
+        cfg, layer(cfg), tokens(1, 32), jnp.zeros((1, 32), bool)
+    )
+    assert not np.asarray(got).any()
+
+
+# -- the rule ----------------------------------------------------------------
+
+# the routed configurations the benchmark serves, as (router width, picks,
+# shares, prefill width): what the rule reads of them
+MIXTRAL = (8, 2, 1, 2048)
+MOONLIGHT = (64, 6, 1, 2048)
+KEYE = (128, 8, 1, 4096)
+EXAONE = (128, 8, 8, 2048)
+ROUTED = {"mixtral": MIXTRAL, "moonlight": MOONLIGHT, "keye": KEYE,
+          "exaone": EXAONE}
+
+
+def routed(shape) -> ModelConfig:
+    experts, k, shares, _ = shape
+    return config(
+        num_experts=experts, num_experts_per_tok=k, expert_shares=shares
+    )
+
+
+@pytest.mark.parametrize("name", sorted(ROUTED))
+@pytest.mark.parametrize("case,want", [
+    ("prefill", "grouped"), ("two_row_prefill", "grouped"),
+    ("decode_4", "dense"), ("decode_32", "dense"), ("verify_32x5", "dense"),
+    ("bucket_64", "dense"), ("prefill_under_a_mesh", "dense"),
+])
+def test_the_rules_table(name, case, want):
+    """Static, from the shape and the config alone, at the kernel's own
+    128-row tile: the four cells' prefill shapes grouped; every decode and
+    verify step, a narrow bucket and any sharded program dense."""
+    assert moe.ROW_TILE == 128
+    cfg, width = routed(ROUTED[name]), ROUTED[name][3]
+    rows, seq_len, sharded = {
+        "prefill": (1, width, False),
+        "two_row_prefill": (2, width, False),
+        "decode_4": (4, 1, False),
+        "decode_32": (32, 1, False),
+        "verify_32x5": (32, 5, False),
+        "bucket_64": (1, 64, False),
+        "prefill_under_a_mesh": (1, width, True),
+    }[case]
+    assert moe.dispatch_path(cfg, rows, seq_len, sharded) == want
+    needed, computed = moe.expert_rows_per_token(
+        cfg, seq_len, rows, 1.0, sharded
+    )
+    held = cfg.num_held_experts
+    assert needed == cfg.num_experts_per_tok * held / cfg.num_experts
+    if want == "dense":
+        assert computed == held
+    else:
+        # its own picks and half a tile an expert, never every expert
+        assert computed == needed + held * 64 / (rows * seq_len)
+        assert computed < held / 2
+
+
+def test_the_program_observes_its_mesh(tile8, monkeypatch):
+    """``moe_mlp`` has no setting for it: a step traced inside a mesh of
+    more than one device (the engine's ``with self.mesh``) sees it and
+    stays dense; the same call outside groups."""
+    taken = []
+    monkeypatch.setattr(
+        moe, "moe_mlp_grouped",
+        lambda cfg, p, x, valid=None: taken.append("grouped") or x,
+    )
+    monkeypatch.setattr(
+        moe, "_dense_combine", lambda cfg, p, x: taken.append("dense") or x
+    )
+    p, x = layer(SOFTMAX), tokens(1, 48)
+    step = lambda: jax.jit(lambda p, x: moe.moe_mlp(SOFTMAX, p, x))(p, x)
+    assert not moe.under_mesh()
+    step()
+    with build_mesh(MeshConfig(ep=4)):
+        assert moe.under_mesh()
+        step()
+    with build_mesh(MeshConfig()):
+        assert not moe.under_mesh()         # one device shards nothing
+    assert taken == ["grouped", "dense"]
+
+
+# -- the counters ------------------------------------------------------------
+
+
+def test_the_engine_counts_dispatches_by_path_and_rows_by_the_rule(
+    tile8, monkeypatch
+):
+    """A CPU engine over a routed model: every prefill-family dispatch wide
+    enough for the rule is counted grouped (and runs the kernel), every
+    decode dispatch dense; ``moe_expert_rows_computed`` counts the valid
+    tokens' own picks plus half a tile an expert where grouped and every
+    expert where dense."""
+    from distributed_llm_inference_tpu.engine import InferenceEngine
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+    from distributed_llm_inference_tpu.models import llama
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    cfg = SOFTMAX
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    engine = InferenceEngine(
+        cfg, params,
+        EngineConfig(max_batch_size=2, prefill_buckets=(8, 32),
+                     max_seq_len=64, dtype="float32"),
+        CacheConfig(kind="dense"), trace_cfg=TraceConfig(),
+    )
+    calls = []
+    grouped = moe.moe_mlp_grouped
+    monkeypatch.setattr(
+        moe, "moe_mlp_grouped",
+        lambda *a, **k: calls.append(1) or grouped(*a, **k),
+    )
+    engine.generate(
+        [list(range(1, 28)), list(range(2, 7))],
+        SamplingOptions(max_new_tokens=4),
+    )
+    m = engine.metrics
+    seen = [(d[0], tuple(d[1]), d[2])
+            for t in engine.flight.snapshot() for d in t.get("dispatches", ())]
+    prefills = [d for d in seen if d[0] != "decode"]
+    decodes = [d for d in seen if d[0] == "decode"]
+    wide = [d for d in prefills if d[1][0] * d[1][1] * K >= E * TILE]
+    assert wide and len(wide) < len(prefills) and decodes
+    assert calls, "a wide prefill traced the grouped form"
+    assert m.get_counter("moe_dispatch_grouped") == len(wide)
+    assert m.get_counter("moe_dispatch_dense") == (
+        len(seen) - len(wide)
+    )
+    assert m.get_counter("moe_dispatch_capacity") == 0
+    layers = cfg.num_expert_layers
+    computed = 0.0
+    for kind, shape, valid in seen:
+        if kind == "decode":
+            computed += shape[0] * shape[1] * E
+        elif (kind, shape, valid) in wide:
+            computed += valid * K + E * TILE / 2
+        else:
+            computed += shape[0] * shape[1] * E
+    assert m.get_counter("moe_expert_rows_computed") == pytest.approx(
+        computed * layers
+    )
+    # needed rows: the valid tokens' picks, whatever the path
+    assert m.get_counter("moe_expert_rows_needed") >= (
+        sum(d[2] for d in prefills) * K * layers
+    )
+
+
+# -- through the model -------------------------------------------------------
+
+
+@pytest.mark.parametrize("stack", ["float32", "int8"])
+def test_a_models_prefill_reads_each_layers_experts_out_of_the_stack(
+    tile8, monkeypatch, stack
+):
+    """``model_apply`` over two routed layers at a grouped shape: the layer
+    scan hands the kernel the expert stacks whole (``LayerOf``: a slice a
+    step would copy a layer's experts before every call) and each layer
+    reads ITS matrices: the logits are dense-combine's."""
+    from distributed_llm_inference_tpu.cache.dense import DenseKVCache
+    from distributed_llm_inference_tpu.models import llama
+
+    cfg = SIGMOID
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    if stack == "int8":
+        params = quantize_params(params, scale_dtype=jnp.float32)
+    tokens_ = jax.random.randint(jax.random.PRNGKey(1), (1, 32), 0, 128)
+    seen = []
+    grouped = moe.moe_mlp_grouped
+    monkeypatch.setattr(
+        moe, "moe_mlp_grouped",
+        lambda cfg, p, x, valid=None: seen.append(p["we_d"]) or grouped(
+            cfg, p, x, valid
+        ),
+    )
+
+    def logits():
+        cache = DenseKVCache.create(
+            cfg.num_layers, 1, 32, cfg.num_kv_heads, cfg.head_dim, jnp.float32
+        )
+        return np.asarray(llama.model_apply(
+            cfg, params, tokens_, cache, jnp.full((1,), 27, jnp.int32)
+        )[0])
+
+    got = logits()
+    assert seen and all(isinstance(w, moe.LayerOf) for w in seen)
+    assert seen[0].stack is params["layers"]["we_d"]
+    monkeypatch.setattr(moe, "dispatch_path", lambda *a, **k: "dense")
+    want = logits()
+    np.testing.assert_allclose(got[:, :27], want[:, :27], atol=2e-5, rtol=1e-5)

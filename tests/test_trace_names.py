@@ -70,11 +70,12 @@ def test_every_pallas_call_has_a_name_of_its_own():
             assert isinstance(value, ast.Constant), f"{path}:{line}"
             assert isinstance(value.value, str) and value.value
             names.setdefault(value.value, []).append(f"{path}:{line} {fn}")
-    assert len(names) >= 18
-    for latent in ("latent_paged_attention", "quantized_latent_paged_attention",
+    assert len(names) >= 19
+    for kernel in ("latent_paged_attention", "quantized_latent_paged_attention",
                    "latent_ragged_paged_attention",
-                   "quantized_latent_ragged_paged_attention"):
-        assert latent in names, latent
+                   "quantized_latent_ragged_paged_attention",
+                   "moe_grouped_matmul"):
+        assert kernel in names, kernel
     shared = {n: at for n, at in names.items() if len(at) > 1}
     assert not shared, shared
 
@@ -124,6 +125,36 @@ def test_a_lowered_moe_forward_carries_the_expert_scopes():
     text = lowered_text(cfg)
     for scope in ("mlp/moe_router", "mlp/moe_experts", "mlp/moe_combine"):
         assert scoped(text, scope), scope
+
+
+def test_a_lowered_grouped_moe_prefill_carries_the_kernel_and_its_scopes(monkeypatch):
+    """A prefill wide enough for the grouped dispatch (at a row tile of 8,
+    32 tokens are): the sort's scope beside the three of a routed MLP and
+    three calls of ``moe_grouped_matmul`` a routed layer's scan body; a
+    4-token step of the same model has neither."""
+    from distributed_llm_inference_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=2, num_kv_heads=2, head_dim=16, num_experts=4,
+        num_experts_per_tok=2,
+    )
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    cache = DenseKVCache.create(
+        cfg.num_layers, 1, 32, cfg.num_kv_heads, cfg.head_dim, jnp.float32
+    )
+    text = jax.jit(lambda p, t, c: llama.model_apply(
+        cfg, p, t, c, jnp.full((1,), 32, jnp.int32), head="last",
+    )).lower(params, jnp.zeros((1, 32), jnp.int32), cache).as_text(
+        debug_info=True
+    )
+    for scope in ("mlp/moe_router", "mlp/moe_sort", "mlp/moe_experts",
+                  "mlp/moe_combine"):
+        assert scoped(text, scope), scope
+    assert "moe_grouped_matmul" in text
+    narrow = lowered_text(cfg)
+    assert "moe_grouped_matmul" not in narrow and "moe_sort" not in narrow
 
 
 def test_a_lowered_two_segment_forward_carries_a_scope_a_segment():
