@@ -330,6 +330,10 @@ def cmd_local(args) -> int:
         trace_cfg=TraceConfig(ticks_capacity=100_000)
         if args.profile_dir else None,
     )
+    if engine.flight is not None:
+        # whoever asks for a profile is watching: the ticks written beside
+        # it carry the dispatch clock, for ``xplane_profile.py --ticks``
+        engine.flight.clock.lease(float("inf"))
     with profile_trace(args.profile_dir):
         out = engine.generate(
             [prompt],

@@ -29,7 +29,8 @@ import functools
 import math
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+import weakref
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,17 @@ from .session import Session, SessionState
 
 # ``_region`` with the flight recorder off: one shared, re-entrant no-op.
 _NO_REGION = contextlib.nullcontext()
+#: the step programs whose dispatches ``plan.note_dispatch`` records: what
+#: the dispatch clock wraps while it is armed (``_clock_turned``)
+_CLOCKED_PROGRAMS = (
+    "_prefill", "_prefill_ns", "_prefill_batch", "_prefill_batch_standalone",
+    "_decode", "_decode_k",
+)
+#: the spans under a traced session's ``engine.first_token``, in the order
+#: of ``DispatchClock.first_token``'s pieces
+_FIRST_TOKEN_SPANS = (
+    "engine.prefill_wait", "engine.prefill_own", "engine.first_token_deliver"
+)
 
 # Seconds without a resident session before ``_shrink_if_idle`` gives the
 # cache's high-water shape back. Every (old, new) shape on the way back up
@@ -193,6 +205,15 @@ class InferenceEngine:
             if trace_cfg is not None and trace_cfg.enabled
             else None
         )
+        # The recorder's dispatch clock (``_clocked``): enqueue, return and
+        # device-ready stamps on every noted dispatch while somebody reads
+        # the ticks (``clock.armed``: ``_clock_turned`` hears of it). None
+        # with the recorder: nothing to arm.
+        self._clock = self.flight.clock if self.flight is not None else None
+        self._unclocked: Dict[str, Any] = {}    # the programs, while wrapped
+        self._noted_rows: Sequence[Session] = ()
+        if self._clock is not None:
+            self._clock.on_turn = weakref.WeakMethod(self._clock_turned)
         # The gateway's span recorder (``EngineBackend.attach_tracer``): a
         # traced session's ``engine.queue`` / ``engine.first_token`` spans
         # go where the gateway's own do. None = no request spans.
@@ -615,7 +636,10 @@ class InferenceEngine:
             _, sub = llama.model_apply(
                 cfg, params, tokens, sub, n_valid[None], head="none", **mkw
             )
-            return cache.merge_row(sub, row)
+            # The tokens written, as a result of its own: what is ready
+            # when the chunk has run (the dispatch clock waits on it; the
+            # cache is donated to the next dispatch).
+            return n_valid + 0, cache.merge_row(sub, row)
 
         def _prefill_rows(params, tokens, cache, rows, n_valid, key, sp):
             """Batched admission: k sessions' prompts in ONE bucketed
@@ -1555,9 +1579,61 @@ class InferenceEngine:
     def _fetch(self, x):
         """``jax.device_get`` for the tick path: the time the drive thread
         waits here is the tick's ``blocked`` phase, measured at the sync
-        itself and taken out of whichever phase encloses it."""
+        itself and taken out of whichever phase encloses it. A clocked
+        dispatch whose result is among ``x`` is ready once this returns."""
         with self._region("blocked"):
-            return jax.device_get(x)
+            got = jax.device_get(x)
+        clk = self._clock
+        if clk is not None and clk.armed:
+            clk.fetched(x)
+        return got
+
+    def _clock_turned(self, armed: bool) -> None:
+        """The dispatch clock was armed or disarmed (the drive thread, at a
+        tick's start): while it is armed the attributes of the step programs
+        (``_CLOCKED_PROGRAMS``) hold ``_clocked`` around the program, and
+        otherwise the program itself. So every call site is written as if
+        there were no clock, and an engine nobody watches calls its programs
+        with the stack it had before there was one: a wrapper's frame on
+        the path to a program's first call moved the lowering's inner loops
+        across a boundary of CPython's frame stack and cost 0.33 s a prefill
+        program, 6 s of chat's set-up (PERF.md §6, PR 41)."""
+        self._noted_rows = ()
+        if armed and not self._unclocked:
+            for name in _CLOCKED_PROGRAMS:
+                fn = self._unclocked[name] = getattr(self, name)
+                wrapped = functools.partial(self._clocked, fn)
+                wrapped.__wrapped__ = fn
+                setattr(self, name, wrapped)
+        elif not armed:
+            while self._unclocked:
+                setattr(self, *self._unclocked.popitem())
+
+    def _clocked(self, fn, *args):
+        """Call the program of the dispatch just noted
+        (``plan.note_dispatch``) under the armed dispatch clock: stamps as
+        the drive thread enters the compiled call and as it returns, and the
+        call's first result (the tokens, a chunk's count; never the cache)
+        kept for the ready stamp: the clock's watcher waits on it, unless
+        the drive thread fetches it at once (a decode dispatch of the
+        synchronous tick: its ``_fetch`` is the stamp, and no watcher is
+        woken beside a drive thread whose host time is the device's idle
+        time). The sessions whose prompt a prefill-family dispatch carries
+        (``_note_prefill``) remember it."""
+        clk = self._clock
+        entry = clk.enter()
+        kind, shape = self.plan.last_dispatch[:2]
+        decode = kind == "decode"
+        rows, self._noted_rows = self._noted_rows, ()
+        if not decode:
+            for s in rows:
+                s.prompt_clock.append(entry)
+        out = fn(*args)
+        clk.leave(
+            entry, out[0], kind, shape[1] if decode else 1,
+            watched=self._pipelined or not decode,
+        )
+        return out
 
     def _live_positions(self, active, pending=None) -> int:
         """The census of a decode dispatch (``plan.note_dispatch``): the
@@ -1587,11 +1663,14 @@ class InferenceEngine:
             for slot in np.flatnonzero(active)
         ]
 
-    def _note_prefill(self, kind: str, shape, row_spans) -> None:
+    def _note_prefill(self, kind: str, shape, row_spans, rows) -> None:
         """The census of a prefill-family dispatch (``plan.note_dispatch``):
         ``row_spans`` holds a ``(first position, tokens)`` pair for every
         real row, which is what the ragged kernel sees as ``q_start`` and
-        ``num_new``; over a paged cache the table's width goes with it."""
+        ``num_new``; over a paged cache the table's width goes with it.
+        ``rows`` are the sessions whose prompt the dispatch carries, for
+        the dispatch clock (``_clocked``)."""
+        self._noted_rows = rows
         paged = self.ccfg.kind == "paged"
         self.plan.note_dispatch(
             kind, shape, sum(n for _, n in row_spans), row_spans=row_spans,
@@ -1610,11 +1689,43 @@ class InferenceEngine:
         if s.trace is not None and self.tracer is not None:
             self._request_span("engine.queue", s, s.submit_time, now)
 
-    def _request_span(self, name: str, s: Session, m0: float, m1: float):
+    def _note_first_token(self, s: Session) -> None:
+        """``engine_first_token_wait`` observes admission dispatch → now,
+        and under an armed dispatch clock its three pieces, which sum to it
+        (``DispatchClock.first_token``): the wait for the device, the
+        device's time on the session's own prompt dispatches, and the time
+        the token lay ready. A traced session records the same as its
+        ``engine.first_token`` span and, under it, one span a piece."""
+        wait = s.first_token_time - s.admit_time
+        self.metrics.observe("engine_first_token_wait", wait)
+        pieces = ()
+        clocked, s.prompt_clock = s.prompt_clock, []
+        clk = self._clock
+        # the pieces of a prompt that one lease of the clock saw whole
+        if clocked and clk.armed and clk.armed_at <= s.admit_time:
+            pieces = clk.first_token(clocked, wait)
+            self.metrics.observe("engine_first_token_prefill_wait", pieces[0])
+            self.metrics.observe("engine_first_token_prefill_own", pieces[1])
+            self.metrics.observe("engine_first_token_deliver", pieces[2])
+        if s.trace is None or self.tracer is None:
+            return
+        whole = self._request_span(
+            "engine.first_token", s, s.admit_time, s.first_token_time
+        )
+        t = s.admit_time
+        for name, seconds in zip(_FIRST_TOKEN_SPANS, pieces):
+            seconds = max(0.0, seconds)
+            self._request_span(name, s, t, t + seconds, parent=whole)
+            t += seconds
+
+    def _request_span(
+        self, name: str, s: Session, m0: float, m1: float, parent=None
+    ):
         """One span of a traced session between two ``time.monotonic()``
         readings, stamped on the epoch clock like every other span of the
-        request, as a child of the request's context."""
-        c = s.trace.child()
+        request, as a child of the request's context (or of ``parent``).
+        Returns the span's own context."""
+        c = (parent or s.trace).child()
         fr = self.flight
         self.tracer.record(Span(
             name, time.time() - (time.monotonic() - m0), m1 - m0,
@@ -1623,6 +1734,7 @@ class InferenceEngine:
             trace_id=c.trace_id, span_id=c.span_id, parent_id=c.parent_id,
             node="engine",
         ))
+        return c
 
     def has_work(self) -> bool:
         with self._lock:
@@ -2737,7 +2849,8 @@ class InferenceEngine:
             opts[i] = s.options
         sp = SamplingParams.stack(opts)
         self._note_prefill(
-            "prefill", (nr, width), [(0, int(n)) for n in n_valid[:k]]
+            "prefill", (nr, width), [(0, int(n)) for n in n_valid[:k]],
+            group,
         )
         for s in group:
             self._note_admitted(s)
@@ -2876,17 +2989,21 @@ class InferenceEngine:
             chunk = prompt[offset : offset + stride]
             padded = jnp.asarray(chunk)[None, :]
             self._window_step(s, offset, offset + stride)
-            self._note_prefill("chunk", (1, stride), [(offset, len(chunk))])
+            self._note_prefill(
+                "chunk", (1, stride), [(offset, len(chunk))], (s,)
+            )
             self.cache = self._prefill_ns(
                 self.params, padded, self.cache, s.slot, jnp.int32(len(chunk))
-            )
+            )[1]
             offset += stride
         rest = prompt[offset:]
         width = self.plan.final_shape(len(rest), chunk_cap)
         padded = np.zeros((1, width), np.int32)
         padded[0, : len(rest)] = rest
         self._window_step(s, offset, len(prompt))
-        self._note_prefill("prefill", (1, width), [(offset, len(rest))])
+        self._note_prefill(
+            "prefill", (1, width), [(offset, len(rest))], (s,)
+        )
         token, self.cache = self._prefill(
             self.params, jnp.asarray(padded), self.cache, s.slot,
             jnp.int32(len(rest)), self._next_key(), sp,
@@ -3008,12 +3125,12 @@ class InferenceEngine:
             if rest > stride:
                 chunk = prompt[s.chunk_off : s.chunk_off + stride]
                 self._note_prefill(
-                    "chunk", (1, stride), [(s.chunk_off, len(chunk))]
+                    "chunk", (1, stride), [(s.chunk_off, len(chunk))], (s,)
                 )
                 self.cache = self._prefill_ns(
                     self.params, jnp.asarray(chunk)[None, :],
                     self.cache, s.slot, jnp.int32(len(chunk)),
-                )
+                )[1]
                 s.chunk_off += stride
                 self.plan.note_chunk_rows()
                 continue
@@ -3023,7 +3140,9 @@ class InferenceEngine:
             sp = SamplingParams.create(
                 1, s.options.temperature, s.options.top_k, s.options.top_p
             )
-            self._note_prefill("prefill", (1, width), [(s.chunk_off, rest)])
+            self._note_prefill(
+                "prefill", (1, width), [(s.chunk_off, rest)], (s,)
+            )
             token, self.cache = self._prefill(
                 self.params, jnp.asarray(padded), self.cache, s.slot,
                 jnp.int32(rest), s.parked_key, sp,
@@ -3519,7 +3638,6 @@ class InferenceEngine:
         ), self._live_positions(active), int(active.sum()),
             query_spans=self._decode_spans(active, K))
         if K == 1:
-            self.metrics.counter("decode_one_token_ticks")
             next_tokens, self.cache = self._decode(
                 self.params, jnp.asarray(tokens), self.cache,
                 jnp.asarray(active), self._next_key(), sp,
@@ -4017,14 +4135,7 @@ class InferenceEngine:
         if first and s.admit_time is not None:
             # the host holds the session's first token: the other end of
             # its admission dispatch (``_note_admitted``)
-            self.metrics.observe(
-                "engine_first_token_wait", s.first_token_time - s.admit_time
-            )
-            if s.trace is not None and self.tracer is not None:
-                self._request_span(
-                    "engine.first_token", s, s.admit_time,
-                    s.first_token_time,
-                )
+            self._note_first_token(s)
         done_eos = token == s.options.eos_token_id
         done_len = len(s.generated) >= s.options.max_new_tokens
         if done_eos or done_len:

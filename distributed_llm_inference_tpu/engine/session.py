@@ -69,6 +69,10 @@ class Session:
     # When the prefill was dispatched (overlap path) — the admit-to-merge
     # latency observed at resolve time is ``resolve_t - prefill_dispatch_t``.
     prefill_dispatch_t: Optional[float] = None
+    # The dispatch clock's entries of the dispatches that carried the
+    # prompt (``engine._clocked``; empty without a flight recorder), until
+    # the first token cuts its wait by them.
+    prompt_clock: List[dict] = dataclasses.field(default_factory=list)
     # Admitted via engine.admit_prefilled (disaggregated serving): the
     # prompt's KV was prefilled on a remote pool and imported here, so TTFT
     # accounting splits into prefill-side (gateway-observed) and

@@ -14,6 +14,11 @@ path. One rule, called by every entry point
 Either way every executable is kept: JAX's default skips compiles under a
 second, which on the chip left a warm second process still compiling 113
 of its 128 executables (my chip run, PR 21).
+
+The same call starts the process's count of the programs it loads
+(``utils/tracing.py:PROGRAM_LOADS``, behind ``engine_program_loads`` and
+``engine_program_load_seconds``), so an entry point counts from before its
+first trace, the weights' and the set-up's programs included.
 """
 
 from __future__ import annotations
@@ -36,6 +41,9 @@ def enable_compile_cache() -> str:
     """Apply the rule above; returns the directory the cache lives in."""
     import jax
 
+    from .tracing import PROGRAM_LOADS
+
+    PROGRAM_LOADS.install()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")

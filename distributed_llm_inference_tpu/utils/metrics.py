@@ -114,11 +114,6 @@ METRICS = {
         "gauge", "Free pages of the full layers' pool beside a window pool"
     ),
     "decode_tokens": ("counter", "Tokens emitted by decode"),
-    # decode ticks of an engine whose decode_steps is 1 (a cache without a
-    # write-behind tail, or the operator's choice): a token a dispatch
-    "decode_one_token_ticks": (
-        "counter", "Decode ticks dispatched on the one-token path"
-    ),
     "cache_growths": ("counter", "KV cache reallocations"),
     # latent (MLA) KV compression (cache/latent.py)
     "kv_bytes_per_token": ("gauge", "Stored KV bytes per token, all layers"),
@@ -169,6 +164,44 @@ METRICS = {
     "engine_ticks": ("counter", "step() calls the flight recorder timed"),
     "engine_tick_seconds": ("counter", "Wall seconds of those ticks, outside included"),
     "engine_tick_*_seconds": ("counter", "The same by host phase (tracing.PHASES)"),
+    # the dispatch clock (utils/tracing.py DispatchClock; TraceConfig on and
+    # somebody reading the ticks: the clock's lease): counted as a noted
+    # dispatch's result is ready on the device, from its enqueue, return and
+    # ready stamps
+    "engine_clocked_ticks": (
+        "counter", "Ticks that ended with the dispatch clock armed"
+    ),
+    "engine_device_seconds_*": (
+        "counter", "Device seconds by kind of dispatch (prefill, chunk, decode)"
+    ),
+    "engine_dispatches_*": ("counter", "Noted dispatches by kind, as they became ready"),
+    "engine_decode_steps": ("counter", "Decode steps of those decode dispatches"),
+    "engine_device_idle_seconds": (
+        "counter", "Seconds the device had nothing of the engine's to run"
+    ),
+    "engine_device_idle_*_seconds": (
+        "counter", "The same by the host phase that held the gap"
+    ),
+    "engine_enqueue_seconds": (
+        "counter", "Drive-thread seconds inside noted dispatches' compiled calls"
+    ),
+    # a first token's wait, cut by the dispatches that carried its prompt
+    "engine_first_token_prefill_wait": (
+        "summary", "First-token wait while the device ran other work or none"
+    ),
+    "engine_first_token_prefill_own": (
+        "summary", "Device seconds of the request's own prompt dispatches"
+    ),
+    "engine_first_token_deliver": (
+        "summary", "Prompt's last dispatch ready to first token on the host"
+    ),
+    # programs the process loaded (utils/tracing.py ProgramLoads: JAX's own
+    # monitoring events), published at the end of each timed tick
+    "engine_program_loads": ("counter", "Programs compiled or read from the cache"),
+    "engine_program_load_seconds": (
+        "counter", "Seconds tracing, lowering and compiling or reading them"
+    ),
+    "engine_compile_cache_hits": ("counter", "Of those, persistent-cache reads"),
     # multi-tenant admission scheduler (sched/)
     "sched_admitted": ("counter", "Tickets admitted by the scheduler"),
     "sched_tenant_admit_*": ("counter", "Admitted tickets by tenant"),

@@ -26,16 +26,24 @@ knows of a trace:
   outside every region (as the tick's own clock has it), ``outside`` between
   two ticks.
 
+The engine's dispatch clock (``utils/tracing.py``: ``dispatch_clock`` in a
+tick's record, epoch nanoseconds) and the trace are joined by
+:func:`join_dispatches`: a tick's ``t0_ns`` and its ``engine_tick`` event
+are one instant on the two clocks, and device 0 runs the noted dispatches in
+the order the engine enqueued them.
+
 Times are nanoseconds; :func:`device_time_ps` alone speaks picoseconds.
 """
 
 from __future__ import annotations
 
+import bisect
 import collections
 import glob
 import os
 import re
-from typing import Dict, Iterable, List, Sequence, Tuple
+import statistics
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from .tracing import PHASES
 
@@ -46,6 +54,13 @@ TICK, REGION = "engine_tick", "engine."
 #: operations that only hold others (their events enclose their bodies')
 CONTAINERS = ("while", "conditional", "call")
 _OPCODE = re.compile(r"([a-z][a-z\-]*)\(")
+#: the programs of the dispatches the engine notes, as a module event's name
+#: holds them (``jit__decode_scan(<id>)``), with the dispatch's kind; the
+#: first that is found in a name decides
+NOTED_PROGRAMS = (
+    ("_prefill_row_nosample", "chunk"), ("_prefill_row", "prefill"),
+    ("_decode_scan", "decode"), ("_decode_step", "decode"),
+)
 
 Event = Tuple[str, int, int, dict]  # name, start_ns, duration_ns, stats
 Interval = Tuple[int, int]
@@ -160,6 +175,22 @@ def _line(plane: dict, name: str) -> Sequence[Event]:
     return ()
 
 
+def _device_planes(planes: Sequence[dict]) -> List[dict]:
+    """The device planes, by device number (not by file order)."""
+    return sorted(
+        (p for p in planes if DEVICE_PLANE.match(p["name"])),
+        key=lambda p: int(DEVICE_PLANE.match(p["name"]).group(1)),
+    )
+
+
+def _tick_starts(planes: Sequence[dict]) -> Dict[int, int]:
+    """``step_num`` (the tick's id) -> start of its ``engine_tick`` event."""
+    return {
+        e[3].get("step_num"): e[1] for p in planes if p["name"] == HOST_PLANE
+        for ln in p["lines"] for e in ln["events"] if e[0] == TICK
+    }
+
+
 def reduce_planes(planes: Sequence[dict]) -> dict:
     """Per device plane: the traced span, busy time as a union, idle time.
     Of device 0: every operation's summed duration and count (containers
@@ -171,11 +202,7 @@ def reduce_planes(planes: Sequence[dict]) -> dict:
     counts: collections.Counter = collections.Counter()
     modules: collections.Counter = collections.Counter()
     gaps0: List[Interval] = []
-    found = sorted(
-        (p for p in planes if DEVICE_PLANE.match(p["name"])),
-        key=lambda p: int(DEVICE_PLANE.match(p["name"]).group(1)),
-    )
-    for plane in found:
+    for plane in _device_planes(planes):
         events = _line(plane, OPS_LINE)
         if not events:
             continue
@@ -204,16 +231,137 @@ def reduce_planes(planes: Sequence[dict]) -> dict:
         host["outside"] = (
             segments[-1][1] - segments[0][0] - sum(host.values())
         )
-    ticks = sorted(
-        e[3].get("step_num") for p in planes if p["name"] == HOST_PLANE
-        for ln in p["lines"] for e in ln["events"] if e[0] == TICK
-    )
+    ticks = sorted(_tick_starts(planes))
     return {
         "devices": devices, "ops_ns": ops, "op_counts": counts,
         "modules_ns": modules,
         "idle_by_phase_ns": split_by_phase(gaps0, segments) if devices else {},
         "host_by_phase_ns": host, "ticks": ticks,
     }
+
+
+def module_kind(name: str) -> Optional[str]:
+    """The kind of noted dispatch a module event of that name ran, if any."""
+    for part, kind in NOTED_PROGRAMS:
+        if part in name:
+            return kind
+    return None
+
+
+def _idle_inside(busy: Sequence[Interval], starts: Sequence[int],
+                 lo: int, hi: int) -> List[Interval]:
+    """What of ``[lo, hi)`` the merged intervals ``busy`` (``starts``: their
+    starts) leave uncovered."""
+    out = []
+    i = max(0, bisect.bisect_right(starts, lo) - 1)
+    while i < len(busy) and busy[i][0] < hi and lo < hi:
+        if busy[i][0] > lo:
+            out.append((lo, busy[i][0]))
+        lo = max(lo, busy[i][1])
+        i += 1
+    if lo < hi:
+        out.append((lo, hi))
+    return out
+
+
+def join_dispatches(planes: Sequence[dict], ticks: Sequence[dict]) -> dict:
+    """The dispatch clock beside the device trace, dispatch by dispatch.
+
+    ``ticks`` are tick records (``/debug/ticks``) with ``dispatches`` and
+    ``dispatch_clock``. Device 0's module events of the noted programs, in
+    the order they ran, are matched to the ticks' noted dispatches, in the
+    order they were enqueued: the first event to the dispatch of its kind
+    whose ``ready_ns`` lies nearest the event's end (the clocks' offset is
+    the median of ``t0_ns`` minus the ``engine_tick`` event's start over the
+    ticks both hold), the rest in turn, anchored anew where the kinds part.
+    The first and the last such event are left out: the trace's edges cut
+    them (a 215 ms dispatch read 62 and 42 ms there: my chip run, PR 40).
+    Dispatches not yet ready when the ticks were polled are left out too.
+
+    Returns ``pairs`` (a dict a matched dispatch: ``tick``, ``index``,
+    ``kind``, ``trace_ms`` the module event's duration, ``clock_ms`` the
+    clock's ``device_ms``, and from the second pair on ``trace_idle_ms``, the
+    time since the previous matched event's end in which no operation ran,
+    beside ``clock_idle_ms``); ``kinds`` (a kind's ``n``, ``trace_ms``,
+    ``clock_ms`` summed and ``worst``, the pair where the two part most);
+    ``idle`` (``trace_ms`` / ``clock_ms`` over the matched span, and
+    ``by_phase``: a phase's ``[trace, clock]`` ms, the trace's by
+    :func:`split_by_phase`); ``unmatched`` module events and
+    ``offset_ns``."""
+    found = _device_planes(planes)
+    out = {"pairs": [], "kinds": {}, "unmatched": 0, "offset_ns": None,
+           "idle": {"trace_ms": 0.0, "clock_ms": 0.0, "by_phase": {}}}
+    if not found:
+        return out
+    ran = sorted(
+        (s, s + d, module_kind(name))
+        for name, s, d, _ in _line(found[0], MODULES_LINE)
+        if module_kind(name)
+    )[1:-1]
+    started = _tick_starts(planes)
+    both = [t for t in ticks if t["tick"] in started and "t0_ns" in t]
+    if not ran or not both:
+        return out
+    offset = out["offset_ns"] = int(statistics.median(
+        t["t0_ns"] - started[t["tick"]] for t in both
+    ))
+    noted = [
+        (t["tick"], i, d[0], c)
+        for t in sorted(ticks, key=lambda t: t["tick"])
+        for i, (d, c) in enumerate(
+            zip(t.get("dispatches", ()), t.get("dispatch_clock", ()))
+        )
+        if c.get("ready_ns") is not None
+    ]
+    busy = merged((s, s + d) for _, s, d, _ in _line(found[0], OPS_LINE))
+    busy_starts = [a for a, _ in busy]
+    by_phase = out["idle"]["by_phase"] = {p: [0.0, 0.0] for p in PHASES}
+    idle_gaps: List[Interval] = []
+    k = None            # the dispatch the next event should be
+    previous = None     # the previous event's end, if it matched in turn
+    for start, end, kind in ran:
+        if k is None or k >= len(noted) or noted[k][2] != kind:
+            near = [
+                (abs(c["ready_ns"] - (end + offset)), j)
+                for j, (_, _, kd, c) in enumerate(noted)
+                if kd == kind and (k is None or j >= k)
+            ]
+            if not near:
+                out["unmatched"] += 1
+                previous = None
+                continue
+            k, previous = min(near)[1], None
+        tick, index, _, c = noted[k]
+        pair = {
+            "tick": tick, "index": index, "kind": kind,
+            "trace_ms": (end - start) / 1e6, "clock_ms": c["device_ms"],
+        }
+        if previous is not None:
+            gaps = _idle_inside(busy, busy_starts, previous, start)
+            idle_gaps.extend(gaps)
+            pair["trace_idle_ms"] = sum(b - a for a, b in gaps) / 1e6
+            pair["clock_idle_ms"] = c["idle_ms"]
+            out["idle"]["trace_ms"] += pair["trace_idle_ms"]
+            out["idle"]["clock_ms"] += c["idle_ms"]
+            for phase, ms in c.get("idle_phase_ms", {}).items():
+                by_phase[phase][1] += ms
+        out["pairs"].append(pair)
+        previous, k = end, k + 1
+    for pair in out["pairs"]:
+        kind = out["kinds"].setdefault(pair["kind"], {
+            "n": 0, "trace_ms": 0.0, "clock_ms": 0.0, "worst": pair,
+        })
+        kind["n"] += 1
+        kind["trace_ms"] += pair["trace_ms"]
+        kind["clock_ms"] += pair["clock_ms"]
+        if abs(pair["clock_ms"] - pair["trace_ms"]) > abs(
+            kind["worst"]["clock_ms"] - kind["worst"]["trace_ms"]
+        ):
+            kind["worst"] = pair
+    # the trace's side: the same gaps, by the phase that holds each instant
+    for phase, ns in split_by_phase(idle_gaps, host_segments(planes)).items():
+        by_phase[phase][0] = ns / 1e6
+    return out
 
 
 def aggregate(path: str) -> dict:

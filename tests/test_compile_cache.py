@@ -57,6 +57,30 @@ def test_every_executable_is_kept(monkeypatch, restore_config, placed):
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
 
 
+def test_the_call_starts_the_count_of_the_programs_the_process_loads(
+    monkeypatch, restore_config
+):
+    """An entry point counts from before its first trace: the one listener
+    of ``utils/tracing.py:PROGRAM_LOADS`` is there after the call, once
+    however often it is called, and hears the next compile."""
+    from distributed_llm_inference_tpu.utils.tracing import PROGRAM_LOADS
+
+    registered = []
+    monkeypatch.setattr(
+        jax.monitoring, "register_event_duration_secs_listener",
+        registered.append,
+    )
+    monkeypatch.setattr(PROGRAM_LOADS, "_installed", False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    compile_cache.enable_compile_cache()
+    compile_cache.enable_compile_cache()
+    assert registered == [PROGRAM_LOADS._duration]
+    before = PROGRAM_LOADS.loads
+    registered[0]("/jax/core/compile/backend_compile_duration", 0.5,
+                  fun_name="jit_f")
+    assert PROGRAM_LOADS.loads == before + 1
+
+
 def test_checkout_path_is_fixed():
     """No pid, clock or temporary component: the directory is part of the
     cache key, so a path that moves never hits."""

@@ -527,7 +527,8 @@ def test_an_int8_latent_engine_decodes_sixteen_steps_a_dispatch(kernel):
     engine resolves ``decode_steps`` 16, pipelines its ticks and every decode
     dispatch is ``_decode_scan``'s ``(rows, 16, width)``; without it (and in
     float32) the cache says it has none and the engine keeps the one-token
-    path, counted by ``decode_one_token_ticks``."""
+    path: a watched engine's ``engine_decode_steps`` over
+    ``engine_dispatches_decode`` is the steps a dispatch."""
     from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
 
     cfg, engine = engine_for(
@@ -537,6 +538,7 @@ def test_an_int8_latent_engine_decodes_sixteen_steps_a_dispatch(kernel):
     assert engine.cache.has_tail is kernel
     assert engine.decode_steps == (16 if kernel else 1)
     assert engine._pipelined is kernel
+    engine.flight.clock.lease(600.0)  # watched: the dispatch clock counts
     out = engine.generate(
         [list(range(1, 12)), list(range(3, 30))],
         SamplingOptions(max_new_tokens=20),
@@ -544,8 +546,11 @@ def test_an_int8_latent_engine_decodes_sixteen_steps_a_dispatch(kernel):
     assert [len(o) for o in out] == [20, 20]
     steps = {d[1] for d in decode_dispatches(engine)}
     assert steps == ({16} if kernel else {1})
-    one_token = engine.metrics.get_counter("decode_one_token_ticks")
-    assert (one_token == 0) if kernel else (one_token >= 19)
+    dispatched = engine.metrics.get_counter("engine_dispatches_decode")
+    assert engine.metrics.get_counter("engine_decode_steps") == (
+        16 * dispatched if kernel else dispatched
+    )
+    assert dispatched >= (2 if kernel else 19)
     _, f32 = engine_for(kernel_conf())
     assert not f32.cache.has_tail and f32.decode_steps == 1
 

@@ -550,3 +550,106 @@ def test_http_tracing_disabled_no_header_404_and_parity():
         assert json.loads(r3.read())["ticks"] == []  # no flight ring
         c3.close()
         assert backend.engine.flight is None  # zero-cost disabled path
+
+
+# -- the dispatch clock -------------------------------------------------------
+
+
+KINDS = ("prefill", "chunk", "decode")  # of dispatch (``plan.note_dispatch``)
+
+
+def _clock_of(engine):
+    """Every noted dispatch of the engine's ticks beside its clock entry."""
+    out = []
+    for t in engine.flight.snapshot():
+        assert len(t["dispatch_clock"]) == len(t["dispatches"]), t
+        out.extend(zip(t["dispatches"], t["dispatch_clock"]))
+    return out
+
+
+def test_dispatch_clock_stamps_every_dispatch_and_cuts_the_first_token():
+    """A short and a chunked prompt through a tiny armed engine: every
+    noted dispatch has its three stamps in order once the engine drains,
+    the kinds' device seconds and the idle seconds are the span from the
+    first enqueue to the last ready stamp, each request's three pieces sum
+    to its first-token wait, and a traced request has them as spans under
+    ``engine.first_token``."""
+    eng = make_engine(trace_cfg=TraceConfig())
+    eng.tracer = SpanRecorder()
+    eng.flight.clock.lease(600.0)  # watched to the end, however slow the host
+    ctxs = [TraceContext.mint(), TraceContext.mint()]
+    opts = SamplingOptions(max_new_tokens=6)
+    eng.submit([1, 2, 3], opts, trace=ctxs[0])
+    eng.submit(list(range(1, 41)), opts, trace=ctxs[1])  # 32 + 8: a chunk
+    while eng.has_work():
+        eng.step()
+    noted = _clock_of(eng)
+    assert {d[0] for d, _ in noted} == {"prefill", "chunk", "decode"}
+    for _, c in noted:
+        assert c["enq_ns"] <= c["ret_ns"] <= c["ready_ns"], c
+        assert c["device_ms"] >= 0.0 and c["idle_ms"] >= 0.0
+        assert sum(c.get("idle_phase_ms", {}).values()) == pytest.approx(
+            c["idle_ms"], abs=1e-6
+        )
+    m = eng.metrics
+    kinds = {k: m.get_counter(f"engine_device_seconds_{k}") for k in KINDS}
+    idle = m.get_counter("engine_device_idle_seconds")
+    span = (noted[-1][1]["ready_ns"] - noted[0][1]["enq_ns"]) / 1e9
+    assert sum(kinds.values()) + idle == pytest.approx(span, abs=1e-3)
+    assert sum(
+        m.get_counter(f"engine_device_idle_{p}_seconds") for p in tracing.PHASES
+    ) == pytest.approx(idle, abs=1e-6)
+    for k in KINDS:
+        assert m.get_counter(f"engine_dispatches_{k}") == sum(
+            1 for d, _ in noted if d[0] == k
+        )
+    assert m.get_counter("engine_decode_steps") == sum(
+        d[1][1] for d, _ in noted if d[0] == "decode"
+    )
+    assert 0.0 <= m.get_counter("engine_enqueue_seconds") <= sum(
+        (c["ret_ns"] - c["enq_ns"]) / 1e9 for _, c in noted
+    ) + 1e-9
+    # a load inside a call is taken out of the enqueue seconds, and named
+    assert any("compile_ms" in c for _, c in noted)
+    assert any(t.get("compiled") for t in eng.flight.snapshot())
+    with m._lock:
+        waits = list(m._timings["engine_first_token_wait"])
+        pieces = [
+            list(m._timings[f"engine_first_token_{p}"])
+            for p in ("prefill_wait", "prefill_own", "deliver")
+        ]
+    assert len(waits) == 2
+    for i, wait in enumerate(waits):
+        assert sum(p[i] for p in pieces) == pytest.approx(wait, abs=1e-3)
+        assert pieces[1][i] > 0.0 and pieces[2][i] >= 0.0
+    for ctx in ctxs:
+        spans = {s.name: s for s in eng.tracer.spans_for(ctx.trace_id)}
+        first = spans["engine.first_token"]
+        for name in ("engine.prefill_wait", "engine.prefill_own",
+                     "engine.first_token_deliver"):
+            assert spans[name].parent_id == first.span_id, name
+            assert spans[name].args["tick"] == first.args["tick"]
+            assert spans[name].start_s >= first.start_s - 1e-3
+        assert sum(
+            spans[n].duration_s for n in spans if n not in
+            ("engine.queue", "engine.first_token")
+        ) == pytest.approx(first.duration_s, abs=2e-3)
+
+
+def test_without_a_trace_config_there_is_no_clock():
+    """``trace_cfg=None``: no watcher thread, no stamp, and ``/metrics`` has
+    none of the clock's names."""
+    before = {t.ident for t in threading.enumerate()}
+    eng = make_engine()
+    eng.generate([[1, 2, 3], list(range(1, 41))], SamplingOptions(max_new_tokens=4))
+    assert eng.flight is None and eng._clock is None
+    assert not [
+        t for t in threading.enumerate()
+        if t.ident not in before and t.name == "dispatch-clock"
+    ]
+    text = eng.metrics.prometheus()
+    for name in ("engine_device_", "engine_dispatches_", "engine_decode_steps",
+                 "engine_enqueue_seconds", "engine_first_token_prefill",
+                 "engine_first_token_deliver", "engine_program_load",
+                 "engine_compile_cache_hits"):
+        assert name not in text, name

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig, ModelConfig
+from distributed_llm_inference_tpu.engine import engine as eng_mod
 from distributed_llm_inference_tpu.engine.engine import InferenceEngine
 from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
 from distributed_llm_inference_tpu.models import llama
@@ -334,3 +335,347 @@ def test_engine_flight_recorder_gated_on_trace_config():
     for t in ticks:
         assert "occupancy" in t and "admitted" in t and "host_ms" in t
     assert any(t["occupancy"] > 0 for t in ticks)  # the session decoded
+
+
+# -- the dispatch clock's arithmetic, on made-up stamps ------------------------
+
+MS = 1_000_000  # the scripts below are in milliseconds
+KINDS = ("prefill", "chunk", "decode")  # of dispatch (``plan.note_dispatch``)
+
+# (at ms, action): "begin" / "end" a tick; "+phase" / "-" a region; "enter" /
+# "leave kind steps" a noted dispatch's call; "ready i" the i-th dispatch's
+# result; "load seconds" a program load reported inside the open call
+CLOCK_CASES = {
+    # the second call is entered while the first still runs: no idle, and
+    # the device seconds are the differences of the ready stamps
+    "back_to_back": dict(
+        script=[(0, "begin"), (0, "+dispatch"), (10, "enter"),
+                (20, "leave decode 4"), (30, "enter"), (40, "leave decode 4"),
+                (100, "ready 0"), (250, "ready 1"), (260, "-"), (300, "end")],
+        device={"decode": 0.240}, idle={}, enqueue=0.020,
+        dispatches={"decode": 2}, steps=8,
+    ),
+    # the device waited from 50 to 100: 20 under admit, 30 under dispatch
+    "a_gap_falls_to_the_phases_that_held_it": dict(
+        script=[(0, "begin"), (10, "enter"), (20, "leave prefill 1"),
+                (50, "ready 0"), (70, "+dispatch"), (100, "enter"),
+                (110, "leave decode 1"), (200, "ready 1"), (210, "-"),
+                (220, "end")],
+        device={"prefill": 0.040, "decode": 0.100},
+        idle={"admit": 0.020, "dispatch": 0.030}, enqueue=0.020,
+        dispatches={"prefill": 1, "decode": 1}, steps=1,
+    ),
+    # a gap over two ticks: the time between them is ``outside``
+    "a_gap_between_two_ticks_is_outside": dict(
+        script=[(0, "begin"), (10, "enter"), (20, "leave chunk 1"),
+                (50, "ready 0"), (60, "end"), (90, "begin"),
+                (95, "+dispatch"), (100, "enter"), (130, "leave decode 16"),
+                (400, "ready 1"), (410, "-"), (420, "end")],
+        device={"chunk": 0.040, "decode": 0.300},
+        idle={"admit": 0.015, "outside": 0.030, "dispatch": 0.005},
+        enqueue=0.040, dispatches={"chunk": 1, "decode": 1}, steps=16,
+    ),
+    # a program load inside the call is not the enqueue's time
+    "a_compile_inside_a_call_leaves_the_enqueue_seconds": dict(
+        script=[(0, "begin"), (10, "enter"), (1500, "load 1.4"),
+                (2010, "leave prefill 1"), (2100, "ready 0"), (2200, "end")],
+        device={"prefill": 2.090}, idle={}, enqueue=0.600,
+        dispatches={"prefill": 1}, steps=0, compile_ms=1400.0,
+    ),
+    # one ready event for two dispatches (the watcher was late): the time
+    # falls to the first, and nothing is lost
+    "a_late_ready_settles_what_was_enqueued_before_it": dict(
+        script=[(0, "begin"), (10, "enter"), (20, "leave chunk 1"),
+                (30, "enter"), (40, "leave prefill 1"), (300, "ready 1"),
+                (310, "end")],
+        device={"chunk": 0.290, "prefill": 0.0}, idle={}, enqueue=0.020,
+        dispatches={"chunk": 1, "prefill": 1}, steps=0,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CLOCK_CASES))
+def test_dispatch_clock_arithmetic(monkeypatch, case):
+    from distributed_llm_inference_tpu.utils.metrics import Metrics
+
+    want = CLOCK_CASES[case]
+    now = [0]
+    monkeypatch.setattr(tracing.time, "time_ns", lambda: now[0])
+    m = Metrics()
+    fr = tracing.FlightRecorder(capacity=8, metrics=m)
+    fr.snapshot()     # somebody watches: the first tick arms the clock
+    clock = fr.clock  # a ``leave`` without a result wakes no watcher
+    entries, regions = [], []
+    for at, action in want["script"]:
+        now[0] = at * MS
+        verb, *args = action.split()
+        if verb == "begin":
+            fr.begin()
+        elif verb == "end":
+            fr.end(kind="plain", dispatches=[])
+        elif verb.startswith("+"):
+            regions.append(fr.region(verb[1:]))
+            regions[-1].__enter__()
+        elif verb == "-":
+            regions.pop().__exit__(None, None, None)
+        elif verb == "enter":
+            entries.append(clock.enter())
+        elif verb == "leave":
+            clock.leave(entries[-1], None, args[0], int(args[1]))
+        elif verb == "ready":
+            clock.settle(entries[int(args[0])])
+        elif verb == "load":
+            fr._loaded("jit_step", float(args[0]))
+    for kind in KINDS:
+        assert m.get_counter(f"engine_device_seconds_{kind}") == pytest.approx(
+            want["device"].get(kind, 0.0)
+        ), kind
+        assert m.get_counter(f"engine_dispatches_{kind}") == (
+            want["dispatches"].get(kind, 0)
+        )
+    assert m.get_counter("engine_decode_steps") == want["steps"]
+    idle = m.get_counter("engine_device_idle_seconds")
+    assert idle == pytest.approx(sum(want["idle"].values()))
+    for phase in tracing.PHASES:
+        assert m.get_counter(
+            f"engine_device_idle_{phase}_seconds"
+        ) == pytest.approx(want["idle"].get(phase, 0.0)), phase
+    assert m.get_counter("engine_enqueue_seconds") == pytest.approx(
+        want["enqueue"]
+    )
+    # what the device ran and what it waited is the whole span
+    span = (entries[-1]["ready_ns"] - entries[0]["enq_ns"]) / 1e9
+    assert sum(want["device"].values()) + idle == pytest.approx(span)
+    assert all(e["enq_ns"] <= e["ret_ns"] <= e["ready_ns"] for e in entries)
+    # the stamps ride the tick's record, a load with its program's name
+    recorded = [c for t in fr.snapshot() for c in t["dispatch_clock"]]
+    assert recorded == entries
+    assert [c.get("compile_ms") for c in recorded if "compile_ms" in c] == (
+        [want["compile_ms"]] if "compile_ms" in want else []
+    )
+    if "compile_ms" in want:
+        assert fr.snapshot()[0]["compiled"] == [("jit_step", want["compile_ms"])]
+
+
+def test_program_loads_count_nested_reports_once(monkeypatch):
+    """JAX reports a trace inside a trace inside it: the seconds are the
+    union's, a load is a ``backend_compile_duration`` event, and the watcher
+    of the thread hears the program's whole load as its compile ends."""
+    loads = tracing.ProgramLoads()
+    now = [0.0]
+    monkeypatch.setattr(tracing.time, "time", lambda: now[0])
+    heard = []
+    loads.watch(lambda name, seconds: heard.append((name, seconds)))
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    for at, event, seconds in [
+        (100.0, trace, 2.0),            # 98..100, inside the next
+        (101.0, trace, 5.0),            # 96..101: 3 s of its own
+        (102.5, "/jax/core/compile/jaxpr_to_mlir_module_duration", 1.0),
+        (106.0, "/jax/compilation_cache/cache_retrieval_time_sec", 0.5),
+        (107.0, "/jax/core/compile/backend_compile_duration", 4.0),
+        (108.0, "/jax/some/other_duration", 9.0),
+    ]:
+        now[0] = at
+        loads._duration(event, seconds, fun_name="jit_step")
+    assert (loads.loads, loads.cache_hits) == (1, 1)
+    assert loads.seconds == pytest.approx(10.0)
+    assert heard == [("jit_step", pytest.approx(10.0))]
+    loads.unwatch()
+    now[0] = 120.0
+    loads._duration("/jax/core/compile/backend_compile_duration", 1.0)
+    assert loads.loads == 2 and len(heard) == 1
+
+
+def test_a_process_that_exits_with_the_watcher_busy_exits_cleanly():
+    """The watcher is a daemon thread that waits inside JAX: left there
+    while the interpreter finalizes it aborts the process (exit 134), which
+    a server would report as a failed run. The recorder's finalizer ends
+    and joins it at exit."""
+    import subprocess
+    import sys
+
+    code = (
+        "import jax.numpy as jnp\n"
+        "from distributed_llm_inference_tpu.utils import tracing\n"
+        "fr = tracing.FlightRecorder()\n"
+        "fr.snapshot()\n"
+        "fr.begin()\n"
+        "assert fr.clock.armed\n"
+        "x = jnp.zeros(())\n"
+        "for _ in range(200):\n"
+        "    fr.clock.leave(fr.clock.enter(), x + 1, 'decode', 1)\n"
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", code], timeout=120,
+        env={**__import__("os").environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+# -- the lease: the clock runs while somebody reads the ticks ------------------
+
+CLOCK_NAMES = (
+    "engine_clocked_ticks", "engine_device_", "engine_dispatches_",
+    "engine_decode_steps", "engine_enqueue_seconds",
+    "engine_first_token_prefill", "engine_first_token_deliver",
+)
+
+
+def _watchers():
+    return [t for t in threading.enumerate() if t.name == "dispatch-clock"]
+
+
+def _clock_lines(metrics):
+    """What ``/metrics`` says under the clock's names."""
+    return [
+        line for line in metrics.prometheus().splitlines()
+        if any(name in line for name in CLOCK_NAMES)
+    ]
+
+
+def _serve(engine, prompt=(1, 2, 3), new=5):
+    """One request to its end; the ids of the ticks that served it."""
+    first = engine.flight.tick
+    engine.submit(list(prompt), SamplingOptions(max_new_tokens=new))
+    while engine.has_work():
+        engine.step()
+    return range(first, engine.flight.tick)
+
+
+def _records(engine, ids):
+    """The ring's records of the ticks ``ids``, read without watching."""
+    with engine.flight._lock:
+        return [t for t in engine.flight._ring if t["tick"] in ids]
+
+
+def _wrapped(engine):
+    """The step programs that stand behind a ``_clocked`` wrapper."""
+    return [
+        name for name in eng_mod._CLOCKED_PROGRAMS
+        if not hasattr(getattr(engine, name), "lower")
+    ]
+
+
+def test_an_engine_nobody_watches_runs_no_dispatch_clock():
+    """``TraceConfig()`` alone arms nothing: ticks run and are recorded with
+    no wrapper around a step program (the call is the jitted program's
+    own), no watcher thread, no ``dispatch_clock`` field, none of the
+    clock's names on ``/metrics`` and no entry remembered by a session."""
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    assert not _watchers()
+    eng = small_engine(trace_cfg=TraceConfig())
+    ids = _serve(eng)
+    clock = eng.flight.clock
+    assert not clock.armed and not _watchers() and clock._thread is None
+    assert not _wrapped(eng) and not eng._unclocked
+    records = _records(eng, ids)
+    assert any(t["dispatches"] for t in records)
+    assert not any("dispatch_clock" in t for t in records)
+    assert not clock.entries and not clock._pending and not eng.flight._marks
+    text = eng.metrics.prometheus()
+    assert "engine_ticks" in text and "engine_first_token_wait" in text
+    assert not _clock_lines(eng.metrics)
+    # the count of the programs the process loads is not the clock's
+    assert eng.metrics.get_counter("engine_program_loads") > 0
+
+
+def test_a_read_of_the_ticks_arms_the_clock_for_a_lease():
+    """One ``snapshot()`` arms it: the next ticks carry the field and the
+    counters run; the lease's end stops the thread and the counters; a
+    second lease starts clean, with no idle gap across the stretch."""
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    eng = small_engine(trace_cfg=TraceConfig(), pipelined_ticks=True)
+    clock, m = eng.flight.clock, eng.metrics
+    _serve(eng)                                 # unarmed: loads the programs
+    before = time.time_ns()
+    eng.flight.snapshot()
+    assert before + 1e9 < clock.lease_ns <= time.time_ns() + (
+        tracing.CLOCK_LEASE_S * 1e9
+    )
+    assert not clock.armed                      # the drive thread's to do
+    clock.lease(600.0)                          # the test's own: no race
+    programs = {
+        name: getattr(eng, name) for name in eng_mod._CLOCKED_PROGRAMS
+    }
+    assert not _wrapped(eng)
+    ids = _serve(eng)
+    assert clock.armed
+    # while armed every step program stands behind the clock's wrapper
+    assert _wrapped(eng) == list(programs)
+    assert all(
+        getattr(eng, name).__wrapped__ is fn for name, fn in programs.items()
+    )
+    first = _records(eng, ids)
+    assert all("dispatch_clock" in t for t in first)
+    assert all(
+        len(t["dispatch_clock"]) == len(t["dispatches"]) for t in first
+    )
+    assert m.get_counter("engine_clocked_ticks") == len(first)
+    assert m.get_counter("engine_dispatches_decode") > 0
+    with m._lock:
+        assert len(m._timings["engine_first_token_prefill_own"]) == 1
+    # the lease runs out: the next tick disarms, the watcher ends
+    clock.lease_ns = 0.0
+    eng.step()
+    assert not clock.armed
+    assert all(getattr(eng, name) is fn for name, fn in programs.items())
+    deadline = time.monotonic() + 5.0
+    while _watchers() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert not _watchers()
+    counted = _clock_lines(m)
+    ids = _serve(eng)
+    assert not any("dispatch_clock" in t for t in _records(eng, ids))
+    assert counted and _clock_lines(m) == counted
+    # a second lease, two tenths of a second later: the device's idle time
+    # starts with it
+    time.sleep(0.2)
+    idle = m.get_counter("engine_device_idle_seconds")
+    clock.lease(600.0)
+    ids = _serve(eng)
+    second = [c for t in _records(eng, ids) for c in t["dispatch_clock"]]
+    assert second and second[0]["idle_ms"] == 0.0
+    assert m.get_counter("engine_device_idle_seconds") - idle < 0.2
+    assert len(_watchers()) <= 1
+    with m._lock:
+        assert len(m._timings["engine_first_token_prefill_own"]) == 2
+        assert len(m._timings["engine_first_token_wait"]) == 4
+    eng.flight.clock.stop()
+    assert not _watchers() and not clock.armed
+
+
+def test_a_session_admitted_before_the_arming_has_no_pieces():
+    """The clock saw the end of its prompt and not its admission: a wait is
+    observed and the pieces are not, and so for a session whose first token
+    comes after the lease's end."""
+    from distributed_llm_inference_tpu.config import TraceConfig
+    from distributed_llm_inference_tpu.engine.session import Session
+
+    eng = small_engine(trace_cfg=TraceConfig())
+    clock, m = eng.flight.clock, eng.metrics
+    clock.lease(600.0)
+    _serve(eng)
+    entry = next(
+        c for t in _records(eng, range(eng.flight.tick))
+        for c in t["dispatch_clock"]
+    )
+
+    def first_token(admitted_at):
+        s = Session([1, 2, 3], SamplingOptions(max_new_tokens=1))
+        s.admit_time, s.first_token_time = admitted_at, time.monotonic()
+        s.prompt_clock = [entry]
+        eng._note_first_token(s)
+        assert s.prompt_clock == []
+        with m._lock:
+            return (len(m._timings["engine_first_token_wait"]),
+                    len(m._timings["engine_first_token_prefill_own"]))
+
+    assert first_token(clock.armed_at + 0.001) == (2, 2)
+    assert first_token(clock.armed_at - 0.001) == (3, 2)
+    clock.lease_ns = 0.0
+    eng.step()
+    assert first_token(clock.armed_at + 0.001) == (4, 2)
+    clock.stop()

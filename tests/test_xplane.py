@@ -144,3 +144,92 @@ def test_a_cpu_trace_carries_the_ticks_and_phases_on_the_host_plane(tmp_path):
     text = "\n".join(xplane.describe(path, like=["engine."]))
     assert "'engine_tick'" in text and "step_num" in text
     assert "'engine.blocked'" in text
+
+
+def test_the_dispatch_clock_joins_the_trace_dispatch_by_dispatch():
+    """Made-up planes and ticks: the trace's clock starts 1000 ns before the
+    epoch's here (tick 7's ``t0_ns`` 1100 is its event's 100). Four noted
+    dispatches, the first of which the trace's start cut and the last of
+    which its end (both are left out): the join anchors the first whole
+    module event on the ready stamp nearest its end and takes the rest in
+    turn; a gap's idle is what no operation covers, and falls to the phases
+    that hold it on both sides."""
+    modules = [
+        ev("jit__decode_scan(12)", 100, 3),             # cut: it ran 145
+        ev("jit__prefill_row(11)", 105, 20),            # 105..125
+        ev("jit_convert_element_type(5)", 126, 2),      # not a noted program
+        ev("jit__decode_scan(12)", 140, 50),            # 140..190
+        ev("jit__prefill_row_nosample(13)", 262, 30),   # 262..292
+        ev("jit__prefill_row(11)", 293, 2),             # cut by the end
+    ]
+    ops = [
+        ev("%fusion.1 = f32[2]{0} fusion(%p)", 105, 20),
+        ev("%convert.2 = f32[2]{0} convert(%p)", 126, 2),
+        ev("%while.3 = (s32[]) while(%t)", 140, 50),
+        ev("%fusion.4 = f32[2]{0} fusion(%p)", 262, 30),
+    ]
+
+    def clock(enq, ready, device_ms, idle_ms=0.0, **by_phase):
+        entry = {"enq_ns": enq, "ret_ns": enq + 1, "ready_ns": ready,
+                 "device_ms": device_ms, "idle_ms": idle_ms}
+        if by_phase:
+            entry["idle_phase_ms"] = by_phase
+        return entry
+
+    ticks = [
+        {"tick": 6, "t0_ns": 900, "dispatches": [("decode", (2, 16, 4), 9)],
+         "dispatch_clock": [clock(905, 1050, 145e-6)]},
+        {"tick": 7, "t0_ns": 1100, "dispatches": [
+            ("prefill", (1, 8), 3), ("decode", (2, 16, 4), 9)],
+         "dispatch_clock": [
+             clock(1102, 1126, 24e-6),
+             clock(1138, 1191, 53e-6, 12e-6, admit=4e-6, dispatch=8e-6)]},
+        {"tick": 8, "t0_ns": 1260, "dispatches": [
+            ("chunk", (1, 8), 8), ("prefill", (1, 8), 2)],
+         "dispatch_clock": [
+             clock(1261, 1293, 32e-6, 70e-6, admit=11e-6, outside=59e-6),
+             clock(1270, None, None)]},     # not ready when polled
+    ]
+    out = xplane.join_dispatches([HOST, device(0, ops, modules)], ticks)
+    assert out["offset_ns"] == 1000 and out["unmatched"] == 0
+    assert [(p["tick"], p["index"], p["kind"]) for p in out["pairs"]] == [
+        (7, 0, "prefill"), (7, 1, "decode"), (8, 0, "chunk"),
+    ]
+    assert [p["trace_ms"] for p in out["pairs"]] == [20e-6, 50e-6, 30e-6]
+    assert [p["clock_ms"] for p in out["pairs"]] == [24e-6, 53e-6, 32e-6]
+    # gap 125..140 holds a 2 ns operation; gap 190..262 none
+    assert [p.get("trace_idle_ms") for p in out["pairs"]] == [
+        None, pytest.approx(13e-6), pytest.approx(72e-6)]
+    assert [p.get("clock_idle_ms") for p in out["pairs"]] == [None, 12e-6, 70e-6]
+    assert out["kinds"]["decode"] == {
+        "n": 1, "trace_ms": 50e-6, "clock_ms": 53e-6, "worst": out["pairs"][1],
+    }
+    assert out["kinds"]["prefill"]["worst"]["tick"] == 7
+    idle = out["idle"]
+    assert idle["trace_ms"] == pytest.approx(85e-6)
+    assert idle["clock_ms"] == pytest.approx(82e-6)
+    # 125..126 and 128..130 admit, 130..140 dispatch; 190..200 admit,
+    # 200..260 between two ticks, 260..262 admit
+    assert idle["by_phase"] == {
+        "admit": [pytest.approx(15e-6), pytest.approx(15e-6)],
+        "dispatch": [pytest.approx(10e-6), pytest.approx(8e-6)],
+        "blocked": [0.0, 0.0], "deliver": [0.0, 0.0],
+        "outside": [pytest.approx(60e-6), pytest.approx(59e-6)],
+    }
+    assert sum(t for t, _ in idle["by_phase"].values()) == pytest.approx(
+        idle["trace_ms"])
+    # no device plane, or ticks the trace does not hold: nothing to join
+    assert xplane.join_dispatches([HOST], ticks)["pairs"] == []
+    assert xplane.join_dispatches(
+        [HOST, device(0, ops, modules)], [{**ticks[0], "tick": 99}]
+    )["pairs"] == []
+    # what ``tools/xplane_profile.py --ticks`` prints of it
+    from tools import xplane_profile
+
+    lines = xplane_profile.clock_report(out, every=True)
+    assert lines[0].startswith("3 dispatches matched, 0 module events unmatched")
+    assert any(ln.startswith("decode") and "7.1" in ln for ln in lines)
+    assert lines[-1].startswith("8.0 chunk:")
+    assert xplane_profile.clock_report(
+        xplane.join_dispatches([HOST], ticks)
+    ) == ["no noted dispatch of these ticks ran in this trace"]

@@ -2,16 +2,21 @@
 
     python tools/tick_cost.py [ticks]
 
-Two readings, each the median of five rounds of ``ticks`` calls:
+Four readings, each the median of five rounds of ``ticks`` calls:
 
 * ``step()`` of an engine whose slots are all idle (nothing queued, nothing
-  decoding: the tick is its bookkeeping alone), with ``TraceConfig()`` and
-  with ``trace_cfg=None`` — the difference is one tick's ``begin`` / ``end``,
-  its ``engine_tick`` step annotation, one ``admit`` region, the tick record
-  and the seven counters;
-* the recorder's calls of a FULL tick alone (``begin``, the four regions a
-  decoding tick opens, ``end`` with every field), since a tick that decodes
-  is bound by its device and hides a few microseconds.
+  decoding: the tick is its bookkeeping alone) with ``trace_cfg=None``; with
+  ``TraceConfig()`` and nobody reading the ticks (the dispatch clock unarmed:
+  one tick's ``begin`` / ``end``, its ``engine_tick`` step annotation, one
+  ``admit`` region, the tick record and the seven counters); and with the
+  clock armed (a lease held: the phase marks and ``engine_clocked_ticks``
+  besides);
+* the recorder's calls of a FULL tick alone, armed (``begin``, the four
+  regions a decoding tick opens, the dispatch clock's ``enter`` / ``leave`` /
+  ``settle`` of its one decode dispatch with the watcher thread running
+  beside it, ``end`` with every field), since a tick that decodes is bound by
+  its device and hides a few microseconds; and the same unarmed, so that the
+  difference is what an armed clock adds a tick.
 
 The model is tiny: this times the host, and says nothing of a device.
 """
@@ -61,13 +66,24 @@ def median_us(fn, ticks, rounds=5):
     return statistics.median(out)
 
 
+#: a dispatch's result, ready: the watcher thread wakes for it as it does
+#: for a pipelined tick's tokens
+READY = jnp.zeros((), jnp.int32)
+
+
 def full_tick(fr):
     fr.begin()
+    clocked = fr.clock.armed
     with fr.region("admit"):
         pass
     with fr.region("dispatch"):
+        if clocked:
+            entry = fr.clock.enter()
+            fr.clock.leave(entry, READY, "decode", 16)
         with fr.region("blocked"):
             pass
+        if clocked:
+            fr.clock.settle(entry)
         with fr.region("deliver"):
             pass
     fr.end(kind="plain", occupancy=32, queued=0, admitted=0, chunking=0,
@@ -78,17 +94,25 @@ def full_tick(fr):
 
 def main(argv):
     ticks = int(argv[0]) if argv else 5000
-    on, off = engine(TraceConfig()), engine(None)
-    enabled = median_us(on.step, ticks)
+    off, on = engine(None), engine(TraceConfig())
     disabled = median_us(off.step, ticks)
+    unarmed = median_us(on.step, ticks)
+    on.flight.clock.lease(3600.0)
+    armed = median_us(on.step, ticks)
     fr = FlightRecorder(512, Metrics())
-    recorder = median_us(lambda: full_tick(fr), ticks)
+    recorder_unarmed = median_us(lambda: full_tick(fr), ticks)
+    fr.clock.lease(3600.0)
+    recorder_armed = median_us(lambda: full_tick(fr), ticks)
+    on.flight.clock.stop()
+    fr.clock.stop()
     print({
         "platform": jax.devices()[0].platform, "ticks": ticks,
-        "idle_step_us_enabled": round(enabled, 2),
         "idle_step_us_disabled": round(disabled, 2),
-        "idle_step_us_tracing": round(enabled - disabled, 2),
-        "full_tick_recorder_calls_us": round(recorder, 2),
+        "idle_step_us_unarmed": round(unarmed, 2),
+        "idle_step_us_armed": round(armed, 2),
+        "full_tick_recorder_calls_us_unarmed": round(recorder_unarmed, 2),
+        "full_tick_recorder_calls_us_armed": round(recorder_armed, 2),
+        "full_tick_armed_clock_us": round(recorder_armed - recorder_unarmed, 2),
     })
 
 

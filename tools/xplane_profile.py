@@ -3,12 +3,24 @@
 host's while the device waited.
 
     python tools/xplane_profile.py <file.xplane.pb | trace dir> [--describe [word ...]]
+    python tools/xplane_profile.py <file.xplane.pb | trace dir> --ticks <ticks.json> [--all]
 
 ``--describe`` lists every plane and line with a few events and their stats
 instead, and every distinct event that holds one of the words: where a
 kernel's ``name=``, a ``jax.named_scope`` or the engine's ``engine_tick`` /
 ``engine.<phase>`` annotations landed.
+
+``--ticks`` joins the engine's dispatch clock to the trace
+(``xplane.join_dispatches``): ``ticks.json`` is a ``/debug/ticks`` body (or
+its list of records) polled while the trace ran. It prints, a kind of
+dispatch, the device seconds the clock counted beside the trace's module
+events for the same dispatches and the dispatch where the two part most, and
+the device's idle time inside the gaps, in all and by host phase, the clock's
+beside the trace's; ``--all`` adds a line a dispatch. Where the two part, the
+program's numbers (``engine_device_seconds_*``, ``engine_device_idle_*``) are
+not the device's: this is the tool that says so.
 """
+import json
 import os
 import sys
 
@@ -42,11 +54,60 @@ def report(agg, top=40):
     return out
 
 
+def clock_report(joined, every=False):
+    """The lines ``--ticks`` prints for one :func:`xplane.join_dispatches`
+    result."""
+    pairs = joined["pairs"]
+    if not pairs:
+        return ["no noted dispatch of these ticks ran in this trace"]
+    out = [
+        f"{len(pairs)} dispatches matched, {joined['unmatched']} module "
+        f"events unmatched; ticks {pairs[0]['tick']}..{pairs[-1]['tick']}; "
+        f"clock - trace {joined['offset_ns']} ns",
+        "kind        n   clock ms   trace ms  clock/trace   worst: "
+        "tick.index clock | trace ms",
+    ]
+    for kind, k in sorted(joined["kinds"].items()):
+        w = k["worst"]
+        out.append(
+            f"{kind:<8}{k['n']:>5}{k['clock_ms']:>11.3f}{k['trace_ms']:>11.3f}"
+            f"{k['clock_ms'] / k['trace_ms']:>13.4f}   "
+            f"{w['tick']}.{w['index']} {w['clock_ms']:.3f} | {w['trace_ms']:.3f}"
+        )
+    idle = joined["idle"]
+    ticks = max(1, pairs[-1]["tick"] - pairs[0]["tick"])
+    out.append(
+        f"idle in the gaps: clock {idle['clock_ms']:.3f} ms, trace "
+        f"{idle['trace_ms']:.3f} ms; by phase, ms (a tick): clock | trace"
+    )
+    for phase, (trace_ms, clock_ms) in idle["by_phase"].items():
+        out.append(
+            f"  {phase:<9}{clock_ms:>10.3f} ({clock_ms / ticks:.3f}) |"
+            f"{trace_ms:>10.3f} ({trace_ms / ticks:.3f})"
+        )
+    if every:
+        out.append("tick.index kind: device clock | trace ms; idle before it clock | trace ms")
+        for p in pairs:
+            line = (f"{p['tick']}.{p['index']} {p['kind']}: "
+                    f"{p['clock_ms']:.3f} | {p['trace_ms']:.3f}")
+            if "trace_idle_ms" in p:
+                line += f"; {p['clock_idle_ms']:.3f} | {p['trace_idle_ms']:.3f}"
+            out.append(line)
+    return out
+
+
 def main(argv):
     path = argv[0]
     if os.path.isdir(path):
         path = xplane.find_xplane(path)
-    if "--describe" in argv[1:]:
+    if "--ticks" in argv[1:]:
+        with open(argv[argv.index("--ticks") + 1]) as f:
+            ticks = json.load(f)
+        if isinstance(ticks, dict):
+            ticks = ticks["ticks"]
+        joined = xplane.join_dispatches(xplane.read_planes(path), ticks)
+        print("\n".join(clock_report(joined, every="--all" in argv[1:])))
+    elif "--describe" in argv[1:]:
         words = [a for a in argv[1:] if a != "--describe"]
         print("\n".join(xplane.describe(path, like=words)))
     else:
