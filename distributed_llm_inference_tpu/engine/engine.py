@@ -742,6 +742,17 @@ class InferenceEngine:
             else (16 if tail_capable else 1)
         )
         K = self.decode_steps
+        if (
+            tail_capable and K > 1
+            and isinstance(self.cache, QuantizedPagedKVCache)
+            and self.cache.use_kernel
+        ):
+            # the fused scan decodes through the in-place sweep by copies
+            # wherever the table is wide enough: the plan counts its pages
+            _, _, pool_heads, _, pool_width = self.cache.k_pages.shape
+            self.plan.sweep_pool = (
+                pool_heads, pool_width, type(self.cache).INPLACE_CTX
+            )
 
         def _decode_scan(params, tokens, cache, active, key, sp, eos_ids, budget):
             """``K`` fused decode steps in one dispatch: sampling, EOS stops,
@@ -1650,9 +1661,12 @@ class InferenceEngine:
     def _decode_spans(self, active, steps: int, pending=None):
         """A decode dispatch's queries as ``(first position, queries)``
         pairs, a pair an active row, for the census of what its queries see
-        (``plan.note_dispatch``: a selection's keys, a window's); None where
-        every layer sees every key."""
-        if self.plan.sparse_topk is None and not self.plan.windowed:
+        (``plan.note_dispatch``: a selection's keys, a window's, the pages
+        a paged pool's sweep attends); None where nothing counts them."""
+        if (
+            self.plan.sparse_topk is None and not self.plan.windowed
+            and self.ccfg.kind != "paged"
+        ):
             return None
         return [
             (

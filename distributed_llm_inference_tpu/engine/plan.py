@@ -45,7 +45,10 @@ The plan centralizes that policy:
   under the ragged plan ``ragged_attn_tiles_live`` /
   ``ragged_attn_tiles_grid``: the tiles of the ragged kernel's grid that
   hold a live (query, key) pair, which it computes, over all it steps
-  through.
+  through; and over a paged cache ``decode_pages_live`` /
+  ``decode_pages_joint``: the pages a decode dispatch's rows hold inside
+  their windows, and those of them that fill whole tiles of the in-place
+  sweep (a block of pages is one tile, a row's last one padded).
 
 This is also the fusion point ROADMAP item 4 (batched spec verification)
 needs: a verify row is just one more ``num_new == k`` row class.
@@ -59,6 +62,7 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
+from ..ops.paged_attention import _live_pages, _pages_per_block
 from ..ops.ragged_attention import _tile_live
 
 __all__ = ["AttentionPlan", "KernelSelection", "PREFILL", "CHUNKED", "DECODE"]
@@ -160,6 +164,13 @@ class AttentionPlan:
         # (``ModelConfig.sparse``): note_dispatch keeps the census of the
         # selected and the live keys.
         self.sparse_topk: Optional[int] = None
+        # Set by the engine where the fused decode scan runs the in-place
+        # sweep by copies (``ops/paged_attention.py``: an int8 paged pool
+        # with the kernel): ``(kv heads, stored width, least table
+        # capacity)``, the capacity in positions at which a table takes
+        # that kernel. note_dispatch keeps the census of the pages it
+        # attends a block as one tile.
+        self.sweep_pool: Optional[Tuple[int, int, int]] = None
 
     @property
     def windowed(self) -> bool:
@@ -386,6 +397,10 @@ class AttentionPlan:
                 grid *= sum(n for _, n in self.attention_layers)
             self.metrics.counter("decode_live_positions", live)
             self.metrics.counter("decode_grid_positions", grid)
+            if paged and query_spans is not None:
+                live, joint = self._swept_pages(shape, query_spans)
+                self.metrics.counter("decode_pages_live", live)
+                self.metrics.counter("decode_pages_joint", joint)
         else:
             self.metrics.counter("prefill_valid_tokens", valid_tokens)
             self.metrics.counter("prefill_padded_tokens", shape[0] * shape[1])
@@ -414,6 +429,35 @@ class AttentionPlan:
             under = max(0, min(hi, k) - lo + 1)    # queries with <= k keys
             selected += (lo + lo + under - 1) * under // 2 + (n - under) * k
         return selected, live
+
+    def _swept_pages(self, shape, spans) -> Tuple[int, int]:
+        """(live, joint) pages of a decode dispatch of ``shape`` (rows,
+        steps, table width) whose active rows' queries span ``spans``: a
+        page a layer a step. Live is what a row's pool holds inside the
+        layer's window (the kernel's own :func:`_live_pages`: the pool's
+        length is the first query's position all through the dispatch, the
+        window moves with the query); joint, those of them that lie in full
+        blocks of :func:`_pages_per_block` pages, the tiles of the in-place
+        sweep (``sweep_pool``) that hold no padding: none where a block is
+        one page (the tile it always was) or another path decodes."""
+        _, steps, width = shape
+        page_size = self.ccfg.page_size
+        block = 0
+        if self.sweep_pool is not None and steps > 1:
+            heads, stored, least = self.sweep_pool
+            if width * page_size >= least:
+                block = _pages_per_block(width, heads, page_size, stored, steps)
+        spans = np.asarray(spans, np.int64).reshape(-1, 2)
+        start = spans[:, 0, None]
+        query = start + np.arange(steps)[None, :]
+        live = joint = 0
+        for window, layers in self.attention_layers:
+            lo, hi = _live_pages(start, query, page_size, width, window, np)
+            pages = np.broadcast_to(hi - lo, query.shape)
+            live += layers * int(pages.sum())
+            if block > 1:
+                joint += layers * int((pages // block * block).sum())
+        return live, joint
 
     def _ragged_tiles(self, shape, row_spans, table_width) -> Tuple[int, int]:
         """(live, all) tiles of the ragged kernel's grid for a dispatch of

@@ -31,8 +31,10 @@ Bandwidth properties:
   grid is over rows only, K and V stay in HBM, and a loop inside the kernel
   fetches (double-buffered async copies, the physical ids from the page
   table in SMEM) and attends to the pages that hold something the query
-  sees. A page past the row's length, or wholly before its sliding window,
-  costs nothing; an empty slot runs the tail tile alone;
+  sees, a block of live pages as ONE tile of the online softmax (both
+  forms: the per-head pools' copies and the latent pool's pipelined
+  blocks). A page past the row's length, or wholly before its sliding
+  window, costs nothing; an empty slot runs the tail tile alone;
 * MHA (``G == 1``) uses a VPU multiply-reduce for QK^T and PV — a 1-row MXU
   matmul per head wastes the systolic array; GQA (``G > 1``) uses
   ``Hkv``-batched ``dot_general``.
@@ -551,13 +553,35 @@ def quantized_latent_paged_fused_attention(
     )
 
 
-# VMEM the in-place kernel's page buffers may take: K and V of a block of
-# pages, twice (double buffering). The row's scale rows, its tail blocks, q
-# and the softmax scratch come on top (under a MiB at 8 kv heads of 128 and
-# a table 64 wide), and Mosaic's own temporaries for one page tile; a core
-# has 16 MiB scoped by default, and tests/test_chip_compile.py compiles the
+# VMEM the in-place kernel's page buffers and pipelined row operands may
+# take: K and V of a block of pages, twice (double buffering), beside the
+# row's scale rows and tail blocks (16 KiB a slot at 8 kv heads: a 64-wide
+# f32 row pads to a 128-lane tile, two planes, two buffers). It is not what
+# a v5e core holds (128 MiB; the call raises Mosaic's scoped limit to 100
+# MiB, and 32 MiB here compiled and ran at every cell's shapes): it is kept
+# at the 4 MiB it was because of what it decides: at 8 kv heads a table of
+# 175 slots and more takes 2 pages a block and one of 207 and more ONE, the
+# tile it always was. Those are the tables of stacks
+# that hold a kernel call a layer in every decode executable
+# (``k-exaone-236b-a23b.mixedlen``: 12 calls, 227 slots), and there a
+# 4-page tile halved the full layers' kernel time and grew the executables'
+# load by a half (39.9 -> 64.3 s, ``setup_s`` 73.7 -> 93.3 s; PERF.md §6,
+# PR 42), which no cell may pay. tests/test_chip_compile.py compiles the
 # cells' shapes.
 _SWEEP_VMEM_BUDGET = 4 * 2**20
+
+# The stored bytes (K and V, int8) of the sweep's tile, a block of pages
+# attended as one. Wider is cheaper a page only so far: with every page live
+# the chip timed a page at 0.36 / 0.27 / 0.24 us in tiles of 2 / 4 / 8 pages
+# at 8 kv heads of 128 (a page a tile: 0.54; its bytes take 0.17) and at
+# 0.29 / 0.19 / 0.14 at 4 heads, but a row's last tile is padded to the
+# width, so at ``mistral-7b.reason``'s 11-12 pages a row 4 and 8 tie (4.70
+# against 4.76 ms a 32-layer step), a quarter of the slots live 4 win (1.84
+# against 2.00) and so they do at a window layer's 3 pages a row (154 us a
+# call against 167; a page a tile: 169). And the tile is code in every
+# decode executable: its load grows with the tile's bytes (PERF.md §6,
+# PR 42). Half a MiB is 4 pages at 8 kv heads and 8 at 4.
+_SWEEP_TILE_BYTES = 512 * 2**10
 
 
 def _pages_by_grid(d):
@@ -575,10 +599,13 @@ def _pages_by_grid(d):
 
 def _pages_per_block(t, hkv, page_size, d, kt, planes=2):
     """Pages of K and V (``planes`` 2; 1 where one stored plane is both) one
-    block of the sweep fetches: the most (a power of two, no more than the
-    table is wide, and no more than 8: a block is then a megabyte in flight,
-    and the chip timed 2, 4 and 8 alike, PERF.md §6) whose double buffers
-    fit :data:`_SWEEP_VMEM_BUDGET` beside the row's scale rows and tail."""
+    block of the sweep fetches, which is also the width of its tile: the
+    most (a power of two, no more than the table is wide, no more than 8)
+    whose stored bytes fit :data:`_SWEEP_TILE_BYTES` and whose double
+    buffers fit :data:`_SWEEP_VMEM_BUDGET` beside the row's scale rows and
+    tail. At 8 kv heads of 128 that is 4 pages up to a table of 174 slots,
+    2 up to 206 and 1 past it; 8 at 4 heads (up to 182 slots) and for the
+    latent pool's one 576-wide plane."""
     lanes = -(-d // 128) * 128
     heads = -(-hkv // 8) * 8
     page = planes * hkv * -(-page_size // 32) * 32 * lanes     # K + V, int8
@@ -588,22 +615,24 @@ def _pages_per_block(t, hkv, page_size, d, kt, planes=2):
     n = 1
     while (
         2 * n <= min(t, 8)
+        and 2 * n * page <= _SWEEP_TILE_BYTES
         and 2 * (2 * n) * page + row <= _SWEEP_VMEM_BUDGET
     ):
         n *= 2
     return n
 
 
-def _live_pages(kv_len, qpos, page_size, width, sliding_window):
+def _live_pages(kv_len, qpos, page_size, width, sliding_window, xp=jnp):
     """A row's live pages ``[lo, hi)``: what lies past its length, or wholly
     before its window, is never fetched and never computed. Both ends are
     held inside the table: a length is the caller's word, and a page id read
-    past the table's end would be the source of a DMA."""
-    hi = jnp.clip((kv_len + page_size - 1) // page_size, 0, width)
+    past the table's end would be the source of a DMA. ``xp=numpy`` counts
+    on the host what the kernel sweeps (``engine/plan.py``)."""
+    hi = xp.clip((kv_len + page_size - 1) // page_size, 0, width)
     if sliding_window is None:
         return 0, hi
-    return jnp.minimum(
-        jnp.maximum(qpos - sliding_window + 1, 0) // page_size, hi
+    return xp.minimum(
+        xp.maximum(qpos - sliding_window + 1, 0) // page_size, hi
     ), hi
 
 
@@ -660,9 +689,27 @@ def quantized_paged_fused_attention(
     computed, and a slot that is not decoding (``tail_valid_len`` 0: the
     engine leaves a released row's length stale until its next admission)
     runs the tail tile alone, whatever its length says: a call costs what
-    the live tokens cost, not slots x table width. Skipping a dead page
-    changes no sum (it was an exact no-op of the online softmax: alpha 1,
-    p 0), so a decoding row's results are the whole-grid walk's, bit for bit.
+    the live tokens cost, not slots x table width.
+
+    **A block of live pages is ONE tile.** A block's pages land side by
+    side in its buffer (``[Hkv, n, PS, D]``, which is ``[Hkv, n * PS, D]``
+    as it lies), their scale rows (and a selection's) are put side by side,
+    and the block is one tile of the online softmax: the scores of ``n *
+    PS`` positions, one masked maximum, exponent and sum, one PV product,
+    one update of the running state, where a page a tile chained ``n`` of
+    each (0.54 us a live page against 0.27, PERF.md §6, PR 42). A row's
+    last block may hold fewer than ``n`` live pages: it is the same tile,
+    its other places (whatever the buffer held, under the scale rows of
+    whatever the table names there) masked as positions and their V scales
+    SELECTED away, since ``p * scale`` with ``p == 0`` and a NaN scale is
+    NaN. :func:`_pages_per_block` keeps the tile narrow enough (half a MiB
+    stored) that a short row's padding costs less than its pages' chain
+    did: a window layer's 3 pages in a tile of 4 take 154 us a call where
+    three tiles took 169. The same pages are read once, in the same bf16
+    operands into float32 sums; what differs from a page a tile is the
+    ORDER of the float32 sums (one maximum over a block where ``n`` were
+    chained), so a row's results are the whole-grid walk's to float32
+    rounding, not bit for bit. Skipping a dead page changes no sum.
 
     **Where Mosaic cannot copy a page** (:func:`_pages_by_grid`: a stored
     row that is not whole 128-lane tiles, the latent pool's 576) the same
@@ -784,7 +831,7 @@ def quantized_paged_fused_attention(
     else:
         pool_specs = [pl.BlockSpec(memory_space=pl.ANY)]
         page_bufs = [
-            *[pltpu.VMEM((2, n, hkv, page_size, d), pool_k.dtype)] * planes,
+            *[pltpu.VMEM((2, hkv, n, page_size, d), pool_k.dtype)] * planes,
             pltpu.SemaphoreType.DMA((2, n)),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -893,10 +940,10 @@ def _qpaged_fused_kernel(
     * ``selected``: the row's selection, by table slot ``[1, T, 1, PS]`` and
       by tail slot ``[1, 1, KT]`` f32 (positive = attend);
     * ``out_ref`` ``[1, Hkv, G, D]``, then the aliased tail outputs;
-    * scratch: unless ``by_grid``, a plane's VMEM ``[2, N, Hkv, PS, D]`` int8
-      (two blocks of N pages) and DMA semaphores ``[2, N]`` (one a page
-      buffer, its planes together); ``acc`` ``[Hkv*G, D]``, ``m`` and ``l``
-      ``[Hkv*G, 128]`` f32.
+    * scratch: unless ``by_grid``, a plane's VMEM ``[2, Hkv, N, PS, D]`` int8
+      (two blocks of N pages, a head's pages side by side) and DMA
+      semaphores ``[2, N]`` (one a page buffer, its planes together);
+      ``acc`` ``[Hkv*G, D]``, ``m`` and ``l`` ``[Hkv*G, 128]`` f32.
     """
     refs = list(refs)
     n = pages_per_block
@@ -1058,12 +1105,13 @@ def _qpaged_fused_kernel(
 
     hbms = pool[0::per]
     num_blocks = (hi - lo + n - 1) // n
+    last = table_ref.shape[1] - 1
 
     def _page_copies(slot, i, page):
         phys = table_ref[b, page]
         return [
             pltpu.make_async_copy(
-                hbm.at[layer, phys], buf.at[slot, i], sems.at[slot, i]
+                hbm.at[layer, phys], buf.at[slot, :, i], sems.at[slot, i]
             )
             for hbm, buf in zip(hbms, bufs)
         ]
@@ -1094,25 +1142,51 @@ def _qpaged_fused_kernel(
     _init()
 
     def _block(blk, carry):
+        """A block of the row's live pages as ONE tile of the online
+        softmax: its pages lie side by side in the buffer (``[Hkv, n, PS,
+        D]`` is the bytes of ``[Hkv, n * PS, D]``), their scale rows (and a
+        selection's) are put side by side, and the positions' mask, the
+        window's term in it, covers them all. The row's last block may
+        hold fewer than ``n`` live pages: the places past them keep what
+        the buffer held (int8: finite) under scale rows of whatever the
+        table names there, so their positions are masked and their V scales
+        SELECTED away (``p * scale`` with ``p == 0`` and a NaN scale is
+        NaN)."""
         slot = blk % 2
 
         @pl.when(blk + 1 < num_blocks)
         def _prefetch():
             _start_block(blk + 1, 1 - slot)
 
-        def attend(i, page):
+        def wait(i, page):
             for copy in _page_copies(slot, i, page):
                 copy.wait()
-            valid = _valid(page, page_size)
-            if selected:
-                valid &= sel_pool[0, page] > 0
-            _tile(
-                [(buf[slot, i], rows[0, page])
-                 for buf, rows in zip(bufs, scale_rows)],
-                valid, page_size,
+
+        _block_pages(blk, wait)
+        first = lo + blk * n
+
+        def side_by_side(rows):  # [1, T, H, PS] by table slot -> [H, n * PS]
+            return jnp.concatenate(
+                [rows[0, jnp.minimum(first + i, last)] for i in range(n)], -1
             )
 
-        _block_pages(blk, attend)
+        width = n * page_size
+        valid = _valid(first, width)
+        if n > 1:  # (a block of one page is a live page: nothing is padded)
+            fetched = first * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (1, width), 1
+            ) < hi * page_size
+            valid &= fetched
+        if selected:
+            valid &= side_by_side(sel_pool) > 0
+        stored = [
+            (buf[slot].reshape(hkv, width, -1), side_by_side(rows))
+            for buf, rows in zip(bufs, scale_rows)
+        ]
+        if n > 1:
+            vv, vvs = stored[-1]
+            stored[-1] = (vv, jnp.where(fetched, vvs, 0.0))
+        _tile(stored, valid, width)
         return carry
 
     jax.lax.fori_loop(0, num_blocks, _block, 0)

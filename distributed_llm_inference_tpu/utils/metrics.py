@@ -67,6 +67,10 @@ METRICS = {
     "prefill_padded_tokens": ("counter", "Rows x pad width of the same dispatches"),
     "decode_live_positions": ("counter", "Context positions of active decode rows"),
     "decode_grid_positions": ("counter", "Rows x table width x page size walked"),
+    "decode_pages_live": (
+        "counter", "Pages decode rows hold inside their windows, a layer a step"),
+    "decode_pages_joint": (
+        "counter", "Of those, pages in full blocks of the in-place sweep (a tile, unpadded)"),
     # routed experts (ops/moe.py:expert_rows_per_token): needed / computed
     # over an interval is pad waste times the compute strategy's waste
     "moe_expert_rows_needed": (

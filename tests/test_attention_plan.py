@@ -371,10 +371,81 @@ def test_census_counters_on_a_two_row_engine(kind):
     else:
         width = eng.cache.max_len
     assert m.get_counter("decode_grid_positions") == 2 * width
+    # a paged pool's rows hold cdiv(4, 8) + cdiv(11, 8) pages before the
+    # step (one layer's, one step's); no in-place sweep runs here
+    assert m.get_counter("decode_pages_live") == (3 if kind == "paged" else 0)
+    assert m.get_counter("decode_pages_joint") == 0
     eng.step()  # both rows one token longer, the same grid again
     assert m.get_counter("decode_live_positions") == (4 + 11) + (5 + 12)
     assert m.get_counter("decode_grid_positions") == 2 * (2 * width)
     assert m.get_counter("prefill_padded_tokens") == 8 + 16  # no new prefill
+
+
+SWEPT = {
+    # layers as (window, count); sweep_pool; table width; page size
+    "one-kind": (((None, 1),), (8, 128, 768), 47, 64),
+    "under-the-capacity": (((None, 1),), (8, 128, 768), 11, 64),
+    "no-sweep": (((None, 1),), None, 47, 64),
+    "window-and-full": (((128, 9), (None, 3)), (8, 128, 768), 160, 64),
+    "a-page-a-block": (((128, 9), (None, 3)), (8, 128, 768), 227, 64),
+    "a-selection's-table": (((None, 1),), (4, 128, 0), 182, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SWEPT))
+def test_swept_pages_are_what_the_kernels_own_arithmetic_gives(case):
+    """``decode_pages_live`` / ``decode_pages_joint`` of a recorded decode
+    dispatch against ``ops/paged_attention.py``'s ``_live_pages`` and
+    ``_pages_per_block`` walked row by row, step by step: a row's pool
+    length is its first query's position all through the dispatch, a window
+    moves with the query, and a page is joint where it lies in a full block
+    of the row's live pages counted from the first."""
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        _live_pages, _pages_per_block,
+    )
+    from distributed_llm_inference_tpu.utils.metrics import Metrics
+
+    layers, pool, width, ps = SWEPT[case]
+    m, steps = Metrics(), 16
+    p = AttentionPlan(
+        EngineConfig(prefill_buckets=(8,)),
+        CacheConfig(kind="paged", page_size=ps), metrics=m,
+    )
+    p.attention_layers, p.sweep_pool = layers, pool
+    rng = np.random.default_rng(3)
+    block = 0
+    if pool is not None and width * ps >= pool[2]:
+        block = _pages_per_block(width, pool[0], ps, pool[1], steps)
+    n = block if block > 1 else 4
+    # exactly n, n + 1, 2n and 2n + 3 pages among the rows, one past the table
+    first = [n * ps - 1, n * ps, 2 * n * ps - 5, (2 * n + 3) * ps - 9, 1, 0,
+             (width + 2) * ps, *rng.integers(1, width * ps, 9).tolist()]
+    spans = [(int(f), steps) for f in first]
+    p.note_dispatch("decode", (32, steps, width), sum(first) + len(first),
+                    16, query_spans=spans)
+    live = joint = 0
+    for window, count in layers:
+        for start in first:
+            for step in range(steps):
+                lo, hi = _live_pages(start, start + step, ps, width, window, np)
+                live += count * int(hi - lo)
+                if block > 1:
+                    joint += count * (int(hi - lo) // block * block)
+    assert live > 0 and m.get_counter("decode_pages_live") == live
+    assert m.get_counter("decode_pages_joint") == joint
+    assert (joint > 0) == (case in ("one-kind", "window-and-full",
+                                    "a-selection's-table"))
+    if case == "window-and-full":
+        # a window of 128 is 3 pages of 64 at most: only full layers' are joint
+        assert block == 4 and joint % 3 == 0
+    if case == "a-page-a-block":
+        # so wide a table leaves the sweep the tile it always had: none joint
+        assert block == 1
+    if case == "a-selection's-table":
+        assert block == 8      # half the heads: a block of twice the pages
+    # a dispatch that does not say where its queries are counts neither
+    p.note_dispatch("decode", (32, steps, width), 100, 16)
+    assert m.get_counter("decode_pages_live") == live
 
 
 @pytest.mark.parametrize("block_q", [8, 16])

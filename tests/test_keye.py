@@ -213,6 +213,32 @@ def test_the_fused_scan_agrees_with_sixteen_one_token_steps(kernel):
     np.testing.assert_allclose(a, b, atol=0.05)
 
 
+@pytest.mark.parametrize("step", [0, 3], ids=["step0", "stepKT-1"])
+@pytest.mark.parametrize("g", [4, 1], ids=["G4", "G1"])
+def test_the_sweep_under_a_selection_attends_a_full_block_as_one_tile(g, step):
+    """The selection form of the fused in-place kernel
+    (``sparse_paged_fused_attention``) over the rows of
+    ``tests/test_paged_attention.py``: exactly ``n``, ``n + 1``, ``2n`` and
+    ``2n + 3`` live pages among them, so a full block's pages, scale rows
+    AND selection rows lie side by side in one tile; every page no live
+    token owns poisoned (NaN scale rows, +-127 values), a selection that
+    keeps about half of every row's positions, some rows' none."""
+    from test_paged_attention import _fused_inputs, _fused_oracle, _fused_rows
+
+    a = _fused_inputs(seed=11 + step, g=g, step=step, select=True)
+    out, *tails = pa.quantized_paged_fused_attention(
+        **a, name="sparse_paged_fused_attention"
+    )
+    ref, want = _fused_oracle(a, None)
+    out = np.asarray(out.astype(jnp.float32))
+    assert np.isfinite(out).all(), "a dead page was read"
+    _, active, _ = _fused_rows()
+    assert (out[~active] == 0).all()
+    np.testing.assert_allclose(out, np.asarray(ref), atol=0.03, rtol=0.02)
+    for got, exact in zip(tails, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exact))
+
+
 @pytest.mark.parametrize("pool", ["float32", "int8"])
 def test_topk_at_least_the_context_is_dense_causal_attention(pool):
     """With every position selected the result is the dense GQA path's on

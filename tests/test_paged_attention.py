@@ -258,24 +258,27 @@ def _fused_rows():
     INACTIVE row (nothing in the pool, nothing valid in the tail), an
     inactive row whose length is STALE (a released slot: the engine resets
     a row at its next admission; every page of it is poisoned, and the
-    kernel must not believe the length), and a row whose length is longer
+    kernel must not believe the length), a row whose length is longer
     than the table: the sweep stops at the table's end and reads no page
-    id past it."""
+    id past it; and rows of exactly ``n``, ``n + 1``, ``2n`` and ``2n + 3``
+    live pages (``n`` pages a block): a block is ONE tile, a row's last one
+    padded to ``n`` pages under the mask."""
     from distributed_llm_inference_tpu.ops.paged_attention import (
         _pages_per_block,
     )
 
     f = _FUSED
     n = _pages_per_block(f["t"], 2, f["ps"], f["d"], f["kt"])
-    assert 1 < n < f["t"] and f["t"] % n, "want partial blocks in this test"
+    assert 2 < n and 2 * n + 3 < f["t"] and f["t"] % n, "want partial blocks"
     ps = f["ps"]
     lengths = [0, 1, ps - 1, ps, ps + 1, (n + 3) * ps - 5, f["t"] * ps, 0,
-               3 * ps + 1, (f["t"] + 2) * ps + 3]
-    active = [True] * 7 + [False, False, True]
+               3 * ps + 1, (f["t"] + 2) * ps + 3,
+               n * ps, (n + 1) * ps - 3, 2 * n * ps, (2 * n + 3) * ps - 2]
+    active = [True] * 7 + [False, False] + [True] * 5
     return np.asarray(lengths, np.int32), np.asarray(active), n
 
 
-def _fused_inputs(seed, g, step):
+def _fused_inputs(seed, g, step, window=None, select=False):
     f = _FUSED
     ps, t, kt, d, layers = f["ps"], f["t"], f["kt"], f["d"], f["layers"]
     hkv = 2
@@ -289,11 +292,17 @@ def _fused_inputs(seed, g, step):
               for _ in range(2)]
     # A shuffled, non-contiguous table: slot order is not pool order.
     table = (rng.permutation(pages - 1)[: b * t] + 1).reshape(b, t).astype(np.int32)
-    # Poison what no live token owns: the null page and every page past a
-    # row's length. A read of one shows as NaN (or as a gross error).
+    # Poison what no live token owns: the null page, every page past a
+    # row's length and every page wholly before its window. A read of one
+    # shows as NaN (or as a gross error).
     live_pages = np.where(active, np.minimum(-(-lengths // ps), t), 0)
-    dead = [0] + [int(table[r, s]) for r in range(b)
-                  for s in range(int(live_pages[r]), t)]
+    first_page = np.zeros(b, np.int64)
+    if window is not None:
+        first_page = np.minimum(
+            np.maximum(lengths + step - window + 1, 0) // ps, live_pages
+        )
+    dead = [0] + [int(table[r, s]) for r in range(b) for s in range(t)
+                  if not first_page[r] <= s < live_pages[r]]
     for plane in pool:
         plane[:, dead] = np.where(rng.random(plane[:, dead].shape) < 0.5, 127, -127)
     for plane in scales:
@@ -308,7 +317,14 @@ def _fused_inputs(seed, g, step):
     v_new = bf16(rng.normal(size=(b, 1, hkv, d)))
     tail_valid_len = np.where(active, step + 1, 0).astype(np.int32)
     q_positions = (lengths + step).astype(np.int32)
+    more = {}
+    if select:  # positive = attend: about half of every row's slots
+        more["select"] = (
+            jnp.asarray(rng.normal(size=(b, t, 1, ps)), jnp.float32),
+            jnp.asarray(rng.normal(size=(b, 1, kt)), jnp.float32),
+        )
     return dict(
+        **more,
         q=q, k_new=k_new, v_new=v_new,
         pool_k=jnp.asarray(pool[0]), pool_ks=jnp.asarray(scales[0]),
         pool_v=jnp.asarray(pool[1]), pool_vs=jnp.asarray(scales[1]),
@@ -359,6 +375,9 @@ def _fused_oracle(a, window):
         qp = a["q_positions"][:, None]
         pool_valid &= pos > qp - window
         tail_valid &= base_len[:, None] + tpos > qp - window
+    if "select" in a:
+        pool_valid &= a["select"][0].reshape(b, t * ps) > 0
+        tail_valid &= a["select"][1][:, 0] > 0
     valid = jnp.concatenate([pool_valid, tail_valid], axis=1)  # [B, T*PS+KT]
 
     def deq(pages, scales, tail, tscale):  # -> [B, Hkv, T*PS + KT, D] f32
@@ -381,21 +400,30 @@ def _fused_oracle(a, window):
     return out.reshape(b, 1, hkv * g, d), tails
 
 
+def _fused_windows():
+    """No window; one of 19 that cuts whole leading pages off the long rows
+    (and none off the short ones); one of ``n + 3`` pages and 3 positions,
+    under which the ``2n``- and ``2n + 3``-page rows keep ``n + 4`` live
+    pages from a first page that is no multiple of ``n``: the window cuts
+    into what would be a full block, and the blocks start at ``lo``."""
+    n = _fused_rows()[2]
+    return [None, 2 * _FUSED["ps"] + 3, (n + 3) * _FUSED["ps"] + 3]
+
+
 @pytest.mark.parametrize("step", [0, _FUSED["kt"] - 1], ids=["step0", "stepKT-1"])
 @pytest.mark.parametrize("g", [4, 1], ids=["G4", "G1"])
 @pytest.mark.parametrize(
-    "window", [None, 2 * _FUSED["ps"] + 3], ids=["nowindow", "window19"]
+    "window", _fused_windows(), ids=["nowindow", "window19", "window-cuts-a-block"]
 )
 def test_fused_inplace_kernel_matches_oracle(window, g, step):
     """``quantized_paged_fused_attention`` — the kernel every int8 paged
     engine decodes through past ``INPLACE_CTX`` — against the oracle, with
-    every page no live token owns poisoned. The window of 19 cuts whole
-    leading pages off the long rows (and none off the short ones)."""
+    every page no live token owns poisoned."""
     from distributed_llm_inference_tpu.ops.paged_attention import (
         quantized_paged_fused_attention,
     )
 
-    a = _fused_inputs(seed=7 + step, g=g, step=step)
+    a = _fused_inputs(seed=7 + step, g=g, step=step, window=window)
     out, tk, tks, tv, tvs = quantized_paged_fused_attention(
         **a, sliding_window=window
     )
@@ -409,3 +437,38 @@ def test_fused_inplace_kernel_matches_oracle(window, g, step):
     np.testing.assert_allclose(out, np.asarray(ref), atol=0.03, rtol=0.02)
     for got, want in zip((tk, tks, tv, tvs), tails):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("window", [None, 2 * _FUSED["ps"] + 3],
+                         ids=["nowindow", "window19"])
+def test_fused_inplace_kernel_a_page_a_block(window, monkeypatch):
+    """A table so wide that the budget leaves a block ONE page
+    (``k-exaone-236b-a23b.mixedlen``'s 227 slots): the tile the sweep always
+    had, no place of it padded, over the same poisoned rows."""
+    from distributed_llm_inference_tpu.ops import paged_attention as pa
+
+    a = _fused_inputs(seed=9, g=4, step=1, window=window)
+    _, active, _ = _fused_rows()
+    monkeypatch.setattr(pa, "_pages_per_block", lambda *a, **k: 1)
+    out, *tails = pa.quantized_paged_fused_attention(**a, sliding_window=window)
+    ref, want = _fused_oracle(a, window)
+    out = np.asarray(out.astype(jnp.float32))
+    assert np.isfinite(out).all(), "a dead page was read"
+    assert (out[~active] == 0).all()
+    np.testing.assert_allclose(out, np.asarray(ref), atol=0.03, rtol=0.02)
+    for got, exact in zip(tails, want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exact))
+
+
+def test_the_window_case_cuts_into_a_full_block():
+    """What the third window of :func:`_fused_windows` is there for, by the
+    kernel's own arithmetic: rows whose first live page is no multiple of
+    ``n`` and that still hold a full block and more."""
+    from distributed_llm_inference_tpu.ops.paged_attention import _live_pages
+
+    lengths, active, n = _fused_rows()
+    f, window = _FUSED, _fused_windows()[2]
+    lo, hi = _live_pages(lengths, lengths, f["ps"], f["t"], window, np)
+    cut = active & (lo % n != 0) & (hi - lo > n)
+    assert cut.sum() >= 2, (lo, hi)
+    assert any((hi - lo)[cut] % n), "and what is left is a tile padded to n"

@@ -1,8 +1,8 @@
 """Time the in-place paged decode kernel alone, on the chip, by occupancy.
 
     python tools/profile_paged_sweep.py [--rows 32] [--width 47] [--pages 1600]
-        [--window 4096] [--occupancy full reason chat]
-        [--form inplace|gathered|latent|latent-grid]
+        [--window 4096] [--occupancy full reason chat window3 select182]
+        [--form inplace|gathered|latent|latent-grid] [--blocks 4 8 16]
 
 ``quantized_paged_fused_attention`` at Mistral-7B widths (32 q / 8 kv heads
 of 128, 64-token pages, 32 layers, a 16-slot tail) over a seeded int8 pool,
@@ -14,7 +14,16 @@ live rows a quarter full (lengths spread about a mean of 657); ``chat`` 5% of
 the table's positions live, in a few rows, the other slots empty; a number is
 that share of every row's table, in per cent. An empty slot has nothing
 valid in its tail, which is what has the kernel skip it (the engine leaves a
-released slot's length stale; the kernel does not read it).
+released slot's length stale; the kernel does not read it). Two more bring
+their own shapes (``CASES``): ``window3`` a window layer's pool of
+``k-exaone-236b-a23b.mixedlen`` (32 rows of 1.5-6k tokens on a 227-slot
+table under a window of 128: 3 live pages a row) and ``select182``
+``keye-vl2-30b-a3b.longdoc``'s sweep (16 rows of 4-10k tokens, 4 kv heads, a
+182-slot table, 2048 positions a row selected at random).
+
+``--blocks``: the pages a block of the sweep (``_pages_per_block``) forced
+to each width in turn, a line a width with ``kernel_us_a_live_page``; left
+out, the kernel picks its own (``pages_a_block`` says which).
 
 ``--form gathered`` times what ``QuantizedPagedKVCache`` runs UNDER
 ``INPLACE_CTX`` instead: ``quantized_fused_decode_attention`` over every
@@ -52,6 +61,12 @@ from distributed_llm_inference_tpu.ops import quant_attention as qa
 from distributed_llm_inference_tpu.utils.xplane import aggregate, find_xplane
 
 PS, KT = 64, 16
+# occupancy -> what it overrides of the command line's shapes
+CASES = {
+    "window3": dict(rows=32, width=227, window=128, pages=512, layers=9),
+    "select182": dict(rows=16, width=182, window=0, pages=3072, hkv=4,
+                      layers=12, select=2048),
+}
 # form -> query heads, kv heads, stored width, layers, the kernel's trace name
 FORMS = {
     "inplace": (32, 8, 128, 32, "quantized_paged_fused_attention"),
@@ -70,6 +85,10 @@ def row_lengths(kind, rows, width, rng):
         return np.minimum(rng.integers(214, 1100, rows), cap).astype(np.int32)
     if kind == "reason1k":
         return np.minimum(rng.integers(1536, 3072, rows), cap).astype(np.int32)
+    if kind == "window3":
+        return np.minimum(rng.integers(1536, 6144, rows), cap).astype(np.int32)
+    if kind == "select182":
+        return np.minimum(rng.integers(4096, 10240, rows), cap).astype(np.int32)
     if kind == "chat":
         lens = np.zeros(rows, np.int32)
         live = max(1, rows // 4)
@@ -88,18 +107,35 @@ def main():
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--form", choices=tuple(FORMS), default="inplace")
+    ap.add_argument("--blocks", nargs="+", type=int, default=[0])
     args = ap.parse_args()
-    HQ, HKV, D, LAYERS, kernel = FORMS[args.form]
-    latent = args.form.startswith("latent")
     if jax.default_backend() != "tpu":
         sys.exit("a device time comes from a chip: no TPU here")
-    b, t = args.rows, args.width
+    pick = pa._pages_per_block
+    for kind in args.occupancy:
+        for block in args.blocks:
+            pa._pages_per_block = (
+                (lambda *a, **k: block) if block else pick
+            )
+            run_case(args, kind, pick)
+
+
+def run_case(args, kind, pick):
+    HQ, HKV, D, LAYERS, kernel = FORMS[args.form]
+    latent = args.form.startswith("latent")
+    case = CASES.get(kind, {})
+    if case and args.form != "inplace":
+        sys.exit(f"{kind} is a case of the in-place form")
+    b, t = case.get("rows", args.rows), case.get("width", args.width)
+    pages = case.get("pages", args.pages)
+    HKV, LAYERS = case.get("hkv", HKV), case.get("layers", LAYERS)
+    window = None if latent else case.get("window", args.window) or None
     key = jax.random.PRNGKey(args.seed)
     kk, kv, ks, kq = jax.random.split(key, 4)
 
     @jax.jit
     def make(kk, kv, ks):
-        shape = (LAYERS, args.pages, HKV, PS, D)
+        shape = (LAYERS, pages, HKV, PS, D)
         k = jax.random.randint(kk, shape, -127, 128, jnp.int8)
         v = jax.random.randint(kv, shape, -127, 128, jnp.int8)
         s = jax.random.uniform(ks, shape[:-1], jnp.float32, 0.01, 0.03)
@@ -108,7 +144,6 @@ def main():
     pool = make(kk, kv, ks)
     q = jax.random.normal(kq, (b, 1, HQ, D), jnp.bfloat16)
     new = jax.random.normal(kq, (b, 1, HKV, D), jnp.bfloat16)
-    window = None if latent else args.window or None
 
     @jax.jit
     def gather(pool, table):  # cache/paged.py tail_big_stacks, kernel order
@@ -119,7 +154,7 @@ def main():
         return tuple(g(p) for p in pool)
 
     @jax.jit
-    def step(pool, tails, table, lens, vlen):
+    def step(pool, tails, table, lens, vlen, select):
         def layer(i, carry):
             tails, acc = carry
             if args.form == "gathered":
@@ -142,6 +177,7 @@ def main():
                 out, *tails = pa.quantized_paged_fused_attention(
                     q, new, new, *pool, *tails, i, jnp.int32(3), table,
                     lens, vlen, lens + 3, sliding_window=window,
+                    **({} if select is None else {"select": select}),
                 )
             return tuple(tails), acc + out.astype(jnp.float32)
 
@@ -149,64 +185,77 @@ def main():
             0, LAYERS, layer, (tails, jnp.zeros(q.shape, jnp.float32))
         )
 
-    for kind in args.occupancy:
-        rng = np.random.default_rng(args.seed)
-        lens = row_lengths(kind, b, t, rng)
-        live = -(-lens // PS)
-        if live.sum() >= args.pages:
-            sys.exit(f"{kind}: {live.sum()} live pages, the pool has {args.pages}")
-        ids = rng.permutation(args.pages - 1)[: live.sum()] + 1
-        table = np.zeros((b, t), np.int32)
-        at = 0
+    rng = np.random.default_rng(args.seed)
+    lens = row_lengths(kind, b, t, rng)
+    hi = -(-lens // PS)
+    # the pages the sweep fetches: under a window, from the first that holds
+    # a position the query (3 past the pool's end) still sees
+    lo = np.minimum(np.maximum(lens + 3 - window + 1, 0) // PS, hi) if window else 0 * hi
+    live = hi - lo
+    if live.sum() >= pages:
+        sys.exit(f"{kind}: {live.sum()} live pages, the pool has {pages}")
+    ids = rng.permutation(pages - 1)[: live.sum()] + 1
+    table = np.zeros((b, t), np.int32)
+    at = 0
+    for r in range(b):
+        table[r, lo[r] : hi[r]] = ids[at : at + live[r]]
+        at += live[r]
+    vlen = np.where(lens > 0, 4, 0).astype(np.int32)
+    select = None
+    if "select" in case:  # positive where attended: topk a row, at random
+        chosen = np.zeros((b, t * PS), np.float32)
         for r in range(b):
-            table[r, : live[r]] = ids[at : at + live[r]]
-            at += live[r]
-        vlen = np.where(lens > 0, 4, 0).astype(np.int32)
-        tails = (
-            jnp.zeros((LAYERS, b, HKV, KT, D), jnp.int8),
-            jnp.zeros((LAYERS, b, HKV, KT), jnp.float32),
-        ) * (1 if latent else 2)
-        big = pool
-        if args.form == "gathered":
-            big = jax.block_until_ready(gather(pool, jnp.asarray(table)))
-        argv = (big, tails, jnp.asarray(table), jnp.asarray(lens),
-                jnp.asarray(vlen))
-        t0 = time.perf_counter()
-        tails, acc = step(*argv)
-        jax.block_until_ready(acc)
-        first_call_s = time.perf_counter() - t0  # trace, compile, run
-        gather_ms = 0.0
-        if args.form == "gathered":
-            with tempfile.TemporaryDirectory() as td:
-                with jax.profiler.trace(td):
-                    jax.block_until_ready(gather(pool, argv[2]))
-                found = aggregate(find_xplane(td))["devices"]
-                gather_ms = found[0]["busy_ns"] / 1e6 if found else 0.0
+            chosen[r, rng.permutation(lens[r])[: case["select"]]] = 1.0
+        select = (jnp.asarray(chosen.reshape(b, t, 1, PS)),
+                  jnp.ones((b, 1, KT), jnp.float32))
+    tails = (
+        jnp.zeros((LAYERS, b, HKV, KT, D), jnp.int8),
+        jnp.zeros((LAYERS, b, HKV, KT), jnp.float32),
+    ) * (1 if latent else 2)
+    big = pool
+    if args.form == "gathered":
+        big = jax.block_until_ready(gather(pool, jnp.asarray(table)))
+    argv = (big, tails, jnp.asarray(table), jnp.asarray(lens),
+            jnp.asarray(vlen), select)
+    t0 = time.perf_counter()
+    tails, acc = step(*argv)
+    jax.block_until_ready(acc)
+    first_call_s = time.perf_counter() - t0  # trace, compile, run
+    gather_ms = 0.0
+    if args.form == "gathered":
         with tempfile.TemporaryDirectory() as td:
             with jax.profiler.trace(td):
-                for _ in range(args.reps):
-                    tails, acc = step(big, tails, *argv[2:])
-                jax.block_until_ready(acc)
-            agg = aggregate(find_xplane(td))
-        ns = sum(v for k, v in agg["ops_ns"].items() if kernel in k)
-        calls = sum(v for k, v in agg["op_counts"].items() if kernel in k)
-        print(json.dumps({
-            "form": args.form, "gather_ms_a_window": round(gather_ms, 3),
-            "occupancy": kind, "rows": b, "width": t,
-            "live_positions_pct": round(100 * lens.sum() / (b * t * PS), 1),
-            "live_pages": int(live.sum()), "kernel_calls": calls,
-            "kernel_us_a_call": round(ns / max(calls, 1) / 1e3, 2),
-            "kernel_ms_a_step": round(ns / args.reps / 1e6, 3),
-            "kernel_us_a_live_page": round(
-                ns / args.reps / LAYERS / max(int(live.sum()), 1) / 1e3, 4
-            ),
-            "busy_ms_a_step": round(
-                agg["devices"][0]["busy_ns"] / args.reps / 1e6, 3
-            ) if agg["devices"] else 0.0,
-            "first_call_s": round(first_call_s, 2),
-            "checksum": float(jnp.sum(acc)),
-            "device": jax.devices()[0].device_kind,
-        }), flush=True)
+                jax.block_until_ready(gather(pool, argv[2]))
+            found = aggregate(find_xplane(td))["devices"]
+            gather_ms = found[0]["busy_ns"] / 1e6 if found else 0.0
+    with tempfile.TemporaryDirectory() as td:
+        with jax.profiler.trace(td):
+            for _ in range(args.reps):
+                tails, acc = step(big, tails, *argv[2:])
+            jax.block_until_ready(acc)
+        agg = aggregate(find_xplane(td))
+    ns = sum(v for k, v in agg["ops_ns"].items() if kernel in k)
+    calls = sum(v for k, v in agg["op_counts"].items() if kernel in k)
+    planes = 1 if latent else 2
+    print(json.dumps({
+        "form": args.form, "gather_ms_a_window": round(gather_ms, 3),
+        "occupancy": kind, "rows": b, "width": t,
+        "pages_a_block": pa._pages_per_block(t, HKV, PS, D, KT, planes),
+        "pages_a_block_own": pick(t, HKV, PS, D, KT, planes),
+        "live_positions_pct": round(100 * lens.sum() / (b * t * PS), 1),
+        "live_pages": int(live.sum()), "kernel_calls": calls,
+        "kernel_us_a_call": round(ns / max(calls, 1) / 1e3, 2),
+        "kernel_ms_a_step": round(ns / args.reps / 1e6, 3),
+        "kernel_us_a_live_page": round(
+            ns / args.reps / LAYERS / max(int(live.sum()), 1) / 1e3, 4
+        ),
+        "busy_ms_a_step": round(
+            agg["devices"][0]["busy_ns"] / args.reps / 1e6, 3
+        ) if agg["devices"] else 0.0,
+        "first_call_s": round(first_call_s, 2),
+        "checksum": float(jnp.sum(acc)),
+        "device": jax.devices()[0].device_kind,
+    }), flush=True)
 
 
 if __name__ == "__main__":
