@@ -48,15 +48,23 @@ latent planes.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from flax import struct
 
 from ..ops.attention import causal_mask
-from .paged import PagedKVCache
+from .paged import PagedKVCache, _IndexPlane, _row_index_keys
 
-__all__ = ["LatentPagedKVCache", "QuantizedLatentPagedKVCache"]
+__all__ = [
+    "LatentPagedKVCache",
+    "QuantizedLatentPagedKVCache",
+    "IndexedLatentPagedKVCache",
+    "IndexedQuantizedLatentPagedKVCache",
+    "indexed_latent_cache_class",
+]
 
 
 class LatentPagedKVCache(PagedKVCache):
@@ -449,3 +457,311 @@ class QuantizedLatentPagedKVCache(LatentPagedKVCache):
             k_pages=new_c, cs_pages=new_cs,
             lengths=self.lengths + tail_len,
         )
+
+
+# -- a learned selection INSIDE latent attention ---------------------------------
+#
+# A block with a latent AND a lightning indexer (``ModelConfig.sparse`` beside
+# ``ModelConfig.latent``) selects, for each query, ``topk`` of the stored
+# latents, and only some of its layers score: a "score" layer has an indexer,
+# writes its index key and chooses; a "reuse" layer has none and attends to
+# what the nearest scoring layer before it chose for the same query
+# (``ModelConfig.index_layers``). Two things follow for the cache.
+#
+# The index plane ``ik_pages [scoring layers, P, 1, PS, INDEX_DIM]`` has rows
+# for the scoring layers ONLY (3 of 9 in the benchmark's cut, 21 of 78 as
+# published), in the model's dtype for ``cache/paged.py:_IndexPlane``'s reason.
+# WHICH layers score is the class's (``SCORING``), as the index key's width
+# is: whoever builds "a cache like this one" from ``k_pages``' shape alone
+# (the benchmark's probe) gets the plane with the right rows.
+# :func:`indexed_latent_cache_class` makes the class once a (stored form,
+# width, scoring layers).
+#
+# The selection outlives the layer that made it. It rides the cache's own
+# per-layer state, which is what the model's scans carry: a segment of the
+# stack (``ModelConfig.segments``: runs of layers alike in MLP, attention AND
+# part in the selection) takes its view of the cache (:meth:`index_view`),
+# whose ``layer_stacks`` hold, beside the latent planes, the index plane (a
+# scoring segment's) and ONE row of selection ``sel`` that every layer of the
+# view reads or writes (:meth:`stack_rows` says which row of each stack a
+# layer owns). A scoring layer writes it, the reusing layers behind read it,
+# and :meth:`advance`, the end of a forward pass, drops it. In the fused
+# decode scan the same state is the tail's: the index tail beside the latent
+# tail, and the step's selection over pool positions and tail slots.
+
+
+class _LatentIndex(_IndexPlane):
+    """What the two indexed latent classes share. ``seg`` (static) is the
+    view's part in the selection and the offset from a layer's index to its
+    row of the index plane; None is the cache as the engine holds it, where
+    every layer is taken to score (a stack without ``index_layers``)."""
+
+    SCORING = None
+
+    @classmethod
+    def index_rows(cls, num_layers: int) -> int:
+        """Rows for the class's scoring layers (``create`` is the parent
+        mixin's: the pool's cache and a zeroed plane, whose ``dtype`` is the
+        index keys': the latent's stored form is the pool's own)."""
+        scoring = cls.SCORING or (True,) * num_layers
+        if len(scoring) != num_layers:
+            raise ValueError(
+                f"{cls.__name__} is a cache of {len(scoring)} layers, got "
+                f"num_layers={num_layers}"
+            )
+        return sum(scoring)
+
+    # -- the segment's view, and what the model's scans carry ---------------
+    def index_view(self, kind: str, delta: int, seq_len: Optional[int] = None):
+        """This cache for a segment whose layers all ``kind`` ("score" |
+        "reuse") and whose layer ``i`` owns row ``i + delta`` of the index
+        plane. ``seq_len``: the dispatch's queries a row, which sizes the
+        selection in flight where none is (None: the fused decode scan,
+        whose tail carries it)."""
+        sel = self.sel
+        if sel is None and seq_len is not None:
+            shape, dtype = self._sel_form(seq_len)
+            sel = jnp.zeros((1, *shape), dtype)
+        return self.replace(seg=(kind, int(delta)), sel=sel)
+
+    def _sel_form(self, s: int):
+        """Shape and dtype of a dispatch's selection as the attention takes
+        it: the ragged kernel's ``[B, T, S, PS]`` int8 (a (page, q-block)
+        tile a block), else the mask ``[B, S, T*PS]`` of the gather path."""
+        b, t = self.page_table.shape
+        if self.use_ragged and s > 1:
+            return (b, t, s, self.page_size), jnp.int8
+        return (b, s, t * self.page_size), jnp.bool_
+
+    @property
+    def _kind(self):
+        return None if self.seg is None else self.seg[0]
+
+    @property
+    def layer_stacks(self):
+        base = self.POOL.layer_stacks.fget(self)
+        if self._kind is None:
+            return (*base, self.ik_pages)
+        if self._kind == "score":
+            return (*base, self.ik_pages, self.sel)
+        return (*base, self.sel)
+
+    def stack_rows(self, idx):
+        """The row of each of :attr:`layer_stacks` that layer ``idx`` owns."""
+        lat = (idx,) * len(self.POOL.LAYER_FIELDS)
+        if self._kind is None:
+            return (*lat, idx)
+        if self._kind == "score":
+            return (*lat, idx + self.seg[1], 0)
+        return (*lat, 0)
+
+    def with_layer_stacks(self, *new):
+        n = len(self.POOL.LAYER_FIELDS)
+        more = dict(zip(
+            {None: ("ik_pages",), "score": ("ik_pages", "sel"),
+             "reuse": ("sel",)}[self._kind],
+            new[n:],
+        ))
+        return self.POOL.with_layer_stacks(self, *new[:n]).replace(
+            seg=None, **more
+        )
+
+    def advance(self, num_new):
+        return self.POOL.advance(self, num_new).replace(seg=None, sel=None)
+
+    def _reuses(self) -> None:
+        if self._kind != "reuse":
+            raise ValueError(
+                "a layer without an indexer attends to the selection of a "
+                "scoring layer before it: the model hands such a layer the "
+                "cache's index_view('reuse', ...)"
+            )
+
+    # -- attention ------------------------------------------------------------
+    def attend(self, layer_state, q, k_new, v_new, rope, q_pos, num_new,
+               sliding_window, attention_fn, scale=None, index=None):
+        """The parent's attention under a selection: the layer's own where
+        it has an indexer (``index``: its index key is written, the row's
+        index keys scored, ``topk`` chosen, and the choice left in the layer
+        state for the layers behind), else the one the state carries."""
+        n = len(self.POOL.LAYER_FIELDS)
+        b, s = q.shape[:2]
+        new_lat = self._scatter_latent(
+            tuple(layer_state[:n]), k_new, q_pos, num_new
+        )
+        rest = tuple(layer_state[n:])
+        if index is not None:
+            nik = self._scatter_index(rest[0], index.k, q_pos, num_new)
+            mask = self._select(index, nik, q_pos, num_new)
+            if self._sel_form(s)[1] == jnp.int8:
+                # [B, S, T*PS] -> a (page, q-block) tile a block
+                mask = mask.reshape(b, s, -1, self.page_size).transpose(
+                    0, 2, 1, 3
+                ).astype(jnp.int8)
+            sel, scope = mask, "sparse_attention"
+            rest = (nik,) if self._kind is None else (nik, sel)
+        else:
+            self._reuses()
+            sel, scope = rest[-1], "index_reuse/sparse_attention"
+        with jax.named_scope(scope):
+            if sel.dtype == jnp.int8:
+                from ..ops.ragged_attention import (
+                    quantized_latent_ragged_paged_attention,
+                )
+
+                out = quantized_latent_ragged_paged_attention(
+                    q, new_lat[0], new_lat[1], self.page_table,
+                    self.lengths + num_new, num_new, scale=scale, select=sel,
+                )
+            else:
+                # the exact form, the CPU's plan, a one-token step of an
+                # engine without the tail: the gather path under the mask
+                c_all = self._contiguous_view(new_lat, b, q.dtype)
+                seen = self._latent_mask(b, q_pos, num_new, sliding_window)
+                out = attention_fn(q, c_all, c_all, seen & sel, scale=scale)
+        return out, (*new_lat, *rest)
+
+
+class IndexedLatentPagedKVCache(_LatentIndex, LatentPagedKVCache):
+    """:class:`LatentPagedKVCache` under a learned selection. The
+    exact-arithmetic form (float32 tests, the int8 class's oracle):
+    attention is the gather path under the selection's mask, a token a
+    dispatch; the kernels and the write-behind tail are the int8 class's."""
+
+    ik_pages: jax.Array = None
+    sel: Optional[jax.Array] = None
+    seg: Optional[Tuple[str, int]] = struct.field(
+        pytree_node=False, default=None
+    )
+
+    POOL, KERNELS = LatentPagedKVCache, False
+    SHARED_FIELDS = ("k_pages", "ik_pages")
+    PLANE_FIELDS = {"c": "k_pages", "ik": "ik_pages"}
+
+
+class IndexedQuantizedLatentPagedKVCache(
+    _LatentIndex, QuantizedLatentPagedKVCache
+):
+    """:class:`QuantizedLatentPagedKVCache` under a learned selection. A
+    prefill chunk runs the ragged kernel under the selection's mask
+    (``sparse_latent_ragged_paged_attention``). The write-behind tail gains
+    an index tail ``[scoring layers, B, 1, K, INDEX_DIM]`` and the step's
+    selection ``(pool [B, T, 1, PS], tail [B, 1, K])``: a scoring layer
+    writes its index key, scores the pool's and the tail's index keys
+    together, selects and leaves the selection in the tail state; every
+    layer runs the fused one-plane sweep under it
+    (``sparse_latent_paged_fused_attention``), and ``tail_flush`` merges the
+    index tail where the latent tail goes (``latent_index_tail_flush``)."""
+
+    ik_pages: jax.Array = None
+    sel: Optional[jax.Array] = None
+    seg: Optional[Tuple[str, int]] = struct.field(
+        pytree_node=False, default=None
+    )
+
+    POOL, KERNELS = QuantizedLatentPagedKVCache, True
+    SHARED_FIELDS = ("k_pages", "cs_pages", "ik_pages")
+    PLANE_FIELDS = {"c": "k_pages", "cs": "cs_pages", "ik": "ik_pages"}
+
+    def tail_big_stacks(self):
+        return (self.k_pages, self.cs_pages, self.ik_pages)
+
+    def tail_init(self, k_steps: int):
+        b, t = self.page_table.shape
+        return (
+            *super().tail_init(k_steps),
+            jnp.zeros(
+                (self.ik_pages.shape[0], b, 1, k_steps, self.INDEX_DIM),
+                self.ik_pages.dtype,
+            ),
+            jnp.zeros((b, t, 1, self.page_size), jnp.float32),
+            jnp.zeros((b, 1, k_steps), jnp.float32),
+        )
+
+    def tail_attend(self, big_state, tail_state, q, k_new, v_new, rope,
+                    base_len, tail_len, step_idx, num_new, sliding_window,
+                    scale=None, index=None):
+        from ..ops.paged_attention import (
+            quantized_latent_paged_fused_attention,
+        )
+
+        if sliding_window is not None:
+            raise ValueError("latent attention has no sliding window")
+        pool_c, pool_cs, gik, lidx = big_state    # whole planes + the layer
+        tail_c, tail_cs, tik, sel_pool, sel_tail = tail_state
+        scope = "index_reuse/sparse_attention"
+        if index is not None:
+            scope = "sparse_attention"
+            row = lidx + (0 if self.seg is None else self.seg[1])
+            tik = jax.lax.dynamic_update_slice(
+                tik, index.k[None, :, None].astype(tik.dtype),
+                (row, 0, 0, step_idx, 0),
+            )
+            pool_keys = _row_index_keys(
+                self.page_table,
+                jax.lax.dynamic_index_in_dim(gik, row, keepdims=False),
+            )
+            tail_keys = jax.lax.dynamic_index_in_dim(
+                tik, row, keepdims=False
+            )                                             # [B, 1, K, D]
+            b, n = pool_keys.shape[:2]
+            sel = self._tail_select(
+                index, pool_keys, tail_keys, base_len, tail_len, num_new
+            ).astype(jnp.float32)
+            sel_pool = sel[:, :n].reshape(b, -1, 1, self.page_size)
+            sel_tail = sel[:, None, n:]
+        else:
+            self._reuses()
+        with jax.named_scope(scope):
+            out, tail_c, tail_cs = quantized_latent_paged_fused_attention(
+                q, k_new, pool_c, pool_cs, tail_c, tail_cs,
+                layer_idx=lidx, step_idx=step_idx,
+                page_table=self.page_table, base_len=base_len,
+                tail_valid_len=tail_len + num_new,
+                q_positions=base_len + tail_len, scale=scale,
+                select=(sel_pool, sel_tail),
+            )
+        return out, (tail_c, tail_cs, tik, sel_pool, sel_tail)
+
+    def tail_flush(self, tail, tail_len):
+        tail_c, tail_cs, tik, _, _ = tail     # tik [scoring, B, 1, K, D]
+        kk = tik.shape[3]
+        if kk <= self.page_size:
+            from ..ops.paged_attention import (
+                KERNEL_LATENT_INDEX_FLUSH, paged_tail_flush,
+            )
+
+            (nik,) = paged_tail_flush(
+                self.ik_pages, None, None, None, tik, None, None, None,
+                self.page_table, self.lengths, tail_len,
+                name=KERNEL_LATENT_INDEX_FLUSH,
+            )
+        else:
+            q_pos = self.lengths[:, None] + jnp.arange(
+                kk, dtype=jnp.int32
+            )[None, :]
+            nik = jax.vmap(
+                lambda lik, t: self._scatter_index(
+                    lik, t[:, 0], q_pos, tail_len
+                )
+            )(self.ik_pages, tik)
+        return self.POOL.tail_flush(
+            self, (tail_c, tail_cs), tail_len
+        ).replace(ik_pages=nik)
+
+
+@functools.lru_cache(maxsize=None)
+def indexed_latent_cache_class(quantized: bool, index_dim: int,
+                               scoring: Tuple[bool, ...]):
+    """THE indexed latent cache class of a stored form, an index key's width
+    and the layers that score (a bool a layer), made once (a class is a
+    pytree node type: two engines of one stack must hold the same one)."""
+    base = (
+        IndexedQuantizedLatentPagedKVCache if quantized
+        else IndexedLatentPagedKVCache
+    )
+    scoring = tuple(bool(x) for x in scoring)
+    return type(
+        f"{base.__name__}{index_dim}x{sum(scoring)}of{len(scoring)}",
+        (base,), {"INDEX_DIM": int(index_dim), "SCORING": scoring},
+    )

@@ -1304,9 +1304,16 @@ class _IndexPlane:
         return cls(
             **{f.name: getattr(base, f.name) for f in dataclasses.fields(base)},
             ik_pages=jnp.zeros(
-                (num_layers, num_pages, 1, page_size, cls.INDEX_DIM), dtype
+                (cls.index_rows(num_layers), num_pages, 1, page_size,
+                 cls.INDEX_DIM), dtype,
             ),
         )
+
+    @classmethod
+    def index_rows(cls, num_layers: int) -> int:
+        """Rows of the index plane of a cache of ``num_layers`` layers: one a
+        layer, where every layer scores a selection."""
+        return num_layers
 
     def _scatter_index(self, lik, index_k, q_pos, num_new):
         """Index keys ``[B, S, D]`` into one layer's plane, at the positions
@@ -1325,6 +1332,26 @@ class _IndexPlane:
             index, _row_index_keys(self.page_table, nik), q_pos,
             self.lengths + num_new,
         )
+
+    def _tail_select(self, index, pool_keys, tail_keys, base_len, tail_len,
+                     num_new):
+        """A decode step's selection ``[B, N + K]`` over a row's pool
+        positions (``pool_keys [B, N, D]``, by table slot) and, behind them,
+        its tail slots (``tail_keys [B, 1, K, D]``, this step's included)."""
+        b, n = pool_keys.shape[:2]
+        steps = jnp.arange(tail_keys.shape[2], dtype=jnp.int32)[None, :]
+        slots = jnp.arange(n, dtype=jnp.int32)[None, :]
+        return selection_mask(
+            index, jnp.concatenate([pool_keys, tail_keys[:, 0]], 1),
+            (base_len + tail_len)[:, None], None,
+            key_pos=jnp.concatenate(
+                [jnp.broadcast_to(slots, (b, n)), base_len[:, None] + steps], 1
+            ),
+            key_valid=jnp.concatenate([
+                slots < base_len[:, None],
+                steps < (tail_len + num_new)[:, None],
+            ], 1),
+        )[:, 0]
 
     def ingest_index_row(self, planes, n_valid, first_slot=0):
         """Install shipped index planes (``INDEX_PLANES`` names; ``[L, 1, S,
@@ -1485,20 +1512,9 @@ class IndexedQuantizedPagedKVCache(_IndexPlane, QuantizedPagedKVCache):
             tik = jax.lax.dynamic_update_slice_in_dim(tik, ik_new, step_idx, 2)
             tail_keys = tik
         b, n = pool_keys.shape[:2]
-        kk = tail_keys.shape[2]
-        steps = jnp.arange(kk, dtype=jnp.int32)[None, :]
-        slots = jnp.arange(n, dtype=jnp.int32)[None, :]
-        sel = selection_mask(
-            index, jnp.concatenate([pool_keys, tail_keys[:, 0]], 1),
-            (base_len + tail_len)[:, None], None,
-            key_pos=jnp.concatenate(
-                [jnp.broadcast_to(slots, (b, n)), base_len[:, None] + steps], 1
-            ),
-            key_valid=jnp.concatenate([
-                slots < base_len[:, None],
-                steps < (tail_len + num_new)[:, None],
-            ], 1),
-        )[:, 0]
+        sel = self._tail_select(
+            index, pool_keys, tail_keys, base_len, tail_len, num_new
+        )
         select = (sel[:, :n], sel[:, n:])
         if whole:
             select = (

@@ -67,6 +67,12 @@ class LatentConfig:
     # Per-head value dim (``v_head_dim``): the width ``wv_b`` up-projects
     # the latent to and ``wo`` consumes; None = head_dim.
     v_head_dim: Optional[int] = None
+    # Compressed queries (``q_lora_rank``): the query is projected down to
+    # this width, RMS-normed and projected up to the heads (``wq_a``,
+    # ``q_a_norm``, ``wq_b`` where a block without it has ``wq``); a
+    # learned selection's index queries are projected from the compressed
+    # query too. None = queries straight from the hidden state.
+    q_lora_rank: Optional[int] = None
 
     @property
     def lat_dim(self) -> int:
@@ -77,7 +83,11 @@ class LatentConfig:
 @dataclasses.dataclass(frozen=True)
 class SparseAttentionConfig:
     """Learned sparse attention (DeepSeek-V3.2's lightning indexer and top-k
-    selection, ``sa_config`` of a ``KeyeVL2`` block) over per-head K/V.
+    selection): ``sa_config`` of a ``KeyeVL2`` block over per-head K/V, or
+    ``index_n_heads`` / ``index_head_dim`` / ``index_topk`` of a block with
+    a latent, where the selection is of stored latents
+    (``cache/latent.py``: the indexed latent classes) and only the layers
+    ``ModelConfig.index_layers`` marks score one.
 
     Beside ``q``, ``k``, ``v`` a layer projects ``index_heads`` index queries
     and ONE index key of ``index_dim`` a token, and a weight a head; the
@@ -92,6 +102,9 @@ class SparseAttentionConfig:
     index_heads: int = 16
     index_dim: int = 64
     topk: int = 2048
+    # How many of an index query's and key's leading dims are rotated
+    # (a latent block rotates ``qk_rope_head_dim`` of them); None = all.
+    rope_dim: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,6 +131,14 @@ class LayerSegment:
     # kind), and ``cache_start`` counts the earlier layers of that kind.
     pool: Optional[str] = None
     cache_start: int = 0
+    # Its part in a learned selection that layers share
+    # (``ModelConfig.index_layers``): "score" layers have an indexer and
+    # choose, "reuse" layers attend to what the nearest scoring layer before
+    # them chose; None where every layer is alike in that too.
+    # ``index_start`` counts the scoring layers before the segment: its
+    # rows of the cache's index plane start there.
+    index: Optional[str] = None
+    index_start: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,6 +212,11 @@ class ModelConfig:
     # Learned top-k key selection (an indexer beside GQA); requires the
     # "keye_vl2" family and the paged cache kind. None = every key.
     sparse: Optional[SparseAttentionConfig] = None
+    # Which layers of a stack that selects its keys score a selection of
+    # their own ("score") and which attend to the selection of the nearest
+    # scoring layer before them ("reuse"), a layer (``indexer_types``:
+    # "full" | "shared"); None = every layer scores. The first layer scores.
+    index_layers: Optional[Tuple[str, ...]] = None
     # The layers' attention, one of "window" | "full" a layer, where they are
     # not all alike (``layer_types``); None = every layer the same: a window
     # layer where ``sliding_window`` is set, else a full one. A window layer
@@ -206,7 +232,7 @@ class ModelConfig:
     expert_shares: int = 1
     expert_share_index: int = 0
     # Model family tag ("llama", "mistral", "qwen2", "mixtral", "mla",
-    # "keye_vl2", "exaone_moe").
+    # "keye_vl2", "exaone_moe", "glm_moe_dsa").
     family: str = "llama"
 
     @property
@@ -245,8 +271,9 @@ class ModelConfig:
     @property
     def segments(self) -> Tuple[LayerSegment, ...]:
         """THE description of the stack: homogeneous segments in layer
-        order, runs of layers alike in MLP ("dense" | "moe") and attention
-        ("window" | "full"). Initialisation, the forward programs, the
+        order, runs of layers alike in MLP ("dense" | "moe"), attention
+        ("window" | "full") and part in a shared selection ("score" |
+        "reuse", ``index_layers``). Initialisation, the forward programs, the
         checkpoint converter, the quantiser and the census all walk this. A
         stack whose layers are all alike is one segment under ``"layers"``,
         the parameter tree every such model has always had; a stack with
@@ -257,16 +284,17 @@ class ModelConfig:
         k = self.first_dense_layers if moe else 0
         kinds = self.attention_kinds
         mixed = self.mixed_attention
-        runs = []                       # [mlp, attention, start, count]
+        index = self.index_layers or (None,) * self.num_layers
+        runs = []                  # [mlp, attention, index, start, count]
         for i in range(self.num_layers):
             mlp = "moe" if moe and i >= k else "dense"
-            if runs and runs[-1][:2] == [mlp, kinds[i]]:
-                runs[-1][3] += 1
+            if runs and runs[-1][:3] == [mlp, kinds[i], index[i]]:
+                runs[-1][4] += 1
             else:
-                runs.append([mlp, kinds[i], i, 1])
-        seen = {"window": 0, "full": 0}
+                runs.append([mlp, kinds[i], index[i], i, 1])
+        seen = {"window": 0, "full": 0, "score": 0}
         out = []
-        for n, (mlp, att, start, count) in enumerate(runs):
+        for n, (mlp, att, idx, start, count) in enumerate(runs):
             out.append(LayerSegment(
                 mlp,
                 "layers" if len(runs) == 1 else f"layers_{n}_{mlp}",
@@ -276,9 +304,23 @@ class ModelConfig:
                 rope=att == "window" or not mixed or self.full_attention_rope,
                 pool=att if mixed else None,
                 cache_start=seen[att] if mixed else start,
+                index=idx,
+                index_start=seen["score"],
             ))
             seen[att] += count
+            if idx == "score":
+                seen["score"] += count
         return tuple(out)
+
+    @property
+    def index_scoring(self) -> Optional[Tuple[bool, ...]]:
+        """Which layers score a selection, a layer; None for a stack that
+        selects no keys. What the cache's index plane has rows for."""
+        if not self.use_sparse:
+            return None
+        if self.index_layers is None:
+            return (True,) * self.num_layers
+        return tuple(kind == "score" for kind in self.index_layers)
 
     @property
     def num_expert_layers(self) -> int:
@@ -318,22 +360,20 @@ class ModelConfig:
                 rope_head_dim=int(get("qk_rope_head_dim", 64)),
                 nope_head_dim=get("qk_nope_head_dim", None),
                 v_head_dim=get("v_head_dim", None),
+                q_lora_rank=get("q_lora_rank", None),
             )
-            model_type = "mla"
-            if get("n_routed_experts", None):
-                method = get("topk_method", "greedy")
-                moe = dict(
-                    moe_intermediate_size=get("moe_intermediate_size", None),
-                    num_shared_experts=get("n_shared_experts", 0) or 0,
-                    first_dense_layers=get("first_k_dense_replace", 0) or 0,
-                    moe_scoring=get("scoring_func", "softmax"),
-                    moe_select_bias=method == "noaux_tc",
-                    moe_norm_topk=bool(get("norm_topk_prob", False)),
-                    moe_routed_scale=float(
-                        get("routed_scaling_factor", 1.0) or 1.0
-                    ),
-                )
+            model_type = _LATENT_FAMILIES.get(model_type, "mla")
         extra = {}
+        if latent is not None:
+            if get("n_routed_experts", None):
+                moe, share = _routing_keys(
+                    get, held="n_routed_experts", shared="n_shared_experts",
+                    scoring="softmax",
+                    select_bias=get("topk_method", "greedy") == "noaux_tc",
+                )
+                extra.update(share)
+            if get("index_topk", None):
+                extra.update(_index_keys(get, latent))
         if model_type == "KeyeVL2":
             _refuse_unimplemented_keye(get)
             sa = get("sa_config", None) or {}
@@ -370,7 +410,9 @@ class ModelConfig:
         )
         if model_type == "exaone_moe":
             moe, extra = _exaone_moe_keys(get)
-            window, experts = extra.pop("sliding_window"), extra.pop("num_experts")
+            window = extra.pop("sliding_window")
+        # the ROUTER's width, where the block's key counts a share held here
+        experts = extra.pop("num_experts", experts)
         # A block may nest its RoPE keys (``rope_parameters``: theta and
         # type together) where older ones spread them at the top level.
         nested = get("rope_parameters", None) or {}
@@ -410,8 +452,8 @@ def _refuse_unimplemented(get) -> None:
     this program does not compute. Each raises under its own name."""
     scaling = get("rope_scaling", None) or {}
     refused = {
-        "q_lora_rank": get("q_lora_rank", None) is not None,
         "n_group": (get("n_group", 1) or 1) > 1,
+        "topk_group": (get("topk_group", 1) or 1) > 1,
         "rope_scaling": any("mscale" in k for k in scaling),
         "num_nextn_predict_layers": (
             get("num_nextn_predict_layers", 0) or 0
@@ -426,9 +468,9 @@ def _refuse_unimplemented(get) -> None:
         if bad:
             raise ValueError(
                 f"config key {key!r} = {get(key)!r} is not implemented: "
-                f"compressed queries, routing by groups, YaRN's mscale, "
-                f"next-token-prediction layers and interleaved dense layers "
-                f"are outside what models/llama.py computes"
+                f"routing by groups, YaRN's mscale, next-token-prediction "
+                f"layers and interleaved dense layers are outside what "
+                f"models/llama.py computes"
             )
 
 
@@ -445,9 +487,7 @@ def _exaone_moe_keys(get):
     extra)`` keyword groups for :class:`ModelConfig`. What the block has
     and this program does not compute raises under its key's name."""
     def refuse(key, why):
-        raise ValueError(
-            f"config key {key!r} = {get(key)!r} is not implemented: {why}"
-        )
+        _refuse(get, key, why)
 
     layers = get("num_hidden_layers", 32)
     if (get("num_nextn_predict_layers", 0) or 0) > 0:
@@ -488,6 +528,48 @@ def _exaone_moe_keys(get):
             window = None
         if len(set(kinds)) == 1:
             kinds = None
+    moe, share = _routing_keys(
+        get, held="num_experts", shared="num_shared_experts",
+        scoring="sigmoid",
+        # the selection bias of DeepSeek-V3's gate: assumed, the config has
+        # no key for it (benchmark/configs/k-exaone-236b-a23b.json)
+        select_bias=True,
+    )
+    extra = dict(
+        qk_norm=True,
+        layer_attention=kinds,
+        full_attention_rope=False,
+        sliding_window=window,
+        **share,
+    )
+    return moe, extra
+
+
+#: ``model_type`` of a block with a latent -> its family, where that is not
+#: "mla" (``models/registry.py``)
+_LATENT_FAMILIES = {"glm_moe_dsa": "glm_moe_dsa"}
+_INDEX_KINDS = {"full": "score", "shared": "reuse"}
+
+
+def _refuse(get, key, why):
+    raise ValueError(
+        f"config key {key!r} = {get(key)!r} is not implemented: {why}"
+    )
+
+
+def _routing_keys(get, held: str, shared: str, scoring: str,
+                  select_bias: bool):
+    """THE reader of DeepSeek-V3's routing keys, for every family that has
+    them (``exaone_moe``, ``deepseek_v2`` / ``deepseek_v3``,
+    ``glm_moe_dsa``): the leading dense layers by ``first_k_dense_replace``
+    and, where the block lists them, ``mlp_layer_types``; the routing rule;
+    and (a benchmark configuration's own key) ``expert_share``:
+    ``{"router_experts", "shares", "index"}``, the share of each layer's
+    experts that the block's ``held`` key counts. ``held`` / ``shared`` name
+    the family's keys for the experts and the shared experts, ``scoring``
+    its default rule. Returns ``(moe, share)`` keyword groups for
+    :class:`ModelConfig`; ``moe`` is empty for a block without experts."""
+    layers = get("num_hidden_layers", 32)
     first_dense = get("first_k_dense_replace", 0) or 0
     mlps = get("mlp_layer_types", None)
     if mlps is not None:
@@ -495,43 +577,81 @@ def _exaone_moe_keys(get):
         if len(mlps) != layers or list(mlps) != (
             ["dense"] * dense + ["sparse"] * (layers - dense)
         ):
-            refuse(
-                "mlp_layer_types",
+            _refuse(
+                get, "mlp_layer_types",
                 "leading dense layers and then expert layers only",
             )
         if dense != first_dense:
-            refuse("first_k_dense_replace", "agreement with mlp_layer_types")
-    held = get("num_experts", 0) or 0
+            _refuse(
+                get, "first_k_dense_replace", "agreement with mlp_layer_types"
+            )
+    count = get(held, 0) or 0
     share = get("expert_share", None) or {}
     shares = int(share.get("shares", 1))
-    router = int(share.get("router_experts", held))
-    if router != held * shares or not 0 <= int(share.get("index", 0)) < shares:
-        refuse(
-            "expert_share",
-            f"router_experts = num_experts ({held}) x shares, and an index "
+    router = int(share.get("router_experts", count))
+    if router != count * shares or not 0 <= int(share.get("index", 0)) < shares:
+        _refuse(
+            get, "expert_share",
+            f"router_experts = {held} ({count}) x shares, and an index "
             f"under shares",
         )
     moe = dict(
         moe_intermediate_size=get("moe_intermediate_size", None),
-        num_shared_experts=get("num_shared_experts", 0) or 0,
+        num_shared_experts=get(shared, 0) or 0,
         first_dense_layers=first_dense,
-        moe_scoring=get("scoring_func", "sigmoid"),
-        # the selection bias of DeepSeek-V3's gate: assumed, the config has
-        # no key for it (benchmark/configs/k-exaone-236b-a23b.json)
-        moe_select_bias=True,
+        moe_scoring=get("scoring_func", scoring),
+        moe_select_bias=select_bias,
         moe_norm_topk=bool(get("norm_topk_prob", False)),
         moe_routed_scale=float(get("routed_scaling_factor", 1.0) or 1.0),
-    ) if held else {}
-    extra = dict(
-        qk_norm=True,
-        layer_attention=kinds,
-        full_attention_rope=False,
-        sliding_window=window,
+    ) if count else {}
+    return moe, dict(
         num_experts=router,
         expert_shares=shares,
         expert_share_index=int(share.get("index", 0)),
     )
-    return moe, extra
+
+
+def _index_keys(get, latent: LatentConfig) -> dict:
+    """A latent block's lightning indexer (``index_n_heads`` /
+    ``index_head_dim`` / ``index_topk``) and which layers have one
+    (``indexer_types``: "full" scores, "shared" attends to the selection of
+    the nearest "full" layer before it). Without the list every layer
+    scores; ``index_topk_freq`` alone is not read as a pattern (the list is
+    the block's own statement of it)."""
+    layers = get("num_hidden_layers", 32)
+    types = get("indexer_types", None)
+    kinds = None
+    if types is not None:
+        if len(types) != layers or any(t not in _INDEX_KINDS for t in types):
+            _refuse(
+                get, "indexer_types",
+                f"one of {sorted(_INDEX_KINDS)} for each of the {layers} "
+                f"layers",
+            )
+        if types[0] != "full":
+            _refuse(
+                get, "indexer_types",
+                "a first layer that scores: a shared layer has no selection "
+                "before it to attend to",
+            )
+        kinds = tuple(_INDEX_KINDS[t] for t in types)
+        if "reuse" not in kinds:
+            kinds = None
+    elif (get("index_topk_freq", 1) or 1) != 1:
+        _refuse(
+            get, "index_topk_freq",
+            "a selection shared by layers is read from indexer_types, a "
+            "layer; the period alone does not say which layers score",
+        )
+    return dict(
+        sparse=SparseAttentionConfig(
+            index_heads=int(get("index_n_heads", 32)),
+            index_dim=int(get("index_head_dim", 128)),
+            topk=int(get("index_topk", 2048)),
+            rope_dim=latent.rope_head_dim,
+        ),
+        index_layers=kinds,
+    )
 
 
 def _refuse_unimplemented_keye(get) -> None:

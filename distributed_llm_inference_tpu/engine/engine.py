@@ -38,7 +38,10 @@ import numpy as np
 
 from ..cache.base import window_ladder
 from ..cache.dense import DenseKVCache, QuantizedDenseKVCache
-from ..cache.latent import LatentPagedKVCache, QuantizedLatentPagedKVCache
+from ..cache.latent import (
+    LatentPagedKVCache, QuantizedLatentPagedKVCache,
+    indexed_latent_cache_class,
+)
 from ..cache.paged import (
     PageAllocator, PagedKVCache, QuantizedPagedKVCache, indexed_cache_class,
     two_pool_cache_class, window_pages_bound,
@@ -306,7 +309,18 @@ class InferenceEngine:
                     "a learned key selection is single-device only (mesh "
                     "sharding of the index plane is not implemented)"
                 )
+            if self._latent and draft is not None:
+                raise ValueError(
+                    "a learned key selection over a latent does not compose "
+                    "with a draft model (the verify pass would need the "
+                    "selection of every proposed position)"
+                )
             self.plan.sparse_topk = cfg.sparse.topk
+            # of the layers that attend under a selection, those that score
+            # one of their own (the rest reuse: ModelConfig.index_layers)
+            self.plan.index_layers = (
+                sum(cfg.index_scoring), cfg.num_layers
+            )
         # Window and full layers in one stack: the cache keeps a pool a kind
         # (cache/paged.py, the two-pool classes), and the window pool's
         # pages leave a row as its window passes them. What is written for
@@ -418,11 +432,21 @@ class InferenceEngine:
                     QuantizedLatentPagedKVCache
                     if cc.kv_quant == "int8" else LatentPagedKVCache
                 )
+                more = {}
+                if cfg.use_sparse:
+                    # the selection is of stored latents: an index plane
+                    # (in the model's dtype) with rows for the layers that
+                    # score, beside the latent planes
+                    latent_cls = indexed_latent_cache_class(
+                        cc.kv_quant == "int8", cfg.sparse.index_dim,
+                        cfg.index_scoring,
+                    )
+                    more["dtype"] = dtype
                 self.cache = latent_cls.create(
                     cfg.num_layers, b, cc.num_pages, cc.page_size,
                     self._first_slots, 1, cfg.latent.lat_dim,
                     use_kernel=self._use_pallas,
-                    use_ragged=_sel.use_ragged,
+                    use_ragged=_sel.use_ragged, **more,
                 )
             else:
                 paged_cls = (
@@ -2057,6 +2081,14 @@ class InferenceEngine:
                 sc = jnp.transpose(cache.cs_pages[:, pages], (0, 1, 3, 2))
                 sc = sc.reshape(sc.shape[0], -1, sc.shape[3])
                 out["cs"] = np.asarray(sc[:, :n])
+            # a selection's index keys, of the layers that score: [Ls,S,1,D]
+            for name, f in getattr(cache, "INDEX_PLANES", {}).items():
+                a = jnp.transpose(
+                    getattr(cache, f)[:, pages], (0, 1, 3, 2, 4)
+                )
+                out[name] = np.asarray(
+                    a.reshape(a.shape[0], -1, *a.shape[3:])[:, :n]
+                )
             return out
         if isinstance(cache, PagedKVCache):
             pages = jnp.asarray(np.asarray(s.pages, np.int32))
@@ -2131,9 +2163,9 @@ class InferenceEngine:
             )
         for name in sorted(want):
             expect = shape if name in ("c", "k", "v") else shape[:3]
-            if name in index_planes:  # one index key a token
+            if name in index_planes:  # one index key a token a scoring layer
                 pool = getattr(cache, index_planes[name])
-                expect = (shape[0], n, 1, pool.shape[4])
+                expect = (pool.shape[0], n, 1, pool.shape[4])
             got = tuple(np.asarray(planes[name]).shape)
             if got != expect:
                 raise ValueError(

@@ -164,6 +164,11 @@ class AttentionPlan:
         # (``ModelConfig.sparse``): note_dispatch keeps the census of the
         # selected and the live keys.
         self.sparse_topk: Optional[int] = None
+        # Beside it: ``(scoring, attending)`` layers of such a stack, where
+        # some layers attend to the selection of a scoring layer before
+        # them (``ModelConfig.index_layers``; equal where every layer
+        # scores). note_dispatch counts them a step.
+        self.index_layers: Optional[Tuple[int, int]] = None
         # Set by the engine where the fused decode scan runs the in-place
         # sweep by copies (``ops/paged_attention.py``: an int8 paged pool
         # with the kernel): ``(kv heads, stored width, least table
@@ -329,9 +334,13 @@ class AttentionPlan:
         (a decode row's queries are the dispatch's steps). A model that
         selects its keys (``sparse_topk``): a query at position ``t`` has
         ``t + 1`` live keys and attends to ``min(topk, t + 1)`` of them, in
-        every layer alike. Their sums add to ``sparse_keys_live`` /
+        every layer that attends alike, whether it scored the selection or
+        reuses one. Their sums (ONE layer's) add to ``sparse_keys_live`` /
         ``sparse_keys_selected`` (``_total`` on ``/metrics``) and ride the
-        dispatch's record as a fourth entry ``(selected, live)``. A stack
+        dispatch's record as a fourth entry ``(selected, live)``; the
+        layers that score and those that attend (``index_layers``) add, a
+        step of the dispatch, to ``index_layers_scored`` /
+        ``index_layers_attended``. A stack
         of window and full layers (``windowed``): a window layer's query
         sees ``min(window, t + 1)`` of the ``t + 1`` keys in its context;
         the sums, of ONE window layer, add to ``window_keys_seen`` /
@@ -361,6 +370,8 @@ class AttentionPlan:
         if sparse_keys is not None:
             self.metrics.counter("sparse_keys_selected", sparse_keys[0])
             self.metrics.counter("sparse_keys_live", sparse_keys[1])
+            if self.index_layers is not None:
+                self._count_index_layers(shape[1] if kind == DECODE else 1)
         if window_keys is not None:
             self.metrics.counter("window_keys_seen", window_keys[0])
             self.metrics.counter("window_keys_in_context", window_keys[1])
@@ -408,6 +419,13 @@ class AttentionPlan:
                 live, grid = self._ragged_tiles(shape, row_spans, table_width)
                 self.metrics.counter("ragged_attn_tiles_live", live)
                 self.metrics.counter("ragged_attn_tiles_grid", grid)
+
+    def _count_index_layers(self, steps: int) -> None:
+        """A dispatch's ``steps`` to ``index_layers_scored`` /
+        ``index_layers_attended``."""
+        scored, attended = self.index_layers
+        self.metrics.counter("index_layers_scored", steps * scored)
+        self.metrics.counter("index_layers_attended", steps * attended)
 
     def _sparse_keys(self, spans) -> Tuple[int, int]:
         """(selected, live) keys of a selection's queries over ``spans``."""

@@ -52,19 +52,32 @@ Params = Dict[str, Any]
 SEGMENT_SCOPES = {"dense": "dense_stack", "moe": "moe_stack"}
 
 
-def _segment_cache(cache, seg: LayerSegment):
+def _segment_cache(cache, seg: LayerSegment, seq_len: Optional[int] = None):
     """The cache a segment's layers read and write: the cache itself, or the
     pool of the segment's attention kind where the cache holds two
-    (``cache/paged.py``: the two-pool classes)."""
+    (``cache/paged.py``: the two-pool classes), or, where layers share a
+    learned selection, the cache's view for the segment's part in it
+    (``cache/latent.py``: ``index_view``; the selection then rides the
+    cache's own layer state, which is the scans' carry, from a scoring
+    segment into the reusing one behind it; ``seq_len``, the dispatch's
+    queries a row, sizes that state: the fused decode scan, whose tail
+    carries it, passes none)."""
+    if seg.index is not None:
+        return cache.index_view(
+            seg.index, seg.index_start - seg.cache_start, seq_len
+        )
     return cache if seg.pool is None else cache.pool_view(seg.pool)
 
 
 def _segment_scope(seg: LayerSegment):
     """A segment's scope in a device trace: its MLP kind's, and under it the
-    attention kind's where the stack has two."""
+    attention kind's where the stack has two, or its part in a shared
+    selection (``index_score_layers`` / ``index_reuse_layers``)."""
     name = SEGMENT_SCOPES[seg.kind]
     if seg.pool is not None:
         name = f"{name}/{seg.attention}_layers"
+    if seg.index is not None:
+        name = f"{name}/index_{seg.index}_layers"
     return jax.named_scope(name)
 
 
@@ -75,11 +88,13 @@ def _segment_scope(seg: LayerSegment):
 
 def init_layer_params(
     cfg: ModelConfig, key: jax.Array, num_layers: int, dtype=jnp.bfloat16,
-    kind: Optional[str] = None,
+    kind: Optional[str] = None, index: Optional[str] = None,
 ) -> Params:
     """Random (normal 0.02) stacked parameters for ``num_layers`` decoder
     layers of one segment ``kind`` (default: the kind of the stack's last
-    segment — a Mixtral's or a dense model's only one)."""
+    segment — a Mixtral's or a dense model's only one). ``index``: the
+    segment's part in a shared selection (``LayerSegment.index``); a
+    "reuse" segment's layers have no indexer."""
     h, d = cfg.hidden_size, cfg.head_dim
     hq, hkv = cfg.num_heads, cfg.num_kv_heads
     kind = kind or cfg.segments[-1].kind
@@ -97,9 +112,16 @@ def init_layer_params(
         dn = lat.nope_head_dim or d
         dr = lat.rope_head_dim
         dv = lat.v_head_dim or d
+        qr = lat.q_lora_rank
         p = {
             "attn_norm": jnp.ones((num_layers, h), dtype),
-            "wq": w(keys[0], h, hq * (dn + dr)),
+            # Queries from the hidden state, or (compressed queries) down
+            # to ``q_lora_rank``, an RMSNorm, and up to the heads.
+            **({"wq": w(keys[0], h, hq * (dn + dr))} if qr is None else {
+                "wq_a": w(keys[0], h, qr),
+                "q_a_norm": jnp.ones((num_layers, qr), dtype),
+                "wq_b": w(jax.random.fold_in(keys[0], 1), qr, hq * (dn + dr)),
+            }),
             # Down-projection to the stored form: [c ; k_rope_pre].
             "wkv_a": w(keys[1], h, lat.rank + dr),
             "kv_norm": jnp.ones((num_layers, lat.rank), dtype),
@@ -122,12 +144,14 @@ def init_layer_params(
     if cfg.qk_norm:
         p["q_norm"] = jnp.ones((num_layers, d), dtype)
         p["k_norm"] = jnp.ones((num_layers, d), dtype)
-    if cfg.use_sparse:
-        # The indexer (see :func:`_index_inputs`): index queries, ONE index
-        # key a token under a LayerNorm, and a weight a head.
+    if cfg.use_sparse and index != "reuse":
+        # The indexer (see :func:`_index_inputs`): index queries (from the
+        # compressed query where the block has one), ONE index key a token
+        # under a LayerNorm, and a weight a head.
         sa = cfg.sparse
         ik = jax.random.split(keys[3], 4)
-        p["wq_i"] = w(ik[1], h, sa.index_heads * sa.index_dim)
+        q_in = (cfg.latent.q_lora_rank if cfg.use_latent else None) or h
+        p["wq_i"] = w(ik[1], q_in, sa.index_heads * sa.index_dim)
         p["wk_i"] = w(ik[2], h, sa.index_dim)
         p["w_i"] = w(ik[3], h, sa.index_heads)
         p["k_i_norm"] = jnp.ones((num_layers, sa.index_dim), dtype)
@@ -189,7 +213,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             * 0.02
         ).astype(dtype),
         **{
-            seg.key: init_layer_params(cfg, k, seg.count, dtype, seg.kind)
+            seg.key: init_layer_params(
+                cfg, k, seg.count, dtype, seg.kind, seg.index
+            )
             for seg, k in zip(segments, seg_keys)
         },
         "final_norm": jnp.ones((cfg.hidden_size,), dtype),
@@ -249,7 +275,7 @@ def _decoder_layer(
         if cfg.use_latent:
             attn_flat, new_state = _latent_attention(
                 cfg, p, h, layer_state, cache, rope, q_pos, num_new,
-                attention_fn,
+                attention_fn, index_rope,
             )
         else:
             q = qmatmul(h, p["wq"])
@@ -286,18 +312,28 @@ def _decoder_layer(
     return _mlp_residual(cfg, p, x, s, num_new), new_state
 
 
-def _index_inputs(cfg, p, h, index_rope) -> IndexInputs:
-    """The lightning indexer's projections of the normed hidden state ``h``
-    (DeepSeek-V3.2's, the query taken from ``h``: this block has no
-    compressed query): ``index_heads`` queries and ONE key of ``index_dim``
-    a token, both rotated over their whole width, the key under a LayerNorm
-    first; a weight a head, scaled by ``index_heads ** -0.5`` and the
-    scores' ``index_dim ** -0.5``. The three matrices stay in the model's
-    dtype (``ops/quant.py``)."""
+def _index_inputs(cfg, p, h, index_rope, cq=None) -> IndexInputs:
+    """The lightning indexer's projections (DeepSeek-V3.2's):
+    ``index_heads`` queries, taken from the compressed query ``cq`` where
+    the block has one and from the normed hidden state ``h`` where it has
+    none, and ONE key of ``index_dim`` a token from ``h``, the key under a
+    LayerNorm first, both rotated over their whole width or over their
+    first ``rope_dim``; a weight a head from ``h``, scaled by ``index_heads
+    ** -0.5`` and the scores' ``index_dim ** -0.5``. The three matrices
+    stay in the model's dtype (``ops/quant.py``)."""
     sa = cfg.sparse
     b, s, _ = h.shape
     cos, sin = index_rope
-    qi = qmatmul(h, p["wq_i"]).reshape(b, s, sa.index_heads, sa.index_dim)
+    rot = apply_rope
+    if sa.rope_dim is not None:
+        def rot(x, cos, sin):
+            return jnp.concatenate([
+                apply_rope(x[..., : sa.rope_dim], cos, sin),
+                x[..., sa.rope_dim:],
+            ], axis=-1)
+    qi = qmatmul(h if cq is None else cq, p["wq_i"]).reshape(
+        b, s, sa.index_heads, sa.index_dim
+    )
     ki = qmatmul(h, p["wk_i"]).astype(jnp.float32)
     mean = jnp.mean(ki, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
@@ -308,8 +344,8 @@ def _index_inputs(cfg, p, h, index_rope) -> IndexInputs:
     ).astype(h.dtype)
     w = qmatmul(h, p["w_i"]) * (sa.index_heads ** -0.5 * sa.index_dim ** -0.5)
     return IndexInputs(
-        q=apply_rope(qi, cos, sin),
-        k=apply_rope(ki[:, :, None, :], cos, sin)[:, :, 0],
+        q=rot(qi, cos, sin),
+        k=rot(ki[:, :, None, :], cos, sin)[:, :, 0],
         w=w,
         topk=sa.topk,
     )
@@ -323,7 +359,10 @@ def _index_rope(cfg: ModelConfig, positions):
         return None
     return rope_cos_sin(
         positions,
-        rope_inv_freq(cfg.sparse.index_dim, cfg.rope_theta, cfg.rope_scaling),
+        rope_inv_freq(
+            cfg.sparse.rope_dim or cfg.sparse.index_dim, cfg.rope_theta,
+            cfg.rope_scaling,
+        ),
     )
 
 
@@ -363,6 +402,7 @@ def _latent_attention(
     q_pos: jnp.ndarray,
     num_new: jnp.ndarray,
     attention_fn=gqa_attention,
+    index_rope=None,
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
     """Absorbed-MLA attention over the latent cache.
 
@@ -385,6 +425,13 @@ def _latent_attention(
     built for ``rope_head_dim`` — see :func:`block_apply`); the cache must
     not rotate anything. Softmax scale is ``(dn + dr)**-0.5``, the
     effective per-head query dim of the UN-absorbed formulation.
+
+    Compressed queries (``LatentConfig.q_lora_rank``: the layer has
+    ``wq_a``, ``q_a_norm``, ``wq_b``): ``cq = RMSNorm(h wq_a)``, ``q = cq
+    wq_b``. Under a learned selection a layer that has an indexer hands the
+    cache its index inputs (the index queries from ``cq``); a layer that has
+    none hands nothing, and the cache attends to the selection its layer
+    state carries from the scoring layer before (``cache/latent.py``).
     """
     lat = cfg.latent
     b, s, _ = h.shape
@@ -394,7 +441,13 @@ def _latent_attention(
     dv = lat.v_head_dim or d
     rank = lat.rank
 
-    q = qmatmul(h, p["wq"]).reshape(b, s, hq, dn + dr)
+    cq = None
+    if "wq_a" in p:
+        cq = rms_norm(qmatmul(h, p["wq_a"]), p["q_a_norm"], cfg.rms_norm_eps)
+        q = qmatmul(cq, p["wq_b"])
+    else:
+        q = qmatmul(h, p["wq"])
+    q = q.reshape(b, s, hq, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     ckv = qmatmul(h, p["wkv_a"])  # [B, S, rank + dr]
     c = rms_norm(ckv[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
@@ -408,9 +461,13 @@ def _latent_attention(
     kv = jnp.concatenate(
         [c[:, :, None, :], k_rope], axis=-1
     )  # [B, S, 1, rank+dr] — the STORED form the cache scatters verbatim
+    more = (
+        {"index": _index_inputs(cfg, p, h, index_rope, cq)}
+        if "wk_i" in p else {}
+    )
     attn, new_state = cache.attend(
         layer_state, q_eff, kv, kv, rope, q_pos, num_new,
-        None, attention_fn, (dn + dr) ** -0.5,
+        None, attention_fn, (dn + dr) ** -0.5, **more,
     )
     # Deferred value up-projection from the latent-space attention result.
     o = jnp.einsum("bshr,rhd->bshd", attn[..., :rank], p["wv_b"])
@@ -515,6 +572,10 @@ def block_apply(
 
     stacks = cache.layer_stacks  # tuple of [L, ...] arrays (k/v [+ scales])
     num_stack = layer_params["attn_norm"].shape[0]
+    # Which row of each stack a layer owns: the layer's own, unless the cache
+    # says otherwise (a plane with rows for some layers only, a state that
+    # layers share: cache/latent.py).
+    rows_of = getattr(cache, "stack_rows", None)
 
     # Cache buffers ride the scan CARRY and are updated in place at the layer
     # index — carries are aliased by XLA, so a decode step writes one token
@@ -530,17 +591,18 @@ def block_apply(
         p, idx = xs
         # whole stacks are this block's own: indexed from its first layer
         p = {**p, **_layer_views(whole_w, idx - first_layer if first_layer else idx)}
+        rows = (idx,) * len(bufs) if rows_of is None else rows_of(idx)
         layer_state = tuple(
-            jax.lax.dynamic_index_in_dim(b, idx, 0, keepdims=False)
-            for b in bufs
+            jax.lax.dynamic_index_in_dim(b, r, 0, keepdims=False)
+            for b, r in zip(bufs, rows)
         )
         out, new_state = _decoder_layer(
             cfg, p, x, layer_state, cache, rope, q_pos, num_new, attention_fn,
             index_rope, segment,
         )
         bufs = tuple(
-            jax.lax.dynamic_update_index_in_dim(b, n, idx, 0)
-            for b, n in zip(bufs, new_state)
+            jax.lax.dynamic_update_index_in_dim(b, n, r, 0)
+            for b, n, r in zip(bufs, new_state, rows)
         )
         return (out, bufs), None
 
@@ -581,7 +643,8 @@ def model_apply(
         for seg in cfg.segments:
             with _segment_scope(seg):
                 x, part = block_apply(
-                    cfg, params[seg.key], x, _segment_cache(cache, seg),
+                    cfg, params[seg.key], x,
+                    _segment_cache(cache, seg, x.shape[1]),
                     num_new, attention_fn, first_layer=seg.cache_start,
                     segment=seg,
                 )
@@ -738,7 +801,9 @@ def multi_decode_apply(
             at = names.index(seg.pool)
             pool = pools[at]
             view = _TailView(
-                pool.cache, base_len, tail_len, i, pool.view_num_big
+                pool.cache if seg.index is None
+                else _segment_cache(cache, seg),
+                base_len, tail_len, i, pool.view_num_big,
             )
             rope = ropes[seg.rope]
             # The read-only big planes ride a segment's scan as ITS layers'
@@ -889,11 +954,24 @@ def convert_hf_layer(
         # ``rotate_half``); ``ops/rotary.py`` rotates halves, so the rope
         # columns are stored de-interleaved once, here.
         halves = np.concatenate([np.arange(0, dr, 2), np.arange(1, dr, 2)])
-        if "wq" in out:
-            wq = out["wq"].reshape(-1, cfg.num_heads, dn + dr)
-            out["wq"] = np.concatenate(
-                [wq[..., :dn], wq[..., dn:][..., halves]], -1
-            ).reshape(out["wq"].shape)
+        # Compressed queries (``q_lora_rank``): q_a_proj, its RMSNorm and
+        # q_b_proj where a block without them has q_proj.
+        for suffix, name, transpose in (
+            ("self_attn.q_a_proj.weight", "wq_a", True),
+            ("self_attn.q_a_layernorm.weight", "q_a_norm", False),
+            ("self_attn.q_b_proj.weight", "wq_b", True),
+        ):
+            if prefix + suffix in state:
+                arr = np.asarray(state[prefix + suffix])
+                out[name] = (arr.T if transpose else arr).astype(
+                    jnp.dtype(dtype)
+                )
+        for name in ("wq", "wq_b"):  # the queries' up-projection
+            if name in out:
+                wq = out[name].reshape(-1, cfg.num_heads, dn + dr)
+                out[name] = np.concatenate(
+                    [wq[..., :dn], wq[..., dn:][..., halves]], -1
+                ).reshape(out[name].shape)
         akey = prefix + "self_attn.kv_a_proj_with_mqa.weight"
         if akey in state:
             wkv_a = np.asarray(state[akey]).T
@@ -977,6 +1055,9 @@ def convert_hf_state_dict(
     for a 14.5 GB checkpoint on a 40 GiB host — my chip run, PR 21).
     """
     if cfg.qk_norm or cfg.use_sparse:
+        # by what the converter lacks, whatever the family's name: a latent
+        # block's compressed queries are mapped (``convert_hf_layer``), an
+        # indexer's tensors are not
         raise ValueError(
             f"family {cfg.family!r} has no checkpoint converter: the key "
             "names of its checkpoint (the per-head q/k norms', an "

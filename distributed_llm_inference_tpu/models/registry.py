@@ -38,6 +38,15 @@ Families:
   no dense layer). Its checkpoint's key names are not known to this
   program: :func:`llama.convert_hf_state_dict` refuses the family.
 
+* ``glm_moe_dsa`` — GLM-5.2's block: the latent of ``mla`` with compressed
+  queries (``LatentConfig.q_lora_rank``), sigmoid-routed experts beside a
+  shared one behind leading dense layers (of which this program may hold a
+  share), AND a learned top-k selection over the stored latents whose
+  indexer exists only in some layers (``ModelConfig.index_layers``): the
+  others attend to the selection of the nearest scoring layer before them
+  (``cache/latent.py``: the indexed latent classes). The one family in
+  which ``latent`` and ``sparse`` compose.
+
 The switches are independent: a family may permit any of them together
 (``mla`` permits experts AND requires the latent); what a family does not
 permit is refused by :func:`validate_config`.
@@ -98,6 +107,10 @@ FAMILIES: Dict[str, ModelFamily] = {
         ModelFamily(
             "exaone_moe", ("exaone_moe",), sliding_window=True, moe=True,
             qk_norm=True, layer_attention=True,
+        ),
+        ModelFamily(
+            "glm_moe_dsa", ("glm_moe_dsa",), latent=True, moe=True,
+            sparse=True,
         ),
     )
 }
@@ -165,8 +178,28 @@ def validate_config(cfg: ModelConfig) -> ModelFamily:
     if cfg.sparse is not None and not fam.sparse:
         raise ValueError(
             f"family {fam.name!r} does not use a learned key selection "
-            f"(ModelConfig.sparse; use the 'keye_vl2' family)"
+            f"(ModelConfig.sparse; use the 'keye_vl2' family, or "
+            f"'glm_moe_dsa' over a latent)"
         )
+    if cfg.index_layers is not None:
+        if cfg.sparse is None:
+            raise ValueError(
+                "index_layers says which layers of a learned key selection "
+                "score (ModelConfig.sparse is None)"
+            )
+        if not fam.latent:
+            raise ValueError(
+                f"family {fam.name!r} scores a selection in every layer "
+                f"(ModelConfig.index_layers is the indexed latent cache's)"
+            )
+        if len(cfg.index_layers) != cfg.num_layers or (
+            set(cfg.index_layers) - {"score", "reuse"}
+        ) or cfg.index_layers[0] != "score":
+            raise ValueError(
+                f"index_layers names 'score' or 'reuse' for each of the "
+                f"{cfg.num_layers} layers, the first 'score' (got "
+                f"{cfg.index_layers})"
+            )
     if cfg.layer_attention is not None:
         if not fam.layer_attention:
             raise ValueError(

@@ -67,12 +67,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _NEG_INF
 
+#: what a device trace calls the latent pool's decode sweep under a learned
+#: selection, and the merge of its index tail into the index plane
+#: (``cache/latent.py``: the indexed latent classes)
+KERNEL_LATENT_DECODE = "sparse_latent_paged_fused_attention"
+KERNEL_LATENT_INDEX_FLUSH = "latent_index_tail_flush"
+
 __all__ = [
     "paged_attention",
     "quantized_paged_attention",
     "latent_paged_attention",
     "quantized_latent_paged_attention",
     "quantized_latent_paged_fused_attention",
+    "KERNEL_LATENT_DECODE",
+    "KERNEL_LATENT_INDEX_FLUSH",
     "quantized_paged_fused_attention",
 ]
 
@@ -533,6 +541,7 @@ def quantized_latent_paged_fused_attention(
     q_positions: jnp.ndarray,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    select=None,
 ):
     """A fused-decode step of an int8 latent engine: the one-stored-plane
     form of :func:`quantized_paged_fused_attention` (its body, its sweep of
@@ -542,14 +551,28 @@ def quantized_latent_paged_fused_attention(
     ``c_new`` ``[B, 1, 1, lat_dim]`` the step's latent in stored form (both
     rotated by the model). Returns ``(out, tail_c', tail_cs')``. Traced as
     ``quantized_latent_paged_attention``: one event is one layer of one
-    decode step of a latent engine, as it was under the grid form."""
-    return quantized_paged_fused_attention(
-        q, c_new, None, pool_c, pool_cs, None, None,
-        tail_c, tail_cs, None, None,
+    decode step of a latent engine, as it was under the grid form. Under a
+    learned selection (``select``, the pair
+    :func:`quantized_paged_fused_attention` describes; ``cache/latent.py``:
+    the indexed classes) the same sweep masks what was not chosen and is
+    traced as ``sparse_latent_paged_fused_attention``; a call without one
+    keeps its operands and its name."""
+    planes = (
+        q, c_new, None, pool_c, pool_cs, None, None, tail_c, tail_cs, None,
+        None,
+    )
+    rows = dict(
         layer_idx=layer_idx, step_idx=step_idx, page_table=page_table,
         base_len=base_len, tail_valid_len=tail_valid_len,
         q_positions=q_positions, scale=scale, interpret=interpret,
-        name="quantized_latent_paged_attention",
+    )
+    if select is None:
+        return quantized_paged_fused_attention(
+            *planes, name="quantized_latent_paged_attention", **rows
+        )
+    return quantized_paged_fused_attention(
+        *planes, name="sparse_latent_paged_fused_attention", select=select,
+        **rows,
     )
 
 
@@ -747,7 +770,9 @@ def quantized_paged_fused_attention(
     float32, positive where a pool position (by table slot) or a tail slot
     is selected. They are two more pipelined blocks a row, and one more
     term of each tile's mask: every live page is still fetched, and a call
-    without a selection traces to the program it always did.
+    without a selection traces to the program it always did. Both forms of
+    the sweep take it: the copies' and the pipelined page blocks' (the
+    latent pool's).
     """
     b, s, hq, d = q.shape
     if s != 1:
@@ -768,8 +793,6 @@ def quantized_paged_fused_attention(
     planes = 1 if shared else 2
     n = _pages_per_block(t, hkv, page_size, d, kt, planes)
     by_grid = _pages_by_grid(d)
-    if select is not None and by_grid:
-        raise ValueError("a selection is swept by copies (whole-tile rows)")
 
     qr = q.reshape(b, hkv, g, d)
     # Per stored plane, in the kernel's operand order: the step's fresh
@@ -1084,21 +1107,25 @@ def _qpaged_fused_kernel(
         # row the table has).
         blk = pl.program_id(1)
         pl.when(blk == 0)(_init)
+        last = table_ref.shape[1] - 1
 
         @pl.when((blk * n < hi) & (blk * n + n > lo))
         def _block_tile():
-            last = table_ref.shape[1] - 1
-            _tile(
-                [
-                    (jnp.concatenate(
-                        [pool[plane * per + i][0, 0] for i in range(n)], 1),
-                     jnp.concatenate(
-                        [scale_rows[plane][0, jnp.minimum(blk * n + i, last)]
-                         for i in range(n)], -1))
-                    for plane in range(planes)
-                ],
-                _valid(blk * n, n * page_size), n * page_size,
-            )
+            stored = [
+                (jnp.concatenate(
+                    [pool[plane * per + i][0, 0] for i in range(n)], 1),
+                 jnp.concatenate(
+                    [scale_rows[plane][0, jnp.minimum(blk * n + i, last)]
+                     for i in range(n)], -1))
+                for plane in range(planes)
+            ]
+            valid = _valid(blk * n, n * page_size)
+            if selected:  # by table slot [1, T, 1, PS], as the scale rows
+                valid &= jnp.concatenate(
+                    [sel_pool[0, jnp.minimum(blk * n + i, last)]
+                     for i in range(n)], -1,
+                ) > 0
+            _tile(stored, valid, n * page_size)
 
         pl.when(blk == pl.num_programs(1) - 1)(_finish)
         return

@@ -53,6 +53,7 @@ __all__ = [
 # biases stay in bf16 — they are O(hidden) and scale-sensitive.
 QUANTIZED_WEIGHTS = (
     "wq", "wk", "wv", "wo", "wg", "wu", "wd",  # dense attention + MLP
+    "wq_a", "wq_b",                            # compressed queries (latent)
     "we_g", "we_u", "we_d",                    # MoE experts
     "ws_g", "ws_u", "ws_d",                    # shared experts
     # latent attention's own: ``wkv_a`` (its output IS the stored latent)

@@ -51,7 +51,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _NEG_INF
 
+#: what a device trace calls the int8 latent pool's ragged kernel under a
+#: learned selection (``cache/latent.py``: the indexed latent classes)
+KERNEL_LATENT_PREFILL = "sparse_latent_ragged_paged_attention"
+
 __all__ = [
+    "KERNEL_LATENT_PREFILL",
     "ragged_paged_attention",
     "quantized_ragged_paged_attention",
     "latent_ragged_paged_attention",
@@ -610,15 +615,28 @@ def quantized_latent_ragged_paged_attention(
     sliding_window: Optional[int] = None,
     block_q: Optional[int] = None,
     interpret: Optional[bool] = None,
+    select: Optional[jnp.ndarray] = None,
 ):
     """As :func:`latent_ragged_paged_attention` over the int8 latent pool
     (``cs_pages``: ``[P, 1, page_size]`` per-token f32 scales); the int8
-    pages stream through VMEM as-is and dequantize on the scores."""
+    pages stream through VMEM as-is and dequantize on the scores. Under a
+    learned selection (``select``: :func:`quantized_ragged_paged_attention`'s
+    ``[B, T, S, page_size]`` int8) it is one more term of the tile's mask,
+    traced as ``sparse_latent_ragged_paged_attention``; a call without one
+    keeps its operands and its name."""
+    rows = dict(
+        q_start=q_start, scale=scale, sliding_window=sliding_window,
+        block_q=block_q, interpret=interpret,
+    )
+    if select is None:
+        return quantized_ragged_paged_attention(
+            q, c_pages, cs_pages, c_pages, cs_pages, page_table, kv_lengths,
+            num_new, name="quantized_latent_ragged_paged_attention", **rows
+        )
     return quantized_ragged_paged_attention(
         q, c_pages, cs_pages, c_pages, cs_pages, page_table, kv_lengths,
-        num_new, q_start=q_start, scale=scale,
-        sliding_window=sliding_window, block_q=block_q, interpret=interpret,
-        name="quantized_latent_ragged_paged_attention",
+        num_new, name="sparse_latent_ragged_paged_attention", select=select,
+        **rows,
     )
 
 

@@ -101,6 +101,14 @@ METRICS = {
     "sparse_keys_live": (
         "counter", "Keys the same queries could see (their positions + 1)"
     ),
+    # where layers share a selection (ModelConfig.index_layers): scored /
+    # attended over an interval is the share of index scoring left
+    "index_layers_scored": (
+        "counter", "Layers that scored a selection of their own, a step"
+    ),
+    "index_layers_attended": (
+        "counter", "Layers that attended under a selection, a step"
+    ),
     # a stack of window and full layers (cache/paged.py, the two-pool
     # classes): what a window layer's queries see of their contexts, and
     # the window pool's pages as they leave rows and as they stand

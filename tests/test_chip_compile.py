@@ -318,6 +318,104 @@ def test_sparse_prefill_chunk_at_keyes_shapes(chip, width):
     assert "sparse_ragged_paged_attention" in text
 
 
+# glm-5.2.codebase: 64 query heads over ONE stored latent of 576, int8, 9
+# layers over 3584 pages of which 3 score a selection (an index plane of 3
+# rows of 128 bf16) and 6 reuse one, 32 index heads of 128, topk 2048, 16
+# rows, a 4096-wide chunk; the table pinned at 227 pages, its cap 256.
+GLM_HQ, GLM_LAT, GLM_RANK, GLM_LAYERS, GLM_PAGES, GLM_ROWS = 64, 576, 512, 9, 3584, 16
+GLM_HI, GLM_DI, GLM_TOPK = 32, 128, 2048
+GLM_SCORING = (True, False, False, False) * 2 + (True,)
+
+
+def _glm_cache(s, rows, width, seg, sel=None):
+    from distributed_llm_inference_tpu.cache.latent import (
+        indexed_latent_cache_class,
+    )
+
+    return indexed_latent_cache_class(True, GLM_DI, GLM_SCORING)(
+        k_pages=s((GLM_LAYERS, GLM_PAGES, 1, PS, GLM_LAT), I8),
+        v_pages=s((GLM_LAYERS, 1, 1, 1, 1), F32),
+        cs_pages=s((GLM_LAYERS, GLM_PAGES, 1, PS), F32),
+        ik_pages=s((3, GLM_PAGES, 1, PS, GLM_DI), jnp.bfloat16),
+        page_table=s((rows, width), I32), lengths=s((rows,), I32),
+        page_size=PS, use_kernel=True, use_ragged=True, seg=seg, sel=sel,
+    )
+
+
+def _glm_index(s, rows, seq):
+    from distributed_llm_inference_tpu.ops.sparse_attention import IndexInputs
+
+    bf16 = jnp.bfloat16
+    return lambda q, k, w: IndexInputs(q, k, w, GLM_TOPK), (
+        s((rows, seq, GLM_HI, GLM_DI), bf16), s((rows, seq, GLM_DI), bf16),
+        s((rows, seq, GLM_HI), bf16),
+    )
+
+
+@pytest.mark.parametrize("width", [227, 256])   # the cell's pinned table; the cap
+@pytest.mark.parametrize("kind", ["score", "reuse"])
+def test_shared_selection_decode_step_at_glms_shapes(chip, kind, width):
+    """One layer of one step of ``glm-5.2.codebase``'s decode scan, as the
+    indexed int8 latent cache runs it. A scoring layer (layer 4: row 1 of the
+    index plane): the index key into its tail, the scores of the pool's and
+    the tail's index keys, the exact selection, and the fused one-plane sweep
+    under its mask (``sparse_latent_paged_fused_attention``) over the WHOLE
+    9-layer pool, 64 heads against the 576-wide latent; a reusing layer: the
+    sweep under the selection the tail state carries. Then the window's
+    flush, the index tail's by its own name."""
+    s, b, bf16 = chip, GLM_ROWS, jnp.bfloat16
+    make, index = _glm_index(s, b, 1)
+    cache = _glm_cache(s, b, width, (kind, 1 - 4))
+
+    def step(cache, tail, q, c, iq, ik, iw, lens, lidx, step):
+        more = {"index": make(iq, ik, iw)} if kind == "score" else {}
+        out, tail = cache.tail_attend(
+            (*cache.tail_big_stacks(), lidx), tail, q, c, c, None, lens,
+            lens * 0, step, lens * 0 + 1, None, 256 ** -0.5, **more,
+        )
+        return out, cache.tail_flush(tail, lens * 0 + 1)
+
+    tail = jax.eval_shape(lambda c: c.tail_init(KT), cache)
+    tail = jax.tree.map(lambda x: s(x.shape, x.dtype), tail)
+    assert [x.shape[0] for x in tail[:3]] == [GLM_LAYERS, GLM_LAYERS, 3]
+    text = jax.jit(step).lower(
+        cache, tail, s((b, 1, GLM_HQ, GLM_LAT), bf16), s((b, 1, 1, GLM_LAT), bf16),
+        *index, s((b,), I32), s((), I32), s((), I32),
+    ).compile().as_text()
+    assert "sparse_latent_paged_fused_attention" in text
+    assert "paged_tail_flush" in text and "latent_index_tail_flush" in text
+
+
+@pytest.mark.parametrize("width", [227, 256])
+@pytest.mark.parametrize("kind", ["score", "reuse"])
+def test_shared_selection_prefill_chunk_at_glms_shapes(chip, kind, width):
+    """One layer of a 4096-wide chunk: the chunk's latents (and, in a scoring
+    layer, index keys) into the pool, every query's selection over the row's
+    whole table (scored a block of queries at a time) or the one the layer
+    state carries, and the ragged kernel over the one stored plane under the
+    (query, key) mask (``sparse_latent_ragged_paged_attention``)."""
+    from distributed_llm_inference_tpu.ops.attention import gqa_attention
+
+    s, seq, bf16 = chip, 4096, jnp.bfloat16
+    make, index = _glm_index(s, 1, seq)
+    cache = _glm_cache(s, 1, width, (kind, 1 - 4), s((1, 1, width, seq, PS), I8))
+
+    def chunk(cache, q, c, iq, ik, iw, q_pos, num_new):
+        rows = cache.stack_rows(4)
+        state = tuple(x[r] for x, r in zip(cache.layer_stacks, rows))
+        more = {"index": make(iq, ik, iw)} if kind == "score" else {}
+        return cache.attend(
+            state, q, c, c, None, q_pos, num_new, None, gqa_attention,
+            256 ** -0.5, **more,
+        )
+
+    text = jax.jit(chunk).lower(
+        cache, s((1, seq, GLM_HQ, GLM_LAT), bf16), s((1, seq, 1, GLM_LAT), bf16),
+        *index, s((1, seq), I32), s((1,), I32),
+    ).compile().as_text()
+    assert "sparse_latent_ragged_paged_attention" in text
+
+
 # k-exaone-236b-a23b.mixedlen: 64 query / 8 kv heads of 128, 9 window layers
 # (window 128) over a pool of their own of 512 pages beside 3 full layers over
 # 4608, int8, 32 rows, a 2048-wide chunk; the table pinned at 227 pages, its

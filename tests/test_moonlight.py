@@ -364,7 +364,7 @@ def test_from_hf_config_reads_the_published_block():
 
 
 @pytest.mark.parametrize("key,value", [
-    ("q_lora_rank", 1536),
+    ("topk_group", 4),
     ("n_group", 8),
     ("rope_scaling", {"type": "yarn", "factor": 40, "mscale": 1.0,
                       "mscale_all_dim": 1.0}),

@@ -70,10 +70,12 @@ def test_every_pallas_call_has_a_name_of_its_own():
             assert isinstance(value, ast.Constant), f"{path}:{line}"
             assert isinstance(value.value, str) and value.value
             names.setdefault(value.value, []).append(f"{path}:{line} {fn}")
-    assert len(names) >= 19
+    assert len(names) >= 21
     for kernel in ("latent_paged_attention", "quantized_latent_paged_attention",
                    "latent_ragged_paged_attention",
                    "quantized_latent_ragged_paged_attention",
+                   "sparse_latent_paged_fused_attention",
+                   "sparse_latent_ragged_paged_attention",
                    "moe_grouped_matmul"):
         assert kernel in names, kernel
     shared = {n: at for n, at in names.items() if len(at) > 1}
@@ -222,6 +224,77 @@ def test_a_lowered_sparse_forward_carries_the_selections_scopes_and_kernels():
         num_heads=2, num_kv_heads=2, head_dim=16,
     ))
     assert "index_scores" not in dense and "sparse_attention" not in dense
+
+
+def test_a_lowered_shared_selection_over_a_latent_carries_its_scopes_and_kernels():
+    """A latent stack whose layers share a learned selection (score, reuse,
+    reuse, score), through the indexed int8 latent cache with its kernels
+    (interpreted here): a segment's scope names its part in the selection
+    under its MLP kind; ``index_scores`` / ``index_select`` exist in the
+    scoring segments only and ``index_reuse`` in the reusing ones only; and
+    the latent kernels run under the selection's names, in a prefill chunk
+    and in the fused decode scan."""
+    from distributed_llm_inference_tpu.cache.latent import (
+        indexed_latent_cache_class,
+    )
+    from distributed_llm_inference_tpu.config import (
+        LatentConfig, SparseAttentionConfig,
+    )
+    from distributed_llm_inference_tpu.ops import paged_attention as pa
+    from distributed_llm_inference_tpu.ops import ragged_attention as ra
+
+    kinds = ("score", "reuse", "reuse", "score")
+    cfg = ModelConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=64, num_layers=4,
+        num_heads=2, num_kv_heads=2, head_dim=16,
+        latent=LatentConfig(rank=16, rope_head_dim=8, nope_head_dim=16,
+                            v_head_dim=16, q_lora_rank=24),
+        sparse=SparseAttentionConfig(2, 16, 4, rope_dim=8),
+        index_layers=kinds, num_experts=4, num_experts_per_tok=2,
+        moe_intermediate_size=16, num_shared_experts=1, first_dense_layers=1,
+        moe_scoring="sigmoid", moe_select_bias=True, family="glm_moe_dsa",
+    )
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    assert [k for k in params if k.startswith("layers")] == [
+        "layers_0_dense", "layers_1_moe", "layers_2_moe"]
+    assert "wk_i" in params["layers_0_dense"] and "wk_i" in params["layers_2_moe"]
+    assert "wk_i" not in params["layers_1_moe"] and "wq_a" in params["layers_1_moe"]
+    cache = indexed_latent_cache_class(
+        True, 16, tuple(k == "score" for k in kinds)
+    ).create(4, 1, 5, 8, 4, 1, 24, jnp.float32, use_kernel=True, use_ragged=True)
+    assert cache.ik_pages.shape[0] == 2
+    one = jnp.ones((1,), jnp.int32)
+    prefill = jax.jit(
+        lambda p, t, c: llama.model_apply(cfg, p, t, c, 8 * one, head="last")
+    ).lower(params, jnp.zeros((1, 8), jnp.int32), cache).as_text(debug_info=True)
+    decode = jax.jit(lambda p, t, c: llama.multi_decode_apply(
+        cfg, p, t, c, 4, lambda i, logits, st: (t[:, 0], one, st, logits),
+        jnp.zeros(()), one,
+    )).lower(params, jnp.zeros((1, 1), jnp.int32), cache).as_text(debug_info=True)
+    assert (pa.KERNEL_LATENT_DECODE, pa.KERNEL_LATENT_INDEX_FLUSH,
+            ra.KERNEL_LATENT_PREFILL) == (
+        "sparse_latent_paged_fused_attention", "latent_index_tail_flush",
+        "sparse_latent_ragged_paged_attention")
+    for text, kernels in (
+        (prefill, [ra.KERNEL_LATENT_PREFILL]),
+        (decode, [pa.KERNEL_LATENT_DECODE, pa.KERNEL_LATENT_INDEX_FLUSH,
+                  "paged_tail_flush"]),
+    ):
+        for scope in ("dense_stack/index_score_layers",
+                      "moe_stack/index_reuse_layers",
+                      "moe_stack/index_score_layers",
+                      "attention/index_scores", "attention/index_select",
+                      "attention/sparse_attention",
+                      "attention/index_reuse/sparse_attention"):
+            assert scoped(text, scope), scope
+        for kernel in kernels:
+            assert kernel in text, kernel
+        # a reusing segment scores nothing, a scoring one reuses nothing
+        lines = text.splitlines()
+        assert not [l for l in lines
+                    if "index_reuse_layers" in l and "index_scores" in l]
+        assert not [l for l in lines
+                    if "index_score_layers" in l and "index_reuse/" in l]
 
 
 def test_a_lowered_stack_of_two_attention_kinds_carries_a_scope_and_kernels_a_kind():
