@@ -182,7 +182,8 @@ class ModelConfig:
     # tokens past an expert's capacity (N·k/E · this factor) are dropped,
     # which also makes chunked prefill depend on chunk boundaries. None
     # (default) keeps the exact paths: the dropless grouped dispatch where
-    # a dispatch fills the experts' row tiles, dense-combine elsewhere.
+    # a dispatch fills the experts' row tiles, the live path where its
+    # tokens fit one tile (decode), dense-combine elsewhere.
     moe_capacity_factor: Optional[float] = None
     # Width of one routed expert (``moe_intermediate_size``); None =
     # ``intermediate_size`` (Mixtral: every MLP of the model is an expert).

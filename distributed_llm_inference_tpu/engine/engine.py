@@ -375,6 +375,8 @@ class InferenceEngine:
                     needed * cfg.num_expert_layers,
                     computed * cfg.num_expert_layers,
                     dispatch_path(cfg, rows, seq_len, sharded),
+                    # one layer's routed experts: held, and run a token
+                    (cfg.num_held_experts, computed - cfg.num_shared_experts),
                 )
 
             self.plan.expert_rows = expert_rows

@@ -41,7 +41,9 @@ The plan centralizes that policy:
   ``decode_grid_positions`` over every decode dispatch, for a routed
   model ``moe_expert_rows_needed`` / ``moe_expert_rows_computed`` and the
   dispatches by the experts' compute path (``moe_dispatch_grouped`` /
-  ``moe_dispatch_dense``), and
+  ``moe_dispatch_live`` / ``moe_dispatch_dense``) with, of a decode
+  dispatch, the held experts a step and those it is expected to read
+  (``moe_decode_experts_held`` / ``moe_decode_experts_live``), and
   under the ragged plan ``ragged_attn_tiles_live`` /
   ``ragged_attn_tiles_grid``: the tiles of the ragged kernel's grid that
   hold a live (query, key) pair, which it computes, over all it steps
@@ -319,7 +321,13 @@ class AttentionPlan:
         rows) times its steps, each step a dispatch of ``shape[0]`` x 1
         tokens to the experts. The path ``ops/moe.py:dispatch_path`` takes
         at the dispatch's shape counts it under ``moe_dispatch_grouped``,
-        ``moe_dispatch_dense`` or ``moe_dispatch_capacity``.
+        ``moe_dispatch_live``, ``moe_dispatch_dense`` or
+        ``moe_dispatch_capacity``. A decode dispatch also adds, for ONE
+        routed layer, the experts held times its steps to
+        ``moe_decode_experts_held`` and those a step reads to
+        ``moe_decode_experts_live``: every held one under dense-combine,
+        under the live path the ones its ``active_rows`` are EXPECTED to
+        pick (uniform routing; which they pick the host does not see).
 
         A prefill-family dispatch under the ragged plan over a paged cache
         also gives ``row_spans``, a ``(q_start, num_new)`` pair a real row
@@ -386,12 +394,15 @@ class AttentionPlan:
             else:
                 valid, padded = valid_tokens, shape[0] * shape[1]
                 seq_len = shape[1]
-            needed, computed, path = self.expert_rows(
+            needed, computed, path, (held, read) = self.expert_rows(
                 shape[0], seq_len, valid / max(padded, 1)
             )
             self.metrics.counter("moe_expert_rows_needed", valid * needed)
             self.metrics.counter("moe_expert_rows_computed", padded * computed)
             self.metrics.counter(f"moe_dispatch_{path}")
+            if kind == DECODE:
+                self.metrics.counter("moe_decode_experts_held", held * shape[1])
+                self.metrics.counter("moe_decode_experts_live", read * shape[1])
         if kind == DECODE:
             paged = self.ccfg.kind == "paged"
             grid = shape[0] * shape[2] * (self.ccfg.page_size if paged else 1)

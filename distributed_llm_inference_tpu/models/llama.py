@@ -375,14 +375,13 @@ def _mlp_residual(cfg, p, x, s, num_new):
         # The segment's kind, read off its leaves: a routed layer carries
         # a router.
         if "router" in p:
-            # Bucket-padding positions (>= num_new) must not consume expert
-            # capacity in the dispatched prefill path.
-            valid = None
-            if s > 1:
-                valid = (
-                    jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
-                    < num_new[:, None]
-                )
+            # Positions past a row's num_new pick no expert: bucket padding
+            # takes no row of a prefill's dispatch, and a decode step's
+            # stopped or empty slots (num_new 0) make no expert live.
+            valid = (
+                jax.lax.broadcasted_iota(jnp.int32, (b, s), 1)
+                < num_new[:, None]
+            )
             mlp = moe_mlp(cfg, p, h2, valid=valid)
         else:
             mlp = qmatmul(
@@ -528,11 +527,12 @@ def _layer_views(whole: Params, idx) -> Params:
 
 def _grouped_stacks(cfg: ModelConfig, layer_params: Params, x) -> tuple:
     """The expert stacks of a routed segment whose dispatch of ``x``'s shape
-    takes the grouped kernel (``ops/moe.py:traced_path``, what ``moe_mlp``
-    asks inside the layer), else none."""
+    takes the grouped kernel, by the grouped or the live path
+    (``ops/moe.py:traced_path``, what ``moe_mlp`` asks inside the layer),
+    else none."""
     from ..ops import moe
 
-    if "router" in layer_params and moe.traced_path(cfg, x) == "grouped":
+    if "router" in layer_params and moe.traced_path(cfg, x) in moe.KERNEL_PATHS:
         return moe.GROUPED_STACKS
     return ()
 
@@ -751,7 +751,13 @@ def multi_decode_apply(
         for name in names
     ]
     base_len = cache.lengths
-    split_w = [_split_whole_stacks(params[seg.key]) for seg in segments]
+    # a step's dispatch to the experts is the carried tokens' [B, 1]
+    split_w = [
+        _split_whole_stacks(
+            params[seg.key], _grouped_stacks(cfg, params[seg.key], tokens)
+        )
+        for seg in segments
+    ]
 
     def token_step(carry, i):
         tokens, tails, tail_len, num_new, state = carry

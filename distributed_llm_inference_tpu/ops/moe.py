@@ -9,12 +9,12 @@ a capability extension required for the Mixtral and DeepSeek-V2/V3 families.
 Routing is ONE function (:func:`route`) whose rule the config chooses:
 Mixtral's (softmax over ALL expert logits in fp32, top-k, renormalise) and
 DeepSeek-V3's (sigmoid scores, selection by score plus a per-expert bias,
-weights from the scores alone, normalised and scaled). Three
+weights from the scores alone, normalised and scaled). Four
 compute strategies sit behind it, all-static shapes; :func:`dispatch_path`
 picks one from the dispatch's shape, and ``moe_mlp`` has no option for it:
 
-* **dropless grouped dispatch** (the default wherever a dispatch's (token,
-  pick) pairs fill the held experts' row tiles: a prefill, a chunk) — every
+* **dropless grouped dispatch** (wherever a dispatch's (token, pick) pairs
+  fill the held experts' row tiles: a prefill, a chunk) — every
   token's own experts and no others. Pairs are stable-sorted by expert, each
   expert's group padded to ``ROW_TILE`` rows, and one Pallas kernel
   (:func:`grouped_matmul`, traced as ``moe_grouped_matmul``) runs gate, up
@@ -25,15 +25,31 @@ picks one from the dispatch's shape, and ``moe_mlp`` has no option for it:
   would serialize on TPU), and under a layer scan the kernel reads a
   layer's matrices out of the stack (:class:`LayerOf`: a slice a scan
   step would copy every held expert's weights before each call).
-* **dense-combine** (every decode and verify step, narrow buckets, and any
-  dispatch under a mesh) — every held expert processes every token and a
-  ``[B, S, held]`` combine matrix (zero off the top-k) weights the outputs.
-  A decode step of 4–32 rows is bound by READING every expert's weights
-  whatever it computes, so the overcompute is free there and the sort,
-  gathers and tile padding of the grouped path would only add to it; and
-  with experts sharded over ``ep`` the combine contraction becomes a
-  ``psum`` XLA inserts automatically, where the grouped path's
-  expert-indexed gathers trip GSPMD.
+* **the live path** (a dispatch whose tokens fit ONE row tile: every decode
+  step, a verify step or a bucket of a few tokens a row) — the experts that
+  a LIVE row picked and no others (``moe_mlp_live``). A step of 4–32 rows
+  is bound by READING expert weights, and most of what dense-combine reads
+  there is for rows nobody decodes: 4 live rows x 2 picks over 8 experts
+  touch 5.5 of them. The same kernel runs with one tile an expert, every
+  tile the dispatch's rows, the held experts ordered live first: a tile
+  past the live count fetches and computes nothing. A dead row (a stopped
+  or empty slot: ``valid`` false) picks nothing. Around the three calls
+  there is no sort of pairs, no gather and no permutation: the routing, one
+  ``argsort`` of a ``[held]`` bool and the ``[N, held]`` combine of
+  dense-combine over the tiles that were written. The stacks ride whole
+  here too. Measured on a v5e (``tools/profile_grouped_moe.py
+  --decode-rows``): the kernel streams a 16- or 32-row tile at 700–750 GB/s,
+  dense-combine's fusions at 733–749, so with every expert live the path
+  costs what dense-combine does (-0.3% at Mixtral's widths, +0.3–0.5% at a
+  share of 16 of 6144 x 2048, +2.5% extrapolated at 64 of 2048 x 1408,
+  where 32 rows leave two experts dead and it is 0.6% faster) and the rule
+  is the shape alone.
+* **dense-combine** (what lies between the two: a narrow bucket, a wide
+  verify; and any dispatch under a mesh) — every held expert processes
+  every token and a ``[B, S, held]`` combine matrix (zero off the top-k)
+  weights the outputs. With experts sharded over ``ep`` the combine
+  contraction becomes a ``psum`` XLA inserts automatically, where the
+  kernel's expert-indexed reads and the grouped path's gathers trip GSPMD.
 * **capacity dispatch** (opt-in, ``ModelConfig.moe_capacity_factor``) —
   the sorted dispatch with a capacity-bounded ``[E, C, H]`` einsum
   (``moe_mlp_dispatch``): it drops the pairs past an expert's capacity.
@@ -72,7 +88,7 @@ from . import quant
 __all__ = [
     "moe_mlp", "route", "router_weights", "expert_rows_per_token",
     "dispatch_path", "traced_path", "grouped_matmul", "LayerOf",
-    "GROUPED_STACKS",
+    "GROUPED_STACKS", "KERNEL_PATHS", "expected_live_experts",
 ]
 
 class LayerOf(NamedTuple):
@@ -98,9 +114,14 @@ class LayerOf(NamedTuple):
 # ``(2048, 1024)``.
 ROW_TILE = 128
 WEIGHT_BLOCK = (2048, 2048)
+# The live path pads its dispatch's tokens, its one tile, to a multiple of
+# this many rows (bf16's sublane tile).
+LIVE_ROWS = 16
 # The leaves of a routed layer that :func:`grouped_matmul` reads: the ones
-# a layer scan hands over whole, as :class:`LayerOf` views.
+# a layer scan hands over whole, as :class:`LayerOf` views, where the
+# dispatch takes one of the paths that run the kernel.
 GROUPED_STACKS = ("we_g", "we_u", "we_d")
+KERNEL_PATHS = ("grouped", "live")
 
 
 def route(cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray, bias=None):
@@ -135,7 +156,8 @@ def route(cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray, bias=None):
 
 
 def router_weights(
-    cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray, bias=None
+    cfg: ModelConfig, x: jnp.ndarray, router: jnp.ndarray, bias=None,
+    valid=None,
 ) -> jnp.ndarray:
     """:func:`route` as the dense combine matrix.
 
@@ -143,15 +165,30 @@ def router_weights(
     Returns ``[B, S, held]``: a token's routing weights at those of its
     selected experts that are held here, 0 elsewhere (``held`` = E unless
     the layer holds a share; a pick outside the share matches no column).
+    A token whose ``valid`` (``[B, S]`` bool) is false picks nothing: its
+    row is zero whatever its hidden state holds.
     """
+    return _combine_matrix(cfg, x, router, bias, valid)[0]
+
+
+def _combine_matrix(cfg: ModelConfig, x, router, bias=None, valid=None):
+    """``(combine [B, S, held] fp32, picked [held] bool)``: the matrix of
+    :func:`router_weights`, and which held experts some valid token
+    selected."""
+    held = cfg.num_held_experts
     with jax.named_scope("moe_router"):
         top_p, top_i = route(cfg, x, router, bias)
         if cfg.expert_shares > 1:
             top_i = top_i - cfg.first_held_expert
-        one_hot = jax.nn.one_hot(
-            top_i, cfg.num_held_experts, dtype=jnp.float32
-        )
-        return jnp.einsum("bsk,bske->bse", top_p, one_hot)
+        if valid is not None:
+            # select, never multiply: a dead row's scores may be anything
+            top_p = jnp.where(valid[..., None], top_p, 0)
+            top_i = jnp.where(valid[..., None], top_i, held)
+        # a pick past the held columns (another share's, a dead row's)
+        # matches none
+        one_hot = jax.nn.one_hot(top_i, held, dtype=jnp.float32)
+        combine = jnp.einsum("bsk,bske->bse", top_p, one_hot)
+        return combine, jnp.any(one_hot > 0, axis=(0, 1, 2))
 
 
 def under_mesh() -> bool:
@@ -167,23 +204,28 @@ def dispatch_path(
     cfg: ModelConfig, rows: int, seq_len: int, sharded: bool = False
 ) -> str:
     """The compute strategy of a dispatch of ``rows x seq_len`` tokens:
-    ``"grouped"``, ``"dense"`` or ``"capacity"`` (module docstring). A
-    static function of the shape and the config.
+    ``"grouped"``, ``"live"``, ``"dense"`` or ``"capacity"`` (module
+    docstring). A static function of the shape and the config.
 
     Grouped where the pairs each expert EXPECTS fill a row tile, ``rows x
     seq_len x k / E >= ROW_TILE``: below that most of a tile is padding and
-    the weight reads, which dense-combine pays as well, lead. That leaves
-    every decode and verify step (``seq_len`` of 1 to a few, 4–32 rows)
-    and narrow buckets dense. A ``sharded`` program stays dense whatever
-    its shape: the grouped path's expert-indexed gathers trip GSPMD under
-    ``ep``/``tp``. ``ModelConfig.moe_capacity_factor`` still opts a
-    prefill-scale dispatch in to the capacity form.
+    the weight reads lead. Live where the dispatch's tokens all fit ONE
+    row tile, ``rows x seq_len <= ROW_TILE``: every decode step and the
+    verify steps and buckets of a few tokens a row (the two never meet:
+    ``E > k``). What lies between (a narrow bucket, a wide verify) is
+    dense, and so is a ``sharded`` program whatever its shape: the
+    kernel's expert-indexed reads and the grouped path's gathers trip
+    GSPMD under ``ep``/``tp``. ``ModelConfig.moe_capacity_factor`` still
+    opts a prefill-scale dispatch in to the capacity form.
     """
     if cfg.moe_capacity_factor is not None and seq_len >= 16:
         return "capacity"
-    pairs = rows * seq_len * cfg.num_experts_per_tok
-    if not sharded and pairs >= cfg.num_experts * ROW_TILE:
-        return "grouped"
+    tokens = rows * seq_len
+    if not sharded:
+        if tokens * cfg.num_experts_per_tok >= cfg.num_experts * ROW_TILE:
+            return "grouped"
+        if tokens <= ROW_TILE:
+            return "live"
     return "dense"
 
 
@@ -192,6 +234,15 @@ def traced_path(cfg: ModelConfig, x: jnp.ndarray) -> str:
     program being traced sees it: what ``moe_mlp`` will take, and what a
     layer scan asks before it hands the expert stacks over."""
     return dispatch_path(cfg, x.shape[0], x.shape[1], under_mesh())
+
+
+def expected_live_experts(cfg: ModelConfig, tokens: float) -> float:
+    """The held experts that ``tokens`` valid tokens are EXPECTED to pick
+    between them under uniform routing: each misses a given expert with
+    probability ``1 - k / E``, so ``held x (1 - (1 - k / E)^tokens)``.
+    Which they pick is data the host does not see."""
+    miss = 1.0 - cfg.num_experts_per_tok / cfg.num_experts
+    return cfg.num_held_experts * (1.0 - miss ** tokens)
 
 
 def expert_rows_per_token(
@@ -208,12 +259,15 @@ def expert_rows_per_token(
     :func:`dispatch_path` takes there: every routed HELD expert under
     dense-combine; its capacity's share under the capacity form; under the
     grouped dispatch the valid tokens' own picks plus half a row tile an
-    expert, what padding each group to whole tiles costs on average. The
+    expert, what padding each group to whole tiles costs on average; under
+    the live path the experts the dispatch's valid tokens are expected to
+    pick between them (:func:`expected_live_experts`: every row of the
+    one tile passes through each of them). The
     census behind ``moe_expert_rows_*``. Where the layer holds a share,
     ``needed`` is an EXPECTATION: of a token's ``k`` picks over the
     router's ``E``, ``k * held / E`` fall here on average (uniform
     routing); which do is data the host does not see, and so is how full
-    a group's last tile is."""
+    a group's last tile is and which experts a step's rows pick."""
     shared = cfg.num_shared_experts
     k = cfg.num_experts_per_tok
     held = cfg.num_held_experts
@@ -225,6 +279,9 @@ def expert_rows_per_token(
     if path == "grouped":
         tile_pad = held * ROW_TILE / 2 / (rows * seq_len)
         return k + shared, k * valid_share + tile_pad + shared
+    if path == "live":
+        live = expected_live_experts(cfg, rows * seq_len * valid_share)
+        return k + shared, live + shared
     return k + shared, held + shared
 
 
@@ -266,21 +323,26 @@ def moe_mlp(
     trace runs under a mesh; there is no option. A prefill-scale dispatch
     takes the dropless grouped dispatch (:func:`moe_mlp_grouped`): each
     token's own experts, exact, and independent of co-batched rows and of
-    chunk boundaries, which is what lets it be the default. Decode and
-    verify steps, narrow buckets and sharded programs keep dense-combine,
-    bit for bit as before: equally exact and row-independent, and a step
-    of a few rows is bound by reading every expert's weights whatever it
-    computes, so the overcompute is free there.
+    chunk boundaries, which is what lets it be the default. A dispatch
+    whose tokens fit one row tile (a decode or verify step) takes the live
+    path (:func:`moe_mlp_live`): dense-combine's sum over the experts a
+    valid token picked, whose weights are the only ones read, at the
+    grouped kernel's precision. What lies between and sharded programs
+    keep dense-combine, the latter bit for bit as before.
     ``ModelConfig.moe_capacity_factor`` still OPTS IN to the capacity form
     (S >= 16), whose drops make results depend on chunk boundaries.
-    ``valid`` (``[B, S]`` bool) marks real tokens; bucket-padding positions
-    take no row of an expert in either sorted path.
+    ``valid`` (``[B, S]`` bool) marks real tokens: bucket padding and a
+    decode step's dead rows take no row of an expert in either sorted
+    path and make no expert live; dense-combine computes them like any
+    other (their results are read by nobody).
     """
     path = traced_path(cfg, x)
     if path == "capacity":
         out = moe_mlp_dispatch(cfg, p, x, cfg.moe_capacity_factor, valid)
     elif path == "grouped":
         out = moe_mlp_grouped(cfg, p, x, valid)
+    elif path == "live":
+        out = moe_mlp_live(cfg, p, x, valid)
     elif x.shape[1] > DENSE_COMBINE_TOKENS and x.shape[1] % DENSE_COMBINE_BLOCK == 0:
         # A wide dispatch (a 4096-wide chunk over 128 experts: ``[b, s, E,
         # H]`` alone is 2.1 GB in bf16) walks its tokens a block at a
@@ -396,12 +458,14 @@ def grouped_matmul(
     """Rows grouped by expert times their expert's matrix, ragged.
 
     ``x``: ``[M, K]``, ``M`` whole ``row_tile``s, every tile's rows ONE
-    expert's; ``w``: ``[E, K, N]`` or its int8 :class:`QuantizedTensor`,
+    expert's, or ONE tile of rows that every tile shares (``M ==
+    row_tile``: the live path's gate and up, each expert over the same
+    tokens); ``w``: ``[E, K, N]`` or its int8 :class:`QuantizedTensor`,
     or a :class:`LayerOf` the layer-stacked form of either;
-    ``tile_expert``: ``[M / row_tile]`` int32, a tile's expert;
+    ``tile_expert``: ``[tiles]`` int32, a tile's expert;
     ``live_tiles``: int32 scalar, the leading tiles that hold rows.
-    Returns ``[M, N]`` in ``x``'s dtype; rows of a tile past the live ones
-    are NOT written (the caller reads none of them).
+    Returns ``[tiles * row_tile, N]`` in ``x``'s dtype; rows of a tile past
+    the live ones are NOT written (the caller reads none of them).
 
     The grid is (out blocks, row tiles, in blocks), the weight block of the
     tile's expert (and of the view's layer) chosen from the prefetched
@@ -410,9 +474,14 @@ def grouped_matmul(
     the per-(expert, out channel) scale once, at the end:
     ``quant.einsum``'s precision, with the scale applied before the
     rounding to ``x``'s dtype and not after it.
+
+    ``interpret``: None runs the kernel on a TPU and, on any other
+    backend, the same product in plain XLA (:func:`_grouped_reference`:
+    every routed model's decode step reaches this function, and the
+    interpreted kernel costs a CPU program about a second of lowering and
+    compiling a call); True interprets the kernel there (the tests of the
+    kernel itself), False compiles it.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     blocks = blocks or WEIGHT_BLOCK
     layer = 0
     if isinstance(w, LayerOf):
@@ -424,10 +493,15 @@ def grouped_matmul(
     elif quantized:
         # the scales are small: a layer's ride as a slice
         scale = jax.lax.dynamic_index_in_dim(scale, layer, 0, keepdims=False)
+    if interpret is None and jax.default_backend() != "tpu":
+        return _grouped_reference(
+            x, wq, scale, layer, tile_expert, live_tiles, row_tile
+        )
     m, k_dim = x.shape
     _, e, _, n_dim = wq.shape
     bk, bn = _block(k_dim, blocks[0]), _block(n_dim, blocks[1])
-    n_k, n_n, tiles = k_dim // bk, n_dim // bn, m // row_tile
+    n_k, n_n, tiles = k_dim // bk, n_dim // bn, tile_expert.shape[0]
+    shared_rows = m == row_tile
 
     def tile(t, at):
         # the tile whose blocks a step names: itself, or the last live one
@@ -439,7 +513,9 @@ def grouped_matmul(
     in_specs = [
         pl.BlockSpec(
             (row_tile, bk),
-            lambda nn, t, kk, te, at: (tile(t, at), k_block(t, kk, at)),
+            lambda nn, t, kk, te, at: (
+                0 if shared_rows else tile(t, at), k_block(t, kk, at)
+            ),
         ),
         pl.BlockSpec(
             (1, 1, bk, bn),
@@ -466,7 +542,7 @@ def grouped_matmul(
             ),
             scratch_shapes=[pltpu.VMEM((row_tile, bn), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((m, n_dim), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((tiles * row_tile, n_dim), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=100 * 1024 * 1024,
@@ -479,6 +555,31 @@ def grouped_matmul(
         ]),
         *operands,
     )
+
+
+def _grouped_reference(x, wq, scale, layer, tile_expert, live_tiles, row_tile):
+    """:func:`grouped_matmul` in plain XLA, for a backend the kernel does
+    not compile for: each tile's rows times its expert's matrix of the
+    layer, at the kernel's precision (an f32 sum, the scale before the
+    rounding). The rows of a tile past the live ones, which the kernel
+    leaves unwritten, are NaN here: a caller that multiplies where it
+    should select shows."""
+    tiles = tile_expert.shape[0]
+    with jax.named_scope("moe_grouped_matmul"):
+        w = jax.lax.dynamic_index_in_dim(wq, layer, 0, keepdims=False)
+        rows = jnp.broadcast_to(
+            x.reshape(-1, row_tile, x.shape[-1]),
+            (tiles, row_tile, x.shape[-1]),
+        )
+        acc = jnp.einsum(
+            "trk,tkn->trn", rows, w[tile_expert].astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        if scale is not None:
+            acc = acc * scale[tile_expert].astype(jnp.float32)[:, None, :]
+        written = jnp.arange(tiles, dtype=jnp.int32) < live_tiles
+        out = jnp.where(written[:, None, None], acc, jnp.nan).astype(x.dtype)
+        return out.reshape(tiles * row_tile, -1)
 
 
 def moe_mlp_grouped(
@@ -563,6 +664,58 @@ def moe_mlp_grouped(
             "nk,nkh->nh", top_p.astype(jnp.float32),
             pair_out.astype(jnp.float32),
         )
+        return out.reshape(b, s, h).astype(x.dtype)
+
+
+def moe_mlp_live(
+    cfg: ModelConfig, p, x: jnp.ndarray, valid=None, blocks=None
+) -> jnp.ndarray:
+    """The routed sum of :func:`moe_mlp` for a dispatch whose tokens fit
+    one row tile (a decode or verify step): dense-combine restricted to
+    the experts that a VALID token picked, whose weights are the only ones
+    read.
+
+    The tokens ride as one tile of ``LIVE_ROWS``-padded rows, a dead row
+    (``valid`` false: a stopped or empty slot, bucket padding) zeroed and
+    picking nothing. The held experts are ordered live first (one stable
+    ``argsort`` of a ``[held]`` bool) and gate, up and down are three calls
+    of :func:`grouped_matmul` with one tile an expert in that order, every
+    tile the dispatch's rows: a tile past the live count fetches and
+    computes nothing. The combine is the ``[N, held]`` matrix of
+    :func:`router_weights`, its columns in the same order, over the tiles
+    that were written (selected, never multiplied: an unwritten tile holds
+    anything). No pair is sorted, no row gathered, nothing permuted back;
+    the precision is the grouped dispatch's (int8 read and converted in
+    VMEM, an f32 accumulator, the scale before the rounding, the combine
+    in f32). Every row dead: zeros.
+    """
+    b, s, h = x.shape
+    e, n = cfg.num_held_experts, b * s
+    tile = -(-n // LIVE_ROWS) * LIVE_ROWS
+    combine, picked = _combine_matrix(
+        cfg, x, p["router"], p.get("router_bias"), valid
+    )
+    with jax.named_scope("moe_sort"):
+        order = jnp.argsort(~picked, stable=True).astype(jnp.int32)
+        live = jnp.sum(picked, dtype=jnp.int32)
+        xf = x.reshape(n, h)
+        if valid is not None:
+            xf = jnp.where(valid.reshape(n, 1), xf, 0)
+        xf = jnp.pad(xf, ((0, tile - n), (0, 0)))
+    mm = functools.partial(
+        grouped_matmul, tile_expert=order, live_tiles=live, row_tile=tile,
+        blocks=blocks,
+    )
+    with jax.named_scope("moe_experts"):
+        t = mm(xf, p["we_g"])
+        u = mm(xf, p["we_u"])
+        y = mm(jax.nn.silu(t) * u, p["we_d"])
+    with jax.named_scope("moe_combine"):
+        written = jnp.arange(e, dtype=jnp.int32) < live
+        y = jnp.where(
+            written[:, None, None], y.reshape(e, tile, h)[:, :n], 0
+        ).astype(jnp.float32)
+        out = jnp.einsum("ne,enh->nh", combine.reshape(n, e)[:, order], y)
         return out.reshape(b, s, h).astype(x.dtype)
 
 

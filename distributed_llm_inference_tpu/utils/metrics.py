@@ -81,9 +81,19 @@ METRICS = {
     ),
     # dispatches of a routed model by the path its experts took
     # (ops/moe.py:dispatch_path, from the dispatch's shape): grouped,
-    # dense or capacity
+    # live, dense or capacity
     "moe_dispatch_*": (
         "counter", "Dispatches by the experts' compute path at their shape"
+    ),
+    # a decode dispatch's steps in one routed layer: live / held is the
+    # share of the held experts' weights a step reads
+    "moe_decode_experts_held": (
+        "counter", "Experts a routed layer holds, a decode step"
+    ),
+    "moe_decode_experts_live": (
+        "counter",
+        "Of those, the experts a step's active rows are expected to pick "
+        "(an expectation under uniform routing; all held where dense)",
     ),
     # the ragged prefill kernel's grid, a layer's a dispatch: live / grid
     # is the share of its steps that compute (ops/ragged_attention.py)

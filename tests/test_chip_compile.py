@@ -556,3 +556,43 @@ def test_grouped_moe_kernel(chip, hidden, ffn, held, pairs):
         gate_and_down, s((rows, hidden), jnp.bfloat16), stack(hidden, ffn),
         stack(ffn, hidden), s((tiles,), I32), s((), I32), s((), I32),
     )
+
+
+@pytest.mark.parametrize(
+    "hidden,ffn,held,rows",
+    [
+        (4096, 14336, 8, 16),       # rag: 16 slots over 8 experts
+        (2048, 1408, 64, 32),       # reason1k: 32 slots over 64
+        (2048, 768, 128, 16),       # longdoc: 16 slots over 128
+        (6144, 2048, 16, 32),       # mixedlen, glm: a share of 16
+        (4096, 14336, 8, 128),      # the widest dispatch the rule sends
+    ],
+    ids=["8x14336", "64x1408", "128x768", "16x2048-share", "128-rows"],
+)
+def test_live_moe_kernel_at_the_decode_shapes(chip, hidden, ffn, held, rows):
+    """The live path's three calls of ``moe_grouped_matmul`` at a decode
+    step's shape: one tile of the dispatch's rows an expert, gate and up
+    over ONE shared tile of tokens, down over a tile an expert, each
+    layer's int8 matrices read out of a two-layer stack."""
+    from distributed_llm_inference_tpu.ops import moe
+    from distributed_llm_inference_tpu.ops.quant import QuantizedTensor
+
+    s = chip
+    stack = lambda k, n: QuantizedTensor(
+        q=s((2, held, k, n), I8), scale=s((2, held, n), jnp.bfloat16)
+    )
+
+    def gate_up_down(x, wg, wu, wd, order, live, layer):
+        mm = lambda x, w: moe.grouped_matmul(
+            x, moe.LayerOf(w, layer), order, live, row_tile=rows,
+            interpret=False,
+        )
+        t = mm(x, wg)
+        assert t.shape == (held * rows, ffn)
+        return mm(jax.nn.silu(t) * mm(x, wu), wd)
+
+    _compiles_with_kernel(
+        gate_up_down, s((rows, hidden), jnp.bfloat16), stack(hidden, ffn),
+        stack(hidden, ffn), stack(ffn, hidden), s((held,), I32), s((), I32),
+        s((), I32),
+    )

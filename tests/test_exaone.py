@@ -258,8 +258,12 @@ def test_the_shares_parts_and_the_shared_expert_once_are_the_uncut_layer():
     np.testing.assert_allclose(total + shared, want, rtol=2e-5, atol=2e-6)
     # a token's needed rows are an expectation where the layer holds a share
     cfg = ModelConfig.from_hf_config(tiny_hf(shares=4, experts=8))
-    assert moe_ops.expert_rows_per_token(cfg, 1) == (3 * 2 / 8 + 1, 2 + 1)
-    assert moe_ops.expert_rows_per_token(whole, 1) == (3 + 1, 8 + 1)
+    # ... and so are the held experts ONE token makes live (the live path);
+    # under a mesh the program runs both held ones
+    assert moe_ops.expert_rows_per_token(cfg, 1) == (3 * 2 / 8 + 1, 2 * 3 / 8 + 1)
+    assert moe_ops.expert_rows_per_token(cfg, 1, sharded=True) == (3 * 2 / 8 + 1, 2 + 1)
+    assert moe_ops.expert_rows_per_token(whole, 1) == (3 + 1, 3 + 1)
+    assert moe_ops.expert_rows_per_token(whole, 1, sharded=True) == (3 + 1, 8 + 1)
 
 
 # -- (c) pages really leave ------------------------------------------------------
@@ -360,7 +364,11 @@ def test_what_a_two_pool_stack_cannot_do_is_refused_by_name(model):
 #: trace to is not this test's business to forbid: regenerate, and say why.
 #: PR 42 regenerated the three decode scans that hold the in-place sweep by
 #: copies (this suite's narrow latent pool takes it too): its body attends a
-#: block of live pages as one tile. The ten others are cdb55a4's still.
+#: block of live pages as one tile. PR 46 regenerated Moonlight's six: its
+#: decode scans and these toy prefills (a few tokens: one row tile) take the
+#: live path of ``ops/moe.py`` where they dense-combined (traced here, off a
+#: TPU, with ``grouped_matmul``'s plain-XLA reference). Mistral's seven are
+#: cdb55a4's (and PR 42's) still.
 OLD_STACKS = {
     "mistral.float.prefill": "ce04728d66ae8e7a",
     "mistral.int8.prefill": "0332a71023c2ddb3",
@@ -369,12 +377,12 @@ OLD_STACKS = {
     "mistral.kernel.8x4.prefill": "fe9e79069e35d0cc",
     "mistral.kernel.64x12.decode_scan": "7db7f3834be39c7c",
     "mistral.kernel.64x12.prefill": "1701601a075c440e",
-    "moonlight.float.prefill": "d3ff937d406c341c",
-    "moonlight.int8.prefill": "60f3cefd4c998403",
-    "moonlight.kernel.8x4.decode_scan": "1b468ae6c54a2732",
-    "moonlight.kernel.8x4.prefill": "9dd667151807f501",
-    "moonlight.kernel.64x12.decode_scan": "36f66e2a795a7227",
-    "moonlight.kernel.64x12.prefill": "d560c9cda3a0d146",
+    "moonlight.float.prefill": "eac83a25e1c45a4d",
+    "moonlight.int8.prefill": "c3335515e1e0ab1c",
+    "moonlight.kernel.8x4.decode_scan": "ddd63de5e8155649",
+    "moonlight.kernel.8x4.prefill": "2426df667f55c3a6",
+    "moonlight.kernel.64x12.decode_scan": "1a03c4bdef39fb36",
+    "moonlight.kernel.64x12.prefill": "cc062e11f80c521d",
 }
 
 

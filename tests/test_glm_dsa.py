@@ -551,31 +551,37 @@ def test_what_cannot_carry_the_plane_is_refused_by_name():
 #: this suite's ``conftest.py`` (its matmul precision is in the jaxprs); jax
 #: 0.9.0. A later change to what these stacks trace to is not this test's
 #: business to forbid: regenerate, and say why.
+#: PR 46 regenerated the nineteen of the four ROUTED stacks: their decode
+#: scans and these toy prefills (a few tokens: one row tile) take the live
+#: path of ``ops/moe.py`` where they dense-combined, and a routed layer's
+#: ``valid`` now marks a decode step's dead rows (traced here, off a TPU, with
+#: ``grouped_matmul``'s plain-XLA reference). Mistral's five are a45a1d5's
+#: still.
 OLD_STACKS = {
     "mistral.float.prefill": "ce04728d66ae8e7a",
     "mistral.int8.prefill": "0332a71023c2ddb3",
     "mistral.int8.decode_scan": "6fe8c360a5b7a039",
     "mistral.kernel.decode_scan": "7db7f3834be39c7c",
     "mistral.kernel.prefill": "1701601a075c440e",
-    "mixtral.float.prefill": "3782f09956b256d8",
-    "mixtral.int8.prefill": "67c668f261c3d6da",
-    "mixtral.int8.decode_scan": "3ab4cd187e0b6052",
-    "mixtral.kernel.decode_scan": "ddcb07c124b65e0d",
-    "mixtral.kernel.prefill": "9fc0107605e1988c",
-    "moonlight.float.prefill": "d3ff937d406c341c",
-    "moonlight.int8.prefill": "60f3cefd4c998403",
-    "moonlight.kernel.decode_scan": "36f66e2a795a7227",
-    "moonlight.kernel.prefill": "d560c9cda3a0d146",
-    "keye.float.prefill": "f5d78bc087ab266a",
-    "keye.int8.prefill": "ba55422b931bf8a2",
-    "keye.int8.decode_scan": "425ff0d4a74e31a3",
-    "keye.kernel.decode_scan": "4a3d2a45ac3e45e1",
-    "keye.kernel.prefill": "699a7d7fbe385c7f",
-    "exaone.float.prefill": "700e9d28acda75eb",
-    "exaone.int8.prefill": "16e69d7ec249939d",
-    "exaone.int8.decode_scan": "761906b5ee024e1d",
-    "exaone.kernel.decode_scan": "23788ff50e61e25e",
-    "exaone.kernel.prefill": "99d164b0e7565677",
+    "mixtral.float.prefill": "4e3b03187d0d0786",
+    "mixtral.int8.prefill": "93ee1d0335f7b09d",
+    "mixtral.int8.decode_scan": "9909d784bd1bcad9",
+    "mixtral.kernel.decode_scan": "4c273ed9094c8b88",
+    "mixtral.kernel.prefill": "1af9a095fea921f2",
+    "moonlight.float.prefill": "eac83a25e1c45a4d",
+    "moonlight.int8.prefill": "c3335515e1e0ab1c",
+    "moonlight.kernel.decode_scan": "1a03c4bdef39fb36",
+    "moonlight.kernel.prefill": "cc062e11f80c521d",
+    "keye.float.prefill": "48e8f6a2f7dab6c5",
+    "keye.int8.prefill": "d40dfc05f0515bef",
+    "keye.int8.decode_scan": "739397ed2aa75e26",
+    "keye.kernel.decode_scan": "2c7e04a6d856eb13",
+    "keye.kernel.prefill": "444e13ff9fa7c7ac",
+    "exaone.float.prefill": "89a759304ca1689c",
+    "exaone.int8.prefill": "40accbd22a444365",
+    "exaone.int8.decode_scan": "7472671651de15b1",
+    "exaone.kernel.decode_scan": "e5e628d5025e8a89",
+    "exaone.kernel.prefill": "c77701ce6ffac032",
 }
 
 

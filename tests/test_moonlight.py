@@ -494,14 +494,24 @@ def test_the_census_counts_latent_and_expert_work_exactly():
     assert m.get_counter("decode_live_positions") == sum(d[2] for d in decodes)
     assert m.get_counter("decode_grid_positions") == sum(
         d[1][0] * d[1][2] * engine.ccfg.page_size for d in decodes)
-    # expert rows: 2 expert layers; a token needs 3 picks + 1 shared and the
-    # program runs all 8 + 1 (dense-combine)
+    # expert rows: 2 expert layers; a token needs 3 picks + 1 shared. These
+    # dispatches each fit one row tile, so the program runs the live path:
+    # a padded token passes through the shared expert and the experts its
+    # dispatch's valid tokens are expected to pick between them, of 8
     decode_valid = m.get_counter("moe_expert_rows_needed") / (2 * 4) - valid
-    decode_padded = m.get_counter("moe_expert_rows_computed") / (2 * 9) - padded
-    assert decode_padded == sum(d[1][0] * d[1][1] for d in decodes)
     # 4 decode tokens a request after the prefill's first: the active rows
     assert decode_valid == 2 * 4
-    assert 0 < decode_valid <= decode_padded
+    assert all(d[1][0] * d[1][1] <= 128 for d in prefills)
+    # (2 active rows of the rehearsal's 4 slots: decode_valid above)
+    live = lambda tokens: 8 * (1 - (1 - 3 / 8) ** tokens)
+    computed = sum(d[1][0] * d[1][1] * (live(d[2]) + 1) for d in prefills)
+    computed += sum(d[1][0] * d[1][1] * (live(2) + 1) for d in decodes)
+    assert m.get_counter("moe_expert_rows_computed") == pytest.approx(2 * computed)
+    assert m.get_counter("moe_dispatch_live") == len(seen)
+    assert m.get_counter("moe_decode_experts_held") == sum(8 * d[1][1] for d in decodes)
+    assert m.get_counter("moe_decode_experts_live") == pytest.approx(
+        sum(live(2) * d[1][1] for d in decodes)
+    )
 
 
 # -- the int8 latent pool's write-behind tail, through the engine -------------

@@ -82,7 +82,7 @@ def test_every_pallas_call_has_a_name_of_its_own():
     assert not shared, shared
 
 
-def lowered_text(cfg):
+def lowered_text(cfg, tokens=4):
     params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     cache = DenseKVCache.create(
         cfg.num_layers, 1, 16, cfg.num_kv_heads, cfg.head_dim, jnp.float32
@@ -97,7 +97,7 @@ def lowered_text(cfg):
         return sample(logits[:, 0], key, sp), cache
 
     return jax.jit(forward).lower(
-        params, jnp.zeros((1, 4), jnp.int32), cache, jax.random.PRNGKey(1)
+        params, jnp.zeros((1, tokens), jnp.int32), cache, jax.random.PRNGKey(1)
     ).as_text(debug_info=True)
 
 
@@ -133,7 +133,9 @@ def test_a_lowered_grouped_moe_prefill_carries_the_kernel_and_its_scopes(monkeyp
     """A prefill wide enough for the grouped dispatch (at a row tile of 8,
     32 tokens are): the sort's scope beside the three of a routed MLP and
     three calls of ``moe_grouped_matmul`` a routed layer's scan body; a
-    4-token step of the same model has neither."""
+    4-token step of the same model (one row tile: the live path) carries
+    the same four and the kernel; 12 tokens, between the two, dense-combine
+    and have neither the sort nor the kernel."""
     from distributed_llm_inference_tpu.ops import moe
 
     monkeypatch.setattr(moe, "ROW_TILE", 8)
@@ -155,8 +157,13 @@ def test_a_lowered_grouped_moe_prefill_carries_the_kernel_and_its_scopes(monkeyp
                   "mlp/moe_combine"):
         assert scoped(text, scope), scope
     assert "moe_grouped_matmul" in text
-    narrow = lowered_text(cfg)
-    assert "moe_grouped_matmul" not in narrow and "moe_sort" not in narrow
+    step = lowered_text(cfg)
+    for scope in ("mlp/moe_router", "mlp/moe_sort", "mlp/moe_experts",
+                  "mlp/moe_combine"):
+        assert scoped(step, scope), scope
+    assert "moe_grouped_matmul" in step
+    between = lowered_text(cfg, tokens=12)
+    assert "moe_grouped_matmul" not in between and "moe_sort" not in between
 
 
 def test_a_lowered_two_segment_forward_carries_a_scope_a_segment():

@@ -1,10 +1,13 @@
 """Time one layer's routed expert MLP alone, on the chip: the dropless
-grouped dispatch against dense-combine, at the four routed configurations'
-widths and pad shares.
+grouped dispatch against dense-combine at the five routed configurations'
+widths and pad shares, and a decode step's live path against dense-combine
+by the rows that are live.
 
     python tools/profile_grouped_moe.py [--configs mixtral-8x7b-8l ...]
         [--rows 1 2] [--valid 0.25 0.75 1.0] [--row-tile 128]
         [--blocks 2048 2048] [--dense 1] [--layers 2] [--stacks whole]
+    python tools/profile_grouped_moe.py --decode-rows 16 32
+        --live-rows 2 4 8 16 32 [--configs ...] [--layers 2] [--ops 8]
 
 A case is a prefill dispatch of ``rows`` prompts padded to the
 configuration's width (its ``prefill_chunk_tokens``), the leading ``valid``
@@ -22,6 +25,16 @@ same tokens; ``--dense 0`` leaves it out), the largest operations, what
 ``dispatch_path`` would pick at this tile, and the largest difference
 between the two forms' results. The shared experts run the same in both and
 are left out.
+
+``--decode-rows`` times a DECODE step instead: a dispatch of that many
+slots x 1 token of which the leading ``--live-rows`` hold a request (the
+others dead, ``valid`` false), through the live path
+(``ops/moe.py:moe_mlp_live``) and through dense-combine. Its line gives
+``live_ms``, ``kernel_ms`` and ``dense_ms`` a layer, the experts the live
+rows picked (``experts_live``, the mean over the layers, of ``held``), the
+int8 bytes of those experts' matrices over ``kernel_ms`` (``kernel_gb_s``)
+and of every held expert's over ``dense_ms`` (``dense_gb_s``), and the
+operations around the kernel's calls (``around_ops``, ``around_ms``).
 
 Import the package from another checkout with ``PYTHONPATH=<root>`` to time
 that checkout on the same chip.
@@ -59,6 +72,9 @@ SHAPES = {
     "k-exaone-236b-a23b": dict(
         hidden=6144, ffn=2048, experts=128, k=8, shares=8, width=2048,
         scoring="sigmoid"),
+    "glm-5.2": dict(
+        hidden=6144, ffn=2048, experts=256, k=8, shares=16, width=2048,
+        scoring="sigmoid"),
 }
 
 
@@ -91,10 +107,12 @@ def seeded_layers(cfg, layers, key):
                     ks, shape[:2] + shape[3:], jnp.float32, 0.5, 1.5
                 ).astype(jnp.bfloat16) * (0.3 / 127 / shape[2] ** 0.5),
             )
-        out["router"] = jax.random.normal(
+        # logits of order one: a sigmoid router's scores must not saturate
+        # into ties (top-k breaks a tie by index: a few experts for all)
+        out["router"] = (jax.random.normal(
             jax.random.fold_in(key, 9), (layers, h, cfg.num_experts),
-            jnp.bfloat16,
-        )
+            jnp.float32,
+        ) * h ** -0.5).astype(jnp.bfloat16)
         return out
 
     return make(key)
@@ -112,6 +130,68 @@ def traced(fn, *args, reps=2):
     return out, {k: v / reps for k, v in agg["ops_ns"].items()}
 
 
+def decode_cases(args, name, cfg, p, live, dense):
+    """A line a (slots, live rows) decode step: the live path beside
+    dense-combine, a layer."""
+    held, h, f = cfg.num_held_experts, cfg.hidden_size, cfg.moe_intermediate_size
+    layer_bytes = 3 * h * f       # one expert's int8 matrices
+    per_layer = lambda ns: ns / args.layers / 1e6
+    # bytes a layer over ns a layer is GB/s
+    gb_s = lambda b, ns: round(b * args.layers / ns, 1) if ns else None
+
+    @jax.jit
+    def picked(p, x, valid):
+        """Held experts the valid rows pick in each layer (the first
+        layer's input: the count the routing's near-uniformity gives)."""
+        return jnp.stack([
+            jnp.sum(moe._combine_matrix(cfg, x, p["router"][i], None, valid)[1])
+            for i in range(args.layers)
+        ])
+
+    for rows in args.decode_rows:
+        x = jax.random.normal(
+            jax.random.PRNGKey(args.seed + 1), (rows, 1, h), jnp.bfloat16
+        )
+        for alive in args.live_rows:
+            if alive > rows:
+                continue
+            valid = (jnp.arange(rows) < alive)[:, None]
+            got, ops = traced(live, p, x, valid)
+            want, dops = traced(dense, p, x, valid)
+            kernel = sum(v for k, v in ops.items() if KERNEL in k)
+            around = {k: v for k, v in ops.items() if KERNEL not in k}
+            experts = float(np.mean(np.asarray(picked(p, x, valid))))
+            keep = np.asarray(valid)[..., None]
+            a = np.where(keep, np.asarray(got, np.float32), 0)
+            b = np.where(keep, np.asarray(want, np.float32), 0)
+            print(json.dumps({
+                "config": name, "decode_rows": rows, "live_rows": alive,
+                "held": held, "experts": cfg.num_experts,
+                "k": cfg.num_experts_per_tok, "H": h, "F": f,
+                "blocks": args.blocks, "layers": args.layers,
+                "rule": moe.dispatch_path(cfg, rows, 1),
+                "experts_live": round(experts, 2),
+                "live_ms": round(per_layer(sum(ops.values())), 4),
+                "kernel_ms": round(per_layer(kernel), 4),
+                "dense_ms": round(per_layer(sum(dops.values())), 4),
+                "kernel_gb_s": gb_s(experts * layer_bytes, kernel),
+                "dense_gb_s": gb_s(held * layer_bytes, sum(dops.values())),
+                "around_ops": len(around),
+                "around_ms": round(per_layer(sum(around.values())), 4),
+                "top_ops_ms": [
+                    [k, round(per_layer(v), 4)]
+                    for k, v in sorted(ops.items(), key=lambda kv: -kv[1])
+                ][:args.ops],
+                "dense_top_ops_ms": [
+                    [k, round(per_layer(v), 4)]
+                    for k, v in sorted(dops.items(), key=lambda kv: -kv[1])
+                ][:args.ops],
+                "max_abs_diff": float(np.abs(a - b).max()),
+                "ref_abs_max": float(np.abs(b).max()),
+                "device": jax.devices()[0].device_kind,
+            }), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--configs", nargs="+", default=sorted(SHAPES),
@@ -124,20 +204,25 @@ def main():
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--stacks", choices=["whole", "sliced"], default="whole")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--decode-rows", nargs="+", type=int, default=[],
+                    help="time a decode step of this many slots instead")
+    ap.add_argument("--live-rows", nargs="+", type=int, default=[2, 4, 8, 16, 32])
+    ap.add_argument("--ops", type=int, default=8,
+                    help="a decode line's largest operations, this many")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         sys.exit("a device time comes from a chip: no TPU here")
 
     def stack(cfg, path):
-        """``layers`` routed MLPs, residual, by ``path``. The grouped form
-        takes its expert stacks whole and names the layer to the kernel,
-        as ``models/llama.py:block_apply`` does (``--stacks sliced``: a
-        slice a scan step, which copies a layer's experts before each
-        call)."""
+        """``layers`` routed MLPs, residual, by ``path``. The grouped and
+        the live form take their expert stacks whole and name the layer to
+        the kernel, as ``models/llama.py:block_apply`` does (``--stacks
+        sliced``: a slice a scan step, which copies a layer's experts
+        before each call)."""
         def run(p, x, valid):
             whole = {
                 k: p[k] for k in moe.GROUPED_STACKS
-                if path == "grouped" and args.stacks == "whole"
+                if path != "dense" and args.stacks == "whole"
             }
             scanned = {k: v for k, v in p.items() if k not in whole}
 
@@ -148,6 +233,10 @@ def main():
                     y = moe.moe_mlp_grouped(
                         cfg, lp, x, valid, row_tile=args.row_tile,
                         blocks=tuple(args.blocks),
+                    )
+                elif path == "live":
+                    y = moe.moe_mlp_live(
+                        cfg, lp, x, valid, blocks=tuple(args.blocks)
                     )
                 else:
                     with mock.patch.object(
@@ -167,6 +256,10 @@ def main():
         cfg = model_config(shape)
         p = seeded_layers(cfg, args.layers, jax.random.PRNGKey(args.seed))
         grouped, dense = stack(cfg, "grouped"), stack(cfg, "dense")
+        if args.decode_rows:
+            decode_cases(args, name, cfg, p, stack(cfg, "live"), dense)
+            del p
+            continue
         for rows in args.rows:
             x = jax.random.normal(
                 jax.random.PRNGKey(args.seed + 1),
