@@ -10,24 +10,32 @@ as static arguments.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Mapping, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
-    """Rotary-embedding scaling (Llama-3 style "llama3" or linear/dynamic)."""
+    """Rotary-embedding scaling: Llama-3's "llama3", "linear", or DeepSeek's
+    "yarn" (``ops/rotary.py``: the frequencies' blend by ``beta_fast`` /
+    ``beta_slow``, and the softmax scale's ``mscale_all_dim`` factor)."""
 
-    rope_type: str = "default"  # "default" | "llama3" | "linear"
+    rope_type: str = "default"  # "default" | "llama3" | "linear" | "yarn"
     factor: float = 1.0
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position_embeddings: int = 8192
+    # YaRN (arXiv:2309.00071, as DeepSeek-V2/V3's modeling code reads it)
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
     @staticmethod
     def from_hf(d: Optional[Mapping[str, Any]]) -> Optional["RopeScaling"]:
         if d is None:
             return None
-        return RopeScaling(
+        scaling = RopeScaling(
             rope_type=d.get("rope_type", d.get("type", "default")),
             factor=float(d.get("factor", 1.0)),
             low_freq_factor=float(d.get("low_freq_factor", 1.0)),
@@ -35,7 +43,31 @@ class RopeScaling:
             original_max_position_embeddings=int(
                 d.get("original_max_position_embeddings", 8192)
             ),
+            beta_fast=float(d.get("beta_fast", 32.0)),
+            beta_slow=float(d.get("beta_slow", 1.0)),
+            mscale=float(d.get("mscale", 1.0)),
+            mscale_all_dim=float(d.get("mscale_all_dim", 0.0)),
         )
+        if scaling.rope_type == "yarn" and scaling.mscale != scaling.mscale_all_dim:
+            # cos and sin would be scaled by mscale(factor, mscale) /
+            # mscale(factor, mscale_all_dim): 1 in every published DeepSeek
+            # block, and the caches that re-derive key angles from
+            # ``inv_freq`` alone (cache/sink.py) carry no such factor
+            raise ValueError(
+                f"config key 'rope_scaling' = {dict(d)!r} is not implemented: "
+                "a YaRN block whose 'mscale' and 'mscale_all_dim' differ "
+                "scales cos and sin, which ops/rotary.py does not"
+            )
+        return scaling
+
+    @property
+    def softmax_factor(self) -> float:
+        """What YaRN multiplies a latent block's softmax scale by:
+        ``mscale(factor, mscale_all_dim) ** 2`` (DeepSeek-V2/V3's
+        attention), 1 for every other scaling."""
+        if self.rope_type != "yarn" or not self.mscale_all_dim or self.factor <= 1:
+            return 1.0
+        return (0.1 * self.mscale_all_dim * math.log(self.factor) + 1.0) ** 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +110,23 @@ class LatentConfig:
     def lat_dim(self) -> int:
         """Stored per-token width: latent rank + decoupled rope key."""
         return self.rank + self.rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class HyperConnectionConfig:
+    """Manifold-constrained hyper-connections (mHC, arXiv:2512.24880): the
+    residual stream of a token is ``mult`` rows of ``hidden_size``, and
+    around every attention and MLP sublayer three maps computed from the
+    stream itself read the sublayer's input out of the rows (``H_pre``),
+    mix the rows among themselves (``H_res``: made doubly stochastic by
+    ``sinkhorn_iters`` column-and-row normalisations of ``exp`` of its
+    logits clamped to ``res_clamp``, ``eps`` in the divisors) and write the
+    sublayer's output back (``H_post``). ``ops/hyper_connections.py``."""
+
+    mult: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -232,8 +281,12 @@ class ModelConfig:
     # every expert is here.
     expert_shares: int = 1
     expert_share_index: int = 0
+    # A residual stream ``hyper.mult`` rows wide, mixed by
+    # manifold-constrained hyper-connections around every sublayer
+    # (``hc_mult``); None = the plain ``x + f(x)``.
+    hyper: Optional[HyperConnectionConfig] = None
     # Model family tag ("llama", "mistral", "qwen2", "mixtral", "mla",
-    # "keye_vl2", "exaone_moe", "glm_moe_dsa").
+    # "keye_vl2", "exaone_moe", "glm_moe_dsa", "xing4_0").
     family: str = "llama"
 
     @property
@@ -442,6 +495,7 @@ class ModelConfig:
             num_experts=experts,
             num_experts_per_tok=get("num_experts_per_tok", 2) or 2,
             latent=latent,
+            hyper=_hyper_keys(get) if get("hc_mult", None) else None,
             family=model_type,
             **moe,
             **extra,
@@ -451,11 +505,9 @@ class ModelConfig:
 def _refuse_unimplemented(get) -> None:
     """Keys of a DeepSeek-V2/V3 ``config.json`` whose published meaning
     this program does not compute. Each raises under its own name."""
-    scaling = get("rope_scaling", None) or {}
     refused = {
         "n_group": (get("n_group", 1) or 1) > 1,
         "topk_group": (get("topk_group", 1) or 1) > 1,
-        "rope_scaling": any("mscale" in k for k in scaling),
         "num_nextn_predict_layers": (
             get("num_nextn_predict_layers", 0) or 0
         ) > 0,
@@ -469,7 +521,7 @@ def _refuse_unimplemented(get) -> None:
         if bad:
             raise ValueError(
                 f"config key {key!r} = {get(key)!r} is not implemented: "
-                f"routing by groups, YaRN's mscale, next-token-prediction "
+                f"routing by groups, next-token-prediction "
                 f"layers and interleaved dense layers are outside what "
                 f"models/llama.py computes"
             )
@@ -548,7 +600,7 @@ def _exaone_moe_keys(get):
 
 #: ``model_type`` of a block with a latent -> its family, where that is not
 #: "mla" (``models/registry.py``)
-_LATENT_FAMILIES = {"glm_moe_dsa": "glm_moe_dsa"}
+_LATENT_FAMILIES = {"glm_moe_dsa": "glm_moe_dsa", "xing4_0": "xing4_0"}
 _INDEX_KINDS = {"full": "score", "shared": "reuse"}
 
 
@@ -609,6 +661,30 @@ def _routing_keys(get, held: str, shared: str, scoring: str,
         num_experts=router,
         expert_shares=shares,
         expert_share_index=int(share.get("index", 0)),
+    )
+
+
+def _hyper_keys(get) -> HyperConnectionConfig:
+    """A block's hyper-connection keys: ``hc_mult`` rows, and with it
+    ``hc_sinkhorn_iters``, ``hc_eps`` and the ``mhc_h_res_clamp_min`` /
+    ``_max`` pair, none of which has a default here: a block that widens
+    its stream states how the mixing map is constrained."""
+    for key in (
+        "hc_sinkhorn_iters", "hc_eps", "mhc_h_res_clamp_min",
+        "mhc_h_res_clamp_max",
+    ):
+        if get(key, None) is None:
+            _refuse(
+                get, key,
+                f"a block with hc_mult = {get('hc_mult')!r} states it",
+            )
+    return HyperConnectionConfig(
+        mult=int(get("hc_mult")),
+        sinkhorn_iters=int(get("hc_sinkhorn_iters")),
+        eps=float(get("hc_eps")),
+        res_clamp=(
+            float(get("mhc_h_res_clamp_min")), float(get("mhc_h_res_clamp_max"))
+        ),
     )
 
 

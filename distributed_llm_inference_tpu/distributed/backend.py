@@ -82,6 +82,13 @@ class BlockBackend:
         relay's job, one node per stage)."""
         self.session_idle_timeout = session_idle_timeout
         self.cfg = cfg
+        if cfg.hyper is not None:
+            raise ValueError(
+                f"hc_mult = {cfg.hyper.mult} (ModelConfig.hyper) is not "
+                "served by block workers: the relay tier's wire schema "
+                "carries [1, S, hidden_size] hidden states between nodes, "
+                "one row of the widened stream"
+            )
         self.mesh = None
         self._shard_cache_fn = None
         tp = 1

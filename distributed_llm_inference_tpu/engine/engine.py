@@ -279,6 +279,19 @@ class InferenceEngine:
             raise ValueError(
                 f"prefix_caching requires the paged cache (got kind={cc.kind!r})"
             )
+        if cfg.hyper is not None:
+            # The stream between layers is ``hyper.mult`` rows of
+            # ``hidden_size`` a token; what a mesh (tp / ep all-reduces,
+            # pp stage hand-offs, sp rings) carries between chips is ONE
+            # row, and is refused here by the key's name.
+            if mesh_cfg is not None:
+                raise ValueError(
+                    f"hc_mult = {cfg.hyper.mult} (ModelConfig.hyper) is "
+                    "single-device only: the mesh programs (tp, ep, pp, sp) "
+                    "carry a residual stream of one row between chips "
+                    f"(got {mesh_cfg})"
+                )
+            self.plan.mhc_mixes_per_token = 2 * cfg.num_layers
         self._latent = cfg.use_latent
         if self._latent:
             # Latent (MLA) attention stores ONE low-rank [rank + dr] vector

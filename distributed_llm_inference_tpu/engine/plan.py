@@ -147,6 +147,10 @@ class AttentionPlan:
         # compute path in a dispatch of that shape (``ops/moe.py``);
         # note_dispatch keeps their census.
         self.expert_rows = None
+        # Set by the engine for a model with a widened residual stream
+        # (``ModelConfig.hyper``): the hyper-connection mixes a token
+        # crosses, two a layer; note_dispatch keeps their census.
+        self.mhc_mixes_per_token: Optional[int] = None
         # Set by the engine over a paged cache: ``pad width -> block_q``,
         # the q block the ragged kernel picks for this model at that width
         # (``ops/ragged_attention.py:_prep``); note_dispatch keeps the
@@ -353,7 +357,13 @@ class AttentionPlan:
         sees ``min(window, t + 1)`` of the ``t + 1`` keys in its context;
         the sums, of ONE window layer, add to ``window_keys_seen`` /
         ``window_keys_in_context`` and ride the record as a fifth entry
-        ``(seen, in context)`` behind a fourth that is None."""
+        ``(seen, in context)`` behind a fourth that is None.
+
+        A model with a widened residual stream (``mhc_mixes_per_token``:
+        two mixes a layer) counts the hyper-connection mixes its valid
+        tokens need and those its padded tokens run, ``mhc_mixes_needed`` /
+        ``mhc_mixes_run``, a decode dispatch's tokens as for the experts'
+        census."""
         shape = tuple(int(x) for x in shape)
         self.last_dispatch = (kind, shape, valid_tokens)
         sparse_keys = window_keys = None
@@ -387,13 +397,21 @@ class AttentionPlan:
             self.metrics.counter("attn_ragged_dispatches")
         if valid_tokens is None:
             return
+        # a dispatch's tokens: a decode dispatch's are its ``active_rows``
+        # (of ``shape[0]`` rows) times its steps
+        if kind == DECODE:
+            valid, padded = (active_rows or 0) * shape[1], shape[0] * shape[1]
+        else:
+            valid, padded = valid_tokens, shape[0] * shape[1]
+        if self.mhc_mixes_per_token is not None:
+            self.metrics.counter(
+                "mhc_mixes_needed", valid * self.mhc_mixes_per_token
+            )
+            self.metrics.counter(
+                "mhc_mixes_run", padded * self.mhc_mixes_per_token
+            )
         if self.expert_rows is not None:
-            if kind == DECODE:
-                valid, padded = (active_rows or 0) * shape[1], shape[0] * shape[1]
-                seq_len = 1
-            else:
-                valid, padded = valid_tokens, shape[0] * shape[1]
-                seq_len = shape[1]
+            seq_len = 1 if kind == DECODE else shape[1]
             needed, computed, path, (held, read) = self.expert_rows(
                 shape[0], seq_len, valid / max(padded, 1)
             )

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import LayerSegment, ModelConfig
+from ..ops import hyper_connections as mhc
 from ..ops.attention import gqa_attention
 from ..ops.moe import moe_mlp
 from ..ops.norms import rms_norm
@@ -157,11 +158,31 @@ def init_layer_params(
         p["k_i_norm"] = jnp.ones((num_layers, sa.index_dim), dtype)
         p["k_i_norm_bias"] = jnp.zeros((num_layers, sa.index_dim), dtype)
     p.update(_mlp_params(cfg, kind, w, keys))
+    if cfg.hyper is not None:
+        p.update(_hyper_params(cfg, jax.random.fold_in(key, 9), num_layers))
     if cfg.qkv_bias:
         p["bq"] = jnp.zeros((num_layers, hq * d), dtype)
         p["bk"] = jnp.zeros((num_layers, hkv * d), dtype)
         p["bv"] = jnp.zeros((num_layers, hkv * d), dtype)
     return p
+
+
+def _hyper_params(cfg: ModelConfig, key, num_layers: int) -> Params:
+    """The hyper-connection leaves of a layer's two sublayers
+    (``ops/hyper_connections.py``), float32: projections drawn so that the
+    maps' logits have a spread of about 1, biases normal."""
+    hc, out = cfg.hyper, {}
+    shapes = mhc.leaf_shapes(hc, cfg.hidden_size)
+    for n, prefix in enumerate(("hc_attn", "hc_mlp")):
+        k_phi, k_bias = jax.random.split(jax.random.fold_in(key, n))
+        out[f"{prefix}_phi"] = jax.random.normal(
+            k_phi, (num_layers, *shapes["phi"]), jnp.float32
+        ) * shapes["phi"][1] ** -0.5
+        out[f"{prefix}_alpha"] = jnp.ones((num_layers, 3), jnp.float32)
+        out[f"{prefix}_bias"] = jax.random.normal(
+            k_bias, (num_layers, *shapes["bias"]), jnp.float32
+        )
+    return out
 
 
 def _mlp_params(cfg: ModelConfig, kind: str, w, keys) -> Params:
@@ -256,7 +277,7 @@ def _decoder_layer(
     A layer without RoPE is handed the identity rotation as ``rope``
     (:func:`_rope_angles`).
     """
-    b, s, _ = x.shape
+    b, s = x.shape[:2]
     hq, hkv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     window = cfg.sliding_window if segment is None else segment.window
     # where the stack has two attention kinds, each has its scope inside
@@ -271,7 +292,8 @@ def _decoder_layer(
     # ``sampler``) are the stable part of every operation's name in a device
     # trace: fusion numbers move with each recompile, these do not.
     with jax.named_scope("attention"):
-        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        h, mix = _stream_read(cfg, p, "hc_attn", x)
+        h = rms_norm(h, p["attn_norm"], cfg.rms_norm_eps)
         if cfg.use_latent:
             attn_flat, new_state = _latent_attention(
                 cfg, p, h, layer_state, cache, rope, q_pos, num_new,
@@ -308,8 +330,25 @@ def _decoder_layer(
         o = qmatmul(attn_flat, p["wo"])
         if "bo" in p:
             o = o + p["bo"]
-        x = x + o
+        x = _stream_write(x, o, mix)
     return _mlp_residual(cfg, p, x, s, num_new), new_state
+
+
+def _stream_read(cfg: ModelConfig, p: Params, prefix: str, x):
+    """What a sublayer reads of the residual stream, and what
+    :func:`_stream_write` needs to write its output back: the stream itself
+    ``[B, S, H]`` and nothing, or, where the stream is ``cfg.hyper.mult``
+    rows wide (``[B, S, n, H]``), the rows mixed by the sublayer's
+    hyper-connection maps (``ops/hyper_connections.py``) and the two maps
+    of the way back."""
+    if cfg.hyper is None:
+        return x, None
+    return mhc.pre_mix(cfg.hyper, p, prefix, x, cfg.rms_norm_eps)
+
+
+def _stream_write(x, y, mix):
+    """The residual stream behind a sublayer whose output is ``y``."""
+    return x + y if mix is None else mhc.post_mix(x, y, mix)
 
 
 def _index_inputs(cfg, p, h, index_rope, cq=None) -> IndexInputs:
@@ -371,7 +410,8 @@ def _mlp_residual(cfg, p, x, s, num_new):
     branches of :func:`_decoder_layer`)."""
     b = x.shape[0]
     with jax.named_scope("mlp"):
-        h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        h2, mix = _stream_read(cfg, p, "hc_mlp", x)
+        h2 = rms_norm(h2, p["mlp_norm"], cfg.rms_norm_eps)
         # The segment's kind, read off its leaves: a routed layer carries
         # a router.
         if "router" in p:
@@ -388,7 +428,7 @@ def _mlp_residual(cfg, p, x, s, num_new):
                 jax.nn.silu(qmatmul(h2, p["wg"])) * qmatmul(h2, p["wu"]),
                 p["wd"],
             )
-        return x + mlp
+        return _stream_write(x, mlp, mix)
 
 
 def _latent_attention(
@@ -422,8 +462,9 @@ def _latent_attention(
 
     Rope is applied here, to the rope slices only (``rope`` tables are
     built for ``rope_head_dim`` — see :func:`block_apply`); the cache must
-    not rotate anything. Softmax scale is ``(dn + dr)**-0.5``, the
-    effective per-head query dim of the UN-absorbed formulation.
+    not rotate anything. The softmax scale is
+    :func:`_latent_softmax_scale`'s: ``(dn + dr)**-0.5``, the effective
+    per-head query dim of the UN-absorbed formulation, times YaRN's factor.
 
     Compressed queries (``LatentConfig.q_lora_rank``: the layer has
     ``wq_a``, ``q_a_norm``, ``wq_b``): ``cq = RMSNorm(h wq_a)``, ``q = cq
@@ -466,11 +507,23 @@ def _latent_attention(
     )
     attn, new_state = cache.attend(
         layer_state, q_eff, kv, kv, rope, q_pos, num_new,
-        None, attention_fn, (dn + dr) ** -0.5, **more,
+        None, attention_fn, _latent_softmax_scale(cfg), **more,
     )
     # Deferred value up-projection from the latent-space attention result.
     o = jnp.einsum("bshr,rhd->bshd", attn[..., :rank], p["wv_b"])
     return o.reshape(b, s, hq * dv), new_state
+
+
+def _latent_softmax_scale(cfg: ModelConfig) -> float:
+    """THE softmax scale of latent attention: ``(dn + dr) ** -0.5``, the
+    query width of the un-absorbed form, times what a YaRN block's
+    ``mscale_all_dim`` makes of it (``RopeScaling.softmax_factor``). The
+    ragged, paged and fused kernels take it as the operand ``cache.attend``
+    hands them."""
+    lat = cfg.latent
+    width = (lat.nope_head_dim or cfg.head_dim) + lat.rope_head_dim
+    factor = 1.0 if cfg.rope_scaling is None else cfg.rope_scaling.softmax_factor
+    return width ** -0.5 * factor
 
 
 def _rope_dim(cfg: ModelConfig) -> int:
@@ -638,7 +691,7 @@ def model_apply(
     every chunked long-prompt step); "none" skips the head (chunked prefill
     interiors), returning ``None`` logits. Shapes: "last" → [B, 1, V].
     """
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = _embed(cfg, params, tokens)
     if block_fn is None:
         for seg in cfg.segments:
             with _segment_scope(seg):
@@ -659,13 +712,32 @@ def model_apply(
     if head == "none":
         return None, cache.advance(num_new)
     if head == "last":
-        x = jnp.take_along_axis(
-            x,
-            jnp.maximum(num_new - 1, 0)[:, None, None].astype(jnp.int32),
-            axis=1,
-        )
-    logits = apply_head(cfg, params, x)
+        last = jnp.maximum(num_new - 1, 0)[:, None, None].astype(jnp.int32)
+        if cfg.hyper is not None:
+            last = last[..., None]      # the stream's rows go with the position
+        x = jnp.take_along_axis(x, last, axis=1)
+    logits = apply_head(cfg, params, _stream_exit(cfg, x))
     return logits, cache.advance(num_new)
+
+
+def _embed(cfg: ModelConfig, params: Params, tokens):
+    """The stack's entry: the tokens' embeddings ``[B, S, H]``, replicated
+    into the ``cfg.hyper.mult`` rows of a widened stream ``[B, S, n, H]``
+    where the model has one."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.hyper is None:
+        return x
+    return jnp.broadcast_to(
+        x[:, :, None, :], (*x.shape[:2], cfg.hyper.mult, x.shape[-1])
+    )
+
+
+def _stream_exit(cfg: ModelConfig, x):
+    """The stack's exit: a widened stream's rows summed (in float32) into
+    the ``[B, S, H]`` the final norm and the head take."""
+    if cfg.hyper is None:
+        return x
+    return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
 
 
 class _TailView:
@@ -762,7 +834,7 @@ def multi_decode_apply(
     def token_step(carry, i):
         tokens, tails, tail_len, num_new, state = carry
         tails = list(tails)
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = _embed(cfg, params, tokens)
         q_pos = (base_len + tail_len)[:, None]
         # one table a RoPE switch: the rotation, and the identity where a
         # segment's layers do not rotate
@@ -827,7 +899,7 @@ def multi_decode_apply(
                     (x, tails[at]),
                     (scanned_w, *seg_big, jnp.arange(lo, hi)),
                 )
-        logits = apply_head(cfg, params, x)
+        logits = apply_head(cfg, params, _stream_exit(cfg, x))
         next_tokens, next_num_new, state, emit = step_fn(i, logits[:, 0], state)
         tail_len = tail_len + num_new
         return (
@@ -1060,15 +1132,15 @@ def convert_hf_state_dict(
     three times over (state, per-layer copies, stacks: 35 GiB and counting
     for a 14.5 GB checkpoint on a 40 GiB host — my chip run, PR 21).
     """
-    if cfg.qk_norm or cfg.use_sparse:
+    if cfg.qk_norm or cfg.use_sparse or cfg.hyper is not None:
         # by what the converter lacks, whatever the family's name: a latent
         # block's compressed queries are mapped (``convert_hf_layer``), an
-        # indexer's tensors are not
+        # indexer's tensors and a widened stream's maps are not
         raise ValueError(
             f"family {cfg.family!r} has no checkpoint converter: the key "
             "names of its checkpoint (the per-head q/k norms', an "
-            "indexer's) are not known to this program, and a guessed "
-            "converter is worse than none"
+            "indexer's, the hyper-connections') are not known to this "
+            "program, and a guessed converter is worse than none"
         )
 
     def stacked(ids):

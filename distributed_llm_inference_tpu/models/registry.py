@@ -47,6 +47,14 @@ Families:
   (``cache/latent.py``: the indexed latent classes). The one family in
   which ``latent`` and ``sparse`` compose.
 
+* ``xing4_0`` — Xing4.0's block: the latent of ``mla`` with compressed
+  queries under YaRN, sigmoid-routed experts beside a shared one behind
+  leading dense layers, and a residual stream ``hc_mult`` rows wide, mixed
+  by manifold-constrained hyper-connections around every sublayer
+  (``ModelConfig.hyper``, ``ops/hyper_connections.py``). Its checkpoint's
+  key names for those maps are not known to this program:
+  :func:`llama.convert_hf_state_dict` refuses the family.
+
 The switches are independent: a family may permit any of them together
 (``mla`` permits experts AND requires the latent); what a family does not
 permit is refused by :func:`validate_config`.
@@ -82,6 +90,8 @@ class ModelFamily:
     sparse: bool = False
     # Window and full layers in one stack (``ModelConfig.layer_attention``).
     layer_attention: bool = False
+    # A residual stream several rows wide (``ModelConfig.hyper``).
+    hyper: bool = False
     # The compute/conversion program (shared stack for all current families).
     apply: Callable = llama.model_apply
     block_apply: Callable = llama.block_apply
@@ -111,6 +121,9 @@ FAMILIES: Dict[str, ModelFamily] = {
         ModelFamily(
             "glm_moe_dsa", ("glm_moe_dsa",), latent=True, moe=True,
             sparse=True,
+        ),
+        ModelFamily(
+            "xing4_0", ("xing4_0",), latent=True, moe=True, hyper=True,
         ),
     )
 }
@@ -173,6 +186,17 @@ def validate_config(cfg: ModelConfig) -> ModelFamily:
             f"family {fam.name!r} does not use latent KV attention "
             f"(use the 'mla' family)"
         )
+    if cfg.hyper is not None:
+        if not fam.hyper:
+            raise ValueError(
+                f"family {fam.name!r} does not widen its residual stream "
+                f"(ModelConfig.hyper; use the 'xing4_0' family)"
+            )
+        if cfg.hyper.mult < 2 or cfg.hyper.sinkhorn_iters < 1:
+            raise ValueError(
+                f"a widened stream has at least 2 rows and 1 Sinkhorn "
+                f"round (got {cfg.hyper})"
+            )
     if cfg.qk_norm and not fam.qk_norm:
         raise ValueError(f"family {fam.name!r} does not use qk_norm")
     if cfg.sparse is not None and not fam.sparse:

@@ -9,7 +9,7 @@ functions; XLA fuses them into the surrounding attention computation, so no
 graph capture is needed.
 
 Conventions match HF ``transformers`` (non-interleaved halves, ``rotate_half``).
-Includes Llama-3 "llama3" frequency scaling.
+Includes Llama-3 "llama3" frequency scaling and DeepSeek-V2/V3's "yarn".
 """
 
 from __future__ import annotations
@@ -62,6 +62,28 @@ def rope_inv_freq(
         out = jnp.where(wavelen > low_wavelen, scaled, inv_freq)
         is_medium = (wavelen <= low_wavelen) & (wavelen >= high_wavelen)
         return jnp.where(is_medium, smoothed, out)
+    if scaling.rope_type == "yarn":
+        # DeepSeek-V2/V3's ``DeepseekV3YarnRotaryEmbedding``: frequencies that
+        # turn more than ``beta_fast`` times over the original context stay,
+        # those that turn less than ``beta_slow`` times are divided by
+        # ``factor``, and a linear ramp over the dims between blends the two.
+        # (What YaRN does to the softmax scale is
+        # ``RopeScaling.softmax_factor``, applied where the scale is made.)
+        def correction_dim(rotations):
+            return head_dim * math.log(
+                scaling.original_max_position_embeddings
+                / (rotations * 2.0 * math.pi)
+            ) / (2.0 * math.log(theta))
+
+        low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(scaling.beta_slow)), head_dim - 1)
+        if low == high:
+            high += 0.001  # the published guard against a zero-width ramp
+        ramp = jnp.clip(
+            (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low),
+            0.0, 1.0,
+        )
+        return inv_freq / scaling.factor * ramp + inv_freq * (1.0 - ramp)
     raise ValueError(f"unsupported rope_type: {scaling.rope_type}")
 
 

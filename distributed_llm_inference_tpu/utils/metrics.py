@@ -95,6 +95,14 @@ METRICS = {
         "Of those, the experts a step's active rows are expected to pick "
         "(an expectation under uniform routing; all held where dense)",
     ),
+    # a widened residual stream (ops/hyper_connections.py): a token crosses
+    # two mixes a layer; needed / run over an interval is the pad waste
+    "mhc_mixes_needed": (
+        "counter", "Hyper-connection mixes the dispatches' valid tokens need"
+    ),
+    "mhc_mixes_run": (
+        "counter", "Hyper-connection mixes the dispatches' padded tokens run"
+    ),
     # the ragged prefill kernel's grid, a layer's a dispatch: live / grid
     # is the share of its steps that compute (ops/ragged_attention.py)
     "ragged_attn_tiles_live": (

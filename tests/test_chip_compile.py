@@ -527,8 +527,9 @@ def test_two_pool_prefill_chunk_at_exaones_shapes(chip, kind, rows):
         (2048, 1408, 64, 6 * 2048),      # 64 of 1408, 6 a token
         (2048, 768, 128, 8 * 4096),      # 128 of 768, 8 a token, 4096 wide
         (6144, 2048, 16, 8 * 2048),      # a share of 16 of 128, 8 a token
+        (3584, 1024, 64, 4 * 2048),      # 64 of 1024 under hidden 3584, 4 a token
     ],
-    ids=["8x14336", "64x1408", "128x768", "16x2048-share"],
+    ids=["8x14336", "64x1408", "128x768", "16x2048-share", "64x1024"],
 )
 def test_grouped_moe_kernel(chip, hidden, ffn, held, pairs):
     """``moe_grouped_matmul`` over int8 expert stacks at the routed cells'
@@ -566,8 +567,10 @@ def test_grouped_moe_kernel(chip, hidden, ffn, held, pairs):
         (2048, 768, 128, 16),       # longdoc: 16 slots over 128
         (6144, 2048, 16, 32),       # mixedlen, glm: a share of 16
         (4096, 14336, 8, 128),      # the widest dispatch the rule sends
+        (3584, 1024, 64, 32),       # a widened stream's reason1k: 32 slots over 64
     ],
-    ids=["8x14336", "64x1408", "128x768", "16x2048-share", "128-rows"],
+    ids=["8x14336", "64x1408", "128x768", "16x2048-share", "128-rows",
+         "64x1024"],
 )
 def test_live_moe_kernel_at_the_decode_shapes(chip, hidden, ffn, held, rows):
     """The live path's three calls of ``moe_grouped_matmul`` at a decode

@@ -366,8 +366,10 @@ def test_from_hf_config_reads_the_published_block():
 @pytest.mark.parametrize("key,value", [
     ("topk_group", 4),
     ("n_group", 8),
+    # YaRN is read since PR 47; what is still refused is a block whose
+    # mscale and mscale_all_dim differ (cos and sin would be scaled)
     ("rope_scaling", {"type": "yarn", "factor": 40, "mscale": 1.0,
-                      "mscale_all_dim": 1.0}),
+                      "mscale_all_dim": 0.707}),
     ("num_nextn_predict_layers", 1),
     ("moe_layer_freq", 2),
 ])
