@@ -402,9 +402,22 @@ class QuantizedLatentPagedKVCache(LatentPagedKVCache):
             jnp.zeros((l, b, 1, k_steps), jnp.float32),
         )
 
+    def tail_walk(self, k_steps: int, base_len, num_new):
+        """What every :meth:`tail_attend` of a window of ``k_steps`` walks
+        (``ops/paged_attention.py:latent_sweep_walk``; ``None`` where the
+        pool is swept by copies): a function of the table, of ``base_len``
+        and of which rows decode, ``num_new`` positive at the window's first
+        step. The model builds it once a window and hands it to every
+        layer's call of every step."""
+        from ..ops.paged_attention import latent_sweep_walk
+
+        return latent_sweep_walk(
+            self.k_pages, k_steps, self.page_table, base_len, num_new
+        )
+
     def tail_attend(self, big_state, tail_state, q, k_new, v_new, rope,
                     base_len, tail_len, step_idx, num_new, sliding_window,
-                    scale=None):
+                    scale=None, walk=None):
         """NO rope here either (see :meth:`attend`): ``q`` and ``k_new``
         arrive rotated, ``k_new`` in stored form."""
         from ..ops.paged_attention import (
@@ -420,7 +433,7 @@ class QuantizedLatentPagedKVCache(LatentPagedKVCache):
             layer_idx=lidx, step_idx=step_idx,
             page_table=self.page_table, base_len=base_len,
             tail_valid_len=tail_len + num_new,
-            q_positions=base_len + tail_len, scale=scale,
+            q_positions=base_len + tail_len, scale=scale, walk=walk,
         )
         return out, (tail_c, tail_cs)
 
@@ -677,6 +690,11 @@ class IndexedQuantizedLatentPagedKVCache(
             jnp.zeros((b, t, 1, self.page_size), jnp.float32),
             jnp.zeros((b, 1, k_steps), jnp.float32),
         )
+
+    def tail_walk(self, k_steps: int, base_len, num_new):
+        """None: the sweep under a selection walks no list yet
+        (``ops/paged_attention.py``: ``walked``)."""
+        return None
 
     def tail_attend(self, big_state, tail_state, q, k_new, v_new, rope,
                     base_len, tail_len, step_idx, num_new, sliding_window,

@@ -792,6 +792,16 @@ class InferenceEngine:
             self.plan.sweep_pool = (
                 pool_heads, pool_width, type(self.cache).INPLACE_CTX
             )
+        if (
+            tail_capable and K > 1 and hasattr(self.cache, "tail_walk")
+            and jax.eval_shape(
+                lambda c: c.tail_walk(K, c.lengths, c.lengths), self.cache
+            ) is not None
+        ):
+            # the fused scan's sweep of the latent pool walks a list of its
+            # live blocks: the plan counts the grid steps it names
+            _, _, pool_heads, _, pool_width = self.cache.k_pages.shape
+            self.plan.walked_pool = (pool_heads, pool_width)
 
         def _decode_scan(params, tokens, cache, active, key, sp, eos_ids, budget):
             """``K`` fused decode steps in one dispatch: sampling, EOS stops,

@@ -50,7 +50,11 @@ The plan centralizes that policy:
   through; and over a paged cache ``decode_pages_live`` /
   ``decode_pages_joint``: the pages a decode dispatch's rows hold inside
   their windows, and those of them that fill whole tiles of the in-place
-  sweep (a block of pages is one tile, a row's last one padded).
+  sweep (a block of pages is one tile, a row's last one padded); where the
+  sweep walks a list of its pool's live blocks (the latent pool's),
+  ``decode_sweep_steps_walked`` / ``decode_sweep_steps_grid``: the grid
+  steps the sweep walks (a row's blocks that hold a live page) over rows x
+  the table's blocks.
 
 This is also the fusion point ROADMAP item 4 (batched spec verification)
 needs: a verify row is just one more ``num_new == k`` row class.
@@ -64,7 +68,7 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
-from ..ops.paged_attention import _live_pages, _pages_per_block
+from ..ops.paged_attention import _live_pages, _pages_per_block, _row_steps
 from ..ops.ragged_attention import _tile_live
 
 __all__ = ["AttentionPlan", "KernelSelection", "PREFILL", "CHUNKED", "DECODE"]
@@ -182,6 +186,12 @@ class AttentionPlan:
         # that kernel. note_dispatch keeps the census of the pages it
         # attends a block as one tile.
         self.sweep_pool: Optional[Tuple[int, int, int]] = None
+        # Set by the engine where that scan's sweep walks a list of its
+        # pool's live blocks instead (pipelined blocks of a stored row
+        # Mosaic cannot copy, the int8 latent pool's one plane, without a
+        # selection: the cache's ``tail_walk``): ``(kv heads, stored
+        # width)``. note_dispatch keeps the census of the grid steps walked.
+        self.walked_pool: Optional[Tuple[int, int]] = None
 
     @property
     def windowed(self) -> bool:
@@ -438,9 +448,14 @@ class AttentionPlan:
             self.metrics.counter("decode_live_positions", live)
             self.metrics.counter("decode_grid_positions", grid)
             if paged and query_spans is not None:
-                live, joint = self._swept_pages(shape, query_spans)
+                live, joint, walked, grid = self._swept_pages(
+                    shape, query_spans
+                )
                 self.metrics.counter("decode_pages_live", live)
                 self.metrics.counter("decode_pages_joint", joint)
+                if grid:
+                    self.metrics.counter("decode_sweep_steps_walked", walked)
+                    self.metrics.counter("decode_sweep_steps_grid", grid)
         else:
             self.metrics.counter("prefill_valid_tokens", valid_tokens)
             self.metrics.counter("prefill_padded_tokens", shape[0] * shape[1])
@@ -477,34 +492,53 @@ class AttentionPlan:
             selected += (lo + lo + under - 1) * under // 2 + (n - under) * k
         return selected, live
 
-    def _swept_pages(self, shape, spans) -> Tuple[int, int]:
-        """(live, joint) pages of a decode dispatch of ``shape`` (rows,
-        steps, table width) whose active rows' queries span ``spans``: a
-        page a layer a step. Live is what a row's pool holds inside the
-        layer's window (the kernel's own :func:`_live_pages`: the pool's
-        length is the first query's position all through the dispatch, the
-        window moves with the query); joint, those of them that lie in full
-        blocks of :func:`_pages_per_block` pages, the tiles of the in-place
-        sweep (``sweep_pool``) that hold no padding: none where a block is
-        one page (the tile it always was) or another path decodes."""
-        _, steps, width = shape
+    def _swept_pages(self, shape, spans) -> Tuple[int, int, int, int]:
+        """(live, joint) pages and (walked, grid) steps of a decode dispatch
+        of ``shape`` (rows, steps, table width) whose active rows' queries
+        span ``spans``: a page, a grid step, a layer a step. Live is what a
+        row's pool holds inside the layer's window (the kernel's own
+        :func:`_live_pages`: the pool's length is the first query's position
+        all through the dispatch, the window moves with the query); joint,
+        those of them that lie in full blocks of :func:`_pages_per_block`
+        pages, the tiles of the in-place sweep (``sweep_pool``) that hold no
+        padding: none where a block is one page (the tile it always was) or
+        another path decodes. Over a pool whose sweep walks a list
+        (``walked_pool``) walked is what the list names
+        (``ops/paged_attention.py:_sweep_walk``, by its own
+        :func:`_row_steps`): an active row's blocks that hold a live page,
+        one step for every other row; grid is rows x the table's blocks,
+        what a grid over the table's width steps through. Both 0 where
+        another path decodes."""
+        rows, steps, width = shape
         page_size = self.ccfg.page_size
-        block = 0
+        block = walk_block = 0
         if self.sweep_pool is not None and steps > 1:
             heads, stored, least = self.sweep_pool
             if width * page_size >= least:
                 block = _pages_per_block(width, heads, page_size, stored, steps)
+        if self.walked_pool is not None and steps > 1:
+            heads, stored = self.walked_pool
+            walk_block = _pages_per_block(
+                width, heads, page_size, stored, steps, planes=1
+            )
         spans = np.asarray(spans, np.int64).reshape(-1, 2)
         start = spans[:, 0, None]
         query = start + np.arange(steps)[None, :]
-        live = joint = 0
+        live = joint = walked = grid = 0
         for window, layers in self.attention_layers:
             lo, hi = _live_pages(start, query, page_size, width, window, np)
             pages = np.broadcast_to(hi - lo, query.shape)
             live += layers * int(pages.sum())
             if block > 1:
                 joint += layers * int((pages // block * block).sum())
-        return live, joint
+            if walk_block:
+                took = np.broadcast_to(
+                    _row_steps(lo, hi, walk_block, np), query.shape
+                )
+                idle = (rows - len(spans)) * steps
+                walked += layers * (int(took.sum()) + idle)
+                grid += layers * rows * steps * -(-width // walk_block)
+        return live, joint, walked, grid
 
     def _ragged_tiles(self, shape, row_spans, table_width) -> Tuple[int, int]:
         """(live, all) tiles of the ragged kernel's grid for a dispatch of

@@ -748,12 +748,14 @@ class _TailView:
     layer state echoes the big planes unchanged (the driver writes back
     only the tail half)."""
 
-    def __init__(self, cache, base_len, tail_len, step_idx, num_big):
+    def __init__(self, cache, base_len, tail_len, step_idx, num_big,
+                 walk=None):
         self.cache = cache
         self.base_len = base_len
         self.tail_len = tail_len
         self.step_idx = step_idx
         self.num_big = num_big
+        self.walk = {} if walk is None else {"walk": walk}
 
     def q_positions(self, seq_len):
         return (self.base_len + self.tail_len)[:, None]
@@ -768,6 +770,7 @@ class _TailView:
         out, new_tail = self.cache.tail_attend(
             big, tail, q, k_new, v_new, rope, self.base_len, self.tail_len,
             self.step_idx, num_new, sliding_window, scale, **more,
+            **self.walk,
         )
         return out, (*big, *new_tail)
 
@@ -823,6 +826,15 @@ def multi_decode_apply(
         for name in names
     ]
     base_len = cache.lengths
+    # What a pool's sweep derives from the window alone (its ``tail_walk``;
+    # most have none): the table and the pool's lengths stand through the
+    # steps, and a row decodes from the first or not at all (``num_new`` does
+    # not grow), so it is built once, outside both scans.
+    walks = [
+        pool.cache.tail_walk(num_steps, base_len, init_num_new)
+        if hasattr(pool.cache, "tail_walk") else None
+        for pool in pools
+    ]
     # a step's dispatch to the experts is the carried tokens' [B, 1]
     split_w = [
         _split_whole_stacks(
@@ -881,7 +893,7 @@ def multi_decode_apply(
             view = _TailView(
                 pool.cache if seg.index is None
                 else _segment_cache(cache, seg),
-                base_len, tail_len, i, pool.view_num_big,
+                base_len, tail_len, i, pool.view_num_big, walks[at],
             )
             rope = ropes[seg.rope]
             # The read-only big planes ride a segment's scan as ITS layers'
@@ -908,10 +920,9 @@ def multi_decode_apply(
         )
 
     zero_len = jnp.zeros_like(base_len)
+    tails = tuple(pool.cache.tail_init(num_steps) for pool in pools)
     (_, tails, tail_len, _, _), emits = jax.lax.scan(
-        token_step,
-        (tokens, tuple(pool.cache.tail_init(num_steps) for pool in pools),
-         zero_len, init_num_new, init_state),
+        token_step, (tokens, tails, zero_len, init_num_new, init_state),
         jnp.arange(num_steps),
     )
     if names == [None]:

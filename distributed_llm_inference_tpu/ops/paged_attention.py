@@ -33,7 +33,8 @@ Bandwidth properties:
   table in SMEM) and attends to the pages that hold something the query
   sees, a block of live pages as ONE tile of the online softmax (both
   forms: the per-head pools' copies and the latent pool's pipelined
-  blocks). A page past the row's length, or wholly before its sliding
+  blocks, whose grid is one axis over the call's live blocks, listed
+  once). A page past the row's length, or wholly before its sliding
   window, costs nothing; an empty slot runs the tail tile alone;
 * MHA (``G == 1``) uses a VPU multiply-reduce for QK^T and PV — a 1-row MXU
   matmul per head wastes the systolic array; GQA (``G > 1``) uses
@@ -79,6 +80,7 @@ __all__ = [
     "latent_paged_attention",
     "quantized_latent_paged_attention",
     "quantized_latent_paged_fused_attention",
+    "latent_sweep_walk",
     "KERNEL_LATENT_DECODE",
     "KERNEL_LATENT_INDEX_FLUSH",
     "quantized_paged_fused_attention",
@@ -542,6 +544,7 @@ def quantized_latent_paged_fused_attention(
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
     select=None,
+    walk=None,
 ):
     """A fused-decode step of an int8 latent engine: the one-stored-plane
     form of :func:`quantized_paged_fused_attention` (its body, its sweep of
@@ -564,7 +567,7 @@ def quantized_latent_paged_fused_attention(
     rows = dict(
         layer_idx=layer_idx, step_idx=step_idx, page_table=page_table,
         base_len=base_len, tail_valid_len=tail_valid_len,
-        q_positions=q_positions, scale=scale, interpret=interpret,
+        q_positions=q_positions, scale=scale, interpret=interpret, walk=walk,
     )
     if select is None:
         return quantized_paged_fused_attention(
@@ -573,6 +576,30 @@ def quantized_latent_paged_fused_attention(
     return quantized_paged_fused_attention(
         *planes, name="sparse_latent_paged_fused_attention", select=select,
         **rows,
+    )
+
+
+def latent_sweep_walk(pool_c, k_steps, page_table, base_len, decoding):
+    """The ``walk`` of :func:`quantized_latent_paged_fused_attention` (a
+    call without a selection) over this pool under a tail of ``k_steps``
+    slots (:func:`_sweep_walk`), or ``None`` where the stored row is swept
+    by copies. It is a function of
+    the table, the pool's lengths and which rows decode (``decoding``
+    positive: a row's valid tail slots are then positive at every step of a
+    fused window, and zero at every step otherwise): the same for every
+    layer of every step of a window, so a caller that scans them builds it
+    once, outside the scans (XLA hoists only a part of it out of a loop's
+    body: PERF.md §6, PR 48)."""
+    _, _, hkv, page_size, d = pool_c.shape
+    if not _pages_by_grid(d):
+        return None
+    n = _pages_per_block(
+        page_table.shape[1], hkv, page_size, d, k_steps, planes=1
+    )
+    lens = base_len.astype(jnp.int32)
+    return _sweep_walk(
+        page_table.astype(jnp.int32), lens, decoding.astype(jnp.int32),
+        lens, n, page_size, None,
     )
 
 
@@ -628,7 +655,9 @@ def _pages_per_block(t, hkv, page_size, d, kt, planes=2):
     buffers fit :data:`_SWEEP_VMEM_BUDGET` beside the row's scale rows and
     tail. At 8 kv heads of 128 that is 4 pages up to a table of 174 slots,
     2 up to 206 and 1 past it; 8 at 4 heads (up to 182 slots) and for the
-    latent pool's one 576-wide plane."""
+    latent pool's one 576-wide plane, whose blocks are pipelined operands
+    and the steps of its grid (:func:`_sweep_walk`): there the width is
+    also what a row's steps are counted in, cdiv(live pages, 8) of them."""
     lanes = -(-d // 128) * 128
     heads = -(-hkv // 8) * 8
     page = planes * hkv * -(-page_size // 32) * 32 * lanes     # K + V, int8
@@ -659,6 +688,55 @@ def _live_pages(kv_len, qpos, page_size, width, sliding_window, xp=jnp):
     ), hi
 
 
+def _row_steps(lo, hi, n, xp=jnp):
+    """The steps a row of live pages ``[lo, hi)`` takes of a sweep by
+    pipelined blocks of ``n`` pages: its blocks that hold a live page,
+    ``[lo // n, cdiv(hi, n))``, and one where there is none."""
+    return xp.maximum((hi + n - 1) // n - lo // n, 1)
+
+
+def _sweep_walk(table, lens, vlen, qpos, n, page_size, sliding_window,
+                xp=jnp):
+    """The steps of a call that sweeps its pool by pipelined blocks
+    (:func:`_pages_by_grid`), listed once: ``(steps, rows, blocks, pages)``.
+    A row's steps are the blocks of ``n`` table slots that hold one of its
+    live pages, ``[lo // n, cdiv(hi, n))`` by :func:`_live_pages`, in the
+    table's order, and the rows follow each other; a row with no such block
+    (not decoding: ``vlen`` 0) keeps ONE step, its block its range's first,
+    because its tail tile, its division and its results' write are a step's.
+    ``rows`` and ``blocks`` ``[B * cdiv(T, n)]`` name a step's row and block,
+    ``pages`` (flat, ``n`` a step) the physical page of each of the block's
+    places, the null page where a place is dead; past the ``steps`` the call
+    walks all three repeat the last step, and nothing reads them.
+    ``xp=numpy`` lists on the host what the kernel walks
+    (``engine/plan.py`` counts it). A row's numbers reach its steps as sums
+    under the steps' one-hot rows, and a step's pages as ONE row of the
+    table cut in blocks: a gather an entry cost the chip more than all the
+    rest (46 us a list; PERF.md §6, PR 48)."""
+    b, t = table.shape
+    nb = -(-t // n)
+    lo, hi = _live_pages(
+        xp.where(vlen > 0, lens, 0), qpos, page_size, t, sliding_window, xp
+    )
+    lo = lo + 0 * hi
+    count = _row_steps(lo, hi, n, xp)
+    end = xp.cumsum(count)
+    step = xp.minimum(xp.arange(b * nb), end[-1] - 1)[:, None]
+    own = (step >= (end - count)[None, :]) & (step < end[None, :])  # [W, B]
+
+    def of_row(x):
+        return xp.sum(xp.where(own, x[None, :], 0), axis=1)
+
+    rows = of_row(xp.arange(b))
+    blocks = of_row(lo // n - (end - count)) + step[:, 0]
+    by_block = xp.pad(table, ((0, 0), (0, nb * n - t))).reshape(b * nb, n)
+    place = blocks[:, None] * n + xp.arange(n)[None, :]
+    live = (place >= of_row(lo)[:, None]) & (place < of_row(hi)[:, None])
+    # (a row with no live page may name the block past its table: all dead)
+    pages = by_block[rows * nb + xp.minimum(blocks, nb - 1)]
+    return end[-1], rows, blocks, xp.where(live, pages, 0).reshape(-1)
+
+
 def quantized_paged_fused_attention(
     q: jnp.ndarray,
     k_new: jnp.ndarray,
@@ -682,6 +760,7 @@ def quantized_paged_fused_attention(
     sliding_window: Optional[int] = None,
     name: str = "quantized_paged_fused_attention",
     select=None,
+    walk=None,
 ):
     """ONE kernel for a fused-decode step over the int8 page pool IN PLACE:
     the WHOLE ``[L, P, Hkv, PS, D]`` K and V planes stay in HBM
@@ -735,20 +814,31 @@ def quantized_paged_fused_attention(
     rounding, not bit for bit. Skipping a dead page changes no sum.
 
     **Where Mosaic cannot copy a page** (:func:`_pages_by_grid`: a stored
-    row that is not whole 128-lane tiles, the latent pool's 576) the same
-    blocks of a row's table are the steps of a second grid axis and the
-    block's pages come as pipelined operands, the whole pool behind each,
-    its index map the page table's entry. A dead page names the null page
-    (one fetch, then none while the index stands); a block with a live page
-    is ONE tile of the online softmax, its pages side by side under the
-    positions' mask (a page cost 0.54 us as a tile of its own and costs
-    0.20-0.25 in a block of eight, PERF.md §6, PR 31), and a block with
-    none does nothing.
-    The accumulator lives across a row's steps; the tail, the division and
-    the results' write are the last step's. Still no slice and no copy of
-    the pool, each live page read once; what it pays over the copies is the
-    pipeline's fixed cost a step of ``(rows, cdiv(table width, pages a
-    block))`` and the dead pages beside a row's last live one.
+    row that is not whole 128-lane tiles, the latent pool's 576) the block's
+    pages come as pipelined operands, the whole pool behind each, and the
+    grid is ONE axis over the call's live steps, its bound the count of
+    them (a dynamic grid dimension: what is not live is not a step the
+    chip executes, where a dead step of a ``(rows, table blocks)`` grid
+    costs 0.65 us and a trailing step of a static bound 0.31; under a
+    selection the grid is still that one, rows x every block of the table,
+    its index maps deriving a row's live range a step: see ``walked``
+    below). The steps are
+    listed once (:func:`_sweep_walk`; ``walk``, where the caller built the
+    list outside its scans: :func:`latent_sweep_walk`) and ride as
+    scalar-prefetch operands: a step's row, its block of the row's table,
+    and the physical page of each of the block's places, the null page
+    where a place is dead (one fetch, then none while the index stands), so
+    an index map is a read of SMEM. A row's steps are its blocks that hold a
+    live page, in the table's order, and they are ONE tile of the online
+    softmax each, the pages side by side under the positions' mask (a page
+    cost 0.54 us as a tile of its own and costs 0.17 in a block of eight,
+    PERF.md §6, PR 31 and PR 48); a row with no live page keeps one step.
+    The accumulator is cleared at a row's first step and lives across its
+    steps; the tail, the division and the results' write are its last
+    step's. Still no slice and no copy of the pool, each live page read
+    once; what it pays over the copies is the pipeline's fixed cost a LIVE
+    step (1.23 us a block of eight pages whose bytes take 0.36) and the
+    dead pages beside a row's last live one.
 
     The scale rows do not come that way: Mosaic (jax 0.9.0) refuses an
     async copy out of an HBM plane whose minor dimension is one page
@@ -773,6 +863,9 @@ def quantized_paged_fused_attention(
     without a selection traces to the program it always did. Both forms of
     the sweep take it: the copies' and the pipelined page blocks' (the
     latent pool's).
+
+    ``walk``: the list :func:`_sweep_walk` gives for these rows, built by
+    the caller; the copies' form and a call under a selection never read it.
     """
     b, s, hq, d = q.shape
     if s != 1:
@@ -793,6 +886,13 @@ def quantized_paged_fused_attention(
     planes = 1 if shared else 2
     n = _pages_per_block(t, hkv, page_size, d, kt, planes)
     by_grid = _pages_by_grid(d)
+    # Pipelined blocks walk the call's list, but not yet under a selection:
+    # that call keeps the (rows, table blocks) grid, its program the one it
+    # was. Walked, ``glm-5.2.codebase``'s decode step was 16% shorter and
+    # its judged ``tpot_ms_p50`` read 2.4% WORSE (a median of 21 requests,
+    # half of them decoding under the lead-in's prefills, that moved with
+    # the schedule's trajectory: PERF.md §6 and §7, PR 48).
+    walked = by_grid and select is None
 
     qr = q.reshape(b, hkv, g, d)
     # Per stored plane, in the kernel's operand order: the step's fresh
@@ -815,18 +915,41 @@ def quantized_paged_fused_attention(
         # test and fill (a quarter of this gather's time on the chip).
         return jnp.take(layer, table, axis=0, mode="clip")
 
-    # An index map takes the grid's ids, then the six scalar operands
-    # (layer, step, table, lengths, valid tail slots, query positions).
-    def _tail_index(bi, *a):
-        return (a[-6][0], bi, 0, 0, 0)
+    lens = base_len.astype(jnp.int32)
+    vlen = tail_valid_len.astype(jnp.int32)
+    qpos = q_positions.astype(jnp.int32)
+    # The scalar operands: layer, step, page ids, lengths, valid tail slots,
+    # query positions. A row's page ids are its table's; the walk's (the
+    # call's list, ``_sweep_walk``) take the table's place, and its rows and
+    # blocks follow.
+    grid, scalars = (b,), (lref, sref, table, lens, vlen, qpos)
+    if walked:
+        steps, rows, blocks, pages = walk or _sweep_walk(
+            table, lens, vlen, qpos, n, page_size, sliding_window
+        )
+        grid = (steps,)
+        scalars = (lref, sref, pages, lens, vlen, qpos, rows, blocks)
+    elif by_grid:
+        grid = (b, -(-t // n))
 
-    def _tail_index3(bi, *a):
-        return (a[-6][0], bi, 0, 0)
+    # An index map takes the grid's ids, then the scalar operands.
+    def _at(a):  # the layer and the row of a grid step
+        s = a[len(grid):]
+        return s[0][0], s[6][a[0]] if walked else a[0]
 
-    def _row_index(bi, *a):
-        return (bi, 0, 0, 0)
+    def _tail_index(*a):
+        return (*_at(a), 0, 0, 0)
+
+    def _tail_index3(*a):
+        return (*_at(a), 0, 0)
+
+    def _row_index(*a):
+        return (_at(a)[1], 0, 0, 0)
 
     def _page_index(i):
+        if walked:  # a read of SMEM is all it computes
+            return lambda at, *s: (s[0][0], s[2][at * n + i], 0, 0, 0)
+
         def index(bi, ji, lidx, step, table, lens, vlen, qpos):
             page = ji * n + i
             lo, hi = _live_pages(
@@ -858,8 +981,8 @@ def quantized_paged_fused_attention(
             pltpu.SemaphoreType.DMA((2, n)),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(b, -(-t // n)) if by_grid else (b,),
+        num_scalar_prefetch=len(scalars),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, hkv, g, d), _row_index),
             *[pl.BlockSpec((1, hkv, 1, d), _row_index)] * planes,
@@ -870,7 +993,7 @@ def quantized_paged_fused_attention(
             ] * planes,
             *([] if select is None else [
                 pl.BlockSpec((1, t, 1, page_size), _row_index),
-                pl.BlockSpec((1, 1, kt), lambda bi, *a: (bi, 0, 0)),
+                pl.BlockSpec((1, 1, kt), lambda *a: (_at(a)[1], 0, 0)),
             ]),
         ],
         out_specs=(
@@ -888,8 +1011,10 @@ def quantized_paged_fused_attention(
         _qpaged_fused_kernel,
         planes=planes,
         by_grid=by_grid,
+        walked=walked,
         scale=scale,
         page_size=page_size,
+        width=t,
         pages_per_block=n,
         sliding_window=sliding_window,
         hkv=hkv,
@@ -898,8 +1023,8 @@ def quantized_paged_fused_attention(
         selected=select is not None,
     )
     # Tail planes update in place; an alias's index counts every flattened
-    # input, the 6 scalar-prefetch operands and q and the fresh values too.
-    first_tail = 6 + 1 + planes
+    # input, the scalar-prefetch operands and q and the fresh values too.
+    first_tail = len(scalars) + 1 + planes
     out, *new_tails = pl.pallas_call(
         kernel,
         name=name,
@@ -914,13 +1039,12 @@ def quantized_paged_fused_attention(
         },
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
-                ("parallel", "arbitrary") if by_grid else ("parallel",)
+                ("arbitrary",) if walked
+                else ("parallel", "arbitrary") if by_grid else ("parallel",)
             ),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
-    )(lref, sref, table, base_len.astype(jnp.int32),
-      tail_valid_len.astype(jnp.int32), q_positions.astype(jnp.int32),
-      qr, *fresh, *tails,
+    )(*scalars, qr, *fresh, *tails,
       *[
           x for plane, sc in pools
           for x in (*[plane] * len(pool_specs), _scale_rows(sc))
@@ -934,16 +1058,18 @@ def quantized_paged_fused_attention(
 def _qpaged_fused_kernel(
     lidx_ref,   # SMEM [1] int32 (layer)
     step_ref,   # SMEM [1] int32 (tail write slot)
-    table_ref,  # SMEM [B, T] int32 (physical page ids)
+    table_ref,  # SMEM [B, T] int32 (physical page ids; ``walked`` the
+                # walk's, flat, N a step: the index maps read them)
     len_ref,    # SMEM [B] int32 (live pool tokens)
     vlen_ref,   # SMEM [B] int32 (valid tail slots incl. this write)
     qpos_ref,   # SMEM [B] int32 (query positions)
-    q_ref,      # [1, Hkv, G, D]
     *refs,
     planes: int,
     by_grid: bool,
+    walked: bool,
     scale: float,
     page_size: int,
+    width: int,
     pages_per_block: int,
     sliding_window: Optional[int],
     hkv: int,
@@ -954,6 +1080,9 @@ def _qpaged_fused_kernel(
     """``refs``, for the stored planes K and V (``planes`` 2) or the one
     plane that is both (``planes`` 1), a plane after the other in each group:
 
+    * ``walked``: the walk's row and block a step, SMEM ``[steps]`` int32
+      (:func:`_sweep_walk`), the last two scalar operands;
+    * the queries ``[1, Hkv, G, D]``;
     * fresh values ``[1, Hkv, 1, D]``;
     * tail in: values ``[1, 1, Hkv, KT, D]`` int8, scale row
       ``[1, 1, Hkv, KT]`` f32;
@@ -976,6 +1105,8 @@ def _qpaged_fused_kernel(
         del refs[:count]
         return taken
 
+    row_ref, blk_ref = _take(2) if walked else (None, None)
+    (q_ref,) = _take(1)
     new_refs = _take(planes)
     tail_in = _take(2 * planes)
     per = n + 1 if by_grid else 2
@@ -988,7 +1119,7 @@ def _qpaged_fused_kernel(
     acc_ref, m_ref, l_ref = refs
     scale_rows = pool[per - 1 :: per]
 
-    b = pl.program_id(0)
+    b = row_ref[pl.program_id(0)] if walked else pl.program_id(0)
     layer = lidx_ref[0]
     # A row with no valid tail slot is not decoding (an empty slot, a
     # released one, one parked mid-prefill: a decoding row's tail holds at
@@ -996,9 +1127,7 @@ def _qpaged_fused_kernel(
     # tenant left there, so it is not believed: the row sweeps nothing.
     kv_len = jnp.where(vlen_ref[b] > 0, len_ref[b], 0)
     qpos = qpos_ref[b]
-    lo, hi = _live_pages(
-        kv_len, qpos, page_size, table_ref.shape[1], sliding_window
-    )
+    lo, hi = _live_pages(kv_len, qpos, page_size, width, sliding_window)
 
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
@@ -1098,16 +1227,24 @@ def _qpaged_fused_kernel(
         out_ref[0] = out.reshape(hkv, g, -1).astype(out_ref.dtype)
 
     if by_grid:
-        # The block of the row's table this grid step holds, its pages the
-        # step's pipelined operands: put side by side they are ONE tile, if
-        # any of them is live (a page a tile is a chain of matmul, maximum,
-        # exponent, sum, matmul that the next page's waits for: 0.54 us a
-        # live page against 0.20-0.25, PERF.md §6, PR 31). A dead page
-        # beside a live one is the null page under a mask (its scale row any
-        # row the table has).
-        blk = pl.program_id(1)
-        pl.when(blk == 0)(_init)
-        last = table_ref.shape[1] - 1
+        # The block of the row's table this step of the walk holds, its
+        # pages the step's pipelined operands: put side by side they are ONE
+        # tile, if any of them is live (a page a tile is a chain of matmul,
+        # maximum, exponent, sum, matmul that the next page's waits for:
+        # 0.54 us a live page against 0.20-0.25, PERF.md §6, PR 31). A dead
+        # page beside a live one is the null page under a mask (its scale
+        # row any row the table has). A row's steps are the blocks from its
+        # range's first to its last, one where it has none (``_sweep_walk``);
+        # or, not ``walked``, every block of its table (grid axis 1).
+        if walked:
+            blk = blk_ref[pl.program_id(0)]
+            first = lo // n
+            finish = first + _row_steps(lo, hi, n) - 1
+        else:
+            blk, first = pl.program_id(1), 0
+            finish = pl.num_programs(1) - 1
+        pl.when(blk == first)(_init)
+        last = width - 1
 
         @pl.when((blk * n < hi) & (blk * n + n > lo))
         def _block_tile():
@@ -1127,12 +1264,12 @@ def _qpaged_fused_kernel(
                 ) > 0
             _tile(stored, valid, n * page_size)
 
-        pl.when(blk == pl.num_programs(1) - 1)(_finish)
+        pl.when(blk == finish)(_finish)
         return
 
     hbms = pool[0::per]
     num_blocks = (hi - lo + n - 1) // n
-    last = table_ref.shape[1] - 1
+    last = width - 1
 
     def _page_copies(slot, i, page):
         phys = table_ref[b, page]

@@ -71,6 +71,12 @@ METRICS = {
         "counter", "Pages decode rows hold inside their windows, a layer a step"),
     "decode_pages_joint": (
         "counter", "Of those, pages in full blocks of the in-place sweep (a tile, unpadded)"),
+    # a pool whose decode sweep walks a list of its live blocks (the latent
+    # pool's): walked / grid is the share of rows x table blocks it keeps
+    "decode_sweep_steps_walked": (
+        "counter", "Grid steps the latent decode sweep walks, a layer a step"),
+    "decode_sweep_steps_grid": (
+        "counter", "Rows x table blocks of the same dispatches"),
     # routed experts (ops/moe.py:expert_rows_per_token): needed / computed
     # over an interval is pad waste times the compute strategy's waste
     "moe_expert_rows_needed": (

@@ -448,6 +448,52 @@ def test_swept_pages_are_what_the_kernels_own_arithmetic_gives(case):
     assert m.get_counter("decode_pages_live") == live
 
 
+@pytest.mark.parametrize("width", [59, 256, 5], ids=["reason1k", "codebase", "narrow"])
+def test_walked_steps_are_what_the_sweeps_own_list_walks(width):
+    """``decode_sweep_steps_walked`` / ``decode_sweep_steps_grid`` of a
+    recorded decode dispatch over a pool whose sweep walks a list
+    (``walked_pool``: the latent pool's one 576-wide plane) against the
+    kernel's own list (``_sweep_walk``, its host twin) over a table laid out
+    for the dispatch's rows: every step of the dispatch walks what its first
+    does (the pool's lengths stand), an idle row one step, and the grid is
+    rows x the table's blocks."""
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        _pages_per_block, _sweep_walk,
+    )
+    from distributed_llm_inference_tpu.utils.metrics import Metrics
+
+    m, steps, rows, ps = Metrics(), 16, 32, 64
+    p = AttentionPlan(
+        EngineConfig(prefill_buckets=(8,)),
+        CacheConfig(kind="paged", page_size=ps), metrics=m,
+    )
+    p.walked_pool = (1, 576)
+    n = _pages_per_block(width, 1, ps, 576, steps, 1)
+    assert n == min(8, 1 << (width.bit_length() - 1))
+    rng = np.random.default_rng(width)
+    first = [n * ps - 1, n * ps, n * ps + 1, 1, 0, (width + 2) * ps,
+             *rng.integers(1, width * ps, 14).tolist()]
+    active = rng.permutation(rows)[: len(first)]
+    # (an idle row's length is stale: whatever its last tenant left)
+    lens, vlen = np.full(rows, 7 * ps, np.int64), np.zeros(rows, np.int64)
+    lens[active], vlen[active] = first, 1
+    walked = _sweep_walk(
+        np.zeros((rows, width), np.int64), lens, vlen, lens, n, ps, None, np
+    )[0]
+    p.note_dispatch("decode", (rows, steps, width), sum(first) + len(first),
+                    len(first), query_spans=[(int(f), steps) for f in first])
+    assert m.get_counter("decode_sweep_steps_walked") == steps * int(walked)
+    assert m.get_counter("decode_sweep_steps_grid") == (
+        steps * rows * -(-width // n))
+    assert rows <= walked < rows * -(-width // n) or width == 5
+    # a pool swept by copies, and a token a dispatch, count neither
+    p.note_dispatch("decode", (rows, 1, width), 100, 4, query_spans=[(9, 1)])
+    p.walked_pool = None
+    p.note_dispatch("decode", (rows, steps, width), 100, 4,
+                    query_spans=[(9, steps)])
+    assert m.get_counter("decode_sweep_steps_walked") == steps * int(walked)
+
+
 @pytest.mark.parametrize("block_q", [8, 16])
 def test_ragged_tile_census_on_a_two_row_engine(block_q):
     """``ragged_attn_tiles_live`` / ``_grid`` against a count made by hand.

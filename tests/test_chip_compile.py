@@ -162,25 +162,72 @@ def test_latent_decode_kernels_at_moonlights_shapes(chip, pool, width):
 
 
 @pytest.mark.parametrize("width", [59, SLOTS])   # the cell's pinned table; the cap
-def test_fused_latent_decode_kernel_at_reason1ks_shapes(chip, width):
-    """The one-stored-plane form of the fused int8 decode kernel as
-    ``moonlight-16b-a3b.reason1k``'s decode scan gives it: the WHOLE
-    16-layer latent pool in HBM (an async copy of a ``[1, 64, 576]`` int8
-    page out of it), 32 rows, a 16-slot tail, bf16 matmuls over the 576-wide
-    row as stored."""
+@pytest.mark.parametrize(
+    "heads,layers", [(LAT_HQ, LAT_LAYERS), (32, 13)],
+    ids=["moonlight-16b-a3b", "xing4.0-29b-a4b"],
+)
+def test_fused_latent_decode_kernel_at_reason1ks_shapes(chip, heads, layers, width):
+    """The one-stored-plane form of the fused int8 decode kernel as the two
+    ``reason1k`` cells' decode scans give it: the WHOLE latent pool in HBM
+    (a ``[1, 64, 576]`` int8 page out of it a pipelined operand, eight a
+    step), 32 rows, a 16-slot tail, bf16 matmuls over the 576-wide row as
+    stored, its grid ONE dynamic axis over the steps of the walk that the
+    window built once (``latent_sweep_walk``: models/llama.py)."""
     s, b = chip, 32
+
+    def step(q, c_new, pool_c, pool_cs, tail_c, tail_cs, layer, at, table,
+             lens, vlen, qpos):
+        walk = pa.latent_sweep_walk(pool_c, KT, table, lens, vlen)
+        assert walk is not None and walk[1].shape == (b * -(-width // 8),)
+        return pa.quantized_latent_paged_fused_attention(
+            q, c_new, pool_c, pool_cs, tail_c, tail_cs, layer, at, table,
+            lens, vlen, qpos, scale=192 ** -0.5, interpret=False, walk=walk,
+        )
+
     _compiles_with_kernel(
-        lambda *a: pa.quantized_latent_paged_fused_attention(
-            *a, scale=192 ** -0.5, interpret=False
-        ),
-        s((b, 1, LAT_HQ, LAT_W), jnp.bfloat16),
+        step,
+        s((b, 1, heads, LAT_W), jnp.bfloat16),
         s((b, 1, 1, LAT_W), jnp.bfloat16),
-        s((LAT_LAYERS, LAT_PAGES, 1, PS, LAT_W), I8),
-        s((LAT_LAYERS, LAT_PAGES, 1, PS), F32),
-        s((LAT_LAYERS, b, 1, KT, LAT_W), I8), s((LAT_LAYERS, b, 1, KT), F32),
+        s((layers, LAT_PAGES, 1, PS, LAT_W), I8),
+        s((layers, LAT_PAGES, 1, PS), F32),
+        s((layers, b, 1, KT, LAT_W), I8), s((layers, b, 1, KT), F32),
         s((), I32), s((), I32),
         s((b, width), I32), s((b,), I32), s((b,), I32), s((b,), I32),
     )
+
+
+def test_a_per_head_pools_call_keeps_the_copies_form():
+    """The walk is the pipelined blocks' alone (``_pages_by_grid``: a stored
+    row Mosaic cannot copy). A per-head pool's call at ``mistral-7b.reason``'s
+    shapes is the call it was: a grid over the rows, both pools whole in HBM
+    (``pl.ANY``), six scalar-prefetch operands (layer, step, table, lengths,
+    valid tail slots, query positions), and ``walk`` a word it never reads."""
+    s = jax.ShapeDtypeStruct
+    b, width, pages, bf16 = 32, 38, 1280, jnp.bfloat16
+    pool = (s((LAYERS, pages, HKV, PS, D), I8), s((LAYERS, pages, HKV, PS), F32))
+    tail = (s((LAYERS, b, HKV, KT, D), I8), s((LAYERS, b, HKV, KT), F32))
+    assert not pa._pages_by_grid(D) and pa._pages_by_grid(LAT_W)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: pa.quantized_paged_fused_attention(
+            *a, sliding_window=4096, interpret=True
+        )
+    )(
+        s((b, 1, HQ, D), bf16), s((b, 1, HKV, D), bf16), s((b, 1, HKV, D), bf16),
+        *pool, *pool, *tail, *tail, s((), I32), s((), I32),
+        s((b, width), I32), s((b,), I32), s((b,), I32), s((b,), I32),
+    )
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    gm = eqn.params["grid_mapping"]
+    assert gm.grid == (b,) and gm.num_dynamic_grid_bounds == 0
+    assert gm.num_index_operands == 6
+    assert [v.aval.shape for v in eqn.invars[:6]] == [
+        (1,), (1,), (b, width), (b,), (b,), (b,)]
+    whole = [m for m in gm.block_mappings[:gm.num_inputs]
+             if m.block_aval.shape == (LAYERS, pages, HKV, PS, D)
+             and "any" in str(m.block_aval)]
+    assert len(whole) == 2, [str(m.block_aval) for m in gm.block_mappings]
+    # nothing but the kernel's scale-row gathers stands beside it: no list
+    assert not [e for e in jaxpr.eqns if e.primitive.name == "cumsum"]
 
 
 @pytest.mark.parametrize("width", [59, SLOTS])

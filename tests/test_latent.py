@@ -587,8 +587,12 @@ def _kernel_cache(cfg, page_size, rows=2, slots=8):
 
 
 @pytest.mark.parametrize("page_size", [8, 16], ids=["scattered", "page-rmw"])
-@pytest.mark.parametrize("layers", [1, 2])
-def test_sixteen_fused_steps_are_sixteen_one_token_steps(layers, page_size):
+@pytest.mark.parametrize(
+    "layers,latent", [(1, MLA_CFG.latent), (2, MLA_CFG.latent),
+                      (2, LatentConfig(rank=128, rope_head_dim=32))],
+    ids=["1", "2", "2-pipelined-pages"],
+)
+def test_sixteen_fused_steps_are_sixteen_one_token_steps(layers, latent, page_size):
     """``multi_decode_apply`` over the int8 latent pool (the kernel,
     interpreted) against 16 ``model_apply`` steps over the same pool: the same
     tokens, and after ``tail_flush`` the same pool. Bit for bit where what is
@@ -599,10 +603,14 @@ def test_sixteen_fused_steps_are_sixteen_one_token_steps(layers, page_size):
     layer's latents may differ by one step of the int8 grid."""
     import dataclasses
 
-    cfg = dataclasses.replace(MLA_CFG, num_layers=layers)
+    cfg = dataclasses.replace(MLA_CFG, num_layers=layers, latent=latent)
     params = llama.init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
     cache = _kernel_cache(cfg, page_size)
     assert cache.has_tail and cache.tail_in_kernel and cache.tail_reads_whole_big
+    # a 160-wide stored row comes as pipelined blocks, walked by the list
+    # the model builds once for the window's 16 steps x layers
+    assert (cache.tail_walk(16, cache.lengths, cache.lengths + 1) is None) == (
+        latent.lat_dim != 160)
     prompts = jnp.asarray([[3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37],
                            [2, 4, 6, 8, 10, 0, 0, 0, 0, 0, 0]], jnp.int32)
     n_valid = jnp.asarray([11, 5], jnp.int32)
@@ -683,3 +691,206 @@ def test_the_fused_kernels_operands_by_stored_planes(planes):
     assert tuple(eqn.params["input_output_aliases"]) == tuple(
         (first_tail + i, 1 + i) for i in range(2 * planes))
     assert len(in_hbm) == planes, [str(m.block_aval) for m in gm.block_mappings]
+
+
+# -- the walk of the latent pool's decode sweep (ISSUE 48) ----------------------
+#
+# Where a pool's pages come as pipelined blocks the grid is ONE axis over the
+# call's live steps: for each row the blocks of ``n`` table slots that hold a
+# live page, a row with none keeping one step (``pa._sweep_walk``). The
+# oracle is this file's own: each row's blocks walked in the table's order
+# with plain ``jax.numpy``, a block one tile of the online softmax in the
+# kernel's arithmetic (bf16 operands into float32 sums), then the tail's
+# tile. Under a selection the kernel still steps through rows x every block
+# of the table (its program the parent's): the same plain walk is what it
+# must give, a block with no live page changing no sum.
+
+_WALK = dict(ps=16, kt=16, g=4, d=160, layers=2, layer=1, step=3, scale=0.2)
+_PS = _WALK["ps"]
+# case -> (table width, (pool length, decoding) a row); 8 pages a block at
+# these shapes wherever the table is that wide
+_WALK_CASES = {
+    "idle-row-between-two": (19, [(5 * _PS + 2, 1), (3 * _PS, 0), (9 * _PS - 1, 1)]),
+    "every-row-idle": (19, [(5 * _PS + 2, 0), (3 * _PS, 0)]),
+    "n-pages-and-n-plus-1": (19, [(8 * _PS, 1), (8 * _PS + 1, 1), (1, 1)]),
+    "table-no-multiple-of-n": (11, [(11 * _PS - 16, 1), (4 * _PS + 5, 1)]),
+    "row-fills-its-table": (16, [(16 * _PS, 1), (16 * _PS - 16, 1)]),
+}
+
+
+def _walk_inputs(case, seed=0):
+    f = _WALK
+    t, kinds = _WALK_CASES[case]
+    ps, kt, g, d, layers = f["ps"], f["kt"], f["g"], f["d"], f["layers"]
+    rows = len(kinds)
+    lens, decoding = map(np.asarray, zip(*kinds))
+    rng = np.random.default_rng([seed, t, rows])
+    pages = rows * t + 1
+    a = dict(
+        q=jnp.asarray(rng.normal(size=(rows, 1, g, d)), jnp.bfloat16),
+        c_new=jnp.asarray(rng.normal(size=(rows, 1, 1, d)), jnp.bfloat16),
+        pool_c=jnp.asarray(rng.integers(-127, 128, (layers, pages, 1, ps, d)), jnp.int8),
+        pool_cs=jnp.asarray(rng.uniform(0.01, 0.03, (layers, pages, 1, ps)), jnp.float32),
+        tail_c=jnp.asarray(rng.integers(-127, 128, (layers, rows, 1, kt, d)), jnp.int8),
+        tail_cs=jnp.asarray(rng.uniform(0.01, 0.03, (layers, rows, 1, kt)), jnp.float32),
+        layer_idx=f["layer"], step_idx=f["step"],
+        page_table=jnp.asarray(
+            rng.permutation(np.arange(1, pages)).reshape(rows, t), jnp.int32),
+        base_len=jnp.asarray(lens, jnp.int32),
+        tail_valid_len=jnp.asarray(np.where(decoding, f["step"] + 1, 0), jnp.int32),
+        q_positions=jnp.asarray(lens + f["step"], jnp.int32), scale=f["scale"],
+    )
+    select = (
+        jnp.asarray(rng.integers(0, 2, (rows, t, 1, ps)), jnp.float32),
+        jnp.asarray(rng.integers(0, 2, (rows, 1, kt)), jnp.float32),
+    )
+    return a, select
+
+
+def _plain_walk(a, select, n, tail_c, tail_cs):
+    """The rows' results by a plain walk: row by row, its blocks
+    ``[0, cdiv(live pages, n))`` in order (none where it is not decoding),
+    a dead place the null page under the mask, then the tail as the kernel
+    left it, then the division."""
+    f = _WALK
+    ps, kt, g, layer, scale = f["ps"], f["kt"], f["g"], f["layer"], f["scale"]
+    table, lens = np.asarray(a["page_table"]), np.asarray(a["base_len"])
+    vlen = np.asarray(a["tail_valid_len"])
+    t = table.shape[1]
+    pool, scales = a["pool_c"][layer], a["pool_cs"][layer]
+    neg = pa._NEG_INF
+
+    def tile(state, qb, kk, kks, valid):
+        acc, m, l = state
+        kb = kk.astype(jnp.bfloat16)[None]
+        s = jax.lax.dot_general(
+            qb, kb, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        s = (s * kks[:, None, :] * scale).reshape(g, -1)
+        s = jnp.where(valid, s, neg)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            (p.reshape(1, g, -1) * kks[:, None, :]).astype(jnp.bfloat16), kb,
+            (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
+        return acc * alpha + pv.reshape(g, -1), m_new, l
+
+    def row(r):
+        kv_len = int(lens[r]) if vlen[r] else 0
+        hi = min(-(-kv_len // ps), t)
+        qb = a["q"][r, 0].astype(jnp.bfloat16)[None]
+        state = (jnp.zeros((g, qb.shape[-1]), jnp.float32),
+                 jnp.full((g, 1), neg, jnp.float32), jnp.zeros((g, 1), jnp.float32))
+        for blk in range(-(-hi // n)):
+            places = range(blk * n, blk * n + n)
+            slots = [min(p, t - 1) for p in places]
+            kk = jnp.concatenate(
+                [pool[table[r, p] if p < hi else 0, 0] for p in places], 0)
+            kks = jnp.concatenate([scales[table[r, s]] for s in slots], -1)
+            valid = (blk * n * ps + jnp.arange(n * ps))[None] < kv_len
+            if select is not None:
+                valid &= jnp.concatenate(
+                    [select[0][r, s] for s in slots], -1) > 0
+            state = tile(state, qb, kk, kks, valid)
+        valid = jnp.arange(kt)[None] < vlen[r]
+        if select is not None:
+            valid &= select[1][r] > 0
+        acc, _, l = tile(
+            state, qb, tail_c[layer, r, 0], tail_cs[layer, r], valid)
+        return (acc / jnp.maximum(l, 1e-20)).astype(a["q"].dtype)
+
+    return jnp.stack([row(r) for r in range(len(lens))])[:, None]
+
+
+@pytest.mark.parametrize("selected", [False, True], ids=["all-keys", "selection"])
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_the_walked_sweep_is_a_plain_walk_of_the_same_blocks_bit_for_bit(
+        case, selected):
+    a, select = _walk_inputs(case)
+    select = select if selected else None
+    t = a["page_table"].shape[1]
+    n = pa._pages_per_block(t, 1, _WALK["ps"], _WALK["d"], _WALK["kt"], 1)
+    assert pa._pages_by_grid(_WALK["d"]) and n == 8
+    out, tail_c, tail_cs = pa.quantized_latent_paged_fused_attention(
+        **a, select=select)
+    # compiled, as the interpreter compiles the kernel's body
+    want = jax.jit(lambda tc, tcs: _plain_walk(a, select, n, tc, tcs))(tail_c, tail_cs)
+    got = np.asarray(out.astype(jnp.float32))
+    np.testing.assert_array_equal(got, np.asarray(want.astype(jnp.float32)))
+    decoding = np.asarray(a["tail_valid_len"]) > 0
+    assert (got[~decoding] == 0).all() and np.abs(got[decoding]).min(axis=(1, 2, 3)).all()
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["every-key", "window-40"])
+@pytest.mark.parametrize("n", [4, 8])
+def test_the_walk_names_each_live_block_once_and_in_row_order(n, window):
+    """The wrapper's list (``jax.numpy``) is its host twin's (``numpy``, what
+    ``engine/plan.py`` counts by), and both are the rows' live blocks by
+    ``_live_pages`` written out in loops: a row's blocks in the table's
+    order, the rows in theirs, one step for a row with no live block, a dead
+    place the null page, and past the walked steps the last one again."""
+    ps, t, rows = 16, 19, 7
+    rng = np.random.default_rng([n, window or 0])
+    lens = np.asarray([0, 5 * ps + 2, t * ps, 3 * ps, 8 * ps, 8 * ps + 1, 12 * ps - 1])
+    vlen = np.asarray([2, 2, 2, 0, 2, 2, 2])
+    qpos = lens + 1
+    table = rng.permutation(np.arange(1, rows * t + 1)).reshape(rows, t).astype(np.int32)
+    host = pa._sweep_walk(table, lens, vlen, qpos, n, ps, window, np)
+    device = pa._sweep_walk(
+        *(jnp.asarray(x, jnp.int32) for x in (table, lens, vlen, qpos)), n, ps, window)
+    for h, d in zip(host, device):
+        np.testing.assert_array_equal(h, np.asarray(d))
+    want = []
+    for r in range(rows):
+        lo, hi = pa._live_pages(
+            lens[r] if vlen[r] else 0, qpos[r], ps, t, window, np)
+        for blk in range(lo // n, max(-(-hi // n), lo // n + 1)):
+            want.append((r, blk, [
+                table[r, p] if lo <= p < hi else 0
+                for p in range(blk * n, blk * n + n)]))
+    steps, at, blocks, pages = host
+    assert steps == len(want) < rows * -(-t // n) == len(at) == len(blocks)
+    want += [want[-1]] * (len(at) - len(want))
+    assert [(r, b) for r, b, _ in want] == list(zip(at.tolist(), blocks.tolist()))
+    np.testing.assert_array_equal(pages.reshape(-1, n), [p for _, _, p in want])
+    assert len(set(zip(at[:steps].tolist(), blocks[:steps].tolist()))) == steps
+
+
+@pytest.mark.parametrize("rank,rope", [(16, 8), (128, 32)],
+                         ids=["copied-pages", "pipelined-pages"])
+def test_an_engine_counts_the_steps_its_latent_sweep_walks(rank, rope):
+    """An int8 latent engine whose stored row comes as pipelined blocks
+    (160 wide here, 576 in the cells) tells its plan (``walked_pool``), and a
+    decode dispatch adds to ``decode_sweep_steps_walked`` /
+    ``decode_sweep_steps_grid``; a row the copies sweep counts neither."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        MLA_CFG, latent=LatentConfig(rank=rank, rope_head_dim=rope))
+    eng = InferenceEngine(
+        cfg, llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32),
+        EngineConfig(max_batch_size=2, prefill_buckets=(8, 16, 32),
+                     max_seq_len=256, dtype="float32",
+                     use_pallas_attention=True),
+        CacheConfig(kind="paged", kv_quant="int8", page_size=PS, num_pages=64,
+                    max_pages_per_session=16),
+        rng=jax.random.PRNGKey(1),
+    )
+    piped = rank == 128
+    assert eng.decode_steps == 16
+    assert eng.plan.walked_pool == ((1, rank + rope) if piped else None)
+    eng.generate([list(range(3, 3 + 9 * PS))], SamplingOptions(max_new_tokens=20))
+    assert eng.cache.page_table.shape[1] == 12
+    walked = eng.metrics.get_counter("decode_sweep_steps_walked")
+    grid = eng.metrics.get_counter("decode_sweep_steps_grid")
+    if not piped:
+        assert walked == grid == 0
+        return
+    # 12 table slots are two blocks of 8 a row: the live row's 10 or 11 pages
+    # take both, the idle row one step, of 2 x 2 every step of a window
+    assert grid > 0 and walked * 4 == grid * 3 and grid % (16 * 4) == 0
+    text = eng.metrics.prometheus()
+    assert "decode_sweep_steps_walked_total" in text
+    assert "decode_sweep_steps_grid_total" in text

@@ -2,7 +2,8 @@
 
     python tools/profile_paged_sweep.py [--rows 32] [--width 47] [--pages 1600]
         [--window 4096] [--occupancy full reason chat window3 select182]
-        [--form inplace|gathered|latent|latent-grid] [--blocks 4 8 16]
+        [--form inplace|gathered|latent|latent32|latent-grid]
+        [--blocks 4 8 16]
 
 ``quantized_paged_fused_attention`` at Mistral-7B widths (32 q / 8 kv heads
 of 128, 64-token pages, 32 layers, a 16-slot tail) over a seeded int8 pool,
@@ -32,11 +33,16 @@ a fused window of ``KT`` steps, so a ``KT``-th of it belongs to a step).
 
 ``--form latent`` times the one-stored-plane form of the same kernel at
 Moonlight's widths (16 query heads on one latent head of 576, 16 layers: an
-int8 latent engine's decode step, ``quantized_latent_paged_fused_attention``)
+int8 latent engine's decode step, ``quantized_latent_paged_fused_attention``),
+``--form latent32`` the same at Xing4.0's (32 query heads, 13 layers),
 and ``--form latent-grid`` what such an engine ran before it had a tail: the
 ``(slots, table width)`` grid of ``quantized_latent_paged_attention`` over a
 layer's slice of the pool (the slice's copy is in ``busy_ms_a_step``).
 ``--occupancy reason1k``: 32 live rows of 1.5-3k tokens, that cell's mix.
+``steps_walked`` / ``steps_grid`` count a call's grid steps where the pool
+is swept by pipelined blocks (a row's blocks that hold a live page, one for a
+row with none, against rows x table blocks), ``kernel_us_a_walked_step`` the
+kernel's time over the first.
 
 Import the package from another checkout with ``PYTHONPATH=<root>`` to time
 that checkout's kernel on the same chip: the tool itself uses nothing else
@@ -44,6 +50,7 @@ of the repository but ``utils/xplane.py``. ``busy_ms_a_step`` is the whole
 step's device time: the kernel and what its wrapper runs beside it.
 """
 import argparse
+import hashlib
 import json
 import os
 import sys
@@ -72,6 +79,7 @@ FORMS = {
     "inplace": (32, 8, 128, 32, "quantized_paged_fused_attention"),
     "gathered": (32, 8, 128, 32, "quantized_fused_decode_attention"),
     "latent": (16, 1, 576, 16, "quantized_latent_paged_attention"),
+    "latent32": (32, 1, 576, 13, "quantized_latent_paged_attention"),
     # "..._grid_attention" since the fused form took the name
     "latent-grid": (16, 1, 576, 16, "quantized_latent_paged_"),
 }
@@ -155,6 +163,15 @@ def run_case(args, kind, pick):
 
     @jax.jit
     def step(pool, tails, table, lens, vlen, select):
+        # the walk of the sweep, built outside the layers' loop as the
+        # engine builds it (models/llama.py; there once a window of 16
+        # steps); a checkout from before PR 48 has none
+        walk = {}
+        if args.form in ("latent", "latent32") and hasattr(
+            pa, "latent_sweep_walk"
+        ):
+            walk["walk"] = pa.latent_sweep_walk(pool[0], KT, table, lens, vlen)
+
         def layer(i, carry):
             tails, acc = carry
             if args.form == "gathered":
@@ -162,10 +179,10 @@ def run_case(args, kind, pick):
                     q, new, new, *pool, *tails, i, jnp.int32(3),
                     lens, vlen, lens + 3, sliding_window=window,
                 )
-            elif args.form == "latent":
+            elif args.form in ("latent", "latent32"):
                 out, *tails = pa.quantized_latent_paged_fused_attention(
                     q, new, *pool, *tails, i, jnp.int32(3), table,
-                    lens, vlen, lens + 3, scale=192 ** -0.5,
+                    lens, vlen, lens + 3, scale=192 ** -0.5, **walk,
                 )
             elif args.form == "latent-grid":
                 out = pa.quantized_latent_paged_attention(
@@ -237,6 +254,11 @@ def run_case(args, kind, pick):
     ns = sum(v for k, v in agg["ops_ns"].items() if kernel in k)
     calls = sum(v for k, v in agg["op_counts"].items() if kernel in k)
     planes = 1 if latent else 2
+    # the grid steps of a pipelined-block sweep: what the ``(rows, table
+    # blocks)`` grid held, and what the walk of PR 48 keeps of it (a row's
+    # blocks with a live page, one for a row with none)
+    n = pa._pages_per_block(t, HKV, PS, D, KT, planes)
+    walked = int(np.maximum(-(-hi // n) - lo // n, 1).sum())
     print(json.dumps({
         "form": args.form, "gather_ms_a_window": round(gather_ms, 3),
         "occupancy": kind, "rows": b, "width": t,
@@ -249,11 +271,19 @@ def run_case(args, kind, pick):
         "kernel_us_a_live_page": round(
             ns / args.reps / LAYERS / max(int(live.sum()), 1) / 1e3, 4
         ),
+        "steps_walked": walked, "steps_grid": b * -(-t // n),
+        "kernel_us_a_walked_step": round(
+            ns / args.reps / LAYERS / walked / 1e3, 4
+        ),
         "busy_ms_a_step": round(
             agg["devices"][0]["busy_ns"] / args.reps / 1e6, 3
         ) if agg["devices"] else 0.0,
         "first_call_s": round(first_call_s, 2),
         "checksum": float(jnp.sum(acc)),
+        # of the step's results and its tail, bit for bit
+        "sha": hashlib.sha256(b"".join(
+            np.asarray(x).tobytes() for x in (acc, *tails)
+        )).hexdigest()[:16],
         "device": jax.devices()[0].device_kind,
     }), flush=True)
 
