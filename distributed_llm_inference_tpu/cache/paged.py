@@ -105,6 +105,26 @@ def _page_chunks(a, cap, slots, ps):
     return jnp.swapaxes(a, 2, 3)
 
 
+def _row_spans(pages, table):
+    """Every row's table span of a pool plane, contiguous and head-major:
+    ``[L, P, H, PS, D]`` by ``table [B, T]`` to ``[L, B, H, T*PS, D]`` (the
+    fused window's read-only big segment where no kernel reads the pages in
+    place; the int8 class's ``tail_big_stacks`` is the same read of its four
+    planes, the whole stack at once). Unmapped slots read the null page:
+    masked by ``pos < base_len``. A layer at a time: the whole stack in one
+    gather holds two more copies of its result while it is transposed (11.7
+    GB of temporaries at a 64-slot table of the tp=4 cell, where this form
+    holds 6.8: described-v5e compiles, PR 49) and takes 24.6 ms where this
+    takes 14.6 (one chip at that cell's shard, 2.55 GB gathered: my chip
+    run, PR 49)."""
+    def layer(plane):                             # [P, H, PS, D]
+        v = jnp.swapaxes(jnp.take(plane, table, axis=0), 1, 2)
+        b, h, t, ps, d = v.shape                  # [B, H, T, PS, D]
+        return v.reshape(b, h, t * ps, d)
+
+    return jax.lax.map(layer, pages)
+
+
 class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
     k_pages: jax.Array
     v_pages: jax.Array
@@ -323,31 +343,79 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
 
     # -- write-behind tail (fused multi-step decode) --------------------------
     #
-    # Kernel-only: the XLA fallback's per-step page gather is exactly the
-    # materialization the tail exists to avoid, so the engine gates the tail
-    # path on use_kernel for this cache. The page POOL stays read-only
-    # through all K steps (it rides the layer scan as a sliced operand —
-    # the carry-slice version costs two full pool copies plus relayouts per
-    # layer per step, ~4x the kernel's own time at 7B shapes) and new
-    # tokens live in a small dense tail merged into pages once per K steps.
+    # The page POOL stays read-only through all K steps and new tokens live
+    # in a small dense tail merged into pages once per K steps. With the
+    # kernel the pool rides the layer scan as a sliced operand (the
+    # carry-slice version costs two full pool copies plus relayouts per layer
+    # per step, ~4x the kernel's own time at 7B shapes) and the paged kernel
+    # sweeps it in place. Without the kernel (a mesh engine, the CPU) the big
+    # segment is the int8 class's gathered form: every row's table span made
+    # contiguous ONCE a window (``tail_big_stacks``) and read under one
+    # softmax with the tail, in pure XLA. The one-token path pays that gather
+    # a layer a step, and carries the pool through the layer scan to write
+    # one position a row into it.
+
+    #: the fused window's protocol (``tail_init`` / ``tail_attend`` /
+    #: ``tail_flush``) is implemented: the engine's tail gate asks this. A
+    #: subclass whose stored form the protocol does not cover says False.
+    has_tail = True
+
+    def tail_big_stacks(self):
+        """Read-only stacks for the fused window: with the kernel the pool
+        planes; without it ``(k, v) [L, B, Hkv, Tmax, D]`` in the pool's
+        dtype (:func:`_row_spans`)."""
+        if self.use_kernel:
+            return (self.k_pages, self.v_pages)
+        return (
+            _row_spans(self.k_pages, self.page_table),
+            _row_spans(self.v_pages, self.page_table),
+        )
 
     def tail_init(self, k_steps: int):
         l = self.k_pages.shape[0]
         b = self.page_table.shape[0]
         hkv, d = self.k_pages.shape[2], self.k_pages.shape[4]
-        z = jnp.zeros((l, b, k_steps, hkv, d), self.k_pages.dtype)
+        # time-major beside the kernel's stats merge, head-major beside the
+        # gathered stacks (the contraction's batch(B, Hkv) structure)
+        shape = (
+            (l, b, k_steps, hkv, d) if self.use_kernel
+            else (l, b, hkv, k_steps, d)
+        )
+        z = jnp.zeros(shape, self.k_pages.dtype)
         return (z, z)
 
     def tail_attend(self, big_state, tail_state, q, k_new, v_new, rope,
                     base_len, tail_len, step_idx, num_new, sliding_window,
                     scale=None):
+        tk, tv = tail_state
+        q_rot = apply_rope(q, rope.cos, rope.sin)
+        k_rot = apply_rope(k_new, rope.cos, rope.sin)
+        if not self.use_kernel:
+            from ..ops.attention import gqa_attention_segments
+            from .dense import segment_valids
+
+            gk, gv = big_state                    # [B, Hkv, Tmax, D]
+            tk = jax.lax.dynamic_update_slice_in_dim(
+                tk, jnp.moveaxis(k_rot, 1, 2).astype(tk.dtype), step_idx,
+                axis=2,
+            )
+            tv = jax.lax.dynamic_update_slice_in_dim(
+                tv, jnp.moveaxis(v_new, 1, 2).astype(tv.dtype), step_idx,
+                axis=2,
+            )
+            big_valid, tail_valid = segment_valids(
+                base_len, tail_len, num_new, gk.shape[2], tk.shape[2],
+                sliding_window,
+            )
+            out = gqa_attention_segments(
+                q_rot, [(gk, gv, big_valid), (tk, tv, tail_valid)], scale,
+                head_major=True,
+            )
+            return out, (tk, tv)
         from ..ops.attention import merge_softmax_segments
         from ..ops.paged_attention import paged_attention
 
         pool_k, pool_v = big_state
-        tk, tv = tail_state
-        q_rot = apply_rope(q, rope.cos, rope.sin)
-        k_rot = apply_rope(k_new, rope.cos, rope.sin)
         tk = jax.lax.dynamic_update_slice_in_dim(tk, k_rot, step_idx, axis=1)
         tv = jax.lax.dynamic_update_slice_in_dim(tv, v_new, step_idx, axis=1)
 
@@ -372,8 +440,11 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
         return out, (tk, tv)
 
     def tail_flush(self, tail, tail_len):
-        """Merge the tail into the page pool: the prefill scatter path, once
-        per K fused steps, batched over layers via vmap."""
+        """Merge the tail into the page pool, once per K fused steps: with
+        the kernel the prefill scatter path, batched over layers via vmap;
+        without it :meth:`_flush_rows`."""
+        if not self.use_kernel:
+            return self._flush_rows(tail, tail_len)
         wk, wv = tail  # [L, B, K, Hkv, D]
         kk = wk.shape[2]
         q_pos = (
@@ -385,6 +456,65 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
                 lk, lv, tkl, tvl, q_pos, num_new
             )
         )(self.k_pages, self.v_pages, wk, wv)
+        return self.replace(
+            k_pages=new_k, v_pages=new_v, lengths=self.lengths + tail_len
+        )
+
+    def _flush_rows(self, tail, tail_len):
+        """The head-major tail ``[L, B, Hkv, K, D]`` into the pages, a row
+        at a time, as a read-modify-write of the pages the row's K positions
+        lie in (at most ``(K + PS - 2) // PS + 1``): what :meth:`_scatter`
+        writes, in place in the donated pool. The XLA scatter wants the pool
+        in a layout of its own: two whole-pool relayout copies a plane in
+        the decode executable, 9.3 ms a window where this takes 2.0 (one
+        chip at the tp=4 cell's shard of a 1.25 GB pool: my chip run,
+        PR 49). A page's new values are one contiguous run of the tail,
+        padded by a page on each side so that the run may start before the
+        tail or end past it; positions outside ``[base, base + tail_len)``
+        keep the page's own, and a row that wrote nothing, or a slot past
+        the table, rewrites the null page with itself (``_slot_pages``'s
+        diversion)."""
+        kk, ps = tail[0].shape[3], self.page_size
+        slots = self.page_table.shape[1]
+        touched = (kk + ps - 2) // ps + 1
+        in_page = jnp.arange(ps, dtype=jnp.int32)
+        pad = ((0, 0), (0, 0), (0, 0), (ps, touched * ps), (0, 0))
+        padded = tuple(jnp.pad(t, pad) for t in tail)
+
+        def row(r, pools):
+            base, n = self.lengths[r], tail_len[r]
+            runs = tuple(
+                jax.lax.dynamic_index_in_dim(t, r, 1, keepdims=False)
+                for t in padded
+            )                                     # [L, Hkv, PS + K + .., D]
+            for j in range(touched):
+                slot = base // ps + j
+                page = jnp.where(
+                    (slot < slots) & (n > 0),
+                    self.page_table[r, jnp.minimum(slot, slots - 1)], 0,
+                )
+                first = slot * ps - base          # tail slot of the page's first
+                src = first + in_page
+                mine = ((src >= 0) & (src < n))[None, None, :, None]
+
+                def merged(pool, run):
+                    old = jax.lax.dynamic_index_in_dim(
+                        pool, page, 1, keepdims=False
+                    )
+                    new = jax.lax.dynamic_slice_in_dim(
+                        run, first + ps, ps, axis=2
+                    )
+                    return jax.lax.dynamic_update_index_in_dim(
+                        pool, jnp.where(mine, new.astype(pool.dtype), old),
+                        page, 1,
+                    )
+
+                pools = tuple(map(merged, pools, runs))
+            return pools
+
+        new_k, new_v = jax.lax.fori_loop(
+            0, self.page_table.shape[0], row, (self.k_pages, self.v_pages)
+        )
         return self.replace(
             k_pages=new_k, v_pages=new_v, lengths=self.lengths + tail_len
         )
@@ -1394,6 +1524,8 @@ class IndexedPagedKVCache(_IndexPlane, PagedKVCache):
         with jax.named_scope("sparse_attention"):
             out = attention_fn(q_rot, k_all, v_all, mask & sel, scale=scale)
         return out, (*new, nik)
+
+    has_tail = False
 
     def tail_init(self, k_steps: int):
         raise NotImplementedError(

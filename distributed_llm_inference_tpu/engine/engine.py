@@ -732,33 +732,26 @@ class InferenceEngine:
 
         # The write-behind tail composes with tp/ep/dp sharding (its scalar
         # slot writes and flush gather partition) but not with the staged
-        # pipeline program, which pp engines use per step instead. The int8
-        # paged cache's tail gathers its pool once per fused window (pure
-        # XLA) or, past ``INPLACE_CTX`` with the kernel, reads it in place;
-        # the bf16 paged tail still reads pages in place and requires the
-        # Pallas kernel.
+        # pipeline program, which pp engines use per step instead. A paged
+        # cache says itself whether it has the protocol (``has_tail``): the
+        # value-dtype and the int8 pool always do (their big segment is a
+        # kernel's sweep of the pages in place where there is a kernel, and
+        # every row's table span gathered once a window, pure XLA, where
+        # there is none: a mesh engine, the CPU); the int8 latent pool with
+        # the Pallas kernel does (its own rope-free tail_attend over the
+        # fused in-place sweep); the float32 latent pool, the int8 latent
+        # pool without the kernel and the value-dtype indexed pool decode a
+        # token a dispatch (their tail_init raises).
         tail_capable = (
             attention is None
             and not self._use_pp
-            # A latent cache says itself whether it has the protocol: the
-            # int8 pool with the Pallas kernel does (its own rope-free
-            # tail_attend over the fused in-place sweep); the float32 pool,
-            # and the int8 pool without the kernel, decode a token a
-            # dispatch (the per-head tail would re-apply RoPE to the
-            # pre-rotated stored form; their tail_init raises).
             and (
-                not isinstance(self.cache, LatentPagedKVCache)
-                or self.cache.has_tail
-            )
-            and (
-                isinstance(
+                self.cache.has_tail
+                if isinstance(self.cache, PagedKVCache)
+                else isinstance(
                     self.cache,
                     (DenseKVCache, QuantizedDenseKVCache,
-                     QuantizedPagedKVCache, QuantizedSinkKVCache),
-                )
-                or (
-                    isinstance(self.cache, PagedKVCache)
-                    and self.cache.use_kernel
+                     QuantizedSinkKVCache),
                 )
             )
         )
@@ -809,9 +802,11 @@ class InferenceEngine:
             (EOS / budget) keep computing but write nothing (``num_new=0``)
             and emit ``-1``. Returns ``(emitted [K, B], cache)``.
 
-            Dense cache kinds run the write-behind-tail fast path
+            Every cache with the tail protocol (the dense kinds, the int8
+            sink ring, the paged pools with or without a kernel: see
+            ``tail_capable`` above) runs the write-behind-tail fast path
             (``llama.multi_decode_apply`` — big KV buffers read-only through
-            all K steps); other caches scan ``model_apply`` per step.
+            all K steps); the others scan ``model_apply`` per step.
             """
             if tail_capable:
                 def step_fn(i, logits, alive):

@@ -807,10 +807,13 @@ def multi_decode_apply(
 
     The dense cache kinds implement the tail protocol
     (``tail_init`` / ``tail_attend`` / ``tail_flush``) natively, and
-    ``PagedKVCache`` implements it over its page pool (kernel-gated: the
-    pool segment runs the Pallas paged kernel with exported softmax stats,
-    joint-merged with the tail — see ``cache/paged.py``); callers fall back
-    to per-step ``model_apply`` for other caches.
+    ``PagedKVCache`` implements it over its page pool in two forms: with the
+    kernel the pool segment runs the Pallas paged kernel with exported
+    softmax stats, joint-merged with the tail; without it (a mesh engine,
+    the CPU) every row's table span is gathered contiguous once a window
+    (``tail_big_stacks``) and the two segments share one softmax in pure
+    XLA — see ``cache/paged.py``. Callers fall back to per-step
+    ``model_apply`` for a cache that says it has no tail (``has_tail``).
     """
     inv_freq = rope_inv_freq(_rope_dim(cfg), cfg.rope_theta, cfg.rope_scaling)
     segments = cfg.segments

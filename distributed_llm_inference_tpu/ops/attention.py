@@ -168,6 +168,7 @@ def gqa_attention_segments(
     q: jnp.ndarray,
     segments: Sequence[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]],
     scale: Optional[float] = None,
+    head_major: bool = False,
 ) -> jnp.ndarray:
     """GQA attention over MULTIPLE KV segments under one joint softmax.
 
@@ -178,11 +179,13 @@ def gqa_attention_segments(
     cache, segment 1 the small write-behind tail.
 
     ``q``: ``[B, S, Hq, D]``; each segment ``(k, v, valid)`` with
-    ``k``/``v`` ``[B, Ti, Hkv, D]`` (time-major) and ``valid`` ``[B, Ti]``
-    (True = attend). Returns ``[B, S, Hq, D]``.
+    ``k``/``v`` ``[B, Ti, Hkv, D]`` (time-major; ``[B, Hkv, Ti, D]`` with
+    ``head_major``, the paged pool's gathered spans) and ``valid`` ``[B,
+    Ti]`` (True = attend). Returns ``[B, S, Hq, D]``.
     """
     b, s, hq, d = q.shape
-    hkv = segments[0][0].shape[2]
+    kv = "bktd" if head_major else "btkd"
+    hkv = segments[0][0].shape[1 if head_major else 2]
     g = hq // hkv
     if scale is None:
         scale = d**-0.5
@@ -191,7 +194,7 @@ def gqa_attention_segments(
     scored = []
     for k, v, valid in segments:
         sc = jnp.einsum(
-            "bskgd,btkd->bkgst", qg, k,
+            f"bskgd,{kv}->bkgst", qg, k,
             preferred_element_type=jnp.float32,
         ) * scale
         m = valid[:, None, None, None, :]
@@ -201,17 +204,23 @@ def gqa_attention_segments(
         jnp.maximum,
         [jnp.max(sc, axis=-1, keepdims=True) for sc, _ in scored],
     )
+    # head-major: the product as the dot gives it, permuted afterwards (the
+    # CPU backend has no bf16 dot with a permuted result)
+    pv = f"bkgst,{kv}->" + ("bkgsd" if head_major else "bskgd")
     denom = 0.0
     out = 0.0
     for (sc, m), (k, v, valid) in zip(scored, segments):
         w = jnp.where(m, jnp.exp(sc - gmax), 0.0)
         denom = denom + jnp.sum(w, axis=-1, keepdims=True)
         out = out + jnp.einsum(
-            "bkgst,btkd->bskgd", w.astype(v.dtype), v,
-            preferred_element_type=jnp.float32,
+            pv, w.astype(v.dtype), v, preferred_element_type=jnp.float32,
         )
-    denom = jnp.maximum(denom, 1e-20).transpose(0, 3, 1, 2, 4)
-    return (out / denom).reshape(b, s, hq, d).astype(q.dtype)
+    denom = jnp.maximum(denom, 1e-20)
+    if head_major:
+        out = (out / denom).transpose(0, 3, 1, 2, 4)
+    else:
+        out = out / denom.transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, s, hq, d).astype(q.dtype)
 
 
 def gqa_attention_quantized_multi_q_segments(

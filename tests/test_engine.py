@@ -26,7 +26,7 @@ CFG = ModelConfig(
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 
 
-def make_engine(kind="paged", batch=4, **cache_kw):
+def make_engine(kind="paged", batch=4, decode_steps=None, **cache_kw):
     cache_defaults = dict(
         kind=kind, page_size=8, num_pages=64, max_pages_per_session=8,
         window_length=32, num_sink_tokens=2,
@@ -36,7 +36,7 @@ def make_engine(kind="paged", batch=4, **cache_kw):
         CFG, PARAMS,
         EngineConfig(
             max_batch_size=batch, prefill_buckets=(8, 16, 32), max_seq_len=64,
-            dtype="float32",
+            dtype="float32", decode_steps=decode_steps,
         ),
         CacheConfig(**cache_defaults),
     )
@@ -586,7 +586,8 @@ def test_cancel_active_session_frees_slot():
     admits queued work (cancel() is a flag; the scheduler owns state)."""
     from distributed_llm_inference_tpu.engine.session import SessionState
 
-    eng = make_engine(batch=1)
+    # a token a tick: what is counted below is ticks
+    eng = make_engine(batch=1, decode_steps=1)
     a = eng.submit(prompts(1, seed=13)[0], SamplingOptions(max_new_tokens=50))
     b = eng.submit(prompts(1, seed=14)[0], SamplingOptions(max_new_tokens=3))
     for _ in range(3):

@@ -205,6 +205,43 @@ def test_prefill_then_the_fused_scan_through_the_int8_two_pool_cache(model, kern
             assert name in text, name
 
 
+def test_the_fused_scan_through_the_float32_two_pool_cache(model):
+    """The value-dtype class has the tail too (since PR 49, without a
+    kernel: each pool's table span gathered once a window, each pool its own
+    tail and flush): one prefill, then the scan, against the reference's
+    full forward at float32's own distance, with a window (8) shorter than
+    the context."""
+    hf, cfg, params = model
+    t, steps, ps = 29, 5, 8
+    toks = np.random.default_rng(1).integers(1, cfg.vocab_size, size=t + steps + 1)
+    gold = np.asarray(reference.forward(hf, params, jnp.asarray(toks[:-1])))
+    cls = two_pool_cache_class(False, cfg.attention_kinds, cfg.sliding_window)
+    cache = cls.create(
+        2, 1, 6, ps, -(-(t + steps) // ps), cfg.num_kv_heads, cfg.head_dim,
+        jnp.float32,
+    ).assign_pages(0, [1, 2, 3, 4, 5])
+    assert cache.has_tail and not cache.use_kernel
+    padded = jnp.zeros((1, 32), jnp.int32).at[0, :t].set(jnp.asarray(toks[:t]))
+    forced = jnp.asarray(toks[t:], jnp.int32)
+    one = jnp.ones((1,), jnp.int32)
+
+    def run(params, padded, forced, cache):
+        first, cache = llama.model_apply(
+            cfg, params, padded, cache, t * one, head="last"
+        )
+        scanned, cache = llama.multi_decode_apply(
+            cfg, params, forced[:1][None], cache, steps,
+            lambda i, logits, st: (forced[i + 1][None], one, st, logits),
+            jnp.zeros(()), one,
+        )
+        return first[0, 0], scanned[:, 0], cache
+
+    first, scanned, cache = jax.jit(run)(params, padded, forced, cache)
+    ours = np.concatenate([np.asarray(first)[None], np.asarray(scanned)])
+    assert max(rel(o, g) for o, g in zip(ours, gold[t - 1:])) < 1e-4
+    assert int(cache.lengths[0]) == t + steps
+
+
 def test_every_layer_full_is_another_model(model):
     """The control the cell's ``correct`` rests on: with the window opened
     wide the reference is far from itself as published."""

@@ -90,11 +90,15 @@ COMBOS = [
 OPTS = dict(max_new_tokens=48)  # room for an in-flight reshape
 
 
-def make_engine(kind="paged", kv_quant=None, batch=2, prefix=False):
+def make_engine(kind="paged", kv_quant=None, batch=2, prefix=False,
+                decode_steps=None):
+    """``decode_steps=1``: a token a tick, for the tests that must catch a
+    48-token stream in mid-run (three ticks of a fused engine)."""
     return InferenceEngine(
         CFG, PARAMS,
         EngineConfig(max_batch_size=batch, prefill_buckets=(8, 16, 32),
-                     max_seq_len=64, dtype="float32"),
+                     max_seq_len=64, dtype="float32",
+                     decode_steps=decode_steps),
         CacheConfig(kind=kind, kv_quant=kv_quant, page_size=8, num_pages=64,
                     max_pages_per_session=8, prefix_caching=prefix),
     )
@@ -543,11 +547,12 @@ def test_drain_hands_off_active_and_waiting_sessions(loop):
     prompts = [[3, 5, 7, 11, 13], [2, 4, 6, 8, 10, 12]]
     bases = []
     for p in prompts:
-        e = make_engine(batch=2)
+        e = make_engine(batch=2, decode_steps=1)
         bases.append(drain_engine(e, e.submit(list(p), opts)))
     with RelayServer() as relay:
         with DirectoryService(relay.port, default_ttl=5.0):
-            n1 = DecodeNode(relay.port, make_engine(batch=1), node_id="n1",
+            n1 = DecodeNode(relay.port,
+                            make_engine(batch=1, decode_steps=1), node_id="n1",
                             disagg_cfg=RECOVERY_DCFG, epoch=1)
             backend = FleetBackend(relay.port, disagg_cfg=RECOVERY_DCFG)
             backend.start(loop)
@@ -568,7 +573,8 @@ def test_drain_hands_off_active_and_waiting_sessions(loop):
                        and time.monotonic() < deadline):
                     time.sleep(0.01)
                 assert len(n1.engine.sessions) == 2
-                n2 = DecodeNode(relay.port, make_engine(batch=2),
+                n2 = DecodeNode(relay.port,
+                                make_engine(batch=2, decode_steps=1),
                                 node_id="n2", disagg_cfg=RECOVERY_DCFG,
                                 epoch=1)
                 deadline = time.monotonic() + 10.0
@@ -601,11 +607,12 @@ def test_rebalance_migrates_sessions_off_hot_node(loop):
     prompts = [[3, 5, 7, 11, 13], [2, 4, 6, 8, 10, 12]]
     bases = []
     for p in prompts:
-        e = make_engine()
+        e = make_engine(decode_steps=1)
         bases.append(drain_engine(e, e.submit(list(p), opts)))
     with RelayServer() as relay:
         with DirectoryService(relay.port, default_ttl=5.0):
-            n1 = DecodeNode(relay.port, make_engine(), node_id="n1",
+            n1 = DecodeNode(relay.port, make_engine(decode_steps=1),
+                            node_id="n1",
                             disagg_cfg=RECOVERY_DCFG, epoch=1)
             backend = FleetBackend(relay.port, disagg_cfg=RECOVERY_DCFG)
             backend.start(loop)
@@ -629,7 +636,8 @@ def test_rebalance_migrates_sessions_off_hot_node(loop):
                            list(n1.engine.sessions.values())) < 6
                        and time.monotonic() < deadline):
                     time.sleep(0.01)
-                n2 = DecodeNode(relay.port, make_engine(), node_id="n2",
+                n2 = DecodeNode(relay.port, make_engine(decode_steps=1),
+                                node_id="n2",
                                 disagg_cfg=RECOVERY_DCFG, epoch=1)
                 deadline = time.monotonic() + 10.0
                 moved = 0

@@ -253,3 +253,133 @@ def test_paged_fused_kernel_tail_matches_xla_path(PS, SLOTS, prompt_lens):
         np.asarray(c0.v_pages, np.int32) - np.asarray(c1.v_pages, np.int32)
     )
     assert dk.max() <= 1 and dv.max() <= 1, (dk.max(), dv.max())
+
+
+def test_kernel_less_tail_matches_per_step_path():
+    """The write-behind tail WITHOUT the kernel (the gathered XLA form a mesh
+    engine and the CPU take): 16 fused steps over a float32 ``PagedKVCache``
+    give the logits of 16 ``model_apply`` steps and, after the flush, the
+    pages the per-step writes leave. Rows stop inside the window, one row
+    has ``num_new`` 0 from the start and no page mapped, the sliding window
+    is shorter than the contexts, and no row's table is mapped to its end."""
+    cfg = ModelConfig(
+        vocab_size=128, hidden_size=64, intermediate_size=160, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim=16, sliding_window=12,
+    )
+    params = llama.init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    B, K, PS, SLOTS = 4, 16, 4, 12
+    lens = jnp.asarray([9, 14, 5, 0], jnp.int32)
+    budget = jnp.asarray([16, 5, 11, 0], jnp.int32)
+    cache = PagedKVCache.create(
+        cfg.num_layers, B, 32, PS, SLOTS, cfg.num_kv_heads, cfg.head_dim,
+        dtype=jnp.float32,
+    )
+    assert cache.has_tail and not cache.use_kernel
+    alloc = PageAllocator(32)
+    for row in range(3):  # the pages a row writes, and no slot past them
+        need = -(-int(lens[row] + budget[row]) // PS)
+        cache = cache.assign_pages(row, alloc.alloc(need))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, 16), 0, 128)
+    logits, cache = llama.model_apply(cfg, params, tokens, cache, lens)
+    first = jnp.argmax(
+        logits[jnp.arange(B), jnp.maximum(lens - 1, 0)], -1
+    )[:, None].astype(jnp.int32)
+    alive0 = budget > 0
+
+    def advance(i, lg, alive):
+        nxt = jnp.argmax(lg, -1).astype(jnp.int32)
+        return nxt, alive & (i + 1 < budget)
+
+    def step_fn(i, lg, alive):
+        nxt, still = advance(i, lg, alive)
+        return nxt, still.astype(jnp.int32), still, (nxt, lg, alive)
+
+    (toks, lgs, alive), fused = jax.jit(
+        lambda c: llama.multi_decode_apply(
+            cfg, params, first, c, K, step_fn, alive0,
+            alive0.astype(jnp.int32),
+        )
+    )(cache)
+
+    one = jax.jit(
+        lambda t, c, n: llama.model_apply(cfg, params, t, c, n)
+    )
+    tok, ref, live = first, cache, alive0
+    for i in range(K):
+        lg, ref = one(tok, ref, live.astype(jnp.int32))
+        np.testing.assert_array_equal(np.asarray(alive[i]), np.asarray(live))
+        rows = np.asarray(live)
+        np.testing.assert_allclose(
+            np.asarray(lgs[i])[rows], np.asarray(lg[:, 0])[rows],
+            atol=2e-5, rtol=2e-5,
+        )
+        nxt, live = advance(i, lg[:, 0], live)
+        np.testing.assert_array_equal(
+            np.asarray(toks[i])[rows], np.asarray(nxt)[rows]
+        )
+        tok = nxt[:, None]
+    np.testing.assert_array_equal(
+        np.asarray(fused.lengths), np.asarray(lens + budget)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(fused.lengths), np.asarray(ref.lengths)
+    )
+    # page 0 absorbs the writes of rows that stopped, in both forms
+    for got, want in ((fused.k_pages, ref.k_pages),
+                      (fused.v_pages, ref.v_pages)):
+        np.testing.assert_allclose(
+            np.asarray(got[:, 1:]), np.asarray(want[:, 1:]),
+            atol=1e-6, rtol=0,
+        )
+
+
+@pytest.mark.parametrize(
+    "PS,K,SLOTS", [(8, 16, 8), (64, 16, 4), (4, 16, 12), (16, 4, 6)],
+    ids=["three-pages", "inside-a-page", "five-pages", "short-window"],
+)
+def test_kernel_less_flush_writes_what_the_scatter_writes(PS, K, SLOTS):
+    """``PagedKVCache._flush_rows`` (a row's pages read, merged, written in
+    place) against the prefill scatter it stands for, bit for bit: a row at
+    length 0, a row that ends on the table's last position, a row that
+    wrote part of its window, a row that wrote nothing, and a row whose
+    window runs past the table (diverted to the null page in both)."""
+    L, B, H, D, P = 2, 5, 2, 8, 64
+    rng = np.random.default_rng(PS)
+    cache = PagedKVCache.create(L, B, P, PS, SLOTS, H, D, jnp.float32)
+    cache = cache.replace(
+        k_pages=jax.random.normal(jax.random.PRNGKey(1), cache.k_pages.shape),
+        v_pages=jax.random.normal(jax.random.PRNGKey(2), cache.v_pages.shape),
+    )
+    cap = PS * SLOTS
+    lens = np.array([0, cap - K, rng.integers(1, cap - K),
+                     rng.integers(1, cap - K), cap - 3], np.int32)
+    wrote = np.array([K, K, rng.integers(1, K), 0, K], np.int32)
+    table, at = np.zeros((B, SLOTS), np.int32), 1
+    for r in range(B):
+        n = min(-(-int(lens[r] + wrote[r]) // PS), SLOTS)
+        table[r, :n] = np.arange(at, at + n)
+        at += n
+    cache = cache.replace(
+        page_table=jnp.asarray(table), lengths=jnp.asarray(lens)
+    )
+    tail = tuple(
+        jax.random.normal(jax.random.PRNGKey(3 + i), t.shape)
+        for i, t in enumerate(cache.tail_init(K))
+    )                                             # [L, B, H, K, D]
+    got = jax.jit(lambda c, t, n: c.tail_flush(t, n))(
+        cache, tail, jnp.asarray(wrote)
+    )
+    q_pos = jnp.asarray(lens)[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
+    want_k, want_v = jax.vmap(
+        lambda lk, lv, tk, tv: cache._scatter(
+            lk, lv, tk, tv, q_pos, jnp.asarray(wrote)
+        )
+    )(cache.k_pages, cache.v_pages,
+      jnp.moveaxis(tail[0], 2, 3), jnp.moveaxis(tail[1], 2, 3))
+    np.testing.assert_array_equal(np.asarray(got.lengths), lens + wrote)
+    np.testing.assert_array_equal(
+        np.asarray(got.k_pages[:, 1:]), np.asarray(want_k[:, 1:])
+    )
+    np.testing.assert_array_equal(
+        np.asarray(got.v_pages[:, 1:]), np.asarray(want_v[:, 1:])
+    )

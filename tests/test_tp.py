@@ -164,3 +164,49 @@ def test_build_mesh_layout_failure_is_an_error(monkeypatch):
     monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
     with pytest.raises(RuntimeError, match="cannot assign"):
         build_mesh(MeshConfig(dp=2, tp=4))
+
+
+def test_tp4_paged_engine_decodes_fused_and_matches_a_token_a_dispatch():
+    """A ``tp=4`` engine over the value-dtype paged cache has no kernel (the
+    plan is off under a mesh) and has the write-behind tail all the same
+    (``PagedKVCache``'s gathered form): it resolves 16 steps a dispatch,
+    pipelined, and its greedy streams are the ``decode_steps=1`` engine's
+    token for token, across an admission in mid-run (five prompts, two
+    slots), rows that stop on an EOS inside a window, and a table that grows
+    (contexts pass the first rung of the ladder)."""
+    from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig
+    from distributed_llm_inference_tpu.engine.engine import InferenceEngine
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    params = llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
+
+    def engine(decode_steps):
+        return InferenceEngine(
+            CFG, params,
+            EngineConfig(max_batch_size=2, prefill_buckets=(8, 16),
+                         max_seq_len=64, dtype="float32",
+                         decode_windows=(16, 32, 64),
+                         decode_steps=decode_steps),
+            CacheConfig(kind="paged", page_size=8, num_pages=48,
+                        max_pages_per_session=8),
+            mesh_cfg=MeshConfig(tp=4),
+        )
+
+    fused, single = engine(None), engine(1)
+    assert not fused.cache.use_kernel and fused.cache.has_tail
+    assert fused.decode_steps == 16 and fused._pipelined
+    assert single.decode_steps == 1 and not single._pipelined
+    rng = np.random.default_rng(49)
+    prompts = [rng.integers(0, CFG.vocab_size, size=n).tolist()
+               for n in (5, 11, 7, 3, 9)]
+    free = SamplingOptions(max_new_tokens=40)
+    want = single.generate(prompts, free)
+    assert [len(w) for w in want] == [40] * 5
+    assert fused.generate(prompts, free) == want
+    assert fused.metrics.snapshot().get("cache_growths", 0) >= 1
+    # a token the first stream emits inside its second window ends it there
+    # (and whichever other stream meets it)
+    eos = SamplingOptions(max_new_tokens=40, eos_token_id=want[0][20])
+    stopped = single.generate(prompts, eos)
+    assert len(stopped[0]) <= 21 and stopped != want
+    assert fused.generate(prompts, eos) == stopped
