@@ -893,7 +893,7 @@ def run_four_chips(size: Size, seed: int, log: CompileLog, on_chip: bool):
                   "ragged": engine.plan.enabled,
                   "use_ragged": engine.cache.use_ragged,
                   "pallas_decode": engine.cache.use_kernel,
-                  "overlap_admission": engine._overlap_ok(),
+                  "overlaps_admission": engine._overlap_ok(),
               },
               "decode_steps": engine.decode_steps, "tokens": tokens,
               "smoke_reading_wall_s": round(time.monotonic() - t0, 1),

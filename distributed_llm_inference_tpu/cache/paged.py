@@ -1167,10 +1167,10 @@ class QuantizedPagedKVCache(PagedKVCache):
     #: engine's growth ladder, which widens the table bucket-by-bucket as
     #: sessions lengthen (grow-disabled mesh configs sit at full capacity
     #: and always take the in-place form, a conservative choice). Below the
-    #: threshold the gathered form wins (r3 measurement: +40% at 256-token
-    #: contexts, where the gather is cheap and row-blocked 256-wide tiles
-    #: beat per-page DMAs); above it the gather's second copy of the live
-    #: KV dominates (halved admissible batch at 1k ctx).
+    #: threshold the gather is cheap and row-blocked 256-wide tiles stand
+    #: against per-page DMAs; above it the gather is a second copy of the
+    #: live KV. The value is not measured on the chip end to end and no
+    #: cell is on the other side (ROADMAP D5); the kernels alone: PERF.md §7.
     INPLACE_CTX = 768
 
     @property

@@ -227,13 +227,6 @@ class ModelConfig:
     # :attr:`num_held_experts` (all, unless ``expert_shares`` > 1).
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # Opt-in capacity-bounded expert dispatch for MoE prefill (ops/moe.py):
-    # tokens past an expert's capacity (N·k/E · this factor) are dropped,
-    # which also makes chunked prefill depend on chunk boundaries. None
-    # (default) keeps the exact paths: the dropless grouped dispatch where
-    # a dispatch fills the experts' row tiles, the live path where its
-    # tokens fit one tile (decode), dense-combine elsewhere.
-    moe_capacity_factor: Optional[float] = None
     # Width of one routed expert (``moe_intermediate_size``); None =
     # ``intermediate_size`` (Mixtral: every MLP of the model is an expert).
     moe_intermediate_size: Optional[int] = None
@@ -824,11 +817,10 @@ class EngineConfig:
     # full max_seq_len buffer (one executable per bucket; big bandwidth win
     # early in long-context serving). None = auto ladder; () disables.
     decode_windows: Optional[Tuple[int, ...]] = None
-    # None (default) = auto: ON for the int8 DENSE cache on a real TPU
-    # backend (its fused Pallas decode kernel is the best-known path — +40%
-    # through the engine at the headline config); OFF elsewhere — the paged
-    # variant wins at MHA b64 but loses at small-batch GQA, and CPU tests
-    # would crawl through interpret mode.
+    # None (default) = auto (engine/plan.py): ON for the int8 DENSE cache on
+    # a real TPU backend; OFF elsewhere, where CPU tests would crawl through
+    # interpret mode. Which side is faster is not measured on the chip; no
+    # cell on the other side (ROADMAP D5).
     use_pallas_attention: Optional[bool] = None
     # Ragged mixed-phase attention (engine/plan.py + ops/ragged_attention.py):
     # prefill-family dispatches pad to ONE width (prefill_chunk_tokens) so
@@ -861,50 +853,18 @@ class EngineConfig:
     # path composes with the cache/mesh (the headline configuration), else 1
     # (pp meshes and caches without a tail path keep per-token dispatch).
     decode_steps: Optional[int] = None
-    # Prompts longer than this prefill sequence-sharded over the mesh's
-    # ``sp`` ring (engines with mesh_cfg.sp > 1 and a dense cache kind)
-    # instead of chunked single-device prefill. None = the largest prefill
-    # bucket.
-    ring_prefill_threshold: Optional[int] = None
-    # Pipelined decode ticks (dense caches, fused decode, no draft): each
-    # step() dispatches the next K-step tick from a DEVICE-resident token
-    # carry before resolving the previous tick's tokens, so consecutive
-    # device steps chain with no host round trip between them (the fetch
-    # overlaps the next tick's compute). Token streams are identical; events
-    # for a tick arrive one step() later. Budgets are computed conservatively
-    # against the in-flight tick so no rollback is ever needed.
-    pipelined_ticks: bool = True
-    # Overlapped (stall-free) admission, pipelined engines only: when a
-    # decode tick is in flight, admission prefills DISPATCH immediately
-    # (JAX dispatch is async — the prefill program executes on-device
-    # right behind the running tick) but the host defers the sampled
-    # first-token fetch to the next tick boundary, where it rides the
-    # tick-resolve ``device_get``. The tick boundary applies only slot /
-    # page bookkeeping — no tick ever blocks on prefill completion. The
-    # device programs and RNG sequence are IDENTICAL to the synchronous
-    # path (only the fetch timing moves), so token streams are byte-exact
-    # with the flag on or off. Opt-out flag; ignored on engines that are
-    # not pipelined (draft models, sink bf16, K=1) or that serve sharded
-    # (mesh engines keep the synchronous single-writer flow).
-    overlap_admission: bool = True
-    # Back-pressure for overlapped admission: at most this many deferred
-    # prefill programs may be in flight at once; an admission flood past
-    # the cap spills to the existing synchronous path (bounded device
-    # queue instead of unbounded queued prefill work).
-    overlap_admission_max_inflight: int = 4
     # speculative decoding
     speculative_k: int = 0  # 0 = disabled
-    # Adaptive speculation (pipelined spec engines): when the MEASURED
-    # tokens-per-round EMA sags below ``speculative_probe_below`` (None =
-    # auto, 0.55*(k+1)), the engine probes the plain fused-decode path for
-    # ``speculative_probe_len`` ticks and serves whichever path measured
-    # faster, re-probing every ``speculative_probe_period`` ticks. Rows'
-    # token streams are identical either way (both are greedy argmax);
+    # Adaptive speculation (every engine with a draft model): when the
+    # MEASURED tokens-per-round EMA sags below ``speculative_probe_below``
+    # (None = auto, 0.55*(k+1)), the engine probes the plain fused-decode
+    # path for ``speculative_probe_len`` ticks and serves whichever path
+    # measured faster, re-probing every ``speculative_probe_period`` ticks.
+    # Rows' token streams are identical either way (both are greedy argmax);
     # switching back re-syncs the draft cache (one chunked draft prefill
     # per speculative session). Addresses low-acceptance regimes where a
     # round's k draft forwards + verify cost more than the tokens they
     # yield.
-    speculative_adaptive: bool = True
     speculative_probe_below: Optional[float] = None
     speculative_probe_period: int = 48
     speculative_probe_len: int = 8
@@ -917,17 +877,6 @@ class EngineConfig:
     # budget divided by k+1 proposals per round (>=1); 1 recovers
     # per-round dispatch.
     speculative_rounds: Optional[int] = None
-    # W8A8 prefill-activation quantization pins (ops/quant.py's
-    # ACT_QUANT_PREFILL / ACT_QUANT_MIN_SEQ dispatch flags). None = keep the
-    # library defaults (ON past 128 positions on TPU); False / an int pin
-    # the policy for this deployment — act_quant_prefill=False serves
-    # bit-exact weight-only int8 prefill numerics. Applied to the
-    # process-wide flags at engine construction (jit traces capture them at
-    # trace time), so in a multi-engine process the last-constructed engine
-    # wins — one engine per serving process is the deployment shape this
-    # pins.
-    act_quant_prefill: Optional[bool] = None
-    act_quant_min_seq: Optional[int] = None
     # quantization="int8_outlier": fp input channels carried beside the int8
     # body per projection (LLM.int8()-inspired decomposition), and optional
     # calibration activation absmax per weight name ({"wq": [..., in], ...})

@@ -71,7 +71,6 @@ class ServingNode:
         kv_quant=None,
         cache_cfg=None,
         mesh_cfg=None,
-        pool_max_batch: Optional[int] = None,
         epoch: int = 1,
     ):
         self.node_id = node_id or f"node-{uuid.uuid4().hex[:8]}"
@@ -126,11 +125,8 @@ class ServingNode:
             self._directory.close()
             raise
         try:
-            # ``pool_max_batch`` exists for A/B measurement (bench.py's
-            # distributed phase): 1 disables co-batching so the batching
-            # win is quantifiable; serving keeps the default.
             self._pool = TaskPool(
-                self._process_batch, max_batch=pool_max_batch or max_sessions,
+                self._process_batch, max_batch=max_sessions,
                 window_s=batch_window_s, signature=lambda item: item[0],
                 name=f"{self.node_id}.pool", metrics=self.metrics,
             )

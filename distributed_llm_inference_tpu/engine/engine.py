@@ -89,6 +89,11 @@ IDLE_SHRINK_S = 30.0
 # Rows of one batched admission dispatch (``_prefill_group``).
 GROUP_ROWS = 8
 
+# Deferred prefill programs in flight at once under overlapped admission
+# (``_overlap_ok``): a flood past it spills to the synchronous path, so the
+# device's queue of prefill work stays bounded. Not swept on the chip.
+OVERLAP_MAX_INFLIGHT = 4
+
 
 def window_pool_pages(window: int, page_size: int, batch: int,
                       chunk_tokens: int, decode_tokens: int) -> int:
@@ -142,20 +147,6 @@ class InferenceEngine:
         self.cfg = cfg
         self._mesh_cfg = mesh_cfg
         self.ecfg = engine_cfg or EngineConfig()
-        if (
-            self.ecfg.act_quant_prefill is not None
-            or self.ecfg.act_quant_min_seq is not None
-        ):
-            # Pin the W8A8 prefill-activation policy for this deployment
-            # (the flags live at module scope because jitted matmuls capture
-            # them at trace time; EngineConfig is the supported way to set
-            # them — see config.py).
-            from ..ops import quant as _quant
-
-            if self.ecfg.act_quant_prefill is not None:
-                _quant.ACT_QUANT_PREFILL = self.ecfg.act_quant_prefill
-            if self.ecfg.act_quant_min_seq is not None:
-                _quant.ACT_QUANT_MIN_SEQ = self.ecfg.act_quant_min_seq
         if self.ecfg.quantization in ("int8", "int4", "int8_outlier"):
             from ..ops.quant import quantize_params
 
@@ -240,12 +231,11 @@ class InferenceEngine:
         b, cc = self.batch, self.ccfg
         # Dispatch-shape and kernel policy is owned by the AttentionPlan
         # (engine/plan.py): it resolves use_pallas_attention's auto rule
-        # (unchanged: ON for the int8 DENSE cache on a real TPU, where the
-        # fused kernel measured +40% through the engine; the paged pool's
-        # gathered variant WINS at MHA batch 64 but LOSES at small-batch
-        # GQA, so paged DECODE keeps the XLA two-segment path), routes
-        # paged multi-token rows through the ragged mixed-phase kernel on
-        # TPU, and owns every prefill-family pad width below.
+        # (ON for the int8 DENSE cache on a real TPU; neither side of the
+        # rule is measured on the chip and no cell is on the other side,
+        # ROADMAP D5), routes paged multi-token rows through the ragged
+        # mixed-phase kernel on TPU, and owns every prefill-family pad
+        # width below.
         self.plan = AttentionPlan(self.ecfg, self.ccfg, metrics=self.metrics)
         if self.flight is not None:
             self.plan.dispatches = []  # a tick's worth; step() takes it
@@ -517,8 +507,7 @@ class InferenceEngine:
                 dtype.itemsize, pool_itemsize,
             )
             # Stored KV footprint per token across all layers — the number
-            # the latent cache exists to shrink (bench.py --phase kvbytes
-            # reads it back for the latent-vs-baseline comparison).
+            # the latent cache exists to shrink.
             self.metrics.gauge(
                 "kv_bytes_per_token",
                 float(sum(
@@ -877,7 +866,8 @@ class InferenceEngine:
         # ``_admit_pend`` charges one conservative in-flight token per row
         # (mirroring the pipelined budget discipline). Device programs and
         # RNG order are identical to the synchronous path — token streams
-        # are byte-exact with ``overlap_admission`` on or off.
+        # are byte-exact with an engine that never overlaps (``_overlap_ok``
+        # says when this one does).
         self._inflight_admits: List[Tuple[List[Session], jax.Array, List[int]]] = []
         self._admit_pend = np.zeros(self.batch, np.int32)
         # Events produced OUTSIDE step() (admit_prefilled's synchronous
@@ -890,12 +880,7 @@ class InferenceEngine:
         # Any tail-capable cache pipelines (dense kinds and the paged pools'
         # fused windows); the sink ring (no tail) and draft-model engines
         # keep the synchronous flow.
-        self._pipelined = (
-            self.ecfg.pipelined_ticks
-            and K > 1
-            and tail_capable
-            and draft is None
-        )
+        self._pipelined = K > 1 and tail_capable and draft is None
 
         def _carry_combine(fresh, carry, use_carry):
             return jnp.where(use_carry[:, None], carry, fresh)
@@ -940,7 +925,7 @@ class InferenceEngine:
                 jax.jit(_ring_prefill_row, **dk)
             )
 
-        # -- speculative decoding (draft model; BASELINE config 5) ------------
+        # -- speculative decoding (draft model) ----------------------------------
         self.draft = None
         self.spec_stats = {"proposed": 0, "accepted": 0, "steps": 0}
         if draft is not None:
@@ -1494,9 +1479,10 @@ class InferenceEngine:
         signals a finish without a new token (capacity rejection/exhaustion) —
         streaming consumers must not append it.
 
-        Pipelined engines (``EngineConfig.pipelined_ticks``) dispatch the
-        next device tick BEFORE resolving the previous one, so a tick's
-        tokens arrive one ``step()`` later than they were dispatched."""
+        Pipelined engines (a fused decode scan over a tail-capable cache,
+        no draft model) dispatch the next device tick BEFORE resolving the
+        previous one, so a tick's tokens arrive one ``step()`` later than
+        they were dispatched."""
         # Flight recorder: host-clock only (no device_get, no
         # block_until_ready of its own), and None unless a TraceConfig
         # enabled it, so the disabled tick pays one attribute load + branch.
@@ -2805,7 +2791,7 @@ class InferenceEngine:
         for s, skip in admitted:
             ring = (
                 self._ring_prefill is not None
-                and len(s.prompt) > self._ring_threshold()
+                and len(s.prompt) > self.ecfg.prefill_buckets[-1]
             )
             if (
                 self._batch_admission
@@ -2848,16 +2834,12 @@ class InferenceEngine:
         flood spills to the synchronous path instead of queueing unbounded
         prefill work on the device)."""
         if not (
-            self.ecfg.overlap_admission
-            and self._pipelined
+            self._pipelined
             and self._pending is not None
             and self.mesh is None
         ):
             return False
-        if (
-            len(self._inflight_admits)
-            >= max(1, self.ecfg.overlap_admission_max_inflight)
-        ):
+        if len(self._inflight_admits) >= OVERLAP_MAX_INFLIGHT:
             self.metrics.counter("admit_overlap_spill")
             return False
         return True
@@ -2989,10 +2971,6 @@ class InferenceEngine:
         # missing merge_rows the day select_rows appears).
         return None
 
-    def _ring_threshold(self) -> int:
-        thr = self.ecfg.ring_prefill_threshold
-        return thr if thr is not None else self.ecfg.prefill_buckets[-1]
-
     def _ring_bucket(self, n: int) -> int:
         """Padded ring length for an ``n``-token prompt: the doubling ladder
         above the largest prefill bucket, capped at ``max_seq_len`` (the
@@ -3034,7 +3012,7 @@ class InferenceEngine:
         if (
             self._ring_prefill is not None
             and skip == 0
-            and len(prompt) > self._ring_threshold()
+            and len(prompt) > self.ecfg.prefill_buckets[-1]
         ):
             bucket = self._ring_bucket(len(prompt))
             padded = np.zeros((1, bucket), np.int32)
@@ -3111,7 +3089,7 @@ class InferenceEngine:
         if (
             self._ring_prefill is not None
             and skip == 0
-            and len(s.prompt) > self._ring_threshold()
+            and len(s.prompt) > self.ecfg.prefill_buckets[-1]
         ):
             return False
         if self.ccfg.prefix_caching and self.pcfg.prefix_share:
@@ -3316,7 +3294,7 @@ class InferenceEngine:
         break-even band it probes the plain fused path, serves whichever
         measured faster, and re-probes speculation every ``probe_period``
         ticks. Token streams are bit-identical in both modes."""
-        if self.draft is None or not self.ecfg.speculative_adaptive:
+        if self.draft is None:
             return
         c = self._spec_ctl
         nspec = sum(
@@ -3608,9 +3586,7 @@ class InferenceEngine:
             g is not None and self._session_speculative(self.sessions[g])
             for g in self.slots
         ):
-            if self.ecfg.pipelined_ticks:
-                return self._speculative_rounds_tick(produced)
-            return self._speculative_tick(produced)
+            return self._speculative_rounds_tick(produced)
         if self.draft is not None and self._spec_pending is not None:
             # Last speculative session retired with a tick in flight.
             self._spec_flush(produced)

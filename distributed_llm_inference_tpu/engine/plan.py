@@ -6,8 +6,7 @@ paths padded to them, ``_install_bucket``/``_flush_installs`` kept their own
 pad set for page-table scatters, and ``__init__`` resolved which attention
 kernel each cache kind got. Every consumer compiled its own executable per
 shape, so mixed-length traffic paid one recompile per (bucket, row-count)
-pair — the "bucket tax" BENCH_r05 measured at 23–28% of nominal prefill
-TFLOP/s.
+pair (the "bucket tax").
 
 The plan centralizes that policy:
 
@@ -335,9 +334,8 @@ class AttentionPlan:
         rows) times its steps, each step a dispatch of ``shape[0]`` x 1
         tokens to the experts. The path ``ops/moe.py:dispatch_path`` takes
         at the dispatch's shape counts it under ``moe_dispatch_grouped``,
-        ``moe_dispatch_live``, ``moe_dispatch_dense`` or
-        ``moe_dispatch_capacity``. A decode dispatch also adds, for ONE
-        routed layer, the experts held times its steps to
+        ``moe_dispatch_live`` or ``moe_dispatch_dense``. A decode dispatch
+        also adds, for ONE routed layer, the experts held times its steps to
         ``moe_decode_experts_held`` and those a step reads to
         ``moe_decode_experts_live``: every held one under dense-combine,
         under the live path the ones its ``active_rows`` are EXPECTED to

@@ -1,7 +1,6 @@
 """Speculative decoding: draft-model proposals verified by the target model.
 
-Capability for BASELINE config 5 ("Llama-3-70B hybrid TPxPP, speculative
-decoding") — absent from the reference, which decodes strictly one token per
+Absent from the reference, which decodes strictly one token per
 step (``/root/reference/distributed_llm_inference/models/llama/modules.py:73``
 gates its whole fast path on ``q_len == 1``).
 

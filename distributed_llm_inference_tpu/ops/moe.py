@@ -9,7 +9,7 @@ a capability extension required for the Mixtral and DeepSeek-V2/V3 families.
 Routing is ONE function (:func:`route`) whose rule the config chooses:
 Mixtral's (softmax over ALL expert logits in fp32, top-k, renormalise) and
 DeepSeek-V3's (sigmoid scores, selection by score plus a per-expert bias,
-weights from the scores alone, normalised and scaled). Four
+weights from the scores alone, normalised and scaled). Three
 compute strategies sit behind it, all-static shapes; :func:`dispatch_path`
 picks one from the dispatch's shape, and ``moe_mlp`` has no option for it:
 
@@ -50,9 +50,6 @@ picks one from the dispatch's shape, and ``moe_mlp`` has no option for it:
   weights the outputs. With experts sharded over ``ep`` the combine
   contraction becomes a ``psum`` XLA inserts automatically, where the
   kernel's expert-indexed reads and the grouped path's gathers trip GSPMD.
-* **capacity dispatch** (opt-in, ``ModelConfig.moe_capacity_factor``) —
-  the sorted dispatch with a capacity-bounded ``[E, C, H]`` einsum
-  (``moe_mlp_dispatch``): it drops the pairs past an expert's capacity.
 
 Shared experts (``p["ws_g"]``/``ws_u``/``ws_d``, present where the config
 has them) are one SwiGLU MLP every token passes through, added to the routed
@@ -73,7 +70,6 @@ Nothing here stands in for the absent experts or for an exchange.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, NamedTuple
 
 import jax
@@ -204,8 +200,8 @@ def dispatch_path(
     cfg: ModelConfig, rows: int, seq_len: int, sharded: bool = False
 ) -> str:
     """The compute strategy of a dispatch of ``rows x seq_len`` tokens:
-    ``"grouped"``, ``"live"``, ``"dense"`` or ``"capacity"`` (module
-    docstring). A static function of the shape and the config.
+    ``"grouped"``, ``"live"`` or ``"dense"`` (module docstring). A static
+    function of the shape, the config and whether a mesh shards the program.
 
     Grouped where the pairs each expert EXPECTS fill a row tile, ``rows x
     seq_len x k / E >= ROW_TILE``: below that most of a tile is padding and
@@ -215,11 +211,8 @@ def dispatch_path(
     ``E > k``). What lies between (a narrow bucket, a wide verify) is
     dense, and so is a ``sharded`` program whatever its shape: the
     kernel's expert-indexed reads and the grouped path's gathers trip
-    GSPMD under ``ep``/``tp``. ``ModelConfig.moe_capacity_factor`` still
-    opts a prefill-scale dispatch in to the capacity form.
+    GSPMD under ``ep``/``tp``.
     """
-    if cfg.moe_capacity_factor is not None and seq_len >= 16:
-        return "capacity"
     tokens = rows * seq_len
     if not sharded:
         if tokens * cfg.num_experts_per_tok >= cfg.num_experts * ROW_TILE:
@@ -257,9 +250,9 @@ def expert_rows_per_token(
     the program runs a PADDED token of a dispatch of ``rows x seq_len``
     tokens of which ``valid_share`` are real, by the path
     :func:`dispatch_path` takes there: every routed HELD expert under
-    dense-combine; its capacity's share under the capacity form; under the
-    grouped dispatch the valid tokens' own picks plus half a row tile an
-    expert, what padding each group to whole tiles costs on average; under
+    dense-combine; under the grouped dispatch the valid tokens' own picks
+    plus half a row tile an expert, what padding each group to whole tiles
+    costs on average; under
     the live path the experts the dispatch's valid tokens are expected to
     pick between them (:func:`expected_live_experts`: every row of the
     one tile passes through each of them). The
@@ -274,8 +267,6 @@ def expert_rows_per_token(
     if cfg.expert_shares > 1:
         k = k * held / cfg.num_experts
     path = dispatch_path(cfg, rows, seq_len, sharded)
-    if path == "capacity":
-        return k + shared, k * cfg.moe_capacity_factor + shared
     if path == "grouped":
         tile_pad = held * ROW_TILE / 2 / (rows * seq_len)
         return k + shared, k * valid_share + tile_pad + shared
@@ -329,17 +320,13 @@ def moe_mlp(
     valid token picked, whose weights are the only ones read, at the
     grouped kernel's precision. What lies between and sharded programs
     keep dense-combine, the latter bit for bit as before.
-    ``ModelConfig.moe_capacity_factor`` still OPTS IN to the capacity form
-    (S >= 16), whose drops make results depend on chunk boundaries.
     ``valid`` (``[B, S]`` bool) marks real tokens: bucket padding and a
-    decode step's dead rows take no row of an expert in either sorted
+    decode step's dead rows take no row of an expert in the grouped
     path and make no expert live; dense-combine computes them like any
     other (their results are read by nobody).
     """
     path = traced_path(cfg, x)
-    if path == "capacity":
-        out = moe_mlp_dispatch(cfg, p, x, cfg.moe_capacity_factor, valid)
-    elif path == "grouped":
+    if path == "grouped":
         out = moe_mlp_grouped(cfg, p, x, valid)
     elif path == "live":
         out = moe_mlp_live(cfg, p, x, valid)
@@ -716,94 +703,4 @@ def moe_mlp_live(
             written[:, None, None], y.reshape(e, tile, h)[:, :n], 0
         ).astype(jnp.float32)
         out = jnp.einsum("ne,enh->nh", combine.reshape(n, e)[:, order], y)
-        return out.reshape(b, s, h).astype(x.dtype)
-
-
-def _expert_matmul(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
-    """Per-expert einsum that handles quantized expert stacks. The generic
-    ``quant.einsum`` needs the weight's non-contracted axes LAST in the
-    output; here the expert axis leads (``ecf``/``ech``), so the
-    per-(expert, out-channel) scale ``[E, out]`` broadcasts at axis -1 with
-    the capacity axis in between."""
-    if isinstance(w, quant.QuantizedTensor):
-        y = jnp.einsum(spec, x, w.q.astype(x.dtype))
-        return y * w.scale[:, None, :].astype(x.dtype)
-    return jnp.einsum(spec, x, w)
-
-
-def moe_mlp_dispatch(
-    cfg: ModelConfig,
-    p,
-    x: jnp.ndarray,
-    capacity_factor: float = 2.0,
-    valid=None,
-    capacity=None,
-) -> jnp.ndarray:
-    """Sorted, capacity-bounded expert dispatch — opt-in
-    (``ModelConfig.moe_capacity_factor``); the default prefill path is the
-    dropless :func:`moe_mlp_grouped`.
-
-    Gather-only by construction (a scatter lowers to a serial row loop on
-    TPU and trips GSPMD — see cache/dense.py): (token, expert) pairs are
-    argsorted by expert, each expert's slots gather their tokens, the
-    per-expert MLP runs on ``[E, C, H]``, and undoing the sort turns the
-    combine into a dense ``[N, k]`` weighted sum. ``C = N·k/E ·
-    capacity_factor`` (E the router's width: the pairs an expert expects)
-    rounds to a static shape; pairs past an expert's
-    capacity are dropped (their routing weight contributes nothing) — rare
-    at factor 2 under Mixtral's near-uniform routing, and bounded: a dropped
-    pair loses at most its renormalized probability share of one token.
-
-    Bucket-padding tokens (``valid`` false) and picks of another share are
-    parked on a sentinel expert id past every real expert's group
-    (:func:`_routed_pairs`), so padding can never evict a real token from
-    capacity.
-
-    NOTE: under an ``ep``-sharded mesh the expert-indexed gathers here have
-    not been perf-verified (GSPMD may all-gather the expert stacks); the
-    dense-combine path is the ep-proven one.
-    """
-    b, s, h = x.shape
-    e, k = cfg.num_held_experts, cfg.num_experts_per_tok
-    n = b * s
-    xf = x.reshape(n, h)
-    top_p, pair_e = _routed_pairs(cfg, p, xf, valid)
-    pair_t = jnp.repeat(jnp.arange(n, dtype=jnp.int32), k)      # [N*k]
-
-    order = jnp.argsort(pair_e, stable=True)
-    sorted_e = pair_e[order]
-    sorted_t = pair_t[order]
-    # e+1 bounds so sentinel (padding) pairs sit past EVERY group_end.
-    bounds = jnp.searchsorted(sorted_e, jnp.arange(e + 1), side="left")
-    group_start, group_end = bounds[:e], bounds[1:]
-    pos_in_group = jnp.arange(n * k, dtype=jnp.int32) - group_start[
-        jnp.clip(sorted_e, 0, e - 1)
-    ]
-    c = capacity if capacity is not None else max(
-        1, min(n, math.ceil((n * k) / cfg.num_experts * capacity_factor))
-    )
-    # Slot (expert, c) holds the token at sorted position start_e + c.
-    slot_pos = group_start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-    slot_valid = slot_pos < group_end[:, None]
-    slot_tok = sorted_t[jnp.clip(slot_pos, 0, n * k - 1)]       # [E, C]
-
-    gathered = xf[slot_tok] * slot_valid[..., None].astype(x.dtype)
-    with jax.named_scope("moe_experts"):
-        t = _expert_matmul("ech,ehf->ecf", gathered, p["we_g"])
-        u = _expert_matmul("ech,ehf->ecf", gathered, p["we_u"])
-        y = _expert_matmul("ecf,efh->ech", jax.nn.silu(t) * u, p["we_d"])
-
-    # Back to pair order (pure gathers: undo the sort), then a dense [N, k]
-    # weighted combine.
-    with jax.named_scope("moe_combine"):
-        kept = pos_in_group < c
-        pair_out_sorted = y[
-            sorted_e, jnp.clip(pos_in_group, 0, c - 1)
-        ] * kept[:, None].astype(x.dtype)                       # [N*k, H]
-        inv = jnp.argsort(order)
-        pair_out = pair_out_sorted[inv].reshape(n, k, h)
-        out = jnp.einsum(
-            "nk,nkh->nh", top_p.astype(jnp.float32),
-            pair_out.astype(jnp.float32),
-        )
         return out.reshape(b, s, h).astype(x.dtype)

@@ -359,11 +359,10 @@ def quantize_int4(
 # int8 x int8 on the MXU with dynamic per-token activation scales (AQT
 # style) instead of dequantizing the weight into a bf16 matmul: the int8
 # systolic path has 2x the bf16 peak on v5e, and at prefill row counts the
-# per-token abs-max/round VPU work amortizes. Measured 8B-shape prefill
-# device time (r5, b1): S=512 213 → 93 ms, S=2048 1109 → 743 ms, S=128
-# 42.8 → 39.4 ms. Decode (S == 1) and short verifies keep the weight-only
-# path: they are HBM-bound, and W8A8 would change their numerics for no
-# throughput.
+# per-token abs-max/round VPU work amortizes. The threshold is not
+# measured on the chip and no cell is on the other side (ROADMAP D5).
+# Decode (S == 1) and short verifies keep the weight-only path: they are
+# HBM-bound, and W8A8 would change their numerics for no throughput.
 ACT_QUANT_PREFILL = True
 ACT_QUANT_MIN_SEQ = 128
 

@@ -4,8 +4,7 @@
 prefill still route through ``cache/paged.py:update_and_gather`` — a full
 contiguous ``[B, max_len, Hkv, D]`` copy of every row's pages per layer —
 and through per-bucket padded dispatches (``engine/engine.py:_bucket_for``),
-whose one-executable-per-bucket tax BENCH_r05 measured at 23–28% of nominal
-prefill TFLOP/s and a 4258→479 tok/s decode collapse from 128 to 2k context.
+one executable a bucket.
 
 This kernel serves rows with PER-ROW true lengths in ONE grid call:
 

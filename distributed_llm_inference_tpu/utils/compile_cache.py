@@ -3,8 +3,8 @@
 A cold process compiles every executable of the serving path (73 s of a
 108 s chip smoke at 7B widths — my chip run, PR 21); the cache's directory
 is part of its key, so it only ever hits when every run names the same
-path. One rule, called by every entry point
-(``cli.main``, ``bench.py``, ``chip_smoke.py``) before the first trace:
+path. One rule, called by every entry point (``cli.main``,
+``benchmark/server.py``, ``chip_smoke.py``) before the first trace:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — whoever runs the program placed the
   cache; JAX reads the variable itself and no directory is set in code.

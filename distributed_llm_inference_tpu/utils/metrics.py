@@ -2,9 +2,8 @@
 
 The reference's only observability is two ``print`` statements in its weight
 loader (``/root/reference/distributed_llm_inference/utils/model.py:61,82``;
-SURVEY §5.5). Here: counters + latency histograms good enough to derive the
-BASELINE metrics (tokens/sec/chip, p50 TTFT, batch occupancy) plus structured
-logging hooks.
+SURVEY §5.5). Here: counters + latency histograms good enough to derive
+tokens/sec/chip, p50 TTFT and batch occupancy, plus structured logging hooks.
 """
 
 from __future__ import annotations
@@ -87,7 +86,7 @@ METRICS = {
     ),
     # dispatches of a routed model by the path its experts took
     # (ops/moe.py:dispatch_path, from the dispatch's shape): grouped,
-    # live, dense or capacity
+    # live or dense
     "moe_dispatch_*": (
         "counter", "Dispatches by the experts' compute path at their shape"
     ),

@@ -4,9 +4,8 @@ time went and what the host was doing while the device waited.
 The reference has no profiling story at all (SURVEY §5.1 — its only
 observability is two ``print`` calls in the weight loader,
 ``/root/reference/distributed_llm_inference/utils/model.py:61,82``); here the
-profiler is a first-class tool: ``tools/xplane_profile.py`` and
-``tools/profile_decode.py`` print :func:`aggregate`'s result, and ``bench.py``
-uses :func:`device_time_ps` for the device-only component of TTFT.
+profiler is a first-class tool: ``tools/xplane_profile.py`` prints
+:func:`aggregate`'s result.
 
 The trace is read with ``jax.profiler.ProfileData`` into plain data, so the
 arithmetic below is checked on made-up planes as well as on a trace. What it
@@ -32,7 +31,7 @@ tick's record, epoch nanoseconds) and the trace are joined by
 are one instant on the two clocks, and device 0 runs the noted dispatches in
 the order the engine enqueued them.
 
-Times are nanoseconds; :func:`device_time_ps` alone speaks picoseconds.
+Times are nanoseconds.
 """
 
 from __future__ import annotations
@@ -377,14 +376,6 @@ def find_xplane(trace_dir: str) -> str:
     if not hits:
         raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
     return max(hits, key=os.path.getmtime)
-
-
-def device_time_ps(trace_dir: str) -> int:
-    """Busy time of device 0 (the union of its operations' intervals, in
-    picoseconds) recorded in a trace directory; 0 where the trace has no
-    device plane (a CPU run)."""
-    devices = aggregate(find_xplane(trace_dir))["devices"]
-    return devices[0]["busy_ns"] * 1000 if devices else 0
 
 
 def describe(path: str, per_line: int = 3, like: Sequence[str] = ()) -> List[str]:

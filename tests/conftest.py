@@ -39,22 +39,6 @@ def pytest_sessionstart(session):
 import pytest  # noqa: E402
 
 
-def pytest_collection_modifyitems(config, items):
-    """Schedule the disagg e2e suite after everything else.
-
-    The tier-1 smoke pass (tools/tier1.sh) runs under a hard 870 s
-    timeout and consumes the suite in collection order, so a new
-    mid-alphabet module would displace long-standing coverage past the
-    cut-off. Moving the `disagg`-marked items (KV-shipping e2e, the
-    slowest new block) to the tail keeps the historical prefix intact;
-    uncapped runs still cover the whole suite. Items move as one
-    contiguous block so module-scoped fixtures instantiate once."""
-    tail = [it for it in items if it.get_closest_marker("disagg")]
-    if tail:
-        head = [it for it in items if not it.get_closest_marker("disagg")]
-        items[:] = head + tail
-
-
 @pytest.fixture(autouse=True, scope="module")
 def _clear_jax_caches_between_modules():
     """Cap per-process compiler/executable state growth: with r4's test

@@ -591,10 +591,12 @@ def test_chunked_admission_pipelined_parity(overlap):
     mix = [prompts(3, seed=2)[0], rng.integers(0, 128, size=30).tolist(),
            prompts(3, seed=2)[2]]
     opts = SamplingOptions(max_new_tokens=6)
-    kw = dict(pipelined_ticks=True, overlap_admission=overlap)
-    base = make_engine(ragged=False, **kw).generate(mix, opts)
-    eng = make_engine(ragged=True, chunk=8, **kw)
-    assert eng.generate(mix, opts) == base
+    base, eng = make_engine(ragged=False), make_engine(ragged=True, chunk=8)
+    assert base._pipelined and eng._pipelined
+    if not overlap:
+        # both held to the synchronous admission path
+        base._overlap_ok = eng._overlap_ok = lambda: False
+    assert eng.generate(mix, opts) == base.generate(mix, opts)
     assert eng.metrics.get_counter("attn_chunked_rows") > 0
 
 

@@ -3,8 +3,8 @@
 SURVEY §4 test strategy items (c)+(d): a tiny random-weight model served
 through the full node stack — directory, lease heartbeats, 2-node pipeline of
 block workers, client-side embed/head — compared against a single-process
-oracle. Covers BASELINE config 2's shape ("2-stage pipeline split across 2
-server nodes") at test scale.
+oracle. Covers CONFIGS.md's config 2 (a 2-stage pipeline split across 2
+server nodes) at test scale.
 """
 
 import time

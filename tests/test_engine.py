@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import distributed_llm_inference_tpu.engine.engine as engine_mod
 from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig, ModelConfig
 from distributed_llm_inference_tpu.engine.engine import (
     IDLE_SHRINK_S,
@@ -341,7 +342,7 @@ def test_engine_ring_prefill_composes_with_tp():
 
 
 def test_engine_tp_pp_dp_continuous_batching_matches_solo():
-    """BASELINE config 5's serving shape: a tp=2 x pp=2 x dp=2 mesh under
+    """CONFIGS.md config 5's serving shape: a tp=2 x pp=2 x dp=2 mesh under
     the UNCHANGED continuous-batching scheduler reproduces solo tokens."""
     from distributed_llm_inference_tpu.config import MeshConfig
 
@@ -436,14 +437,14 @@ def test_decode_windows_do_not_change_tokens():
 
 
 def test_cache_growth_and_idle_shrink():
-    # pipelined_ticks=False: this test inspects max_len between generates,
-    # and the pipelined flow's trailing admit shrinks the idle cache before
-    # generate() returns (growth itself is covered by the counter assert and
-    # by test_pipelined_growth_ladder below).
+    # decode_steps=1 (the synchronous engine): this test inspects max_len
+    # between generates, and the pipelined flow's trailing admit shrinks the
+    # idle cache before generate() returns (growth itself is covered by the
+    # counter assert and by test_pipelined_growth_ladder below).
     eng = InferenceEngine(
         CFG, PARAMS,
         EngineConfig(max_batch_size=2, prefill_buckets=(8, 32), max_seq_len=64,
-                     dtype="float32", pipelined_ticks=False),
+                     dtype="float32", decode_steps=1),
         CacheConfig(kind="dense"),
     )
     first_bucket = eng._windows[0]
@@ -638,7 +639,7 @@ def test_pipelined_growth_ladder():
     mk = lambda pipelined: InferenceEngine(
         CFG, PARAMS,
         EngineConfig(max_batch_size=2, prefill_buckets=(8, 32), max_seq_len=64,
-                     dtype="float32", pipelined_ticks=pipelined),
+                     dtype="float32", decode_steps=None if pipelined else 1),
         CacheConfig(kind="dense"),
     )
     long_prompt = prompts(1, lo=30, hi=31, seed=50)[0]
@@ -658,7 +659,7 @@ def test_pipelined_matches_sync_mixed_sessions():
     mk = lambda pipelined: InferenceEngine(
         CFG, PARAMS,
         EngineConfig(max_batch_size=3, prefill_buckets=(8, 16), max_seq_len=32,
-                     dtype="float32", pipelined_ticks=pipelined),
+                     dtype="float32", decode_steps=None if pipelined else 1),
         CacheConfig(kind="dense"),
     )
     assert mk(True).generate(ps, opts) == mk(False).generate(ps, opts)
@@ -670,22 +671,37 @@ def test_pipelined_matches_sync_mixed_sessions():
     )
 
 
-def test_pipelined_paged_matches_sync():
-    """Paged engines pipeline too (conservative page growth against the
-    in-flight tick): token-exact vs the synchronous flow, pages reclaimed."""
+@pytest.mark.parametrize("decode_steps", [None, 1], ids=["defaults", "one_token"])
+def test_pipelined_paged_matches_sync(decode_steps):
+    """A paged engine built with the defaults pipelines (conservative page
+    growth against the in-flight tick) and overlaps an admission that lands
+    behind a tick in flight; at ``decode_steps=1`` it does neither. Both are
+    token-exact vs the same engine held to the synchronous flow (an int8
+    pool's tail makes a 16-step tick's numbers its own, so the one-token
+    engine is no reference here), pages reclaimed."""
     ps = prompts(6, lo=3, hi=12, seed=41)
-    opts = SamplingOptions(max_new_tokens=11)
-    mk = lambda pipelined: InferenceEngine(
+    opts = SamplingOptions(max_new_tokens=24)  # past one 16-step tick
+    mk = lambda steps: InferenceEngine(
         CFG, PARAMS,
         EngineConfig(max_batch_size=3, prefill_buckets=(8, 16), max_seq_len=48,
-                     dtype="float32", pipelined_ticks=pipelined),
+                     dtype="float32", decode_steps=steps),
         CacheConfig(kind="paged", kv_quant="int8", page_size=8, num_pages=64,
                     max_pages_per_session=6),
     )
-    ref = mk(False).generate(ps, opts)
-    eng = mk(True)
-    assert eng._pipelined
-    assert eng.generate(ps, opts) == ref
+    sync = mk(decode_steps)
+    sync._pipelined = False  # each tick resolved before the next dispatch
+    ref = sync.generate(ps, opts)
+    eng, pipelined = mk(decode_steps), decode_steps is None
+    gids = [eng.submit(ps[0], opts)]
+    eng.step()  # admitted synchronously: no tick in flight yet
+    eng.step()  # a pipelined engine's first tick is now in flight
+    assert eng._pipelined is pipelined and eng._overlap_ok() is pipelined
+    gids += [eng.submit(p, opts) for p in ps[1:]]
+    while eng.has_work():
+        eng.step()
+    assert [eng.sessions[g].generated for g in gids] == ref
+    overlapped = eng.metrics.snapshot().get("admit_overlap_sessions", 0)
+    assert (overlapped > 0) is pipelined
     assert eng.allocator.free_count == 63  # all pages back (minus null page)
 
 
@@ -771,7 +787,7 @@ def test_batched_admission_padded_group_preserves_every_row():
     (dict(pp=2), "int8"),
 ])
 def test_engine_pp_paged_matches_solo(mesh_kw, kv_quant):
-    """BASELINE configs 4+5 composed (VERDICT r4 ask 9): the vLLM-style
+    """CONFIGS.md's configs 4+5 composed (VERDICT r4 ask 9): the vLLM-style
     paged pool serves under a pipeline-parallel mesh. The pool's layer axis
     leads every array, so each pp stage holds its own layers' pages
     (pipeline SHARED_FIELDS pass-through); page installs ride the chunked
@@ -843,11 +859,15 @@ def _overlap_engine(kind, overlap, rng_seed=7, batch=3, **ekw):
     # default 16-step tick, these tiny max_new budgets fit in ONE tick
     # and every admission would (correctly) fall back to sync.
     ekw.setdefault("decode_steps", 4)
-    return InferenceEngine(
-        CFG, PARAMS,
-        EngineConfig(dtype="float32", overlap_admission=overlap, **ekw),
+    eng = InferenceEngine(
+        CFG, PARAMS, EngineConfig(dtype="float32", **ekw),
         CacheConfig(**cache_kw), rng=jax.random.PRNGKey(rng_seed),
     )
+    assert eng._pipelined
+    if not overlap:
+        # the same pipelined engine held to the synchronous admission path
+        eng._overlap_ok = lambda: False
+    return eng
 
 
 def _churn_run(kind, overlap, ps, opts, rng_seed=7):
@@ -870,8 +890,8 @@ def _churn_run(kind, overlap, ps, opts, rng_seed=7):
 
 @pytest.mark.parametrize("kind", ["dense", "paged"])
 def test_overlap_admission_parity_greedy(kind):
-    """Byte-exact token parity with overlap_admission on vs off under
-    churn (7 prompts over 3 slots: later admissions land while a
+    """Byte-exact token parity of overlapped against synchronous
+    admission under churn (7 prompts over 3 slots: later admissions land while a
     pipelined tick is in flight and take the deferred-fetch path)."""
     ps = prompts(7, lo=3, hi=14, seed=71)
     opts = SamplingOptions(max_new_tokens=10)
@@ -951,16 +971,16 @@ def test_deadline_during_inflight_prefill():
     assert eng.allocator.free_count == free0
 
 
-def test_overlap_admission_flood_backpressure():
-    """An admission flood past overlap_admission_max_inflight spills to
+def test_overlap_admission_flood_backpressure(monkeypatch):
+    """An admission flood past OVERLAP_MAX_INFLIGHT spills to
     the synchronous path (bounded in-flight device work) and still
     produces byte-exact streams."""
     ps = prompts(9, lo=3, hi=15, seed=90)
     opts = SamplingOptions(max_new_tokens=7)
+    monkeypatch.setattr(engine_mod, "OVERLAP_MAX_INFLIGHT", 1)
 
     def run(overlap):
-        eng = _overlap_engine("dense", overlap, batch=8,
-                              overlap_admission_max_inflight=1)
+        eng = _overlap_engine("dense", overlap, batch=8)
         # One resident session keeps a tick in flight, then the flood of 8
         # arrives in a single admission pass spanning both prompt buckets.
         first = eng.submit(ps[0], opts)

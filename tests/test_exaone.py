@@ -285,10 +285,10 @@ def test_the_shares_parts_and_the_shared_expert_once_are_the_uncut_layer():
             got, np.asarray(reference.moe(hf, part, x.reshape(want.shape))),
             rtol=2e-5, atol=2e-6,
         )
-        # ... and so does the sorted dispatch, with room for every pair
-        sorted_ = moe_ops.moe_mlp_dispatch(cfg, part, x, capacity=x.shape[0] * x.shape[1])
+        # ... and so does dense-combine, the form every mesh program runs
+        dense = moe_ops._dense_combine(cfg, part, x)
         np.testing.assert_allclose(
-            np.asarray(sorted_).reshape(want.shape) + shared, got,
+            np.asarray(dense).reshape(want.shape) + shared, got,
             rtol=2e-5, atol=2e-6,
         )
         total += got - shared

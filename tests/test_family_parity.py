@@ -1,7 +1,7 @@
 """Logits parity of Mistral / Qwen2 / Mixtral against ``transformers``.
 
 Extends the Llama parity suite (``test_llama_parity.py``) across the other
-model families the framework serves (BASELINE config 4 is Mistral): same
+model families the framework serves (CONFIGS.md's config 4 is Mistral): same
 tiny-random-HF-model-as-oracle strategy, exercising each family's quirk —
 sliding-window attention, q/k/v biases + tied embeddings, MoE routing.
 """

@@ -373,7 +373,7 @@ def test_spec_adapt_normalizes_per_spec_row():
         BASE_CFG, _params(BASE_CFG),
         EngineConfig(max_batch_size=4, prefill_buckets=(8,),
                      max_seq_len=64, dtype="float32", speculative_k=2,
-                     speculative_adaptive=True, speculative_probe_len=2),
+                     speculative_probe_len=2),
         CacheConfig(kind="dense"),
         draft=(BASE_CFG, _params(BASE_CFG)),
     )

@@ -163,92 +163,3 @@ def test_validate_ep_rejects_bad_degrees():
     dense = ModelConfig(num_experts=0)
     with pytest.raises(ValueError):
         validate_tp(dense, 1, ep=2)
-
-
-def test_dispatch_equals_dense_combine_at_full_capacity():
-    """With capacity >= every expert's load, sorted dispatch must equal the
-    dense-combine path exactly (no drops)."""
-    from distributed_llm_inference_tpu.ops.moe import moe_mlp_dispatch
-
-    cfg = CFG
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
-    lp = {k: v[0] for k, v in params["layers"].items() if k in
-          ("router", "we_g", "we_u", "we_d")}
-    x = jax.random.normal(jax.random.PRNGKey(3), (2, 9, cfg.hidden_size),
-                          jnp.float32)
-    # Dense-combine reference: force the S==1 formula over the whole seq by
-    # reshaping tokens into the batch axis.
-    xs = x.reshape(-1, 1, cfg.hidden_size)
-    from distributed_llm_inference_tpu.ops.moe import moe_mlp
-    ref = moe_mlp(cfg, lp, xs).reshape(x.shape)
-    out = moe_mlp_dispatch(cfg, lp, x, capacity_factor=float(cfg.num_experts))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_dispatch_default_capacity_close():
-    """Factor-2 capacity: near-uniform routing rarely drops; outputs stay
-    close to the no-drop reference."""
-    from distributed_llm_inference_tpu.ops.moe import moe_mlp_dispatch
-
-    cfg = CFG
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
-    lp = {k: v[0] for k, v in params["layers"].items() if k in
-          ("router", "we_g", "we_u", "we_d")}
-    x = jax.random.normal(jax.random.PRNGKey(4), (2, 16, cfg.hidden_size),
-                          jnp.float32)
-    full = moe_mlp_dispatch(cfg, lp, x, capacity_factor=float(cfg.num_experts))
-    out = moe_mlp_dispatch(cfg, lp, x, capacity_factor=2.0)
-    a, b = np.asarray(full), np.asarray(out)
-    cos = (a * b).sum() / (np.linalg.norm(a) * np.linalg.norm(b) + 1e-9)
-    assert cos > 0.98, cos
-
-
-def test_dispatch_quantized_weights_prefill():
-    """int8-quantized expert stacks run the dispatched prefill path
-    (regression: the expert-axis-leading einsum broke quant.einsum's scale
-    broadcast)."""
-    from distributed_llm_inference_tpu.ops.moe import moe_mlp_dispatch
-    from distributed_llm_inference_tpu.ops.quant import quantize_params
-
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
-    lp = {k: v[0] for k, v in params["layers"].items() if k in
-          ("router", "we_g", "we_u", "we_d")}
-    qp = quantize_params(lp, scale_dtype=jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(9), (2, 16, CFG.hidden_size),
-                          jnp.float32)
-    full_cap = float(CFG.num_experts)
-    ref = moe_mlp_dispatch(CFG, lp, x, capacity_factor=full_cap)
-    out = moe_mlp_dispatch(CFG, qp, x, capacity_factor=full_cap)
-    a, b = np.asarray(ref), np.asarray(out)
-    cos = (a * b).sum() / (np.linalg.norm(a) * np.linalg.norm(b) + 1e-9)
-    assert cos > 0.98, cos
-
-
-def test_dispatch_padding_never_evicts_real_tokens():
-    """Bucket-padding positions route to the sentinel expert: real tokens'
-    outputs are IDENTICAL with and without padded junk in the batch, even at
-    tight capacity."""
-    from distributed_llm_inference_tpu.ops.moe import moe_mlp_dispatch
-
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
-    lp = {k: v[0] for k, v in params["layers"].items() if k in
-          ("router", "we_g", "we_u", "we_d")}
-    x = jax.random.normal(jax.random.PRNGKey(10), (1, 16, CFG.hidden_size),
-                          jnp.float32)
-    n_real = 9
-    valid = (jnp.arange(16) < n_real)[None, :]
-    # Padded region filled with a constant junk vector that would otherwise
-    # concentrate on one expert and evict real pairs at tight capacity.
-    junk = jnp.broadcast_to(x[:, :1], x.shape)
-    x_padded = jnp.where(valid[..., None], x, junk * 5.0)
-    # Same explicit capacity both runs (the factor formula scales with N,
-    # which would change which REAL pairs drop and confound the comparison).
-    out_padded = moe_mlp_dispatch(CFG, lp, x_padded, valid=valid, capacity=6)
-    out_clean = moe_mlp_dispatch(CFG, lp, x[:, :n_real],
-                                 valid=jnp.ones((1, n_real), bool),
-                                 capacity=6)
-    np.testing.assert_allclose(
-        np.asarray(out_padded[:, :n_real]), np.asarray(out_clean),
-        rtol=2e-5, atol=2e-5,
-    )

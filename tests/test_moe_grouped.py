@@ -96,33 +96,55 @@ def tile8(monkeypatch):
 
 # -- grouped against dense-combine -------------------------------------------
 
+# What a parity case runs against dense-combine. The first three hold both
+# sides to one layer and one input: float32 to rounding, bf16 activations
+# and int8 stacks (the served form) within four of bf16's steps at the
+# results' scale (the kernel scales its f32 sum before it rounds, the einsum
+# after). ``quantized`` holds the int8 stacks to the FLOAT layer they were
+# made from (what quantising the experts costs a routed sum), ``padded``
+# puts junk where ``valid`` is false and holds the real tokens' rows to the
+# clean input's.
+STACKS = ["float32", "bfloat16", "int8", "quantized", "padded"]
 
-@pytest.mark.parametrize("stack", ["float32", "bfloat16", "int8"])
-@pytest.mark.parametrize("shared", [0, 1], ids=["routed_only", "shared_expert"])
-@pytest.mark.parametrize("cfg", [SOFTMAX, SIGMOID], ids=["softmax", "sigmoid_bias"])
-def test_grouped_equals_dense_combine(kernel, tile8, monkeypatch, cfg, shared, stack):
-    """``moe_mlp`` at a prefill shape (grouped by the rule) against the same
-    call held to dense-combine: float32 to rounding, bf16 activations and
-    int8 stacks (the served form) within four of bf16's steps at the
-    results' scale (the kernel scales its f32 sum before it rounds, the
-    einsum after)."""
-    act = jnp.float32 if stack == "float32" else jnp.bfloat16
-    p = layer(cfg, dtype=act, shared=shared)
-    if stack == "int8":
+
+def parity(monkeypatch, cfg, shared, stack, rows, seq, valid):
+    """``moe_mlp`` by the rule and dense-combine's answer, both zeroed
+    where ``valid`` is false, compared as ``stack`` says."""
+    act = jnp.bfloat16 if stack in ("bfloat16", "int8") else jnp.float32
+    want_p = p = layer(cfg, dtype=act, shared=shared)
+    want_x = x = tokens(rows, seq, dtype=act)
+    if stack in ("int8", "quantized"):
         p = quantize_params(p, scale_dtype=jnp.float32)
         assert type(p["we_g"]).__name__ == "QuantizedTensor"
-    x = tokens(2, 24, dtype=act)
-    valid = jnp.arange(24)[None, :] < jnp.array([[13], [24]])
-    assert moe.dispatch_path(cfg, 2, 24) == "grouped"
+        if stack == "int8":
+            want_p = p
+    if stack == "padded":
+        x = jnp.where(valid[..., None], x, x[:1, :1] * 50.0)
     got = moe.moe_mlp(cfg, p, x, valid)
     monkeypatch.setattr(moe, "dispatch_path", lambda *a, **k: "dense")
-    want = moe.moe_mlp(cfg, p, x, valid)
+    want = moe.moe_mlp(cfg, want_p, want_x, valid)
     keep = np.asarray(valid)[..., None]
     a = np.where(keep, np.asarray(got, np.float32), 0)
     b = np.where(keep, np.asarray(want, np.float32), 0)
     assert got.dtype == want.dtype == act
-    tol = 1e-5 if stack == "float32" else 2 ** -5 * np.abs(b).max()
-    np.testing.assert_allclose(a, b, atol=tol, rtol=0)
+    if stack == "quantized":
+        cos = (a * b).sum() / (np.linalg.norm(a) * np.linalg.norm(b))
+        assert 0.98 < cos < 1.0, cos
+    else:
+        tol = 1e-5 if act == jnp.float32 else 2 ** -5 * np.abs(b).max()
+        np.testing.assert_allclose(a, b, atol=tol, rtol=0)
+    return p, x
+
+
+@pytest.mark.parametrize("stack", STACKS)
+@pytest.mark.parametrize("shared", [0, 1], ids=["routed_only", "shared_expert"])
+@pytest.mark.parametrize("cfg", [SOFTMAX, SIGMOID], ids=["softmax", "sigmoid_bias"])
+def test_grouped_equals_dense_combine(kernel, tile8, monkeypatch, cfg, shared, stack):
+    """``moe_mlp`` at a prefill shape (grouped by the rule) against
+    dense-combine, a case of ``STACKS``."""
+    valid = jnp.arange(24)[None, :] < jnp.array([[13], [24]])
+    assert moe.dispatch_path(cfg, 2, 24) == "grouped"
+    parity(monkeypatch, cfg, shared, stack, 2, 24, valid)
 
 
 @pytest.mark.parametrize("blocks", [(128, 128), (256, 128), (2048, 2048)])
@@ -190,8 +212,8 @@ def test_the_xla_reference_is_the_kernels_product(rows, stack):
 
 def test_one_expert_takes_every_token_and_nothing_is_dropped(tile8):
     """A routing that sends every token's first pick to expert 5 and its
-    second to expert 2: 48 rows each where a capacity form at factor 1
-    holds 12. Every pair is computed."""
+    second to expert 2: 48 rows each, six tiles for either and none for the
+    six others. Every pair is computed."""
     cfg = SOFTMAX
     p = layer(cfg)
     router = np.zeros((H, E), np.float32)
@@ -203,26 +225,6 @@ def test_one_expert_takes_every_token_and_nothing_is_dropped(tile8):
     got = moe.moe_mlp_grouped(cfg, p, x)
     want = moe._dense_combine(cfg, p, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
-    dropped = moe.moe_mlp_dispatch(cfg, p, x, capacity_factor=1.0)
-    assert np.abs(np.asarray(dropped) - np.asarray(want)).max() > 1e-2
-
-
-def test_the_default_path_has_no_capacity(tile8, monkeypatch):
-    """Without ``moe_capacity_factor`` no dispatch reaches the capacity
-    form, whatever its shape; with it a prefill-scale one still does."""
-    def refuse(*a, **k):
-        raise AssertionError("capacity form on the default path")
-
-    assert SOFTMAX.moe_capacity_factor is None
-    monkeypatch.setattr(moe, "moe_mlp_dispatch", refuse)
-    p = layer(SOFTMAX)
-    for rows, width in ((1, 48), (4, 1), (2, 4), (1, 16)):
-        assert moe.dispatch_path(SOFTMAX, rows, width) != "capacity"
-        moe.moe_mlp(SOFTMAX, p, tokens(rows, width))
-    opted = dataclasses.replace(SOFTMAX, moe_capacity_factor=2.0)
-    assert moe.dispatch_path(opted, 1, 48) == "capacity"
-    assert moe.dispatch_path(opted, 4, 1) == "live"
-    assert moe.dispatch_path(opted, 1, 12) == "dense"
 
 
 @pytest.mark.parametrize("cfg", [SOFTMAX, SIGMOID], ids=["softmax", "sigmoid_bias"])
@@ -333,12 +335,12 @@ def live_calls(monkeypatch):
 
 
 @pytest.mark.parametrize("shape", LIVE_SHAPES, ids=lambda s: "x".join(map(str, s)))
-@pytest.mark.parametrize("stack", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("stack", STACKS)
 @pytest.mark.parametrize("shared", [0, 1], ids=["routed_only", "shared_expert"])
 @pytest.mark.parametrize("cfg", [SOFTMAX, SIGMOID], ids=["softmax", "sigmoid_bias"])
 def test_live_equals_dense_combine(request, monkeypatch, cfg, shared, stack, shape):
     """``moe_mlp`` at a decode or verify shape (live by the rule) with dead
-    rows, against the same call held to dense-combine, within the grouped
+    rows, against dense-combine, a case of ``STACKS`` within the grouped
     path's tolerance; the kernel ran one tile an expert over the live
     experts alone: ``live_tiles`` is the count of distinct experts the
     valid tokens picked, and they lead the order. The 16-slot decode step
@@ -346,23 +348,10 @@ def test_live_equals_dense_combine(request, monkeypatch, cfg, shared, stack, sha
     rows, seq, alive = shape
     if shape == (16, 1, 4):
         request.getfixturevalue("kernel")
-    act = jnp.float32 if stack == "float32" else jnp.bfloat16
-    p = layer(cfg, dtype=act, shared=shared)
-    if stack == "int8":
-        p = quantize_params(p, scale_dtype=jnp.float32)
-    x = tokens(rows, seq, dtype=act)
     valid = dead_rows(rows, seq, alive)
     assert moe.dispatch_path(cfg, rows, seq) == "live"
     calls = live_calls(monkeypatch)
-    got = moe.moe_mlp(cfg, p, x, valid)
-    monkeypatch.setattr(moe, "dispatch_path", lambda *a, **k: "dense")
-    want = moe.moe_mlp(cfg, p, x, valid)
-    keep = np.asarray(valid)[..., None]
-    a = np.where(keep, np.asarray(got, np.float32), 0)
-    b = np.where(keep, np.asarray(want, np.float32), 0)
-    assert got.dtype == want.dtype == act
-    tol = 1e-5 if stack == "float32" else 2 ** -5 * np.abs(b).max()
-    np.testing.assert_allclose(a, b, atol=tol, rtol=0)
+    p, x = parity(monkeypatch, cfg, shared, stack, rows, seq, valid)
     _, picks = moe.route(cfg, x, p["router"], p.get("router_bias"))
     picked = np.unique(np.asarray(picks)[np.asarray(valid)])
     assert len(calls) == 3
@@ -638,7 +627,6 @@ def test_the_engine_counts_dispatches_by_path_and_rows_by_the_rule(
     assert m.get_counter("moe_dispatch_grouped") == len(wide)
     assert m.get_counter("moe_dispatch_live") == len(narrow) + len(decodes)
     assert m.get_counter("moe_dispatch_dense") == len(between)
-    assert m.get_counter("moe_dispatch_capacity") == 0
     layers = cfg.num_expert_layers
     expected = lambda tokens_: E * (1 - (1 - K / E) ** tokens_)
     computed = held = live = 0.0

@@ -648,39 +648,6 @@ def test_quantized_cache_flash_prefill_path_matches_int8_path():
 # -- EngineConfig surface for the quantization knobs --------------------------
 
 
-def test_engine_config_pins_act_quant_globals():
-    """EngineConfig.act_quant_prefill / act_quant_min_seq pin the module
-    dispatch flags at engine construction (the per-deployment bit-exact
-    weight-only knob); None leaves the library defaults alone."""
-    from distributed_llm_inference_tpu.ops import quant as quant_mod
-
-    params = llama.init_params(CFG, jax.random.PRNGKey(0))
-    old = (quant_mod.ACT_QUANT_PREFILL, quant_mod.ACT_QUANT_MIN_SEQ)
-    try:
-        InferenceEngine(
-            CFG, params,
-            EngineConfig(max_batch_size=2, prefill_buckets=(16,),
-                         max_seq_len=32, quantization="int8"),
-            CacheConfig(kind="dense"),
-        )
-        assert (quant_mod.ACT_QUANT_PREFILL,
-                quant_mod.ACT_QUANT_MIN_SEQ) == old  # None = untouched
-        eng = InferenceEngine(
-            CFG, params,
-            EngineConfig(max_batch_size=2, prefill_buckets=(16,),
-                         max_seq_len=32, max_new_tokens=3,
-                         quantization="int8", act_quant_prefill=False,
-                         act_quant_min_seq=64),
-            CacheConfig(kind="dense"),
-        )
-        assert quant_mod.ACT_QUANT_PREFILL is False
-        assert quant_mod.ACT_QUANT_MIN_SEQ == 64
-        outs = eng.generate([[1, 2, 3]], SamplingOptions(max_new_tokens=3))
-        assert len(outs[0]) == 3
-    finally:
-        quant_mod.ACT_QUANT_PREFILL, quant_mod.ACT_QUANT_MIN_SEQ = old
-
-
 def test_engine_config_outlier_channels_and_act_scales():
     """outlier_channels / act_scales round-trip from EngineConfig into the
     int8_outlier decomposition: channel count honored, calibration scales

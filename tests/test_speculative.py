@@ -197,7 +197,7 @@ def test_engine_speculative_survives_capacity_disable_and_resume():
 
 
 def test_engine_speculative_composes_with_tp_pp_mesh():
-    """BASELINE config 5's full shape: hybrid TP×PP serving WITH speculative
+    """CONFIGS.md config 5's full shape: hybrid TP×PP serving WITH speculative
     decoding in the same engine — verify runs the pipelined program while
     draft proposals ride unsharded."""
     from distributed_llm_inference_tpu.config import (
@@ -262,7 +262,6 @@ def test_engine_adaptive_suspends_on_low_acceptance_and_output_identical():
             EngineConfig(max_batch_size=3, prefill_buckets=(8, 16, 32),
                          max_seq_len=128, dtype="float32", speculative_k=3,
                          decode_steps=4, speculative_rounds=1,
-                         speculative_adaptive=True,
                          speculative_probe_len=2,
                          speculative_probe_period=6),
             CacheConfig(kind="dense"),
@@ -297,7 +296,7 @@ def test_engine_adaptive_keeps_speculating_with_perfect_draft():
         EngineConfig(max_batch_size=2, prefill_buckets=(8, 16, 32),
                      max_seq_len=128, dtype="float32", speculative_k=3,
                      decode_steps=4, speculative_rounds=1,
-                     speculative_adaptive=True, speculative_probe_len=2,
+                     speculative_probe_len=2,
                      speculative_probe_period=6),
         CacheConfig(kind="dense"),
         draft=(CFG, PARAMS),  # draft == target: acceptance 1

@@ -587,7 +587,7 @@ def test_a_read_of_the_ticks_arms_the_clock_for_a_lease():
     second lease starts clean, with no idle gap across the stretch."""
     from distributed_llm_inference_tpu.config import TraceConfig
 
-    eng = small_engine(trace_cfg=TraceConfig(), pipelined_ticks=True)
+    eng = small_engine(trace_cfg=TraceConfig())
     clock, m = eng.flight.clock, eng.metrics
     _serve(eng)                                 # unarmed: loads the programs
     before = time.time_ns()

@@ -131,7 +131,7 @@ def test_a_cpu_trace_carries_the_ticks_and_phases_on_the_host_plane(tmp_path):
     path = xplane.find_xplane(str(tmp_path))
     out = xplane.aggregate(path)
     assert out["ticks"] == list(range(before, eng.flight.tick))
-    assert out["devices"] == [] and xplane.device_time_ps(str(tmp_path)) == 0
+    assert out["devices"] == []
     host_ms = out["host_by_phase_ns"]
     assert set(host_ms) == set(tracing.PHASES)
     for phase in ("admit", "dispatch", "blocked", "deliver"):
