@@ -157,6 +157,23 @@ class SparseAttentionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RetentionConfig:
+    """Power retention (Manifest AI, arXiv:2507.04239) in place of softmax
+    attention: a query weighs key ``j <= t`` by ``(q_t . k_j) ** 2`` (the
+    one degree ``ops/power_retention.py``'s feature map is written for)
+    times the decay ``exp(G_t - G_j)`` of a learned log-gate a key-value
+    head summed over the positions between, and divides by the summed
+    weights plus ``eps``. With ``phi(x) . phi(y) = (x . y) ** 2`` that is a
+    recurrence over a state of FIXED size a row, a layer and a key-value
+    head (``[D, head_dim]`` float32 and its sum of keys), which
+    the cache manager holds beside a short paged K/V tail
+    (``cache/retention.py``; the feature map and the kernels:
+    ``ops/power_retention.py``)."""
+
+    eps: float = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSegment:
     """A run of consecutive decoder layers that are all alike: one
     ``lax.scan`` over one stacked parameter dict (``params[key]``). The
@@ -278,8 +295,11 @@ class ModelConfig:
     # manifold-constrained hyper-connections around every sublayer
     # (``hc_mult``); None = the plain ``x + f(x)``.
     hyper: Optional[HyperConnectionConfig] = None
+    # Power retention in place of softmax attention, in every layer
+    # (``model_type`` "brumby"); None = softmax attention.
+    retention: Optional[RetentionConfig] = None
     # Model family tag ("llama", "mistral", "qwen2", "mixtral", "mla",
-    # "keye_vl2", "exaone_moe", "glm_moe_dsa", "xing4_0").
+    # "keye_vl2", "exaone_moe", "glm_moe_dsa", "xing4_0", "brumby").
     family: str = "llama"
 
     @property
@@ -302,8 +322,11 @@ class ModelConfig:
 
     @property
     def attention_kinds(self) -> Tuple[str, ...]:
-        """"window" | "full", a layer. One window for the whole model
-        (Mistral) is every layer a window layer."""
+        """"window" | "full" | "retention", a layer. One window for the
+        whole model (Mistral) is every layer a window layer; a model with
+        a retention part has retention layers only."""
+        if self.retention is not None:
+            return ("retention",) * self.num_layers
         if self.layer_attention is not None:
             return self.layer_attention
         kind = "window" if self.sliding_window is not None else "full"
@@ -339,7 +362,7 @@ class ModelConfig:
                 runs[-1][4] += 1
             else:
                 runs.append([mlp, kinds[i], index[i], i, 1])
-        seen = {"window": 0, "full": 0, "score": 0}
+        seen = {"window": 0, "full": 0, "retention": 0, "score": 0}
         out = []
         for n, (mlp, att, idx, start, count) in enumerate(runs):
             out.append(LayerSegment(
@@ -384,6 +407,12 @@ class ModelConfig:
     def use_sparse(self) -> bool:
         """THE selection predicate (as :attr:`use_latent` is the latent's)."""
         return self.sparse is not None
+
+    @property
+    def use_retention(self) -> bool:
+        """THE retention predicate: the layers keep a fixed-size state a
+        row and no keys that grow with the context."""
+        return self.retention is not None
 
     @staticmethod
     def from_hf_config(hf: Any) -> "ModelConfig":
@@ -458,6 +487,8 @@ class ModelConfig:
         if model_type == "exaone_moe":
             moe, extra = _exaone_moe_keys(get)
             window = extra.pop("sliding_window")
+        if model_type == "brumby":
+            extra, window = _brumby_keys(get), None
         # the ROUTER's width, where the block's key counts a share held here
         experts = extra.pop("num_experts", experts)
         # A block may nest its RoPE keys (``rope_parameters``: theta and
@@ -518,6 +549,25 @@ def _refuse_unimplemented(get) -> None:
                 f"layers and interleaved dense layers are outside what "
                 f"models/llama.py computes"
             )
+
+
+def _brumby_keys(get) -> dict:
+    """The keys of a ``brumby`` ``config.json`` (Brumby-14B-Base): the Qwen3
+    block (per-head q/k norms) with power retention in every layer. The
+    published keys state neither the degree, the gate's form nor the
+    normaliser's epsilon (a configuration file lists them as assumed):
+    degree 2 (the feature map's), one log-gate a key-value head (the gate
+    projection's width is ``num_kv_heads``), ``rms_norm_eps``. A window the
+    block switches on is refused by the key's name."""
+    if get("use_sliding_window", False):
+        _refuse(
+            get, "use_sliding_window",
+            "a retention layer keeps a decayed state and no window of keys",
+        )
+    return dict(
+        qk_norm=True,
+        retention=RetentionConfig(eps=float(get("rms_norm_eps", 1e-6))),
+    )
 
 
 _ATTENTION_KINDS = {"sliding_attention": "window", "full_attention": "full"}

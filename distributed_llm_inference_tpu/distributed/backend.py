@@ -89,6 +89,13 @@ class BlockBackend:
                 "carries [1, S, hidden_size] hidden states between nodes, "
                 "one row of the widened stream"
             )
+        if cfg.use_retention:
+            raise ValueError(
+                f"family {cfg.family!r} (ModelConfig.retention) is not served "
+                "by block workers: a block node's caches hold per-token K/V "
+                "and no per-session state that is zeroed at admission and "
+                "folded at page boundaries"
+            )
         self.mesh = None
         self._shard_cache_fn = None
         tp = 1

@@ -46,6 +46,7 @@ from ..cache.paged import (
     PageAllocator, PagedKVCache, QuantizedPagedKVCache, indexed_cache_class,
     two_pool_cache_class, window_pages_bound,
 )
+from ..cache.retention import retention_cache_class
 from ..cache.sink import QuantizedSinkKVCache, SinkKVCache
 
 # Cache kinds implementing the StreamingLLM sink-window policy (unbounded
@@ -352,6 +353,31 @@ class InferenceEngine:
                         "a stack of window and full layers does not "
                         f"compose with {what}"
                     )
+        # Retention layers keep a fixed-size state a row beside a short paged
+        # K/V tail (cache/retention.py). What carries K/V planes between
+        # places, and every mesh, is refused here by the family's name.
+        self._retention = cfg.use_retention
+        if self._retention:
+            name = f"family {cfg.family!r} (ModelConfig.retention)"
+            if cc.kind != "paged":
+                raise ValueError(
+                    f"{name} requires the paged cache (got kind={cc.kind!r})"
+                )
+            for bad, what in (
+                (mesh_cfg is not None,
+                 f"a mesh ({mesh_cfg}: sharding of the state pool over tp, "
+                 "ep, pp, sp or dp is not implemented)"),
+                (cc.prefix_caching,
+                 "prefix_caching (a shared prefix would need a snapshot of "
+                 "the state at its end, not its pages)"),
+                (self.pcfg.spill_bytes_max > 0,
+                 "a spill tier (a folded page holds nothing to reload)"),
+                (draft is not None,
+                 "a draft model (a rejected proposal cannot be taken back "
+                 "out of a folded state)"),
+            ):
+                if bad:
+                    raise ValueError(f"{name} does not compose with {what}")
         self.plan.latent = self._latent
         # The census walks the stack's attention kinds: (window, layers) a
         # kind. A stack of ONE kind counts one layer, as it always did
@@ -463,6 +489,44 @@ class InferenceEngine:
                         cc.kv_quant == "int8", cfg.sparse.index_dim
                     )
                 pool_layers, more = cfg.num_layers, {}
+                if self._retention or two_pools:
+                    # A ROLLING pool (``_pool_reach``): a row holds the
+                    # pages its next dispatch reads or writes, whatever its
+                    # context. What admission leaves free of it (``_admit``):
+                    # one chunk's pages and every row's while it decodes (a
+                    # dispatch and the one in flight).
+                    sizes = (self._pool_reach, cc.page_size)
+                    chunk = self.ecfg.prefill_buckets[-1]
+                    ahead = 2 * (self.ecfg.decode_steps or 16)
+                    self._window_reserve = window_pages_bound(
+                        *sizes, chunk
+                    ) + b * window_pages_bound(*sizes, ahead)
+                    self._pending_window_installs: List[
+                        Tuple[int, int, int]
+                    ] = []
+                if self._retention:
+                    # the rolling pool is the ONE pool: ``num_pages`` is
+                    # held to its bound and not to the contexts
+                    paged_cls = retention_cache_class(
+                        cfg.head_dim, cfg.retention.eps,
+                        cc.kv_quant == "int8",
+                    )
+                    least = 1 + self._window_reserve + window_pages_bound(
+                        *sizes, chunk
+                    )
+                    if cc.num_pages < least:
+                        raise ValueError(
+                            f"num_pages={cc.num_pages} is under what "
+                            f"{b} rows of retention layers hold around one "
+                            f"{chunk}-token dispatch: {least} pages of "
+                            f"{cc.page_size} (a chunk in flight, a chunk "
+                            "held back for admission, every row's open "
+                            "page and look-ahead, the null page)"
+                        )
+                    state = paged_cls.FEATURE_DIM * (cfg.head_dim + 1) * 4
+                    self.plan.retention_state_bytes = (
+                        cfg.num_layers * cfg.num_kv_heads * state
+                    )
                 if two_pools:
                     # ``num_pages`` sizes the FULL layers' pool (the one
                     # that grows with the context); the window pool is
@@ -473,23 +537,12 @@ class InferenceEngine:
                         cc.kv_quant == "int8", kinds, cfg.sliding_window
                     )
                     pool_layers = paged_cls.num_layers_of("full")
-                    sizes = (cfg.sliding_window, cc.page_size)
-                    chunk = self.ecfg.prefill_buckets[-1]
-                    ahead = 2 * (self.ecfg.decode_steps or 16)
                     more["window_pages"] = window_pool_pages(
                         *sizes, b, chunk, ahead
                     )
                     self.window_allocator = PageAllocator(
                         more["window_pages"]
                     )
-                    # what admission leaves free: one chunk's pages and
-                    # every row's while it decodes (``_admit``)
-                    self._window_reserve = window_pages_bound(
-                        *sizes, chunk
-                    ) + b * window_pages_bound(*sizes, ahead)
-                    self._pending_window_installs: List[
-                        Tuple[int, int, int]
-                    ] = []
                 self.cache = paged_cls.create(
                     pool_layers, b, cc.num_pages, cc.page_size,
                     self._first_slots, cfg.num_kv_heads, cfg.head_dim, dtype,
@@ -497,6 +550,10 @@ class InferenceEngine:
                     use_ragged=_sel.use_ragged, **more,
                 )
             self.allocator = PageAllocator(cc.num_pages)
+            if self._retention:
+                # the rolling pool IS the pool: its pages come and go by
+                # the window pool's rule, a row keeps no run of pages
+                self.window_allocator = self.allocator
             # The q block the ragged kernel picks at a pad width over THIS
             # pool (heads, stored width, element size): the plan's census
             # of live tiles (note_dispatch) walks that kernel's grid.
@@ -776,6 +833,7 @@ class InferenceEngine:
             )
         if (
             tail_capable and K > 1 and hasattr(self.cache, "tail_walk")
+            and not self._retention   # (its walk lists live ROWS, no blocks)
             and jax.eval_shape(
                 lambda c: c.tail_walk(K, c.lengths, c.lengths), self.cache
             ) is not None
@@ -1530,7 +1588,15 @@ class InferenceEngine:
         """What moves a row's KV as ONE run of pages (disaggregated export
         and admission, preemption with resume) is refused by name for a
         stack of window and full layers: a window layer holds only the
-        row's last pages. Such a row is neither exported nor resumed."""
+        row's last pages; and for a stack of retention layers, whose rows
+        hold a state and an open page. Such a row is neither exported nor
+        resumed."""
+        if self._retention:
+            raise ValueError(
+                f"{what} is not implemented for family {self.cfg.family!r} "
+                "(ModelConfig.retention): a row's past is a folded state and "
+                "its open page, not a run of pages that can be shipped"
+            )
         if self.window_allocator is not None:
             raise ValueError(
                 f"{what} is not implemented for a stack of window and full "
@@ -1547,12 +1613,15 @@ class InferenceEngine:
         ``/metrics``."""
         if self.window_allocator is None:
             return {}
-        free = self.window_allocator.free_count
         most = max(
             (len(s.window_pages) for s in self.sessions.values()
              if s.slot is not None),
             default=0,
         )
+        if self._retention:
+            # one pool: ``free_pages`` says the rest
+            return {"row_window_pages_max": most}
+        free = self.window_allocator.free_count
         self.metrics.gauge("window_pool_free_pages", float(free))
         self.metrics.gauge("kv_pool_free_pages", float(self.allocator.free_count))
         return {
@@ -2690,7 +2759,9 @@ class InferenceEngine:
             if isinstance(self.cache, PagedKVCache):
                 ps = self.ccfg.page_size
                 n = len(s.prompt)
-                need = math.ceil((n + 1) / ps)
+                # (a retention row holds no run of pages: its pages are the
+                # rolling pool's, covered below)
+                need = 0 if self._retention else math.ceil((n + 1) / ps)
                 shared: List[int] = []
                 cow = False
                 if self.ccfg.prefix_caching:
@@ -3177,6 +3248,10 @@ class InferenceEngine:
                 )[1]
                 s.chunk_off += stride
                 self.plan.note_chunk_rows()
+                if self.window_allocator is not None:
+                    # enqueued: every query still to come is at or past the
+                    # next chunk's first
+                    self._window_release(s, s.chunk_off)
                 continue
             width = self.plan.final_shape(rest, chunk_cap)
             padded = np.zeros((1, width), np.int32)
@@ -3458,7 +3533,7 @@ class InferenceEngine:
             if sink:  # the ring evicts; streams are (near-)unbounded
                 cap = self._sink_cap()
             elif paged:
-                cap = len(s.pages) * self.ccfg.page_size
+                cap = self._run_capacity(s)
             else:
                 cap = self.ecfg.max_seq_len
             if pend == 0 and s.total_len + 1 > cap:
@@ -3719,7 +3794,7 @@ class InferenceEngine:
         with the row too: those its window has passed leave, those the
         tokens need are taken, and the capacity is what both pools map."""
         ps = self.ccfg.page_size
-        while len(s.pages) * ps < s.total_len + want:
+        while not self._retention and len(s.pages) * ps < s.total_len + want:
             if (
                 len(s.pages) >= self.ccfg.max_pages_per_session
                 or self.allocator.free_count == 0
@@ -3731,7 +3806,11 @@ class InferenceEngine:
             new = self.allocator.alloc(1)
             self._queue_install(s.slot, len(s.pages), new[0])
             s.pages.extend(new)
-        cap = len(s.pages) * ps
+        if self._retention:
+            self._ensure_capacity(
+                min(s.total_len + want, self._run_capacity(s))
+            )
+        cap = self._run_capacity(s)
         if self.window_allocator is not None:
             # every query still to come is at or past the host's count
             self._window_release(s, s.total_len - 1)
@@ -3764,12 +3843,33 @@ class InferenceEngine:
     # it, and an XLA gather of the whole table reads it under the window's
     # mask (cache/paged.py, the two-pool classes' note).
 
+    @property
+    def _pool_reach(self) -> int:
+        """How far back of a query the rolling pool's pages are read: a
+        window layer's window, or ONE position for a stack of retention
+        layers, whose every full page is folded into the row's state by the
+        dispatch that fills it (``cache/retention.py``): the page a query
+        lies in is all it reads of the pool. The release rule, the cover and
+        the bounds below are the same code for both."""
+        return 1 if self._retention else self.cfg.sliding_window
+
+    def _run_capacity(self, s: Session) -> int:
+        """Positions the first pool maps for ``s``: its run of pages, or,
+        where the rolling pool is the only one (retention layers), what the
+        table can name."""
+        ps = self.ccfg.page_size
+        if self._retention:
+            return min(
+                self.ecfg.max_seq_len, self.ccfg.max_pages_per_session * ps
+            )
+        return len(s.pages) * ps
+
     def _window_slots(self, lo_pos: int, hi_pos: int) -> range:
         """Table slots a dispatch touches that writes positions ``lo_pos ..
         hi_pos`` of a row: from the slot its first query's window reaches
         back into, to the slot of its last token."""
         ps = self.ccfg.page_size
-        first = max(0, lo_pos - self.cfg.sliding_window + 1) // ps
+        first = max(0, lo_pos - self._pool_reach + 1) // ps
         return range(first, (max(hi_pos, lo_pos + 1) - 1) // ps + 1)
 
     def _window_cover(self, s: Session, row: int, lo_pos: int, hi_pos: int,
@@ -3802,7 +3902,7 @@ class InferenceEngine:
     def _window_release(self, s: Session, t_min: int) -> None:
         """Release the window pages of ``s`` that no query at or past
         position ``t_min`` can see (the note above)."""
-        keep_from = max(0, t_min - self.cfg.sliding_window + 1) // (
+        keep_from = max(0, t_min - self._pool_reach + 1) // (
             self.ccfg.page_size
         )
         gone = [j for j in s.window_pages if j < keep_from]

@@ -154,6 +154,10 @@ class AttentionPlan:
         # (``ModelConfig.hyper``): the hyper-connection mixes a token
         # crosses, two a layer; note_dispatch keeps their census.
         self.mhc_mixes_per_token: Optional[int] = None
+        # A stack of retention layers: the bytes of a row's state and summed
+        # keys over every layer, which each decode step of a live row reads
+        # (the engine sets it; the fold lies at the page boundaries).
+        self.retention_state_bytes: Optional[int] = None
         # Set by the engine over a paged cache: ``pad width -> block_q``,
         # the q block the ragged kernel picks for this model at that width
         # (``ops/ragged_attention.py:_prep``); note_dispatch keeps the
@@ -367,6 +371,10 @@ class AttentionPlan:
         ``window_keys_in_context`` and ride the record as a fifth entry
         ``(seen, in context)`` behind a fourth that is None.
 
+        A stack of retention layers (``retention_state_bytes``;
+        :meth:`_count_retention`) counts what its fixed-size state costs and
+        how much of its work is still pair by pair.
+
         A model with a widened residual stream (``mhc_mixes_per_token``:
         two mixes a layer) counts the hyper-connection mixes its valid
         tokens need and those its padded tokens run, ``mhc_mixes_needed`` /
@@ -382,6 +390,13 @@ class AttentionPlan:
             window = next(w for w, _ in self.attention_layers if w)
             window_keys = self._keys_under(window, query_spans)
             self.last_dispatch += (None, window_keys)
+        folds = None
+        if self.retention_state_bytes is not None and query_spans is not None:
+            # a dispatch that folds says so in its record: a sixth entry
+            # (rows that fold in it, positions folded)
+            folds = self._folds(query_spans)
+            if folds[0]:
+                self.last_dispatch += (None, None, folds)
         if self.dispatches is not None:
             self.dispatches.append(self.last_dispatch)
         key = (kind,) + shape
@@ -403,6 +418,8 @@ class AttentionPlan:
             self.metrics.counter("window_keys_in_context", window_keys[1])
         if self.enabled and kind != DECODE:
             self.metrics.counter("attn_ragged_dispatches")
+        if folds is not None:
+            self._count_retention(kind, shape, active_rows, query_spans, folds)
         if valid_tokens is None:
             return
         # a dispatch's tokens: a decode dispatch's are its ``active_rows``
@@ -445,7 +462,10 @@ class AttentionPlan:
                 grid *= sum(n for _, n in self.attention_layers)
             self.metrics.counter("decode_live_positions", live)
             self.metrics.counter("decode_grid_positions", grid)
-            if paged and query_spans is not None:
+            if (
+                paged and query_spans is not None
+                and self.retention_state_bytes is None
+            ):
                 live, joint, walked, grid = self._swept_pages(
                     shape, query_spans
                 )
@@ -461,6 +481,46 @@ class AttentionPlan:
                 live, grid = self._ragged_tiles(shape, row_spans, table_width)
                 self.metrics.counter("ragged_attn_tiles_live", live)
                 self.metrics.counter("ragged_attn_tiles_grid", grid)
+
+    def _folds(self, spans) -> Tuple[int, int]:
+        """(rows that fold, positions folded) of a dispatch of a stack of
+        retention layers over ``spans``: a row starts at position ``p`` with
+        ``p // PS * PS`` positions folded and ends with every full page
+        folded (``cache/retention.py``)."""
+        ps, rows, tokens = self.ccfg.page_size, 0, 0
+        for start, n in spans:
+            crossed = (int(start) + int(n)) // ps - int(start) // ps
+            rows += crossed > 0
+            tokens += crossed * ps
+        return rows, tokens
+
+    def _count_retention(self, kind, shape, active_rows, spans, folds) -> None:
+        """The census of a stack of retention layers, ONE layer's positions
+        and every layer's bytes. Every dispatch: ``retention_folds`` (rows
+        that fold in it) and ``retention_tokens_folded`` (:meth:`_folds`). A
+        decode dispatch of ``steps``: ``retention_state_rows_live`` /
+        ``_held`` (active rows over the pool's rows, x steps),
+        ``retention_decode_row_steps`` and ``retention_tail_positions`` (the
+        unfolded positions a step's query attends pair by pair, itself among
+        them, summed over rows and steps), ``retention_state_bytes_read``
+        (live rows x steps x the state's bytes)."""
+        ps, count = self.ccfg.page_size, self.metrics.counter
+        count("retention_folds", folds[0])
+        count("retention_tokens_folded", folds[1])
+        if kind != DECODE:
+            return
+        rows, steps = active_rows or 0, shape[1]
+        count("retention_state_rows_live", rows * steps)
+        count("retention_state_rows_held", shape[0] * steps)
+        count("retention_decode_row_steps", sum(int(n) for _, n in spans))
+        count("retention_tail_positions", sum(
+            int(n) * (int(p) % ps + 1) + int(n) * (int(n) - 1) // 2
+            for p, n in spans
+        ))
+        count(
+            "retention_state_bytes_read",
+            rows * steps * self.retention_state_bytes,
+        )
 
     def _count_index_layers(self, steps: int) -> None:
         """A dispatch's ``steps`` to ``index_layers_scored`` /

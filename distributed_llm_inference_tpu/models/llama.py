@@ -145,6 +145,13 @@ def init_layer_params(
     if cfg.qk_norm:
         p["q_norm"] = jnp.ones((num_layers, d), dtype)
         p["k_norm"] = jnp.ones((num_layers, d), dtype)
+    if cfg.use_retention:
+        # The gate of a retention layer: one output a key-value head, its
+        # biases spread so that the gates lie between 0.9 and 0.999.
+        p["w_gate"] = w(jax.random.fold_in(keys[3], 7), h, hkv)
+        p["b_gate"] = jnp.broadcast_to(
+            jnp.linspace(2.2, 6.9, hkv, dtype=jnp.float32), (num_layers, hkv)
+        )
     if cfg.use_sparse and index != "reuse":
         # The indexer (see :func:`_index_inputs`): index queries (from the
         # compressed query where the block has one), ONE index key a token
@@ -321,6 +328,10 @@ def _decoder_layer(
                 {"index": _index_inputs(cfg, p, h, index_rope)}
                 if cfg.use_sparse else {}
             )
+            if "w_gate" in p:
+                # A retention layer hands the cache a log-gate a key-value
+                # head beside q, k, v (``cache/retention.py``).
+                more["gate"] = _retention_gate(p, h)
             with kind_scope:
                 attn, new_state = cache.attend(
                     layer_state, q, k, v, rope, q_pos, num_new,
@@ -332,6 +343,17 @@ def _decoder_layer(
             o = o + p["bo"]
         x = _stream_write(x, o, mix)
     return _mlp_residual(cfg, p, x, s, num_new), new_state
+
+
+def _retention_gate(p: Params, h):
+    """A retention layer's log-gates ``[B, S, Hkv]`` float32: ``logsigmoid``
+    of a projection of the normed hidden state (``g_proj``), with its bias
+    where the layer has one."""
+    with jax.named_scope("retention_gate"):
+        g = qmatmul(h, p["w_gate"]).astype(jnp.float32)
+        if "b_gate" in p:
+            g = g + p["b_gate"].astype(jnp.float32)
+        return jax.nn.log_sigmoid(g)
 
 
 def _stream_read(cfg: ModelConfig, p: Params, prefix: str, x):
@@ -998,6 +1020,11 @@ _LAYER_KEY_MAP = {
     "self_attn.v_proj.bias": ("bv", False),
     "self_attn.o_proj.bias": ("bo", False),
     "post_attention_layernorm.weight": ("mlp_norm", False),
+    # a retention block (``brumby``): Qwen3's per-head norms and the gate
+    "self_attn.q_norm.weight": ("q_norm", False),
+    "self_attn.k_norm.weight": ("k_norm", False),
+    "self_attn.g_proj.weight": ("w_gate", True),
+    "self_attn.g_proj.bias": ("b_gate", False),
     "mlp.gate_proj.weight": ("wg", True),
     "mlp.up_proj.weight": ("wu", True),
     "mlp.down_proj.weight": ("wd", True),
@@ -1146,10 +1173,14 @@ def convert_hf_state_dict(
     three times over (state, per-layer copies, stacks: 35 GiB and counting
     for a 14.5 GB checkpoint on a 40 GiB host — my chip run, PR 21).
     """
-    if cfg.qk_norm or cfg.use_sparse or cfg.hyper is not None:
+    if (
+        cfg.qk_norm and not cfg.use_retention
+    ) or cfg.use_sparse or cfg.hyper is not None:
         # by what the converter lacks, whatever the family's name: a latent
-        # block's compressed queries are mapped (``convert_hf_layer``), an
-        # indexer's tensors and a widened stream's maps are not
+        # block's compressed queries are mapped (``convert_hf_layer``), and a
+        # retention block's checkpoint is Qwen3's with a ``g_proj``
+        # (``_LAYER_KEY_MAP``); an indexer's tensors and a widened stream's
+        # maps are not
         raise ValueError(
             f"family {cfg.family!r} has no checkpoint converter: the key "
             "names of its checkpoint (the per-head q/k norms', an "

@@ -55,6 +55,14 @@ Families:
   key names for those maps are not known to this program:
   :func:`llama.convert_hf_state_dict` refuses the family.
 
+* ``brumby`` — Brumby-14B-Base's block: the Qwen3 block (``qk_norm``) with
+  softmax attention replaced by power retention in every layer
+  (``ModelConfig.retention``: squared scores decayed by a learned gate a
+  key-value head, over a fixed-size state a row that the cache manager
+  holds beside a short paged K/V tail; ``cache/retention.py``,
+  ``ops/power_retention.py``). Its checkpoint is Qwen3's with a ``g_proj``
+  beside q, k, v: the converter maps it.
+
 The switches are independent: a family may permit any of them together
 (``mla`` permits experts AND requires the latent); what a family does not
 permit is refused by :func:`validate_config`.
@@ -92,6 +100,9 @@ class ModelFamily:
     layer_attention: bool = False
     # A residual stream several rows wide (``ModelConfig.hyper``).
     hyper: bool = False
+    # Power retention in place of softmax attention: the family both
+    # permits AND requires ``ModelConfig.retention``.
+    retention: bool = False
     # The compute/conversion program (shared stack for all current families).
     apply: Callable = llama.model_apply
     block_apply: Callable = llama.block_apply
@@ -125,6 +136,7 @@ FAMILIES: Dict[str, ModelFamily] = {
         ModelFamily(
             "xing4_0", ("xing4_0",), latent=True, moe=True, hyper=True,
         ),
+        ModelFamily("brumby", ("brumby",), qk_norm=True, retention=True),
     )
 }
 
@@ -248,6 +260,17 @@ def validate_config(cfg: ModelConfig) -> ModelFamily:
             f"expert_shares={cfg.expert_shares} must divide num_experts="
             f"{cfg.num_experts}, with expert_share_index="
             f"{cfg.expert_share_index} under it"
+        )
+    if (cfg.retention is not None) != fam.retention:
+        raise ValueError(
+            f"family {fam.name!r} "
+            + ("requires" if fam.retention else "does not use")
+            + " power retention (ModelConfig.retention; the 'brumby' family)"
+        )
+    if cfg.retention is not None and cfg.head_dim % 2:
+        raise ValueError(
+            "power retention's feature map pairs the halves of an even "
+            f"head_dim (got head_dim={cfg.head_dim})"
         )
     if fam.latent and (cfg.latent is None or not cfg.latent.enabled):
         raise ValueError(

@@ -646,3 +646,67 @@ def test_live_moe_kernel_at_the_decode_shapes(chip, hidden, ffn, held, rows):
         stack(hidden, ffn), stack(ffn, hidden), s((held,), I32), s((), I32),
         s((), I32),
     )
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.bfloat16, F32], ids=["bf16", "f32"])
+def test_retention_decode_kernel_at_brumbys_shapes(chip, q_dtype):
+    """``power_retention_decode`` over the WHOLE state stacks of 10 layers x
+    16 rows (40 query / 8 kv heads of 128: a head's state 8320 x 128
+    float32, 4.26 MB a grid step), the open page's 64 and the tail's 16
+    places, walking a list of live rows whose length is data."""
+    from distributed_llm_inference_tpu.ops import power_retention as pr
+
+    s, rows, layers, g, n = chip, 16, 10, 5, 80
+    width = pr.feature_dim(D)
+    _compiles_with_kernel(
+        lambda q, st, zs, dec, k, v, w, layer, count, walk:
+            pr.power_retention_decode(
+                q, st, zs, dec, k, v, w, 1e-6, layer=layer,
+                walk=(count, walk), interpret=False,
+            ),
+        s((rows, HKV, g, D), q_dtype), s((layers, rows, HKV, width, D), F32),
+        s((layers, rows, HKV, width), F32), s((rows, HKV), F32),
+        s((rows, HKV, n, D), q_dtype), s((rows, HKV, n, D), q_dtype),
+        s((rows, HKV, n), F32), s((1,), I32), s((), I32), s((rows,), I32),
+    )
+
+
+def test_retention_fold_kernel_at_brumbys_shapes(chip):
+    """``power_retention_fold``: the state and the summed keys of 10 layers
+    x 16 rows aliased in place, two pages' 128 positions a row, the rows
+    that fold listed as data."""
+    from distributed_llm_inference_tpu.ops import power_retention as pr
+
+    s, rows, layers, n = chip, 16, 10, 128
+    width = pr.feature_dim(D)
+    _compiles_with_kernel(
+        lambda st, zs, ref, k, v, g, fold: pr.power_retention_fold(
+            st, zs, ref, k, v, g, fold, interpret=False
+        ),
+        s((layers, rows, HKV, width, D), F32),
+        s((layers, rows, HKV, width), F32), s((layers, rows, HKV), F32),
+        s((layers, rows, n, HKV, D), jnp.bfloat16),
+        s((layers, rows, n, HKV, D), jnp.bfloat16),
+        s((layers, rows, n, HKV), F32), s((rows, n), jnp.bool_),
+    )
+
+
+def test_retention_prefill_kernel_at_brumbys_shapes(chip):
+    """``power_retention_prefill``: a 4096-wide chunk behind an open page,
+    17 steps of 256 positions x 5 query heads a key-value head, the head's
+    8320 x 128 state resident in VMEM across them and aliased in place."""
+    from distributed_llm_inference_tpu.ops import power_retention as pr
+
+    s, e, g = chip, 4352, 5
+    width = pr.feature_dim(D)
+    _compiles_with_kernel(
+        lambda q, k, v, gs, vq, vk, fold, st, zs, ref:
+            pr.power_retention_prefill(
+                q, k, v, gs, vq, vk, fold, st, zs, ref, 1e-6, 256,
+                interpret=False,
+            ),
+        s((1, e, HKV, g, D), jnp.bfloat16), s((1, e, HKV, D), jnp.bfloat16),
+        s((1, e, HKV, D), jnp.bfloat16), s((1, e, HKV), F32),
+        s((1, e), jnp.bool_), s((1, e), jnp.bool_), s((1, e), jnp.bool_),
+        s((1, HKV, width, D), F32), s((1, HKV, width), F32), s((1, HKV), F32),
+    )
