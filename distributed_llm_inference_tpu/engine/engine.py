@@ -915,23 +915,23 @@ class InferenceEngine:
         self._idle_since = time.monotonic()  # last time every slot was free
         # -- overlapped (stall-free) admission ---------------------------------
         # With a pipelined tick in flight, admission prefills DISPATCH as
-        # usual (the program queues right behind the running tick — JAX
-        # dispatch is async) but the host defers the blocking first-token
-        # fetch: each record below holds (sessions, device tokens, skips)
-        # until the next tick boundary, where the fetch rides the tick
-        # resolve's device_get. The sampled tokens scatter into the carry
-        # so the very next tick consumes them with NO host round trip, and
-        # ``_admit_pend`` charges one conservative in-flight token per row
-        # (mirroring the pipelined budget discipline). Device programs and
-        # RNG order are identical to the synchronous path — token streams
-        # are byte-exact with an engine that never overlaps (``_overlap_ok``
-        # says when this one does).
+        # usual (the program queues behind the running tick: JAX dispatch is
+        # async) but the host defers the blocking first-token fetch: each
+        # record below holds (sessions, device tokens, skips) until the next
+        # tick boundary, where the fetch rides the tick resolve's device_get.
+        # The sampled tokens scatter into the carry so the next tick consumes
+        # them with NO host round trip, and ``_admit_pend`` charges one
+        # conservative in-flight token per row. Device programs and RNG order
+        # are the synchronous path's: token streams are byte-exact with an
+        # engine that never overlaps (``_overlap_ok`` says when this one does:
+        # on one chip, and under a mesh too where ``_batch_whole`` holds).
         self._inflight_admits: List[Tuple[List[Session], jax.Array, List[int]]] = []
         self._admit_pend = np.zeros(self.batch, np.int32)
+        # Is the batch axis whole on every device: one chip, or a mesh whose
+        # only axis over 1 is ``tp``? The deferred carry scatter needs that.
+        self._batch_whole = self.mesh is None or self.mesh.size == self.mesh.shape["tp"]
         # Events produced OUTSIDE step() (admit_prefilled's synchronous
-        # first-token delivery happens on a gateway thread): step() drains
-        # them into its own event list so streaming consumers see every
-        # token through the one event channel they already poll.
+        # first-token delivery, on a gateway thread): step() drains them.
         self._ext_produced: List[Tuple[str, int, bool]] = []
         # Admission-ordering hook (set_admission_order): None = FIFO.
         self._admission_order = None
@@ -955,7 +955,7 @@ class InferenceEngine:
 
         self._carry_combine = self._with_mesh(jax.jit(_carry_combine))
         self._carry_merge = self._with_mesh(jax.jit(_carry_merge))
-        self._carry_scatter = jax.jit(_carry_scatter)
+        self._carry_scatter = self._with_mesh(jax.jit(_carry_scatter))
 
         # -- ring (sequence-parallel) prefill (SURVEY §5.7) -------------------
         self._ring_prefill = None
@@ -2897,17 +2897,17 @@ class InferenceEngine:
         pipelined carry machinery (so the next tick consumes the deferred
         first token without a host fetch), a tick actually in flight
         (otherwise the synchronous path is already stall-free — there is
-        nothing to overlap), a single-device engine (mesh engines keep the
-        synchronous flow: ring/sp prefill is a different, collective-
-        bearing program, and the same GSPMD scatter constraint that turns
-        batched admission off applies to the deferred carry scatter), and
-        head-room under the in-flight cap (back-pressure: an admission
-        flood spills to the synchronous path instead of queueing unbounded
-        prefill work on the device)."""
+        nothing to overlap), a batch axis whole on every device
+        (``_batch_whole``: one chip or a ``tp``-only mesh; a ``dp``/``pp``
+        mesh shards the axis the deferred carry scatter writes, the GSPMD
+        constraint that turns batched admission off, and ``sp``/``ep``
+        prefill through other, collective-bearing programs), and head-room
+        under the in-flight cap (back-pressure: an admission flood spills
+        to the synchronous path, not onto the device's queue)."""
         if not (
             self._pipelined
             and self._pending is not None
-            and self.mesh is None
+            and self._batch_whole
         ):
             return False
         if len(self._inflight_admits) >= OVERLAP_MAX_INFLIGHT:
@@ -3094,7 +3094,7 @@ class InferenceEngine:
             )
             self.metrics.counter("ring_prefills")
             # Ring/sp prefill stays synchronous by design: it only exists
-            # on mesh engines (see _overlap_ok's rationale).
+            # on an ``sp`` mesh, which never overlaps (see _overlap_ok).
             self.metrics.counter("admit_sync_sessions")
             self._finish_sync_prefill(s, token, prompt, produced, skip)
             return

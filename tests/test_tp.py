@@ -174,25 +174,11 @@ def test_tp4_paged_engine_decodes_fused_and_matches_a_token_a_dispatch():
     token for token, across an admission in mid-run (five prompts, two
     slots), rows that stop on an EOS inside a window, and a table that grows
     (contexts pass the first rung of the ladder)."""
-    from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig
-    from distributed_llm_inference_tpu.engine.engine import InferenceEngine
     from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
 
-    params = llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
-
-    def engine(decode_steps):
-        return InferenceEngine(
-            CFG, params,
-            EngineConfig(max_batch_size=2, prefill_buckets=(8, 16),
-                         max_seq_len=64, dtype="float32",
-                         decode_windows=(16, 32, 64),
-                         decode_steps=decode_steps),
-            CacheConfig(kind="paged", page_size=8, num_pages=48,
-                        max_pages_per_session=8),
-            mesh_cfg=MeshConfig(tp=4),
-        )
-
-    fused, single = engine(None), engine(1)
+    fused = _mesh_engine(MeshConfig(tp=4), batch=2, decode_steps=None)
+    single = _mesh_engine(MeshConfig(tp=4), batch=2, decode_steps=1,
+                          pipelined=False)
     assert not fused.cache.use_kernel and fused.cache.has_tail
     assert fused.decode_steps == 16 and fused._pipelined
     assert single.decode_steps == 1 and not single._pipelined
@@ -210,3 +196,155 @@ def test_tp4_paged_engine_decodes_fused_and_matches_a_token_a_dispatch():
     stopped = single.generate(prompts, eos)
     assert len(stopped[0]) <= 21 and stopped != want
     assert fused.generate(prompts, eos) == stopped
+
+
+# -- overlapped admission under a tp-only mesh ---------------------------------
+
+_OVERLAP_CACHES = {
+    "paged": dict(kind="paged", page_size=8, num_pages=96,
+                  max_pages_per_session=8),
+    "paged_int8": dict(kind="paged", kv_quant="int8", page_size=8,
+                       num_pages=96, max_pages_per_session=8),
+}
+
+
+def _mesh_engine(mesh_cfg, cache="paged", overlap=True, batch=3, rng_seed=7,
+                 decode_steps=4, pipelined=True):
+    """A mesh engine, by default with short ticks (``decode_steps=4``, as
+    ``tests/test_engine.py:_overlap_engine``), so a session's budget spans
+    several ticks and an admission meets one in flight. ``overlap=False`` is
+    the same pipelined engine held to the synchronous admission."""
+    from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig
+    from distributed_llm_inference_tpu.engine.engine import InferenceEngine
+
+    params = llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
+    eng = InferenceEngine(
+        CFG, params,
+        EngineConfig(max_batch_size=batch, prefill_buckets=(8, 16),
+                     max_seq_len=64, dtype="float32",
+                     decode_windows=(16, 32, 64), decode_steps=decode_steps),
+        CacheConfig(**_OVERLAP_CACHES[cache]),
+        mesh_cfg=mesh_cfg, rng=jax.random.PRNGKey(rng_seed),
+    )
+    assert eng._pipelined is pipelined
+    if not overlap:
+        eng._overlap_ok = lambda: False
+    return eng
+
+
+def _overlap_prompts(n, seed):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, CFG.vocab_size, size=int(k)).tolist()
+            for k in rng.integers(3, 14, size=n)]
+
+
+def _staggered(eng, ps, opts, cancel_last=False):
+    """Two residents first, the rest once a pipelined tick is in flight (as
+    ``_churn_run`` does), so the later admissions land behind a tick. With
+    ``cancel_last`` the last prompt is cancelled right after the step that
+    admitted it: on an overlapping engine its prefill is in flight then."""
+    gids = [eng.submit(p, o) for p, o in zip(ps[:2], opts[:2])]
+    eng.step()  # the residents admit synchronously: no tick in flight yet
+    eng.step()  # the first pipelined tick is in flight
+    gids += [eng.submit(p, o) for p, o in zip(ps[2:], opts[2:])]
+    last, inflight = eng.sessions[gids[-1]], None
+    while eng.has_work():
+        eng.step()
+        if cancel_last and inflight is None and last.slot is not None:
+            inflight = last.prefill_inflight
+            eng.cancel(gids[-1])
+    return ([eng.sessions[g].generated for g in gids],
+            eng.metrics.snapshot(), inflight)
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("cache", sorted(_OVERLAP_CACHES))
+def test_tp4_overlapped_admission_matches_the_synchronous_flow(cache, sampled):
+    """Under a mesh whose only axis over 1 is ``tp`` an admission behind a
+    tick in flight defers its first token's fetch, and the streams are those
+    of the same engine with ``_overlap_ok = lambda: False``, token for token:
+    seven prompts over three slots with budgets that end rows on different
+    ticks and grow the table; then, with both engines' keys wound back to
+    the start, a row that stops on an EOS inside a window and a cancellation
+    while a prefill is in flight."""
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    kw = dict(temperature=0.9, top_p=0.95) if sampled else {}
+    budgets = (30, 9, 22, 13, 26, 11, 40)
+    ps = _overlap_prompts(len(budgets), seed=54)
+
+    on = _mesh_engine(MeshConfig(tp=4), cache, rng_seed=11)
+    off = _mesh_engine(MeshConfig(tp=4), cache, overlap=False, rng_seed=11)
+    assert on._batch_whole and on.decode_steps == 4
+    opts = [SamplingOptions(max_new_tokens=n, **kw) for n in budgets]
+    got, snap, _ = _staggered(on, ps, opts)
+    want, snap_off, _ = _staggered(off, ps, opts)
+    assert [len(g) for g in got] == list(budgets)
+    assert got == want
+    assert snap.get("admit_overlap_sessions", 0) > 0
+    assert snap_off.get("admit_overlap_sessions", 0) == 0
+    assert snap_off.get("admit_sync_sessions", 0) == len(ps)
+    assert snap.get("cache_growths", 0) >= 1
+    # the sixth token of the first stream (the first step of its second
+    # window) ends whichever stream meets it, and the last prompt is
+    # cancelled while its prefill is in flight
+    on.rng = off.rng = jax.random.PRNGKey(11)
+    eos = [SamplingOptions(max_new_tokens=n, eos_token_id=got[0][5], **kw)
+           for n in budgets]
+    got2, snap2, inflight = _staggered(on, ps, eos, cancel_last=True)
+    want2, _, inflight_off = _staggered(off, ps, eos, cancel_last=True)
+    assert inflight is True and inflight_off is False
+    assert got2[-1] == [] and len(want2[-1]) == 1  # the deferred token dropped
+    assert got2[:-1] == want2[:-1]
+    assert len(got2[0]) <= 6 and got2[0] == got[0][:len(got2[0])]
+    assert snap2["admit_overlap_sessions"] > snap["admit_overlap_sessions"]
+    assert not on._inflight_admits and not on._admit_pend.any()
+    assert on.allocator.free_count == off.allocator.free_count
+
+
+def test_tp4_admission_flood_spills_past_the_cap_and_matches(monkeypatch):
+    """A flood past ``OVERLAP_MAX_INFLIGHT`` spills to the synchronous path
+    under the mesh as on one chip (a mesh engine admits a row a dispatch, so
+    the second admission of a tick already meets the cap of 1), and the
+    streams do not move."""
+    from distributed_llm_inference_tpu.engine import engine as engine_mod
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    monkeypatch.setattr(engine_mod, "OVERLAP_MAX_INFLIGHT", 1)
+    ps = _overlap_prompts(6, seed=55)
+    opts = [SamplingOptions(max_new_tokens=14)] * 6
+
+    def run(overlap):
+        eng = _mesh_engine(MeshConfig(tp=4), overlap=overlap, batch=6)
+        return _staggered(eng, ps, opts)
+
+    (on, snap, _), (off, _, _) = run(True), run(False)
+    assert on == off
+    assert snap.get("admit_overlap_sessions", 0) > 0
+    assert snap.get("admit_overlap_spill", 0) > 0
+    assert snap.get("admit_sync_sessions", 0) > 2  # the residents and the spill
+
+
+@pytest.mark.parametrize("mesh_cfg,pipelined", [
+    (MeshConfig(dp=2, tp=2), True), (MeshConfig(pp=2), False),
+], ids=["dp2_tp2", "pp2"])
+def test_a_mesh_that_shards_the_batch_admits_synchronously(mesh_cfg, pipelined):
+    """Where the batch axis is sharded the deferred scatter has no place to
+    land: such an engine answers ``_overlap_ok()`` False with a tick in
+    flight (stood in for: a ``pp`` engine never pipelines), and every
+    admission of a staggered run, whose later ones meet a real tick in
+    flight on the ``dp`` mesh, is synchronous."""
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    ps = _overlap_prompts(4, seed=56)
+    opts = [SamplingOptions(max_new_tokens=n) for n in (24, 9, 12, 7)]
+    eng = _mesh_engine(mesh_cfg, batch=2, pipelined=pipelined)
+    assert not eng._batch_whole
+    eng._pipelined, eng._pending = True, ("a tick in flight",)
+    assert not eng._overlap_ok()
+    eng._pipelined, eng._pending = pipelined, None
+    got, snap, _ = _staggered(eng, ps, opts)
+    assert [len(g) for g in got] == [24, 9, 12, 7]
+    assert snap.get("admit_overlap_sessions", 0) == 0
+    assert snap.get("admit_sync_sessions", 0) == len(ps)
+    assert snap.get("admit_overlap_spill", 0) == 0
