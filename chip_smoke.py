@@ -31,7 +31,10 @@ kernels. In order:
 ``--chips 4`` runs instead, and only, the tensor-parallel path: the same
 widths in bf16 at the depth one chip can hold, ``tp=4`` through
 ``engine.generate`` — shards spread, all-reduces compiled in — against the
-same weights on one chip, by the same float32 yardstick.
+same weights on one chip, by the same float32 yardstick; and the tp=4
+engine's fresh-row prefill program (a scratch dense cache, whole pages
+installed) beside its page-table program, a pad width each, logits and
+pages by that yardstick (``phase_fresh_prefill``).
 
 Every line on stdout is one JSON object; the LAST is
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -511,9 +514,9 @@ def record_steps(engine) -> dict:
 
     recorded = {}
     steps = {
-        "prefill": "_prefill", "prefill_chunk": "_prefill_ns",
-        "prefill_batch": "_prefill_batch", "decode_scan": "_decode_k",
-        "decode": "_decode",
+        "prefill": "_prefill", "prefill_fresh": "_prefill_fresh",
+        "prefill_chunk": "_prefill_ns", "prefill_batch": "_prefill_batch",
+        "decode_scan": "_decode_k", "decode": "_decode",
     }
 
     def spec(x):
@@ -539,7 +542,8 @@ def record_steps(engine) -> dict:
         return call
 
     for name, attr in steps.items():
-        setattr(engine, attr, recorder(name, getattr(engine, attr)))
+        if getattr(engine, attr) is not None:   # no fresh program here
+            setattr(engine, attr, recorder(name, getattr(engine, attr)))
     return recorded
 
 
@@ -739,6 +743,143 @@ def as_close_to_float32(name, ours, theirs, gold, **info) -> None:
     )
 
 
+def phase_fresh_prefill(cfg, engine, wide, seed: int, dtype) -> None:
+    """The engine's two prefill programs for a FRESH one-piece prompt, a pad
+    width each: ``_prefill_fresh`` (the model over a scratch dense cache,
+    the K/V installed as whole pages) beside ``_prefill`` (the row's page
+    table: what every row took before there was a fresh program), on the
+    engine's own mesh, from copies of its own cache. The pages each leaves
+    and the greedy token come out of the engine's executables; they return
+    no logits, so those come from the two programs' bodies written out here
+    (as ``probe`` writes out the table path's), and the yardstick for both
+    is the table path with float32 weights over a float32 pool (``wide``):
+    the fresh program may sit no farther from it than the table program
+    does, in the logits and in the pages (``as_close_to_float32``'s rule;
+    two valid bf16 programs are about as far from each other as each is
+    from float32, so they are not compared directly)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_llm_inference_tpu.cache.dense import DenseKVCache
+    from distributed_llm_inference_tpu.engine.sampling import SamplingParams
+    from distributed_llm_inference_tpu.models import llama
+    from distributed_llm_inference_tpu.parallel import (
+        cache_pspecs, shard_pytree,
+    )
+
+    check("engine_has_the_fresh_row_program", engine._prefill_fresh is not None)
+    ps, row = engine.cache.page_size, 0
+    sp, key = SamplingParams.create(1), jax.random.PRNGKey(seed)
+
+    def table(params, tokens, cache, n_valid):
+        sub = cache.select_row(row)
+        logits, sub = llama.model_apply(
+            cfg, params, tokens, sub, n_valid[None], head="last"
+        )
+        return logits[0, 0], cache.merge_row(sub, row)
+
+    def fresh(params, tokens, cache, n_valid):
+        layers, _, kv_heads, _, head_dim = cache.k_pages.shape
+        scratch = DenseKVCache.create(
+            layers, 1, tokens.shape[1], kv_heads, head_dim, cache.k_pages.dtype
+        )
+        logits, scratch = llama.model_apply(
+            cfg, params, tokens, scratch, n_valid[None], head="last"
+        )
+        sub = cache.select_row(row).ingest_row(scratch.k, scratch.v, n_valid)
+        return logits[0, 0], cache.merge_row(sub, row)
+
+    def rel(x, y):
+        x, y = np.asarray(x, np.float64), np.asarray(y, np.float64)
+        return float(np.linalg.norm(x - y) / np.linalg.norm(y))
+
+    def held(cache, n):
+        """The row's K and V as ``[2, L, n, Hkv * D]``, and whether the
+        row's length and table are the prompt's and every page past its run
+        is as the pool was made (zero)."""
+        need = -(-n // ps)
+        out = []
+        ok = int(cache.lengths[row]) == n and np.array_equal(
+            np.asarray(cache.page_table[row, :need]), np.arange(1, need + 1)
+        )
+        for plane in (cache.k_pages, cache.v_pages):
+            a = np.asarray(jax.device_get(plane[:, 1:need + 1]), np.float32)
+            a = np.swapaxes(a, 2, 3).reshape(a.shape[0], need * ps, -1)
+            out.append(a[:, :n])
+            ok = ok and not bool(jnp.any(plane[:, need + 1:]))
+        return np.stack(out), ok
+
+    engine._ensure_capacity(engine.ecfg.max_seq_len)
+    like = engine.cache
+    slots = like.page_table.shape[1]
+    for index, bucket in enumerate(engine.plan.buckets):
+        n = bucket * 3 // 4 + 1             # a ragged last page
+        prompt = seeded_prompt(seed, 10 + index, n, cfg.vocab_size)
+        check("the_prompt_pads_to_its_bucket",
+              engine.plan.final_shape(n, engine._max_chunk()) == bucket)
+        tokens = jnp.zeros((1, bucket), jnp.int32).at[0, :n].set(
+            jnp.asarray(prompt, jnp.int32)
+        )
+        need, n_valid = -(-n // ps), jnp.int32(n)
+
+        def pool(dt, pages):
+            """A zeroed pool like the engine's, the row's pages assigned."""
+            made = type(like).create(
+                cfg.num_layers, like.lengths.shape[0], pages, ps, slots,
+                cfg.num_kv_heads, cfg.head_dim, dt,
+                use_kernel=like.use_kernel, use_ragged=like.use_ragged,
+            ).assign_pages(row, list(range(1, need + 1)))
+            return shard_pytree(made, engine.mesh, cache_pspecs(made))
+
+        with engine.mesh:
+            # the engine's executables, over a pool of its own pool's shape
+            # (they donate the cache they are given)
+            own = like.k_pages.shape[1]
+            tok_f, cache = engine._prefill_fresh(
+                engine.params, tokens, pool(dtype, own), row, n_valid, key, sp
+            )
+            kv_f, ok_f = held(cache, n)
+            tok_t, cache = engine._prefill(
+                engine.params, tokens, pool(dtype, own), row, n_valid, key, sp
+            )
+            kv_t, ok_t = held(cache, n)
+            small = need + 2
+            log_f = jax.jit(fresh)(
+                engine.params, tokens, pool(dtype, small), n_valid
+            )[0]
+            log_t = jax.jit(table)(
+                engine.params, tokens, pool(dtype, small), n_valid
+            )[0]
+            with jax.default_matmul_precision("highest"):
+                gold, cache = jax.jit(table)(
+                    wide, tokens, pool(jnp.float32, small), n_valid
+                )
+            kv_gold, _ = held(cache, n)
+            del cache
+        far = {
+            "logits": (rel(log_f, gold), rel(log_t, gold), rel(log_f, log_t)),
+            "pages": (rel(kv_f, kv_gold), rel(kv_t, kv_gold), rel(kv_f, kv_t)),
+        }
+        check(
+            f"fresh_prefill_as_close_to_float32_as_the_table_path_{bucket}",
+            np.all(np.isfinite(np.asarray(log_f, np.float32)))
+            and all(f <= 1.25 * t + 0.01 and t < 0.7
+                    for f, t, _ in far.values())
+            and ok_f and ok_t
+            # layer 0's V is the same sums in both programs: value for value
+            and np.array_equal(kv_f[1, 0], kv_t[1, 0]),
+            prompt_tokens=n, pad_width=bucket, table_slots=slots,
+            **{f"{k}_fresh_table_between": [round(x, 4) for x in v]
+               for k, v in far.items()},
+            pages_between_by_layer={
+                int(l): round(rel(kv_f[:, l], kv_t[:, l]), 4)
+                for l in sorted({0, 1, cfg.num_layers // 2, cfg.num_layers - 1})
+            },
+            greedy_tokens=[int(tok_f), int(tok_t), int(np.argmax(gold))],
+        )
+
+
 def phase_numerics(size: Size, cfg, params, engine, served: dict,
                    slots: int) -> None:
     import jax
@@ -862,7 +1003,8 @@ def run_one_chip(size: Size, seed: int, log: CompileLog, on_chip: bool):
 def run_four_chips(size: Size, seed: int, log: CompileLog, on_chip: bool):
     """tp=4 over the host's four chips against one chip, both at the depth
     one chip holds in bf16: a prefill and 32 decode steps through
-    ``engine.generate``. No other phase."""
+    ``engine.generate``, then the mesh engine's two prefill programs for a
+    fresh prompt (``phase_fresh_prefill``). No other phase."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -929,6 +1071,7 @@ def run_four_chips(size: Size, seed: int, log: CompileLog, on_chip: bool):
     wide = jax.tree.map(lambda x: np.asarray(x, np.float32), params)
     wide = shard_pytree(wide, engine.mesh, param_pspecs(wide))
     gold = probe(engine, cfg, wide, prompt, forced, slots, jnp.float32)
+    phase_fresh_prefill(cfg, engine, wide, seed, dtype)
     del engine, leaves, wide
     engine, _, single = run(None)
     one_chip = probe(engine, cfg, engine.params, prompt, forced, slots, dtype)

@@ -193,6 +193,9 @@ class _DenseRowsMixin(GatherAttendMixin):
         row's write offset (``lengths``)."""
         b, s = new_vals.shape[:2]
         t = layer_buf.shape[1]
+        # the buffer's dtype (a scratch in a page pool's dtype under a model
+        # that computes in another rounds as the pool's own write does)
+        new_vals = new_vals.astype(layer_buf.dtype)
         if s == 1:
             # Decode hot path: single-token contiguous write. Always in
             # bounds — the scheduler's capacity check guarantees

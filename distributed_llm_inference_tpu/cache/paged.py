@@ -89,19 +89,18 @@ def _page_write(pool, tile, page):
     )
 
 
-def _page_chunks(a, cap, slots, ps):
+def _page_chunks(a, cap, ps):
     """Chunk contiguous 1-row ring KV ``[L, 1, S, ...]`` into per-page
-    tiles ``[L, slots, heads, PS(, D)]`` (shared by the bf16 and int8 pool
-    ingests so the layout cannot drift between them)."""
+    tiles ``[L, ceil(S / PS), heads, PS(, D)]``, cropped at ``cap``
+    positions (shared by the bf16 and int8 pool ingests so the layout
+    cannot drift between them)."""
     a = a[:, 0]
-    s = a.shape[1]
-    if s >= cap:
-        a = jax.lax.slice_in_dim(a, 0, cap, axis=1)
-    else:
-        widths = [(0, 0)] * a.ndim
-        widths[1] = (0, cap - s)
-        a = jnp.pad(a, widths)
-    a = a.reshape(a.shape[0], slots, ps, *a.shape[2:])
+    s = min(a.shape[1], cap)
+    a = jax.lax.slice_in_dim(a, 0, s, axis=1)
+    widths = [(0, 0)] * a.ndim
+    widths[1] = (0, -s % ps)
+    a = jnp.pad(a, widths)
+    a = a.reshape(a.shape[0], -1, ps, *a.shape[2:])
     return jnp.swapaxes(a, 2, 3)
 
 
@@ -532,6 +531,16 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
     ) -> Tuple[jnp.ndarray, ...]:
         """Scatter new k/v into pages; gather each row's pages for attention.
 
+        Which rows come here: a prefill with history (a prefix hit, a later
+        chunk, the tail of a chunked prompt), the batched ``_prefill_rows``
+        and a one-token step outside the fused window, wherever no kernel
+        reads the pages in place. A FRESH row's one-piece prompt does not
+        (``fresh_install``): every position it may attend to is in the
+        dispatch's own ``k_new`` / ``v_new``, so the engine prefills it over
+        a scratch dense cache and installs whole pages (:meth:`ingest_row`)
+        where this scatters position by position (XLA rewrites the pool in a
+        layout of its own for that) and attends the whole table span.
+
         ``layer_state``: ``(layer_k, layer_v)``, each ``[P, Hkv, page_size,
         D]`` (one layer). The gather materializes
         ``[B, max_pages_per_session * page_size, …]`` per layer — the
@@ -616,15 +625,33 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
             **updated,
         )
 
+    @classmethod
+    def fresh_install(cls) -> bool:
+        """Whether a FRESH row (length 0) whose whole prompt is one piece
+        may prefill against the dispatch's own K/V (a scratch
+        ``DenseKVCache``) and install it through :meth:`ingest_row`: the
+        engine's fresh-row prefill asks this where no kernel reads the pages
+        in place (``use_ragged`` False). True for THIS class alone, whose
+        stored form is the model's rotated K and raw V; every subclass says
+        False until it says otherwise itself (int8 and its scales, a latent,
+        an index plane, two pools, a retention state: ``ingest_row`` makes
+        none of those from a dense scratch's K and V, or the prefill over
+        one would not read what the table path reads)."""
+        return cls is PagedKVCache
+
     def ingest_row(self, ks, vs, n_valid, first_slot=0):
-        """Install ring-prefill KV into the page pool (cf.
+        """Install a row's contiguous K/V into the page pool (cf.
         ``DenseKVCache.ingest_row``; 1-row ``select_row`` view — the pool
         is SHARED, so the pages land in place and ``merge_row`` writes the
-        table/length back): the contiguous ``[L, 1, S, Hkv, D]`` ring KV is
-        chunked into page-size pieces and scattered to this row's table
-        slots. Slots past the assigned run hold the null page; their junk
-        writes are never read (validity derives from ``lengths``), and
-        duplicate null-page indices are harmless for the same reason.
+        table/length back): the contiguous ``[L, 1, S, Hkv, D]`` K/V (keys
+        rotated) is cut into page tiles and written to this row's table
+        slots, the ``ceil(n_valid / PS)`` it owns and no other. Whose K/V:
+        ring prefill's, a disaggregated admission's shipped copy, and a
+        fresh row's own prefill where :meth:`fresh_install` holds (a row
+        with history, a later chunk, a prefix hit write position by
+        position instead: :meth:`update_and_gather`). Positions past
+        ``n_valid`` in the last page take the K/V's own (never read:
+        validity derives from ``lengths``).
 
         ``first_slot`` > 0 additionally diverts the HEAD of the run: slots
         below it map SHARED prefix pages whose content is already resident
@@ -637,7 +664,7 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
     def _ingest_planes(self, planes, n_valid, first_slot=0):
         """Shared ring-ingest write pattern (bf16 values and int8+scale
         planes alike): chunk each contiguous plane into page tiles and
-        scatter to this row's table slots, then set lengths. Batch-1 views
+        write them to this row's table slots, then set lengths. Batch-1 views
         ONLY — a multi-row cache would broadcast ``n_valid`` into rows
         whose pages received nothing (silent corruption), so fail loudly."""
         if self.lengths.shape[0] != 1:
@@ -647,30 +674,47 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
             )
         ps = self.page_size
         slots = self.page_table.shape[1]
-        # Scatter ONLY slots [first_slot, ceil(n_valid/page_size)) — the run
-        # this ingest actually owns. Slots outside it are diverted to the
-        # null page (page 0): past the run they hold the null page anyway,
-        # and below ``first_slot`` they map shared prefix pages that must
-        # not be overwritten with this ingest's copy of the same content.
-        n_owned = (jnp.asarray(n_valid, jnp.int32) + ps - 1) // ps
-        arange = jnp.arange(slots, dtype=jnp.int32)
-        owned = (arange >= jnp.asarray(first_slot, jnp.int32)) & (
-            arange < n_owned
+        # Write ONLY slots [first_slot, ceil(n_valid/page_size)) — the run
+        # this ingest actually owns: past it the table holds the null page,
+        # and below ``first_slot`` it maps shared prefix pages that must not
+        # be overwritten with this ingest's copy of the same content. A page
+        # at a time, each a contiguous ``[L, heads, PS(, D)]`` slab written
+        # into the donated pool in place, as ``_flush_rows`` writes its
+        # pages. (One scatter over all the table's slots would pad the K/V
+        # to the table's span first and write the unowned slots' tiles to
+        # the null page: 40 MB a plane a chip where a 512-token piece owns
+        # 8, at the tp=4 cell's 38 slots.)
+        names = tuple(planes)
+        tiles = tuple(
+            _page_chunks(planes[f], slots * ps, ps).astype(getattr(self, f).dtype)
+            for f in names
         )
-        pages = jnp.where(owned, self.page_table[0], 0)
-        updates = {
-            name: getattr(self, name).at[:, pages].set(
-                _page_chunks(a, slots * ps, slots, ps).astype(
-                    getattr(self, name).dtype
+        n_owned = jnp.minimum(
+            (jnp.asarray(n_valid, jnp.int32) + ps - 1) // ps,
+            tiles[0].shape[1],
+        )
+
+        def install(carry):
+            slot, pools = carry
+            page = self.page_table[0, slot]
+            return slot + 1, tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    pool, jax.lax.dynamic_slice_in_dim(t, slot, 1, axis=1),
+                    page, axis=1,
                 )
+                for pool, t in zip(pools, tiles)
             )
-            for name, a in planes.items()
-        }
+
+        _, pools = jax.lax.while_loop(
+            lambda carry: carry[0] < n_owned, install,
+            (jnp.asarray(first_slot, jnp.int32),
+             tuple(getattr(self, f) for f in names)),
+        )
         return self.replace(
             lengths=jnp.broadcast_to(
                 jnp.asarray(n_valid, jnp.int32), self.lengths.shape
             ),
-            **updates,
+            **dict(zip(names, pools)),
         )
 
     def copy_page(self, dst: int, src: int) -> "PagedKVCache":

@@ -67,8 +67,8 @@ _NO_REGION = contextlib.nullcontext()
 #: the step programs whose dispatches ``plan.note_dispatch`` records: what
 #: the dispatch clock wraps while it is armed (``_clock_turned``)
 _CLOCKED_PROGRAMS = (
-    "_prefill", "_prefill_ns", "_prefill_batch", "_prefill_batch_standalone",
-    "_decode", "_decode_k",
+    "_prefill", "_prefill_fresh", "_prefill_ns", "_prefill_batch",
+    "_prefill_batch_standalone", "_decode", "_decode_k",
 )
 #: the spans under a traced session's ``engine.first_token``, in the order
 #: of ``DispatchClock.first_token``'s pieces
@@ -703,11 +703,44 @@ class InferenceEngine:
             batch_mkw["block_fn"] = _pp_block_fn
 
         def _prefill_row(params, tokens, cache, row, n_valid, key, sp):
+            """A row's final (or only) prefill piece THROUGH ITS PAGE TABLE
+            (or its dense row): the cache writes the piece behind the row's
+            history and attends to both. Every row with history comes here
+            (a prefix hit, the tail of a chunked prompt), and every fresh
+            one where a kernel reads the pages in place or the cache cannot
+            install a contiguous K/V (``_prefill_row_fresh`` below)."""
             # ``row`` and ``n_valid`` are traced: one compile per prefill
             # bucket shape, not per (row, length) combination.
             sub = cache.select_row(row)
             logits, sub = llama.model_apply(
                 cfg, params, tokens, sub, n_valid[None], head="last", **mkw
+            )
+            cache = cache.merge_row(sub, row)
+            token = sample(logits[:, 0], key, sp)
+            return token[0], cache
+
+        def _prefill_row_fresh(params, tokens, cache, row, n_valid, key, sp):
+            """A FRESH row's whole prompt in one piece, where no kernel
+            reads the pages in place: the row is at position 0, so every
+            position it may attend to is in the dispatch's own K/V. The
+            model runs over a scratch dense cache as wide as the piece (S x
+            S causal attention), and the K/V it leaves is installed into
+            the row's pages as whole page tiles (``ingest_row``), where
+            ``_prefill_row`` scatters position by position into the pool
+            and attends the row's whole table span: ``_ring_prefill_row``
+            without the ring. Same logits but for the order of sums, the
+            same sample with the same key, the same pages."""
+            layers, _, kv_heads, _, head_dim = cache.k_pages.shape
+            scratch = DenseKVCache.create(
+                layers, 1, tokens.shape[1], kv_heads, head_dim,
+                cache.k_pages.dtype,
+            )
+            logits, scratch = llama.model_apply(
+                cfg, params, tokens, scratch, n_valid[None], head="last",
+                **mkw
+            )
+            sub = cache.select_row(row).ingest_row(
+                scratch.k, scratch.v, n_valid
             )
             cache = cache.merge_row(sub, row)
             token = sample(logits[:, 0], key, sp)
@@ -887,6 +920,23 @@ class InferenceEngine:
         dk = dict(donate_argnums=(2,)) if donate else {}
         self._prefill = self._with_mesh(jax.jit(_prefill_row, **dk))
         self._prefill_ns = self._with_mesh(jax.jit(_prefill_row_nosample, **dk))
+        # A fresh row's one-piece prompt prefills against its own K/V where
+        # the cache can install it (``fresh_install``) and no kernel reads
+        # the pages in place; None where every row goes through its table.
+        # Not under ``pp``: the staged program has not seen a scratch cache.
+        self._prefill_fresh = None
+        if (
+            isinstance(self.cache, PagedKVCache)
+            and self.cache.fresh_install()
+            and not self.cache.use_ragged
+            and not self._use_pp
+        ):
+            self._prefill_fresh = self._with_mesh(
+                jax.jit(_prefill_row_fresh, **dk)
+            )
+        # the (table slots, pad width) shapes each of the two is loaded at
+        # (``_pair_widths``)
+        self._fresh_widths, self._table_widths = set(), set()
         self._prefill_batch = jax.jit(_prefill_rows, **dk)
         self._prefill_batch_standalone = jax.jit(_prefill_rows_standalone, **dk)
         mdk = (
@@ -1711,7 +1761,10 @@ class InferenceEngine:
         self._noted_rows = ()
         if armed and not self._unclocked:
             for name in _CLOCKED_PROGRAMS:
-                fn = self._unclocked[name] = getattr(self, name)
+                fn = getattr(self, name)
+                if fn is None:      # this engine has no ``_prefill_fresh``
+                    continue
+                self._unclocked[name] = fn
                 wrapped = functools.partial(self._clocked, fn)
                 wrapped.__wrapped__ = fn
                 setattr(self, name, wrapped)
@@ -3119,10 +3172,20 @@ class InferenceEngine:
         self._note_prefill(
             "prefill", (1, width), [(offset, len(rest))], (s,)
         )
-        token, self.cache = self._prefill(
-            self.params, jnp.asarray(padded), self.cache, s.slot,
-            jnp.int32(len(rest)), self._next_key(), sp,
-        )
+        if offset == 0 and self._prefill_fresh is not None:
+            self._pair_widths(s, sp, width, self._fresh_widths)
+            self.metrics.counter("prefill_fresh_rows")
+            token, self.cache = self._prefill_fresh(
+                self.params, jnp.asarray(padded), self.cache, s.slot,
+                jnp.int32(len(rest)), self._next_key(), sp,
+            )
+        else:
+            self._pair_widths(s, sp, width, self._table_widths)
+            self.metrics.counter("prefill_table_rows")
+            token, self.cache = self._prefill(
+                self.params, jnp.asarray(padded), self.cache, s.slot,
+                jnp.int32(len(rest)), self._next_key(), sp,
+            )
         if self.window_allocator is not None:
             self._window_release(s, len(prompt))
         if self._overlap_ok():
@@ -3133,6 +3196,40 @@ class InferenceEngine:
             return
         self.metrics.counter("admit_sync_sessions")
         self._finish_sync_prefill(s, token, prompt, produced, skip)
+
+    def _pair_widths(self, s, sp, width, loaded) -> None:
+        """Keep the page-table program loaded at every shape (table slots,
+        pad width: what the engine's executables are keyed by) the fresh
+        program is loaded at, from the first row with history on. ``loaded``
+        is the set of the program about to run at ``width``
+        (``_fresh_widths`` or ``_table_widths``).
+
+        Where there is no fresh program every prompt loads ``_prefill_row``
+        at its width, so a row with history (a prefix hit, the tail of a
+        chunked prompt) finds it there. Where there is one, fresh prompts
+        load ``_prefill_row_fresh`` instead, and a tail's first visit to a
+        width would compile in service. So once a row with history has come,
+        each shape of the one is a shape of the other: the table program is
+        loaded by a dispatch of NO tokens through the row being admitted
+        (``n_valid`` 0 writes the null page alone and leaves the row's
+        length), outside the census and the clock and with the engine's key
+        unsplit, so the key order is as it was. A warm-up that sends every
+        pad width once and one prompt with history leaves every tail its
+        program; an engine that never sees such a row loads none."""
+        if self._prefill_fresh is None:
+            return
+        slots = self.cache.page_table.shape[1]
+        loaded.add((slots, width))
+        if not self._table_widths:
+            return
+        for shape in sorted(self._fresh_widths - self._table_widths):
+            if shape[0] != slots:       # another rung of the table: its turn
+                continue                # comes when a row is admitted there
+            self.cache = self._unclocked.get("_prefill", self._prefill)(
+                self.params, jnp.asarray(np.zeros((1, shape[1]), np.int32)),
+                self.cache, s.slot, jnp.int32(0), self.rng, sp,
+            )[1]
+            self._table_widths.add(shape)
 
     def _finish_sync_prefill(self, s, token, prompt, produced, skip):
         """A synchronous admission's tail: wait for the sampled first token
@@ -3262,6 +3359,7 @@ class InferenceEngine:
             self._note_prefill(
                 "prefill", (1, width), [(s.chunk_off, rest)], (s,)
             )
+            self.metrics.counter("prefill_table_rows")
             token, self.cache = self._prefill(
                 self.params, jnp.asarray(padded), self.cache, s.slot,
                 jnp.int32(rest), s.parked_key, sp,

@@ -45,6 +45,8 @@ METRICS = {
     "prefill_tokens": ("counter", "Prompt tokens prefilled"),
     "batched_prefills": ("counter", "Prefills served by batched dispatch"),
     "ring_prefills": ("counter", "Prefills served by the ring pipeline"),
+    "prefill_fresh_rows": ("counter", "Single-row final prefills over the dispatch's own K/V, installed as whole pages"),
+    "prefill_table_rows": ("counter", "Single-row final prefills through the row's page table or dense row"),
     "prefix_cached_tokens": ("counter", "Prompt tokens served from prefix cache"),
     # prefixstore: CoW sharing / host-DRAM spill tier / prefix routing
     "prefix_hit_rate": ("gauge", "Cumulative fraction of prompt tokens reused"),

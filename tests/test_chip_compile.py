@@ -710,3 +710,54 @@ def test_retention_prefill_kernel_at_brumbys_shapes(chip):
         s((1, e), jnp.bool_), s((1, e), jnp.bool_), s((1, e), jnp.bool_),
         s((1, HKV, width, D), F32), s((1, HKV, width), F32), s((1, HKV), F32),
     )
+
+
+def test_a_fresh_rows_install_stays_sharded_and_in_place_on_a_tp4_mesh(chip):
+    """``PagedKVCache.ingest_row`` as the fresh-row prefill of
+    ``mistral-7b-bf16-tp4.chat32`` gives it (``engine.py:_prefill_row_fresh``):
+    a 640-page bf16 pool sharded by kv head over the four chips of a v5e
+    2x2, a 38-slot table, a 2048-wide piece's K/V sharded as the pool. The
+    owned pages are written in place by a loop of dynamic-update-slices:
+    no collective, no copy of a pool plane (the per-position scatter's
+    relayout, PERF.md section 5, PR 55), no array as wide as the table's
+    span."""
+    import re
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_llm_inference_tpu.cache.paged import PagedKVCache
+    from distributed_llm_inference_tpu.parallel import cache_pspecs
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4, 1, 1),
+                ("dp", "pp", "tp", "sp", "ep"))
+    on = lambda spec: NamedSharding(mesh, spec)
+    rows, slots, pages, width = 32, 38, 640, 2048
+    cache = jax.eval_shape(lambda: PagedKVCache.create(
+        LAYERS, rows, pages, PS, slots, HKV, D, jnp.bfloat16))
+    cache = jax.tree.map(
+        lambda x, spec: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on(spec)),
+        cache, cache_pspecs(cache),
+    )
+    kv = jax.ShapeDtypeStruct(
+        (LAYERS, 1, width, HKV, D), jnp.bfloat16,
+        sharding=on(P(None, None, None, "tp", None)),
+    )
+    scalar = jax.ShapeDtypeStruct((), I32, sharding=on(P()))
+
+    def install(cache, k, v, row, n_valid):
+        sub = cache.select_row(row).ingest_row(k, v, n_valid)
+        return cache.merge_row(sub, row)
+
+    with mesh:
+        text = jax.jit(install, donate_argnums=(0,)).lower(
+            cache, kv, kv, scalar, scalar).compile().as_text()
+    results = [ln.split(" = ", 1)[1] for ln in text.splitlines() if " = " in ln]
+    assert not [r for r in results if re.match(r"\S+ (all-gather|all-reduce|all-to-all|collective-permute)", r)]
+    plane = rf"bf16\[({LAYERS},)?{pages},{HKV // 4},{PS},{D}\]"
+    assert not [r for r in results if re.match(plane + r"\S* copy\(", r)]
+    assert not [r for r in results if re.match(rf"\S*\[[0-9,]*\b{slots * PS}\b", r)]
+    assert [r for r in results if re.match(plane + r"\S* dynamic-update-slice\(", r)]
+    assert "input_output_alias" in text and " while(" in text

@@ -383,3 +383,115 @@ def test_kernel_less_flush_writes_what_the_scatter_writes(PS, K, SLOTS):
     np.testing.assert_array_equal(
         np.asarray(got.v_pages[:, 1:]), np.asarray(want_v[:, 1:])
     )
+
+
+# (page size, piece width S, valid tokens, the row's mapped slots of 6)
+_FRESH_INSTALLS = {
+    "page-aligned": (8, 16, 16, 2),
+    "ragged-last-page": (8, 32, 19, 3),
+    "no-tokens": (8, 16, 0, 2),
+    "slots-past-the-run": (4, 16, 3, 5),
+    "a-slot-left-unmapped": (8, 32, 21, 2),
+    "piece-wider-than-the-table": (4, 32, 24, 6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FRESH_INSTALLS))
+def test_a_fresh_rows_install_leaves_what_the_scatter_leaves(case):
+    """A fresh row's K/V installed as whole page tiles (``ingest_row``, what
+    the engine's ``_prefill_row_fresh`` does with its scratch cache) against
+    the position-by-position ``_scatter`` of the same K/V at positions
+    ``0..n``: the table and ``lengths`` equal, every page but the null page
+    equal value for value at the positions the row owns (a page-aligned run,
+    a ragged last page, no token at all), every page past the run untouched
+    though the table maps it and the piece is wider, and a slot the table
+    leaves at 0 diverted to the null page in both. Past ``n`` in the last
+    page the install leaves the K/V's own where the scatter leaves the
+    pool's: no reader looks there (``lengths``)."""
+    PS, S, n, mapped = _FRESH_INSTALLS[case]
+    L, B, H, D, P, SLOTS, row = 2, 3, 2, 8, 24, 6, 1
+    cache = PagedKVCache.create(L, B, P, PS, SLOTS, H, D, jnp.float32)
+    cache = cache.replace(
+        k_pages=jax.random.normal(jax.random.PRNGKey(1), cache.k_pages.shape),
+        v_pages=jax.random.normal(jax.random.PRNGKey(2), cache.v_pages.shape),
+    )
+    table = np.zeros((B, SLOTS), np.int32)
+    table[0, :3] = (1, 2, 3)                      # a neighbour's pages
+    table[row, :mapped] = np.arange(7, 7 + mapped)
+    cache = cache.replace(page_table=jnp.asarray(table))
+    k, v = (
+        jax.random.normal(jax.random.PRNGKey(s), (L, 1, S, H, D))
+        for s in (3, 4)
+    )
+
+    @jax.jit
+    def install(cache, k, v):
+        sub = cache.select_row(row).ingest_row(k, v, jnp.int32(n))
+        return cache.merge_row(sub, row)
+
+    @jax.jit
+    def scatter(cache, k, v):
+        sub = cache.select_row(row)
+        q_pos, num_new = sub.q_positions(S), jnp.full((1,), n, jnp.int32)
+        new_k, new_v = jax.vmap(
+            lambda lk, lv, kk, vv: sub._scatter(lk, lv, kk, vv, q_pos, num_new)
+        )(sub.k_pages, sub.v_pages, k, v)
+        sub = sub.replace(k_pages=new_k, v_pages=new_v).advance(num_new)
+        return cache.merge_row(sub, row)
+
+    got, want = install(cache, k, v), scatter(cache, k, v)
+    np.testing.assert_array_equal(np.asarray(got.page_table), table)
+    np.testing.assert_array_equal(
+        np.asarray(got.lengths), np.asarray(want.lengths)
+    )
+    assert got.lengths.tolist() == [0, n, 0]
+    owned = min(-(-n // PS), SLOTS)
+    last = table[row, owned - 1] if n % PS and owned else None
+    for plane, ref, src in (("k_pages", want.k_pages, k),
+                            ("v_pages", want.v_pages, v)):
+        new, ref = np.asarray(getattr(got, plane)), np.array(ref)
+        if last:                    # the ragged page's positions past n
+            at = (owned - 1) * PS
+            ref[:, last, :, n % PS:] = np.swapaxes(
+                np.asarray(src)[:, 0, at + n % PS:at + PS], 1, 2
+            )
+        np.testing.assert_array_equal(new[:, 1:], ref[:, 1:])
+        untouched = [p for p in range(1, P) if p not in table[row, :owned]]
+        np.testing.assert_array_equal(
+            new[:, untouched], np.asarray(getattr(cache, plane))[:, untouched]
+        )
+
+
+def _subclasses(cls):
+    for sub in cls.__subclasses__():
+        yield sub
+        yield from _subclasses(sub)
+
+
+def test_only_the_value_dtype_pool_installs_a_fresh_rows_kv():
+    """``fresh_install()`` (what the engine's fresh-row prefill asks) is True
+    for the value-dtype pool ALONE: every subclass there is (int8, latent,
+    indexed, two-pool, the window views, retention, and the classes their
+    factories make) says False without a line of its own, and so does one
+    written later, until it says otherwise itself."""
+    from distributed_llm_inference_tpu.cache import latent, paged, retention
+
+    made = [
+        retention.retention_cache_class(16, 1e-6),
+        retention.retention_cache_class(16, 1e-6, quantized=True),
+        paged.indexed_cache_class(False, 8), paged.indexed_cache_class(True, 8),
+    ]
+
+    class Later(PagedKVCache):
+        pass
+
+    assert PagedKVCache.fresh_install() is True
+    others = set(_subclasses(PagedKVCache))
+    assert others >= {
+        paged.QuantizedPagedKVCache, paged.IndexedPagedKVCache,
+        paged.TwoPoolPagedKVCache, paged._WindowPagedKVCache,
+        latent.LatentPagedKVCache, retention.RetentionPagedKVCache,
+        Later, *made,
+    }
+    for cls in others:
+        assert cls.fresh_install() is False, cls.__name__

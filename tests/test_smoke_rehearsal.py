@@ -62,3 +62,24 @@ def test_rehearsal_lines(tmp_path):
                 assert key.startswith("smoke_reading_") or key == "compile_s"
     with open(tmp_path / "smoke.jsonl") as f:
         assert len(f.read().splitlines()) == len(lines) - 1
+
+
+def test_four_chip_rehearsal_holds_the_fresh_prefill_to_float32(tmp_path):
+    """``--chips 4``: the tp=4 engine's fresh-row prefill program beside its
+    page-table program and the float32 table path, a pad width each (on the
+    CPU in float32 the three agree; on the chip this is the bf16 check), and
+    the fresh program among the recorded steps with its all-reduces."""
+    proc = _run(["--rehearse-cpu", "--chips", "4", "--seed", "4"], tmp_path)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = [json.loads(line) for line in proc.stdout.splitlines()]
+    assert lines[-1]["ok"] is True and lines[-1]["device"]["count"] == 4
+    checks = {d["check"]: d for d in lines if "check" in d}
+    assert all(c["ok"] for c in checks.values())
+    assert "prefill_fresh_holds_all_reduces" in checks
+    assert "tp4_logits_as_close_to_float32_as_one_chip" in checks
+    for width in (8, 16, 32):
+        c = checks[f"fresh_prefill_as_close_to_float32_as_the_table_path_{width}"]
+        assert c["pad_width"] == width and c["prompt_tokens"] > width // 2
+        assert max(c["logits_fresh_table_between"]) < 1e-3
+        assert max(c["pages_fresh_table_between"]) < 1e-3
+        assert len(set(c["greedy_tokens"])) == 1

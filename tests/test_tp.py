@@ -348,3 +348,104 @@ def test_a_mesh_that_shards_the_batch_admits_synchronously(mesh_cfg, pipelined):
     assert snap.get("admit_overlap_sessions", 0) == 0
     assert snap.get("admit_sync_sessions", 0) == len(ps)
     assert snap.get("admit_overlap_spill", 0) == 0
+
+
+# -- a fresh row's prefill against its own K/V ---------------------------------
+
+def _fresh_engine():
+    """A ``tp=4`` value-dtype paged engine with prefix caching: it has the
+    fresh-row program."""
+    from distributed_llm_inference_tpu.config import CacheConfig, EngineConfig
+    from distributed_llm_inference_tpu.engine.engine import InferenceEngine
+
+    return InferenceEngine(
+        CFG, llama.init_params(CFG, jax.random.PRNGKey(0), jnp.float32),
+        EngineConfig(max_batch_size=3, prefill_buckets=(8, 16),
+                     max_seq_len=64, dtype="float32",
+                     decode_windows=(16, 32, 64), decode_steps=4),
+        CacheConfig(prefix_caching=True, **_OVERLAP_CACHES["paged"]),
+        mesh_cfg=MeshConfig(tp=4), rng=jax.random.PRNGKey(55),
+    )
+
+
+@pytest.fixture(scope="module")
+def fresh_pair():
+    """One engine that takes the fresh-row program and one held to the
+    page-table path (what it was before there was one), for the case
+    below."""
+    on, off = _fresh_engine(), _fresh_engine()
+    assert on._prefill_fresh is not None and not on.cache.use_ragged
+    off._prefill_fresh = None
+    return on, off
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_tp4_fresh_rows_prefill_against_their_own_kv_and_match(fresh_pair, sampled):
+    """A fresh one-piece prompt a bucket (5 and 13 tokens), a prompt of two
+    pieces (30 tokens past the 16-wide bucket: a chunk, then a tail with
+    history) and a prefix hit (the 13-token prompt's first page, then 6
+    tokens of its own) on the ``tp=4`` engine: the two fresh ones take
+    ``_prefill_row_fresh`` (their K/V attended in place and installed as
+    whole pages), the two others the page-table path, and every stream is
+    that of the same engine without the fresh program, token for token,
+    greedy and sampled; the later admissions still ride behind a tick."""
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    on, off = fresh_pair
+    rng = np.random.default_rng(55 + sampled)   # no page of the other case's
+    draw = lambda n: rng.integers(0, CFG.vocab_size, size=n).tolist()
+    five, thirteen, thirty = draw(5), draw(13), draw(30)
+    ps = [thirteen, five, thirty, thirteen[:8] + draw(6)]
+    kw = dict(temperature=0.9, top_p=0.95) if sampled else {}
+    opts = [SamplingOptions(max_new_tokens=n, **kw) for n in (14, 9, 11, 12)]
+    on.rng = off.rng = jax.random.PRNGKey(7)
+    before = [e.metrics.snapshot() for e in (on, off)]
+    got, snap, _ = _staggered(on, ps, opts)
+    want, snap_off, _ = _staggered(off, ps, opts)
+    assert [len(g) for g in got] == [14, 9, 11, 12]
+    assert got == want
+    moved = lambda s, b, k: s.get(k, 0) - b.get(k, 0)
+    assert moved(snap, before[0], "prefill_fresh_rows") == 2
+    assert moved(snap, before[0], "prefill_table_rows") == 2
+    assert moved(snap_off, before[1], "prefill_fresh_rows") == 0
+    assert moved(snap_off, before[1], "prefill_table_rows") == 4
+    assert moved(snap, before[0], "prefix_cached_tokens") == 8
+    assert moved(snap, before[0], "admit_overlap_sessions") > 0
+    assert on.allocator.free_count == off.allocator.free_count
+
+
+def test_tp4_a_tail_finds_the_table_program_loaded_at_every_fresh_width():
+    """Fresh prompts load ``_prefill_row_fresh`` at their pad widths and,
+    while no row with history has come, no page-table program at all. The
+    first chunked prompt (a 16-token piece, then a 14-token tail at width
+    16) loads ``_prefill_row`` at its own width AND at the other width the
+    fresh program has (8); a width first seen fresh afterwards is paired at
+    once. So a later tail of 3 tokens (width 8) compiles nothing: what a
+    warm-up of every width once and one chunked prompt leaves a window. The
+    table is pinned at its widest, as a warm-up's anchor pins it (a program
+    is keyed by the table's width too)."""
+    from distributed_llm_inference_tpu.engine.sampling import SamplingOptions
+
+    eng = _fresh_engine()
+    eng._ensure_capacity(eng.ecfg.max_seq_len)
+    slots = eng.cache.page_table.shape[1]
+    table, fresh = eng._prefill.__wrapped__, eng._prefill_fresh.__wrapped__
+    rng = np.random.default_rng(5)
+    draw = lambda n: rng.integers(0, CFG.vocab_size, size=n).tolist()
+    opts = SamplingOptions(max_new_tokens=3)
+    eng.generate([draw(5)], opts)
+    assert (eng._fresh_widths, eng._table_widths) == ({(slots, 8)}, set())
+    assert (fresh._cache_size(), table._cache_size()) == (1, 0)
+    before = eng.metrics.snapshot()
+    eng.generate([draw(30)], opts)
+    assert eng._table_widths == {(slots, 8), (slots, 16)}
+    assert eng._fresh_widths == {(slots, 8)} and table._cache_size() == 2
+    eng.generate([draw(13)], opts)              # 16 wide, fresh: paired already
+    assert eng._fresh_widths == eng._table_widths
+    eng.generate([draw(19)], opts)              # a tail of 3 at width 8
+    assert (fresh._cache_size(), table._cache_size()) == (2, 2)
+    assert eng.cache.page_table.shape[1] == slots
+    snap = eng.metrics.snapshot()
+    moved = lambda k: snap.get(k, 0) - before.get(k, 0)
+    # the loads are no row's prefill: neither counter saw them
+    assert (moved("prefill_fresh_rows"), moved("prefill_table_rows")) == (1, 2)

@@ -549,11 +549,20 @@ def _records(engine, ids):
         return [t for t in engine.flight._ring if t["tick"] in ids]
 
 
+def _programs(engine):
+    """The step programs this engine has (``_prefill_fresh`` is None where
+    every row prefills through its table), by attribute name."""
+    return {
+        name: getattr(engine, name) for name in eng_mod._CLOCKED_PROGRAMS
+        if getattr(engine, name) is not None
+    }
+
+
 def _wrapped(engine):
     """The step programs that stand behind a ``_clocked`` wrapper."""
     return [
-        name for name in eng_mod._CLOCKED_PROGRAMS
-        if not hasattr(getattr(engine, name), "lower")
+        name for name, fn in _programs(engine).items()
+        if not hasattr(fn, "lower")
     ]
 
 
@@ -597,9 +606,7 @@ def test_a_read_of_the_ticks_arms_the_clock_for_a_lease():
     )
     assert not clock.armed                      # the drive thread's to do
     clock.lease(600.0)                          # the test's own: no race
-    programs = {
-        name: getattr(eng, name) for name in eng_mod._CLOCKED_PROGRAMS
-    }
+    programs = _programs(eng)
     assert not _wrapped(eng)
     ids = _serve(eng)
     assert clock.armed

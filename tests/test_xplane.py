@@ -97,6 +97,41 @@ def test_a_trace_without_a_device_plane_reduces_to_the_host_alone():
     assert out["ticks"] == [7, 8] and not out["ops_ns"]
 
 
+def test_ops_inside_lists_a_programs_operations_a_compiled_shape_at_a_time():
+    """``ops_inside`` (``tools/xplane_profile.py --inside``): device 0's
+    operations inside the executions of the programs whose name holds the
+    word, by module fingerprint (a prefill's pad widths come apart), the
+    containers left out and the other programs' operations with them."""
+    wide = "%fusion.1 = f32[1,2048,2432]{2,1,0} fusion(%p)"
+    copy = "%copy.2 = bf16[640,2,64,128]{3,1,2,0} copy(%pool)"
+    ops = [
+        ev("%while.3 = (s32[]) while(%t)", 100, 60),        # a container
+        ev(wide, 100, 40), ev(copy, 140, 20),               # wide run 1
+        ev("%fusion.1 = f32[1,512,2432]{2,1,0} fusion(%p)", 200, 10),
+        ev(wide, 300, 44), ev(copy, 344, 16),               # wide run 2
+        ev("%fusion.9 = f32[2]{0} fusion(%z)", 400, 30),    # the decode scan's
+    ]
+    modules = [
+        ev("jit__prefill_row(11)", 100, 62), ev("jit__prefill_row(22)", 200, 12),
+        ev("jit__prefill_row(11)", 300, 61), ev("jit__decode_scan(5)", 400, 30),
+    ]
+    from tools.xplane_profile import ops_inside
+
+    out = ops_inside(
+        [HOST, device(1, ops, modules), device(0, ops, modules)], "_prefill_row"
+    )
+    assert sorted(out) == ["jit__prefill_row(11)", "jit__prefill_row(22)"]
+    wide_runs, narrow = out["jit__prefill_row(11)"], out["jit__prefill_row(22)"]
+    assert (wide_runs["runs"], wide_runs["ns"]) == (2, 123)
+    assert wide_runs["ops_ns"] == {"fusion:fusion.1": 84, "copy:copy.2": 36}
+    assert wide_runs["op_counts"] == {"fusion:fusion.1": 2, "copy:copy.2": 2}
+    assert wide_runs["text"]["copy:copy.2"] == copy     # the shapes say what
+    assert (narrow["runs"], narrow["ns"]) == (1, 12)
+    assert narrow["ops_ns"] == {"fusion:fusion.1": 10}
+    assert "[1,512,2432]" in narrow["text"]["fusion:fusion.1"]
+    assert ops_inside([HOST], "_prefill_row") == {}
+
+
 def test_short_op_name():
     text = "%closed_call.41 = bf16[32,8,4,128]{3,2,1,0} custom-call(%a, %b)"
     assert xplane.short_op_name(text) == "custom-call:closed_call.41"

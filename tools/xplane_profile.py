@@ -4,6 +4,7 @@ host's while the device waited.
 
     python tools/xplane_profile.py <file.xplane.pb | trace dir> [--describe [word ...]]
     python tools/xplane_profile.py <file.xplane.pb | trace dir> --ticks <ticks.json> [--all]
+    python tools/xplane_profile.py <file.xplane.pb | trace dir> --inside <word> [--top N]
 
 ``--describe`` lists every plane and line with a few events and their stats
 instead, and every distinct event that holds one of the words: where a
@@ -19,7 +20,14 @@ the device's idle time inside the gaps, in all and by host phase, the clock's
 beside the trace's; ``--all`` adds a line a dispatch. Where the two part, the
 program's numbers (``engine_device_seconds_*``, ``engine_device_idle_*``) are
 not the device's: this is the tool that says so.
+
+``--inside`` lists device 0's operations inside the executions of every
+program whose name holds the word (``_prefill_row``), a compiled shape at a
+time (``ops_inside``): ms a run, events a run and the operation's HLO
+text, which carries the shapes that say what it is.
 """
+import bisect
+import collections
 import json
 import os
 import sys
@@ -96,6 +104,59 @@ def clock_report(joined, every=False):
     return out
 
 
+def ops_inside(planes, word):
+    """Device 0's operations INSIDE the executions of every program whose
+    module event's name holds ``word``, a program at a time (a module event
+    is named ``<jit name>(<fingerprint>)``: one a compiled shape, so a
+    prefill's pad widths come apart). Of each: ``runs``, ``ns`` (the module
+    events' durations summed), and an operation's summed duration
+    (``ops_ns``), count (``op_counts``) and whole HLO text as the trace names
+    it (``text``: the result's shape, the opcode, the operands), containers
+    left out as in ``xplane.reduce_planes``."""
+    found = xplane._device_planes(planes)
+    if not found:
+        return {}
+    ops = sorted(xplane._line(found[0], xplane.OPS_LINE), key=lambda e: e[1])
+    starts = [e[1] for e in ops]
+    out = {}
+    for name, start, dur, _ in xplane._line(found[0], xplane.MODULES_LINE):
+        if word not in name:
+            continue
+        prog = out.setdefault(name, {
+            "runs": 0, "ns": 0, "ops_ns": collections.Counter(),
+            "op_counts": collections.Counter(), "text": {},
+        })
+        prog["runs"] += 1
+        prog["ns"] += dur
+        lo = bisect.bisect_left(starts, start)
+        for text, _, d, _ in ops[lo:bisect.bisect_left(starts, start + dur)]:
+            op = xplane.short_op_name(text)
+            if op.partition(":")[0] in xplane.CONTAINERS:
+                continue
+            prog["ops_ns"][op] += d
+            prog["op_counts"][op] += 1
+            prog["text"].setdefault(op, text)
+    return out
+
+
+def inside_report(programs, top=60, width=260):
+    """The lines ``--inside`` prints for one :func:`ops_inside` result."""
+    out = []
+    for name, p in sorted(programs.items(), key=lambda kv: -kv[1]["ns"]):
+        runs = p["runs"]
+        listed = sum(p["ops_ns"].values())
+        out.append(
+            f"program {name}: {runs} runs, {p['ns'] / runs / 1e6:.3f} ms a "
+            f"run, its operations {listed / runs / 1e6:.3f} ms a run"
+        )
+        for op, ns in p["ops_ns"].most_common(top):
+            out.append(
+                f"{ns / runs / 1e6:9.4f} ms  x{p['op_counts'][op] / runs:<6g} "
+                f"{p['text'][op][:width]}"
+            )
+    return out
+
+
 def main(argv):
     path = argv[0]
     if os.path.isdir(path):
@@ -107,6 +168,12 @@ def main(argv):
             ticks = ticks["ticks"]
         joined = xplane.join_dispatches(xplane.read_planes(path), ticks)
         print("\n".join(clock_report(joined, every="--all" in argv[1:])))
+    elif "--inside" in argv[1:]:
+        top = int(argv[argv.index("--top") + 1]) if "--top" in argv[1:] else 60
+        programs = ops_inside(
+            xplane.read_planes(path), argv[argv.index("--inside") + 1]
+        )
+        print("\n".join(inside_report(programs, top)))
     elif "--describe" in argv[1:]:
         words = [a for a in argv[1:] if a != "--describe"]
         print("\n".join(xplane.describe(path, like=words)))
