@@ -174,6 +174,23 @@ class RetentionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LoopConfig:
+    """Layers that run several times (Ouro's looped stack, arXiv:2510.25741):
+    every token crosses the WHOLE stack ``steps`` times, each lap with the
+    same weights and its own rows of the cache (lap ``t`` of layer ``l``
+    attends to what lap ``t`` of layer ``l`` wrote: cache row ``t x layers +
+    l``). Each sublayer's output is normed before it is added, the final norm
+    ends every lap and its output enters the next, and one gate behind each
+    lap says with which weight a position would leave there; the head reads
+    the first lap at which the summed weights reach ``exit_threshold`` (the
+    last, at the published threshold of 1). Every lap runs whatever the gate
+    says (``models/llama.py``)."""
+
+    steps: int = 4
+    exit_threshold: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSegment:
     """A run of consecutive decoder layers that are all alike: one
     ``lax.scan`` over one stacked parameter dict (``params[key]``). The
@@ -298,9 +315,25 @@ class ModelConfig:
     # Power retention in place of softmax attention, in every layer
     # (``model_type`` "brumby"); None = softmax attention.
     retention: Optional[RetentionConfig] = None
+    # Layers that run several times (``total_ut_steps``: ``model_type``
+    # "ouro"), with an exit gate behind each lap; None = once.
+    loop: Optional[LoopConfig] = None
     # Model family tag ("llama", "mistral", "qwen2", "mixtral", "mla",
-    # "keye_vl2", "exaone_moe", "glm_moe_dsa", "xing4_0", "brumby").
+    # "keye_vl2", "exaone_moe", "glm_moe_dsa", "xing4_0", "brumby", "ouro").
     family: str = "llama"
+
+    @property
+    def loop_steps(self) -> int:
+        """Laps a token makes over the stack: 1 but for a looped model."""
+        return 1 if self.loop is None else self.loop.steps
+
+    @property
+    def cache_layers(self) -> int:
+        """THE count of a cache's layers: a lap of a layer has rows of its
+        own, so a looped stack caches ``loop_steps x num_layers`` layers of K
+        and V for ``num_layers`` layers of weights. What every cache
+        constructor takes where the weights take ``num_layers``."""
+        return self.loop_steps * self.num_layers
 
     @property
     def q_per_kv(self) -> int:
@@ -489,6 +522,8 @@ class ModelConfig:
             window = extra.pop("sliding_window")
         if model_type == "brumby":
             extra, window = _brumby_keys(get), None
+        if model_type == "ouro":
+            extra, window = _ouro_keys(get), None
         # the ROUTER's width, where the block's key counts a share held here
         experts = extra.pop("num_experts", experts)
         # A block may nest its RoPE keys (``rope_parameters``: theta and
@@ -568,6 +603,26 @@ def _brumby_keys(get) -> dict:
         qk_norm=True,
         retention=RetentionConfig(eps=float(get("rms_norm_eps", 1e-6))),
     )
+
+
+def _ouro_keys(get) -> dict:
+    """The keys of an ``ouro`` ``config.json`` (Ouro-2.6B): the dense block
+    run ``total_ut_steps`` times with an exit gate behind each lap read at
+    ``early_exit_threshold``. The published keys state neither the norms of
+    the sublayers' outputs, nor that the final norm feeds the next lap, nor
+    the gate's form (a configuration file lists them as assumed). A window
+    the block switches on is refused by the key's name."""
+    if get("use_sliding_window", False):
+        _refuse(
+            get, "use_sliding_window",
+            "every layer of a looped stack attends to its lap's whole context",
+        )
+    if any(t != "full_attention" for t in get("layer_types", None) or ()):
+        _refuse(get, "layer_types", "full_attention in every layer")
+    return dict(loop=LoopConfig(
+        steps=int(get("total_ut_steps", 4)),
+        exit_threshold=float(get("early_exit_threshold", 1.0)),
+    ))
 
 
 _ATTENTION_KINDS = {"sliding_attention": "window", "full_attention": "full"}

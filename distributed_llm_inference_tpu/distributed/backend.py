@@ -96,6 +96,14 @@ class BlockBackend:
                 "and no per-session state that is zeroed at admission and "
                 "folded at page boundaries"
             )
+        if cfg.loop is not None:
+            from ..models.llama import LOOP_NEEDS_ONE_STAGE
+
+            raise ValueError(
+                f"family {cfg.family!r} (ModelConfig.loop) is not served by "
+                f"block workers (layers {first_layer}:{last_layer}): "
+                + LOOP_NEEDS_ONE_STAGE
+            )
         self.mesh = None
         self._shard_cache_fn = None
         tp = 1

@@ -378,6 +378,35 @@ class InferenceEngine:
             ):
                 if bad:
                     raise ValueError(f"{name} does not compose with {what}")
+        if cfg.loop is not None:
+            # Layers that run several times: the laps live inside the model
+            # programs (models/llama.py), over a cache of ``cache_layers``.
+            # What passes a hidden state down its stages ONCE is refused
+            # here by the mechanism's name.
+            name = f"family {cfg.family!r} (ModelConfig.loop)"
+            if mesh_cfg is not None and mesh_cfg.pp > 1:
+                raise ValueError(
+                    f"{name} does not compose with pp={mesh_cfg.pp}: "
+                    + llama.LOOP_NEEDS_ONE_STAGE
+                )
+            for bad, what in (
+                (mesh_cfg is not None and mesh_cfg.sp > 1,
+                 "sp ring prefill (parallel/ring.py runs the stack once "
+                 "over its own K/V)"),
+                (draft is not None,
+                 "a draft model (the verify pass and its rollback have not "
+                 "seen a cache of laps x layers)"),
+            ):
+                if bad:
+                    raise ValueError(f"{name} does not compose with {what}")
+            # (laps, layers, the layers' stored bytes: what a lap reads)
+            self.plan.loop = (
+                cfg.loop.steps, cfg.num_layers,
+                sum(
+                    leaf.nbytes for seg in cfg.segments
+                    for leaf in jax.tree.leaves(self.params[seg.key])
+                ),
+            )
         self.plan.latent = self._latent
         # The census walks the stack's attention kinds: (window, layers) a
         # kind. A stack of ONE kind counts one layer, as it always did
@@ -432,7 +461,7 @@ class InferenceEngine:
             self._windows = self._window_ladder()
             first = self._windows[0] if self._windows else self.ecfg.max_seq_len
             self.cache = cache_cls.create(
-                cfg.num_layers, b, first, cfg.num_kv_heads,
+                cfg.cache_layers, b, first, cfg.num_kv_heads,
                 cfg.head_dim, dtype, **create_kw,
             )
             self.allocator = None
@@ -474,7 +503,7 @@ class InferenceEngine:
                     )
                     more["dtype"] = dtype
                 self.cache = latent_cls.create(
-                    cfg.num_layers, b, cc.num_pages, cc.page_size,
+                    cfg.cache_layers, b, cc.num_pages, cc.page_size,
                     self._first_slots, 1, cfg.latent.lat_dim,
                     use_kernel=self._use_pallas,
                     use_ragged=_sel.use_ragged, **more,
@@ -488,7 +517,7 @@ class InferenceEngine:
                     paged_cls = indexed_cache_class(
                         cc.kv_quant == "int8", cfg.sparse.index_dim
                     )
-                pool_layers, more = cfg.num_layers, {}
+                pool_layers, more = cfg.cache_layers, {}
                 if self._retention or two_pools:
                     # A ROLLING pool (``_pool_reach``): a row holds the
                     # pages its next dispatch reads or writes, whatever its
@@ -590,13 +619,13 @@ class InferenceEngine:
         elif cc.kind == "sink":
             if cc.kv_quant == "int8":
                 self.cache = QuantizedSinkKVCache.create(
-                    cfg.num_layers, b, cc.window_length, cc.num_sink_tokens,
+                    cfg.cache_layers, b, cc.window_length, cc.num_sink_tokens,
                     cfg.num_kv_heads, cfg.head_dim, dtype,
                     use_kernel=self._use_pallas,
                 )
             else:
                 self.cache = SinkKVCache.create(
-                    cfg.num_layers, b, cc.window_length, cc.num_sink_tokens,
+                    cfg.cache_layers, b, cc.window_length, cc.num_sink_tokens,
                     cfg.num_kv_heads, cfg.head_dim, dtype,
                 )
             self.allocator = None
@@ -876,6 +905,8 @@ class InferenceEngine:
             _, _, pool_heads, _, pool_width = self.cache.k_pages.shape
             self.plan.walked_pool = (pool_heads, pool_width)
 
+        looped = cfg.loop is not None
+
         def _decode_scan(params, tokens, cache, active, key, sp, eos_ids, budget):
             """``K`` fused decode steps in one dispatch: sampling, EOS stops,
             and per-row token budgets all carried on device. Rows that stop
@@ -886,7 +917,10 @@ class InferenceEngine:
             sink ring, the paged pools with or without a kernel: see
             ``tail_capable`` above) runs the write-behind-tail fast path
             (``llama.multi_decode_apply`` — big KV buffers read-only through
-            all K steps); the others scan ``model_apply`` per step.
+            all K steps); the others scan ``model_apply`` per step. A third
+            result, for a looped stack through the fast path only: the lap
+            each emitted token's position left at ``[K, B]`` (None, no
+            output at all, otherwise).
             """
             if tail_capable:
                 def step_fn(i, logits, alive):
@@ -895,10 +929,15 @@ class InferenceEngine:
                     alive = alive & (nxt != eos_ids) & (i + 1 < budget)
                     return nxt, alive.astype(jnp.int32), alive, emitted
 
-                return llama.multi_decode_apply(
+                emitted, cache = llama.multi_decode_apply(
                     cfg, params, tokens, cache, K, step_fn,
-                    active, active.astype(jnp.int32),
+                    active, active.astype(jnp.int32), exit_laps=looped,
                 )
+                # a looped stack's scan also says which lap each token's
+                # position left at; laps is None, no output at all, for
+                # every other model
+                emitted, laps = emitted if looped else (emitted, None)
+                return emitted, cache, laps
 
             def one(carry, i):
                 tok, cache, alive = carry
@@ -914,7 +953,7 @@ class InferenceEngine:
             (_, cache, _), emitted = jax.lax.scan(
                 one, (tokens, cache, active), jnp.arange(K)
             )
-            return emitted, cache
+            return emitted, cache, None
 
         donate = jax.default_backend() == "tpu"
         dk = dict(donate_argnums=(2,)) if donate else {}
@@ -1038,6 +1077,11 @@ class InferenceEngine:
         self.spec_stats = {"proposed": 0, "accepted": 0, "steps": 0}
         if draft is not None:
             dcfg, dparams = draft
+            if dcfg.loop is not None:
+                raise ValueError(
+                    f"a draft of family {dcfg.family!r} (ModelConfig.loop): "
+                    + llama.LOOP_NEEDS_ONE_STAGE
+                )
             if dcfg.vocab_size != cfg.vocab_size:
                 raise ValueError("draft and target must share a vocabulary")
             if isinstance(self.cache, _SINK_KINDS):
@@ -2283,10 +2327,10 @@ class InferenceEngine:
                 f"must agree across pools)"
             )
         if "c" in want:
-            shape = (self.cfg.num_layers, n, 1, self.cfg.latent.lat_dim)
+            shape = (self.cfg.cache_layers, n, 1, self.cfg.latent.lat_dim)
         else:
             shape = (
-                self.cfg.num_layers, n,
+                self.cfg.cache_layers, n,
                 self.cfg.num_kv_heads, self.cfg.head_dim,
             )
         for name in sorted(want):
@@ -2701,7 +2745,7 @@ class InferenceEngine:
                 if isinstance(self.cache, QuantizedDenseKVCache) else {}
             )
             self.cache = type(self.cache).create(
-                self.cfg.num_layers, self.batch, self._windows[0],
+                self.cfg.cache_layers, self.batch, self._windows[0],
                 self.cfg.num_kv_heads, self.cfg.head_dim,
                 jnp.dtype(self.ecfg.dtype), **kw,
             )
@@ -3076,17 +3120,17 @@ class InferenceEngine:
         cfg, dtype = self.cfg, jnp.dtype(self.ecfg.dtype)
         if isinstance(c, QuantizedDenseKVCache):
             return QuantizedDenseKVCache.create(
-                cfg.num_layers, nr, c.max_len, cfg.num_kv_heads,
+                cfg.cache_layers, nr, c.max_len, cfg.num_kv_heads,
                 cfg.head_dim, dtype, use_kernel=c.use_kernel,
             )
         if isinstance(c, DenseKVCache):
             return DenseKVCache.create(
-                cfg.num_layers, nr, c.max_len, cfg.num_kv_heads,
+                cfg.cache_layers, nr, c.max_len, cfg.num_kv_heads,
                 cfg.head_dim, dtype,
             )
         if isinstance(c, QuantizedSinkKVCache):
             return QuantizedSinkKVCache.create(
-                cfg.num_layers, nr, c.window, c.num_sinks,
+                cfg.cache_layers, nr, c.window, c.num_sinks,
                 cfg.num_kv_heads, cfg.head_dim, dtype,
                 use_kernel=c.use_kernel,
             )
@@ -3679,7 +3723,7 @@ class InferenceEngine:
             else int(getattr(self.cache, "max_len", 0)),
         ), self._live_positions(active, pend_b), int(active.sum()),
             query_spans=self._decode_spans(active, K, pend_b))
-        emitted, self.cache = self._decode_k(
+        emitted, self.cache, laps = self._decode_k(
             self.params, tokens_dev, self.cache, act_dev,
             self._next_key(), sp, jnp.asarray(eos_ids),
             jnp.asarray(budget),
@@ -3690,7 +3734,7 @@ class InferenceEngine:
         )
         self._carry = self._carry_merge(emitted[-1], old, act_dev)
         self._carry_ok = self._carry_ok | active
-        return (emitted, budget, active, list(self.slots))
+        return (emitted, budget, active, list(self.slots), laps)
 
     def _resolve_pending(self, produced, prev) -> None:
         """Fetch and deliver the PREVIOUS tick's tokens (the copy overlaps
@@ -3709,7 +3753,8 @@ class InferenceEngine:
             return
         fetch = [toks for _, toks, _ in admits]
         if prev is not None:
-            fetch.append(prev[0])
+            # a looped stack's exit laps ride the same fetch (None: no leaf)
+            fetch += [prev[4], prev[0]]
         got = self._fetch(fetch)
         if admits:
             self._admit_pend[:] = 0
@@ -3730,8 +3775,8 @@ class InferenceEngine:
                     )
         if prev is None:
             return
-        emitted_dev, budget, active, gids = prev
-        emitted = np.asarray(got[-1])
+        emitted_dev, budget, active, gids, _ = prev
+        emitted, laps = np.asarray(got[-1]), got[-2]
         delivered_total = 0
         for slot, gid in enumerate(gids):
             if gid is None or not active[slot]:
@@ -3749,9 +3794,23 @@ class InferenceEngine:
                 self._deliver(s, tok, produced)
                 delivered += 1
             delivered_total += delivered
+            self._count_exit_laps(laps, slot, delivered)
             if delivered < int(budget[slot]) and s.state == SessionState.ACTIVE:
                 self._carry_ok[slot] = False
         self.metrics.counter("decode_tokens", delivered_total)
+
+    def _count_exit_laps(self, laps, slot: int, delivered: int) -> None:
+        """``loop_exit_lap_sum`` / ``loop_exit_positions``: the lap the exit
+        selection took for each of a row's ``delivered`` tokens of a fused
+        decode dispatch, as the device reported it beside the tokens
+        (``laps [K, B]``; None for a stack that runs once). Their ratio is
+        the mean exit lap: ``loop_steps - 1`` at a threshold of 1."""
+        if laps is None or not delivered:
+            return
+        self.metrics.counter(
+            "loop_exit_lap_sum", int(np.asarray(laps)[:delivered, slot].sum())
+        )
+        self.metrics.counter("loop_exit_positions", delivered)
 
     def _decode_tick(self, produced) -> None:
         self._spec_adapt(produced)
@@ -3852,6 +3911,7 @@ class InferenceEngine:
             else int(getattr(self.cache, "max_len", 0)),
         ), self._live_positions(active), int(active.sum()),
             query_spans=self._decode_spans(active, K))
+        laps = None
         if K == 1:
             next_tokens, self.cache = self._decode(
                 self.params, jnp.asarray(tokens), self.cache,
@@ -3863,13 +3923,14 @@ class InferenceEngine:
             eos_ids = np.asarray(
                 [o.eos_token_id for o in opts], np.int32
             )
-            emitted, self.cache = self._decode_k(
+            emitted, self.cache, laps = self._decode_k(
                 self.params, jnp.asarray(tokens), self.cache,
                 jnp.asarray(active), self._next_key(), sp,
                 jnp.asarray(eos_ids), jnp.asarray(budget),
             )
             # distcheck: host-sync-ok(the one per-tick fetch for K>1)
-            emitted = np.asarray(self._fetch(emitted))
+            emitted, laps = self._fetch([emitted, laps])
+            emitted = np.asarray(emitted)
 
         delivered = 0
         with self._region("deliver"):
@@ -3877,11 +3938,13 @@ class InferenceEngine:
                 if gid is None or not active[slot]:
                     continue
                 s = self.sessions[gid]
+                before = delivered
                 for i in range(int(budget[slot])):
                     if s.state != SessionState.ACTIVE:
                         break
                     self._deliver(s, int(emitted[i, slot]), produced)
                     delivered += 1
+                self._count_exit_laps(laps, slot, delivered - before)
         self.metrics.counter("decode_tokens", delivered)
 
     def _grow_pages(self, s: Session, want: int) -> int:

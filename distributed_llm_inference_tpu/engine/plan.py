@@ -158,6 +158,10 @@ class AttentionPlan:
         # keys over every layer, which each decode step of a live row reads
         # (the engine sets it; the fold lies at the page boundaries).
         self.retention_state_bytes: Optional[int] = None
+        # A looped stack (``ModelConfig.loop``): ``(laps, layers, the
+        # layers' stored bytes)``; note_dispatch counts what the laps cost
+        # (:meth:`_count_loop`).
+        self.loop: Optional[Tuple[int, int, int]] = None
         # Set by the engine over a paged cache: ``pad width -> block_q``,
         # the q block the ragged kernel picks for this model at that width
         # (``ops/ragged_attention.py:_prep``); note_dispatch keeps the
@@ -379,7 +383,12 @@ class AttentionPlan:
         two mixes a layer) counts the hyper-connection mixes its valid
         tokens need and those its padded tokens run, ``mhc_mixes_needed`` /
         ``mhc_mixes_run``, a decode dispatch's tokens as for the experts'
-        census."""
+        census.
+
+        A looped stack (``loop``; :meth:`_count_loop`) counts the layer
+        applications, the cached positions over every cache layer and the
+        weight bytes its laps cost: ``loop_layer_passes``,
+        ``loop_kv_positions_read``, ``loop_weight_bytes_read``."""
         shape = tuple(int(x) for x in shape)
         self.last_dispatch = (kind, shape, valid_tokens)
         sparse_keys = window_keys = None
@@ -428,6 +437,8 @@ class AttentionPlan:
             valid, padded = (active_rows or 0) * shape[1], shape[0] * shape[1]
         else:
             valid, padded = valid_tokens, shape[0] * shape[1]
+        if self.loop is not None:
+            self._count_loop(kind, shape, valid, valid_tokens, query_spans)
         if self.mhc_mixes_per_token is not None:
             self.metrics.counter(
                 "mhc_mixes_needed", valid * self.mhc_mixes_per_token
@@ -481,6 +492,31 @@ class AttentionPlan:
                 live, grid = self._ragged_tiles(shape, row_spans, table_width)
                 self.metrics.counter("ragged_attn_tiles_live", live)
                 self.metrics.counter("ragged_attn_tiles_grid", grid)
+
+    def _count_loop(self, kind, shape, valid, live, spans) -> None:
+        """The census of a looped stack, every dispatch. ``loop_layer_passes``:
+        laps x layers x the dispatch's valid tokens (a decode dispatch's are
+        its active rows x steps): the layer applications its tokens cross.
+        ``loop_kv_positions_read``: the cached positions its queries attend,
+        over every CACHE layer (laps x layers): a decode step's are its rows'
+        contexts as the host knows them (``live``) plus the steps before it
+        in the dispatch, a prefill row's query at position ``t`` attends ``t
+        + 1``. ``loop_weight_bytes_read``: laps x the layers' stored bytes,
+        a decode step or a prefill dispatch each."""
+        laps, layers, layer_bytes = self.loop
+        count = self.metrics.counter
+        steps = shape[1] if kind == DECODE else 1
+        count("loop_layer_passes", laps * layers * valid)
+        if kind == DECODE:
+            rows = valid // max(steps, 1)
+            positions = live * steps + rows * steps * (steps - 1) // 2
+        else:
+            positions = sum(
+                int(n) * int(p) + int(n) * (int(n) + 1) // 2
+                for p, n in spans or ()
+            )
+        count("loop_kv_positions_read", laps * layers * positions)
+        count("loop_weight_bytes_read", laps * layer_bytes * steps)
 
     def _folds(self, spans) -> Tuple[int, int]:
         """(rows that fold, positions folded) of a dispatch of a stack of

@@ -53,6 +53,11 @@ class SpeculativeDecoder:
             raise ValueError("draft and target must share a vocabulary")
         if k < 1:
             raise ValueError(f"k must be >= 1, got {k}")
+        if draft_cfg.loop is not None:
+            raise ValueError(
+                f"a draft of family {draft_cfg.family!r} (ModelConfig.loop): "
+                + llama.LOOP_NEEDS_ONE_STAGE
+            )
         self.cfg, self.dcfg = cfg, draft_cfg
         self.params, self.dparams = params, draft_params
         self.k = k
@@ -105,7 +110,7 @@ class SpeculativeDecoder:
 
     def _mk_cache(self, cfg: ModelConfig) -> DenseKVCache:
         return DenseKVCache.create(
-            cfg.num_layers, 1, self.max_seq_len, cfg.num_kv_heads,
+            cfg.cache_layers, 1, self.max_seq_len, cfg.num_kv_heads,
             cfg.head_dim, self.dtype,
         )
 

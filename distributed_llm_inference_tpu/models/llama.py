@@ -142,6 +142,10 @@ def init_layer_params(
             "wo": w(keys[3], hq * d, h),
             "mlp_norm": jnp.ones((num_layers, h), dtype),
         }
+    if cfg.loop is not None:
+        # A looped block norms each sublayer's OUTPUT before it is added.
+        p["attn_out_norm"] = jnp.ones((num_layers, h), dtype)
+        p["mlp_out_norm"] = jnp.ones((num_layers, h), dtype)
     if cfg.qk_norm:
         p["q_norm"] = jnp.ones((num_layers, d), dtype)
         p["k_norm"] = jnp.ones((num_layers, d), dtype)
@@ -253,6 +257,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
             jax.random.normal(k_head, (cfg.hidden_size, cfg.vocab_size), jnp.float32)
             * 0.02
         ).astype(dtype)
+    if cfg.loop is not None:
+        # The exit gate behind every lap (:func:`_loop_exit`): one
+        # ``hidden_size -> 1`` projection and its bias, shared by the laps.
+        params["exit_w"] = (
+            jax.random.normal(
+                jax.random.fold_in(k_head, 1), (cfg.hidden_size,), jnp.float32
+            ) * 0.02
+        ).astype(dtype)
+        params["exit_b"] = jnp.zeros((), jnp.float32)
     return params
 
 
@@ -341,6 +354,9 @@ def _decoder_layer(
         o = qmatmul(attn_flat, p["wo"])
         if "bo" in p:
             o = o + p["bo"]
+        if "attn_out_norm" in p:
+            # a looped block's "sandwich": the output is normed, then added
+            o = rms_norm(o, p["attn_out_norm"], cfg.rms_norm_eps)
         x = _stream_write(x, o, mix)
     return _mlp_residual(cfg, p, x, s, num_new), new_state
 
@@ -450,6 +466,8 @@ def _mlp_residual(cfg, p, x, s, num_new):
                 jax.nn.silu(qmatmul(h2, p["wg"])) * qmatmul(h2, p["wu"]),
                 p["wd"],
             )
+        if "mlp_out_norm" in p:
+            mlp = rms_norm(mlp, p["mlp_out_norm"], cfg.rms_norm_eps)
         return _stream_write(x, mlp, mix)
 
 
@@ -621,6 +639,7 @@ def block_apply(
     attention_fn=gqa_attention,
     first_layer: int = 0,
     segment: Optional[LayerSegment] = None,
+    laps=None,
 ):
     """Run a block (contiguous or not) of decoder layers over hidden states.
 
@@ -634,6 +653,19 @@ def block_apply(
     layers ``first_layer .. first_layer + n``; and itself as ``segment``,
     for its window and its RoPE switch (None: the model's one window, RoPE
     on: a block of a stack whose layers are all alike).
+
+    ``laps`` (a looped stack's: ``(steps, lap_end, state)``) runs the block
+    ``steps`` times over the same weights as ONE scan of ``steps x n``
+    layer applications, which parts the row of the weights from the row of
+    the cache: application ``i`` runs layer ``i % n`` over cache layer
+    ``first_layer + i`` (lap ``t`` of layer ``l`` owns row ``t x n + l``),
+    and behind each lap ``lap_end(x, lap, state) -> (x, state)`` runs (the
+    final norm and the exit gate). One scan and not a scan of laps around
+    the scan of layers: a pool carried by two loops is no longer the
+    donated parameter itself, and the TPU compiler then moves the WHOLE
+    pool into the layout its scatter prefers and back (2 x 3.75 GB of
+    temporaries at Ouro-2.6B's pool, a described-v5e compile:
+    tests/test_chip_compile.py). Returns ``(x, cache, state)`` then.
 
     Returns ``(x, cache)`` with the cache's k/v updated (lengths NOT advanced —
     call ``cache.advance(num_new)`` after the last block of the model so that
@@ -661,11 +693,13 @@ def block_apply(
         layer_params, _grouped_stacks(cfg, layer_params, x)
     )
 
-    def step(carry, xs):
+    def step(carry, xs, at=None):
         x, bufs = carry
         p, idx = xs
         # whole stacks are this block's own: indexed from its first layer
         p = {**p, **_layer_views(whole_w, idx - first_layer if first_layer else idx)}
+        if at is not None:
+            idx = at    # a lap's row of the cache; the weights' stays above
         rows = (idx,) * len(bufs) if rows_of is None else rows_of(idx)
         layer_state = tuple(
             jax.lax.dynamic_index_in_dim(b, r, 0, keepdims=False)
@@ -681,6 +715,34 @@ def block_apply(
         )
         return (out, bufs), None
 
+    if laps is not None:
+        steps, lap_end, state = laps
+
+        def application(carry, i):
+            x, bufs, state = carry
+            layer = i % num_stack
+            p = jax.tree.map(
+                lambda w: jax.lax.dynamic_index_in_dim(
+                    w, layer, 0, keepdims=False
+                ),
+                scanned_w,
+            )
+            with jax.named_scope("loop_lap"):
+                (x, bufs), _ = step(
+                    (x, bufs), (p, first_layer + layer), first_layer + i
+                )
+            x, state = jax.lax.cond(
+                layer == num_stack - 1,
+                lambda x, state: lap_end(x, i // num_stack, state),
+                lambda x, state: (x, state),
+                x, state,
+            )
+            return (x, bufs, state), None
+
+        (x, new_stacks, state), _ = jax.lax.scan(
+            application, (x, stacks, state), jnp.arange(steps * num_stack)
+        )
+        return x, cache.with_layer_stacks(*new_stacks), state
     (x, new_stacks), _ = jax.lax.scan(
         step, (x, stacks),
         (scanned_w, jnp.arange(first_layer, first_layer + num_stack)),
@@ -714,6 +776,12 @@ def model_apply(
     interiors), returning ``None`` logits. Shapes: "last" → [B, 1, V].
     """
     x = _embed(cfg, params, tokens)
+    if cfg.loop is not None:
+        if block_fn is not None:
+            raise ValueError(LOOP_NEEDS_ONE_STAGE)
+        return _looped_model_apply(
+            cfg, params, x, cache, num_new, attention_fn, head
+        )
     if block_fn is None:
         for seg in cfg.segments:
             with _segment_scope(seg):
@@ -740,6 +808,104 @@ def model_apply(
         x = jnp.take_along_axis(x, last, axis=1)
     logits = apply_head(cfg, params, _stream_exit(cfg, x))
     return logits, cache.advance(num_new)
+
+
+#: why a looped stack runs whole in one place (what every refusal says)
+LOOP_NEEDS_ONE_STAGE = (
+    "layers run several times: a lap must return to the first stage, with "
+    "the client-side final norm and the exit gate between laps, which no "
+    "stage protocol here does (pp stages, relay chains of partial blocks "
+    "and a draft's own stack pass a hidden state down ONCE); serve a looped "
+    "model whole on one device or under tp"
+)
+
+
+def _last_positions(x, num_new):
+    """``x [B, S, H]`` at each row's last valid position: ``[B, 1, H]``."""
+    last = jnp.maximum(num_new - 1, 0)[:, None, None].astype(jnp.int32)
+    return jnp.take_along_axis(x, last, axis=1)
+
+
+def _loop_exit_start(x):
+    """The exit selection before the first lap, for the positions ``x [B, S,
+    H]`` stands for: ``(the chosen lap's hidden state, the weight still
+    inside, the summed exit weights, the chosen lap or -1)``."""
+    b, s = x.shape[:2]
+    return (
+        jnp.zeros_like(x),
+        jnp.ones((b, s), jnp.float32),
+        jnp.zeros((b, s), jnp.float32),
+        jnp.full((b, s), -1, jnp.int32),
+    )
+
+
+def _loop_exit(cfg: ModelConfig, params: Params, h, lap, state):
+    """The exit gate behind lap ``lap`` and the selection so far. ``h [B, S,
+    H]`` is the lap's final-normed hidden state; ``lam = sigmoid(w . h + b)``
+    is the share of the weight still inside that leaves here, the last lap
+    takes what is left, and a position is served the FIRST lap at which the
+    summed weights reach ``exit_threshold`` (the last lap if none does: at
+    the published threshold of 1 always the last). Float32 throughout."""
+    chosen, inside, summed, at = state
+    f32 = jnp.float32
+    lam = jax.nn.sigmoid(
+        jnp.einsum("bsh,h->bs", h.astype(f32), params["exit_w"].astype(f32))
+        + params["exit_b"].astype(f32)
+    )
+    last = lap == cfg.loop.steps - 1
+    summed = summed + jnp.where(last, inside, lam * inside)
+    take = (at < 0) & ((summed >= cfg.loop.exit_threshold) | last)
+    return (
+        jnp.where(take[..., None], h, chosen),
+        inside * (1.0 - lam),
+        summed,
+        jnp.where(take, lap, at),
+    )
+
+
+def _lap_end(cfg: ModelConfig, params: Params, x, lap, state, pick=None):
+    """What follows the stack in every lap: the model's one final norm,
+    whose output enters the next lap, and (``state`` not None) the exit
+    gate and the selection at the positions ``pick`` takes of it."""
+    with jax.named_scope("loop_exit"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        if state is not None:
+            state = _loop_exit(
+                cfg, params, x if pick is None else pick(x), lap, state
+            )
+    return x, state
+
+
+def _looped_model_apply(cfg, params, x, cache, num_new, attention_fn, head):
+    """:func:`model_apply` behind the embedding for a looped stack
+    (``ModelConfig.loop``): ``block_apply`` with its ``laps``, ONE
+    ``lax.scan`` over ``steps x layers`` layer applications, so a program
+    compiles one layer whatever the laps' number. Lap ``t`` runs the whole
+    stack over cache layers ``t x L .. (t + 1) x L``, then the final norm
+    and the exit gate (:func:`_lap_end`); the head reads the hidden state
+    the selection chose, which is already normed. Every lap runs whatever
+    the gate says."""
+    (seg,) = cfg.segments
+    pick = None
+    if head == "last":
+        pick = functools.partial(_last_positions, num_new=num_new)
+    state = None
+    if head != "none":
+        state = _loop_exit_start(x if pick is None else pick(x))
+    with _segment_scope(seg):
+        _, cache, state = block_apply(
+            cfg, params[seg.key], x, cache, num_new, attention_fn,
+            first_layer=seg.cache_start, segment=seg,
+            laps=(
+                cfg.loop.steps,
+                lambda x, lap, state: _lap_end(cfg, params, x, lap, state, pick),
+                state,
+            ),
+        )
+    cache = cache.advance(num_new)
+    if head == "none":
+        return None, cache
+    return apply_head(cfg, params, state[0], normed=True), cache
 
 
 def _embed(cfg: ModelConfig, params: Params, tokens):
@@ -806,6 +972,7 @@ def multi_decode_apply(
     step_fn,
     init_state,
     init_num_new: jnp.ndarray,
+    exit_laps: bool = False,
 ):
     """``num_steps`` fused decode steps with a WRITE-BEHIND KV tail.
 
@@ -826,6 +993,14 @@ def multi_decode_apply(
     sampling/stop logic; ``num_new`` must be non-increasing per row across
     steps (a finished row stays finished) so each row's tail slots stay
     contiguous. Returns ``(emits stacked [K, ...], cache flushed+advanced)``.
+
+    A looped stack (``ModelConfig.loop``) runs its laps INSIDE a step, as
+    one ``lax.scan`` over the lap: lap ``t`` of layer ``l`` reads the big
+    planes' and writes the tail's layer ``t x L + l`` (the tail is a plane a
+    CACHE layer), the final norm and the exit gate follow each lap, and the
+    head reads the chosen lap's hidden state. ``exit_laps``: the emits come
+    back as ``(emits, laps [K, B])``, the lap the selection took for each
+    row's token of each step.
 
     The dense cache kinds implement the tail protocol
     (``tail_init`` / ``tail_attend`` / ``tail_flush``) natively, and
@@ -881,13 +1056,16 @@ def multi_decode_apply(
                 ropes[seg.rope] = _rope_angles(inv_freq, q_pos, seg.rope)
         index_rope = _index_rope(cfg, q_pos)
 
-        def layer_step(pool, view, rope, seg, whole_w, carry2, xs):
+        def layer_step(pool, view, rope, seg, whole_w, offset, carry2, xs):
             x, tail_bufs = carry2
             p = xs[0]
             idx = xs[-1]
             # whole stacks are a segment's own: indexed from its first layer
             first = seg.cache_start
             p = {**p, **_layer_views(whole_w, idx - first if first else idx)}
+            if offset is not None:
+                # a lap's rows of the cache: the weights' row stays ``idx``
+                idx = idx + offset
             if pool.whole_big:
                 big_state = (*pool.big_stacks, idx)
             else:
@@ -912,7 +1090,11 @@ def multi_decode_apply(
                 )
             return (out, tail_bufs), None
 
-        for seg, (whole_w, scanned_w) in zip(segments, split_w):
+        def run_segment(x, seg, whole_w, scanned_w, lap=None, lap_big=None):
+            """``x`` through a segment's layers, its pool's tail updated
+            in ``tails``. ``lap`` (a looped stack's, traced) offsets the
+            cache's rows by whole stacks, and ``lap_big`` are that lap's
+            layers of the big planes."""
             at = names.index(seg.pool)
             pool = pools[at]
             view = _TailView(
@@ -924,20 +1106,61 @@ def multi_decode_apply(
             # The read-only big planes ride a segment's scan as ITS layers'
             # slice (the whole of them for a one-segment stack).
             lo, hi = seg.cache_start, seg.cache_start + seg.count
-            seg_big = () if pool.whole_big else (
-                pool.big_stacks if seg.count == pool.num_stack
-                else tuple(b[lo:hi] for b in pool.big_stacks)
-            )
-            with _segment_scope(seg):
+            if lap is not None:
+                seg_big = lap_big
+            else:
+                seg_big = () if pool.whole_big else (
+                    pool.big_stacks if seg.count == pool.num_stack
+                    else tuple(b[lo:hi] for b in pool.big_stacks)
+                )
+            # (a looped stack's segment scope is around its laps)
+            with _segment_scope(seg) if lap is None else jax.named_scope(
+                "loop_lap"
+            ):
                 (x, tails[at]), _ = jax.lax.scan(
                     functools.partial(
-                        layer_step, pool, view, rope, seg, whole_w
+                        layer_step, pool, view, rope, seg, whole_w,
+                        None if lap is None else lap * seg.count,
                     ),
                     (x, tails[at]),
                     (scanned_w, *seg_big, jnp.arange(lo, hi)),
                 )
-        logits = apply_head(cfg, params, _stream_exit(cfg, x))
+            return x
+
+        lap_at = None
+        if cfg.loop is None:
+            for seg, (whole_w, scanned_w) in zip(segments, split_w):
+                x = run_segment(x, seg, whole_w, scanned_w)
+            logits = apply_head(cfg, params, _stream_exit(cfg, x))
+        else:
+            # The laps of a looped stack: one scan, the lap's layers of the
+            # big planes riding it as its xs (sliced for free, as a layer's
+            # are), the tail whole in its carry.
+            (seg,), ((whole_w, scanned_w),) = segments, split_w
+            laps = cfg.loop.steps
+            lap_big = () if pools[0].whole_big else tuple(
+                b.reshape(laps, seg.count, *b.shape[1:])
+                for b in pools[0].big_stacks
+            )
+
+            def lap_step(carry, xs):
+                x, tails[0], exit_state = carry
+                x = run_segment(
+                    x, seg, whole_w, scanned_w, xs[0], tuple(xs[1:])
+                )
+                x, exit_state = _lap_end(cfg, params, x, xs[0], exit_state)
+                return (x, tails[0], exit_state), None
+
+            with _segment_scope(seg):
+                (_, tails[0], exit_state), _ = jax.lax.scan(
+                    lap_step, (x, tails[0], _loop_exit_start(x)),
+                    (jnp.arange(laps), *lap_big),
+                )
+            lap_at = exit_state[3][:, 0]
+            logits = apply_head(cfg, params, exit_state[0], normed=True)
         next_tokens, next_num_new, state, emit = step_fn(i, logits[:, 0], state)
+        if exit_laps:
+            emit = (emit, lap_at)
         tail_len = tail_len + num_new
         return (
             (next_tokens[:, None], tuple(tails), tail_len, next_num_new, state),
@@ -994,11 +1217,15 @@ class _ScanPool:
         self.view_num_big = self.num_big + 1 if self.whole_big else self.num_big
 
 
-def apply_head(cfg: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
+def apply_head(
+    cfg: ModelConfig, params: Params, x: jnp.ndarray, normed: bool = False
+) -> jnp.ndarray:
     """Final norm + lm_head (tied to the embedding when absent): ``[..., H]``
-    hidden states → fp32 logits ``[..., V]``."""
+    hidden states → fp32 logits ``[..., V]``. ``normed``: ``x`` has passed
+    the final norm already (a looped stack's laps end in it)."""
     with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        if not normed:
+            x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         head = params.get("lm_head")
         if head is None:
             head = params["embed"].T
@@ -1175,16 +1402,17 @@ def convert_hf_state_dict(
     """
     if (
         cfg.qk_norm and not cfg.use_retention
-    ) or cfg.use_sparse or cfg.hyper is not None:
+    ) or cfg.use_sparse or cfg.hyper is not None or cfg.loop is not None:
         # by what the converter lacks, whatever the family's name: a latent
         # block's compressed queries are mapped (``convert_hf_layer``), and a
         # retention block's checkpoint is Qwen3's with a ``g_proj``
         # (``_LAYER_KEY_MAP``); an indexer's tensors and a widened stream's
-        # maps are not
+        # maps, and a looped block's output norms and gate, are not
         raise ValueError(
             f"family {cfg.family!r} has no checkpoint converter: the key "
             "names of its checkpoint (the per-head q/k norms', an "
-            "indexer's, the hyper-connections') are not known to this "
+            "indexer's, the hyper-connections', a looped block's output "
+            "norms' and exit gate's) are not known to this "
             "program, and a guessed converter is worse than none"
         )
 

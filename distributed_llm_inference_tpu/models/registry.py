@@ -63,6 +63,16 @@ Families:
   ``ops/power_retention.py``). Its checkpoint is Qwen3's with a ``g_proj``
   beside q, k, v: the converter maps it.
 
+* ``ouro`` — Ouro's looped block (Ouro-2.6B): the dense block with each
+  sublayer's OUTPUT normed before it is added, the whole stack run
+  ``total_ut_steps`` times over the same weights, each lap with its own rows
+  of the cache (``ModelConfig.loop``, ``ModelConfig.cache_layers``), the
+  final norm between laps and one exit gate behind each lap
+  (``models/llama.py``: the lap scans of ``model_apply`` and
+  ``multi_decode_apply``). Its checkpoint's key names for the output norms
+  and the gate are not known to this program:
+  :func:`llama.convert_hf_state_dict` refuses the family.
+
 The switches are independent: a family may permit any of them together
 (``mla`` permits experts AND requires the latent); what a family does not
 permit is refused by :func:`validate_config`.
@@ -103,6 +113,9 @@ class ModelFamily:
     # Power retention in place of softmax attention: the family both
     # permits AND requires ``ModelConfig.retention``.
     retention: bool = False
+    # Layers that run several times, an exit gate behind each lap: the
+    # family both permits AND requires ``ModelConfig.loop``.
+    loop: bool = False
     # The compute/conversion program (shared stack for all current families).
     apply: Callable = llama.model_apply
     block_apply: Callable = llama.block_apply
@@ -137,6 +150,7 @@ FAMILIES: Dict[str, ModelFamily] = {
             "xing4_0", ("xing4_0",), latent=True, moe=True, hyper=True,
         ),
         ModelFamily("brumby", ("brumby",), qk_norm=True, retention=True),
+        ModelFamily("ouro", ("ouro",), loop=True),
     )
 }
 
@@ -272,6 +286,24 @@ def validate_config(cfg: ModelConfig) -> ModelFamily:
             "power retention's feature map pairs the halves of an even "
             f"head_dim (got head_dim={cfg.head_dim})"
         )
+    if (cfg.loop is not None) != fam.loop:
+        raise ValueError(
+            f"family {fam.name!r} "
+            + ("requires" if fam.loop else "does not use")
+            + " layers that run several times (ModelConfig.loop; the 'ouro' "
+            "family)"
+        )
+    if cfg.loop is not None:
+        if cfg.loop.steps < 1 or not 0.0 < cfg.loop.exit_threshold <= 1.0:
+            raise ValueError(
+                "a looped stack makes at least one lap and leaves at a "
+                f"summed exit weight in (0, 1] (got {cfg.loop})"
+            )
+        if len(cfg.segments) != 1:
+            raise ValueError(
+                "a looped stack is ONE run of like layers: the lap scans "
+                f"carry one segment (got {cfg.segments})"
+            )
     if fam.latent and (cfg.latent is None or not cfg.latent.enabled):
         raise ValueError(
             f"family {fam.name!r} requires an enabled ModelConfig.latent"

@@ -50,6 +50,9 @@ _LAYER_RULES: Dict[str, P] = {
     "wo": P("pp", "tp", None),
     "bo": P("pp", None),
     "mlp_norm": P("pp", None),
+    # a looped block's norms of the sublayers' outputs
+    "attn_out_norm": P("pp", None),
+    "mlp_out_norm": P("pp", None),
     "wg": P("pp", None, "tp"),
     "wu": P("pp", None, "tp"),
     "wd": P("pp", "tp", None),
@@ -140,6 +143,8 @@ def param_pspecs(params: Dict[str, Any], use_pp: bool = False) -> Dict[str, Any]
         out["final_norm"] = P(None)
     if "lm_head" in params:
         out["lm_head"] = _maybe_qspec(params["lm_head"], P(None, "tp"))
+    if "exit_w" in params:  # a looped stack's exit gate: replicated
+        out["exit_w"], out["exit_b"] = P(None), P()
     return out
 
 

@@ -38,6 +38,12 @@ METRICS = {
     "admission_order_errors": ("counter", "Admission-order hook raised; tick fell back to FIFO"),
     "admit_sync_sessions": ("counter", "Sessions admitted synchronously"),
     "admit_overlap_sessions": ("counter", "Sessions admitted via overlap"),
+    "loop_exit_lap_sum": (
+        "counter", "Exit laps of a looped stack's delivered decode tokens, summed"
+    ),
+    "loop_exit_positions": (
+        "counter", "Delivered decode tokens whose exit lap the device reported"
+    ),
     "admit_overlap_spill": ("counter", "Overlap admissions spilled to sync"),
     "admit_overlap_inflight": ("gauge", "Prefills in flight behind decode"),
     "admit_to_merge": ("summary", "Overlap admission to KV-merge latency"),

@@ -761,3 +761,99 @@ def test_a_fresh_rows_install_stays_sharded_and_in_place_on_a_tp4_mesh(chip):
     assert not [r for r in results if re.match(rf"\S*\[[0-9,]*\b{slots * PS}\b", r)]
     assert [r for r in results if re.match(plane + r"\S* dynamic-update-slice\(", r)]
     assert "input_output_alias" in text and " while(" in text
+
+
+# ouro-2.6b.mathchat: 48 layers that every token crosses four times over a
+# cache of 4 x 48 = 192 layers: 16 query and 16 key-value heads of 128 (a
+# group of ONE query a key-value head), bf16 weights, an int8 pool of 160
+# pages of 64, 16 rows, a 16-slot table, one 512-wide pad width.
+OURO_SLOTS, OURO_PAD = 16, 512
+
+
+def _ouro(s):
+    import json
+    import pathlib
+
+    from benchmark import server
+    from benchmark.weights import ouro_looped_gqa as maker
+    from distributed_llm_inference_tpu.cache.paged import QuantizedPagedKVCache
+    from distributed_llm_inference_tpu.config import ModelConfig
+
+    conf = json.loads(
+        (pathlib.Path(server.REPO) / "benchmark/configs/ouro-2.6b.json").read_text()
+    )
+    cfg = ModelConfig.from_hf_config(server.hf_block(conf))
+    abstract = lambda tree: jax.tree.map(lambda x: s(x.shape, x.dtype), tree)
+    params = abstract(jax.eval_shape(
+        lambda: maker.make(cfg, 0, jnp.bfloat16, None)
+    ))
+    serve = conf["serve"]
+    assert serve["cache"]["kv_quant"] == "int8" and serve["weights"] == "bf16"
+    cache = abstract(jax.eval_shape(lambda: QuantizedPagedKVCache.create(
+        cfg.cache_layers, serve["engine"]["max_batch_size"],
+        serve["cache"]["num_pages"], serve["cache"]["page_size"], OURO_SLOTS,
+        cfg.num_kv_heads, cfg.head_dim, use_kernel=True, use_ragged=True,
+    )))
+    return cfg, params, cache
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode-scan"])
+def test_the_looped_programs_at_ouros_published_widths(chip, monkeypatch, program):
+    """The looped prefill (one row's 512-wide piece through its page table,
+    head last) and the fused 16-step decode scan of 16 rows, WHOLE, at the
+    published widths over the cell's pool: the lap scan around the layer
+    scan, the ragged prefill kernel and the in-place decode sweep at a group
+    of one query a key-value head over 192 cache layers, the tail's flush.
+    Bytes and temporaries are known before the first chip call: arguments
+    and temporaries fit the chip's 17.18 GB."""
+    from distributed_llm_inference_tpu.models import llama
+
+    # the kernels ask the backend whether to interpret: the described chip's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = chip
+    cfg, params, cache = _ouro(s)
+    rows, pages = cache.page_table.shape[0], cache.k_pages.shape[1]
+    assert cache.k_pages.shape == (192, pages, 16, PS, 128)
+
+    def prefill(params, tokens, cache, row, n_valid):
+        sub = cache.select_row(row)
+        logits, sub = llama.model_apply(
+            cfg, params, tokens, sub, n_valid[None], head="last"
+        )
+        return logits, cache.merge_row(sub, row)
+
+    def decode(params, tokens, cache, active):
+        return llama.multi_decode_apply(
+            cfg, params, tokens, cache, KT,
+            lambda i, logits, alive: (
+                jnp.argmax(logits, -1).astype(I32), alive.astype(I32), alive,
+                jnp.argmax(logits, -1).astype(I32),
+            ),
+            active, active.astype(I32), exit_laps=True,
+        )
+
+    if program == "prefill":
+        lowered = jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, s((1, OURO_PAD), I32), cache, s((), I32), s((), I32)
+        )
+        kernels = ("quantized_ragged_paged_attention",)
+    else:
+        lowered = jax.jit(decode, donate_argnums=(2,)).lower(
+            params, s((rows, 1), I32), cache, s((rows,), jnp.bool_)
+        )
+        kernels = ("quantized_paged_fused_attention", "paged_tail_flush")
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for name in kernels + ("loop_lap", "loop_exit"):
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    # 5.34 GB of bf16 weights and the pool (8.3 GB at 160 pages) are
+    # arguments, the pool aliased to the result; the temporaries (2.0 and
+    # 2.1 GB: the pool's float32 scale planes, 16 heads minor, padded
+    # eightfold to the 128 lanes on their way through the scatter's layout)
+    # fit what is left of the 16.9 GB (15.75 GiB) the compiler may use
+    assert mem.argument_size_in_bytes > 5.3e9 + 50e6 * pages
+    assert held < 15.75 * 2 ** 30, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    print(program, "arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
