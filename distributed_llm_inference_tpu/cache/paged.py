@@ -151,9 +151,9 @@ class PagedKVCache(GatherAttendMixin, struct.PyTreeNode):
     PLANE_FIELDS = {"k": "k_pages", "v": "v_pages"}
     #: per-row fields ``[B, slots]`` that widen and narrow with the table
     TABLE_FIELDS = ("page_table",)
-    #: role ("decode" | "prefill" | "flush") -> the name a class gives its
-    #: kernel calls in a device trace; a role left out keeps the kernel's
-    #: own (the window pool of a two-pool cache names all three)
+    #: role ("decode" | "prefill" | "flush" | "write") -> the name a class
+    #: gives its kernel calls in a device trace; a role left out keeps the
+    #: kernel's own (the window pool of a two-pool cache names all four)
     KERNEL_NAMES = {}
 
     def _kernel_name(self, role: str) -> Dict[str, str]:
@@ -1109,6 +1109,66 @@ class QuantizedPagedKVCache(PagedKVCache):
             ),
         )
 
+    @property
+    def ragged_reads_whole_stacks(self) -> bool:
+        """Whether a prefill-family dispatch (S > 1) over this cache takes
+        the WHOLE carried stacks and the cache layer's index as its layer
+        state (``models/llama.py:block_apply`` asks, as
+        ``multi_decode_apply`` asks :attr:`tail_reads_whole_big` for decode)
+        and hands the updated stacks back: :meth:`attend` then writes the
+        piece by whole pages at ``(layer, page)`` (:meth:`_write_pages`) and
+        the ragged kernel fetches its blocks there, so that no operation of
+        the layer body has a layer's plane as its result. Slicing a layer
+        out of the carry to scatter into it position by position costs four
+        plane-sized copies a plane a layer (the slice, the relayout XLA's
+        scatter wants, the relayout back, the write-back): the pool's size,
+        whatever the piece's. True where the ragged kernel serves the
+        dispatch and ``attend`` is THIS class's: a subclass that attends its
+        own way (an index plane's selection) keeps a layer's planes until it
+        says otherwise itself."""
+        return self.use_ragged and (
+            type(self).attend is QuantizedPagedKVCache.attend
+        )
+
+    def _piece_tiles(self, a, offset):
+        """A piece's plane ``[B, S, Hkv(, D)]`` laid out as the pages it
+        fills, ``[B, N, Hkv, PS(, D)]``: with ``offset[b]`` the offset of the
+        row's first position in its page, token ``s`` stands at tile
+        ``(offset[b] + s) // PS``, offset ``(offset[b] + s) % PS`` (what
+        :func:`paged_piece_write` takes). ``N`` covers the piece wherever in
+        a page it starts; offsets the piece has nothing for hold zeros and
+        are never written."""
+        ps = self.page_size
+        b, s = a.shape[:2]
+        n = (s + 2 * ps - 2) // ps
+        blank = jnp.zeros((n * ps, *a.shape[2:]), a.dtype)
+        rows = jnp.stack([
+            jax.lax.dynamic_update_slice_in_dim(blank, a[r], offset[r], axis=0)
+            for r in range(b)
+        ])
+        return jnp.swapaxes(rows.reshape(b, n, ps, *a.shape[2:]), 2, 3)
+
+    def _write_pages(self, stacks, layer, k_rot, v_new, q_pos, num_new):
+        """:meth:`_scatter_q` over the WHOLE stacks ``(k, v, ks, vs) [L, P,
+        ...]`` at cache layer ``layer``, by the pages the piece fills and in
+        place: every page but the null page holds afterwards, bit for bit,
+        what the scatter leaves there (the null page is where the scatter
+        diverts a pad position's write, in no defined order; nothing is
+        diverted here, and nothing reads it unmasked)."""
+        from ..ops.paged_attention import paged_piece_write
+        from .dense import _quantize_kv
+
+        k_q, k_s = _quantize_kv(k_rot)
+        v_q, v_s = _quantize_kv(v_new)
+        start = q_pos[:, 0]
+        offset = start % self.page_size
+        return paged_piece_write(
+            stacks,
+            tuple(self._piece_tiles(a, offset) for a in (k_q, v_q, k_s, v_s)),
+            layer, self.page_table, start, num_new,
+            **self._kernel_name("write"),
+        )
+
     def attend(self, layer_state, q, k_new, v_new, rope, q_pos, num_new,
                sliding_window, attention_fn, scale=None):
         if self.use_ragged and q.shape[1] > 1:
@@ -1116,19 +1176,28 @@ class QuantizedPagedKVCache(PagedKVCache):
                 quantized_ragged_paged_attention,
             )
 
-            lk, lv, lks, lvs = layer_state
             q_rot = apply_rope(q, rope.cos, rope.sin)
             k_rot = apply_rope(k_new, rope.cos, rope.sin)
-            new = self._scatter_q(
-                lk, lv, lks, lvs, k_rot, v_new, q_pos, num_new
-            )
+            if layer_state[0].ndim == 5:
+                # the whole stacks and the cache layer's index
+                # (``ragged_reads_whole_stacks``): written and read at
+                # (layer, page), the updated stacks handed back
+                *stacks, layer = layer_state
+                new = self._write_pages(
+                    tuple(stacks), layer, k_rot, v_new, q_pos, num_new
+                )
+            else:
+                layer = None
+                new = self._scatter_q(
+                    *layer_state, k_rot, v_new, q_pos, num_new
+                )
             out = quantized_ragged_paged_attention(
                 q_rot, new[0], new[2], new[1], new[3], self.page_table,
                 self.lengths + num_new, num_new,
-                scale=scale, sliding_window=sliding_window,
+                scale=scale, sliding_window=sliding_window, layer=layer,
                 **self._kernel_name("prefill"),
             )
-            return out, new
+            return out, tuple(new)
         if not self.use_kernel or q.shape[1] != 1:
             # Long prefill: flash over the dequantized pool view (see
             # cache/base.py flash_prefill_fn — the full-score path
@@ -1791,6 +1860,7 @@ _WINDOW_KERNELS = {
     "decode": "window_paged_fused_attention",
     "prefill": "window_ragged_paged_attention",
     "flush": "window_tail_flush",
+    "write": "window_piece_write",
 }
 
 

@@ -976,6 +976,12 @@ class InferenceEngine:
         # the (table slots, pad width) shapes each of the two is loaded at
         # (``_pair_widths``)
         self._fresh_widths, self._table_widths = set(), set()
+        # which of two counters a prefill-family dispatch's rows move
+        # (``_note_prefill``): whether the cache writes and reads its pool in
+        # place by (layer, page) there, or is handed a layer's planes
+        self._pool_inplace = bool(
+            getattr(self.cache, "ragged_reads_whole_stacks", False)
+        )
         self._prefill_batch = jax.jit(_prefill_rows, **dk)
         self._prefill_batch_standalone = jax.jit(_prefill_rows_standalone, **dk)
         mdk = (
@@ -1879,8 +1885,14 @@ class InferenceEngine:
         real row, which is what the ragged kernel sees as ``q_start`` and
         ``num_new``; over a paged cache the table's width goes with it.
         ``rows`` are the sessions whose prompt the dispatch carries, for
-        the dispatch clock (``_clocked``)."""
+        the dispatch clock (``_clocked``). Its real rows count as
+        ``prefill_pool_inplace_rows`` or ``prefill_pool_scatter_rows``, by
+        the cache (``ragged_reads_whole_stacks``)."""
         self._noted_rows = rows
+        if self._pool_inplace:
+            self.metrics.counter("prefill_pool_inplace_rows", len(row_spans))
+        else:
+            self.metrics.counter("prefill_pool_scatter_rows", len(row_spans))
         paged = self.ccfg.kind == "paged"
         self.plan.note_dispatch(
             kind, shape, sum(n for _, n in row_spans), row_spans=row_spans,

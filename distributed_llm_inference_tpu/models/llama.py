@@ -693,6 +693,15 @@ def block_apply(
         layer_params, _grouped_stacks(cfg, layer_params, x)
     )
 
+    # A prefill-family dispatch over a cache that says so hands the layer the
+    # WHOLE carried stacks and its row's index, and takes the updated stacks
+    # back: the cache writes and reads them at (layer, page), and no layer's
+    # plane is sliced out of the carry and written back (a copy of the plane
+    # each way, and XLA's scatter wants two relayouts between them).
+    whole_state = x.shape[1] > 1 and getattr(
+        cache, "ragged_reads_whole_stacks", False
+    )
+
     def step(carry, xs, at=None):
         x, bufs = carry
         p, idx = xs
@@ -700,15 +709,20 @@ def block_apply(
         p = {**p, **_layer_views(whole_w, idx - first_layer if first_layer else idx)}
         if at is not None:
             idx = at    # a lap's row of the cache; the weights' stays above
-        rows = (idx,) * len(bufs) if rows_of is None else rows_of(idx)
-        layer_state = tuple(
-            jax.lax.dynamic_index_in_dim(b, r, 0, keepdims=False)
-            for b, r in zip(bufs, rows)
-        )
+        if whole_state:
+            layer_state = (*bufs, idx)
+        else:
+            rows = (idx,) * len(bufs) if rows_of is None else rows_of(idx)
+            layer_state = tuple(
+                jax.lax.dynamic_index_in_dim(b, r, 0, keepdims=False)
+                for b, r in zip(bufs, rows)
+            )
         out, new_state = _decoder_layer(
             cfg, p, x, layer_state, cache, rope, q_pos, num_new, attention_fn,
             index_rope, segment,
         )
+        if whole_state:
+            return (out, tuple(new_state)), None
         bufs = tuple(
             jax.lax.dynamic_update_index_in_dim(b, n, r, 0)
             for b, n, r in zip(bufs, new_state, rows)

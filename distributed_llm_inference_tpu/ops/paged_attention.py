@@ -1492,3 +1492,105 @@ def paged_tail_flush(
         ),
     )(page_table.astype(jnp.int32), base_len.astype(jnp.int32),
       tail_len.astype(jnp.int32), *tails, *pools)
+
+
+def paged_piece_write(
+    pools,
+    tiles,
+    layer: jnp.ndarray,
+    page_table: jnp.ndarray,
+    start: jnp.ndarray,
+    num_new: jnp.ndarray,
+    interpret: Optional[bool] = None,
+    name: str = "paged_piece_write",
+):
+    """Write a prefill piece into the WHOLE page stacks at ``(layer, page)``,
+    by the pages it fills, in place.
+
+    ``pools``: the carried stacks, values ``[L, P, Hkv, PS, D]`` and scale
+    rows ``[L, P, Hkv, PS]`` in any order; ``tiles``: the piece's planes in
+    the same order, each LAID OUT AS THE PAGES IT FILLS, ``[B, N, Hkv, PS(,
+    D)]``: tile ``i`` of row ``b`` holds what the piece has of table slot
+    ``start[b] // PS + i`` at the offsets it has it (elsewhere anything);
+    ``layer``: the cache layer's index, a traced scalar; ``start`` /
+    ``num_new`` ``[B]``: the row's first position of the piece and its valid
+    tokens. Grid step ``(b, i)`` round-trips one physical page through VMEM:
+    positions in ``[start, start + num_new)`` take the tile's value, every
+    other keeps the page's (a first or last page that the piece fills in
+    part, a continuation chunk that starts inside a page), so a position a
+    per-position scatter with ``mode="drop"`` would not write is not written.
+    A step past the row's last page (``num_new`` 0, pad width past the
+    prompt, a slot past the table) visits the null page 0 and writes back
+    what it read. The stacks keep their layout and are aliased to the
+    results: no operation has a layer's plane as its value
+    (:func:`paged_tail_flush` is the same round trip for a decode window's
+    tail, every layer in one call)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    ps = pools[0].shape[3]
+    b, t = page_table.shape
+    n = tiles[0].shape[1]
+
+    def _page(bi, ji, table, first, nnew):
+        slot = first[bi] // ps + ji
+        live = (slot * ps < first[bi] + nnew[bi]) & (nnew[bi] > 0) & (slot < t)
+        return slot, live, jnp.where(live, table[bi, jnp.minimum(slot, t - 1)], 0)
+
+    def _pool_index(rank):
+        def index(bi, ji, lay, table, first, nnew):
+            page = _page(bi, ji, table, first, nnew)[2]
+            return (lay[0], page) + (0,) * (rank - 2)
+        return index
+
+    def _tile_index(rank):
+        def index(bi, ji, lay, table, first, nnew):
+            return (bi, ji) + (0,) * (rank - 2)
+        return index
+
+    def kernel(lay_ref, table_ref, first_ref, nnew_ref, *refs):
+        tile_refs = refs[: len(tiles)]
+        pool_in = refs[len(tiles) : 2 * len(tiles)]
+        pool_out = refs[2 * len(tiles) :]
+        bi = pl.program_id(0)
+        ji = pl.program_id(1)
+        slot, live, _ = _page(bi, ji, table_ref, first_ref, nnew_ref)
+        lo = first_ref[bi]
+        hi = lo + nnew_ref[bi]
+        for tile_ref, in_ref, out_ref in zip(tile_refs, pool_in, pool_out):
+            values = len(in_ref.shape) == 5
+            pos = slot * ps + jax.lax.broadcasted_iota(
+                jnp.int32, (1, ps, 1) if values else (1, ps), 1
+            )
+            hit = live & (pos >= lo) & (pos < hi)
+            out_ref[0, 0] = jnp.where(hit, tile_ref[0, 0], in_ref[0, 0])
+
+    def _specs(arrays, index):
+        return [
+            pl.BlockSpec((1, 1, *a.shape[2:]), index(a.ndim)) for a in arrays
+        ]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, n),
+        in_specs=[*_specs(tiles, _tile_index), *_specs(pools, _pool_index)],
+        out_specs=tuple(_specs(pools, _pool_index)),
+        scratch_shapes=[],
+    )
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        out_shape=tuple(
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools
+        ),
+        grid_spec=grid_spec,
+        interpret=interpret,
+        # inputs counting scalars: layer 0, table 1, start 2, num_new 3, then
+        # the tiles, then the stacks, each aliased to its result
+        input_output_aliases={
+            4 + len(tiles) + i: i for i in range(len(pools))
+        },
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), page_table.astype(jnp.int32),
+      start.astype(jnp.int32), num_new.astype(jnp.int32), *tiles, *pools)

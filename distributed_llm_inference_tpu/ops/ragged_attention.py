@@ -344,7 +344,7 @@ def _block_q(s, hq, hkv, d, page_size, q_itemsize, kv_itemsize):
 
 def _prep(q, k_pages, block_q):
     b, s, hq, d = q.shape
-    _, hkv, page_size, _ = k_pages.shape
+    hkv, page_size = k_pages.shape[-3:-1]   # a layer's plane or a whole stack
     if block_q is None:
         block_q = _block_q(
             s, hq, hkv, d, page_size, q.dtype.itemsize,
@@ -358,22 +358,25 @@ def _index_maps(block_q, page_size, sliding_window):
     """The grid's index maps: a dead tile (:func:`_tile_live`) is clamped to
     the null page 0, so consecutive dead steps name one block and nothing
     is fetched for them (BlockSpec semantics skip an unchanged block); the
-    kernel bodies skip the same tiles by the same predicate."""
+    kernel bodies skip the same tiles by the same predicate. Over whole
+    ``[L, P, ...]`` stacks the cache layer's index is one more scalar-prefetch
+    operand (``layer``) and the page maps name ``(layer, page)``, as
+    ``paged_attention.quantized_paged_fused_attention``'s do."""
 
-    def _page(bi, qi, ji, table, lens, qstart, nnew):
+    def _page(bi, qi, ji, table, lens, qstart, nnew, *layer):
         live = _tile_live(
             qi, ji, qstart[bi], nnew[bi], lens[bi], block_q=block_q,
             page_size=page_size, sliding_window=sliding_window,
         )
-        return jnp.where(live, table[bi, ji], 0)
+        return (*(ref[0] for ref in layer), jnp.where(live, table[bi, ji], 0))
 
-    def _page_index(bi, qi, ji, table, lens, qstart, nnew):
-        return (_page(bi, qi, ji, table, lens, qstart, nnew), 0, 0, 0)
+    def _page_index(*at):
+        return (*_page(*at), 0, 0, 0)
 
-    def _page_index3(bi, qi, ji, table, lens, qstart, nnew):
-        return (_page(bi, qi, ji, table, lens, qstart, nnew), 0, 0)
+    def _page_index3(*at):
+        return (*_page(*at), 0, 0)
 
-    def _q_index(bi, qi, ji, table, lens, qstart, nnew):
+    def _q_index(bi, qi, ji, table, lens, qstart, nnew, *layer):
         return (bi, qi, 0, 0, 0)
 
     return _page_index, _page_index3, _q_index
@@ -383,7 +386,7 @@ def _select_index(block_q, page_size, sliding_window):
     """Index map of a selection ``[B, T, S, PS]``: the (page, q-block) tile
     of a live step, and one unchanged tile through a run of dead ones."""
 
-    def _sel_index(bi, qi, ji, table, lens, qstart, nnew):
+    def _sel_index(bi, qi, ji, table, lens, qstart, nnew, *layer):
         live = _tile_live(
             qi, ji, qstart[bi], nnew[bi], lens[bi], block_q=block_q,
             page_size=page_size, sliding_window=sliding_window,
@@ -491,6 +494,7 @@ def quantized_ragged_paged_attention(
     interpret: Optional[bool] = None,
     name: str = "quantized_ragged_paged_attention",
     select: Optional[jnp.ndarray] = None,
+    layer: Optional[jnp.ndarray] = None,
 ):
     """As :func:`ragged_paged_attention` over int8 pages with per-(slot,
     head) scale planes (``ks_pages``/``vs_pages``: ``[P, Hkv, page_size]``
@@ -498,8 +502,24 @@ def quantized_ragged_paged_attention(
     ``ops/sparse_attention.py``): nonzero where query ``s`` of the dispatch
     attends to the position at table slot ``t``, offset ``p``; one more
     pipelined block a tile and one more term of its mask. Without it the
-    call traces to the program it always did."""
-    _, hkv, page_size, _ = k_pages.shape
+    call traces to the program it always did.
+
+    The pool's two forms are told apart by the operands' rank. A layer's
+    planes (``k_pages`` 4-D): the program above. The WHOLE stacks
+    (``[L, P, Hkv, page_size, D]`` / ``[L, P, Hkv, page_size]``) with
+    ``layer``, the cache layer's index (a traced scalar): the index is one
+    more scalar-prefetch operand and every page block is fetched at
+    ``(layer, page)`` of the stack, so no caller slices a layer's plane out
+    of the pool to feed the call (a slice is a copy of the plane through HBM,
+    a layer: ``cache/paged.py``'s prefill hands the stacks over); a tile
+    computes what it computed."""
+    stacked = k_pages.ndim == 5
+    if stacked != (layer is not None):
+        raise ValueError(
+            "whole [L, P, ...] stacks come with the cache layer's index, a "
+            "layer's [P, ...] planes without one"
+        )
+    hkv, page_size = k_pages.shape[-3:-1]
     b, s, hq, d, bq, s_pad = _prep(q, k_pages, block_q)
     t = page_table.shape[1]
     g = hq // hkv
@@ -518,15 +538,18 @@ def quantized_ragged_paged_attention(
         bq, page_size, sliding_window
     )
 
+    # a stack's layer axis is squeezed out of its blocks: the kernel sees a
+    # page's ``[1, Hkv, PS(, D)]`` in both forms
+    at = (None,) if stacked else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5 if stacked else 4,
         grid=(b, s_pad // bq, t),
         in_specs=[
             pl.BlockSpec((1, bq, hkv, g, d), _q_index),
-            pl.BlockSpec((1, hkv, page_size, d), _page_index),
-            pl.BlockSpec((1, hkv, page_size), _page_index3),
-            pl.BlockSpec((1, hkv, page_size, d), _page_index),
-            pl.BlockSpec((1, hkv, page_size), _page_index3),
+            pl.BlockSpec((*at, 1, hkv, page_size, d), _page_index),
+            pl.BlockSpec((*at, 1, hkv, page_size), _page_index3),
+            pl.BlockSpec((*at, 1, hkv, page_size, d), _page_index),
+            pl.BlockSpec((*at, 1, hkv, page_size), _page_index3),
             *([] if select is None else [pl.BlockSpec(
                 (1, 1, bq, page_size),
                 _select_index(bq, page_size, sliding_window),
@@ -557,15 +580,25 @@ def quantized_ragged_paged_attention(
                 select, ((0, 0), (0, 0), (0, s_pad - s), (0, 0))
             )
         extra = (select.astype(jnp.int8),)
+    scalars = (
+        page_table.astype(jnp.int32), kv_lengths.astype(jnp.int32),
+        q_start.astype(jnp.int32), num_new.astype(jnp.int32),
+    )
+    if stacked:
+        body = kernel
+
+        def kernel(table_ref, len_ref, qstart_ref, nnew_ref, layer_ref, *refs):
+            # the layer is spent in the index maps
+            body(table_ref, len_ref, qstart_ref, nnew_ref, *refs)
+
+        scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
     out = pl.pallas_call(
         kernel,
         name=name,
         out_shape=jax.ShapeDtypeStruct((b, s_pad, hkv, g, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(page_table.astype(jnp.int32), kv_lengths.astype(jnp.int32),
-      q_start.astype(jnp.int32), num_new.astype(jnp.int32),
-      qr, k_pages, ks_pages, v_pages, vs_pages, *extra)
+    )(*scalars, qr, k_pages, ks_pages, v_pages, vs_pages, *extra)
     return out[:, :s].reshape(b, s, hq, d)
 
 

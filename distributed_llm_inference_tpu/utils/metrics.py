@@ -53,6 +53,8 @@ METRICS = {
     "ring_prefills": ("counter", "Prefills served by the ring pipeline"),
     "prefill_fresh_rows": ("counter", "Single-row final prefills over the dispatch's own K/V, installed as whole pages"),
     "prefill_table_rows": ("counter", "Single-row final prefills through the row's page table or dense row"),
+    "prefill_pool_inplace_rows": ("counter", "Rows of prefill-family dispatches whose cache writes whole pages and reads at (layer, page) of the carried pool stacks"),
+    "prefill_pool_scatter_rows": ("counter", "Rows of prefill-family dispatches whose cache is handed a layer's planes (a position scatter, a dense row, a fresh install)"),
     "prefix_cached_tokens": ("counter", "Prompt tokens served from prefix cache"),
     # prefixstore: CoW sharing / host-DRAM spill tier / prefix routing
     "prefix_hit_rate": ("gauge", "Cumulative fraction of prompt tokens reused"),

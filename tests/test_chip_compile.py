@@ -797,6 +797,106 @@ def _ouro(s):
     return cfg, params, cache
 
 
+# mistral-7b.chat / .reason: 32 layers, int8 weights, the cell's int8 pool of
+# 1280 pages, 32 rows, a 64-slot table, the 2048-wide pad width.
+def _mistral(s):
+    import json
+    import pathlib
+
+    from benchmark import server
+    from benchmark.weights import dense_gqa as maker
+    from distributed_llm_inference_tpu.cache.paged import QuantizedPagedKVCache
+    from distributed_llm_inference_tpu.config import ModelConfig
+
+    conf = json.loads(
+        (pathlib.Path(server.REPO) / "benchmark/configs/mistral-7b.json").read_text()
+    )
+    cfg = ModelConfig.from_hf_config(server.hf_block(conf))
+    abstract = lambda tree: jax.tree.map(lambda x: s(x.shape, x.dtype), tree)
+    serve = conf["serve"]
+    assert serve["cache"]["kv_quant"] == "int8" and serve["weights"] == "int8"
+    params = abstract(jax.eval_shape(
+        lambda: maker.make(cfg, 0, jnp.bfloat16, "int8")
+    ))
+    cache = abstract(jax.eval_shape(lambda: QuantizedPagedKVCache.create(
+        cfg.num_layers, serve["engine"]["max_batch_size"],
+        serve["cache"]["num_pages"], serve["cache"]["page_size"], SLOTS,
+        cfg.num_kv_heads, cfg.head_dim, use_kernel=True, use_ragged=True,
+    )))
+    return cfg, params, cache
+
+
+#: arguments + temporaries of Ouro's 512-wide prefill on the parent of PR 58
+#: (commit 738f3c5; this file's compile against that tree): 13.72 GB of
+#: arguments and 2.02 GB of temporaries, the pool's planes on their way
+#: through the position scatter's layout
+OURO_PREFILL_HELD_BEFORE = 13_724_569_600 + 2_017_048_064
+
+
+@pytest.mark.parametrize("served,width", [("ouro", OURO_PAD), ("mistral", 2048)])
+def test_a_prefill_over_the_int8_pool_has_no_layers_plane_as_a_result(
+    chip, monkeypatch, served, width
+):
+    """``engine.py:_prefill_row`` at the served shapes of
+    ``ouro-2.6b.mathchat`` (the looped scan, 192 cache layers of 160 pages,
+    16 kv heads) and of ``mistral-7b.chat`` / ``.reason`` (32 layers of 1280
+    pages, 8 kv heads, int8 weights): the cache writes the piece by whole
+    pages and the ragged kernel reads at (layer, page) of the carried stacks
+    (``QuantizedPagedKVCache.ragged_reads_whole_stacks``), so NO operation
+    of the compiled program has a layer's K or V plane, or a layer's scale
+    plane, as its result: not the slice out of the carry, not the relayout
+    XLA's scatter wants, not the relayout back, not a copy on the way to the
+    write-back (four of them a plane a layer before: 16 x 21 MB x 192 a
+    dispatch at Ouro's pool, 16 x 84 MB x 32 at Mistral's, whatever the
+    piece's width). The pool is still the donated argument, aliased to the
+    result; Ouro's arguments and temporaries stand under the parent's."""
+    import re
+
+    from distributed_llm_inference_tpu.models import llama
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = chip
+    cfg, params, cache = (_ouro if served == "ouro" else _mistral)(s)
+    layers, pages, hkv = cache.k_pages.shape[:3]
+
+    def prefill(params, tokens, cache, row, n_valid):
+        sub = cache.select_row(row)
+        logits, sub = llama.model_apply(
+            cfg, params, tokens, sub, n_valid[None], head="last"
+        )
+        return logits, cache.merge_row(sub, row)
+
+    compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+        params, s((1, width), I32), cache, s((), I32), s((), I32)
+    ).compile()
+    text = compiled.as_text()
+    assert " while(" in text and "input_output_alias" in text
+    for kernel in ("quantized_ragged_paged_attention", "paged_piece_write"):
+        assert kernel in text, kernel
+    results = [ln.split(" = ", 1) for ln in text.splitlines() if " = " in ln]
+    planes = (
+        rf"s8\[(1,)?{pages},{hkv},{PS},{D}\]", rf"f32\[(1,)?{pages},{hkv},{PS}\]",
+    )
+    made = r"\S* (copy|dynamic-slice|dynamic-update-slice|transpose|fusion|scatter)\("
+    assert not [
+        name + " = " + r[:90] for name, r in results for plane in planes
+        if re.match(plane + made, r)
+    ]
+    # every stack is carried whole through the layers' loop and handed to
+    # the kernels at (layer, page)
+    stack = rf"s8\[{layers},{pages},{hkv},{PS},{D}\]"
+    assert [r for _, r in results if re.match(r"\(?.*" + stack + r".* while\(", r)]
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.75 * 2 ** 30, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    if served == "ouro":
+        # what is left of the 2.0 GB: the two float32 scale stacks in the
+        # kernels' row-major layout, once a dispatch (0.5 GB)
+        assert held < OURO_PREFILL_HELD_BEFORE - 1.2e9, held
+    print(served, "arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode-scan"])
 def test_the_looped_programs_at_ouros_published_widths(chip, monkeypatch, program):
     """The looped prefill (one row's 512-wide piece through its page table,
@@ -849,10 +949,13 @@ def test_the_looped_programs_at_ouros_published_widths(chip, monkeypatch, progra
     mem = compiled.memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     # 5.34 GB of bf16 weights and the pool (8.3 GB at 160 pages) are
-    # arguments, the pool aliased to the result; the temporaries (2.0 and
-    # 2.1 GB: the pool's float32 scale planes, 16 heads minor, padded
-    # eightfold to the 128 lanes on their way through the scatter's layout)
-    # fit what is left of the 16.9 GB (15.75 GiB) the compiler may use
+    # arguments, the pool aliased to the result; the temporaries fit what
+    # is left of the 16.9 GB (15.75 GiB) the compiler may use: 0.50 GB for
+    # the prefill since PR 58 (the pool's two float32 scale stacks, which
+    # lie in the device's compact layout between dispatches, in the kernels'
+    # row-major one: 64 offsets minor, padded to the 128 lanes; 2.0 GB
+    # before, the planes on their way through the scatter's layout) and 2.1
+    # GB for the scan
     assert mem.argument_size_in_bytes > 5.3e9 + 50e6 * pages
     assert held < 15.75 * 2 ** 30, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
     print(program, "arguments", mem.argument_size_in_bytes,

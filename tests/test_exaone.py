@@ -404,16 +404,21 @@ def test_what_a_two_pool_stack_cannot_do_is_refused_by_name(model):
 #: block of live pages as one tile. PR 46 regenerated Moonlight's six: its
 #: decode scans and these toy prefills (a few tokens: one row tile) take the
 #: live path of ``ops/moe.py`` where they dense-combined (traced here, off a
-#: TPU, with ``grouped_matmul``'s plain-XLA reference). Mistral's seven are
-#: cdb55a4's (and PR 42's) still.
+#: TPU, with ``grouped_matmul``'s plain-XLA reference). PR 58 regenerated
+#: Mistral's two kernel prefills: the int8 pool under the ragged kernel is
+#: written by whole pages and read at (layer, page) of the carried stacks
+#: there (``QuantizedPagedKVCache.ragged_reads_whole_stacks``; the other
+#: eleven, the int8 prefill without the kernel and every decode scan among
+#: them, are untouched by it). Mistral's other five are cdb55a4's (and PR
+#: 42's) still.
 OLD_STACKS = {
     "mistral.float.prefill": "ce04728d66ae8e7a",
     "mistral.int8.prefill": "0332a71023c2ddb3",
     "mistral.int8.decode_scan": "6fe8c360a5b7a039",
     "mistral.kernel.8x4.decode_scan": "36b335e3f969605d",
-    "mistral.kernel.8x4.prefill": "fe9e79069e35d0cc",
+    "mistral.kernel.8x4.prefill": "08a1619b4f8eea2a",
     "mistral.kernel.64x12.decode_scan": "7db7f3834be39c7c",
-    "mistral.kernel.64x12.prefill": "1701601a075c440e",
+    "mistral.kernel.64x12.prefill": "b05ce1beffbe99c6",
     "moonlight.float.prefill": "eac83a25e1c45a4d",
     "moonlight.int8.prefill": "c3335515e1e0ab1c",
     "moonlight.kernel.8x4.decode_scan": "ddd63de5e8155649",

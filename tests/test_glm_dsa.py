@@ -555,19 +555,24 @@ def test_what_cannot_carry_the_plane_is_refused_by_name():
 #: scans and these toy prefills (a few tokens: one row tile) take the live
 #: path of ``ops/moe.py`` where they dense-combined, and a routed layer's
 #: ``valid`` now marks a decode step's dead rows (traced here, off a TPU, with
-#: ``grouped_matmul``'s plain-XLA reference). Mistral's five are a45a1d5's
-#: still.
+#: ``grouped_matmul``'s plain-XLA reference). PR 58 regenerated the three
+#: kernel prefills over the int8 pool proper (Mistral's, Mixtral's, K-EXAONE's
+#: two pools): the piece is written by whole pages and read at (layer, page)
+#: of the carried stacks (``QuantizedPagedKVCache.ragged_reads_whole_stacks``);
+#: Keye's (the index plane's own ``attend``) and Moonlight's (the latent pool)
+#: kernel prefills, and every decode scan, are the digests they were.
+#: Mistral's other four are a45a1d5's still.
 OLD_STACKS = {
     "mistral.float.prefill": "ce04728d66ae8e7a",
     "mistral.int8.prefill": "0332a71023c2ddb3",
     "mistral.int8.decode_scan": "6fe8c360a5b7a039",
     "mistral.kernel.decode_scan": "7db7f3834be39c7c",
-    "mistral.kernel.prefill": "1701601a075c440e",
+    "mistral.kernel.prefill": "b05ce1beffbe99c6",
     "mixtral.float.prefill": "4e3b03187d0d0786",
     "mixtral.int8.prefill": "93ee1d0335f7b09d",
     "mixtral.int8.decode_scan": "9909d784bd1bcad9",
     "mixtral.kernel.decode_scan": "4c273ed9094c8b88",
-    "mixtral.kernel.prefill": "1af9a095fea921f2",
+    "mixtral.kernel.prefill": "cc5166cd764950bc",
     "moonlight.float.prefill": "eac83a25e1c45a4d",
     "moonlight.int8.prefill": "c3335515e1e0ab1c",
     "moonlight.kernel.decode_scan": "1a03c4bdef39fb36",
@@ -581,7 +586,7 @@ OLD_STACKS = {
     "exaone.int8.prefill": "40accbd22a444365",
     "exaone.int8.decode_scan": "7472671651de15b1",
     "exaone.kernel.decode_scan": "e5e628d5025e8a89",
-    "exaone.kernel.prefill": "c77701ce6ffac032",
+    "exaone.kernel.prefill": "99e11592f9999a59",
 }
 
 
