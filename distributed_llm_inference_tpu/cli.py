@@ -598,9 +598,27 @@ def cmd_trace(args) -> int:
             f.write(body)
         print(json.dumps({"event": "trace_written", "path": args.out,
                           "bytes": len(body)}), flush=True)
+    elif args.ticks:
+        for line in _ticks_lines(json.loads(body)):
+            print(line, flush=True)
     else:
         print(body, flush=True)
     return 0
+
+
+def _ticks_lines(doc: dict) -> List[str]:
+    """A ``/debug/ticks`` body as ``trace --ticks`` prints it, one JSON
+    object a line: the ticks, then the programs the process loaded with the
+    most load seconds first, then the boot marks."""
+    programs = sorted(
+        doc.get("programs", {}).items(),
+        key=lambda kv: -sum(v for k, v in kv[1].items() if k.endswith("_s")),
+    )
+    return [
+        json.dumps({"ticks": doc["ticks"]}),
+        json.dumps({"programs": dict(programs)}),
+        json.dumps({"boot": doc.get("boot")}),
+    ]
 
 
 def cmd_info(args) -> int:

@@ -57,7 +57,7 @@ from ..config import CacheConfig, EngineConfig, ModelConfig, PrefixConfig
 from ..models import llama
 from ..ops.ragged_attention import _block_q
 from ..utils.metrics import Metrics
-from ..utils.tracing import FlightRecorder, Span
+from ..utils.tracing import BOOT, FlightRecorder, Span
 from .plan import AttentionPlan
 from .sampling import SamplingOptions, SamplingParams, sample
 from .session import Session, SessionState
@@ -1382,6 +1382,7 @@ class InferenceEngine:
                 # (ADVICE r5 — mixed-composition rates bias the A/B).
                 "comp": None,
             }
+        BOOT.mark("engine_built", self.flight)
 
     def _sink_cap(self) -> int:
         """Stream-length bound for sink sessions. The bf16 ring rotates at
@@ -1583,6 +1584,8 @@ class InferenceEngine:
         ``trace`` is the request's distributed TraceContext (None for
         unsampled requests); it rides the Session for span attribution
         and never affects scheduling or tokens."""
+        if BOOT.first_request is None:
+            BOOT.mark("first_request", self.flight)
         return self._submit_session(
             prompt, options, deadline, sched_key=sched_key, trace=trace
         ).generation_id

@@ -11,7 +11,9 @@ no chunked encoding needed). Routes:
 * ``GET /healthz`` — liveness + drain state (+ trace recorder depth).
 * ``GET /debug/trace/<id>`` — one request's stitched cross-node trace
   (Chrome trace-event JSON; spans pulled from remote nodes on demand).
-* ``GET /debug/ticks`` — the engine flight recorder's per-tick ring.
+* ``GET /debug/ticks`` — the engine flight recorder's per-tick ring
+  (``"ticks"``), the programs the process loaded, by stage (``"programs"``),
+  and its boot marks (``"boot"``).
 
 Admission control: at ``ServingConfig.max_queue_depth`` gateway-in-flight
 completions, new ones get 429 + ``Retry-After`` (backpressure a load
@@ -35,6 +37,8 @@ from typing import Optional
 from ..config import SchedConfig, ServingConfig, TraceConfig
 from ..sched import Scheduler
 from ..utils.tracing import (
+    BOOT,
+    PROGRAM_LOADS,
     Span,
     SpanRecorder,
     TraceContext,
@@ -339,13 +343,19 @@ class ApiServer:
         writer.write(_response("200 OK", body))
         await writer.drain()
 
+    def _ticks_body(self) -> bytes:
+        return json.dumps({
+            "ticks": self.backend.flight_snapshot(),
+            "programs": PROGRAM_LOADS.snapshot(), "boot": BOOT.snapshot(),
+        }).encode()
+
     async def _debug_ticks(self, writer) -> None:
-        # Snapshot takes the recorder lock the engine drive thread also
-        # touches — executor keeps even that blip off the accept loop.
-        ticks = await asyncio.get_running_loop().run_in_executor(
-            None, self.backend.flight_snapshot
+        # The snapshots take locks the engine drive thread and JAX's
+        # monitoring also touch — executor keeps even that blip, and the
+        # ring's serialization, off the accept loop.
+        body = await asyncio.get_running_loop().run_in_executor(
+            None, self._ticks_body
         )
-        body = json.dumps({"ticks": ticks}).encode()
         writer.write(_response("200 OK", body))
         await writer.drain()
 

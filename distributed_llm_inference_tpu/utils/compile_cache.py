@@ -18,7 +18,12 @@ of its 128 executables (my chip run, PR 21).
 The same call starts the process's count of the programs it loads
 (``utils/tracing.py:PROGRAM_LOADS``, behind ``engine_program_loads`` and
 ``engine_program_load_seconds``), so an entry point counts from before its
-first trace, the weights' and the set-up's programs included.
+first trace, the weights' and the set-up's programs included. The seconds
+are kept by stage (``engine_program_load_{trace,lower,compile,cache_read}_
+seconds``: a load that this cache answered is ``cache_read``, one the
+backend compiled is ``compile``, and tracing and lowering are paid either
+way, since the cache's key is made from the lowered module) and by program
+(``PROGRAM_LOADS.programs``, the ``"programs"`` of ``/debug/ticks``).
 """
 
 from __future__ import annotations

@@ -246,7 +246,16 @@ METRICS = {
     "engine_program_load_seconds": (
         "counter", "Seconds tracing, lowering and compiling or reading them"
     ),
+    "engine_program_load_*_seconds": (
+        "counter", "The same by stage (tracing.LOAD_STAGES); the four sum to it"
+    ),
     "engine_compile_cache_hits": ("counter", "Of those, persistent-cache reads"),
+    # the process's boot (utils/tracing.py BootMarks; TraceConfig on), set as
+    # each mark is passed
+    "process_start_time_seconds": ("gauge", "Epoch seconds at which the OS started the process"),
+    "boot_*_seconds": (
+        "gauge", "Seconds from that start to a boot mark (tracing.BOOT_MARKS)"
+    ),
     # multi-tenant admission scheduler (sched/)
     "sched_admitted": ("counter", "Tickets admitted by the scheduler"),
     "sched_tenant_admit_*": ("counter", "Admitted tickets by tenant"),
@@ -399,5 +408,6 @@ class Metrics:
         for name in sorted(gauges):
             metric = clean(name)
             lines.append(f"# TYPE {metric} gauge")
-            lines.append(f"{metric} {gauges[name]:.10g}")
+            # thirteen digits: an epoch stamp to the millisecond
+            lines.append(f"{metric} {gauges[name]:.13g}")
         return "\n".join(lines) + "\n"

@@ -38,7 +38,9 @@ model.py:61,82``). This module supplies the two tiers the TPU rebuild needs:
   calls its programs bare, with no stamp, no thread and no counter.
   :class:`ProgramLoads` counts the programs the process traces, lowers and
   compiles or reads from the persistent cache, from JAX's own monitoring,
-  always: a set-up is counted from its first trace.
+  always: a set-up is counted from its first trace, by stage and by program.
+  :class:`BootMarks` dates the process's start and, from it, the engine's
+  construction and its first request: the rest of a set-up.
 
 Clocks: every stamp that leaves the process (``Span.start_s``, a tick's
 ``t`` and ``t0_ns``, a dispatch's ``enq_ns`` / ``ret_ns`` / ``ready_ns``) is
@@ -51,6 +53,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import logging
+import os
 import queue
 import random
 import threading
@@ -75,6 +78,10 @@ __all__ = [
     "CLOCK_LEASE_S",
     "ProgramLoads",
     "PROGRAM_LOADS",
+    "LOAD_STAGES",
+    "BootMarks",
+    "BOOT",
+    "BOOT_MARKS",
     "PHASES",
     "trace_span",
     "stitch_chrome_trace",
@@ -289,15 +296,31 @@ _LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
+#: The stages of a program's load, as :class:`ProgramLoads` keeps them: a
+#: ``backend_compile_duration`` is ``cache_read`` where its thread reported a
+#: retrieval from the persistent cache inside it, ``compile`` where not.
+LOAD_STAGES = ("trace", "lower", "compile", "cache_read")
+_TRACE, _LOWER, _COMPILE, _CACHE_READ = range(len(LOAD_STAGES))
+_STAGE_OF = {_TRACE_EVENT: _TRACE, _LOWER_EVENT: _LOWER, _COMPILE_EVENT: _COMPILE}
+_STAGE_KEYS = tuple(f"{stage}_s" for stage in LOAD_STAGES)
+#: The reports a thread keeps until a later one holds them. An unrolled
+#: stack's trace has a few reports a layer DIRECTLY inside it, and what has
+#: been dropped by then is counted twice: at 64 a probe that took 11.8 s read
+#: 16.9 s of tracing and lowering (PERF.md section 6, PR 60).
+_OPEN_REPORTS = 4096
+
 
 class _ThreadLoads(threading.local):
     """A thread's own of :class:`ProgramLoads`: the reported intervals not
     yet inside a later one (``stack``: start, seconds), the seconds since
-    its last load (``pending``), who watches (``sink``)."""
+    its last load by stage (``pending``), whether the persistent cache
+    answered the compile in progress (``cache_read``), who watches
+    (``sink``)."""
 
     def __init__(self):
         self.stack: List[Tuple[float, float]] = []
-        self.pending = 0.0
+        self.pending = [0.0] * len(LOAD_STAGES)
+        self.cache_read = False
         self.sink = None
 
 
@@ -308,7 +331,11 @@ class ProgramLoads:
     way); ``seconds``, of tracing, lowering and that compile or read, as JAX
     reports them; ``cache_hits``, the reads. The reports nest (a jitted
     function traced inside another's trace reports inside it), so the
-    seconds are those of the union of a thread's reported intervals.
+    seconds are those of the union of a thread's reported intervals, each
+    second under the stage of the innermost report that holds it
+    (``stages``, by :data:`LOAD_STAGES`: the four sum to ``seconds``).
+    ``programs`` keeps the same by program: what a thread gathered since its
+    last load goes to the ``fun_name`` its compile event names.
 
     One instance a process, :data:`PROGRAM_LOADS`; :meth:`install` registers
     its listener once (``enable_compile_cache`` calls it, so an entry point
@@ -321,6 +348,9 @@ class ProgramLoads:
         self.loads = 0
         self.seconds = 0.0
         self.cache_hits = 0
+        self.stages = [0.0] * len(LOAD_STAGES)
+        # fun_name -> {loads, cache_hits, <stage>_s...}
+        self.programs: Dict[str, Dict[str, float]] = {}
         self._lock = threading.Lock()
         self._installed = False
         self._local = _ThreadLoads()
@@ -338,14 +368,23 @@ class ProgramLoads:
     def unwatch(self) -> None:
         self._local.sink = None
 
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        """A copy of ``programs``, as ``/debug/ticks`` carries it: ``fun_name
+        -> {loads, cache_hits, trace_s, lower_s, compile_s, cache_read_s}``."""
+        with self._lock:
+            return {name: dict(row) for name, row in self.programs.items()}
+
     def _duration(self, event: str, seconds: float, **kw: Any) -> None:
+        local = self._local
         if event == _CACHE_READ_EVENT:
+            # reported inside the compile event it answers, on its thread
+            local.cache_read = True
             with self._lock:
                 self.cache_hits += 1
             return
-        if event not in (_TRACE_EVENT, _LOWER_EVENT, _COMPILE_EVENT):
+        stage = _STAGE_OF.get(event)
+        if stage is None:
             return
-        local = self._local
         stack = local.stack
         # the report comes as its interval ends: what started inside it was
         # reported before it, and is counted already
@@ -354,20 +393,108 @@ class ProgramLoads:
         while stack and stack[-1][0] >= start:
             inside += stack.pop()[1]
         stack.append((start, seconds))
-        del stack[:-64]
+        del stack[:-_OPEN_REPORTS]
         own = max(0.0, seconds - inside)
-        loaded = event == _COMPILE_EVENT
+        loaded = stage == _COMPILE
+        if loaded and local.cache_read:
+            stage, local.cache_read = _CACHE_READ, False
+        pending = local.pending
+        pending[stage] += own
         with self._lock:
             self.seconds += own
-            self.loads += int(loaded)
-        local.pending += own
-        if loaded:
-            whole, local.pending = local.pending, 0.0
-            if local.sink is not None:
-                local.sink(kw.get("fun_name", "?"), whole)
+            self.stages[stage] += own
+            if loaded:
+                # the compile event names the program: what the thread
+                # gathered since its last load is this program's
+                name = kw.get("fun_name", "?")
+                self.loads += 1
+                row = self.programs.get(name)
+                if row is None:
+                    row = self.programs[name] = {
+                        "loads": 0, "cache_hits": 0,
+                        **{key: 0.0 for key in _STAGE_KEYS},
+                    }
+                row["loads"] += 1
+                row["cache_hits"] += int(stage == _CACHE_READ)
+                for key, s in zip(_STAGE_KEYS, pending):
+                    row[key] += s
+        if not loaded:
+            return
+        whole = sum(pending)
+        pending[:] = [0.0] * len(LOAD_STAGES)
+        if local.sink is not None:
+            local.sink(name, whole)
 
 
 PROGRAM_LOADS = ProgramLoads()
+
+#: What :class:`BootMarks` dates, in the order a process passes them.
+BOOT_MARKS = ("engine_build", "engine_built", "first_request")
+
+
+def _process_start() -> float:
+    """Epoch seconds at which the OS started this process: its start in
+    clock ticks since the machine's boot (``/proc/self/stat``, field 22)
+    against the boot clock's reading now. Where the OS does not say, now:
+    this module's import."""
+    now = time.time()
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rpartition(")")[2].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - (
+            ticks / os.sysconf("SC_CLK_TCK")
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return now
+    return now - age if age >= 0.0 else now
+
+
+class BootMarks:
+    """The process's life up to its first request, on the clock every other
+    stamp that leaves the process uses: ``start``, epoch seconds at which the
+    OS started it (not the first line Python ran), and as seconds since it
+    ``engine_build`` (the first :class:`FlightRecorder` made, which an
+    engine's constructor does as it starts: interpreter, imports, backend
+    and weights lie before it), ``engine_built`` (the constructor left) and
+    ``first_request`` (the first ``submit``: a probe and a server's bind lie
+    before it). Each mark is written once, by the first to come by, and
+    only for a recorder: an engine without one marks nothing, as it records
+    nothing. :meth:`mark` also sets the recorder's gauges
+    (``process_start_time_seconds``, ``boot_<mark>_seconds``) of what is
+    written so far, so every traced engine's ``/metrics`` tells the
+    process's boot.
+
+    One instance a process, :data:`BOOT`."""
+
+    def __init__(self):
+        self.start = _process_start()
+        self.engine_build: Optional[float] = None
+        self.engine_built: Optional[float] = None
+        self.first_request: Optional[float] = None
+        self._lock = threading.Lock()
+
+    def mark(self, name: str, recorder: Optional["FlightRecorder"]) -> None:
+        if recorder is None:
+            return
+        with self._lock:
+            if getattr(self, name) is None:
+                setattr(self, name, time.time() - self.start)
+        m = recorder.metrics
+        if m is not None:
+            m.gauge("process_start_time_seconds", self.start)
+            for passed in BOOT_MARKS:
+                seconds = getattr(self, passed)
+                if seconds is not None:
+                    m.gauge(f"boot_{passed}_seconds", seconds)
+
+    def snapshot(self) -> Dict[str, Optional[float]]:
+        """``{"start": epoch seconds, <mark>: seconds since it or None}``,
+        as ``/debug/ticks`` carries it."""
+        return {"start": self.start,
+                **{name: getattr(self, name) for name in BOOT_MARKS}}
+
+
+BOOT = BootMarks()
 
 #: Seconds the dispatch clock stays armed after a read of the ticks
 #: (``FlightRecorder.snapshot``): longer than any poll of ``/debug/ticks``
@@ -704,7 +831,9 @@ class FlightRecorder:
         # the counters last had them
         self._compiled: List[Tuple[str, float]] = []
         self._loads_seen = (0, 0.0, 0)
+        self._stages_seen = [0.0] * len(LOAD_STAGES)
         PROGRAM_LOADS.install()
+        BOOT.mark("engine_build", self)
 
     def record(self, **fields: Any) -> None:
         with self._lock:
@@ -779,6 +908,10 @@ class FlightRecorder:
                 m.counter("engine_program_loads", seen[0] - was[0])
                 m.counter("engine_program_load_seconds", seen[1] - was[1])
                 m.counter("engine_compile_cache_hits", seen[2] - was[2])
+                stages = list(loads.stages)
+                for name, now, then in zip(LOAD_STAGES, stages, self._stages_seen):
+                    m.counter(f"engine_program_load_{name}_seconds", now - then)
+                self._stages_seen = stages
         fields["t0_ns"] = self._t0
         fields["host_ms"] = (self._end - self._t0) / 1e6
         for name, seconds in zip(PHASES, acc):

@@ -472,15 +472,69 @@ def test_http_trace_id_debug_trace_ticks_and_healthz():
         assert req["dur"] >= max(e["dur"] for e in doc["traceEvents"])
         c3, r3 = _get(server.port, "/debug/ticks")
         assert r3.status == 200
-        ticks = json.loads(r3.read())["ticks"]
+        body = json.loads(r3.read())
         c3.close()
+        ticks = body["ticks"]
         assert ticks and len(ticks) <= 64
         assert any(t["occupancy"] > 0 for t in ticks)
+        # beside the ticks: what the process loaded, by program and stage,
+        # and its boot marks, all passed by now
+        assert set(body) == {"ticks", "programs", "boot"}
+        rows = body["programs"].values()
+        assert rows and all(set(r) == {
+            "loads", "cache_hits", "trace_s", "lower_s", "compile_s",
+            "cache_read_s",
+        } for r in rows)
+        assert sum(r["loads"] for r in rows) >= len(rows)
+        boot = body["boot"]
+        assert set(boot) == {"start", *tracing.BOOT_MARKS}
+        assert 0 < boot["engine_build"] <= boot["engine_built"] <= boot["first_request"]
+        assert boot["start"] + boot["first_request"] <= time.time()
+        c5, r5 = _get(server.port, "/metrics")
+        text = r5.read().decode()
+        c5.close()
+        for name in ("process_start_time_seconds", "boot_engine_build_seconds",
+                     "boot_engine_built_seconds", "boot_first_request_seconds",
+                     *(f"engine_program_load_{s}_seconds_total"
+                       for s in tracing.LOAD_STAGES)):
+            assert f"dli_{name} " in text, name
         c4, r4 = _get(server.port, "/healthz")
         health = json.loads(r4.read())
         c4.close()
         assert health["trace"]["depth"] >= 1
         assert health["trace"]["dropped"] == 0
+
+
+@pytest.mark.http
+def test_trace_ticks_prints_the_programs_most_seconds_first_then_the_boot(capsys):
+    """``distribute trace --ticks``: three JSON lines, the ticks as they
+    were, the programs' rows ordered by their load seconds, the boot marks."""
+    import argparse
+
+    from distributed_llm_inference_tpu import cli
+
+    with serving(trace_cfg=TraceConfig(ticks_capacity=64)) as (server, _b):
+        conn, resp = _post(server.port, {"prompt": [1, 2, 3], "max_tokens": 4})
+        resp.read()
+        conn.close()
+        assert cli.cmd_trace(argparse.Namespace(
+            url=f"http://127.0.0.1:{server.port}/", ticks=True, trace_id=None,
+            out=None, timeout=10.0,
+        )) == 0
+    ticks, programs, boot = (
+        json.loads(l) for l in capsys.readouterr().out.strip().splitlines()[-3:]
+    )
+    assert list(ticks) == ["ticks"] and ticks["ticks"]
+    rows = list(programs["programs"].values())
+    seconds = [
+        sum(r[f"{s}_s"] for s in tracing.LOAD_STAGES) for r in rows
+    ]
+    assert len(rows) > 1 and seconds == sorted(seconds, reverse=True)
+    assert set(boot["boot"]) == {"start", *tracing.BOOT_MARKS}
+    # a body without the two keys (an older gateway) prints what it has
+    assert [json.loads(l) for l in cli._ticks_lines({"ticks": []})] == [
+        {"ticks": []}, {"programs": {}}, {"boot": None},
+    ]
 
 
 @pytest.mark.http
@@ -651,5 +705,5 @@ def test_without_a_trace_config_there_is_no_clock():
     for name in ("engine_device_", "engine_dispatches_", "engine_decode_steps",
                  "engine_enqueue_seconds", "engine_first_token_prefill",
                  "engine_first_token_deliver", "engine_program_load",
-                 "engine_compile_cache_hits"):
+                 "engine_compile_cache_hits", "process_start_time", "boot_"):
         assert name not in text, name

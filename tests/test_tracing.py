@@ -486,6 +486,210 @@ def test_program_loads_count_nested_reports_once(monkeypatch):
     assert loads.loads == 2 and len(heard) == 1
 
 
+_EVENTS = {
+    "trace": "/jax/core/compile/jaxpr_trace_duration",
+    "lower": "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "compile": "/jax/core/compile/backend_compile_duration",
+    "read": "/jax/compilation_cache/cache_retrieval_time_sec",
+}
+
+
+def _feed(loads, monkeypatch, reports, fun_name="jit_step"):
+    """``(end, kind, seconds)`` reports through the listener, each at its
+    end on a clock the test holds."""
+    now = [0.0]
+    monkeypatch.setattr(tracing.time, "time", lambda: now[0])
+    for at, kind, seconds in reports:
+        now[0] = at
+        loads._duration(_EVENTS[kind], seconds, fun_name=fun_name)
+
+
+@pytest.mark.parametrize("reports,want", [
+    # the reports of ``..._count_nested_reports_once``: the inner trace's
+    # 2 s and the outer's own 3 s are tracing, the cache answered the compile
+    ([(100.0, "trace", 2.0), (101.0, "trace", 5.0), (102.5, "lower", 1.0),
+      (106.0, "read", 0.5), (107.0, "compile", 4.0)],
+     {"trace": 5.0, "lower": 1.0, "compile": 0.0, "cache_read": 4.0}),
+    # a jitted function traced, lowered and compiled INSIDE another's
+    # lowering: each second under the innermost report that holds it
+    ([(10.0, "trace", 1.0), (13.0, "trace", 0.5), (14.0, "lower", 0.75),
+      (16.0, "compile", 2.0), (17.0, "lower", 6.0), (20.0, "compile", 3.0)],
+     {"trace": 1.5, "lower": 0.75 + (6.0 - 0.5 - 0.75 - 2.0),
+      "compile": 5.0, "cache_read": 0.0}),
+    # reports that only touch (one ends as the next starts) nest nothing
+    ([(5.0, "trace", 1.0), (6.0, "lower", 1.0), (7.0, "compile", 1.0)],
+     {"trace": 1.0, "lower": 1.0, "compile": 1.0, "cache_read": 0.0}),
+], ids=["nested-traces-and-a-cache-read", "a-load-inside-a-lowering", "end-to-start"])
+def test_program_loads_keep_each_second_under_the_stage_that_owns_it(
+    monkeypatch, reports, want
+):
+    loads = tracing.ProgramLoads()
+    _feed(loads, monkeypatch, reports)
+    got = dict(zip(tracing.LOAD_STAGES, loads.stages))
+    assert got == pytest.approx(want)
+    assert sum(loads.stages) == pytest.approx(loads.seconds)
+    # and by program: the rows hold every second and every load
+    rows = loads.snapshot().values()
+    for stage in tracing.LOAD_STAGES:
+        assert sum(r[f"{stage}_s"] for r in rows) == pytest.approx(want[stage])
+    assert sum(r["loads"] for r in rows) == loads.loads
+    assert sum(r["cache_hits"] for r in rows) == loads.cache_hits
+
+
+def test_a_trace_with_hundreds_of_reports_directly_inside_it_counts_each_once(
+    monkeypatch,
+):
+    """An unrolled stack: 48 layers of 20 jitted calls each, every one a
+    report of its own inside ONE outer trace (PR 60: with the 64 newest kept,
+    the outer's own seconds held the 896 dropped ones a second time)."""
+    loads = tracing.ProgramLoads()
+    inner = [(10.0 + 0.01 * (i + 1), "trace", 0.005) for i in range(960)]
+    _feed(loads, monkeypatch, inner + [
+        (20.0, "trace", 10.5),          # 9.5..20: the 960 and 5.7 s of its own
+        (21.0, "lower", 1.0), (23.0, "compile", 2.0),
+    ])
+    got = dict(zip(tracing.LOAD_STAGES, loads.stages))
+    assert got == pytest.approx(
+        {"trace": 10.5, "lower": 1.0, "compile": 2.0, "cache_read": 0.0})
+    assert loads.seconds == pytest.approx(13.5)
+    assert loads.snapshot()["jit_step"]["trace_s"] == pytest.approx(10.5)
+
+
+def test_only_a_retrieval_inside_it_makes_a_compile_a_cache_read(monkeypatch):
+    """The retrieval is reported inside the compile event it answers, on its
+    thread: that compile is ``cache_read``; the next one, with no retrieval
+    of its own, is ``compile`` again, and so is one on another thread while
+    this thread's retrieval waits for its compile event."""
+    loads = tracing.ProgramLoads()
+    _feed(loads, monkeypatch, [
+        (10.0, "read", 0.25), (10.5, "compile", 1.0),      # a hit: 1 s
+        (20.0, "compile", 3.0),                             # a miss: 3 s
+        (30.0, "read", 0.5),                                # pending here
+    ])
+    other = threading.Thread(target=lambda: loads._duration(
+        _EVENTS["compile"], 2.0, fun_name="jit_other"))
+    other.start()
+    other.join()
+    _feed(loads, monkeypatch, [(31.0, "compile", 0.75)])
+    got = dict(zip(tracing.LOAD_STAGES, loads.stages))
+    assert got == pytest.approx(
+        {"trace": 0.0, "lower": 0.0, "compile": 5.0, "cache_read": 1.75})
+    assert (loads.loads, loads.cache_hits) == (4, 2)
+    rows = loads.snapshot()
+    assert rows["jit_other"] == {
+        "loads": 1, "cache_hits": 0, "trace_s": 0.0, "lower_s": 0.0,
+        "compile_s": 2.0, "cache_read_s": 0.0,
+    }
+    assert rows["jit_step"]["loads"] == 3 and rows["jit_step"]["cache_hits"] == 2
+    assert rows["jit_step"]["cache_read_s"] == pytest.approx(1.75)
+    assert rows["jit_step"]["compile_s"] == pytest.approx(3.0)
+
+
+def test_two_programs_on_two_threads_keep_separate_rows(monkeypatch):
+    """What a thread gathered since its last load is its own: two threads
+    tracing at once, each row gets its thread's seconds and no other's."""
+    loads = tracing.ProgramLoads()
+    clock = threading.local()           # each thread's reports end to start
+    monkeypatch.setattr(tracing.time, "time", lambda: clock.now)
+    traced = threading.Barrier(2)
+
+    def load(name, trace_s, compile_s):
+        clock.now = 100.0
+        loads._duration(_EVENTS["trace"], trace_s, fun_name=name)
+        traced.wait(timeout=10.0)       # both pending before either compiles
+        clock.now = 101.0
+        loads._duration(_EVENTS["lower"], 0.25, fun_name=name)
+        clock.now = 110.0
+        loads._duration(_EVENTS["compile"], compile_s, fun_name=name)
+
+    threads = [
+        threading.Thread(target=load, args=("jit_a", 1.0, 4.0)),
+        threading.Thread(target=load, args=("jit_b", 2.0, 8.0)),
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    rows = loads.snapshot()
+    assert set(rows) == {"jit_a", "jit_b"}
+    for name, trace_s, compile_s in (("jit_a", 1.0, 4.0), ("jit_b", 2.0, 8.0)):
+        assert rows[name] == {
+            "loads": 1, "cache_hits": 0, "trace_s": trace_s, "lower_s": 0.25,
+            "compile_s": compile_s, "cache_read_s": 0.0,
+        }
+    assert loads.seconds == pytest.approx(15.5) == pytest.approx(sum(loads.stages))
+
+
+def test_boot_marks_are_written_once_and_in_order(monkeypatch):
+    """A fresh process's marks: the first recorder writes ``engine_build``,
+    the constructor's end ``engine_built``, the first ``submit``
+    ``first_request``; a second engine moves none of them and shows the same
+    gauges; an engine without a recorder marks nothing."""
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    boot = tracing.BootMarks()
+    monkeypatch.setattr(tracing, "BOOT", boot)
+    monkeypatch.setattr(eng_mod, "BOOT", boot)
+    assert 0.0 < time.time() - boot.start < 24 * 3600.0     # the OS's, not ours
+    assert boot.snapshot() == {
+        "start": boot.start, "engine_build": None, "engine_built": None,
+        "first_request": None,
+    }
+    bare = small_engine()
+    bare.submit([1, 2, 3], SamplingOptions(max_new_tokens=2))
+    assert boot.engine_build is None and boot.first_request is None
+    assert "boot_" not in bare.metrics.prometheus()
+    eng = small_engine(trace_cfg=TraceConfig())
+    assert boot.first_request is None
+    assert eng.metrics.get_gauge("boot_engine_built_seconds") == boot.engine_built
+    assert "boot_first_request" not in eng.metrics.prometheus()
+    _serve(eng)
+    marks = boot.snapshot()
+    assert 0.0 < marks["engine_build"] <= marks["engine_built"] <= marks["first_request"]
+    assert marks["first_request"] <= time.time() - boot.start
+    second = small_engine(trace_cfg=TraceConfig())
+    _serve(second)
+    assert boot.snapshot() == marks
+    for m in (eng.metrics, second.metrics):
+        assert m.get_gauge("process_start_time_seconds") == boot.start
+        for name in tracing.BOOT_MARKS:
+            assert m.get_gauge(f"boot_{name}_seconds") == marks[name]
+    # /metrics keeps the start to the millisecond, epoch seconds as it is
+    line = next(
+        l for l in eng.metrics.prometheus().splitlines()
+        if l.startswith("dli_process_start_time_seconds ")
+    )
+    assert float(line.split()[1]) == pytest.approx(boot.start, abs=1e-3)
+
+
+def test_an_engine_that_served_a_request_shows_its_loads_by_stage():
+    """The four stage counters are on ``/metrics`` beside the sum, and sum to
+    it; with the persistent cache off (``conftest``) nothing is a cache
+    read; the programs' rows hold what the counters hold."""
+    from distributed_llm_inference_tpu.config import TraceConfig
+
+    eng = small_engine(trace_cfg=TraceConfig())
+    _serve(eng)
+    m = eng.metrics
+    stages = {
+        s: m.get_counter(f"engine_program_load_{s}_seconds")
+        for s in tracing.LOAD_STAGES
+    }
+    text = m.prometheus()
+    for s in tracing.LOAD_STAGES:
+        assert f"dli_engine_program_load_{s}_seconds_total" in text, s
+    assert stages["trace"] > 0 and stages["lower"] > 0 and stages["compile"] > 0
+    assert stages["cache_read"] == 0.0
+    assert sum(stages.values()) == pytest.approx(
+        m.get_counter("engine_program_load_seconds"))
+    rows = tracing.PROGRAM_LOADS.snapshot()
+    assert sum(r["loads"] for r in rows.values()) == tracing.PROGRAM_LOADS.loads
+    assert all(r["cache_hits"] == 0 and r["loads"] >= 1 for r in rows.values())
+    assert sum(
+        r[f"{s}_s"] for r in rows.values() for s in tracing.LOAD_STAGES
+    ) == pytest.approx(tracing.PROGRAM_LOADS.seconds, rel=1e-6)
+
+
 def test_a_process_that_exits_with_the_watcher_busy_exits_cleanly():
     """The watcher is a daemon thread that waits inside JAX: left there
     while the interpreter finalizes it aborts the process (exit 134), which
