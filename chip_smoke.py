@@ -636,6 +636,15 @@ def phase_serve(size: Size, seed: int, engine, cfg, log: CompileLog) -> dict:
         cache_growths=counters.get("cache_growths"),
         admission_order_errors=counters.get("admission_order_errors", 0),
     )
+    # the in-place sweep's scale rows (none while the table is under
+    # INPLACE_CTX, as the rehearsal's always is): a page size of whole
+    # tiles leaves the wrapper's gather of every table slot nothing
+    check(
+        "decode_scale_rows_came_by_the_page",
+        counters.get("decode_scale_rows_gathered", 0) == 0,
+        decode_scale_rows_by_page=counters.get("decode_scale_rows_by_page", 0),
+        decode_pages_live=counters.get("decode_pages_live", 0),
+    )
     emit({
         "phase": "serve", "layers": cfg.num_layers,
         "smoke_reading_compile_s": round(rounds[0]["compile_s"], 1),

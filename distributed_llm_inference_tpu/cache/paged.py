@@ -1292,11 +1292,20 @@ class QuantizedPagedKVCache(PagedKVCache):
 
     def tail_big_stacks(self):
         """Read-only stacks for the fused window: past ``INPLACE_CTX`` the
-        whole pool planes (in-place kernel); below it a contiguous
+        whole pool planes (in-place kernel), the two scale planes as ONE
+        with a page's K and V rows side by side where the kernel can then
+        copy them by the live page (``joined_scale_rows``: a read and a
+        write of both, once a window, here outside both scans; the planes
+        as they are stored where it cannot); below it a contiguous
         head-major gather of every row's table span:
         ``(k [L,B,Hkv,Tmax,D] int8, v, ks [L,B,Hkv,Tmax] f32, vs)``. Unmapped
         table slots read the null page — masked by ``pos < base_len``."""
         if self._fused_inplace:
+            from ..ops.paged_attention import joined_scale_rows
+
+            joined = joined_scale_rows(self.ks_pages, self.vs_pages)
+            if joined is not None:
+                return (self.k_pages, self.v_pages, joined)
             return (self.k_pages, self.v_pages, self.ks_pages, self.vs_pages)
         table = self.page_table  # [B, T]
 
@@ -1372,7 +1381,10 @@ class QuantizedPagedKVCache(PagedKVCache):
         q_rot = apply_rope(q, rope.cos, rope.sin)
         k_rot = apply_rope(k_new, rope.cos, rope.sin)
         if self._kernel_tail_ok and q.shape[1] == 1:
-            gk, gv, gks, gvs, lidx = big_state  # whole [L, ...] + layer idx
+            # whole [L, ...] + layer idx; in place, K's and V's scale rows
+            # may be one joined plane (``tail_big_stacks``)
+            gk, gv, gks, *gvs, lidx = big_state
+            gvs = gvs[0] if gvs else None
             tk, tv, tks, tvs = tail_state
             if self._fused_inplace:
                 from ..ops.paged_attention import (

@@ -80,9 +80,11 @@ _FIRST_TOKEN_SPANS = (
 # cache's high-water shape back. Every (old, new) shape on the way back up
 # is an executable of its own: 0.6 s each to load from the compile cache
 # and 8-10 s to compile (chip runs, PERF.md §6, PR 24), where keeping a
-# paged table at its widest costs the requests that come next a tenth of a
-# decode step at most (the scale rows' gather, 1.3 of 12.4 ms at 47 slots)
-# and an idle engine nothing. So a pause between two requests must not
+# paged table at its widest cost the requests that come next a tenth of a
+# decode step at most (the scale rows' gather, 1.3 of 12.4 ms at 47 slots;
+# since PR 61 the in-place sweep copies a live page's scale rows itself and
+# a wide table costs a decode step nothing) and an idle engine nothing. So a
+# pause between two requests must not
 # shrink, and 30 s outlasts 95% of the gaps of arrivals as sparse as one
 # in ten seconds. Not swept; a constant, not an option.
 IDLE_SHRINK_S = 30.0

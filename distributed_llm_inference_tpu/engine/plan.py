@@ -49,7 +49,11 @@ The plan centralizes that policy:
   through; and over a paged cache ``decode_pages_live`` /
   ``decode_pages_joint``: the pages a decode dispatch's rows hold inside
   their windows, and those of them that fill whole tiles of the in-place
-  sweep (a block of pages is one tile, a row's last one padded); where the
+  sweep (a block of pages is one tile, a row's last one padded), beside them
+  ``decode_scale_rows_by_page`` / ``decode_scale_rows_gathered``: the scale
+  rows that sweep's kernel copied itself, a live page's of each stored
+  plane, and those its wrapper gathered in XLA instead, every table slot's
+  of every row (the fall-back by the pool's shape); where the
   sweep walks a list of its pool's live blocks (the latent pool's),
   ``decode_sweep_steps_walked`` / ``decode_sweep_steps_grid``: the grid
   steps the sweep walks (a row's blocks that hold a live page) over rows x
@@ -67,7 +71,9 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
-from ..ops.paged_attention import _live_pages, _pages_per_block, _row_steps
+from ..ops.paged_attention import (
+    _live_pages, _pages_per_block, _row_steps, _scale_rows_by_page,
+)
 from ..ops.ragged_attention import _tile_live
 
 __all__ = ["AttentionPlan", "KernelSelection", "PREFILL", "CHUNKED", "DECODE"]
@@ -482,6 +488,9 @@ class AttentionPlan:
                 )
                 self.metrics.counter("decode_pages_live", live)
                 self.metrics.counter("decode_pages_joint", joint)
+                by_page, gathered = self._scale_rows(shape, live)
+                self.metrics.counter("decode_scale_rows_by_page", by_page)
+                self.metrics.counter("decode_scale_rows_gathered", gathered)
                 if grid:
                     self.metrics.counter("decode_sweep_steps_walked", walked)
                     self.metrics.counter("decode_sweep_steps_grid", grid)
@@ -586,6 +595,34 @@ class AttentionPlan:
             selected += (lo + lo + under - 1) * under // 2 + (n - under) * k
         return selected, live
 
+    def _swept_in_place(self, steps, width) -> bool:
+        """Whether a decode dispatch of ``steps`` over a table ``width``
+        slots wide runs the in-place sweep by copies (``sweep_pool``)."""
+        return (
+            self.sweep_pool is not None and steps > 1
+            and width * self.ccfg.page_size >= self.sweep_pool[2]
+        )
+
+    def _scale_rows(self, shape, live) -> Tuple[int, int]:
+        """(by page, gathered) scale rows of a decode dispatch of ``shape``
+        (rows, steps, table width) whose rows hold ``live`` pages
+        (:meth:`_swept_pages`), where the in-place sweep by copies decodes
+        (an int8 pool's K and V: two stored planes): the rows its kernel
+        copied beside a live page's K and V, that page's of both planes; or,
+        where the pool's page size leaves the kernel no copy it may make
+        (``ops/paged_attention.py:_scale_rows_by_page``), those the wrapper
+        gathered for it: every table slot's of every row, both planes, a
+        layer a step, in ``decode_pages_live``'s layers (one where every
+        layer is alike). Both 0 where another path decodes."""
+        rows, steps, width = shape
+        if not self._swept_in_place(steps, width):
+            return 0, 0
+        planes = 2
+        if _scale_rows_by_page(planes * self.ccfg.page_size):
+            return planes * live, 0
+        layers = sum(n for _, n in self.attention_layers)
+        return 0, rows * width * planes * layers * steps
+
     def _swept_pages(self, shape, spans) -> Tuple[int, int, int, int]:
         """(live, joint) pages and (walked, grid) steps of a decode dispatch
         of ``shape`` (rows, steps, table width) whose active rows' queries
@@ -606,10 +643,9 @@ class AttentionPlan:
         rows, steps, width = shape
         page_size = self.ccfg.page_size
         block = walk_block = 0
-        if self.sweep_pool is not None and steps > 1:
-            heads, stored, least = self.sweep_pool
-            if width * page_size >= least:
-                block = _pages_per_block(width, heads, page_size, stored, steps)
+        if self._swept_in_place(steps, width):
+            heads, stored, _ = self.sweep_pool
+            block = _pages_per_block(width, heads, page_size, stored, steps)
         if self.walked_pool is not None and steps > 1:
             heads, stored = self.walked_pool
             walk_block = _pages_per_block(

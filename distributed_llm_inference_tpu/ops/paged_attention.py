@@ -603,21 +603,26 @@ def latent_sweep_walk(pool_c, k_steps, page_table, base_len, decoding):
     )
 
 
-# VMEM the in-place kernel's page buffers and pipelined row operands may
-# take: K and V of a block of pages, twice (double buffering), beside the
-# row's scale rows and tail blocks (16 KiB a slot at 8 kv heads: a 64-wide
-# f32 row pads to a 128-lane tile, two planes, two buffers). It is not what
-# a v5e core holds (128 MiB; the call raises Mosaic's scoped limit to 100
-# MiB, and 32 MiB here compiled and ran at every cell's shapes): it is kept
-# at the 4 MiB it was because of what it decides: at 8 kv heads a table of
-# 175 slots and more takes 2 pages a block and one of 207 and more ONE, the
-# tile it always was. Those are the tables of stacks
+# What the in-place kernel's page buffers and a row's operands are CHARGED
+# by :func:`_pages_per_block`: K and V of a block of pages, twice (double
+# buffering), beside a row's tail blocks and 16 KiB a table slot at 8 kv
+# heads (what a row's ``[T, Hkv, PS]`` scale rows took of VMEM as a
+# pipelined block, a 64-wide f32 row padded to a 128-lane tile, two planes,
+# two buffers, while the wrapper gathered them). Since PR 61 the copies'
+# form holds no such block (a page's scale rows arrive with the page, 8 KiB a
+# page of the tile), so the slot term is no longer VMEM the kernel takes: it
+# is a LOAD budget, kept because of what it decides. It never was what a
+# v5e core holds (128 MiB; the call raises Mosaic's scoped limit to 100 MiB,
+# and 32 MiB here compiled and ran at every cell's shapes): at 8 kv heads a
+# table of 175 slots and more takes 2 pages a block and one of 207 and more
+# ONE, the tile it always was. Those are the tables of stacks
 # that hold a kernel call a layer in every decode executable
 # (``k-exaone-236b-a23b.mixedlen``: 12 calls, 227 slots), and there a
 # 4-page tile halved the full layers' kernel time and grew the executables'
 # load by a half (39.9 -> 64.3 s, ``setup_s`` 73.7 -> 93.3 s; PERF.md §6,
-# PR 42), which no cell may pay. tests/test_chip_compile.py compiles the
-# cells' shapes.
+# PR 42), which no cell may pay (ROADMAP D5, S5 b). tests/test_chip_compile.py
+# compiles the cells' shapes; tests/test_paged_attention.py pins the width at
+# each cell's.
 _SWEEP_VMEM_BUDGET = 4 * 2**20
 
 # The stored bytes (K and V, int8) of the sweep's tile, a block of pages
@@ -647,13 +652,40 @@ def _pages_by_grid(d):
     return d > 128 and d % 128 != 0
 
 
+def _scale_rows_by_page(lanes):
+    """Whether the copies' form fetches a page's scale rows itself, an async
+    copy at ``(layer, physical page)`` beside the page's K and V: where the
+    scale plane it is handed holds a page's rows of every stored plane side
+    by side in ``lanes`` that are whole 128-lane tiles (two planes at pages
+    of 64: :func:`joined_scale_rows`). Mosaic (jax 0.9.0) refuses an async
+    copy out of a plane whose minor dimension is anything else ("Slice shape
+    along dimension 3 must be aligned to tiling (128), but is 64"); there the
+    wrapper gathers the rows of every table slot in XLA, a layer a step."""
+    return lanes % 128 == 0
+
+
+def joined_scale_rows(pool_ks, pool_vs):
+    """K's and V's scale rows of a page side by side in ONE plane, ``[L, P,
+    Hkv, PS]`` float32 twice -> ``[L, P, Hkv, 2 * PS]``: the form in which
+    :func:`quantized_paged_fused_attention` copies them by the live page
+    (pass it as ``pool_ks``, with ``pool_vs`` None). None where the kernel
+    could not (:func:`_scale_rows_by_page`): the caller keeps the two planes.
+    It is a read and a write of both planes (1/32 of the pool's K and V
+    bytes), so a caller that scans layers and steps makes it once, outside
+    the scans: the pool is read-only through a fused window."""
+    if not _scale_rows_by_page(2 * pool_ks.shape[-1]):
+        return None
+    return jnp.concatenate([pool_ks, pool_vs], axis=-1)
+
+
 def _pages_per_block(t, hkv, page_size, d, kt, planes=2):
     """Pages of K and V (``planes`` 2; 1 where one stored plane is both) one
     block of the sweep fetches, which is also the width of its tile: the
     most (a power of two, no more than the table is wide, no more than 8)
     whose stored bytes fit :data:`_SWEEP_TILE_BYTES` and whose double
-    buffers fit :data:`_SWEEP_VMEM_BUDGET` beside the row's scale rows and
-    tail. At 8 kv heads of 128 that is 4 pages up to a table of 174 slots,
+    buffers fit :data:`_SWEEP_VMEM_BUDGET` beside the row's tail and the
+    charge a table slot that budget keeps (a load budget: see there). At 8
+    kv heads of 128 that is 4 pages up to a table of 174 slots,
     2 up to 206 and 1 past it; 8 at 4 heads (up to 182 slots) and for the
     latent pool's one 576-wide plane, whose blocks are pipelined operands
     and the steps of its grid (:func:`_sweep_walk`): there the width is
@@ -661,7 +693,7 @@ def _pages_per_block(t, hkv, page_size, d, kt, planes=2):
     lanes = -(-d // 128) * 128
     heads = -(-hkv // 8) * 8
     page = planes * hkv * -(-page_size // 32) * 32 * lanes     # K + V, int8
-    row = 2 * planes * t * heads * max(page_size, 128) * 4     # scale rows
+    row = 2 * planes * t * heads * max(page_size, 128) * 4     # a slot's charge
     row += 2 * 2 * (planes * hkv * -(-kt // 32) * 32 * lanes   # tail in +
                     + planes * heads * max(kt, 128) * 4)       # out, 2 bufs
     n = 1
@@ -840,17 +872,32 @@ def quantized_paged_fused_attention(
     step (1.23 us a block of eight pages whose bytes take 0.36) and the
     dead pages beside a row's last live one.
 
-    The scale rows do not come that way: Mosaic (jax 0.9.0) refuses an
-    async copy out of an HBM plane whose minor dimension is one page
-    (``[.., Hkv, 64]`` f32: "Slice shape along dimension 3 must be aligned
-    to tiling (128), but is 64"). So the wrapper gathers the layer's scale
-    rows of every table slot (``[B, T, Hkv, PS]`` f32, 1/64 of the bytes
-    K and V hold there) and a row's come as one pipelined block.
+    **The scale rows arrive with the page** in the copies' form, where the
+    caller hands them over as ONE plane of whole 128-lane tiles: K's and
+    V's rows of a page side by side, ``pool_ks`` ``[L, P, Hkv, 2 * PS]``
+    with ``pool_vs`` None (:func:`joined_scale_rows`, made once a window
+    outside the caller's scans; the one plane of a one-plane pool as it is
+    stored, where its pages are whole tiles wide). A third async copy at
+    ``(layer, physical page)`` is started and waited beside that page's K
+    and V, into a double-buffered ``[2, N, Hkv, 2 * PS]`` scratch, for the
+    row's LIVE pages only; a tile's scale rows are the lane halves of its
+    pages' rows put side by side. Mosaic (jax 0.9.0) refuses that copy out
+    of a plane whose minor dimension is one page (``[.., Hkv, 64]`` f32:
+    "Slice shape along dimension 3 must be aligned to tiling (128), but is
+    64"), so handed the planes as they are stored (``pool_vs`` given: a
+    page size whose two rows are no whole tiles, :func:`_scale_rows_by_page`)
+    the wrapper gathers the layer's scale rows of EVERY table slot in XLA
+    (``[B, T, Hkv, PS]`` f32 a plane, a layer a step, live or not, out of a
+    slice of the layer's whole plane: 0.72 ms of a 17.0 ms step at 32 rows x
+    38 slots over 1280 pages, and more in what the two crowded out of VMEM,
+    PERF.md §6, PR 61) and a row's come as one pipelined block. The values and the order of the sums are the
+    same: the two forms' results are bit for bit each other's. The
+    pipelined blocks' form (``by_grid``) always takes them gathered.
 
     Shapes: ``q`` ``[B, 1, Hq, D]`` (rotated); ``k_new``/``v_new``
     ``[B, 1, Hkv, D]`` (k rotated); pool planes ``[L, P, Hkv, PS, D]`` int8
-    (+ ``[L, P, Hkv, PS]`` f32 scales); tail planes ``[L, B, Hkv, KT, D]``
-    (+ scales, io-aliased). Returns ``(out, tail_k', tail_ks', tail_v',
+    (+ ``[L, P, Hkv, PS]`` f32 scales, or the one joined plane above); tail
+    planes ``[L, B, Hkv, KT, D]`` (+ scales, io-aliased). Returns ``(out, tail_k', tail_ks', tail_v',
     tail_vs')``, or ``(out, tail_k', tail_ks')`` of one stored plane.
     ``name`` is what a device trace calls the kernel.
 
@@ -886,6 +933,19 @@ def quantized_paged_fused_attention(
     planes = 1 if shared else 2
     n = _pages_per_block(t, hkv, page_size, d, kt, planes)
     by_grid = _pages_by_grid(d)
+    # One scale plane that holds a page's rows of every stored plane side
+    # by side, whole tiles wide: the copies' form fetches them by the page.
+    lanes = pool_ks.shape[-1]
+    by_page = (
+        not by_grid and pool_vs is None and lanes == planes * page_size
+        and _scale_rows_by_page(lanes)
+    )
+    if not shared and pool_vs is None and not by_page:
+        raise ValueError(
+            f"one scale plane of {lanes} lanes is not K's and V's rows of a "
+            f"{page_size}-token page side by side in whole tiles, or this "
+            "pool is swept by pipelined blocks: pass both planes"
+        )
     # Pipelined blocks walk the call's list, but not yet under a selection:
     # that call keeps the (rows, table blocks) grid, its program the one it
     # was. Walked, ``glm-5.2.codebase``'s decode step was 16% shorter and
@@ -897,7 +957,9 @@ def quantized_paged_fused_attention(
     qr = q.reshape(b, hkv, g, d)
     # Per stored plane, in the kernel's operand order: the step's fresh
     # values, the tail (values + scale row, io-aliased), the pool (whole)
-    # with its scale plane (gathered to the row's table slots below).
+    # with its scale plane (gathered to the row's table slots below; where
+    # the kernel fetches them by the page, the one joined plane whole, after
+    # the pools).
     fresh = [jnp.moveaxis(k_new, 1, 2)]                  # [B, Hkv, 1, D]
     tails = [tail_k, tail_ks]
     pools = [(pool_k, pool_ks)]
@@ -978,7 +1040,21 @@ def quantized_paged_fused_attention(
         pool_specs = [pl.BlockSpec(memory_space=pl.ANY)]
         page_bufs = [
             *[pltpu.VMEM((2, hkv, n, page_size, d), pool_k.dtype)] * planes,
+            *([pltpu.VMEM((2, n, hkv, lanes), jnp.float32)] if by_page
+              else []),
             pltpu.SemaphoreType.DMA((2, n)),
+        ]
+    if by_page:
+        pool_in = [pl.BlockSpec(memory_space=pl.ANY)] * (planes + 1)
+        pool_args = [*(plane for plane, _ in pools), pool_ks]
+    else:
+        pool_in = [
+            *pool_specs,
+            pl.BlockSpec((1, t, hkv, page_size), _row_index),
+        ] * planes
+        pool_args = [
+            x for plane, sc in pools
+            for x in (*[plane] * len(pool_specs), _scale_rows(sc))
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
@@ -987,10 +1063,7 @@ def quantized_paged_fused_attention(
             pl.BlockSpec((1, hkv, g, d), _row_index),
             *[pl.BlockSpec((1, hkv, 1, d), _row_index)] * planes,
             *_tail_specs(),
-            *[
-                *pool_specs,
-                pl.BlockSpec((1, t, hkv, page_size), _row_index),
-            ] * planes,
+            *pool_in,
             *([] if select is None else [
                 pl.BlockSpec((1, t, 1, page_size), _row_index),
                 pl.BlockSpec((1, 1, kt), lambda *a: (_at(a)[1], 0, 0)),
@@ -1011,6 +1084,7 @@ def quantized_paged_fused_attention(
         _qpaged_fused_kernel,
         planes=planes,
         by_grid=by_grid,
+        by_page=by_page,
         walked=walked,
         scale=scale,
         page_size=page_size,
@@ -1044,11 +1118,7 @@ def quantized_paged_fused_attention(
             ),
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
-    )(*scalars, qr, *fresh, *tails,
-      *[
-          x for plane, sc in pools
-          for x in (*[plane] * len(pool_specs), _scale_rows(sc))
-      ],
+    )(*scalars, qr, *fresh, *tails, *pool_args,
       *(() if select is None else (
           select[0].astype(jnp.float32), select[1].astype(jnp.float32),
       )))
@@ -1066,6 +1136,7 @@ def _qpaged_fused_kernel(
     *refs,
     planes: int,
     by_grid: bool,
+    by_page: bool,
     walked: bool,
     scale: float,
     page_size: int,
@@ -1088,13 +1159,17 @@ def _qpaged_fused_kernel(
       ``[1, 1, Hkv, KT]`` f32;
     * pool: HBM ``[L, P, Hkv, PS, D]`` int8 (the whole pool) or, ``by_grid``,
       the N pages of this step's block ``[1, 1, Hkv, PS, D]`` each; then the
-      row's scale rows by table slot ``[1, T, Hkv, PS]`` f32;
+      row's scale rows by table slot ``[1, T, Hkv, PS]`` f32; or,
+      ``by_page``, the pools and after them ONE scale plane, HBM ``[L, P,
+      Hkv, planes * PS]`` f32 (a page's rows side by side, plane by plane);
     * ``selected``: the row's selection, by table slot ``[1, T, 1, PS]`` and
       by tail slot ``[1, 1, KT]`` f32 (positive = attend);
     * ``out_ref`` ``[1, Hkv, G, D]``, then the aliased tail outputs;
     * scratch: unless ``by_grid``, a plane's VMEM ``[2, Hkv, N, PS, D]`` int8
-      (two blocks of N pages, a head's pages side by side) and DMA
-      semaphores ``[2, N]`` (one a page buffer, its planes together);
+      (two blocks of N pages, a head's pages side by side), ``by_page``
+      the pages' scale rows ``[2, N, Hkv, planes * PS]`` f32, and DMA
+      semaphores ``[2, N]`` (one a page buffer, its planes and its scale
+      rows together);
       ``acc`` ``[Hkv*G, D]``, ``m`` and ``l`` ``[Hkv*G, 128]`` f32.
     """
     refs = list(refs)
@@ -1109,15 +1184,17 @@ def _qpaged_fused_kernel(
     (q_ref,) = _take(1)
     new_refs = _take(planes)
     tail_in = _take(2 * planes)
-    per = n + 1 if by_grid else 2
+    per = n + 1 if by_grid else 1 if by_page else 2
     pool = _take(per * planes)
+    scale_hbm = _take(1)[0] if by_page else None
     sel_pool, sel_tail = _take(2) if selected else (None, None)
     (out_ref,) = _take(1)
     tail_out = _take(2 * planes)
     bufs = [] if by_grid else _take(planes)
+    scale_buf = _take(1)[0] if by_page else None
     sems = None if by_grid else _take(1)[0]
     acc_ref, m_ref, l_ref = refs
-    scale_rows = pool[per - 1 :: per]
+    scale_rows = [] if by_page else pool[per - 1 :: per]
 
     b = row_ref[pl.program_id(0)] if walked else pl.program_id(0)
     layer = lidx_ref[0]
@@ -1273,11 +1350,12 @@ def _qpaged_fused_kernel(
 
     def _page_copies(slot, i, page):
         phys = table_ref[b, page]
+        pairs = [(hbm, buf.at[slot, :, i]) for hbm, buf in zip(hbms, bufs)]
+        if by_page:  # the page's scale rows, plane beside plane
+            pairs.append((scale_hbm, scale_buf.at[slot, i]))
         return [
-            pltpu.make_async_copy(
-                hbm.at[layer, phys], buf.at[slot, :, i], sems.at[slot, i]
-            )
-            for hbm, buf in zip(hbms, bufs)
+            pltpu.make_async_copy(src.at[layer, phys], dst, sems.at[slot, i])
+            for src, dst in pairs
         ]
 
     def _block_pages(blk, body):
@@ -1313,7 +1391,8 @@ def _qpaged_fused_kernel(
         window's term in it, covers them all. The row's last block may
         hold fewer than ``n`` live pages: the places past them keep what
         the buffer held (int8: finite) under scale rows of whatever the
-        table names there, so their positions are masked and their V scales
+        table names there (``by_page``: whatever the scale buffer held),
+        so their positions are masked and their V scales
         SELECTED away (``p * scale`` with ``p == 0`` and a NaN scale is
         NaN)."""
         slot = blk % 2
@@ -1343,9 +1422,20 @@ def _qpaged_fused_kernel(
             valid &= fetched
         if selected:
             valid &= side_by_side(sel_pool) > 0
+        # ``by_page``: what came with the pages, [Hkv, planes * PS] each
+        came = [scale_buf[slot, i] for i in range(n)] if by_page else None
+
+        def scales_of(plane):  # its scale rows of the block's places
+            if not by_page:
+                return side_by_side(scale_rows[plane])
+            at = plane * page_size
+            return jnp.concatenate(
+                [rows[:, at : at + page_size] for rows in came], -1
+            )
+
         stored = [
-            (buf[slot].reshape(hkv, width, -1), side_by_side(rows))
-            for buf, rows in zip(bufs, scale_rows)
+            (buf[slot].reshape(hkv, width, -1), scales_of(plane))
+            for plane, buf in enumerate(bufs)
         ]
         if n > 1:
             vv, vvs = stored[-1]

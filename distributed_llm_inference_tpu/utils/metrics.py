@@ -80,6 +80,13 @@ METRICS = {
         "counter", "Pages decode rows hold inside their windows, a layer a step"),
     "decode_pages_joint": (
         "counter", "Of those, pages in full blocks of the in-place sweep (a tile, unpadded)"),
+    # the in-place sweep's scale rows: by_page / (by_page + gathered) is the
+    # share the kernel copied itself, a live page's, over what its wrapper
+    # gathered of every table slot
+    "decode_scale_rows_by_page": (
+        "counter", "Scale rows the decode sweep copied by the live page, a stored plane each"),
+    "decode_scale_rows_gathered": (
+        "counter", "Scale rows its wrapper gathered instead: rows x table slots x planes, a layer a step"),
     # a pool whose decode sweep walks a list of its live blocks (the latent
     # pool's): walked / grid is the share of rows x table blocks it keeps
     "decode_sweep_steps_walked": (

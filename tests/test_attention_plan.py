@@ -389,6 +389,9 @@ SWEPT = {
     "window-and-full": (((128, 9), (None, 3)), (8, 128, 768), 160, 64),
     "a-page-a-block": (((128, 9), (None, 3)), (8, 128, 768), 227, 64),
     "a-selection's-table": (((None, 1),), (4, 128, 0), 182, 64),
+    # two scale rows of a 32-token page are 64 lanes: the kernel may not copy
+    # them, the wrapper gathers every slot's
+    "a-page-of-32": (((128, 9), (None, 3)), (8, 128, 768), 47, 32),
 }
 
 
@@ -434,7 +437,19 @@ def test_swept_pages_are_what_the_kernels_own_arithmetic_gives(case):
     assert live > 0 and m.get_counter("decode_pages_live") == live
     assert m.get_counter("decode_pages_joint") == joint
     assert (joint > 0) == (case in ("one-kind", "window-and-full",
-                                    "a-selection's-table"))
+                                    "a-selection's-table", "a-page-of-32"))
+    # the in-place sweep's scale rows: a live page's of both stored planes
+    # by the kernel's own copy, none gathered; at a page of 32 every table
+    # slot's of every row, a layer a step, gathered; neither where another
+    # path decodes
+    by_page, gathered = 2 * live * (block > 0), 0
+    if case == "a-page-of-32":
+        by_page, gathered = 0, 32 * width * 2 * (9 + 3) * steps
+    assert m.get_counter("decode_scale_rows_by_page") == by_page
+    assert m.get_counter("decode_scale_rows_gathered") == gathered
+    assert (by_page + gathered > 0) == (
+        case not in ("under-the-capacity", "no-sweep")
+    )
     if case == "window-and-full":
         # a window of 128 is 3 pages of 64 at most: only full layers' are joint
         assert block == 4 and joint % 3 == 0

@@ -105,32 +105,55 @@ def test_paged_decode_kernels(chip, pages):
 
 
 @pytest.mark.parametrize(
-    "rows,width,pages,window",
+    "rows,width,pages,window,hkv,layers",
     [
-        (8, SLOTS, PAGES, 4096),     # the engine's defaults
-        (32, 38, 1280, 4096),        # mistral-7b.reason's pinned table
-        (32, 47, 1280, 4096),        # mistral-7b.chat's
-        (16, 64, 1024, None),        # mixtral-8x7b-8l.rag: no window
-        (32, 64, 1280, None),
+        (8, SLOTS, PAGES, 4096, HKV, LAYERS),     # the engine's defaults
+        (32, 38, 1280, 4096, HKV, LAYERS),  # mistral-7b.reason's pinned table
+        (32, 47, 1280, 4096, HKV, LAYERS),  # mistral-7b.chat's
+        (16, 64, 1024, None, HKV, LAYERS),  # mixtral-8x7b-8l.rag: no window
+        (32, 64, 1280, None, HKV, LAYERS),
+        (16, 16, 160, None, 16, 192),       # ouro-2.6b.mathchat: 2 pages a block
+        (32, 227, 512, 128, HKV, 12),       # mixedlen's window pool: 1 a block
     ],
 )
-def test_fused_int8_paged_decode_kernel(chip, rows, width, pages, window):
+@pytest.mark.parametrize("scales", ["joined", "as-stored"])
+def test_fused_int8_paged_decode_kernel(
+    chip, rows, width, pages, window, hkv, layers, scales
+):
     """At the shapes the engine's decode scan gives it in the benchmark's
     cells: the whole ``[L, P, ...]`` pool, a 16-slot tail, rank-0 layer and
     step indices, and the pages a block the kernel picks for itself — so a
-    block over the scoped VMEM, or an operand Mosaic refuses, fails here."""
+    block over the scoped VMEM, or an operand Mosaic refuses, fails here.
+    ``joined``: K's and V's scale rows of a page in ONE plane of 128 lanes,
+    which the kernel copies by the live page (what every cell's cache hands
+    it since PR 61: no gather is compiled beside the kernel); ``as-stored``:
+    the two planes of 64 lanes, whose rows the wrapper gathers (the
+    fall-back by the shape)."""
     s, b = chip, rows
     bf16 = jnp.bfloat16
-    pool = (s((LAYERS, pages, HKV, PS, D), I8), s((LAYERS, pages, HKV, PS), F32))
-    tail = (s((LAYERS, b, HKV, KT, D), I8), s((LAYERS, b, HKV, KT), F32))
-    _compiles_with_kernel(
+    plane = s((layers, pages, hkv, PS, D), I8)
+    tail = (s((layers, b, hkv, KT, D), I8), s((layers, b, hkv, KT), F32))
+    joined = jax.eval_shape(
+        pa.joined_scale_rows, *[s((layers, pages, hkv, PS), F32)] * 2
+    )
+    stored = s(joined.shape[:-1] + (PS,), F32)
+    pool_ks, pool_vs = (
+        (s(joined.shape, F32), None) if scales == "joined" else (stored, stored)
+    )
+    compiled = jax.jit(
         lambda *a: pa.quantized_paged_fused_attention(
             *a, sliding_window=window, interpret=False
-        ),
-        s((b, 1, HQ, D), bf16), s((b, 1, HKV, D), bf16), s((b, 1, HKV, D), bf16),
-        *pool, *pool, *tail, *tail, s((), I32), s((), I32),
+        )
+    ).lower(
+        s((b, 1, hkv * 4, D), bf16), s((b, 1, hkv, D), bf16),
+        s((b, 1, hkv, D), bf16), plane, pool_ks, plane, pool_vs,
+        *tail, *tail, s((), I32), s((), I32),
         s((b, width), I32), s((b,), I32), s((b,), I32), s((b,), I32),
-    )
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the compiled step"
+    gathered = f"f32[{b * width},{hkv},{PS}]"
+    assert (gathered in text) == (scales == "as-stored")
 
 
 # moonlight-16b-a3b.reason1k: 16 query heads on ONE latent head, the stored
@@ -897,6 +920,68 @@ def test_a_prefill_over_the_int8_pool_has_no_layers_plane_as_a_result(
           "temporaries", mem.temp_size_in_bytes)
 
 
+#: temporaries of the fused 16-step decode scan on the parent of PR 61 (commit
+#: 6669722; this file's compile against that tree), bytes. PR 61's own read
+#: LESS, 2_014_785_536 and 647_214_592: the one joined plane (128 lanes, no
+#: padding) takes the place of the two stored planes' row-major forms (64
+#: offsets minor, padded to the 128 lanes) that the gather's layer slices read
+DECODE_SCAN_TEMP_BEFORE = {"ouro": 2_115_158_016, "mistral": 815_616_000}
+
+
+def _decode_scan(cfg, exit_laps):
+    """The fused 16-step decode scan under a greedy step function."""
+    from distributed_llm_inference_tpu.models import llama
+
+    def decode(params, tokens, cache, active):
+        return llama.multi_decode_apply(
+            cfg, params, tokens, cache, KT,
+            lambda i, logits, alive: (
+                jnp.argmax(logits, -1).astype(I32), alive.astype(I32), alive,
+                jnp.argmax(logits, -1).astype(I32),
+            ),
+            active, active.astype(I32), exit_laps=exit_laps,
+        )
+
+    return decode
+
+
+def _joined_plane_bytes(cache):
+    layers, pages, hkv, ps = cache.ks_pages.shape
+    return layers * pages * hkv * 2 * ps * 4
+
+
+def test_the_decode_scan_at_mistrals_served_shapes(chip, monkeypatch):
+    """``mistral-7b.reason`` / ``.chat``: the fused 16-step decode scan of 32
+    rows over the cell's pool (32 layers of 1280 pages, int8 weights) still
+    compiles and fits, holds no gather of a scale plane's rows (the sweep
+    copies them by the live page out of the plane joined once, outside both
+    scans), and its temporaries are no more than the parent's plus that
+    joined plane."""
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = chip
+    cfg, params, cache = _mistral(s)
+    rows, slots = cache.page_table.shape
+    compiled = jax.jit(_decode_scan(cfg, False), donate_argnums=(2,)).lower(
+        params, s((rows, 1), I32), cache, s((rows,), jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    for name in ("quantized_paged_fused_attention", "paged_tail_flush"):
+        assert name in text, name
+    pages, hkv = cache.k_pages.shape[1:3]
+    assert f"f32[{rows * slots},{hkv},{PS}]" not in text      # the gather
+    assert not re.search(rf"= f32\[{pages},{hkv},{PS}\]\S* fusion\(", text)  # its slice
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.75 * 2 ** 30, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes <= (
+        DECODE_SCAN_TEMP_BEFORE["mistral"] + _joined_plane_bytes(cache)
+    ), mem.temp_size_in_bytes
+    print("mistral decode-scan arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode-scan"])
 def test_the_looped_programs_at_ouros_published_widths(chip, monkeypatch, program):
     """The looped prefill (one row's 512-wide piece through its page table,
@@ -922,15 +1007,7 @@ def test_the_looped_programs_at_ouros_published_widths(chip, monkeypatch, progra
         )
         return logits, cache.merge_row(sub, row)
 
-    def decode(params, tokens, cache, active):
-        return llama.multi_decode_apply(
-            cfg, params, tokens, cache, KT,
-            lambda i, logits, alive: (
-                jnp.argmax(logits, -1).astype(I32), alive.astype(I32), alive,
-                jnp.argmax(logits, -1).astype(I32),
-            ),
-            active, active.astype(I32), exit_laps=True,
-        )
+    decode = _decode_scan(cfg, True)
 
     if program == "prefill":
         lowered = jax.jit(prefill, donate_argnums=(2,)).lower(
@@ -958,5 +1035,11 @@ def test_the_looped_programs_at_ouros_published_widths(chip, monkeypatch, progra
     # GB for the scan
     assert mem.argument_size_in_bytes > 5.3e9 + 50e6 * pages
     assert held < 15.75 * 2 ** 30, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    if program == "decode-scan":
+        # since PR 61 the scan holds K's and V's scale rows joined in one
+        # plane while it runs, and no more than that over the parent's
+        assert mem.temp_size_in_bytes <= (
+            DECODE_SCAN_TEMP_BEFORE["ouro"] + _joined_plane_bytes(cache)
+        ), mem.temp_size_in_bytes
     print(program, "arguments", mem.argument_size_in_bytes,
           "temporaries", mem.temp_size_in_bytes)

@@ -409,15 +409,21 @@ def test_what_a_two_pool_stack_cannot_do_is_refused_by_name(model):
 #: written by whole pages and read at (layer, page) of the carried stacks
 #: there (``QuantizedPagedKVCache.ragged_reads_whole_stacks``; the other
 #: eleven, the int8 prefill without the kernel and every decode scan among
-#: them, are untouched by it). Mistral's other five are cdb55a4's (and PR
-#: 42's) still.
+#: them, are untouched by it). PR 61 regenerated ONE, Mistral's decode scan
+#: over pages of 64 in a table of 12 (768 positions: the in-place sweep by
+#: copies at a page size whose two scale rows are a whole 128-lane tile): the
+#: cache joins K's and V's scale planes once a window and the kernel copies a
+#: live page's rows itself, where the wrapper gathered every table slot's a
+#: layer a step (``ops/paged_attention.py:joined_scale_rows``); the latent
+#: pool's one plane of 64 lanes keeps the gather, and its scan stands.
+#: Mistral's other four are cdb55a4's (and PR 42's) still.
 OLD_STACKS = {
     "mistral.float.prefill": "ce04728d66ae8e7a",
     "mistral.int8.prefill": "0332a71023c2ddb3",
     "mistral.int8.decode_scan": "6fe8c360a5b7a039",
     "mistral.kernel.8x4.decode_scan": "36b335e3f969605d",
     "mistral.kernel.8x4.prefill": "08a1619b4f8eea2a",
-    "mistral.kernel.64x12.decode_scan": "7db7f3834be39c7c",
+    "mistral.kernel.64x12.decode_scan": "d216caf8622f0f4a",
     "mistral.kernel.64x12.prefill": "b05ce1beffbe99c6",
     "moonlight.float.prefill": "eac83a25e1c45a4d",
     "moonlight.int8.prefill": "c3335515e1e0ab1c",

@@ -561,17 +561,25 @@ def test_what_cannot_carry_the_plane_is_refused_by_name():
 #: of the carried stacks (``QuantizedPagedKVCache.ragged_reads_whole_stacks``);
 #: Keye's (the index plane's own ``attend``) and Moonlight's (the latent pool)
 #: kernel prefills, and every decode scan, are the digests they were.
-#: Mistral's other four are a45a1d5's still.
+#: PR 61 regenerated the four kernel decode scans over the int8 pool by
+#: copies (Mistral's, Mixtral's, Keye's under its selection, K-EXAONE's two
+#: pools; pages of 64 in a table of 768 positions): the cache joins K's and
+#: V's scale planes once a window and the sweep copies a live page's scale
+#: rows itself, where the wrapper gathered every table slot's a layer a step
+#: (``ops/paged_attention.py:joined_scale_rows``; the two forms bit-equal:
+#: ``tests/test_paged_attention.py``); Moonlight's (one latent plane of 64
+#: lanes: the gather stays) is the digest it was.
+#: Mistral's other three are a45a1d5's still.
 OLD_STACKS = {
     "mistral.float.prefill": "ce04728d66ae8e7a",
     "mistral.int8.prefill": "0332a71023c2ddb3",
     "mistral.int8.decode_scan": "6fe8c360a5b7a039",
-    "mistral.kernel.decode_scan": "7db7f3834be39c7c",
+    "mistral.kernel.decode_scan": "d216caf8622f0f4a",
     "mistral.kernel.prefill": "b05ce1beffbe99c6",
     "mixtral.float.prefill": "4e3b03187d0d0786",
     "mixtral.int8.prefill": "93ee1d0335f7b09d",
     "mixtral.int8.decode_scan": "9909d784bd1bcad9",
-    "mixtral.kernel.decode_scan": "4c273ed9094c8b88",
+    "mixtral.kernel.decode_scan": "47f6be7be1ddda67",
     "mixtral.kernel.prefill": "cc5166cd764950bc",
     "moonlight.float.prefill": "eac83a25e1c45a4d",
     "moonlight.int8.prefill": "c3335515e1e0ab1c",
@@ -580,12 +588,12 @@ OLD_STACKS = {
     "keye.float.prefill": "48e8f6a2f7dab6c5",
     "keye.int8.prefill": "d40dfc05f0515bef",
     "keye.int8.decode_scan": "739397ed2aa75e26",
-    "keye.kernel.decode_scan": "2c7e04a6d856eb13",
+    "keye.kernel.decode_scan": "4c60b200684cbe7c",
     "keye.kernel.prefill": "444e13ff9fa7c7ac",
     "exaone.float.prefill": "89a759304ca1689c",
     "exaone.int8.prefill": "40accbd22a444365",
     "exaone.int8.decode_scan": "7472671651de15b1",
-    "exaone.kernel.decode_scan": "e5e628d5025e8a89",
+    "exaone.kernel.decode_scan": "346843a413da70cc",
     "exaone.kernel.prefill": "99e11592f9999a59",
 }
 

@@ -472,3 +472,172 @@ def test_the_window_case_cuts_into_a_full_block():
     cut = active & (lo % n != 0) & (hi - lo > n)
     assert cut.sum() >= 2, (lo, hi)
     assert any((hi - lo)[cut] % n), "and what is left is a tile padded to n"
+
+
+# -- the scale rows by the live page (PR 61) -----------------------------------
+
+_BY_PAGE = {
+    # two planes at keye's, Mistral's and ouro's kv heads (8, 4 and 2 pages a
+    # block)
+    "hkv4": dict(hkv=4),
+    "hkv8": dict(hkv=8),
+    "hkv16": dict(hkv=16),
+    "window-of-2-pages-on-a-wide-table": dict(hkv=2, t=40, window=2 * 64),
+    "selection": dict(hkv=4, select=True),
+    # the first row's pages carry NaN scale rows (its results are NaN in
+    # both forms): they stay in the kernel's scale buffer under the dead
+    # places of the next rows' last blocks
+    "nan-rows-in-the-dead-places": dict(hkv=2, nan_row=True),
+    # two rows of a 32-token page are 64 lanes: Mosaic would refuse the copy
+    "fallback-page-of-32": dict(hkv=2, ps=32, by_page=False),
+}
+
+
+def _by_page_inputs(hkv, ps=64, t=20, window=None, select=False,
+                    nan_row=False, **_):
+    """A call of the copies' form at a page size whose two scale rows are
+    whole tiles (``ps`` 64): rows of one token, a page and a bit, a last
+    block short of ``n`` pages, whole blocks, the full table, an empty
+    decoding row, and a row that is NOT decoding under a stale length; what
+    no live token owns is poisoned (values at the int8 ends, scales NaN)."""
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        _pages_per_block,
+    )
+
+    d, kt, layers, layer, step, g = 16, 4, 2, 1, 2, 2
+    n = _pages_per_block(t, hkv, ps, d, kt)
+    lengths = np.asarray(
+        [n * ps, 1, ps + 5, (n + 1) * ps - 3, 2 * n * ps, t * ps, 0,
+         3 * ps + 1], np.int32,
+    )
+    active = np.asarray([True] * 7 + [False])
+    assert 1 < n and 2 * n <= t, "want a last block short of n pages"
+    b = len(lengths)
+    rng = np.random.default_rng(61 + hkv)
+    pages = b * t + 1
+    pool = [rng.integers(-127, 128, (layers, pages, hkv, ps, d)).astype(np.int8)
+            for _ in range(2)]
+    scales = [rng.uniform(0.01, 0.03, (layers, pages, hkv, ps)).astype(np.float32)
+              for _ in range(2)]
+    table = (rng.permutation(pages - 1)[: b * t] + 1).reshape(b, t).astype(np.int32)
+    hi = np.where(active, np.minimum(-(-lengths // ps), t), 0)
+    lo = np.zeros(b, np.int64)
+    if window is not None:
+        lo = np.minimum(np.maximum(lengths + step - window + 1, 0) // ps, hi)
+    dead = [0] + [int(table[r, s]) for r in range(b) for s in range(t)
+                  if not lo[r] <= s < hi[r]]
+    if nan_row:
+        dead += [int(p) for p in table[0, : hi[0]]]
+    for plane in pool:
+        plane[:, dead] = np.where(rng.random(plane[:, dead].shape) < 0.5, 127, -127)
+    for plane in scales:
+        plane[:, dead] = np.nan
+    bf16 = lambda x: jnp.asarray(x, jnp.float32).astype(jnp.bfloat16)
+    a = dict(
+        q=bf16(rng.normal(size=(b, 1, hkv * g, d))),
+        k_new=bf16(rng.normal(size=(b, 1, hkv, d))),
+        v_new=bf16(rng.normal(size=(b, 1, hkv, d))),
+        pool_k=jnp.asarray(pool[0]), pool_ks=jnp.asarray(scales[0]),
+        pool_v=jnp.asarray(pool[1]), pool_vs=jnp.asarray(scales[1]),
+        layer_idx=jnp.asarray(layer, jnp.int32),
+        step_idx=jnp.asarray(step, jnp.int32),
+        page_table=jnp.asarray(table), base_len=jnp.asarray(lengths),
+        tail_valid_len=jnp.asarray(np.where(active, step + 1, 0), jnp.int32),
+        q_positions=jnp.asarray(lengths + step, jnp.int32),
+        sliding_window=window,
+    )
+    for name, plane in (("k", 0), ("v", 1)):
+        a[f"tail_{name}"] = jnp.asarray(rng.integers(
+            -127, 128, (layers, b, hkv, kt, d)).astype(np.int8))
+        a[f"tail_{name}s"] = jnp.asarray(rng.uniform(
+            0.01, 0.03, (layers, b, hkv, kt)).astype(np.float32))
+    if select:
+        a["select"] = (
+            jnp.asarray(rng.normal(size=(b, t, 1, ps)), jnp.float32),
+            jnp.asarray(rng.normal(size=(b, 1, kt)), jnp.float32),
+        )
+    return a, active
+
+
+@pytest.mark.parametrize("case", _BY_PAGE, ids=list(_BY_PAGE))
+def test_scale_rows_by_the_page_are_bit_equal_to_the_gather(case):
+    """The copies' form handed ONE joined scale plane (what
+    ``QuantizedPagedKVCache.tail_big_stacks`` hands it: the kernel copies a
+    live page's scale rows beside its K and V) against the same call handed
+    the two planes as stored (the wrapper's XLA gather of every table
+    slot's rows, kept as the fall-back by the shape and here as the plain
+    reference): results and written tail bit for bit, and no gather left
+    beside the kernel."""
+    from distributed_llm_inference_tpu.ops import paged_attention as pa
+
+    spec = _BY_PAGE[case]
+    a, active = _by_page_inputs(**spec)
+    joined = pa.joined_scale_rows(a["pool_ks"], a["pool_vs"])
+    assert (joined is not None) == spec.get("by_page", True)
+    by_page = dict(a) if joined is None else {
+        **a, "pool_ks": joined, "pool_vs": None,
+    }
+
+    def call(kw):  # (results, the program's text); the window is static
+        kw = dict(kw)
+        window = kw.pop("sliding_window")
+        fn = lambda x: pa.quantized_paged_fused_attention(
+            **x, sliding_window=window
+        )
+        return fn(kw), str(jax.make_jaxpr(fn)(kw))
+
+    got, program = call(by_page)
+    want, reference = call(a)
+    assert "gather" in reference
+    assert ("gather" in program) == (joined is None)
+    for x, y in zip(got, want):
+        np.testing.assert_array_equal(
+            np.asarray(x.astype(jnp.float32)), np.asarray(y.astype(jnp.float32))
+        )
+    out = np.asarray(got[0].astype(jnp.float32))
+    clean = active & (np.arange(len(active)) > 0 if spec.get("nan_row")
+                      else True)
+    assert np.isfinite(out[clean]).all(), "a dead page's scale row was used"
+    assert (out[~active] == 0).all(), "an inactive row attends to nothing"
+    if spec.get("nan_row"):
+        assert np.isnan(out[0]).all()
+    if joined is None:  # and one plane of 64 lanes is refused, not miscopied
+        with pytest.raises(ValueError, match="pass both planes"):
+            pa.quantized_paged_fused_attention(**{
+                **a, "pool_ks": jnp.concatenate(
+                    [a["pool_ks"], a["pool_vs"]], -1), "pool_vs": None,
+            })
+
+
+# (table slots, kv heads, page size, stored width, tail slots, stored planes)
+# of the pool a cell's decode sweeps (its pinned table; the cap where the
+# cell's decode takes another path: tp4's, brumby's) -> pages a block
+_CELL_TILES = {
+    "mistral-7b.chat": ((47, 8, 64, 128, 16, 2), 4),
+    "mistral-7b.reason": ((38, 8, 64, 128, 16, 2), 4),
+    "mixtral-8x7b-8l.rag": ((64, 8, 64, 128, 16, 2), 4),
+    "mistral-7b-bf16-tp4.chat32": ((64, 8, 64, 128, 16, 2), 4),
+    "moonlight-16b-a3b.reason1k": ((59, 1, 64, 576, 16, 1), 8),
+    "keye-vl2-30b-a3b.longdoc": ((182, 4, 64, 128, 16, 2), 8),
+    "k-exaone-236b-a23b.mixedlen": ((227, 8, 64, 128, 16, 2), 1),
+    "glm-5.2.codebase": ((227, 1, 64, 576, 16, 1), 8),
+    "xing4.0-29b-a4b.reason1k": ((59, 1, 64, 576, 16, 1), 8),
+    "brumby-14b.longgen": ((256, 8, 64, 128, 16, 2), 1),
+    "ouro-2.6b.mathchat": ((16, 16, 64, 128, 16, 2), 2),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_TILES))
+def test_the_tile_keeps_its_width_at_every_cells_shape(cell):
+    """``_pages_per_block`` at the eleven cells' shapes returns what it
+    returned on the parent of PR 61 (commit 6669722), though a row's scale
+    rows no longer lie in VMEM: the slot term of ``_SWEEP_VMEM_BUDGET`` stays
+    as a load budget (a wider tile at mixedlen's 227 slots read ``setup_s``
+    73.7 -> 93.3 s: PERF.md §6, PR 42), and the wider tile is a later
+    issue's."""
+    from distributed_llm_inference_tpu.ops.paged_attention import (
+        _pages_per_block,
+    )
+
+    shape, n = _CELL_TILES[cell]
+    assert _pages_per_block(*shape) == n

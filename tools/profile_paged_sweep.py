@@ -47,7 +47,14 @@ kernel's time over the first.
 Import the package from another checkout with ``PYTHONPATH=<root>`` to time
 that checkout's kernel on the same chip: the tool itself uses nothing else
 of the repository but ``utils/xplane.py``. ``busy_ms_a_step`` is the whole
-step's device time: the kernel and what its wrapper runs beside it.
+step's device time: the kernel and what its wrapper prepares for it a layer
+(``beside_kernel_ms_a_step`` is that alone, ``beside_kernel_top`` its largest
+operations by the trace's names, ms a step: before PR 61 the gather of every
+table slot's scale rows). What a checkout prepares once a window instead
+(``pa.joined_scale_rows``: K's and V's scale rows of a page side by side in
+one plane, which the kernel then copies by the live page) is timed apart,
+``join_ms_a_window``, as ``--form gathered``'s gather is: a ``KT``-th of it
+belongs to a step.
 """
 import argparse
 import hashlib
@@ -232,19 +239,30 @@ def run_case(args, kind, pick):
     big = pool
     if args.form == "gathered":
         big = jax.block_until_ready(gather(pool, jnp.asarray(table)))
+    # once a window: the scale planes in the form the kernel copies by the
+    # page, where this checkout has one for the pool's shape
+    join = jax.jit(getattr(pa, "joined_scale_rows", lambda ks, vs: None))
+    joined = None if args.form != "inplace" else join(pool[1], pool[3])
+    if joined is not None:
+        big = (pool[0], jax.block_until_ready(joined), pool[2], None)
     argv = (big, tails, jnp.asarray(table), jnp.asarray(lens),
             jnp.asarray(vlen), select)
     t0 = time.perf_counter()
     tails, acc = step(*argv)
     jax.block_until_ready(acc)
     first_call_s = time.perf_counter() - t0  # trace, compile, run
-    gather_ms = 0.0
-    if args.form == "gathered":
+    def busy_ms(prepare):  # a traced call of what a window prepares once
         with tempfile.TemporaryDirectory() as td:
             with jax.profiler.trace(td):
-                jax.block_until_ready(gather(pool, argv[2]))
+                jax.block_until_ready(prepare())
             found = aggregate(find_xplane(td))["devices"]
-            gather_ms = found[0]["busy_ns"] / 1e6 if found else 0.0
+        return found[0]["busy_ns"] / 1e6 if found else 0.0
+
+    gather_ms = join_ms = 0.0
+    if args.form == "gathered":
+        gather_ms = busy_ms(lambda: gather(pool, argv[2]))
+    if joined is not None:
+        join_ms = busy_ms(lambda: join(pool[1], pool[3]))
     with tempfile.TemporaryDirectory() as td:
         with jax.profiler.trace(td):
             for _ in range(args.reps):
@@ -253,6 +271,11 @@ def run_case(args, kind, pick):
         agg = aggregate(find_xplane(td))
     ns = sum(v for k, v in agg["ops_ns"].items() if kernel in k)
     calls = sum(v for k, v in agg["op_counts"].items() if kernel in k)
+    busy = agg["devices"][0]["busy_ns"] if agg["devices"] else 0
+    beside = sorted(
+        ((v, k) for k, v in agg["ops_ns"].items() if kernel not in k),
+        reverse=True,
+    )[:4]
     planes = 1 if latent else 2
     # the grid steps of a pipelined-block sweep: what the ``(rows, table
     # blocks)`` grid held, and what the walk of PR 48 keeps of it (a row's
@@ -261,6 +284,7 @@ def run_case(args, kind, pick):
     walked = int(np.maximum(-(-hi // n) - lo // n, 1).sum())
     print(json.dumps({
         "form": args.form, "gather_ms_a_window": round(gather_ms, 3),
+        "join_ms_a_window": round(join_ms, 3),
         "occupancy": kind, "rows": b, "width": t,
         "pages_a_block": pa._pages_per_block(t, HKV, PS, D, KT, planes),
         "pages_a_block_own": pick(t, HKV, PS, D, KT, planes),
@@ -275,9 +299,11 @@ def run_case(args, kind, pick):
         "kernel_us_a_walked_step": round(
             ns / args.reps / LAYERS / walked / 1e3, 4
         ),
-        "busy_ms_a_step": round(
-            agg["devices"][0]["busy_ns"] / args.reps / 1e6, 3
-        ) if agg["devices"] else 0.0,
+        "busy_ms_a_step": round(busy / args.reps / 1e6, 3),
+        "beside_kernel_ms_a_step": round((busy - ns) / args.reps / 1e6, 3),
+        "beside_kernel_top": {
+            k: round(v / args.reps / 1e6, 3) for v, k in beside
+        },
         "first_call_s": round(first_call_s, 2),
         "checksum": float(jnp.sum(acc)),
         # of the step's results and its tail, bit for bit
