@@ -474,6 +474,13 @@ def test_directory_restart_mid_generation(cluster, params):
                                           timeout=5.0)
             assert again == _oracle_greedy(params, PROMPT, 4)
     finally:
+        # The nodes go while a directory still answers. Stopped after it (by
+        # the fixture), a node's stop() asks a directory that is gone while
+        # its health thread's heartbeat waits on the same client: each ends
+        # the other's socket timeout, and one of them then waits for ever
+        # (three whole runs of five hung here, PR 63).
+        n1.stop()
+        n2.stop()
         new_service.stop()
 
 

@@ -34,7 +34,7 @@ I8, F32, I32 = jnp.int8, jnp.float32, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def chip():
+def chip(compiles_cold):
     try:
         from jax.experimental import topologies
 
@@ -49,15 +49,13 @@ def chip():
     # whatever the session set. And compile at the chip's own matmul
     # precision, not the "highest" the CPU suite asks for in conftest
     # (Mosaic has no fp32-precision matmul over bf16 operands).
-    was = (jax.config.jax_enable_compilation_cache,
-           jax.config.jax_default_matmul_precision)
-    jax.config.update("jax_enable_compilation_cache", False)
+    was = jax.config.jax_default_matmul_precision
     jax.config.update("jax_default_matmul_precision", None)
-    yield lambda shape, dtype: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=one
-    )
-    jax.config.update("jax_enable_compilation_cache", was[0])
-    jax.config.update("jax_default_matmul_precision", was[1])
+    with compiles_cold():
+        yield lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=one
+        )
+    jax.config.update("jax_default_matmul_precision", was)
 
 
 def _compiles_with_kernel(fn, *args):

@@ -81,6 +81,28 @@ def test_the_call_starts_the_count_of_the_programs_the_process_loads(
     assert PROGRAM_LOADS.loads == before + 1
 
 
+def test_a_test_session_keeps_its_cache_to_itself(restore_config):
+    """``conftest``: the cache is on, in a directory outside the checkout
+    that this process made for itself, and ``enable_compile_cache()`` called
+    in-process (as ``cli.main`` calls it) leaves it there: what a test
+    compiles lands in that directory and not in ``<checkout>/.jax_cache``."""
+    import jax.numpy as jnp
+
+    path = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    assert jax.config.jax_enable_compilation_cache
+    assert jax.config.jax_compilation_cache_dir == path
+    assert os.path.isdir(path)
+    assert os.path.relpath(path, REPO).startswith("..")
+    assert f"_{os.getpid()}_" in os.path.basename(path)
+    assert compile_cache.enable_compile_cache() == path
+    assert jax.config.jax_compilation_cache_dir == path
+    here = compile_cache.cache_entries(path)
+    checkout = compile_cache.cache_entries(compile_cache.CHECKOUT_CACHE_DIR)
+    jax.jit(lambda x: x * 3 + os.getpid())(jnp.arange(7)).block_until_ready()
+    assert compile_cache.cache_entries(path) > here
+    assert compile_cache.cache_entries(compile_cache.CHECKOUT_CACHE_DIR) == checkout
+
+
 def test_checkout_path_is_fixed():
     """No pid, clock or temporary component: the directory is part of the
     cache key, so a path that moves never hits."""

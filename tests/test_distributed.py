@@ -246,10 +246,22 @@ def test_midstream_node_death_reroute_and_replay(cluster, params):
     ref = _oracle_greedy(params, prompt, 8)
 
     replacement = []
+    streamed, dead = threading.Event(), threading.Event()
+    seen = []
+
+    def on_token(token):
+        # prefill and two decode hops are out: the client stands still until
+        # the node is down, so the next hop is the one that meets the loss
+        seen.append(token)
+        if len(seen) == 3:
+            streamed.set()
+            assert dead.wait(30.0), "node 2 not stopped 30 s after token 3"
 
     def kill_and_replace():
-        time.sleep(0.8)  # let prefill + a few decode steps happen
+        if not streamed.wait(60.0):
+            return  # the assertion on ``dead`` below says so
         n2.stop()
+        dead.set()
         replacement.append(ServingNode(
             relay.port, CFG,
             {k: v[2:4] for k, v in params["layers"].items()}, 2, 3,
@@ -263,12 +275,15 @@ def test_midstream_node_death_reroute_and_replay(cluster, params):
         killer.start()
         try:
             got = client.generate(
-                prompt, max_new_tokens=8, timeout=4.0, reroute_wait=20.0
+                prompt, max_new_tokens=8, timeout=4.0, reroute_wait=20.0,
+                on_token=on_token,
             )
         finally:
+            streamed.set()  # a generate that raised must not leave it waiting
             killer.join()
             for node in replacement:
                 node.stop()
+        assert dead.is_set(), f"no third token in 60 s, node 2 not stopped: {seen}"
         assert client.failovers >= 1, "node died but no failover happened"
     assert got == ref
 

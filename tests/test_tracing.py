@@ -662,14 +662,22 @@ def test_boot_marks_are_written_once_and_in_order(monkeypatch):
     assert float(line.split()[1]) == pytest.approx(boot.start, abs=1e-3)
 
 
-def test_an_engine_that_served_a_request_shows_its_loads_by_stage():
+def test_an_engine_that_served_a_request_shows_its_loads_by_stage(
+    compiles_cold, monkeypatch
+):
     """The four stage counters are on ``/metrics`` beside the sum, and sum to
-    it; with the persistent cache off (``conftest``) nothing is a cache
-    read; the programs' rows hold what the counters hold."""
+    it; with the persistent cache off (the session's is on, ``conftest``:
+    this test compiles cold, and counts from nothing, as a process that has
+    just started does) nothing is a cache read; the programs' rows hold what
+    the counters hold."""
     from distributed_llm_inference_tpu.config import TraceConfig
 
-    eng = small_engine(trace_cfg=TraceConfig())
-    _serve(eng)
+    for name, value in vars(tracing.ProgramLoads()).items():
+        if name not in ("_lock", "_installed"):  # the listener stays as it is
+            monkeypatch.setattr(tracing.PROGRAM_LOADS, name, value)
+    with compiles_cold():
+        eng = small_engine(trace_cfg=TraceConfig())
+        _serve(eng)
     m = eng.metrics
     stages = {
         s: m.get_counter(f"engine_program_load_{s}_seconds")
